@@ -78,14 +78,6 @@ ENV_VARS: Dict[str, EnvVar] = {v.name: v for v in (
     _e("DLLM_NATIVE", None, "native/__init__.py",
        "'0' disables the g++-built native tokenizer/counter helpers; "
        "behavior is bit-identical to the pure-Python fallback."),
-    _e("DLLM_CHIP", "tpu_v5e", "utils/roofline.py",
-       "Chip name stamped into roofline/MFU accounting."),
-    _e("DLLM_PEAK_FLOPS", None, "utils/roofline.py",
-       "Peak accelerator FLOP/s for roofline accounting (float); unset "
-       "= the v5e peak constant in utils/roofline.py."),
-    _e("DLLM_PEAK_HBM", None, "utils/roofline.py",
-       "Peak HBM bytes/s for roofline accounting (float); unset = the "
-       "v5e peak constant in utils/roofline.py."),
     _e("DLLM_LINT_CHANGED", "HEAD", "lint/__main__.py",
        "Base git ref for `scripts/lint.sh --changed` (dllm-lint's "
        "diff-scoped mode): per-file checkers report only findings in "
@@ -120,18 +112,15 @@ ENV_VARS: Dict[str, EnvVar] = {v.name: v for v in (
     _e("DLLM_FLAGSHIP_KV_INT8", None, "config.py",
        "'1' opts the single-chip flagship orin tier into int8 KV cache "
        "(measured ~break-even r5; default off, VERDICT r5 #4)."),
-    _e("DLLM_TEST_COMPILE_CACHE", None, "tests/conftest.py",
-       "Suite-local XLA compile-cache dir override (wins over any global "
-       "JAX_COMPILATION_CACHE_DIR)."),
     _e("DLLM_BENCH_BUDGET_S", "1200", "bench.py",
        "Wall-clock budget for the whole bench run (s); phases are skipped "
        "with a stamped reason once it runs dry."),
     _e("DLLM_BENCH_WATCHDOG_S", "900", "bench.py",
        "Bench idle watchdog (s): no liveness beat for this long flushes "
-       "the partial artifact and exits (wedged-chip insurance)."),
+       "the partial artifact and exits (hung-device insurance)."),
     _e("DLLM_BENCH_NO_AB", None, "bench.py",
-       "'1' skips the in-process kernel A/B (set by __main__ after the "
-       "out-of-process dispatch measurement already ran)."),
+       "'1' skips the in-process kernel A/B that bench.run() makes when "
+       "no same-backend dispatch table exists."),
     _e("DLLM_BENCH_REPEATS", "3", "bench.py",
        "Headline sweep repeats; the artifact reports {median, iqr, n}."),
     _e("DLLM_BENCH_CLIENTS", "4", "bench.py",
@@ -143,9 +132,6 @@ ENV_VARS: Dict[str, EnvVar] = {v.name: v for v in (
        "'1' forces the flagship phase on the CPU fallback backend "
        "(normally skipped: a 1B model on one host core is not a "
        "measurement)."),
-    _e("DLLM_BENCH_PROBE_ATTEMPTS", "4", "bench.py",
-       "Accelerator-health probe attempts (with backoff) before the bench "
-       "surrenders the headline run to CPU."),
     _e("DLLM_HOST_KV_BYTES", None, "engine/batching.py",
        "Global override of TierConfig.host_kv_bytes — the host-RAM "
        "budget of the hierarchical KV spill tier in bytes ('0' disables "

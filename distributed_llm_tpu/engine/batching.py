@@ -316,6 +316,13 @@ class ContinuousBatchingEngine:
             from ..utils.checkpoint import load_params_for_tier
             params = load_params_for_tier(tier.checkpoint_path, self.cfg,
                                           mesh=mesh, devices=self.devices)
+        # An unsharded engine lives on the ONE device its tier was carved
+        # (``devices[0]``), committed there: every program it runs takes
+        # the weights or the pool, so its computation follows them.  Left
+        # to the process default, every tier of a multi-chip host would
+        # stack up on chip 0.
+        own = (jax.sharding.SingleDeviceSharding(self.devices[0])
+               if mesh is None and self.devices else None)
         if params is None:
             if mesh is not None:
                 from ..parallel.sharding import param_shardings
@@ -324,11 +331,11 @@ class ContinuousBatchingEngine:
                                out_shardings=param_shardings(self.cfg, mesh))
             else:
                 init = jax.jit(partial(models.init_params, self.cfg),
-                               static_argnames=("seed",))
+                               static_argnames=("seed",), out_shardings=own)
             params = init(seed=seed)
         from ..ops.quant import maybe_quantize
         self.params = maybe_quantize(params, tier, self.cfg, mesh=mesh)
-        self.pool = init_pool(self.cfg, self.paged, tier.kv_quantize)
+        self.pool = self._new_pool(self.cfg, own)
         self._pool_shardings = None
         self._replicated = None
         if mesh is not None:
@@ -507,7 +514,7 @@ class ContinuousBatchingEngine:
                 self._pool_shardings_d = self._pool_shardings
             else:
                 init_d = jax.jit(partial(models.init_params, self.cfg_d),
-                                 static_argnames=("seed",))
+                                 static_argnames=("seed",), out_shardings=own)
                 from ..ops.quant import maybe_quantize as _mq
                 self.params_d = _mq(init_d(seed=seed + 1), tier, self.cfg_d)
                 if mesh is not None:
@@ -521,7 +528,7 @@ class ContinuousBatchingEngine:
                     self._pool_shardings_d = self._replicated
             # Draft pool: same geometry (block count/size) as the target
             # pool so the target's block tables index it directly.
-            self.pool_d = init_pool(self.cfg_d, self.paged, tier.kv_quantize)
+            self.pool_d = self._new_pool(self.cfg_d, own)
             if self._pool_shardings_d is not None:
                 self.pool_d = jax.device_put(self.pool_d,
                                              self._pool_shardings_d)
@@ -585,6 +592,16 @@ class ContinuousBatchingEngine:
         self.phases = PhaseTimer()
         self._wbytes = roofline.weight_bytes(self.cfg, tier.quantize)
 
+    def _new_pool(self, cfg, own):
+        """A zeroed paged pool for ``cfg`` — allocated on, and committed
+        to, the engine's own device when it has one (``own``)."""
+        if own is None:
+            return init_pool(cfg, self.paged, self.tier.kv_quantize)
+        (device,) = own.device_set
+        with jax.default_device(device):
+            pool = init_pool(cfg, self.paged, self.tier.kv_quantize)
+        return jax.device_put(pool, own)
+
     def _resolve_ragged(self) -> bool:
         """Whether the decode tick runs the ragged fused path.
 
@@ -613,10 +630,6 @@ class ContinuousBatchingEngine:
         if self.mesh is not None:
             from ..parallel.tp_attention import _tp_ragged_ok
             if not _tp_ragged_ok(self.mesh, self.cfg):
-                return False
-            try:
-                from ..compat import shard_map  # noqa: F401
-            except ImportError:
                 return False
         from ..config_registry import env_str
         raw = env_str("DLLM_RAGGED")
@@ -780,8 +793,7 @@ class ContinuousBatchingEngine:
     def _decode_step(self):
         """One compiled tick for all slots: ``decode_steps_per_tick``
         sequential decode steps inside a single device call (lax.scan), so
-        the host↔device round trip — the dominant cost of a tick on a
-        tunneled or busy chip — is amortized over T tokens per slot.
+        the host↔device round trip is amortized over T tokens per slot.
         Returns tokens [T, B]; the host applies budget/EOS per slot and
         discards the ≤T-1 overshoot a mid-tick finisher decodes (its writes
         land in its own still-allocated blocks, freed on finish)."""
@@ -1343,10 +1355,11 @@ class ContinuousBatchingEngine:
         wait_ms = round((time.perf_counter() - req.t_submit) * 1000.0, 3)
         obs_spans.annotate(req.trace, queue_wait_ms=wait_ms,
                            admission_wait_ms=wait_ms)
-        ids, bucket = prepare_prompt(self.tokenizer, req.history,
-                                     self.tier.prefill_buckets,
-                                     self.cfg.max_seq_len,
-                                     self.tier.max_new_tokens)
+        with self.phases.phase("tokenize"):
+            ids, bucket = prepare_prompt(self.tokenizer, req.history,
+                                         self.tier.prefill_buckets,
+                                         self.cfg.max_seq_len,
+                                         self.tier.max_new_tokens)
         n = len(ids)
         budget = self.tier.max_new_tokens
         if req.max_new_tokens and req.max_new_tokens > 0:

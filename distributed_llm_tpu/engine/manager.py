@@ -208,11 +208,14 @@ class EngineManager:
             # from None.
             self._started_at = time.time()
             self._engine = engine
-            logger.info("tier %s up in %.1fs (model=%s, devices=%s)",
+            from ..ops.pallas_attention import kernel_mode
+            logger.info("tier %s up in %.1fs (model=%s, devices=%s, "
+                        "pallas kernels %s on backend %s)",
                         self.tier.name, time.perf_counter() - t0,
                         self.tier.model_preset,
                         [d.id for d in (self.devices or
-                                        (mesh_devs(self.mesh) or [jax.devices()[0]]))])
+                                        (mesh_devs(self.mesh) or [jax.devices()[0]]))],
+                        kernel_mode(), jax.default_backend())
 
     def stop_server(self) -> None:
         """Drop the engine; params/KV buffers are freed with it."""

@@ -69,7 +69,7 @@ import time
 from collections import deque
 from typing import Any, Dict, List, Optional
 
-# Canonical phase taxonomy (DESIGN.md "Tick forensics").  The profiler
+# Canonical phase names (DESIGN.md "Tick forensics").  The profiler
 # accepts any name — this tuple is the documented set the engine stamps
 # and the bench table orders by.  ``demote``/``promote`` (ISSUE 14) are
 # the hierarchical-KV spill tier's dispatch costs: the async gather

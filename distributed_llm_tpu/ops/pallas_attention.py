@@ -47,7 +47,23 @@ KERNEL_GEN = 2
 
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    """Interpret mode is for the host CPU only (the test suite).  On the
+    TPU the kernels compile; on any other backend there is no kernel to
+    run, and interpreting one in silence would pass for it — raise."""
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(
+        f"Pallas TPU kernels cannot run on backend {backend!r}: they "
+        f"compile on 'tpu' and are interpreted on 'cpu' only")
+
+
+def kernel_mode() -> str:
+    """'compiled' | 'interpret' — what a pallas_call of this package
+    does on the running backend (logged at engine start)."""
+    return "interpret" if _interpret() else "compiled"
 
 
 # =============================================================================

@@ -50,10 +50,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .attention import NEG_INF
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+from .pallas_attention import _interpret
 
 
 def _ragged_decode_kernel(tables_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,

@@ -22,7 +22,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 import numpy as np
-from ..compat import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 # Health record layout, one row per participant:

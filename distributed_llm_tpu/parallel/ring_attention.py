@@ -20,7 +20,7 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
-from ..compat import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..ops.attention import NEG_INF, _expand_kv

@@ -63,7 +63,7 @@ def sp_flash_decode(mesh: jax.sharding.Mesh, sp_axis: str = "sp",
     """(q [B,Nq,D], k/v [B,S,Nkv,D] sequence-sharded, pos [B]) ->
     [B,Nq,D]: per-shard partials + exact log-sum-exp merge over 'sp'.
     ``head_axis`` additionally shards the head axes over 'tp'."""
-    from ..compat import shard_map
+    from jax import shard_map
 
     def local(q, k_shard, v_shard, pos):
         s_local = k_shard.shape[1]

@@ -29,7 +29,7 @@ def tp_flash_causal(mesh: jax.sharding.Mesh,
                     head_axis: str = "tp") -> Callable:
     """(q, k, v) -> out with every array [B, S, N, D] sharded on its head
     axis over ``head_axis``; runs the flash kernel per shard."""
-    from ..compat import shard_map
+    from jax import shard_map
 
     from ..ops.pallas_attention import flash_causal_attention
 
@@ -46,7 +46,7 @@ def tp_flash_decode(mesh: jax.sharding.Mesh,
     """(q [B,Nq,D], k/v [B,S,Nkv,D], pos [B]) -> [B,Nq,D], head-sharded:
     the KV-length-tiled flash decode kernel runs per head-shard — each
     chip streams only its own heads' frontier-clamped cache slice."""
-    from ..compat import shard_map
+    from jax import shard_map
 
     from ..ops.pallas_attention import flash_decode_attention
 
@@ -65,7 +65,7 @@ def tp_paged_decode(mesh: jax.sharding.Mesh, quantized: bool = False,
     in-kernel block walk is shard-local.  Signature matches the
     decode_step_paged attention hook: (q, k_pool, v_pool, tables, pos,
     k_scale, v_scale)."""
-    from ..compat import shard_map
+    from jax import shard_map
 
     from ..ops.pallas_attention import (paged_decode_attention,
                                         paged_decode_attention_q8)
@@ -99,7 +99,7 @@ def tp_ragged_decode(mesh: jax.sharding.Mesh, impl: str = "auto",
     softmax merge.  Signature matches the decode_step_paged /
     verify_step_paged attention hook: (q, k_pool, v_pool, tables, pos,
     k_scale, v_scale) with per-layer pools [Nkv, NB, bs, D]."""
-    from ..compat import shard_map
+    from jax import shard_map
 
     from ..ops import attention
 
@@ -132,7 +132,7 @@ def tp_ragged_verify(mesh: jax.sharding.Mesh, impl: str = "auto",
     its head axis, pools on the kv-head axis — the γ+1-query twin of
     ``tp_ragged_decode`` so a spec round verifies every slot's drafts in
     ONE fused sharded call.  Same hook signature."""
-    from ..compat import shard_map
+    from jax import shard_map
 
     from ..ops import attention
 
@@ -167,7 +167,7 @@ def tp_local_ragged_decode(mesh: jax.sharding.Mesh, impl: str = "auto",
     pick the fused Pallas kernel, which is illegal in a plain jit over
     a mesh but fine inside shard_map's per-device region.  Hook
     signature matches ``tp_ragged_decode``."""
-    from ..compat import shard_map
+    from jax import shard_map
 
     from ..ops import attention
 

@@ -204,7 +204,7 @@ def main(argv=None) -> None:
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--eval-only", action="store_true")
     ap.add_argument("--cpu", action="store_true",
-                    help="pin jax to host CPU (safe on a wedged-chip box)")
+                    help="pin jax to host CPU")
     args = ap.parse_args(argv)
     if args.cpu:
         import jax

@@ -521,7 +521,22 @@ def install_drain_handler(router: Router, exit_after: bool = True) -> bool:
 
 def main() -> None:
     logging.basicConfig(level=logging.INFO)
-    router = Router(strategy="hybrid", config=dict(BASE_CONFIG))
+    import jax
+
+    from ..config import describe_cluster
+    from ..utils.compile_cache import enable_persistent_compile_cache
+    from .router import default_cluster
+    cache_dir = enable_persistent_compile_cache()
+    devices = jax.devices()
+    cluster = default_cluster()
+    # First line of a server's life: what it runs on and what it serves,
+    # so a server that came up on the host CPU (tiny test tiers) says so.
+    logger.info(
+        "serving on platform=%s device_kind=%s count=%d; cluster: %s; "
+        "compile cache: %s", devices[0].platform, devices[0].device_kind,
+        len(devices), describe_cluster(cluster), cache_dir)
+    router = Router(strategy="hybrid", config=dict(BASE_CONFIG),
+                    cluster=cluster)
     app = create_app(router=router)
     install_drain_handler(router)
     print("🚀 API running on http://0.0.0.0:8000")

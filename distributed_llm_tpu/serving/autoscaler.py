@@ -11,7 +11,7 @@ goodput-per-replica-second the economic headline the bench's elastic
 leg measures (the serving-cost framing the Gemma-on-TPU comparison in
 PAPERS.md judges TPU deployments by).
 
-Signal taxonomy — nothing here is a new measurement; the controller is
+Signal classes — nothing here is a new measurement; the controller is
 a pure READER of existing surfaces:
 
 - **SLO goodput** (obs/slo.py ``SLOMonitor.goodput(tier=...)``): the

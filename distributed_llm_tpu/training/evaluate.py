@@ -112,7 +112,7 @@ def main(argv=None) -> None:
     ap.add_argument("--batch-size", type=int, default=8)
     ap.add_argument("--seq-len", type=int, default=None)
     ap.add_argument("--cpu", action="store_true",
-                    help="pin jax to host CPU (safe on a wedged-chip box)")
+                    help="pin jax to host CPU")
     args = ap.parse_args(argv)
     if args.cpu:
         jax.config.update("jax_platforms", "cpu")

@@ -50,7 +50,6 @@ def pretrain(preset: str, out: str, *,
     steps.  The resumed run draws from a fresh generator stream offset by
     the saved step count — disjoint from the original run's batches at
     ANY (batch_size, seq_len), so changing the batch shape on resume
-    (tpu_round.sh extends the r3 orin checkpoint at a larger batch)
     neither repeats nor skips training text.
 
     Data parallelism uses every local device that divides the batch
@@ -148,7 +147,7 @@ def main(argv=None) -> None:
                     help="continue from an existing checkpoint at --out "
                          "(max-steps counts additional steps)")
     ap.add_argument("--cpu", action="store_true",
-                    help="pin jax to host CPU (safe on a wedged-chip box)")
+                    help="pin jax to host CPU")
     args = ap.parse_args(argv)
     from ..utils.compile_cache import enable_persistent_compile_cache
     enable_persistent_compile_cache()

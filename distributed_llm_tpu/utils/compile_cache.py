@@ -1,50 +1,53 @@
-"""Persistent XLA compilation cache for the chip entry points.
+"""Persistent XLA compilation cache for the entry points.
 
-Every bench/measurement process on this box recompiles the same
-programs: the tester builds a fresh Router per config (reference
-semantics — routing_chatbot_tester.py:368-376), tpu_round.sh runs each
-step as a separate claimant process, and the driver's round-end bench
-is yet another process.  On chip each compile is 20-40 s, so the sweep
-cost is compile-dominated.  JAX's persistent cache keys serialized
-executables by HLO hash on disk — fresh processes (and fresh jit
-closures inside one process) deserialize instead of recompiling.
+Every process that serves or measures recompiles the same programs: the
+server warms each tier's program family at start, the tester builds a
+fresh Router per config (reference semantics —
+routing_chatbot_tester.py:368-376), and each chip run is a new process
+on a new machine.  JAX's persistent cache keys serialized executables by
+HLO hash on disk — fresh processes (and fresh jit closures inside one
+process) deserialize instead of recompiling.
 
-The test suite wires the same thing in tests/conftest.py; this helper
-is for the runtime entry points (bench.py, bench.tester, ab_kernels,
-training.pretrain).  Call before the first device computation; the
-cache dir is env-overridable (JAX_COMPILATION_CACHE_DIR wins if set).
+Where the cache lives is the deployment's choice, not the code's: when
+``JAX_COMPILATION_CACHE_DIR`` is set, jax already uses that directory
+and this module sets no other.  Unset, the cache goes to one fixed
+directory inside the checkout (the path is part of the cache key's
+surroundings — a directory that moves never hits).
+
+The test suite wires the same rule in tests/conftest.py; this helper is
+for the runtime entry points (serving/app.py, chip_smoke.py, bench.py,
+bench.tester, ab_kernels, training.pretrain).  Call before the first
+device computation.
 """
 
 from __future__ import annotations
 
 import os
 
-DEFAULT_CACHE_DIR = "/tmp/dllm_jax_cache"
+REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+DEFAULT_CACHE_DIR = os.path.join(REPO_ROOT, ".jax_cache")
 
 
-def enable_persistent_compile_cache(path: str = None) -> str:
-    """Point jax at a persistent compilation cache; returns the dir.
+def compile_cache_dir() -> str:
+    """The directory the persistent cache belongs in: the externally set
+    ``JAX_COMPILATION_CACHE_DIR`` when there is one, else the fixed
+    in-checkout default."""
+    return os.environ.get("JAX_COMPILATION_CACHE_DIR") or DEFAULT_CACHE_DIR
 
-    Also exports the env vars so child processes (bench.py's per-kind
-    A/B subprocesses, subprocess-driven tests) inherit the same cache.
-    Safe to call any time before (or even after) backend init; a
-    backend that can't serialize executables just logs and skips —
-    never an error.
-    """
-    path = (path or os.environ.get("JAX_COMPILATION_CACHE_DIR")
-            or DEFAULT_CACHE_DIR)
-    os.environ["JAX_COMPILATION_CACHE_DIR"] = path
-    os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES", "-1")
-    os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS",
-                          "0.5")
+
+def enable_persistent_compile_cache() -> str:
+    """Point jax at the persistent compilation cache; returns the dir.
+
+    The thresholds make small/fast programs cacheable too (the serving
+    program family is many second-scale compiles); jax reads its own
+    ``JAX_PERSISTENT_CACHE_MIN_*`` variables, which win when set."""
     import jax
-    try:
+    path = compile_cache_dir()
+    if jax.config.jax_compilation_cache_dir != path:
         jax.config.update("jax_compilation_cache_dir", path)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", int(
-            os.environ["JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES"]))
-        jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                          float(os.environ[
-                              "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS"]))
-    except Exception:      # older jax without a knob: env vars still apply
-        pass
+    if "JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES" not in os.environ:
+        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    if "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS" not in os.environ:
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
     return path

@@ -23,28 +23,40 @@ Conventions (How-to-Scale-Your-Model accounting):
 
 from __future__ import annotations
 
-import os
 from typing import Any, Dict, Optional
 
-# Chip peaks for utilization denominators.  The bench box is a single
-# TPU v5e (16 GB HBM): 197 TFLOP/s bf16 on the MXU, 819 GB/s HBM.
-# Overridable for other chips without a code change.
-_V5E_PEAK_FLOPS = 197e12
-_V5E_PEAK_HBM = 819e9
+# Chip peaks for utilization denominators, keyed by the ``device_kind``
+# jax reports.  A kind that is not in the table is an error, not a
+# default: a utilization against guessed peaks reads like a measurement
+# and is not one.
+CHIP_PEAKS: Dict[str, Dict[str, Any]] = {
+    "TPU v5 lite": {
+        "chip": "tpu_v5e",
+        "peak_flops": 197e12,               # bf16 on the MXU
+        "peak_hbm_bytes_per_s": 819e9,
+        "source": "Google Cloud documentation, \"TPU v5e\": 197 TFLOP/s "
+                  "bf16, 16 GB HBM at 819 GB/s per chip",
+    },
+}
 
 
-def chip_peaks(backend: str) -> Optional[Dict[str, float]]:
-    """Peak FLOP/s and HBM B/s for the backend, or None when utilization
-    is meaningless (host CPU fallback has no published roofline here)."""
-    if backend == "cpu":
+def chip_peaks(device=None) -> Optional[Dict[str, Any]]:
+    """Peak FLOP/s and HBM B/s of ``device`` (default: the process's
+    first jax device), looked up by its ``device_kind``.  None on the
+    host CPU, which has no roofline here (tests); any other device whose
+    kind is not in ``CHIP_PEAKS`` raises."""
+    if device is None:
+        import jax
+        device = jax.devices()[0]
+    if device.platform == "cpu":
         return None
-    return {
-        "peak_flops": float(os.environ.get("DLLM_PEAK_FLOPS",
-                                           _V5E_PEAK_FLOPS)),
-        "peak_hbm_bytes_per_s": float(os.environ.get("DLLM_PEAK_HBM",
-                                                     _V5E_PEAK_HBM)),
-        "chip": os.environ.get("DLLM_CHIP", "tpu_v5e"),
-    }
+    try:
+        return dict(CHIP_PEAKS[device.device_kind])
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device_kind={device.device_kind!r} "
+            f"(platform {device.platform!r}); add it to "
+            f"utils/roofline.CHIP_PEAKS with its source") from None
 
 
 def active_matmul_params(cfg) -> int:

@@ -165,6 +165,43 @@ def test_mesh_engine_shards_params_and_pool():
         eng.stop()
 
 
+@pytest.mark.parametrize("quantize,draft", [("none", None),
+                                            ("int8", None),
+                                            ("none", "draft_test")],
+                         ids=["bf16", "int8-weights", "speculative"])
+def test_unsharded_engine_lives_on_the_device_it_is_given(quantize, draft):
+    """An unsharded batched engine is committed to ``devices[0]`` — the
+    chip its tier was carved — not to the process's default device:
+    weights, pool (and a draft's) sit there, stay there through a
+    multi-turn exchange, and the tokens are the default-device engine's.
+    (Before PR 22 it ignored ``devices`` and every tier of a multi-chip
+    host stacked up on chip 0.)"""
+    tier = _tier(quantize=quantize, draft_preset=draft,
+                 spec_decode=True if draft else None)
+    home = jax.devices()[3]
+    turns = ["user: what is the capital of France?",
+             "user: what is the capital of France? assistant: Paris. "
+             "user: and of Spain?"]
+
+    def run(devices):
+        eng = ContinuousBatchingEngine(tier, seed=5, devices=devices)
+        try:
+            out = [eng.generate(t, max_new_tokens=6).token_ids
+                   for t in turns]
+            where = {d for label in ("params", "pool", "params_d", "pool_d")
+                     for leaf in jax.tree_util.tree_leaves(
+                         getattr(eng, label, None))
+                     for d in leaf.devices()}
+            return out, where
+        finally:
+            eng.stop()
+
+    base, _ = run(None)
+    placed, where = run([home])
+    assert where == {home}
+    assert placed == base
+
+
 def test_multi_step_tick_respects_budget_and_matches_single_step():
     """T decode steps per device call must not change outputs: budgets are
     enforced on host (overshoot discarded) and greedy tokens are identical

@@ -3,10 +3,6 @@ the train → serve weight handoff."""
 
 import jax
 
-from conftest import (ENV_SKIP_ORBAX_PARTIAL_RESTORE,
-                      env_require_shard_map)
-
-env_require_shard_map()   # this module's imports need jax.shard_map
 import numpy as np
 import pytest
 
@@ -64,7 +60,6 @@ def test_cross_mesh_restore(tmp_path):
     assert np.isfinite(t_small.train_step(tokens, mask)["loss"])
 
 
-@ENV_SKIP_ORBAX_PARTIAL_RESTORE   # restores a published checkpoint
 def test_train_then_serve_from_checkpoint(tmp_path):
     t = _trainer(jax.devices()[:2], seed=5)
     tokens, mask = next(batches(4, 32, seed=2))
@@ -150,8 +145,8 @@ def test_save_replaces_stale_same_step_version(tmp_path):
 
 
 def test_peek_vocab_size_reads_metadata_only():
-    """scripts/tpu_round.sh's stale-vocab guard depends on this returning
-    the real embed row count (ADVICE-style regression: the orbax metadata
+    """A stale-vocab guard before serving or resuming a checkpoint
+    depends on this returning the real embed row count (ADVICE-style regression: the orbax metadata
     pytree lives under item_metadata.tree)."""
     from distributed_llm_tpu.config import MODEL_PRESETS, default_checkpoint
     from distributed_llm_tpu.utils.checkpoint import peek_vocab_size

@@ -1,76 +1,71 @@
-"""Environment-failure hygiene pins (ISSUE 12 satellite).
+"""Environment hygiene pins (ISSUE 12 satellite, re-cut by PR 22).
 
-This container's jax lacks ``from jax import shard_map``, its orbax
-predates ``PyTreeRestore(partial_restore=...)``, and ``hypothesis`` is
-not installed.  Those used to surface as a fixed pile of 15 failures +
-7 collection errors every session re-diffed against the seed baseline
-by hand; they are now explicit ``env:``-reasoned skip guards
-(tests/conftest.py) so tier-1 is green-or-real.
+Older containers lacked ``from jax import shard_map``, an orbax with
+``PyTreeRestore(partial_restore=...)`` and ``hypothesis``; the suite
+carried ``env:``-reasoned skip guards for each, and this file pinned
+their count so a regression could not hide inside a growing skip pile.
 
-The PIN: the guard count per capability is asserted here by scanning
-the test sources.  Adding a new env skip without updating
-``EXPECTED_GUARDS`` fails this test — a genuine regression cannot hide
-inside a silently growing skip pile, and a guard that stops being
-needed (container upgraded, capability restored) is noticed when the
-probes flip True.
+There is one installation now (jax 0.9.0, orbax 0.11.32, hypothesis
+6.x) and it has all three, so the guards — which had all come to
+evaluate to "run" — are gone.  What is pinned instead: that no guard
+comes back under any name, and that the installation still has what the
+guards used to probe, so a container that loses one fails HERE, by name,
+instead of skipping or dying at collection somewhere else.
 """
 
 import glob
+import inspect
 import os
 import re
 
-import conftest
+import pytest
 
 TESTS_DIR = os.path.dirname(os.path.abspath(__file__))
 
-# capability-guard symbol -> exact number of use sites across tests/
-# (module-level guards count call sites; markers count decorations).
-EXPECTED_GUARDS = {
-    # PR 16's compat shim (distributed_llm_tpu/compat) flips the
-    # shard_map probe True in this container — the guards below remain
-    # (for a jax with NEITHER spelling) but no longer skip here, which
-    # exposed the checkpoint-backed tests inside those modules to the
-    # orbax partial_restore gap: they now carry their own orbax guard
-    # (hence 8 -> 13).
-    "env_require_shard_map": 8,       # module imports need shard_map
-    "env_require_hypothesis": 1,      # test_properties
-    "ENV_SKIP_SHARD_MAP": 1,          # test_health ICI allgather
-    "ENV_SKIP_ORBAX_PARTIAL_RESTORE": 13,  # checkpoint-backed serving
-}
 
-
-def _guard_uses():
-    counts = {name: 0 for name in EXPECTED_GUARDS}
-    for path in glob.glob(os.path.join(TESTS_DIR, "test_*.py")):
+def test_no_env_skip_guards_remain():
+    """No test skips on a probe of the installed packages: tier-1 is
+    green-or-real."""
+    offenders = []
+    pattern = re.compile(
+        r"importorskip\(|ENV_SKIP_|env_require_|HAS_(SHARD_MAP|ORBAX|"
+        r"HYPOTHESIS)|allow_module_level")
+    for path in glob.glob(os.path.join(TESTS_DIR, "*.py")):
         if os.path.basename(path) == os.path.basename(__file__):
             continue
-        src = open(path, encoding="utf-8").read()
-        for name in EXPECTED_GUARDS:
-            # Use sites only: a decoration (@NAME) or a module-level
-            # guard call (NAME()), never the import line.
-            counts[name] += len(re.findall(
-                rf"(?m)^@{name}\b|^{name}\(\)", src))
-    return counts
+        if pattern.search(open(path, encoding="utf-8").read()):
+            offenders.append(os.path.basename(path))
+    assert offenders == []
 
 
-def test_env_skip_counts_are_pinned():
-    assert _guard_uses() == EXPECTED_GUARDS, (
-        "environment skip-guard count changed: if you added or removed "
-        "an `env:` skip, update EXPECTED_GUARDS here — the pin exists "
-        "so regressions can't hide inside the skip pile")
+def _shard_map():
+    from jax import shard_map
+    assert "check_vma" in inspect.signature(shard_map).parameters
 
 
-def test_env_guards_carry_env_reasons():
-    """Every capability marker must carry an 'env: ' reason so a skip
-    report is attributable at a glance."""
-    for mark in (conftest.ENV_SKIP_SHARD_MAP,
-                 conftest.ENV_SKIP_ORBAX_PARTIAL_RESTORE):
-        assert mark.kwargs.get("reason", "").startswith("env: ")
+def _orbax_partial_restore():
+    import orbax.checkpoint as ocp
+    assert "partial_restore" in inspect.signature(
+        ocp.args.PyTreeRestore.__init__).parameters
 
 
-def test_capability_probes_are_booleans():
-    """The probes must PROBE (never raise), whichever container runs
-    them — a probe crash would turn hygiene back into red."""
-    assert isinstance(conftest.HAS_SHARD_MAP, bool)
-    assert isinstance(conftest.HAS_ORBAX_PARTIAL_RESTORE, bool)
-    assert isinstance(conftest.HAS_HYPOTHESIS, bool)
+def _hypothesis():
+    import hypothesis  # noqa: F401
+
+
+@pytest.mark.parametrize("probe", [_shard_map, _orbax_partial_restore,
+                                   _hypothesis],
+                         ids=["jax.shard_map(check_vma)",
+                              "orbax-partial_restore", "hypothesis"])
+def test_installation_has_what_the_old_guards_probed(probe):
+    probe()
+
+
+def test_jax_compat_shim_is_gone():
+    """The package imports ``shard_map`` from jax itself (PR 22 deleted
+    distributed_llm_tpu/compat, the pre-0.4.35 spelling shim)."""
+    pkg = os.path.join(os.path.dirname(TESTS_DIR), "distributed_llm_tpu")
+    assert not os.path.exists(os.path.join(pkg, "compat"))
+    for path in glob.glob(os.path.join(pkg, "parallel", "*.py")):
+        assert "jax.experimental.shard_map" not in open(
+            path, encoding="utf-8").read(), path

@@ -5,8 +5,6 @@ import numpy as np
 import jax
 import pytest
 
-from conftest import ENV_SKIP_SHARD_MAP
-
 from distributed_llm_tpu.config import tiny_cluster
 from distributed_llm_tpu.serving.health import HealthMonitor
 from distributed_llm_tpu.serving.router import Router
@@ -57,7 +55,6 @@ def test_auto_restart_after_running_tier_fails(router):
     assert mgr.is_server_running()
 
 
-@ENV_SKIP_SHARD_MAP   # the ICI allgather needs jax.shard_map
 def test_exchange_merges_remote_rows_only(router):
     devs = np.array(jax.devices()[:2])
     mesh = jax.sharding.Mesh(devs, ("hosts",))

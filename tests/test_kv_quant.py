@@ -13,8 +13,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from conftest import ENV_SKIP_ORBAX_PARTIAL_RESTORE
-
 from distributed_llm_tpu.config import MODEL_PRESETS, tiny_cluster
 from distributed_llm_tpu.engine.paged_kv import (PagedConfig,
                                                  dequantize_kv_rows,
@@ -141,7 +139,6 @@ def _tier(**kw):
                                max_new_tokens=8, **kw)
 
 
-@ENV_SKIP_ORBAX_PARTIAL_RESTORE   # serves from a published checkpoint
 def test_batched_engine_kv_int8_serves_close_to_bf16():
     """Engine level: an int8-KV engine on trained weights produces the
     same greedy tokens as bf16 for a short generation (quantization noise
@@ -192,7 +189,6 @@ def test_tp_mesh_kv_int8_pool_sharded_and_consistent():
         tp.stop()
 
 
-@ENV_SKIP_ORBAX_PARTIAL_RESTORE   # serves from a published checkpoint
 def test_sequential_engine_kv_int8_matches_bf16_tokens():
     """Contiguous-cache int8 (the sequential engine — the headline sweep
     path): same greedy tokens as bf16 on trained weights, int8 cache

@@ -617,7 +617,7 @@ def test_jit_purity_shard_map_wrapped_pallas_dispatcher_flagged():
         from functools import partial
 
         from jax.experimental import pallas as pl
-        from distributed_llm_tpu.compat import shard_map
+        from jax import shard_map
 
 
         def _kernel(q_ref, o_ref, *, bs):

@@ -3,10 +3,6 @@ parallelism, and end-to-end serving through the engine."""
 
 import jax
 
-from conftest import (ENV_SKIP_ORBAX_PARTIAL_RESTORE,
-                      env_require_shard_map)
-
-env_require_shard_map()   # this module's imports need jax.shard_map
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -97,7 +93,6 @@ def test_moe_serves_through_engine():
     assert r.token_ids == r2.token_ids
 
 
-@ENV_SKIP_ORBAX_PARTIAL_RESTORE   # restores a published checkpoint
 def test_moe_checkpoint_roundtrip(tmp_path):
     from distributed_llm_tpu.utils import checkpoint as ckpt
     mesh = moe_training_mesh(jax.devices()[:4], num_experts=CFG.num_experts)

@@ -3,9 +3,6 @@ and the pipeline-parallel transformer trainer."""
 
 import jax
 
-from conftest import env_require_shard_map
-
-env_require_shard_map()   # this module's imports need jax.shard_map
 import jax.numpy as jnp
 import numpy as np
 import pytest

@@ -13,8 +13,6 @@ import dataclasses
 import numpy as np
 import pytest
 
-from conftest import ENV_SKIP_ORBAX_PARTIAL_RESTORE
-
 from distributed_llm_tpu.config import (default_checkpoint, tiny_cluster,
                                         with_default_checkpoints)
 from distributed_llm_tpu.engine.inference import InferenceEngine
@@ -37,7 +35,6 @@ def _tier(**kw):
     return dataclasses.replace(base, **kw)
 
 
-@ENV_SKIP_ORBAX_PARTIAL_RESTORE   # restores a published checkpoint
 def test_checkpoint_text_is_deterministic_across_seeds():
     """Engine seed must not matter once weights come from the checkpoint
     (greedy decode): the reply is a function of the artifact."""
@@ -47,7 +44,6 @@ def test_checkpoint_text_is_deterministic_across_seeds():
     assert a.gen_tokens >= 4
 
 
-@ENV_SKIP_ORBAX_PARTIAL_RESTORE   # restores a published checkpoint
 def test_checkpoint_text_is_non_garbage():
     """Served text is structured corpus-like English: printable ASCII and
     mostly words the training distribution contains — not random bytes
@@ -65,7 +61,6 @@ def test_checkpoint_text_is_non_garbage():
     assert hits / len(words) >= 0.4, (text, hits, len(words))
 
 
-@ENV_SKIP_ORBAX_PARTIAL_RESTORE   # restores a published checkpoint
 def test_trained_weights_beat_random_on_corpus_nll():
     """The strongest non-garbage signal: the checkpoint's next-byte NLL on
     held-out synthetic text must crush random init's."""
@@ -101,7 +96,6 @@ def test_default_cluster_serves_published_weights():
     assert keep.nano.checkpoint_path == "/x"
 
 
-@ENV_SKIP_ORBAX_PARTIAL_RESTORE   # restores a published checkpoint
 def test_batching_engine_serves_checkpoint():
     """The continuous-batching engine path loads the same artifact (the
     EngineManager passes params through for decode_batch > 1 tiers)."""

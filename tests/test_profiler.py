@@ -20,12 +20,30 @@ from distributed_llm_tpu.obs.spans import RequestTrace, use_trace
 
 # -- TickProfiler unit mechanics ---------------------------------------------
 
-def test_phase_nesting_self_time_and_ring_bound():
+class _FakeClock:
+    """Stands in for the profiler module's ``time``: the test advances
+    it by hand, so the self-time arithmetic is checked exactly and a
+    descheduled worker (6 xdist workers share the box) cannot stretch a
+    span."""
+
+    def __init__(self):
+        self.now = 100.0
+
+    def perf_counter(self) -> float:
+        return self.now
+
+    def sleep(self, seconds: float) -> None:
+        self.now += seconds
+
+
+def test_phase_nesting_self_time_and_ring_bound(monkeypatch):
+    clock = _FakeClock()
+    monkeypatch.setattr(P, "time", clock)
     prof = P.TickProfiler("t", capacity=16)
     with prof.phase("admit"):
-        time.sleep(0.002)
+        clock.sleep(0.002)
         with prof.phase("prefill"):
-            time.sleep(0.005)
+            clock.sleep(0.005)
     prof.commit(slots=2)
     (rec,) = prof.records()
     assert rec["slots"] == 2 and rec["seq"] == 1
@@ -33,8 +51,9 @@ def test_phase_nesting_self_time_and_ring_bound():
              for name, _rel, dur, self_ms in rec["spans"]}
     # The child's full duration is excluded from the parent's SELF time
     # (self-times partition the tick wall; durations nest).
-    assert spans["admit"][0] > spans["prefill"][0]
-    assert spans["admit"][1] < spans["prefill"][0]
+    assert spans["admit"][0] == pytest.approx(7.0)
+    assert spans["admit"][1] == pytest.approx(2.0)
+    assert spans["prefill"][0] == pytest.approx(5.0)
     assert spans["prefill"][0] == pytest.approx(spans["prefill"][1])
     total_self = sum(s for _, s in spans.values())
     assert total_self <= rec["dur_ms"] * 1.001

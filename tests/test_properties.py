@@ -8,9 +8,6 @@ reclaimed prefix must actually be a prefix)."""
 
 import jax  # noqa: F401  (conftest pins CPU before anything imports jax)
 
-from conftest import env_require_hypothesis
-
-env_require_hypothesis()  # this module's imports need hypothesis
 from hypothesis import given, settings, strategies as st
 
 from distributed_llm_tpu.engine.paged_kv import TRASH_BLOCK, BlockAllocator

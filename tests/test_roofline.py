@@ -65,11 +65,36 @@ def test_decode_work_scales_with_batch_and_ctx():
     assert one["tokens"] == 4 and two["tokens"] == 8
 
 
-def test_chip_peaks_cpu_none_tpu_v5e():
-    assert roofline.chip_peaks("cpu") is None
-    peaks = roofline.chip_peaks("tpu")
-    assert peaks["peak_flops"] == pytest.approx(197e12)
-    assert peaks["peak_hbm_bytes_per_s"] == pytest.approx(819e9)
+class _Dev:
+    def __init__(self, platform, device_kind):
+        self.platform, self.device_kind = platform, device_kind
+
+
+@pytest.mark.parametrize("platform,kind,expect", [
+    ("cpu", "cpu", None),
+    ("tpu", "TPU v5 lite", (197e12, 819e9)),
+    ("tpu", "TPU v9 imaginary", ValueError),
+    ("gpu", "NVIDIA H100", ValueError),
+])
+def test_chip_peaks_keyed_by_device_kind(platform, kind, expect):
+    """Peaks come from a table keyed by device_kind with their source;
+    the host CPU has none, and an unknown accelerator raises instead of
+    borrowing the v5e's numbers."""
+    dev = _Dev(platform, kind)
+    if expect is None:
+        assert roofline.chip_peaks(dev) is None
+    elif expect is ValueError:
+        with pytest.raises(ValueError, match="device_kind"):
+            roofline.chip_peaks(dev)
+    else:
+        peaks = roofline.chip_peaks(dev)
+        assert peaks["peak_flops"] == pytest.approx(expect[0])
+        assert peaks["peak_hbm_bytes_per_s"] == pytest.approx(expect[1])
+        assert peaks["chip"] == "tpu_v5e" and peaks["source"]
+
+
+def test_chip_peaks_defaults_to_the_process_device():
+    assert roofline.chip_peaks() is None          # the suite runs on CPU
 
 
 def test_utilization_math():

@@ -742,3 +742,49 @@ def test_default_cluster_cpu_bench_pair_is_opt_in(monkeypatch):
         lambda preset: None if preset == "mini_bench" else f"/ck/{preset}")
     cl = R.default_cluster(cpu_bench=True)
     assert cl.nano.model_preset == "nano_test"
+
+
+def test_server_main_says_what_it_runs_on_before_serving(monkeypatch,
+                                                         caplog, tmp_path):
+    """`python -m distributed_llm_tpu`: the compile cache is placed and
+    the FIRST log line names platform, device kind/count, the cluster
+    chosen and the cache — so a server that came up on the host CPU
+    (tiny test tiers) says so before it serves anything."""
+    import logging
+
+    from distributed_llm_tpu.serving import app as app_mod
+
+    built = {}
+
+    class StubRouter:
+        def __init__(self, **kw):
+            built["router"] = kw
+
+    class StubApp:
+        def run(self, **kw):
+            built["run"] = kw
+
+    prior = jax_cache_dir()
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    monkeypatch.setattr(app_mod, "Router", StubRouter)
+    monkeypatch.setattr(app_mod, "create_app", lambda router: StubApp())
+    monkeypatch.setattr(app_mod, "install_drain_handler", lambda r: True)
+    try:
+        with caplog.at_level(logging.INFO,
+                             logger="distributed_llm_tpu.serving.app"):
+            app_mod.main()
+        assert jax_cache_dir() == str(tmp_path)
+    finally:
+        import jax
+        jax.config.update("jax_compilation_cache_dir", prior)
+    first = caplog.records[0].getMessage()
+    assert "platform=cpu" in first and "count=8" in first
+    assert "nano=nano_test" in first and "orin=orin_test" in first
+    assert str(tmp_path) in first
+    assert built["router"]["cluster"].nano.model_preset == "nano_test"
+    assert built["run"]["port"] == 8000
+
+
+def jax_cache_dir():
+    import jax
+    return jax.config.jax_compilation_cache_dir

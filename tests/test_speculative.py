@@ -6,8 +6,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from conftest import ENV_SKIP_ORBAX_PARTIAL_RESTORE
-
 from distributed_llm_tpu.config import TierConfig
 from distributed_llm_tpu.engine.inference import InferenceEngine
 from distributed_llm_tpu.engine.speculative import (SpeculativeEngine,
@@ -156,7 +154,6 @@ def test_speculative_stream_matches_generate():
     assert handle.result.token_ids == ref.token_ids
 
 
-@ENV_SKIP_ORBAX_PARTIAL_RESTORE   # serves from a published checkpoint
 def test_fused_loop_matches_streaming_tokens():
     """generate() (one fused while_loop device call) and generate_stream()
     (one device call per round) must emit identical tokens — both are

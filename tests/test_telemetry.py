@@ -56,26 +56,33 @@ def test_device_memory_snapshot_shape():
     assert {"device", "platform", "bytes_in_use"} <= set(snap[0])
 
 
-def test_enable_persistent_compile_cache_exports_env(tmp_path, monkeypatch):
-    """The helper must point jax at the cache dir AND export the env vars
-    so subprocess children (per-kind A/B, subprocess tests) inherit the
-    same cache; an explicit JAX_COMPILATION_CACHE_DIR wins."""
+def test_enable_persistent_compile_cache_placement(tmp_path, monkeypatch):
+    """An externally set JAX_COMPILATION_CACHE_DIR is used as is (and no
+    other directory is set); unset, the cache goes to ONE fixed directory
+    inside the checkout — never /tmp, never a per-process name."""
+    import os
+
     import jax
 
-    from distributed_llm_tpu.utils.compile_cache import \
-        enable_persistent_compile_cache
+    from distributed_llm_tpu.utils import compile_cache
 
-    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "env"))
-    assert enable_persistent_compile_cache() == str(tmp_path / "env")
-
-    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
     prior = jax.config.jax_compilation_cache_dir
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     try:
-        got = enable_persistent_compile_cache(str(tmp_path / "explicit"))
-        assert got == str(tmp_path / "explicit")
-        import os
-        assert os.environ["JAX_COMPILATION_CACHE_DIR"] == got
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "env"))
+        got = compile_cache.enable_persistent_compile_cache()
+        assert got == str(tmp_path / "env")
         assert jax.config.jax_compilation_cache_dir == got
+        assert os.environ["JAX_COMPILATION_CACHE_DIR"] == got
+
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        got = compile_cache.enable_persistent_compile_cache()
+        assert got == os.path.join(repo, ".jax_cache")
+        assert got == compile_cache.enable_persistent_compile_cache()
+        assert jax.config.jax_compilation_cache_dir == got
+        # The helper places the cache; it does not rewrite the caller's
+        # environment.
+        assert "JAX_COMPILATION_CACHE_DIR" not in os.environ
     finally:
         # Restore the suite-wide cache dir (conftest set it): this config
         # is process-global and later tests should keep their warm cache.

@@ -7,8 +7,6 @@ per-query + summary CSV schemas; accuracy vs expected_device labels.
 
 import csv
 
-from conftest import ENV_SKIP_ORBAX_PARTIAL_RESTORE
-
 from distributed_llm_tpu.bench import tester
 from distributed_llm_tpu.bench.query_sets import query_sets
 
@@ -118,7 +116,6 @@ def test_analysis_report_and_plots(tmp_path):
     assert pngs, "expected at least one plot"
 
 
-@ENV_SKIP_ORBAX_PARTIAL_RESTORE   # phase timings need the checkpoint-backed engines
 def test_stats_endpoint_exposes_phases_and_cache():
     from distributed_llm_tpu.serving.app import create_app
     app = create_app()

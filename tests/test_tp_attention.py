@@ -12,9 +12,6 @@ import dataclasses
 
 import jax
 
-from conftest import env_require_shard_map
-
-env_require_shard_map()   # this module's imports need jax.shard_map
 import jax.numpy as jnp
 import numpy as np
 import pytest

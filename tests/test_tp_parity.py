@@ -24,9 +24,6 @@ import time
 
 import jax
 
-from conftest import env_require_shard_map
-
-env_require_shard_map()   # shard_map spelling probe (compat shim)
 import numpy as np
 import pytest
 

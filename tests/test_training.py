@@ -7,9 +7,6 @@ sharding placement, loss decrease, and determinism of the data pipeline.
 
 import jax
 
-from conftest import env_require_shard_map
-
-env_require_shard_map()   # this module's imports need jax.shard_map
 import numpy as np
 import pytest
 
