@@ -88,9 +88,10 @@ ENV_VARS: Dict[str, EnvVar] = {v.name: v for v in (
        "'0' disables the batched engines' tick-phase profiler AND the "
        "per-request device-time/KV-residency attribution (zero-cost "
        "null object); default on (measured <= 1% of tick p50)."),
-    _e("DLLM_PROFILE_TICKS", "512", "obs/profiler.py",
+    _e("DLLM_PROFILE_TICKS", "4096", "obs/profiler.py",
        "Tick-phase profiler ring capacity in tick records per engine "
-       "(GET /debug/trace exports the ring's span)."),
+       "(GET /debug/trace exports the ring's span): 120 s at 30 "
+       "scheduler passes a second, 8-9 MB an engine when full."),
     _e("DLLM_OBS_SLOW_MS", "30000", "obs/__init__.py",
        "Global flight-recorder slow-request threshold in ms; '0'/'off' "
        "disables the slow trigger (failed/degraded still record)."),

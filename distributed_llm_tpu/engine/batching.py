@@ -76,10 +76,12 @@ class EngineStoppedError(RuntimeError):
 def _sample_batched(logits: jax.Array, rng: jax.Array,
                     temps: jax.Array) -> jax.Array:
     """Per-slot runtime temperature: greedy where temp<=0, else sampled."""
-    greedy = jnp.argmax(logits, axis=-1)
-    scaled = logits.astype(jnp.float32) / jnp.maximum(temps, 1e-6)[:, None]
-    sampled = jax.random.categorical(rng, scaled, axis=-1)
-    return jnp.where(temps > 0.0, sampled, greedy)
+    with jax.named_scope("sample"):
+        greedy = jnp.argmax(logits, axis=-1)
+        scaled = (logits.astype(jnp.float32)
+                  / jnp.maximum(temps, 1e-6)[:, None])
+        sampled = jax.random.categorical(rng, scaled, axis=-1)
+        return jnp.where(temps > 0.0, sampled, greedy)
 
 
 def _fetch_tick(x):
@@ -135,6 +137,12 @@ class _Request:
     # prefill lane was busy: the scheduler skips re-popping (and
     # re-tokenizing) the head request every tick until the lane frees.
     needs_chunk: bool = False
+    # Time spent at the scheduler head held by the busy prefill lane
+    # (``head_blocked``): stamped when an admission first defers on it,
+    # closed by the next admission attempt, annotated ``lane_wait_ms``
+    # (0 for a request the lane never held up).
+    t_lane_blocked: Optional[float] = None
+    lane_wait_ms: float = 0.0
     # Billing identity (ISSUE 17): which tenant's quota this request
     # draws down.  None (direct engine use, quotas off) bills to the
     # shared default tenant where tenant state exists at all.
@@ -591,6 +599,20 @@ class ContinuousBatchingEngine:
         from ..utils import roofline
         self.phases = PhaseTimer()
         self._wbytes = roofline.weight_bytes(self.cfg, tier.quantize)
+        # The decode ticks' roofline work is COUNTED on the tick and
+        # computed when /stats asks (_account_tick,
+        # _tick_work_estimate): {(kind, window, slots, γ bucket):
+        # [ticks, Σ mean KV span]}, scheduler-thread writes only.
+        self._tick_work: Dict[tuple, List[float]] = {}
+        self.phases.lazy_work = self._tick_work_estimate
+        # Attention dispatch kind of a plain and of a speculative tick
+        # (fixed for the engine's life) and, per (kind, window rung),
+        # the impl the measured table chose with its metric children.
+        q8 = "_q8" if tier.kv_quantize == "int8" else ""
+        self._tick_kind = ("ragged_decode" if self.ragged
+                           else "paged_decode") + q8
+        self._tick_kind_spec = "ragged_verify" + q8
+        self._tick_sink_cache: Dict[tuple, tuple] = {}
 
     def _new_pool(self, cfg, own):
         """A zeroed paged pool for ``cfg`` — allocated on, and committed
@@ -776,7 +798,7 @@ class ContinuousBatchingEngine:
         from ..parallel.tp_attention import tp_prefill_attn
         attn = tp_prefill_attn(self.mesh, cfg, bucket)
 
-        def run(params, tokens, true_len, rng, temp):
+        def cold_prefill(params, tokens, true_len, rng, temp):
             b, s = tokens.shape
             positions = jnp.broadcast_to(jnp.arange(s)[None], (b, s))
             hidden, (k_all, v_all) = models.serving_prefill(
@@ -786,7 +808,7 @@ class ContinuousBatchingEngine:
             first = _sample_batched(logits, rng, temp[None])[0]
             return first, k_all[:, 0], v_all[:, 0]       # squeeze batch
 
-        fn = jax.jit(run)
+        fn = jax.jit(cold_prefill)
         self._prefill_fns[bucket] = fn
         return fn
 
@@ -806,7 +828,7 @@ class ContinuousBatchingEngine:
         ragged = self.ragged
         quantized = self.tier.kv_quantize == "int8"
 
-        def run(params, pool, tables, pos, cur, temps, rng):
+        def decode_tick(params, pool, tables, pos, cur, temps, rng):
             # TP tiers: ragged ticks wrap the DISPATCHING ragged decode
             # in shard_map over the kv-head axis (PR 16 — the fused
             # paged path runs sharded, combine is a head concat); dense
@@ -843,7 +865,8 @@ class ContinuousBatchingEngine:
         kw = {}
         if self._pool_shardings is not None:
             kw["out_shardings"] = (self._replicated, self._pool_shardings)
-        self._decode_fn = jax.jit(run, donate_argnums=donate, **kw)
+        self._decode_fn = jax.jit(decode_tick, donate_argnums=donate,
+                                  **kw)
         return self._decode_fn
 
     def _chunk_prefill_fn(self, bucket: int, window: int):
@@ -855,7 +878,8 @@ class ContinuousBatchingEngine:
         self._note_compile("chunk_prefill", (bucket, window))
         cfg = self.cfg
 
-        def run(params, pool, tokens, start, true_len, table, rng, temp):
+        def chunk_prefill(params, pool, tokens, start, true_len, table,
+                          rng, temp):
             hidden, pool = chunk_prefill_paged(
                 cfg, params, tokens, start, true_len, pool, table, window)
             last = hidden[0, true_len[0] - start[0] - 1]
@@ -867,7 +891,7 @@ class ContinuousBatchingEngine:
         kw = {}
         if self._pool_shardings is not None:
             kw["out_shardings"] = (self._replicated, self._pool_shardings)
-        fn = jax.jit(run, donate_argnums=donate, **kw)
+        fn = jax.jit(chunk_prefill, donate_argnums=donate, **kw)
         self._prefill_fns[key] = fn
         return fn
 
@@ -928,13 +952,13 @@ class ContinuousBatchingEngine:
         from ..parallel.tp_attention import tp_prefill_attn
         attn = tp_prefill_attn(None, cfg_d, bucket)
 
-        def run(params_d, tokens):
+        def draft_prefill(params_d, tokens):
             b, s = tokens.shape
             positions = jnp.broadcast_to(jnp.arange(s)[None], (b, s))
             _, (k_all, v_all) = models.serving_prefill(
                 cfg_d, params_d, tokens, positions, attn=attn)
             return k_all[:, 0], v_all[:, 0]              # squeeze batch
-        fn = jax.jit(run)
+        fn = jax.jit(draft_prefill)
         self._spec_fns[key] = fn
         return fn
 
@@ -962,13 +986,13 @@ class ContinuousBatchingEngine:
         self._note_compile("draft", ("chunk", bucket, window))
         cfg_d = self.cfg_d
 
-        def run(params_d, pool_d, tokens, start, true_len, table):
+        def draft_chunk(params_d, pool_d, tokens, start, true_len, table):
             _, pool_d = chunk_prefill_paged(
                 cfg_d, params_d, tokens, start, true_len, pool_d, table,
                 window)
             return pool_d
         donate = (1,) if jax.default_backend() != "cpu" else ()
-        fn = jax.jit(run, donate_argnums=donate)
+        fn = jax.jit(draft_chunk, donate_argnums=donate)
         self._spec_fns[key] = fn
         return fn
 
@@ -1007,7 +1031,7 @@ class ContinuousBatchingEngine:
                                               impl=cfg_d.attention_impl,
                                               quantized=quantized)
 
-        def run(params_d, pool_d, tables, pos, cur):
+        def spec_draft(params_d, pool_d, tables, pos, cur):
             def step(carry, _):
                 pool_d, tok, p = carry
                 logits, pool_d = decode_step_paged(
@@ -1026,7 +1050,7 @@ class ContinuousBatchingEngine:
             # to come back resharded, silently multiplying KV memory.
             kw["out_shardings"] = (self._replicated,
                                    self._pool_shardings_d)
-        fn = jax.jit(run, donate_argnums=donate, **kw)
+        fn = jax.jit(spec_draft, donate_argnums=donate, **kw)
         self._spec_fns[key] = fn
         return fn
 
@@ -1054,8 +1078,8 @@ class ContinuousBatchingEngine:
                 self.mesh, cfg,
                 quantized=self.tier.kv_quantize == "int8")
 
-        def run(params, pool, tables, pos, cur, drafted, gammas, temps,
-                rng):
+        def spec_verify(params, pool, tables, pos, cur, drafted, gammas,
+                        temps, rng):
             chunk = jnp.concatenate([cur[:, None], drafted], axis=1)
             logits, pool = verify_step_paged(cfg, params, chunk, pos,
                                              pool, tables, attn=attn)
@@ -1082,7 +1106,7 @@ class ContinuousBatchingEngine:
         if self._pool_shardings is not None:
             kw["out_shardings"] = (self._replicated, self._replicated,
                                    self._pool_shardings)
-        fn = jax.jit(run, donate_argnums=donate, **kw)
+        fn = jax.jit(spec_verify, donate_argnums=donate, **kw)
         self._spec_fns[key] = fn
         return fn
 
@@ -1352,9 +1376,14 @@ class ContinuousBatchingEngine:
         # whether TTFT went to WAITING for the scheduler or to
         # PREFILLING the prompt (chunked prefills can spend many ticks
         # there while decode keeps streaming).
-        wait_ms = round((time.perf_counter() - req.t_submit) * 1000.0, 3)
+        now = time.perf_counter()
+        wait_ms = round((now - req.t_submit) * 1000.0, 3)
+        if req.t_lane_blocked is not None:
+            req.lane_wait_ms += (now - req.t_lane_blocked) * 1000.0
+            req.t_lane_blocked = None
         obs_spans.annotate(req.trace, queue_wait_ms=wait_ms,
-                           admission_wait_ms=wait_ms)
+                           admission_wait_ms=wait_ms,
+                           lane_wait_ms=round(req.lane_wait_ms, 3))
         with self.phases.phase("tokenize"):
             ids, bucket = prepare_prompt(self.tokenizer, req.history,
                                          self.tier.prefill_buckets,
@@ -2463,6 +2492,175 @@ class ContinuousBatchingEngine:
             except Exception:
                 pass
 
+    def _plan_and_grow(self, active: List[int]):
+        """The host work a tick needs before its uploads: the
+        speculative plan and lazy KV growth (with the preemptions and
+        COW copies either may force).  Returns the surviving active
+        slots and the round's γ bucket (None = plain tick)."""
+        # Speculative plan first (ISSUE 15): the round's γ bucket
+        # decides how many positions this tick writes, so growth must
+        # cover the chunk, not just the plain tick's T steps.
+        # Re-planned after growth — a preemption may have evicted the
+        # very slot that set the bucket.
+        spec_gb = self._spec_plan(active)
+        # Lazy KV growth (+ preemption under starvation) BEFORE the
+        # tick: every surviving slot's table covers the positions this
+        # tick writes.
+        self._ensure_growth(active, spec_gb=spec_gb)
+        active = [ix for ix, s in enumerate(self._slots) if s is not None]
+        if spec_gb is not None:
+            spec_gb = self._spec_plan(active)
+            if spec_gb is not None:
+                # Rollback contract guard (PR 10): every block the
+                # round will write — or a rejection will abandon — must
+                # be slot-private before the first draft write lands.
+                # Runs OUTSIDE the tick's try, same discipline as growth
+                # (whose preemption behavior it shares): a COW failure
+                # in here must never reach the tick handler that fails
+                # a pre-guard active list.
+                self._ensure_spec_private(active, spec_gb)
+                active = [ix for ix, s in enumerate(self._slots)
+                          if s is not None]
+                spec_gb = self._spec_plan(active)
+            if spec_gb is None and active:
+                # Growth (or the COW guard) preempted every speculating
+                # slot: the tick falls back to the PLAIN T-step path,
+                # but the survivors were only grown for their own γ+1
+                # chunk rows (1 position for non-spec slots).  Re-grow
+                # for the plain span — a plain tick over under-grown
+                # tables would scatter real positions' K/V into the
+                # trash block and silently corrupt every later read.
+                self._ensure_growth(active, spec_gb=None)
+                active = [ix for ix, s in enumerate(self._slots)
+                          if s is not None]
+        return active, spec_gb
+
+    def _tick_sinks(self, kind: str, window: int):
+        """(impl, tick histogram child, tick counter child) for one
+        (dispatch kind, window rung): the measured table's choice and
+        the metric children are resolved once per rung, not per tick —
+        which kernel actually serves decode must be readable off
+        /metrics, not guessed.  No injection path on the engine (same
+        pattern as the preemption counter): the process-global
+        registry.  A registry failure leaves the children None and the
+        tick unobserved, never failed."""
+        sinks = self._tick_sink_cache.get((kind, window))
+        if sinks is None:
+            from ..ops import attention as attn_ops
+            impl = attn_ops._choose(self.cfg.attention_impl, kind, window)
+            try:
+                from ..obs import get_observability
+                m = get_observability().m
+                sinks = (impl, m.decode_tick_ms.labels(self.tier.name),
+                         m.decode_ticks.labels(self.tier.name, kind, impl))
+            except Exception:
+                sinks = (impl, None, None)
+            self._tick_sink_cache[(kind, window)] = sinks
+        return sinks
+
+    def _account_tick(self, active: List[int], tick_ms: float, wb: int,
+                      spec_gb: Optional[int]) -> None:
+        """The bookkeeping between a tick's fetch and its emit: the
+        tick ring, per-request cost attribution, the tick's two
+        metrics, and the COUNT the roofline estimate is later computed
+        from (``_tick_work_estimate``, when ``/stats`` asks — the
+        estimate itself is off the tick since ISSUE 26)."""
+        window = wb * self.paged.block_size
+        kind = self._tick_kind_spec if spec_gb is not None \
+            else self._tick_kind
+        self.tick_ms.append(tick_ms)
+        self.phases.add_time("decode", tick_ms / 1000.0)
+        if self.profiler.enabled:
+            # Per-request cost attribution (ISSUE 11): the tick's
+            # device time divides evenly across the slots it served
+            # (one fused call decodes them together — an even split is
+            # the honest division of a shared program), and each slot
+            # bills blocks-held × 1 tick of KV residency, shared prefix
+            # blocks at 1/refcount each (PR 10's dedup lowers the
+            # bill).  Sums are conserved by construction: per tick the
+            # shares add back up to tick_ms (tests pin 5%).
+            share = tick_ms / len(active)
+            for ix in active:
+                slot = self._slots[ix]
+                trace = slot.request.trace
+                if trace is None:
+                    continue     # direct engine use: unbilled
+                kv_ticks = self._kv_weights.get(ix)
+                if kv_ticks is None:
+                    kv_ticks = 0.0
+                    for r in self.allocator.refcounts(slot.blocks):
+                        kv_ticks += 1.0 / (r if r > 0 else 1)
+                    self._kv_weights[ix] = kv_ticks
+                obs_spans.charge(trace, share, kv_ticks)
+        impl, tick_hist, tick_counter = self._tick_sinks(kind, window)
+        if tick_hist is not None:
+            tick_hist.observe(tick_ms)
+            tick_counter.inc()
+        # Roofline work, counted not computed: one entry per (kind,
+        # window, slots served, γ bucket) with its ticks and the sum of
+        # the per-tick mean KV span.  The XLA paths read the whole
+        # window; frontier-clamped Pallas kernels stream
+        # ceil((pos+1)/bs) blocks of each row, taken mid-tick.
+        if impl == "pallas":
+            mid = (spec_gb if spec_gb is not None
+                   else self.steps_per_tick) // 2
+            bs = self.paged.block_size
+            kv_ctx = float(np.minimum(
+                window, ((self._pos[active] + mid) // bs + 1) * bs).mean())
+        else:
+            kv_ctx = float(window)
+        key = (kind, window, len(active), spec_gb)
+        acc = self._tick_work.get(key)
+        if acc is None:
+            acc = self._tick_work[key] = [0, 0.0]
+        acc[0] += 1
+        acc[1] += kv_ctx
+
+    def _tick_work_estimate(self) -> Dict[str, Dict[str, float]]:
+        """FLOPs / HBM bytes / tokens of every decode tick so far, from
+        the per-shape tick counts (``PhaseTimer.lazy_work``: read by
+        ``/stats``' ``work`` block, never on the tick).
+        ``decode_work`` is linear in the KV span, so ticks × the mean
+        span gives the same sums the per-tick calls gave."""
+        from ..utils import roofline
+        for _ in range(3):
+            try:
+                counts = list(self._tick_work.items())
+                break
+            except RuntimeError:       # resized by the scheduler mid-copy
+                continue
+        else:
+            counts = []
+        total: Dict[str, float] = {}
+        kvq = self.tier.kv_quantize
+        for (_kind, window, batch, spec_gb), (ticks, kv_sum) in counts:
+            if not ticks:
+                continue
+            kv_ctx = kv_sum / ticks
+            if spec_gb is None:
+                parts = [roofline.decode_work(
+                    self.cfg, self.steps_per_tick, window, batch=batch,
+                    wbytes=self._wbytes, kv_quantize=kvq, kv_ctx=kv_ctx)]
+            else:
+                # Roofline split, sequential-engine style: the draft
+                # pays γ+1 sequential small-model steps; the target
+                # verify is ONE step whose γ+1 query rows share a
+                # single KV read per slot (kv_batch charges B KV
+                # streams, not B·(γ+1)).
+                parts = [
+                    roofline.decode_work(
+                        self.cfg_d, spec_gb + 1, window, batch=batch,
+                        wbytes=self._wbytes_d, kv_quantize=kvq,
+                        kv_ctx=kv_ctx),
+                    roofline.decode_work(
+                        self.cfg, 1, window, batch=(spec_gb + 1) * batch,
+                        wbytes=self._wbytes, kv_quantize=kvq,
+                        kv_batch=batch, kv_ctx=kv_ctx)]
+            for part in parts:
+                for k, v in part.items():
+                    total[k] = total.get(k, 0.0) + v * ticks
+        return {"decode": total} if total else {}
+
     # The scheduler thread + fused decode tick: THE hot path.  The
     # transfer lint walks everything reachable from here, project-wide;
     # every device sync/round-trip below either moved to a tick boundary
@@ -2506,8 +2704,12 @@ class ContinuousBatchingEngine:
                     with self.profiler.phase("admit"):
                         admitted = self._admit(req, ix)
                     if not admitted:
-                        # No KV blocks yet: back to the scheduler HEAD so
-                        # the starved elder re-admits before newer work.
+                        # No KV blocks yet, or the prefill lane is busy:
+                        # back to the scheduler HEAD so the elder
+                        # re-admits before newer work.
+                        if (req.needs_chunk and self._prefill is not None
+                                and req.t_lane_blocked is None):
+                            req.t_lane_blocked = time.perf_counter()
                         self._head.appendleft(req)
                         break
                     admitted_any = True
@@ -2521,47 +2723,11 @@ class ContinuousBatchingEngine:
             active = [ix for ix, s in enumerate(self._slots) if s is not None]
             spec_gb = None
             if active:
-                # Speculative plan first (ISSUE 15): the round's γ
-                # bucket decides how many positions this tick writes,
-                # so growth must cover the chunk, not just the plain
-                # tick's T steps.  Re-planned after growth — a
-                # preemption may have evicted the very slot that set
-                # the bucket.
-                spec_gb = self._spec_plan(active)
-                # Lazy KV growth (+ preemption under starvation) BEFORE
-                # the tick: every surviving slot's table covers the
-                # positions this tick writes.
-                self._ensure_growth(active, spec_gb=spec_gb)
-                active = [ix for ix, s in enumerate(self._slots)
-                          if s is not None]
-                if spec_gb is not None:
-                    spec_gb = self._spec_plan(active)
-                    if spec_gb is not None:
-                        # Rollback contract guard (PR 10): every block
-                        # the round will write — or a rejection will
-                        # abandon — must be slot-private before the
-                        # first draft write lands.  Runs OUTSIDE the
-                        # tick's try, same discipline as growth (whose
-                        # preemption behavior it shares): a COW failure
-                        # in here must never reach the tick handler
-                        # that fails a pre-guard active list.
-                        self._ensure_spec_private(active, spec_gb)
-                        active = [ix for ix, s in enumerate(self._slots)
-                                  if s is not None]
-                        spec_gb = self._spec_plan(active)
-                    if spec_gb is None and active:
-                        # Growth (or the COW guard) preempted every
-                        # speculating slot: the tick falls back to the
-                        # PLAIN T-step path, but the survivors were
-                        # only grown for their own γ+1 chunk rows (1
-                        # position for non-spec slots).  Re-grow for
-                        # the plain span — a plain tick over
-                        # under-grown tables would scatter real
-                        # positions' K/V into the trash block and
-                        # silently corrupt every later read.
-                        self._ensure_growth(active, spec_gb=None)
-                        active = [ix for ix, s in enumerate(self._slots)
-                                  if s is not None]
+                # Host work before the launch, with the device idle:
+                # the first half of the tick's ``prepare`` phase (the
+                # second is the rng split and the uploads below).
+                with self.profiler.phase("prepare"):
+                    active, spec_gb = self._plan_and_grow(active)
             if not active:
                 if self._prefill is not None:
                     # No decoding slots: the whole tick is prefill — a
@@ -2582,7 +2748,8 @@ class ContinuousBatchingEngine:
                     # when pool pressure makes the timeline interesting.
                     self.profiler.commit(0)
                     if not progressed:
-                        self._wake.wait(timeout=0.05)
+                        with self.profiler.idle_wait():
+                            self._wake.wait(timeout=0.05)
                         self._wake.clear()
                     self._progress_t = time.monotonic()
                 elif not admitted_any:
@@ -2592,7 +2759,8 @@ class ContinuousBatchingEngine:
                     # before sleeping, for the same coverage reason.
                     self._progress_t = time.monotonic()
                     self.profiler.commit(0)
-                    self._wake.wait(timeout=0.05)
+                    with self.profiler.idle_wait():
+                        self._wake.wait(timeout=0.05)
                     self._wake.clear()
                 else:
                     # Admitted-and-already-finished pass: no wait ran.
@@ -2600,37 +2768,51 @@ class ContinuousBatchingEngine:
                 continue
 
             try:
-                self._rng, rng = jax.random.split(self._rng)
                 spec_tick = spec_gb is not None
-                if self.ragged:
-                    # Ragged fused tick: the FULL tables go to one
-                    # attention.ragged_decode call with true per-slot
-                    # lengths — shape-stable, so exactly ONE compiled
-                    # decode program serves the engine's life, and the
-                    # upload is cached until a table row changes.
-                    wb = self.paged.blocks_per_slot
-                    if self._tables_dev is None:
-                        with self.profiler.phase("table_upload"):
-                            self._tables_dev = jnp.asarray(self._tables)
-                    tables_arg = self._tables_dev
-                else:
-                    # Dense windowed tick: bound the per-step pool gather
-                    # by a bucketed high-water mark over active slots
-                    # (positions written this tick stay < window); jit
-                    # retraces per distinct width, one compile per bucket
-                    # crossed as conversations grow.
-                    w_need = int(max(self._pos[ix] for ix in active)) \
-                        + self.steps_per_tick
-                    wb = self._suffix_window(w_need) \
-                        // self.paged.block_size
-                    tables_arg = self._tables_dev_w.get(wb)
-                    if tables_arg is None:
-                        # One upload per (table-change, rung), not one
-                        # per tick — same policy as the ragged cache.
-                        with self.profiler.phase("table_upload"):
-                            # dllm-lint: disable=retrace-dynamic-shape -- bounded by design: wb only takes values from the validated bucket ladder, so this is the dense rung-ladder program family PR 6 documents (ragged mode removes it); the cache above bounds the UPLOADS to one per table change
-                            tables_arg = jnp.asarray(self._tables[:, :wb])
-                        self._tables_dev_w[wb] = tables_arg
+                with self.profiler.phase("prepare"):
+                    self._rng, rng = jax.random.split(self._rng)
+                    if self.ragged:
+                        # Ragged fused tick: the FULL tables go to one
+                        # attention.ragged_decode call with true
+                        # per-slot lengths — shape-stable, so exactly
+                        # ONE compiled decode program serves the
+                        # engine's life, and the upload is cached until
+                        # a table row changes.
+                        wb = self.paged.blocks_per_slot
+                        if self._tables_dev is None:
+                            with self.profiler.phase("table_upload"):
+                                self._tables_dev = jnp.asarray(self._tables)
+                        tables_arg = self._tables_dev
+                    else:
+                        # Dense windowed tick: bound the per-step pool
+                        # gather by a bucketed high-water mark over
+                        # active slots (positions written this tick stay
+                        # < window); jit retraces per distinct width,
+                        # one compile per bucket crossed as
+                        # conversations grow.
+                        w_need = int(max(self._pos[ix] for ix in active)) \
+                            + self.steps_per_tick
+                        wb = self._suffix_window(w_need) \
+                            // self.paged.block_size
+                        tables_arg = self._tables_dev_w.get(wb)
+                        if tables_arg is None:
+                            # One upload per (table-change, rung), not
+                            # one per tick — same policy as the ragged
+                            # cache.
+                            with self.profiler.phase("table_upload"):
+                                # dllm-lint: disable=retrace-dynamic-shape -- bounded by design: wb only takes values from the validated bucket ladder, so this is the dense rung-ladder program family PR 6 documents (ragged mode removes it); the cache above bounds the UPLOADS to one per table change
+                                tables_arg = jnp.asarray(self._tables[:, :wb])
+                            self._tables_dev_w[wb] = tables_arg
+                    pos_dev = jnp.asarray(self._pos)
+                    cur_dev = jnp.asarray(self._cur)
+                    temps_dev = jnp.asarray(self._temps)
+                    if spec_tick:
+                        gammas = np.zeros(self.paged.max_slots, np.int32)
+                        for ix in active:
+                            slot = self._slots[ix]
+                            if slot is not None and slot.spec:
+                                gammas[ix] = min(slot.gamma, spec_gb)
+                        gammas_dev = jnp.asarray(gammas)
                 t_tick = time.perf_counter()
                 if spec_tick:
                     # One speculative round: γ_bucket drafts per slot in
@@ -2641,122 +2823,36 @@ class ContinuousBatchingEngine:
                     # wall, the verify phase carries the device wait
                     # (DESIGN.md "Batched speculation" documents the
                     # attribution).
-                    gammas = np.zeros(self.paged.max_slots, np.int32)
-                    for ix in active:
-                        slot = self._slots[ix]
-                        if slot is not None and slot.spec:
-                            gammas[ix] = min(slot.gamma, spec_gb)
-                    pos_dev = jnp.asarray(self._pos)
-                    cur_dev = jnp.asarray(self._cur)
-                    with self.phases.phase("decode"), \
-                            self.profiler.phase("draft"):
+                    with self.profiler.phase("draft"):
                         drafted, self.pool_d = self._spec_draft_fn(
                             spec_gb)(self.params_d, self.pool_d,
                                      tables_arg, pos_dev, cur_dev)
-                    with self.phases.phase("decode"), \
-                            self.profiler.phase("verify"):
+                    with self.profiler.phase("verify"):
                         out, n_acc, self.pool = self._spec_verify_fn(
                             spec_gb)(self.params, self.pool, tables_arg,
                                      pos_dev, cur_dev, drafted,
-                                     jnp.asarray(gammas),
-                                     jnp.asarray(self._temps), rng)
+                                     gammas_dev, temps_dev, rng)
                         out, n_acc = _fetch_tick((out, n_acc))
                 else:
                     self._note_compile("decode", (wb, self._tp_degree()))
-                    with self.phases.phase("decode"), \
-                            self.profiler.phase("decode"):
-                        toks, self.pool = self._decode_step()(
-                            self.params, self.pool, tables_arg,
-                            jnp.asarray(self._pos), jnp.asarray(self._cur),
-                            jnp.asarray(self._temps), rng)
-                        toks = _fetch_tick(toks)               # [T, B]
+                    # ``decode`` is the tick as the device sees it; its
+                    # children split it into the launch (host work, the
+                    # device idle unless the last program still runs)
+                    # and the one sanctioned sync.
+                    with self.profiler.phase("decode"):
+                        with self.profiler.phase("dispatch"):
+                            toks, self.pool = self._decode_step()(
+                                self.params, self.pool, tables_arg,
+                                pos_dev, cur_dev, temps_dev, rng)
+                        with self.profiler.phase("fetch"):
+                            toks = _fetch_tick(toks)           # [T, B]
                 tick_ms = (time.perf_counter() - t_tick) * 1000.0
-                from ..utils import roofline
-                from ..ops import attention as attn_ops
-                window = wb * self.paged.block_size
-                q8 = self.tier.kv_quantize == "int8"
-                if spec_tick:
-                    kind = "ragged_verify_q8" if q8 else "ragged_verify"
-                elif self.ragged:
-                    kind = "ragged_decode_q8" if q8 else "ragged_decode"
-                else:
-                    kind = "paged_decode_q8" if q8 else "paged_decode"
-                self.tick_ms.append(tick_ms)
-                if self.profiler.enabled:
-                    # Per-request cost attribution (ISSUE 11): the
-                    # tick's device time divides evenly across the slots
-                    # it served (one fused call decodes them together —
-                    # an even split is the honest division of a shared
-                    # program), and each slot bills blocks-held × 1 tick
-                    # of KV residency, shared prefix blocks at
-                    # 1/refcount each (PR 10's dedup lowers the bill).
-                    # Sums are conserved by construction: per tick the
-                    # shares add back up to tick_ms (tests pin 5%).
-                    share = tick_ms / len(active)
-                    for ix in active:
-                        slot = self._slots[ix]
-                        trace = slot.request.trace
-                        if trace is None:
-                            continue     # direct engine use: unbilled
-                        kv_ticks = self._kv_weights.get(ix)
-                        if kv_ticks is None:
-                            kv_ticks = 0.0
-                            for r in self.allocator.refcounts(
-                                    slot.blocks):
-                                kv_ticks += 1.0 / (r if r > 0 else 1)
-                            self._kv_weights[ix] = kv_ticks
-                        obs_spans.charge(trace, share, kv_ticks)
-                try:
-                    # No injection path on the engine (same pattern as
-                    # the preemption counter): the process-global
-                    # registry — which kernel actually serves decode must
-                    # be readable off /metrics, not guessed.
-                    from ..obs import get_observability
-                    m = get_observability().m
-                    m.decode_tick_ms.labels(self.tier.name).observe(tick_ms)
-                    m.decode_ticks.labels(
-                        self.tier.name, kind,
-                        attn_ops._choose(self.cfg.attention_impl, kind,
-                                         window)).inc()
-                except Exception:
-                    pass
-                if spec_tick:
-                    # Roofline split, sequential-engine style: the draft
-                    # pays γ+1 sequential small-model steps; the target
-                    # verify is ONE step whose γ+1 query rows share a
-                    # single KV read per slot (kv_batch charges B KV
-                    # streams, not B·(γ+1)).
-                    kv_ctx = attn_ops.decode_kv_span(
-                        kind, window,
-                        [self._pos[ix] + spec_gb // 2 for ix in active],
-                        impl=self.cfg.attention_impl,
-                        block=self.paged.block_size)
-                    self.phases.add_work("decode", **roofline.decode_work(
-                        self.cfg_d, spec_gb + 1, window,
-                        batch=len(active), wbytes=self._wbytes_d,
-                        kv_quantize=self.tier.kv_quantize, kv_ctx=kv_ctx))
-                    self.phases.add_work("decode", **roofline.decode_work(
-                        self.cfg, 1, window,
-                        batch=(spec_gb + 1) * len(active),
-                        wbytes=self._wbytes,
-                        kv_quantize=self.tier.kv_quantize,
-                        kv_batch=len(active), kv_ctx=kv_ctx))
-                else:
-                    # Mid-tick per-row positions (each row advances
-                    # steps_per_tick this tick): frontier-clamped Pallas
-                    # paged kernels stream ceil((pos+1)/bs) blocks, not
-                    # the window.
-                    mid = self.steps_per_tick // 2
-                    self.phases.add_work("decode", **roofline.decode_work(
-                        self.cfg, self.steps_per_tick,
-                        window, batch=len(active),
-                        wbytes=self._wbytes,
-                        kv_quantize=self.tier.kv_quantize,
-                        kv_ctx=attn_ops.decode_kv_span(
-                            kind, window,
-                            [self._pos[ix] + mid for ix in active],
-                            impl=self.cfg.attention_impl,
-                            block=self.paged.block_size)))
+                # Everything between the fetch and the emit, with the
+                # device idle: ``account``.  What it holds is priced in
+                # PERF.md ("what tracing costs") — keep it to dict
+                # lookups and float adds.
+                with self.profiler.phase("account"):
+                    self._account_tick(active, tick_ms, wb, spec_gb)
             except BaseException as exc:
                 # A dead tick must not become a dead scheduler: fail the
                 # in-flight requests and keep serving new ones.
@@ -2937,6 +3033,7 @@ class ContinuousBatchingEngine:
             # The chunk bookmark belongs to the dead engine's prefill
             # lane; this engine's admission re-derives it.
             req.needs_chunk = False
+            req.t_lane_blocked = None
             self._queue.put(req)
             n += 1
         if n:

@@ -343,28 +343,31 @@ def chunk_prefill_paged(
         # Scatter the chunk's K/V to its (head, block, offset) cells, then
         # attend the table window (Pallas: in-kernel block walk; XLA:
         # gather-then-attend).
-        k_rows = jnp.swapaxes(k[0], 0, 1)              # [nkv, S_c, d]
-        v_rows = jnp.swapaxes(v[0], 0, 1)
-        if quantized:
-            k_rows, k_sc = quantize_kv_rows(k_rows)
-            v_rows, v_sc = quantize_kv_rows(v_rows)
-            ks_pool = ks_pool.at[:, blk, off].set(k_sc)
-            vs_pool = vs_pool.at[:, blk, off].set(v_sc)
-        k_pool = k_pool.at[:, blk, off].set(k_rows)
-        v_pool = v_pool.at[:, blk, off].set(v_rows)
-        attn = attention.paged_chunk(q, k_pool, v_pool, table, start, q_pos,
-                                     window, impl=cfg.attention_impl,
-                                     k_scale=ks_pool, v_scale=vs_pool)
+        with jax.named_scope("kv_write"):
+            k_rows = jnp.swapaxes(k[0], 0, 1)          # [nkv, S_c, d]
+            v_rows = jnp.swapaxes(v[0], 0, 1)
+            if quantized:
+                k_rows, k_sc = quantize_kv_rows(k_rows)
+                v_rows, v_sc = quantize_kv_rows(v_rows)
+                ks_pool = ks_pool.at[:, blk, off].set(k_sc)
+                vs_pool = vs_pool.at[:, blk, off].set(v_sc)
+            k_pool = k_pool.at[:, blk, off].set(k_rows)
+            v_pool = v_pool.at[:, blk, off].set(v_rows)
+        with jax.named_scope("attention"):
+            attn = attention.paged_chunk(
+                q, k_pool, v_pool, table, start, q_pos, window,
+                impl=cfg.attention_impl, k_scale=ks_pool, v_scale=vs_pool)
         x = x + quant.matmul(attn.reshape(b, s_c, cfg.num_heads * d),
                              lp["wo"])
-        h_ffn = transformer.rms_norm(x, lp["ln2"], cfg.norm_eps)
-        if cfg.num_experts > 1:
-            from ..models.moe import moe_ffn_train
-            ffn_out, _ = moe_ffn_train(cfg, lp, h_ffn)
-            x = x + ffn_out
-        else:
-            x = x + transformer._swiglu(h_ffn, lp["w_gate"], lp["w_up"],
-                                        lp["w_down"])
+        with jax.named_scope("ffn"):
+            h_ffn = transformer.rms_norm(x, lp["ln2"], cfg.norm_eps)
+            if cfg.num_experts > 1:
+                from ..models.moe import moe_ffn_train
+                ffn_out, _ = moe_ffn_train(cfg, lp, h_ffn)
+                x = x + ffn_out
+            else:
+                x = x + transformer._swiglu(h_ffn, lp["w_gate"],
+                                            lp["w_up"], lp["w_down"])
         if quantized:
             return x, (k_pool, v_pool, ks_pool, vs_pool)
         return x, (k_pool, v_pool)
@@ -450,29 +453,32 @@ def verify_step_paged(
         # Write-before-attend for the whole chunk: [nkv, B, G, d] rows
         # scatter to (head, blk[b, g], off[b, g]) — trash rows collide
         # harmlessly like idle decode slots.
-        k_rows = jnp.moveaxis(k, 2, 0)                 # [nkv, B, G, d]
-        v_rows = jnp.moveaxis(v, 2, 0)
-        if quantized:
-            k_rows, k_sc = quantize_kv_rows(k_rows)
-            v_rows, v_sc = quantize_kv_rows(v_rows)
-            ks_pool = ks_pool.at[:, blk, off].set(k_sc)
-            vs_pool = vs_pool.at[:, blk, off].set(v_sc)
-        k_pool = k_pool.at[:, blk, off].set(k_rows)
-        v_pool = v_pool.at[:, blk, off].set(v_rows)
+        with jax.named_scope("kv_write"):
+            k_rows = jnp.moveaxis(k, 2, 0)             # [nkv, B, G, d]
+            v_rows = jnp.moveaxis(v, 2, 0)
+            if quantized:
+                k_rows, k_sc = quantize_kv_rows(k_rows)
+                v_rows, v_sc = quantize_kv_rows(v_rows)
+                ks_pool = ks_pool.at[:, blk, off].set(k_sc)
+                vs_pool = vs_pool.at[:, blk, off].set(v_sc)
+            k_pool = k_pool.at[:, blk, off].set(k_rows)
+            v_pool = v_pool.at[:, blk, off].set(v_rows)
 
-        attn_out = attn(q, k_pool, v_pool, tables, pos,
-                        ks_pool, vs_pool)              # [B, G, Nq, d]
+        with jax.named_scope("attention"):
+            attn_out = attn(q, k_pool, v_pool, tables, pos,
+                            ks_pool, vs_pool)          # [B, G, Nq, d]
 
         x = x + quant.matmul(
             attn_out.reshape(b, g, cfg.num_heads * d), lp["wo"])
-        h_ffn = transformer.rms_norm(x, lp["ln2"], cfg.norm_eps)
-        if cfg.num_experts > 1:
-            from ..models.moe import moe_ffn_train
-            ffn_out, _ = moe_ffn_train(cfg, lp, h_ffn)
-            x = x + ffn_out
-        else:
-            x = x + transformer._swiglu(h_ffn, lp["w_gate"], lp["w_up"],
-                                        lp["w_down"])
+        with jax.named_scope("ffn"):
+            h_ffn = transformer.rms_norm(x, lp["ln2"], cfg.norm_eps)
+            if cfg.num_experts > 1:
+                from ..models.moe import moe_ffn_train
+                ffn_out, _ = moe_ffn_train(cfg, lp, h_ffn)
+                x = x + ffn_out
+            else:
+                x = x + transformer._swiglu(h_ffn, lp["w_gate"],
+                                            lp["w_up"], lp["w_down"])
         if quantized:
             return x, (k_pool, v_pool, ks_pool, vs_pool)
         return x, (k_pool, v_pool)
@@ -549,30 +555,34 @@ def decode_step_paged(
 
         # Write-before-attend at (head, block, offset); batched scatter —
         # active slots hit distinct blocks, idle ones collide in trash.
-        k_rows = jnp.swapaxes(k, 0, 1)                 # [nkv, B, d]
-        v_rows = jnp.swapaxes(v, 0, 1)
-        if quantized:
-            k_rows, k_sc = quantize_kv_rows(k_rows)
-            v_rows, v_sc = quantize_kv_rows(v_rows)
-            ks_pool = ks_pool.at[:, blk, off].set(k_sc)
-            vs_pool = vs_pool.at[:, blk, off].set(v_sc)
-        k_pool = k_pool.at[:, blk, off].set(k_rows)
-        v_pool = v_pool.at[:, blk, off].set(v_rows)
+        with jax.named_scope("kv_write"):
+            k_rows = jnp.swapaxes(k, 0, 1)             # [nkv, B, d]
+            v_rows = jnp.swapaxes(v, 0, 1)
+            if quantized:
+                k_rows, k_sc = quantize_kv_rows(k_rows)
+                v_rows, v_sc = quantize_kv_rows(v_rows)
+                ks_pool = ks_pool.at[:, blk, off].set(k_sc)
+                vs_pool = vs_pool.at[:, blk, off].set(v_sc)
+            k_pool = k_pool.at[:, blk, off].set(k_rows)
+            v_pool = v_pool.at[:, blk, off].set(v_rows)
 
         # Attend this slot's logical window: position p is
         # (table[p//bs], p%bs).  The Pallas path streams table blocks
         # through VMEM in-kernel; the XLA path gathers them contiguous.
-        attn_out = attn(q, k_pool, v_pool, tables, pos, ks_pool, vs_pool)
+        with jax.named_scope("attention"):
+            attn_out = attn(q, k_pool, v_pool, tables, pos, ks_pool,
+                            vs_pool)
 
         x = x + quant.matmul(attn_out.reshape(b, cfg.num_heads * d),
                              lp["wo"])
-        h_ffn = transformer.rms_norm(x, lp["ln2"], cfg.norm_eps)
-        if cfg.num_experts > 1:
-            from ..models.moe import moe_ffn_decode
-            x = x + moe_ffn_decode(cfg, lp, h_ffn)
-        else:
-            x = x + transformer._swiglu(h_ffn, lp["w_gate"], lp["w_up"],
-                                        lp["w_down"])
+        with jax.named_scope("ffn"):
+            h_ffn = transformer.rms_norm(x, lp["ln2"], cfg.norm_eps)
+            if cfg.num_experts > 1:
+                from ..models.moe import moe_ffn_decode
+                x = x + moe_ffn_decode(cfg, lp, h_ffn)
+            else:
+                x = x + transformer._swiglu(h_ffn, lp["w_gate"],
+                                            lp["w_up"], lp["w_down"])
         if quantized:
             return x, (k_pool, v_pool, ks_pool, vs_pool)
         return x, (k_pool, v_pool)

@@ -309,6 +309,24 @@ METRIC_REGISTRY: Tuple[Tuple[str, str, str, Tuple[str, ...], str], ...] = (
      "Mean time between tokens per request"),
     ("queue_wait_ms", "histogram", "dllm_queue_wait_ms", ("tier",),
      "Submit-to-batch-slot-admission wait in the tier's engine"),
+    ("prefill_wait_ms", "histogram", "dllm_prefill_wait_ms", ("tier",),
+     "Prefill start to first token in the tier's engine (the "
+     "request's prefill_wait_ms annotation): one compiled call for a "
+     "monolithic prefill, every chunk and the decode ticks between "
+     "them for a chunked one — the other half of TTFT beside "
+     "dllm_queue_wait_ms"),
+    ("prefill_lane_wait_ms", "histogram", "dllm_prefill_lane_wait_ms",
+     ("tier",),
+     "Time a request that needs chunked prefill sat at the "
+     "scheduler head because the single prefill lane was busy "
+     "(lane_wait_ms; 0 for a request the lane never held up; "
+     "part of dllm_queue_wait_ms)"),
+    ("first_delta_hold_ms", "histogram", "dllm_first_delta_hold_ms",
+     ("strategy",),
+     "First generated token (the trace's token timeline) to the "
+     "first delta the streaming edge yields: what the turn clipper's "
+     "held-back characters add to a streamed request's visible TTFT; "
+     "one observation per streamed request that yielded a delta"),
     ("request_ms", "histogram", "dllm_request_ms", ("strategy",),
      "End-to-end routed request wall time"),
     ("admission_rejected", "counter", "dllm_admission_rejected_total",
@@ -486,8 +504,17 @@ METRIC_REGISTRY: Tuple[Tuple[str, str, str, Tuple[str, ...], str], ...] = (
     ("tick_phase_p50_g", "gauge", "dllm_tick_phase_p50_ms",
      ("tier", "phase"),
      "p50 per-tick SELF time of one scheduler phase (admit|"
-     "prefill|cow_copy|table_upload|decode|emit|chunk_prefill) "
-     "over the profiler ring's recent tail (sampled)"),
+     "prefill|cow_copy|table_upload|decode|draft|verify|emit|"
+     "chunk_prefill|demote|promote; decode at its full duration, "
+     "its time being all in its dispatch and fetch children) over "
+     "the profiler ring's recent tail (sampled)"),
+    ("tick_phase_ms", "counter", "dllm_tick_phase_ms_total",
+     ("tier", "phase"),
+     "Lifetime SELF time of one scheduler phase in ms (the tick "
+     "profiler's totals, exported when sampled and when scraped; "
+     "self-times partition the scheduler's stamped time, so phases "
+     "add).  Per decode tick: divide a delta by the delta of "
+     "dllm_decode_ticks_total summed over kind and impl"),
     ("profile_coverage_g", "gauge", "dllm_profile_coverage", ("tier",),
      "Fraction of tick wall time covered by stamped phase self-"
      "times (sampled; the bench profile leg pins >= 0.95)"),
@@ -580,8 +607,9 @@ BOUNDED_LABELS: Dict[str, str] = {
     "action": "closed set: rejected|truncated",
     "impl": "closed set: xla|pallas",
     "stage": "closed set: prefill|chunk_prefill|writer|decode",
-    "phase": "closed set: admit|prefill|cow_copy|table_upload|decode|"
-             "emit|chunk_prefill",
+    "phase": "closed set: obs/profiler.py PHASES (admit|prefill|cow_copy|"
+             "prepare|table_upload|decode|dispatch|fetch|draft|verify|"
+             "account|emit|chunk_prefill|demote|promote|idle_wait)",
     "session": "open set: BoundedLabels(cap=256) — 64-char truncation, "
                "257th distinct value collapses to '~overflow'",
     "tenant": "open set: BoundedLabels(cap=256) — 64-char truncation, "

@@ -259,14 +259,21 @@ def _gather_pool_seq(q_dtype, k_pool, v_pool, tables, k_scale, v_scale):
     mechanical, not maintained by hand."""
     b, mb = tables.shape
     nkv, bs, d = k_pool.shape[0], k_pool.shape[2], k_pool.shape[3]
-    # [Nkv, B, MB, bs, D] -> [B, S, Nkv, D]
-    k_seq = k_pool[:, tables].reshape(nkv, b, mb * bs, d).transpose(1, 2, 0, 3)
-    v_seq = v_pool[:, tables].reshape(nkv, b, mb * bs, d).transpose(1, 2, 0, 3)
-    if k_scale is not None:
-        k_sc = k_scale[:, tables].reshape(nkv, b, mb * bs).transpose(1, 2, 0)
-        v_sc = v_scale[:, tables].reshape(nkv, b, mb * bs).transpose(1, 2, 0)
-        k_seq = (k_seq.astype(jnp.float32) * k_sc[..., None]).astype(q_dtype)
-        v_seq = (v_seq.astype(jnp.float32) * v_sc[..., None]).astype(q_dtype)
+    with jax.named_scope("kv_gather"):
+        # [Nkv, B, MB, bs, D] -> [B, S, Nkv, D]
+        k_seq = k_pool[:, tables].reshape(
+            nkv, b, mb * bs, d).transpose(1, 2, 0, 3)
+        v_seq = v_pool[:, tables].reshape(
+            nkv, b, mb * bs, d).transpose(1, 2, 0, 3)
+        if k_scale is not None:
+            k_sc = k_scale[:, tables].reshape(
+                nkv, b, mb * bs).transpose(1, 2, 0)
+            v_sc = v_scale[:, tables].reshape(
+                nkv, b, mb * bs).transpose(1, 2, 0)
+            k_seq = (k_seq.astype(jnp.float32)
+                     * k_sc[..., None]).astype(q_dtype)
+            v_seq = (v_seq.astype(jnp.float32)
+                     * v_sc[..., None]).astype(q_dtype)
     return k_seq, v_seq
 
 
@@ -411,17 +418,20 @@ def paged_chunk(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
         from .pallas_attention import paged_chunk_attention
         return paged_chunk_attention(q, k_pool, v_pool, table, start, window)
     wb = window // bs
-    k_seq = jnp.swapaxes(
-        k_pool[:, table[:wb]].reshape(nkv, window, d), 0, 1)[None]
-    v_seq = jnp.swapaxes(
-        v_pool[:, table[:wb]].reshape(nkv, window, d), 0, 1)[None]
-    if k_scale is not None:
-        k_sc = jnp.swapaxes(
-            k_scale[:, table[:wb]].reshape(nkv, window), 0, 1)[None]
-        v_sc = jnp.swapaxes(
-            v_scale[:, table[:wb]].reshape(nkv, window), 0, 1)[None]
-        k_seq = (k_seq.astype(jnp.float32) * k_sc[..., None]).astype(q.dtype)
-        v_seq = (v_seq.astype(jnp.float32) * v_sc[..., None]).astype(q.dtype)
+    with jax.named_scope("kv_gather"):
+        k_seq = jnp.swapaxes(
+            k_pool[:, table[:wb]].reshape(nkv, window, d), 0, 1)[None]
+        v_seq = jnp.swapaxes(
+            v_pool[:, table[:wb]].reshape(nkv, window, d), 0, 1)[None]
+        if k_scale is not None:
+            k_sc = jnp.swapaxes(
+                k_scale[:, table[:wb]].reshape(nkv, window), 0, 1)[None]
+            v_sc = jnp.swapaxes(
+                v_scale[:, table[:wb]].reshape(nkv, window), 0, 1)[None]
+            k_seq = (k_seq.astype(jnp.float32)
+                     * k_sc[..., None]).astype(q.dtype)
+            v_seq = (v_seq.astype(jnp.float32)
+                     * v_sc[..., None]).astype(q.dtype)
     return chunk_attention(q, k_seq, v_seq, q_pos)
 
 

@@ -319,7 +319,11 @@ def create_app(router: Optional[Router] = None,
         (obs/metrics.py): TTFT/TBT/queue-wait histograms, admission
         rejects, breaker transitions + state, watchdog wedges, cache
         hits, degraded count.  Scrape-friendly twin of GET /stats."""
-        body = state["router"].obs.metrics.render().encode("utf-8")
+        router_ = state["router"]
+        export = getattr(router_, "export_tick_totals", None)
+        if callable(export):
+            export()          # dllm_tick_phase_ms_total as of this scrape
+        body = router_.obs.metrics.render().encode("utf-8")
         return static_response(
             body, "text/plain; version=0.0.4; charset=utf-8")
 
@@ -330,10 +334,24 @@ def create_app(router: Optional[Router] = None,
         nested child slices with self-times, compile/host-sync instants
         stitched in.  Load it in chrome://tracing or ui.perfetto.dev —
         the "why did that tick cost 40 ms" surface.  Empty traceEvents
-        when no profiler is live (DLLM_PROFILE=0, sequential tiers)."""
+        when no profiler is live (DLLM_PROFILE=0, sequential tiers).
+        ``?since=<s>&until=<s>`` (wall-clock seconds, either may be
+        left out) cut the ring to a window after the fact; ``metadata``
+        gives the origin of ``ts`` on ``time.time()`` and
+        ``time.perf_counter()``."""
+        window = {}
+        for key in ("since", "until"):
+            raw = request.args.get(key)
+            if raw is None:
+                continue
+            try:
+                window[key] = float(raw)
+            except ValueError:
+                return jsonify({"error": f"Request failed: '{key}' must "
+                                         f"be a number of seconds"}), 400
         router_ = state["router"]
         fn = getattr(router_, "profiler_trace", None)
-        body = fn() if callable(fn) else {"traceEvents": []}
+        body = fn(**window) if callable(fn) else {"traceEvents": []}
         return jsonify(body)
 
     @app.route("/stats", methods=["GET"])

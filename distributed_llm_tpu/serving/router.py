@@ -180,6 +180,11 @@ class Router:
                 period_s=sample_ms / 1000.0,
                 capacity=env_int("DLLM_OBS_TIMELINE_SAMPLES", 240))
 
+        # What export_tick_totals last saw per (engine label, phase),
+        # so dllm_tick_phase_ms_total survives an engine rebuilt from 0.
+        self._tick_totals_lock = threading.Lock()
+        self._tick_totals_seen: Dict[Tuple[str, str], float] = {}
+
         # Bounded per-(tier, strategy, session) cost ledger (ISSUE 11):
         # the GET /stats-inspectable aggregate of the attribution the
         # _finish_request exit feeds to the dllm_device_time_ms_total /
@@ -482,6 +487,10 @@ class Router:
             if b is not None:
                 st["breaker"] = b.get("state")
             out[name] = st
+        try:
+            self.export_tick_totals()
+        except Exception:
+            pass
         return out
 
     _KV_FETCH = object()      # sentinel: "read kv_stats off the engine"
@@ -559,11 +568,9 @@ class Router:
         prof = getattr(engine, "profiler", None)
         if prof is not None and getattr(prof, "enabled", False):
             try:
-                ps = prof.phase_stats(last=128)
-                st["tick_phases"] = {
-                    name: s.get("p50_ms")
-                    for name, s in ps["phases"].items()}
-                st["profile_coverage"] = ps.get("coverage")
+                ps = prof.sampled_phases(last=128)
+                st["tick_phases"] = ps["tick_phases"]
+                st["profile_coverage"] = ps["coverage"]
             except Exception:
                 pass
         return st
@@ -629,33 +636,62 @@ class Router:
         rows.sort(key=lambda r: r["device_time_ms"], reverse=True)
         return rows
 
-    def profiler_trace(self) -> Dict[str, Any]:
-        """The GET /debug/trace body: every live engine's tick-phase
-        ring + compile/host-sync events rendered as one Chrome-trace/
-        Perfetto JSON document (obs/profiler.chrome_trace).  Advisory
-        ring snapshots — never the lifecycle lock; tiers without a
+    def _live_profilers(self):
+        """(label, TickProfiler) of every live engine that has one on.
+        Advisory reads — never the lifecycle lock; tiers without a
         profiler (remote, sequential, DLLM_PROFILE=0) contribute
         nothing."""
-        from ..obs import profiler as obs_profiler
-        by_tier: Dict[str, Dict[str, Any]] = {}
         for name, tier in self.tiers.items():
             mgr = tier.server_manager
             subs = getattr(mgr, "live_engines", None)
             if callable(subs):
-                # Replicated tier: one synthetic trace thread PER
-                # REPLICA ("nano/r0", "nano/r1", ...) so Perfetto shows
-                # the replicas' tick timelines side by side.
+                # Replicated tier: one label PER REPLICA ("nano/r0",
+                # "nano/r1", ...) so Perfetto shows the replicas' tick
+                # timelines side by side.
                 engines = [(f"{name}/{key}", eng) for key, eng in subs()]
             else:
                 engines = [(name, getattr(mgr, "_engine", None))]
             for label, engine in engines:
                 prof = getattr(engine, "profiler", None)
                 if prof is not None and getattr(prof, "enabled", False):
-                    try:
-                        by_tier[label] = prof.snapshot()
-                    except Exception:
-                        pass
-        return obs_profiler.chrome_trace(by_tier)
+                    yield label, prof
+
+    def profiler_trace(self, since: Optional[float] = None,
+                       until: Optional[float] = None) -> Dict[str, Any]:
+        """The GET /debug/trace body: every live engine's tick-phase
+        ring + compile/host-sync events rendered as one Chrome-trace/
+        Perfetto JSON document (obs/profiler.chrome_trace), cut to
+        [since, until] wall seconds where given."""
+        from ..obs import profiler as obs_profiler
+        by_tier: Dict[str, Dict[str, Any]] = {}
+        for label, prof in self._live_profilers():
+            try:
+                by_tier[label] = prof.snapshot()
+            except Exception:
+                pass
+        return obs_profiler.chrome_trace(by_tier, since=since, until=until)
+
+    def export_tick_totals(self) -> None:
+        """Raise ``dllm_tick_phase_ms_total{tier,phase}`` to the tick
+        profilers' lifetime self-time totals.  Called from the
+        sampler's collect and from ``GET /metrics``, so a scrape reads
+        the totals as of the scrape and nothing is added to the tick
+        path.  The counter never falls: a total below the last one seen
+        under the same label (an engine rebuilt from 0) counts from
+        there again."""
+        fam = self.obs.m.tick_phase_ms
+        with self._tick_totals_lock:
+            for label, prof in self._live_profilers():
+                try:
+                    totals = prof.self_totals()
+                except Exception:
+                    continue
+                for phase, total in totals.items():
+                    seen = self._tick_totals_seen.get((label, phase), 0.0)
+                    delta = total - seen if total >= seen else total
+                    self._tick_totals_seen[(label, phase)] = total
+                    if delta > 0:
+                        fam.labels(label, phase).inc(delta)
 
     def _obs_state_snapshot(self) -> Dict[str, Any]:
         """Cheap serving-state snapshot attached to flight-recorder
@@ -717,9 +753,18 @@ class Router:
             if tbt is not None:
                 m.tbt_ms.labels(strategy).observe(tbt)
             tbt_p95 = trace.tbt_p95_ms()
-        qw = trace.attrs.get("queue_wait_ms")
-        if qw is not None and which:
-            m.queue_wait_ms.labels(which).observe(float(qw))
+        # The engine's own split of TTFT, beside each other: waiting
+        # for a slot (of which lane_wait_ms is the part spent behind a
+        # busy prefill lane), then prefilling.
+        for attr, fam in (("queue_wait_ms", m.queue_wait_ms),
+                          ("prefill_wait_ms", m.prefill_wait_ms),
+                          ("lane_wait_ms", m.prefill_lane_wait_ms)):
+            val = trace.attrs.get(attr)
+            if val is not None and which:
+                fam.labels(which).observe(float(val))
+        hold = trace.attrs.get("first_delta_hold_ms")
+        if hold is not None:
+            m.first_delta_hold_ms.labels(strategy).observe(float(hold))
         # SLO goodput feed — the ONLY sanctioned record_request site
         # (obs_discipline lint): this exit runs exactly once per request
         # on every path of both pipelines, so goodput counts requests,
@@ -1609,6 +1654,16 @@ class Router:
                                gen_tokens=result.gen_tokens)
             self._finish_request(trace, state["device"], ok=ok)
 
+        def on_first_delta() -> None:
+            # The edge's hold-back, measured inside: the first token the
+            # engine generated (token timeline) to the first delta this
+            # stream hands to the SSE layer — the turn clipper's held
+            # characters.  Observed at the exactly-once exit.
+            times = trace.token_times
+            if times:
+                trace.annotate(first_delta_hold_ms=round(
+                    (time.perf_counter() - times[0]) * 1000.0, 3))
+
         def resume_mid_stream(emitted_chars: int, exc: BaseException):
             """Mid-stream failover: the live stream died after emitting
             ``emitted_chars`` chars.  Re-issue the SAME request on the
@@ -1690,7 +1745,8 @@ class Router:
             meta["overflow_truncated"] = True
             meta["overflow_dropped_messages"] = overflow_dropped
         return RoutedStream(state, meta, on_done,
-                            resume=resume_mid_stream)
+                            resume=resume_mid_stream,
+                            on_first_delta=on_first_delta)
 
 
 class RoutedStream:
@@ -1710,11 +1766,12 @@ class RoutedStream:
     error-shaped tail event."""
 
     def __init__(self, state: Dict[str, Any], meta: Dict[str, Any],
-                 on_done, resume=None):
+                 on_done, resume=None, on_first_delta=None):
         self._state = state
         self.meta = meta
         self._on_done = on_done
         self._resume = resume
+        self._on_first_delta = on_first_delta
         self._resumed = False
         self._fired = False
 
@@ -1750,6 +1807,9 @@ class RoutedStream:
                         continue
                 self._fire(False)
                 raise
+            if emitted_chars == 0 and self._on_first_delta is not None:
+                self._on_first_delta()
+                self._on_first_delta = None
             try:
                 yield delta
             except GeneratorExit:

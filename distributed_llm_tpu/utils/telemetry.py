@@ -11,9 +11,10 @@ the same trapezoidal window integration.  The integral is bytes·s (an
 occupancy proxy, NOT millijoules); CSV columns keep the reference schema
 with this documented substitution (SURVEY.md §5.1).
 
-Also here: ``jax.profiler`` capture helpers — the flamegraph-class tooling
-the reference never had — and a phase-timer used by the serving stack to
-attribute time to tokenize/prefill/decode/detokenize.
+Also here: a phase-timer used by the serving stack to attribute time to
+tokenize/prefill/decode/detokenize.  (A ``jax.profiler`` capture needs no
+helper: ``obs/profiler.py`` puts the scheduler's phases into any capture
+as ``dllm.<tier>.<phase>`` annotations.)
 """
 
 from __future__ import annotations
@@ -23,7 +24,7 @@ import threading
 import time
 from collections import defaultdict
 from datetime import datetime
-from typing import Any, Dict, Iterable, List, Optional, Tuple
+from typing import Any, Callable, Dict, Iterable, List, Optional, Tuple
 
 import jax
 
@@ -47,17 +48,6 @@ def device_memory_snapshot() -> List[Dict[str, Any]]:
     return out
 
 
-@contextlib.contextmanager
-def profiler_trace(log_dir: str = "/tmp/dllm_tpu_trace"):
-    """Capture a jax.profiler trace (TensorBoard / xprof readable) around a
-    block — per-op HLO timings on TPU, the flamegraph the reference lacked."""
-    jax.profiler.start_trace(log_dir)
-    try:
-        yield log_dir
-    finally:
-        jax.profiler.stop_trace()
-
-
 class PhaseTimer:
     """Accumulates wall-time per named phase across queries, plus the
     roofline work (FLOPs / HBM bytes / tokens, utils/roofline.py) the
@@ -69,6 +59,12 @@ class PhaseTimer:
         self.counts: Dict[str, int] = defaultdict(int)
         self.work: Dict[str, Dict[str, float]] = defaultdict(
             lambda: defaultdict(float))
+        # Work an engine computes only when asked: a callable giving
+        # {phase: {counter: amount}}, merged in by ``work_summary`` (the
+        # batched engine counts its decode ticks by shape and leaves the
+        # roofline arithmetic off the tick).
+        self.lazy_work: Optional[Callable[[], Dict[str, Dict[str, float]]]] \
+            = None
 
     @contextlib.contextmanager
     def phase(self, name: str):
@@ -78,6 +74,12 @@ class PhaseTimer:
         finally:
             self.totals[name] += time.perf_counter() - t0
             self.counts[name] += 1
+
+    def add_time(self, name: str, seconds: float) -> None:
+        """One occurrence of a phase the caller timed itself (the
+        batched engine's decode tick already holds its own stamps)."""
+        self.totals[name] += seconds
+        self.counts[name] += 1
 
     def add_work(self, name: str, **amounts: float) -> None:
         """Accumulate work counters (flops, hbm_bytes, tokens) for a phase."""
@@ -94,9 +96,15 @@ class PhaseTimer:
 
     def work_summary(self) -> Dict[str, Dict[str, float]]:
         """Per-phase accumulated work joined with its measured seconds."""
+        work = {name: dict(acc) for name, acc in self.work.items() if acc}
+        if self.lazy_work is not None:
+            for name, amounts in self.lazy_work().items():
+                acc = work.setdefault(name, {})
+                for key, val in amounts.items():
+                    acc[key] = acc.get(key, 0.0) + float(val)
         return {name: {**{k: round(v, 2) for k, v in acc.items()},
                        "seconds": round(self.totals.get(name, 0.0), 4)}
-                for name, acc in self.work.items() if acc}
+                for name, acc in work.items()}
 
 
 def engine_stats(engine) -> Dict[str, Any]:

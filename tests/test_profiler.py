@@ -127,7 +127,8 @@ def test_engine_phase_breakdown_covers_tick_wall(profiled_engine):
     eng, _ = profiled_engine
     st = eng.profiler.phase_stats()
     assert st["ticks"] >= 1
-    assert {"admit", "decode", "emit"} <= set(st["phases"])
+    assert {"admit", "prepare", "decode", "dispatch", "fetch", "account",
+            "emit"} <= set(st["phases"])
     for entry in st["phases"].values():
         assert entry["p50_ms"] <= entry["p95_ms"] or entry["n"] == 1
     # Acceptance: stamped phases explain >= 95% of tick wall time.
