@@ -3,7 +3,7 @@
 
 One process, one TPU chip by default:
 
-    python chip_smoke.py            # device, serve, what ran, kernels
+    python chip_smoke.py            # device, serve, what ran, pool, kernels
     python chip_smoke.py --chips 4  # placement + tp=2 vs tp=1, nothing else
 
 Phases print their own lines; any failure exits non-zero at once.  The
@@ -19,9 +19,11 @@ import argparse
 import dataclasses
 import json
 import os
+import re
 import sys
 import time
-from typing import Any, Callable, Dict, List, NamedTuple, Optional, Sequence
+from typing import (Any, Callable, Dict, List, NamedTuple, Optional, Sequence,
+                    Tuple)
 
 # Kernel-comparison tolerances, fixed here before any run.  float32 is
 # the pin tests/test_ragged_parity.py and tests/test_pallas_attention.py
@@ -446,6 +448,133 @@ def phase_what_ran(served: Served) -> Dict[str, Any]:
     return out
 
 
+# =============================================================================
+# the pool's programs
+# =============================================================================
+def pool_programs(engine, pool, windows: Sequence[int],
+                  chunks: Sequence[Tuple[int, int]] = (), cow: bool = False
+                  ) -> Dict[str, Tuple[Any, int]]:
+    """The engine's OWN pool programs, compiled against ``pool`` where it
+    lives — the engine's pool on the attached chip, or the shapes of a
+    pool on a described one (``jax.eval_shape(init_pool)``, as
+    tests/test_tpu_compile.py builds it beside a tiny engine): the decode
+    tick at each table window of ``windows`` (tokens; the ragged tick
+    takes the full span), the chunk-prefill program per (chunk, window)
+    of ``chunks``, ``copy_block``.  Gives ``{label: (compiled, position
+    of the pool argument)}``.  Nothing runs."""
+    import jax
+    import jax.numpy as jnp
+    params = engine.params
+    home = jax.tree.leaves(pool)[0].sharding
+
+    def arg(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=home)
+
+    b, bs = engine.paged.max_slots, engine.paged.block_size
+    mb = engine.paged.blocks_per_slot
+    out = {}
+    for w in windows:
+        wb = mb if engine.ragged else w // bs
+        out[f"decode tick, window {wb * bs}"] = engine._decode_step().lower(
+            params, pool, arg((b, wb)), arg((b,)), arg((b,)),
+            arg((b,), jnp.float32), arg((2,), jnp.uint32)).compile(), 1
+    for c, w in chunks:
+        out[f"chunk prefill ({c}, {w})"] = engine._chunk_prefill_fn(
+            c, w).lower(params, pool, arg((1, c)), arg((1,)), arg((1,)),
+                        arg((mb,)), arg((2,), jnp.uint32),
+                        arg((), jnp.float32)).compile(), 1
+    if cow:
+        out["copy_block"] = engine._cow_copy_fn().lower(
+            pool, arg(()), arg(())).compile(), 0
+    return out
+
+
+_HLO_RESULT = re.compile(r"^\s*(ROOT )?%?[\w.-]+ = (\w+\[[\d,]*\])\S* "
+                         r"([\w-]+)\((.*)$")
+_HLO_TYPES = {"bfloat16": "bf16", "float32": "f32", "int8": "s8"}
+# What may have a pool-shaped result in a program that updates the pool
+# in place: the buffer coming in and going round (a ``while`` and a
+# ``tuple`` are tuple-typed and never match) and the in-place writes.
+_POOL_IN_PLACE = {"parameter", "get-tuple-element", "bitcast", "scatter",
+                  "dynamic-update-slice"}
+
+
+def pool_program_facts(compiled, pool_arg: int, pool) -> Dict[str, Any]:
+    """What says a compiled pool program leaves the pool in place: its
+    temporaries, the aliased bytes beside everything it returns, whether
+    the pool's input and output formats agree, and a count by opcode of
+    the instructions with a pool-shaped result that are neither the
+    buffer going round nor an in-place write (a ``copy``, a stacked
+    ``ys``, a slice fusion; a ``fusion`` is judged by its root)."""
+    import jax
+    fmt_in = compiled.input_formats[0][pool_arg]
+    fmt_out = compiled.output_formats
+    if not isinstance(fmt_out, dict):
+        fmt_out = fmt_out[-1]
+    shapes = {f"{_HLO_TYPES[str(x.dtype)]}[{','.join(map(str, x.shape))}]"
+              for x in jax.tree.leaves(pool)}
+    roots: Dict[str, str] = {}
+    suspects, computation = [], None
+    for line in compiled.as_text().splitlines():
+        if line.endswith("{") and " = " not in line:
+            words = line.split()
+            computation = words[1 if words[0] == "ENTRY" else 0].lstrip("%")
+            continue
+        m = _HLO_RESULT.match(line)
+        if not m or m[2] not in shapes:
+            continue
+        if m[1]:
+            roots[computation] = m[3]
+        if m[3] not in _POOL_IN_PLACE:
+            suspects.append((m[3], m[4]))
+    moves: Dict[str, int] = {}
+    for op, rest in suspects:
+        called = re.search(r"calls=%?([\w.-]+)", rest)
+        if (op == "fusion" and called
+                and roots.get(called[1]) in _POOL_IN_PLACE):
+            continue
+        moves[op] = moves.get(op, 0) + 1
+    mem = compiled.memory_analysis()
+    return {"temp_bytes": mem.temp_size_in_bytes,
+            "alias_bytes": mem.alias_size_in_bytes,
+            "output_bytes": mem.output_size_in_bytes,
+            "formats_match": fmt_in == fmt_out,
+            "pool_sized_moves": moves}
+
+
+def phase_pool_programs(served: Served) -> Dict[str, Any]:
+    """Per tier: the pool's format at rest, and for the decode tick and
+    one chunk program what the chip's compiler made of them — to be set
+    beside the compile for a described v5e of tests/test_tpu_compile.py.
+    On the chip a pool program that does not alias the pool, gives it
+    back in another format than it took it, or moves it (a pool-sized
+    ``copy``, a stacked ``ys``) fails the smoke."""
+    out: Dict[str, Any] = {}
+    for name in served.router.tiers:
+        engine = _engine(served.router, name)
+        at_rest = {k: str(x.format.layout) for k, x in engine.pool.items()}
+        say("pool", f"tier {name}: pool at rest " + json.dumps(at_rest))
+        bs = engine.paged.block_size
+        chunk = engine._reuse_buckets[0]
+        programs = pool_programs(
+            engine, engine.pool, [engine._buckets[0]],
+            [(chunk, max(engine._chunk_windows[0], -(-chunk // bs) * bs))],
+            cow=True)
+        out[name] = {"at_rest": at_rest}
+        for label, (compiled, pool_arg) in programs.items():
+            facts = pool_program_facts(compiled, pool_arg, engine.pool)
+            out[name][label] = facts
+            say("pool", f"tier {name}: {label}: " + json.dumps(facts))
+            check(facts["formats_match"],
+                  f"{name}: {label} returns the pool in another format")
+            if engine._pool_donated:
+                check(facts["output_bytes"] - facts["alias_bytes"] < 1 << 20,
+                      f"{name}: {label} does not alias the pool: {facts}")
+                check(not facts["pool_sized_moves"],
+                      f"{name}: {label} moves the pool: {facts}")
+    return out
+
+
 def phase_drain(served: Served) -> None:
     """router.drain(): the edge answers 503 and every engine stops."""
     out = served.router.drain()
@@ -807,6 +936,7 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
         served = phase_serve(smoke_cluster(), devices=jax.devices()[:1])
         try:
             phase_what_ran(served)
+            phase_pool_programs(served)
         finally:
             phase_drain(served)
         phase_kernels()

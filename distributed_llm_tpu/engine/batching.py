@@ -343,22 +343,33 @@ class ContinuousBatchingEngine:
             params = init(seed=seed)
         from ..ops.quant import maybe_quantize
         self.params = maybe_quantize(params, tier, self.cfg, mesh=mesh)
-        self.pool = self._new_pool(self.cfg, own)
-        self._pool_shardings = None
+        # Where this engine's pools rest — decided once here, applied by
+        # _pool_program to every program that takes or returns a pool.  A
+        # tensor-parallel tier shards the pool on its kv-head axis, so
+        # every scatter/gather in decode_step_paged stays shard-local and
+        # GSPMD's only collectives are the two per-layer matmul
+        # all-reduces (same as the contiguous TP engine); pool-valued jit
+        # outputs are pinned to that sharding — left unconstrained, XLA
+        # may replicate the output pool, which silently multiplies KV
+        # memory by the mesh size.  An unsharded pool lives with the
+        # weights, and off the CPU a pool is donated to its programs.
         self._replicated = None
+        home = own
         if mesh is not None:
-            # Tensor-parallel tier: the pool shards on its kv-head axis, so
-            # every scatter/gather in decode_step_paged stays shard-local
-            # and GSPMD's only collectives are the two per-layer matmul
-            # all-reduces (same as the contiguous TP engine).  Pool-valued
-            # jit outputs are pinned to this sharding (out_shardings) —
-            # left unconstrained, XLA may replicate the output pool, which
-            # silently multiplies KV memory by the mesh size.
             from ..parallel.sharding import kv_pool_shardings, replicated
-            self._pool_shardings = kv_pool_shardings(
+            home = kv_pool_shardings(
                 mesh, quantized=(tier.kv_quantize == "int8"))
             self._replicated = replicated(mesh)
-            self.pool = jax.device_put(self.pool, self._pool_shardings)
+            platform = mesh.devices.flat[0].platform
+        else:
+            first = jax.tree.leaves(self.params)[0]
+            if home is None and isinstance(first, jax.Array):
+                home = first.sharding
+            platform = (next(iter(home.device_set)).platform
+                        if home is not None else jax.default_backend())
+        self._pool_donated = platform != "cpu"
+        self._pool_home = home
+        self.pool = self._new_pool(self.cfg, home)
         self.allocator = BlockAllocator(self.paged.num_blocks)
 
         b, mb = self.paged.max_slots, self.paged.blocks_per_slot
@@ -503,7 +514,7 @@ class ContinuousBatchingEngine:
         # a skewed mix's low-acceptance tenant is visible next to the
         # aggregate ratio.
         self._spec_slot_acc: Dict[int, List[int]] = {}
-        self._pool_shardings_d = None
+        self._pool_home_d = None
         if tier.spec_decode and self._resolve_spec():
             self.spec = True
             dcfg = tier.draft_model()
@@ -519,7 +530,7 @@ class ContinuousBatchingEngine:
                 # draft rounds run through the same shard-mapped ragged
                 # hook as the tick (PR 16).
                 self.params_d = self.params
-                self._pool_shardings_d = self._pool_shardings
+                self._pool_home_d = self._pool_home
             else:
                 init_d = jax.jit(partial(models.init_params, self.cfg_d),
                                  static_argnames=("seed",), out_shardings=own)
@@ -533,13 +544,12 @@ class ContinuousBatchingEngine:
                     # rewind bookkeeping sees one draft pool image.
                     self.params_d = jax.device_put(self.params_d,
                                                    self._replicated)
-                    self._pool_shardings_d = self._replicated
+                    self._pool_home_d = self._replicated
+                else:
+                    self._pool_home_d = self._pool_home
             # Draft pool: same geometry (block count/size) as the target
             # pool so the target's block tables index it directly.
-            self.pool_d = self._new_pool(self.cfg_d, own)
-            if self._pool_shardings_d is not None:
-                self.pool_d = jax.device_put(self.pool_d,
-                                             self._pool_shardings_d)
+            self.pool_d = self._new_pool(self.cfg_d, self._pool_home_d)
             from ..utils import roofline as _roofline
             self._wbytes_d = _roofline.weight_bytes(self.cfg_d,
                                                     tier.quantize)
@@ -614,15 +624,31 @@ class ContinuousBatchingEngine:
         self._tick_kind_spec = "ragged_verify" + q8
         self._tick_sink_cache: Dict[tuple, tuple] = {}
 
-    def _new_pool(self, cfg, own):
-        """A zeroed paged pool for ``cfg`` — allocated on, and committed
-        to, the engine's own device when it has one (``own``)."""
-        if own is None:
-            return init_pool(cfg, self.paged, self.tier.kv_quantize)
-        (device,) = own.device_set
-        with jax.default_device(device):
-            pool = init_pool(cfg, self.paged, self.tier.kv_quantize)
-        return jax.device_put(pool, own)
+    def _new_pool(self, cfg, home):
+        """A zeroed paged pool for ``cfg``, made in place on the engine's
+        device(s) ``home`` (one sharding, or a dict of them under a
+        mesh; None: wherever jax puts it)."""
+        make = partial(init_pool, cfg, self.paged, self.tier.kv_quantize)
+        if home is None:
+            return make()
+        return jax.jit(make, out_shardings=home)()
+
+    def _pool_program(self, fn, pool_arg: int, lead: int = 0,
+                      draft: bool = False):
+        """jit ``fn``, a program that takes a pool as argument
+        ``pool_arg`` and returns it after ``lead`` small outputs.  The
+        one place that knows how a pool is placed: it comes out where it
+        rests (``_pool_home``) and, off the CPU, is donated — so XLA
+        aliases the output to the input and the program updates the one
+        buffer in place."""
+        home = self._pool_home_d if draft else self._pool_home
+        kw = {}
+        if home is not None:
+            kw["out_shardings"] = ((self._replicated,) * lead + (home,)
+                                   if lead else home)
+        if self._pool_donated:
+            kw["donate_argnums"] = (pool_arg,)
+        return jax.jit(fn, **kw)
 
     def _resolve_ragged(self) -> bool:
         """Whether the decode tick runs the ragged fused path.
@@ -861,12 +887,7 @@ class ContinuousBatchingEngine:
                 step, (pool, pos, cur, rng), None, length=steps)
             return toks, pool                      # [T, B]
 
-        donate = (1,) if jax.default_backend() != "cpu" else ()
-        kw = {}
-        if self._pool_shardings is not None:
-            kw["out_shardings"] = (self._replicated, self._pool_shardings)
-        self._decode_fn = jax.jit(decode_tick, donate_argnums=donate,
-                                  **kw)
+        self._decode_fn = self._pool_program(decode_tick, 1, lead=1)
         return self._decode_fn
 
     def _chunk_prefill_fn(self, bucket: int, window: int):
@@ -887,11 +908,7 @@ class ContinuousBatchingEngine:
             first = _sample_batched(logits[None], rng, temp[None])[0]
             return first, pool
 
-        donate = (1,) if jax.default_backend() != "cpu" else ()
-        kw = {}
-        if self._pool_shardings is not None:
-            kw["out_shardings"] = (self._replicated, self._pool_shardings)
-        fn = jax.jit(chunk_prefill, donate_argnums=donate, **kw)
+        fn = self._pool_program(chunk_prefill, 1, lead=1)
         self._prefill_fns[key] = fn
         return fn
 
@@ -900,12 +917,8 @@ class ContinuousBatchingEngine:
         compile per prefill block count."""
         if nb not in self._writer_fns:
             self._note_compile("writer", nb)
-            donate = (0,) if jax.default_backend() != "cpu" else ()
-            kw = {}
-            if self._pool_shardings is not None:
-                kw["out_shardings"] = self._pool_shardings
-            self._writer_fns[nb] = jax.jit(write_prefill_blocks,
-                                           donate_argnums=donate, **kw)
+            self._writer_fns[nb] = self._pool_program(
+                write_prefill_blocks, 0)
         return self._writer_fns[nb]
 
     def _cow_copy_fn(self):
@@ -918,11 +931,7 @@ class ContinuousBatchingEngine:
         if self._cow_fn is None:
             from .paged_kv import copy_block
             self._note_compile("writer", "cow_copy")
-            donate = (0,) if jax.default_backend() != "cpu" else ()
-            kw = {}
-            if self._pool_shardings is not None:
-                kw["out_shardings"] = self._pool_shardings
-            self._cow_fn = jax.jit(copy_block, donate_argnums=donate, **kw)
+            self._cow_fn = self._pool_program(copy_block, 0)
         return self._cow_fn
 
     def _cow_copy_fn_d(self):
@@ -934,8 +943,7 @@ class ContinuousBatchingEngine:
         if self._cow_fn_d is None:
             from .paged_kv import copy_block
             self._note_compile("writer", "cow_copy_draft")
-            donate = (0,) if jax.default_backend() != "cpu" else ()
-            self._cow_fn_d = jax.jit(copy_block, donate_argnums=donate)
+            self._cow_fn_d = self._pool_program(copy_block, 0, draft=True)
         return self._cow_fn_d
 
     def _draft_prefill_fn(self, bucket: int):
@@ -969,9 +977,8 @@ class ContinuousBatchingEngine:
         key = ("draft_writer", nb)
         if key not in self._spec_fns:
             self._note_compile("draft", ("writer", nb))
-            donate = (0,) if jax.default_backend() != "cpu" else ()
-            self._spec_fns[key] = jax.jit(write_prefill_blocks,
-                                          donate_argnums=donate)
+            self._spec_fns[key] = self._pool_program(
+                write_prefill_blocks, 0, draft=True)
         return self._spec_fns[key]
 
     def _draft_chunk_fn(self, bucket: int, window: int):
@@ -991,8 +998,7 @@ class ContinuousBatchingEngine:
                 cfg_d, params_d, tokens, start, true_len, pool_d, table,
                 window)
             return pool_d
-        donate = (1,) if jax.default_backend() != "cpu" else ()
-        fn = jax.jit(draft_chunk, donate_argnums=donate)
+        fn = self._pool_program(draft_chunk, 1, draft=True)
         self._spec_fns[key] = fn
         return fn
 
@@ -1042,15 +1048,11 @@ class ContinuousBatchingEngine:
             (pool_d, _, _), drafted = jax.lax.scan(
                 step, (pool_d, cur, pos), None, length=gb + 1)
             return jnp.swapaxes(drafted, 0, 1)[:, :gb], pool_d   # [B, γ]
-        donate = (1,) if jax.default_backend() != "cpu" else ()
-        kw = {}
-        if self._pool_shardings_d is not None:
-            # Pin the draft pool's placement (sharded for self-draft,
-            # replicated for a small draft) — an unpinned output is free
-            # to come back resharded, silently multiplying KV memory.
-            kw["out_shardings"] = (self._replicated,
-                                   self._pool_shardings_d)
-        fn = jax.jit(spec_draft, donate_argnums=donate, **kw)
+        # The draft pool's placement (sharded for self-draft, replicated
+        # for a small draft) is pinned like the target's — an unpinned
+        # output is free to come back resharded, silently multiplying KV
+        # memory.
+        fn = self._pool_program(spec_draft, 1, lead=1, draft=True)
         self._spec_fns[key] = fn
         return fn
 
@@ -1101,12 +1103,7 @@ class ContinuousBatchingEngine:
                 jnp.take_along_axis(picks, jnp.minimum(idx, n_acc[:, None]),
                                     axis=1))
             return out, n_acc, pool
-        donate = (1,) if jax.default_backend() != "cpu" else ()
-        kw = {}
-        if self._pool_shardings is not None:
-            kw["out_shardings"] = (self._replicated, self._replicated,
-                                   self._pool_shardings)
-        fn = jax.jit(spec_verify, donate_argnums=donate, **kw)
+        fn = self._pool_program(spec_verify, 1, lead=2)
         self._spec_fns[key] = fn
         return fn
 
@@ -1131,11 +1128,7 @@ class ContinuousBatchingEngine:
         fn = self._spill_fns.get("write")
         if fn is None:
             from .paged_kv import scatter_blocks
-            donate = (0,) if jax.default_backend() != "cpu" else ()
-            kw = {}
-            if self._pool_shardings is not None:
-                kw["out_shardings"] = self._pool_shardings
-            fn = jax.jit(scatter_blocks, donate_argnums=donate, **kw)
+            fn = self._pool_program(scatter_blocks, 0)
             self._spill_fns["write"] = fn
         return fn
 
@@ -2004,7 +1997,7 @@ class ContinuousBatchingEngine:
                     return progressed, budget_left
                 pf.blocks.extend(extra)
             lo = pf.promote_done
-            tiles = {name: jnp.asarray(arr[:, :, lo:lo + k])  # dllm-lint: disable=retrace-dynamic-shape -- bounded: k is whole blocks under the per-tick promote budget, so upload widths (and the scatter traces they feed) are capped at promote-budget blocks
+            tiles = {name: jnp.asarray(arr[:, lo:lo + k])  # dllm-lint: disable=retrace-dynamic-shape -- bounded: k is whole blocks under the per-tick promote budget, so upload widths (and the scatter traces they feed) are capped at promote-budget blocks
                      for name, arr in host_tiles.items()}
             with self.profiler.phase("promote"):
                 self._note_compile("spill", ("write", k))

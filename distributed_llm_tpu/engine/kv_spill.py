@@ -77,7 +77,7 @@ class HostEntry:
         self.nbytes = nbytes
         self.state = COPYING
         self.pins = 0
-        # Host tiles in pool layout; promote grants slice [:, :, lo:hi]
+        # Host tiles in pool layout; promote grants slice [:, lo:hi]
         # views off a LOCAL reference (a concurrent invalidation nulls
         # this field — engine/batching.py snapshots it with the state
         # check).

@@ -5,11 +5,26 @@ context per server process (SURVEY.md §2.1); a continuous-batching engine
 needs many sequences of very different lengths resident at once, so the
 TPU-native design is vLLM-style paging adapted to XLA's static shapes:
 
-- One HBM **pool** per tier, ``[L, N_kv, num_blocks, block_size, D]``.
-  Head-major: each (head, block) is a contiguous ``[block_size, D]`` tile —
-  the TPU-native (sublane, lane) shape — so the Pallas paged-attention
-  kernel DMAs exactly the blocks it attends, and a 'tp' mesh axis can
-  shard the pool on the head dim like the contiguous cache.
+- One HBM **pool** per tier, ``[L, num_blocks, block_size, N_kv * D]``:
+  token-major, a block a contiguous run of tokens and a token its kv
+  heads side by side on ONE axis.  That is the order the step programs
+  below use inside their layer loop (a row write and the table gather
+  both move whole tokens), and with the heads merged the last axis fills
+  the chip's 128 lanes at any head_dim — so the device's DEFAULT layout
+  for the array is that order, unpadded, on both sides of every program,
+  and no program transposes the pool on its way in or out.  (Head-major
+  ``[L, N_kv, NB, bs, D]`` at head_dim 64 rested block-axis-minor and
+  was transposed, whole, twice a program; a layout pinned with
+  ``jax.experimental.layout`` does not survive the persistent compile
+  cache — PERF.md §6, PR 27.)  A 'tp' mesh axis shards the merged axis
+  (heads are contiguous runs of it), and a Pallas kernel is handed a
+  layer's head-major ``[N_kv, NB, bs, D]`` view
+  (``ops.attention._layer_views``).
+- The pool is ONE buffer that every step program updates in place: the
+  layer loop CARRIES it whole (``_scan_layers``) and each layer writes
+  its rows at ``(layer, block, offset)`` and gathers its window from the
+  whole pool at that layer — never sliced out per layer and stacked
+  back, which copied the pool every step (ROADMAP S0).
 - A host-side **BlockAllocator** (free list) hands fixed-size blocks to
   slots; block 0 is reserved as a trash block that idle batch slots write
   into, so the batched decode step needs no host-side compaction.
@@ -40,8 +55,8 @@ from ..config import ModelConfig
 from ..models import transformer
 from ..ops import attention, quant
 
-KVPool = Dict[str, jax.Array]    # {"k","v": [L, N_kv, NB, bs, D]}
-# int8 pools add {"ks","vs": [L, N_kv, NB, bs]} per-row dequant scales.
+KVPool = Dict[str, jax.Array]    # {"k","v": [L, NB, bs, N_kv * D]}
+# int8 pools add {"ks","vs": [L, NB, bs, N_kv]} per-row dequant scales.
 
 TRASH_BLOCK = 0
 
@@ -80,13 +95,14 @@ def init_pool(cfg: ModelConfig, pcfg: PagedConfig,
     bandwidth-bound; the KV term dominates the weight term at long
     context × batch).  Writes quantize, reads dequantize at the attention
     op (ops/attention.py paged paths)."""
-    shape = (cfg.num_layers, cfg.num_kv_heads, pcfg.num_blocks,
-             pcfg.block_size, cfg.head_dim)
+    rows = (cfg.num_layers, pcfg.num_blocks, pcfg.block_size)
+    shape = rows + (cfg.num_kv_heads * cfg.head_dim,)
     if kv_quantize == "int8":
+        scales = rows + (cfg.num_kv_heads,)
         return {"k": jnp.zeros(shape, jnp.int8),
                 "v": jnp.zeros(shape, jnp.int8),
-                "ks": jnp.ones(shape[:-1], jnp.float32),
-                "vs": jnp.ones(shape[:-1], jnp.float32)}
+                "ks": jnp.ones(scales, jnp.float32),
+                "vs": jnp.ones(scales, jnp.float32)}
     if kv_quantize != "none":
         raise ValueError(f"kv_quantize={kv_quantize!r}: expected 'none' "
                          "or 'int8'")
@@ -212,18 +228,14 @@ def write_prefill_blocks(pool: KVPool, blocks: jax.Array,
     l, s, nkv, d = k_all.shape
     nb = blocks.shape[0]
     bs = s // nb
-    # [L, S, N_kv, D] -> [L, N_kv, nb, bs, D] (head-major pool tiles).
-    k_blk = k_all.reshape(l, nb, bs, nkv, d).transpose(0, 3, 1, 2, 4)
-    v_blk = v_all.reshape(l, nb, bs, nkv, d).transpose(0, 3, 1, 2, 4)
+    # [L, S, N_kv, D] -> [L, nb, bs, N_kv(, D)]: already token-major.
+    rows = {"k": k_all.reshape(l, nb, bs, nkv, d),
+            "v": v_all.reshape(l, nb, bs, nkv, d)}
     if "ks" in pool:                       # int8 pool: quantize on write
-        k_blk, k_sc = quantize_kv_rows(k_blk)
-        v_blk, v_sc = quantize_kv_rows(v_blk)
-        return {"k": pool["k"].at[:, :, blocks].set(k_blk),
-                "v": pool["v"].at[:, :, blocks].set(v_blk),
-                "ks": pool["ks"].at[:, :, blocks].set(k_sc),
-                "vs": pool["vs"].at[:, :, blocks].set(v_sc)}
-    return {"k": pool["k"].at[:, :, blocks].set(k_blk),
-            "v": pool["v"].at[:, :, blocks].set(v_blk)}
+        rows["k"], rows["ks"] = quantize_kv_rows(rows["k"])
+        rows["v"], rows["vs"] = quantize_kv_rows(rows["v"])
+    return {key: x.at[:, blocks].set(rows[key].reshape(
+        l, nb, bs, x.shape[-1])) for key, x in pool.items()}
 
 
 def copy_block(pool: KVPool, src: jax.Array, dst: jax.Array) -> KVPool:
@@ -238,17 +250,16 @@ def copy_block(pool: KVPool, src: jax.Array, dst: jax.Array) -> KVPool:
     bounded exactly like the prefill writers (a per-pair or per-length
     wrap would re-trace on the admit path; the retrace lint fixtures in
     tests/test_lint.py pin the idiom)."""
-    out = {"k": pool["k"].at[:, :, dst].set(pool["k"][:, :, src]),
-           "v": pool["v"].at[:, :, dst].set(pool["v"][:, :, src])}
-    if "ks" in pool:
-        out["ks"] = pool["ks"].at[:, :, dst].set(pool["ks"][:, :, src])
-        out["vs"] = pool["vs"].at[:, :, dst].set(pool["vs"][:, :, src])
-    return out
+    def copy(x):       # one block-sized slice out, one in-place update in
+        tile = jax.lax.dynamic_slice_in_dim(x, src, 1, axis=1)
+        return jax.lax.dynamic_update_slice_in_dim(x, tile, dst, axis=1)
+
+    return {key: copy(x) for key, x in pool.items()}
 
 
 def gather_blocks(pool: KVPool, blocks: jax.Array) -> KVPool:
     """Snapshot ``blocks``' K/V tiles (and int8 scales) out of the pool:
-    ``[L, N_kv, nb, bs, D]`` — the DEMOTE copy of the hierarchical KV
+    ``[L, nb, bs, N_kv * D]`` — the DEMOTE copy of the hierarchical KV
     spill tier (engine/kv_spill.py).  The output is a fresh functional
     array that owns its data, so the source blocks may return to the
     free list the moment this gather is *issued*: later pool writes
@@ -256,26 +267,17 @@ def gather_blocks(pool: KVPool, blocks: jax.Array) -> KVPool:
     donating backends the enqueued gather reads its input before the
     donated update may alias it.  The device→host pull of the snapshot
     happens on the spill copier thread, never here."""
-    out = {"k": pool["k"][:, :, blocks], "v": pool["v"][:, :, blocks]}
-    if "ks" in pool:
-        out["ks"] = pool["ks"][:, :, blocks]
-        out["vs"] = pool["vs"][:, :, blocks]
-    return out
+    return {key: x[:, blocks] for key, x in pool.items()}
 
 
 def scatter_blocks(pool: KVPool, blocks: jax.Array,
                    tiles: KVPool) -> KVPool:
-    """Write previously gathered ``[L, N_kv, nb, bs, D]`` tiles back
+    """Write previously gathered ``[L, nb, bs, N_kv * D]`` tiles back
     into ``blocks`` — the PROMOTE copy of the hierarchical KV spill
     tier.  The exact inverse of ``gather_blocks`` (bit-identical round
     trip, int8 scales included), so a promoted prefix serves decode
     exactly like one that never left the pool."""
-    out = {"k": pool["k"].at[:, :, blocks].set(tiles["k"]),
-           "v": pool["v"].at[:, :, blocks].set(tiles["v"])}
-    if "ks" in pool:
-        out["ks"] = pool["ks"].at[:, :, blocks].set(tiles["ks"])
-        out["vs"] = pool["vs"].at[:, :, blocks].set(tiles["vs"])
-    return out
+    return {key: x.at[:, blocks].set(tiles[key]) for key, x in pool.items()}
 
 
 def pool_block_bytes(cfg: ModelConfig, block_size: int,
@@ -291,6 +293,61 @@ def pool_block_bytes(cfg: ModelConfig, block_size: int,
         return per_row * (d * 2 + 4 * 2)
     itemsize = jnp.dtype(cfg.dtype).itemsize
     return per_row * d * itemsize * 2
+
+
+_POOL_KEYS = ("k", "v", "ks", "vs")
+
+
+def _write_rows(pools, i, blk, off, k, v):
+    """Write one layer's new K/V rows into the CARRIED pool, in place.
+
+    ``pools`` is the whole pool as the layer loop carries it — ``(k, v)``
+    or, int8, ``(k, v, ks, vs)``, each ``[L, NB, bs, ·]``; ``i`` the
+    traced layer index; ``blk``/``off`` ``[...]`` the block and offset
+    of each row; ``k``/``v`` ``[..., N_kv, D]`` the rows (an int8 pool
+    quantizes them here, a scale a head).  One scatter an array and no
+    loop: the benchmark tells a decode tick from a prefill program by
+    how deep its ``while``s nest."""
+    rows = (k, v)
+    if len(pools) == 4:
+        (k, k_sc), (v, v_sc) = quantize_kv_rows(k), quantize_kv_rows(v)
+        rows = (k, v, k_sc, v_sc)
+    return tuple(p.at[i, blk, off].set(r.reshape(*blk.shape, p.shape[-1]))
+                 for p, r in zip(pools, rows))
+
+
+def _whole(pools):
+    """``(k, v, ks, vs)`` of the carried pool, ``ks``/``vs`` None for a
+    bf16 pool: what the dispatching ops of ops/attention.py take with
+    ``layer=i`` (their XLA path gathers straight from the whole pool)."""
+    return pools + (None,) * (4 - len(pools))
+
+
+def _hooked(attn, q, pools, i, *where):
+    """One layer's attention through an ``attn`` hook — the shard-mapped
+    and forced-kernel paths of parallel/tp_attention.py — which keeps its
+    ``(q, kp, vp, *where, ks, vs)`` contract over per-layer head-major
+    ``[N_kv, NB, bs(, D)]`` views."""
+    k, v, ks, vs = attention._layer_views(i, q.shape[-1], *_whole(pools))
+    return attn(q, k, v, *where, ks, vs)
+
+
+def _scan_layers(layer, x, layers, pool: KVPool):
+    """The layer loop of the three paged step functions: ``xs`` is
+    (layer weights, layer index) and the pool rides the CARRY whole, so
+    XLA updates the one buffer in place — scanned over as ``xs`` and
+    stacked back as ``ys`` it was sliced, rewritten and copied every
+    step (ROADMAP S0).  ``layer(x, pools, lp, i) -> (x, pools)``."""
+    keys = [key for key in _POOL_KEYS if key in pool]
+
+    def body(carry, scanned):
+        return layer(*carry, *scanned), None
+
+    n_layers = pool["k"].shape[0]
+    (x, pools), _ = jax.lax.scan(
+        body, (x, tuple(pool[key] for key in keys)),
+        (layers, jnp.arange(n_layers)))
+    return x, dict(zip(keys, pools))
 
 
 def chunk_prefill_paged(
@@ -314,7 +371,7 @@ def chunk_prefill_paged(
     """
     b, s_c = tokens.shape
     d = cfg.head_dim
-    bs = pool["k"].shape[3]
+    bs = pool["k"].shape[2]
 
     x = quant.embed_rows(params["embed"], tokens)            # [1, S_c, H]
     positions = start[:, None] + jnp.arange(s_c)[None, :]    # [1, S_c]
@@ -325,14 +382,7 @@ def chunk_prefill_paged(
     blk = table[flat_pos // bs]                              # [S_c]
     off = flat_pos % bs
 
-    quantized = "ks" in pool
-
-    def layer(x, scanned):
-        if quantized:
-            lp, k_pool, v_pool, ks_pool, vs_pool = scanned
-        else:
-            lp, k_pool, v_pool = scanned
-            ks_pool = vs_pool = None
+    def layer(x, pools, lp, i):
         h_in = transformer.rms_norm(x, lp["ln1"], cfg.norm_eps)
         q = quant.matmul(h_in, lp["wq"]).reshape(b, s_c, cfg.num_heads, d)
         k = quant.matmul(h_in, lp["wk"]).reshape(b, s_c, cfg.num_kv_heads, d)
@@ -340,23 +390,16 @@ def chunk_prefill_paged(
         q = transformer.apply_rope(q, sin, cos)
         k = transformer.apply_rope(k, sin, cos)
 
-        # Scatter the chunk's K/V to its (head, block, offset) cells, then
+        # Write the chunk's K/V rows to their (block, offset) cells, then
         # attend the table window (Pallas: in-kernel block walk; XLA:
         # gather-then-attend).
         with jax.named_scope("kv_write"):
-            k_rows = jnp.swapaxes(k[0], 0, 1)          # [nkv, S_c, d]
-            v_rows = jnp.swapaxes(v[0], 0, 1)
-            if quantized:
-                k_rows, k_sc = quantize_kv_rows(k_rows)
-                v_rows, v_sc = quantize_kv_rows(v_rows)
-                ks_pool = ks_pool.at[:, blk, off].set(k_sc)
-                vs_pool = vs_pool.at[:, blk, off].set(v_sc)
-            k_pool = k_pool.at[:, blk, off].set(k_rows)
-            v_pool = v_pool.at[:, blk, off].set(v_rows)
+            pools = _write_rows(pools, i, blk, off, k[0], v[0])
         with jax.named_scope("attention"):
+            k_p, v_p, ks_p, vs_p = _whole(pools)
             attn = attention.paged_chunk(
-                q, k_pool, v_pool, table, start, q_pos, window,
-                impl=cfg.attention_impl, k_scale=ks_pool, v_scale=vs_pool)
+                q, k_p, v_p, table, start, q_pos, window,
+                impl=cfg.attention_impl, k_scale=ks_p, v_scale=vs_p, layer=i)
         x = x + quant.matmul(attn.reshape(b, s_c, cfg.num_heads * d),
                              lp["wo"])
         with jax.named_scope("ffn"):
@@ -368,19 +411,9 @@ def chunk_prefill_paged(
             else:
                 x = x + transformer._swiglu(h_ffn, lp["w_gate"],
                                             lp["w_up"], lp["w_down"])
-        if quantized:
-            return x, (k_pool, v_pool, ks_pool, vs_pool)
-        return x, (k_pool, v_pool)
+        return x, pools
 
-    if quantized:
-        x, (k_new, v_new, ks_new, vs_new) = jax.lax.scan(
-            layer, x, (params["layers"], pool["k"], pool["v"],
-                       pool["ks"], pool["vs"]))
-        new_pool = {"k": k_new, "v": v_new, "ks": ks_new, "vs": vs_new}
-    else:
-        x, (k_new, v_new) = jax.lax.scan(
-            layer, x, (params["layers"], pool["k"], pool["v"]))
-        new_pool = {"k": k_new, "v": v_new}
+    x, new_pool = _scan_layers(layer, x, params["layers"], pool)
     hidden = transformer.rms_norm(x, params["final_ln"], cfg.norm_eps)
     return hidden, new_pool
 
@@ -415,7 +448,7 @@ def verify_step_paged(
     onto live KV."""
     b, g = tokens.shape
     d = cfg.head_dim
-    bs = pool["k"].shape[3]
+    bs = pool["k"].shape[2]
     max_pos = cfg.max_seq_len - 1
 
     x = quant.embed_rows(params["embed"], tokens)      # [B, G, H]
@@ -431,18 +464,7 @@ def verify_step_paged(
         jnp.take_along_axis(tables, wpos // bs, axis=1),
         TRASH_BLOCK)                                   # [B, G]
     off = wpos % bs
-    quantized = "ks" in pool
-    if attn is None:
-        attn = lambda q, kp, vp, tbl, p, ks, vs: attention.ragged_verify(
-            q, kp, vp, tbl, p, impl=cfg.attention_impl,
-            k_scale=ks, v_scale=vs)
-
-    def layer(x, scanned):
-        if quantized:
-            lp, k_pool, v_pool, ks_pool, vs_pool = scanned
-        else:
-            lp, k_pool, v_pool = scanned
-            ks_pool = vs_pool = None
+    def layer(x, pools, lp, i):
         h_in = transformer.rms_norm(x, lp["ln1"], cfg.norm_eps)
         q = quant.matmul(h_in, lp["wq"]).reshape(b, g, cfg.num_heads, d)
         k = quant.matmul(h_in, lp["wk"]).reshape(b, g, cfg.num_kv_heads, d)
@@ -450,23 +472,20 @@ def verify_step_paged(
         q = transformer.apply_rope(q, sin, cos)
         k = transformer.apply_rope(k, sin, cos)
 
-        # Write-before-attend for the whole chunk: [nkv, B, G, d] rows
-        # scatter to (head, blk[b, g], off[b, g]) — trash rows collide
-        # harmlessly like idle decode slots.
+        # Write-before-attend for the whole chunk: the [B, G] rows go to
+        # (blk[b, g], off[b, g]) — trash rows collide harmlessly like
+        # idle decode slots.
         with jax.named_scope("kv_write"):
-            k_rows = jnp.moveaxis(k, 2, 0)             # [nkv, B, G, d]
-            v_rows = jnp.moveaxis(v, 2, 0)
-            if quantized:
-                k_rows, k_sc = quantize_kv_rows(k_rows)
-                v_rows, v_sc = quantize_kv_rows(v_rows)
-                ks_pool = ks_pool.at[:, blk, off].set(k_sc)
-                vs_pool = vs_pool.at[:, blk, off].set(v_sc)
-            k_pool = k_pool.at[:, blk, off].set(k_rows)
-            v_pool = v_pool.at[:, blk, off].set(v_rows)
+            pools = _write_rows(pools, i, blk, off, k, v)
 
         with jax.named_scope("attention"):
-            attn_out = attn(q, k_pool, v_pool, tables, pos,
-                            ks_pool, vs_pool)          # [B, G, Nq, d]
+            if attn is not None:
+                attn_out = _hooked(attn, q, pools, i, tables, pos)
+            else:
+                k_p, v_p, ks_p, vs_p = _whole(pools)
+                attn_out = attention.ragged_verify(
+                    q, k_p, v_p, tables, pos, impl=cfg.attention_impl,
+                    k_scale=ks_p, v_scale=vs_p, layer=i)  # [B, G, Nq, d]
 
         x = x + quant.matmul(
             attn_out.reshape(b, g, cfg.num_heads * d), lp["wo"])
@@ -479,19 +498,9 @@ def verify_step_paged(
             else:
                 x = x + transformer._swiglu(h_ffn, lp["w_gate"],
                                             lp["w_up"], lp["w_down"])
-        if quantized:
-            return x, (k_pool, v_pool, ks_pool, vs_pool)
-        return x, (k_pool, v_pool)
+        return x, pools
 
-    if quantized:
-        x, (k_new, v_new, ks_new, vs_new) = jax.lax.scan(
-            layer, x, (params["layers"], pool["k"], pool["v"],
-                       pool["ks"], pool["vs"]))
-        new_pool = {"k": k_new, "v": v_new, "ks": ks_new, "vs": vs_new}
-    else:
-        x, (k_new, v_new) = jax.lax.scan(
-            layer, x, (params["layers"], pool["k"], pool["v"]))
-        new_pool = {"k": k_new, "v": v_new}
+    x, new_pool = _scan_layers(layer, x, params["layers"], pool)
     hidden = transformer.rms_norm(x, params["final_ln"], cfg.norm_eps)
     return transformer.logits_from_hidden(params, hidden), new_pool
 
@@ -523,29 +532,16 @@ def decode_step_paged(
     """
     b = token.shape[0]
     d = cfg.head_dim
-    bs = pool["k"].shape[3]
+    bs = pool["k"].shape[2]
 
     x = quant.embed_rows(params["embed"], token)       # [B, H]
     sin, cos = transformer.rope_sincos(pos, d, cfg.rope_theta)
 
     blk = jnp.take_along_axis(tables, (pos // bs)[:, None], axis=1)[:, 0]
     off = pos % bs                                     # [B]
-    batch_ix = jnp.arange(b)
+    attn_op = attention.ragged_decode if ragged else attention.paged_decode
 
-    quantized = "ks" in pool
-    if attn is None:
-        attn_op = (attention.ragged_decode if ragged
-                   else attention.paged_decode)
-        attn = lambda q, kp, vp, tbl, p, ks, vs: attn_op(
-            q, kp, vp, tbl, p, impl=cfg.attention_impl,
-            k_scale=ks, v_scale=vs)
-
-    def layer(x, scanned):
-        if quantized:
-            lp, k_pool, v_pool, ks_pool, vs_pool = scanned
-        else:
-            lp, k_pool, v_pool = scanned               # pools: [nkv, NB, bs, d]
-            ks_pool = vs_pool = None
+    def layer(x, pools, lp, i):
         h_in = transformer.rms_norm(x, lp["ln1"], cfg.norm_eps)
         q = quant.matmul(h_in, lp["wq"]).reshape(b, cfg.num_heads, d)
         k = quant.matmul(h_in, lp["wk"]).reshape(b, cfg.num_kv_heads, d)
@@ -553,25 +549,22 @@ def decode_step_paged(
         q = transformer.apply_rope(q, sin, cos)
         k = transformer.apply_rope(k, sin, cos)
 
-        # Write-before-attend at (head, block, offset); batched scatter —
-        # active slots hit distinct blocks, idle ones collide in trash.
+        # Write-before-attend at (block, offset), one row a slot — active
+        # slots hit distinct blocks, idle ones collide in trash.
         with jax.named_scope("kv_write"):
-            k_rows = jnp.swapaxes(k, 0, 1)             # [nkv, B, d]
-            v_rows = jnp.swapaxes(v, 0, 1)
-            if quantized:
-                k_rows, k_sc = quantize_kv_rows(k_rows)
-                v_rows, v_sc = quantize_kv_rows(v_rows)
-                ks_pool = ks_pool.at[:, blk, off].set(k_sc)
-                vs_pool = vs_pool.at[:, blk, off].set(v_sc)
-            k_pool = k_pool.at[:, blk, off].set(k_rows)
-            v_pool = v_pool.at[:, blk, off].set(v_rows)
+            pools = _write_rows(pools, i, blk, off, k, v)
 
         # Attend this slot's logical window: position p is
         # (table[p//bs], p%bs).  The Pallas path streams table blocks
         # through VMEM in-kernel; the XLA path gathers them contiguous.
         with jax.named_scope("attention"):
-            attn_out = attn(q, k_pool, v_pool, tables, pos, ks_pool,
-                            vs_pool)
+            if attn is not None:
+                attn_out = _hooked(attn, q, pools, i, tables, pos)
+            else:
+                k_p, v_p, ks_p, vs_p = _whole(pools)
+                attn_out = attn_op(
+                    q, k_p, v_p, tables, pos, impl=cfg.attention_impl,
+                    k_scale=ks_p, v_scale=vs_p, layer=i)
 
         x = x + quant.matmul(attn_out.reshape(b, cfg.num_heads * d),
                              lp["wo"])
@@ -583,18 +576,8 @@ def decode_step_paged(
             else:
                 x = x + transformer._swiglu(h_ffn, lp["w_gate"],
                                             lp["w_up"], lp["w_down"])
-        if quantized:
-            return x, (k_pool, v_pool, ks_pool, vs_pool)
-        return x, (k_pool, v_pool)
+        return x, pools
 
-    if quantized:
-        x, (k_new, v_new, ks_new, vs_new) = jax.lax.scan(
-            layer, x, (params["layers"], pool["k"], pool["v"],
-                       pool["ks"], pool["vs"]))
-        new_pool = {"k": k_new, "v": v_new, "ks": ks_new, "vs": vs_new}
-    else:
-        x, (k_new, v_new) = jax.lax.scan(
-            layer, x, (params["layers"], pool["k"], pool["v"]))
-        new_pool = {"k": k_new, "v": v_new}
+    x, new_pool = _scan_layers(layer, x, params["layers"], pool)
     hidden = transformer.rms_norm(x, params["final_ln"], cfg.norm_eps)
     return transformer.logits_from_hidden(params, hidden), new_pool

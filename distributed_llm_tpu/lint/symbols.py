@@ -587,7 +587,10 @@ def project_symbols(project) -> ProjectSymbols:
 # jit-root discovery (shared by jit_purity and retrace)
 # ---------------------------------------------------------------------------
 
-JIT_WRAPPERS = {"jit", "pjit", "shard_map", "pallas_call"}
+# ``_pool_program`` is engine/batching.py's one jit wrapper for the
+# programs that take the KV pool (donation + placement in one place):
+# what it wraps is traced exactly like a ``jax.jit`` argument.
+JIT_WRAPPERS = {"jit", "pjit", "shard_map", "pallas_call", "_pool_program"}
 
 
 def wrapper_leaf(node: ast.expr) -> Optional[str]:

@@ -251,47 +251,76 @@ def chunk(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
     return chunk_attention(q, k_cache, v_cache, q_positions)
 
 
-def _gather_pool_seq(q_dtype, k_pool, v_pool, tables, k_scale, v_scale):
-    """The paged fallbacks' ONE table gather: pools [Nkv, NB, bs, D] +
-    tables [B, MB] -> contiguous [B, S, Nkv, D] views (int8 pools
+def _layer_views(layer, head_dim, k_pool, v_pool, k_scale=None,
+                 v_scale=None):
+    """Per-layer head-major ``(k, v, ks, vs)`` views ``[Nkv, NB, bs(,
+    D)]`` for the kernels and hooks: with a ``layer`` index the pools are
+    the WHOLE token-major arrays of engine/paged_kv.py (``[L, NB, bs,
+    Nkv * D]``, scales ``[L, NB, bs, Nkv]``) and the layer is sliced out
+    and turned here — a layer-sized copy, which the XLA paths below avoid
+    by gathering from the whole pool; without one they already are the
+    views."""
+    if layer is None:
+        return k_pool, v_pool, k_scale, v_scale
+
+    def view(pool, *heads):
+        x = jax.lax.dynamic_index_in_dim(pool, layer, 0, keepdims=False)
+        return jnp.moveaxis(x.reshape(*x.shape[:2], -1, *heads), 2, 0)
+
+    return (view(k_pool, head_dim), view(v_pool, head_dim),
+            k_scale if k_scale is None else view(k_scale),
+            v_scale if v_scale is None else view(v_scale))
+
+
+def _gather_pool_seq(q, k_pool, v_pool, tables, k_scale, v_scale,
+                     layer=None):
+    """The paged fallbacks' ONE table gather: pools + tables [B, MB] ->
+    contiguous [B, S, Nkv, D] views in ``q``'s dtype (int8 pools
     dequantized through the gathered scales).  Shared by the decode
-    (q_len=1) and verify (q_len=γ+1) fallbacks so their byte-parity is
-    mechanical, not maintained by hand."""
+    (q_len=1), verify (q_len=γ+1) and chunk fallbacks so their
+    byte-parity is mechanical, not maintained by hand.
+
+    The pools are per-layer head-major views ``[Nkv, NB, bs(, D)]`` or,
+    with a ``layer`` index, the WHOLE token-major pool ``[L, NB, bs,
+    Nkv * D]`` (scales ``[L, NB, bs, Nkv]``), gathered at (layer, block)
+    directly: whole blocks of whole tokens, already in the order the
+    attention wants, and no layer-sized slice in between."""
     b, mb = tables.shape
-    nkv, bs, d = k_pool.shape[0], k_pool.shape[2], k_pool.shape[3]
+    if layer is None:
+        def seq(pool, *_):     # [Nkv, B, MB, bs(, D)] -> [B, S, Nkv(, D)]
+            blocks = jnp.moveaxis(pool[:, tables], 0, 3)
+            return blocks.reshape(b, -1, *blocks.shape[3:])
+    else:
+        def seq(pool, *heads):  # [B, MB, bs, ·] -> [B, S, Nkv(, D)]
+            return pool[layer, tables].reshape(b, mb * pool.shape[2], -1,
+                                               *heads)
+
     with jax.named_scope("kv_gather"):
-        # [Nkv, B, MB, bs, D] -> [B, S, Nkv, D]
-        k_seq = k_pool[:, tables].reshape(
-            nkv, b, mb * bs, d).transpose(1, 2, 0, 3)
-        v_seq = v_pool[:, tables].reshape(
-            nkv, b, mb * bs, d).transpose(1, 2, 0, 3)
+        k_seq, v_seq = seq(k_pool, q.shape[-1]), seq(v_pool, q.shape[-1])
         if k_scale is not None:
-            k_sc = k_scale[:, tables].reshape(
-                nkv, b, mb * bs).transpose(1, 2, 0)
-            v_sc = v_scale[:, tables].reshape(
-                nkv, b, mb * bs).transpose(1, 2, 0)
             k_seq = (k_seq.astype(jnp.float32)
-                     * k_sc[..., None]).astype(q_dtype)
+                     * seq(k_scale)[..., None]).astype(q.dtype)
             v_seq = (v_seq.astype(jnp.float32)
-                     * v_sc[..., None]).astype(q_dtype)
+                     * seq(v_scale)[..., None]).astype(q.dtype)
     return k_seq, v_seq
 
 
-def _gather_decode_paged(q, k_pool, v_pool, tables, pos, k_scale, v_scale):
+def _gather_decode_paged(q, k_pool, v_pool, tables, pos, k_scale, v_scale,
+                         layer=None):
     """XLA fallback shared by ``paged_decode`` and ``ragged_decode``:
     gather the block table into a contiguous view and reuse
     ``decode_attention`` (portable / GSPMD-shardable; one code path so
     the two kinds' fallbacks are byte-identical — the parity reference
     for the Pallas kernels)."""
-    k_seq, v_seq = _gather_pool_seq(q.dtype, k_pool, v_pool, tables,
-                                    k_scale, v_scale)
+    k_seq, v_seq = _gather_pool_seq(q, k_pool, v_pool, tables,
+                                    k_scale, v_scale, layer)
     return decode_attention(q, k_seq, v_seq, pos)
 
 
 def paged_decode(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
                  tables: jax.Array, pos: jax.Array,
                  impl: str = "auto", k_scale: jax.Array = None,
-                 v_scale: jax.Array = None) -> jax.Array:
+                 v_scale: jax.Array = None, layer=None) -> jax.Array:
     """Dispatching batched decode attention over a paged KV pool
     (engine/paged_kv.py): q [B, Nq, D], pools [Nkv, NB, bs, D], tables
     [B, MB], pos [B] -> [B, Nq, D].  The Pallas path walks the block table
@@ -301,25 +330,35 @@ def paged_decode(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
     ``k_scale``/``v_scale`` ([Nkv, NB, bs]) mark an int8 pool: the Pallas
     path streams int8 blocks + scales and dequantizes in VMEM
     (paged_decode_attention_q8, its own dispatch kind); the XLA path
-    gathers HALF the bytes and dequantizes after."""
+    gathers HALF the bytes and dequantizes after.
+
+    ``layer`` (here and in the three ops below): the pools and scales
+    are the WHOLE token-major arrays of engine/paged_kv.py ([L, NB, bs,
+    Nkv * D], scales [L, NB, bs, Nkv]) and this is the traced layer to
+    attend — the XLA path gathers straight from the whole pool, a kernel
+    gets the layer's head-major view (``_layer_views``)."""
     b, mb = tables.shape
-    bs = k_pool.shape[2]
+    bs = k_pool.shape[-2]
     if k_scale is None:
         if _choose(impl, "paged_decode", mb * bs) == "pallas":
             from .pallas_attention import paged_decode_attention
-            return paged_decode_attention(q, k_pool, v_pool, tables, pos)
+            return paged_decode_attention(
+                q, *_layer_views(layer, q.shape[-1], k_pool, v_pool)[:2],
+                tables, pos)
     elif _choose(impl, "paged_decode_q8", mb * bs) == "pallas":
         from .pallas_attention import paged_decode_attention_q8
-        return paged_decode_attention_q8(q, k_pool, v_pool, k_scale,
-                                         v_scale, tables, pos)
+        return paged_decode_attention_q8(
+            q, *_layer_views(layer, q.shape[-1], k_pool, v_pool, k_scale,
+                             v_scale),
+            tables, pos)
     return _gather_decode_paged(q, k_pool, v_pool, tables, pos,
-                                k_scale, v_scale)
+                                k_scale, v_scale, layer)
 
 
 def ragged_decode(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
                   tables: jax.Array, pos: jax.Array,
                   impl: str = "auto", k_scale: jax.Array = None,
-                  v_scale: jax.Array = None) -> jax.Array:
+                  v_scale: jax.Array = None, layer=None) -> jax.Array:
     """Dispatching RAGGED batched decode attention over a paged KV pool:
     same shapes as ``paged_decode`` (q [B, Nq, D], pools [Nkv, NB, bs, D],
     tables [B, MB], pos [B] -> [B, Nq, D]) but a different contract — the
@@ -337,21 +376,25 @@ def ragged_decode(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
     ``v_scale`` ([Nkv, NB, bs]) mark an int8 pool (ragged_decode_q8,
     in-VMEM dequant on the Pallas path)."""
     b, mb = tables.shape
-    bs = k_pool.shape[2]
+    bs = k_pool.shape[-2]
     if k_scale is None:
         if _choose(impl, "ragged_decode", mb * bs) == "pallas":
             from .ragged_attention import ragged_paged_decode_attention
-            return ragged_paged_decode_attention(q, k_pool, v_pool, tables,
-                                                 pos)
+            return ragged_paged_decode_attention(
+                q, *_layer_views(layer, q.shape[-1], k_pool, v_pool)[:2],
+                tables, pos)
     elif _choose(impl, "ragged_decode_q8", mb * bs) == "pallas":
         from .ragged_attention import ragged_paged_decode_attention_q8
-        return ragged_paged_decode_attention_q8(q, k_pool, v_pool, k_scale,
-                                                v_scale, tables, pos)
+        return ragged_paged_decode_attention_q8(
+            q, *_layer_views(layer, q.shape[-1], k_pool, v_pool, k_scale,
+                             v_scale),
+            tables, pos)
     return _gather_decode_paged(q, k_pool, v_pool, tables, pos,
-                                k_scale, v_scale)
+                                k_scale, v_scale, layer)
 
 
-def _gather_verify_paged(q, k_pool, v_pool, tables, pos, k_scale, v_scale):
+def _gather_verify_paged(q, k_pool, v_pool, tables, pos, k_scale, v_scale,
+                         layer=None):
     """XLA fallback for ``ragged_verify``: the SAME ``_gather_pool_seq``
     gather as ``_gather_decode_paged`` (so the q_len=1 and q_len=γ+1
     fallbacks agree block-for-block by construction), attended through
@@ -359,8 +402,8 @@ def _gather_verify_paged(q, k_pool, v_pool, tables, pos, k_scale, v_scale):
     byte-level correctness reference the Pallas verify kernels are
     pinned against."""
     g = q.shape[1]
-    k_seq, v_seq = _gather_pool_seq(q.dtype, k_pool, v_pool, tables,
-                                    k_scale, v_scale)
+    k_seq, v_seq = _gather_pool_seq(q, k_pool, v_pool, tables,
+                                    k_scale, v_scale, layer)
     q_pos = pos[:, None] + jnp.arange(g)[None]               # [B, G]
     return chunk_attention(q, k_seq, v_seq, q_pos)
 
@@ -368,7 +411,7 @@ def _gather_verify_paged(q, k_pool, v_pool, tables, pos, k_scale, v_scale):
 def ragged_verify(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
                   tables: jax.Array, pos: jax.Array,
                   impl: str = "auto", k_scale: jax.Array = None,
-                  v_scale: jax.Array = None) -> jax.Array:
+                  v_scale: jax.Array = None, layer=None) -> jax.Array:
     """Dispatching RAGGED speculative-verify attention over a paged KV
     pool: q [B, G, Nq, D] — G = γ+1 chunk queries per slot at absolute
     positions ``pos[b] + g`` (``pos`` [B] is the FIRST query's position;
@@ -387,24 +430,27 @@ def ragged_verify(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
     parity reference.  ``k_scale``/``v_scale`` ([Nkv, NB, bs]) mark an
     int8 pool (ragged_verify_q8, in-VMEM dequant on the Pallas path)."""
     b, mb = tables.shape
-    bs = k_pool.shape[2]
+    bs = k_pool.shape[-2]
     if k_scale is None:
         if _choose(impl, "ragged_verify", mb * bs) == "pallas":
             from .ragged_attention import ragged_paged_verify_attention
-            return ragged_paged_verify_attention(q, k_pool, v_pool, tables,
-                                                 pos)
+            return ragged_paged_verify_attention(
+                q, *_layer_views(layer, q.shape[-1], k_pool, v_pool)[:2],
+                tables, pos)
     elif _choose(impl, "ragged_verify_q8", mb * bs) == "pallas":
         from .ragged_attention import ragged_paged_verify_attention_q8
-        return ragged_paged_verify_attention_q8(q, k_pool, v_pool, k_scale,
-                                                v_scale, tables, pos)
+        return ragged_paged_verify_attention_q8(
+            q, *_layer_views(layer, q.shape[-1], k_pool, v_pool, k_scale,
+                             v_scale),
+            tables, pos)
     return _gather_verify_paged(q, k_pool, v_pool, tables, pos,
-                                k_scale, v_scale)
+                                k_scale, v_scale, layer)
 
 
 def paged_chunk(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
                 table: jax.Array, start: jax.Array, q_pos: jax.Array,
                 window: int, impl: str = "auto", k_scale: jax.Array = None,
-                v_scale: jax.Array = None) -> jax.Array:
+                v_scale: jax.Array = None, layer=None) -> jax.Array:
     """Dispatching suffix-chunk attention over a paged KV pool
     (engine/paged_kv.chunk_prefill_paged): q [1, S_c, Nq, D], pools
     [Nkv, NB, bs, D], table [MB], start [1], q_pos [1, S_c] clamped
@@ -413,25 +459,15 @@ def paged_chunk(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
     flash_chunk_attention); the XLA path gathers the window and masks by
     ``q_pos`` (portable / GSPMD-shardable fallback).  ``k_scale``/
     ``v_scale`` mark an int8 pool (XLA dequant path, see paged_decode)."""
-    nkv, bs, d = k_pool.shape[0], k_pool.shape[2], k_pool.shape[3]
+    bs = k_pool.shape[-2]
     if k_scale is None and _choose(impl, "paged_chunk", window) == "pallas":
         from .pallas_attention import paged_chunk_attention
-        return paged_chunk_attention(q, k_pool, v_pool, table, start, window)
-    wb = window // bs
-    with jax.named_scope("kv_gather"):
-        k_seq = jnp.swapaxes(
-            k_pool[:, table[:wb]].reshape(nkv, window, d), 0, 1)[None]
-        v_seq = jnp.swapaxes(
-            v_pool[:, table[:wb]].reshape(nkv, window, d), 0, 1)[None]
-        if k_scale is not None:
-            k_sc = jnp.swapaxes(
-                k_scale[:, table[:wb]].reshape(nkv, window), 0, 1)[None]
-            v_sc = jnp.swapaxes(
-                v_scale[:, table[:wb]].reshape(nkv, window), 0, 1)[None]
-            k_seq = (k_seq.astype(jnp.float32)
-                     * k_sc[..., None]).astype(q.dtype)
-            v_seq = (v_seq.astype(jnp.float32)
-                     * v_sc[..., None]).astype(q.dtype)
+        return paged_chunk_attention(
+            q, *_layer_views(layer, q.shape[-1], k_pool, v_pool)[:2], table,
+            start, window)
+    k_seq, v_seq = _gather_pool_seq(q, k_pool, v_pool,
+                                    table[None, :window // bs],
+                                    k_scale, v_scale, layer)
     return chunk_attention(q, k_seq, v_seq, q_pos)
 
 

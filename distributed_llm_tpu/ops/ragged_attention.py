@@ -17,9 +17,10 @@ regardless of length skew.
 
 - Grid is (slot, table-block) — slots × KV blocks, heads looped in VMEM.
   Each grid step DMAs pool block ``tables[b, j]`` across ALL kv heads as
-  one [Nkv, bs, D] tile (the pool is head-major, so the tile is Nkv
-  strided (bs, D) sublane×lane planes — the layout init_pool chose for
-  exactly this kernel).
+  one [Nkv, bs, D] tile (of the layer's head-major view [Nkv, NB, bs,
+  D], so the tile is Nkv strided (bs, D) sublane×lane planes; the
+  engine's pool rests token-major and ops.attention._layer_views makes
+  the view).
 - Per-slot TRUE lengths: iterations past ``pos[b]`` are index-clamped
   onto the slot's frontier block (the repeated index elides the DMA) and
   compute-skipped, so a slot at position p streams ceil((p+1)/bs) blocks

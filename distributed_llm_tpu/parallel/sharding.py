@@ -209,16 +209,17 @@ def kv_cache_specs(tp_axis: str = "tp",
 
 def kv_pool_specs(tp_axis: str = "tp",
                   quantized: bool = False) -> Dict[str, P]:
-    """Paged KV pool [L, N_kv, NB, bs, D] (engine/paged_kv.py head-major
-    layout): shard the kv-head axis over tp, like the contiguous cache —
-    each shard owns its heads' blocks, and the decode step's scatter/gather
-    batch over the head axis without resharding.  int8 pools carry per-row
-    scale planes [L, N_kv, NB, bs], head-sharded the same way."""
-    spec = {"k": P(None, tp_axis, None, None, None),
-            "v": P(None, tp_axis, None, None, None)}
+    """Paged KV pool [L, NB, bs, N_kv * D] (engine/paged_kv.py: token-major,
+    the kv heads merged into the last axis, each a contiguous run of it):
+    shard that axis over tp, like the contiguous cache's head axis — each
+    shard owns its heads' columns of every block, and the decode step's
+    scatter/gather stay shard-local.  int8 pools carry per-row scale
+    planes [L, NB, bs, N_kv], head-sharded the same way."""
+    spec = {"k": P(None, None, None, tp_axis),
+            "v": P(None, None, None, tp_axis)}
     if quantized:
-        spec["ks"] = P(None, tp_axis, None, None)
-        spec["vs"] = P(None, tp_axis, None, None)
+        spec["ks"] = P(None, None, None, tp_axis)
+        spec["vs"] = P(None, None, None, tp_axis)
     return spec
 
 

@@ -153,12 +153,12 @@ def test_decode_error_fails_slot_but_scheduler_survives():
 
 def test_mesh_engine_shards_params_and_pool():
     """A mesh-sharded batching engine places params by the Megatron rules
-    and the pool on its kv-head axis (kv_pool_specs)."""
+    and the pool on its (merged) kv-head axis (kv_pool_specs)."""
     devs = np.array(jax.devices()[:2])
     mesh = jax.sharding.Mesh(devs, ("tp",))
     eng = ContinuousBatchingEngine(_tier(), mesh=mesh)
     try:
-        assert eng.pool["k"].sharding.spec[1] == "tp"
+        assert eng.pool["k"].sharding.spec[3] == "tp"
         # Column-parallel Q projection shards its output features.
         assert eng.params["layers"]["wq"].sharding.spec[2] == "tp"
     finally:
@@ -318,7 +318,7 @@ def test_batched_tp_mesh_matches_unsharded_tokens():
         assert a == b
         # Pool really is sharded over the mesh, on the kv-head axis.
         shard_spec = tp.pool["k"].sharding.spec
-        assert shard_spec[1] == "tp", shard_spec
+        assert shard_spec[3] == "tp", shard_spec
     finally:
         plain.stop()
         tp.stop()

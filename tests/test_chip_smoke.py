@@ -55,6 +55,7 @@ def test_serve_and_what_ran_on_the_tiny_cluster(capsys):
                                     devices=jax.devices()[:1])
     try:
         what = chip_smoke.phase_what_ran(served)
+        pool = chip_smoke.phase_pool_programs(served)
     finally:
         chip_smoke.phase_drain(served)
 
@@ -66,6 +67,12 @@ def test_serve_and_what_ran_on_the_tiny_cluster(capsys):
     assert by["orin#3-long"]["device"] == "orin"
     assert all(r["gen_tokens"] > 0 for r in served.record["requests"]
                if "gen_tokens" in r)
+    # The pool phase compiled each tier's tick, one chunk program and
+    # copy_block, and found the pool's format the same in and out.
+    for tier in ("nano", "orin"):
+        programs = {k: v for k, v in pool[tier].items() if k != "at_rest"}
+        assert len(programs) == 3 and set(pool[tier]["at_rest"]) == {"k", "v"}
+        assert all(f["formats_match"] for f in programs.values()), programs
     # Warm-up is reported apart from the requests, per tier.
     assert set(served.record["warmup_s"]) == {"nano", "orin"}
     # Off-TPU the batched engine takes the fused ragged tick on the XLA

@@ -40,7 +40,7 @@ def test_init_pool_int8_layout_and_memory():
     pcfg = PagedConfig(block_size=16, max_slots=2, max_seq_len=64)
     pool = init_pool(cfg, pcfg, "int8")
     assert pool["k"].dtype == jnp.int8
-    assert pool["ks"].shape == pool["k"].shape[:-1]
+    assert pool["ks"].shape == pool["k"].shape[:-1] + (cfg.num_kv_heads,)
     bf16 = init_pool(cfg, pcfg)
     bytes_q = sum(x.size * x.dtype.itemsize for x in pool.values())
     bytes_f = sum(x.size * x.dtype.itemsize for x in bf16.values())
@@ -183,7 +183,7 @@ def test_tp_mesh_kv_int8_pool_sharded_and_consistent():
         a = plain.generate("user: int8 pool under tp?").token_ids
         b = tp.generate("user: int8 pool under tp?").token_ids
         assert a == b
-        assert tp.pool["ks"].sharding.spec[1] == "tp"
+        assert tp.pool["ks"].sharding.spec[3] == "tp"
     finally:
         plain.stop()
         tp.stop()
@@ -276,3 +276,172 @@ def test_flash_decode_q8_serving_geometry_multiblock():
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want, np.float32),
                                atol=2e-2, rtol=2e-2)
+
+
+# -- the pool rides the layer loop's carry (ISSUE 27) --------------------------
+
+def _scans(jaxpr):
+    """Every ``scan`` equation of a jaxpr, nested ones included."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "scan":
+            yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _scans(sub)
+
+
+def _step_case(step, kv_quantize):
+    """(function of (params, pool), params, a pool with a prefix already
+    written) for one of the three paged step functions on nano_test."""
+    from distributed_llm_tpu import models
+    from distributed_llm_tpu.engine import paged_kv
+    cfg = MODEL_PRESETS["nano_test"]
+    pcfg = PagedConfig(block_size=16, max_slots=2, max_seq_len=64)
+    params = models.init_params(cfg, seed=3)
+    pool = init_pool(cfg, pcfg, kv_quantize)
+    # Slot 0 owns blocks 1, 2, slot 1 blocks 3, 4; an 18-token prefix is
+    # prefilled into slot 0 so the steps attend real K/V across a block
+    # boundary.
+    tables = jnp.asarray([[1, 2, 0, 0], [3, 4, 0, 0]], jnp.int32)
+    prefix = jnp.asarray([[5 + i for i in range(18)] + [0] * 14], jnp.int32)
+    _, pool = paged_kv.chunk_prefill_paged(
+        cfg, params, prefix, jnp.asarray([0]), jnp.asarray([18]), pool,
+        tables[0], 32)
+    if step == "decode":
+        def fn(params, pool):
+            return paged_kv.decode_step_paged(
+                cfg, params, jnp.asarray([7, 9]), jnp.asarray([18, 0]), pool,
+                tables[:, :2])
+    elif step == "chunk":
+        def fn(params, pool):
+            return paged_kv.chunk_prefill_paged(
+                cfg, params, jnp.asarray([[11 + i for i in range(16)]]),
+                jnp.asarray([18]), jnp.asarray([30]), pool, tables[0], 48)
+    else:
+        def fn(params, pool):
+            return paged_kv.verify_step_paged(
+                cfg, params, jnp.asarray([[7, 8, 9], [4, 5, 6]]),
+                jnp.asarray([18, 0]), pool, tables)
+    return cfg, fn, params, pool, tables
+
+
+def _reference_step(step, cfg, params, pool, tables):
+    """The straightforward per-layer form the carried loop replaced:
+    each layer works on ITS slice of a head-major pool ``[N_kv, NB, bs(,
+    D)]`` — head-major row scatter, the attention op on the per-layer
+    view — and the slices are stacked back (``lax.scan`` hands them in as ``xs``
+    and stacks them as ``ys``; unrolled in Python the same arithmetic
+    fuses differently on the CPU and bf16 rounds elsewhere)."""
+    from distributed_llm_tpu.models import transformer
+    from distributed_llm_tpu.ops import attention, quant
+    d, bs = cfg.head_dim, pool["k"].shape[2]
+    quantized = "ks" in pool
+    # The reference keeps its pool head-major, [L, N_kv, NB, bs(, D)].
+    def head_major(x, *heads):
+        return jnp.moveaxis(x.reshape(*x.shape[:3], -1, *heads), 3, 1)
+    merged = pool
+    pool = {key: head_major(x, *([d] if key in "kv" else []))
+            for key, x in pool.items()}
+    if step == "decode":
+        tokens, pos = jnp.asarray([7, 9]), jnp.asarray([18, 0])
+        tables = tables[:, :2]
+        positions = pos
+        blk = jnp.take_along_axis(tables, (pos // bs)[:, None], 1)[:, 0]
+        lead = (2,)
+    elif step == "chunk":
+        tokens = jnp.asarray([[11 + i for i in range(16)]])
+        start, true_len = jnp.asarray([18]), jnp.asarray([30])
+        positions = start[:, None] + jnp.arange(16)[None]
+        q_pos = jnp.minimum(positions, true_len[:, None] - 1)
+        blk = tables[0][positions[0] // bs]
+        lead = (1, 16)
+    else:
+        tokens, pos = jnp.asarray([[7, 8, 9], [4, 5, 6]]), jnp.asarray([18, 0])
+        positions = pos[:, None] + jnp.arange(3)[None]
+        blk = jnp.take_along_axis(tables, positions // bs, axis=1)
+        lead = (2, 3)
+    off = (positions[0] if step == "chunk" else positions) % bs
+    x = quant.embed_rows(params["embed"], tokens)
+    sin, cos = transformer.rope_sincos(positions, d, cfg.rope_theta)
+    def one_layer(x, scanned):
+        lp, view = scanned
+        view = dict(view)
+        h_in = transformer.rms_norm(x, lp["ln1"], cfg.norm_eps)
+        q = quant.matmul(h_in, lp["wq"]).reshape(*lead, cfg.num_heads, d)
+        k = quant.matmul(h_in, lp["wk"]).reshape(*lead, cfg.num_kv_heads, d)
+        v = quant.matmul(h_in, lp["wv"]).reshape(*lead, cfg.num_kv_heads, d)
+        q = transformer.apply_rope(q, sin, cos)
+        k = transformer.apply_rope(k, sin, cos)
+        rows = {"k": k[0] if step == "chunk" else k,
+                "v": v[0] if step == "chunk" else v}
+        if quantized:
+            rows["k"], rows["ks"] = quantize_kv_rows(rows["k"])
+            rows["v"], rows["vs"] = quantize_kv_rows(rows["v"])
+        for key, r in rows.items():   # [..., N_kv(, D)] -> head-major
+            view[key] = view[key].at[:, blk, off].set(jnp.moveaxis(
+                r, blk.ndim, 0))
+        scales = dict(k_scale=view.get("ks"), v_scale=view.get("vs"))
+        if step == "decode":
+            attn = attention.paged_decode(q, view["k"], view["v"], tables,
+                                          pos, impl=cfg.attention_impl,
+                                          **scales)
+        elif step == "chunk":
+            attn = attention.paged_chunk(q, view["k"], view["v"], tables[0],
+                                         start, q_pos, 48,
+                                         impl=cfg.attention_impl, **scales)
+        else:
+            attn = attention.ragged_verify(q, view["k"], view["v"], tables,
+                                           pos, impl=cfg.attention_impl,
+                                           **scales)
+        x = x + quant.matmul(attn.reshape(*lead, cfg.num_heads * d),
+                             lp["wo"])
+        h_ffn = transformer.rms_norm(x, lp["ln2"], cfg.norm_eps)
+        x = x + transformer._swiglu(h_ffn, lp["w_gate"], lp["w_up"],
+                                    lp["w_down"])
+        return x, view
+
+    x, new_pool = jax.lax.scan(one_layer, x, (params["layers"], dict(pool)))
+    hidden = transformer.rms_norm(x, params["final_ln"], cfg.norm_eps)
+    out = (hidden if step == "chunk"
+           else transformer.logits_from_hidden(params, hidden))
+    # ... and hands it back token-major, heads merged, to be compared.
+    return out, {key: jnp.moveaxis(x_, 1, 3).reshape(merged[key].shape)
+                 for key, x_ in new_pool.items()}
+
+
+@pytest.mark.parametrize("kv_quantize", ["none", "int8"])
+@pytest.mark.parametrize("step", ["decode", "chunk", "verify"])
+def test_step_carries_the_pool_and_matches_a_per_layer_reference(
+        step, kv_quantize):
+    cfg, fn, params, pool, tables = _step_case(step, kv_quantize)
+    # The pool rides the layer scan's CARRY: scanned over as xs (or
+    # stacked back as ys) it is sliced and rewritten every step.
+    pool_shapes = {x.shape for x in pool.values()}
+    scans = list(_scans(jax.make_jaxpr(fn)(params, pool).jaxpr))
+    assert scans
+    for eqn in scans:
+        n_fixed = eqn.params["num_consts"] + eqn.params["num_carry"]
+        carried = {v.aval.shape for v in
+                   eqn.invars[eqn.params["num_consts"]:n_fixed]}
+        moved = ([v.aval.shape for v in eqn.invars[n_fixed:]]
+                 + [v.aval.shape for v in
+                    eqn.outvars[eqn.params["num_carry"]:]])
+        assert pool_shapes <= carried, (carried, pool_shapes)
+        assert not pool_shapes & set(moved), moved
+    # Same arithmetic, other addresses: logits (hidden states for the
+    # chunk program) and every pool array equal to the last bit.
+    out, new_pool = jax.jit(fn)(params, pool)
+    ref_out, ref_pool = jax.jit(
+        lambda params, pool: _reference_step(step, cfg, params, pool,
+                                             tables))(params, pool)
+    np.testing.assert_array_equal(np.asarray(out, np.float32),
+                                  np.asarray(ref_out, np.float32))
+    assert set(new_pool) == set(ref_pool) == set(pool)
+    for key in pool:
+        assert new_pool[key].dtype == pool[key].dtype
+        np.testing.assert_array_equal(
+            np.asarray(new_pool[key].astype(jnp.float32)),
+            np.asarray(ref_pool[key].astype(jnp.float32)), err_msg=key)
+    # ... and the step wrote something (the comparison is not of zeros).
+    assert not np.array_equal(
+        np.asarray(new_pool["k"].astype(jnp.float32)),
+        np.asarray(pool["k"].astype(jnp.float32)))

@@ -130,17 +130,17 @@ def test_copy_block_isolates_writer_from_source(kv_quantize):
     cfg = _tier().model()
     pcfg = PagedConfig(block_size=8, max_slots=1, max_seq_len=32)
     pool = init_pool(cfg, pcfg, kv_quantize)
-    one = jnp.ones_like(pool["k"][:, :, 1])
-    pool = dict(pool, k=pool["k"].at[:, :, 1].set(one))
+    one = jnp.ones_like(pool["k"][:, 1])
+    pool = dict(pool, k=pool["k"].at[:, 1].set(one))
     copied = copy_block(pool, jnp.asarray(1, jnp.int32),
                         jnp.asarray(2, jnp.int32))
-    assert bool((copied["k"][:, :, 2] == one).all())
+    assert bool((copied["k"][:, 2] == one).all())
     if kv_quantize == "int8":
-        assert bool((copied["ks"][:, :, 2] == pool["ks"][:, :, 1]).all())
+        assert bool((copied["ks"][:, 2] == pool["ks"][:, 1]).all())
     # The writer scribbles over its private copy; the source block (the
     # sharers' view) must not move.
-    written = dict(copied, k=copied["k"].at[:, :, 2].set(7 * one))
-    assert bool((written["k"][:, :, 1] == one).all())
+    written = dict(copied, k=copied["k"].at[:, 2].set(7 * one))
+    assert bool((written["k"][:, 1] == one).all())
 
 
 # -- shared hits: byte-identity + no crosstalk -------------------------------
@@ -561,16 +561,16 @@ def test_spec_tick_cow_protects_externally_shared_frontier_block():
         eng._pos[0] = 4                       # write window inside block 0
         shared = slot.blocks[0]
         eng.allocator.share([shared])         # second holder appears
-        before = np.asarray(eng.pool["k"][:, :, shared])
+        before = np.asarray(eng.pool["k"][:, shared])
 
         eng._ensure_spec_private([0], eng.spec_gamma_max)
 
         assert shared not in slot.blocks, "guard must swap the block out"
         fresh = slot.blocks[0]
         np.testing.assert_array_equal(
-            np.asarray(eng.pool["k"][:, :, shared]), before)
+            np.asarray(eng.pool["k"][:, shared]), before)
         np.testing.assert_array_equal(
-            np.asarray(eng.pool["k"][:, :, fresh]), before)   # true copy
+            np.asarray(eng.pool["k"][:, fresh]), before)   # true copy
         assert eng.allocator.refcount(shared) == 1            # ours only
         assert eng.allocator.refcount(fresh) == 1
         # Conservation: slot blocks + our shared ref account for every

@@ -165,17 +165,17 @@ def test_verify_step_overflow_rows_write_trash_not_live_kv():
     nb = pcfg.blocks_per_slot
     tables = jnp.asarray(np.arange(1, nb + 1, dtype=np.int32)[None])
     last_block = nb                          # holds positions max_seq-16..
-    before = np.asarray(pool["k"][:, :, last_block])
+    before = np.asarray(pool["k"][:, last_block])
     # First chunk position = max_seq-1: rows 1..3 overflow the context.
     chunk = jnp.asarray([[7, 8, 9, 10]], jnp.int32)
     pos = jnp.asarray([cfg.max_seq_len - 1], jnp.int32)
     _, new_pool = verify_step_paged(cfg, params, chunk, pos, pool, tables)
-    after = np.asarray(new_pool["k"][:, :, last_block])
+    after = np.asarray(new_pool["k"][:, last_block])
     # Row 0 (position max_seq-1) legitimately wrote ONE row of the last
     # block; the three overflow rows must have gone to trash, leaving
     # every other row of the last block untouched.
     changed_rows = {int(r) for r in
-                    np.argwhere(np.any(before != after, axis=(0, 1, 3)))
+                    np.argwhere(np.any(before != after, axis=(0, 2)))
                     .ravel()}
     assert changed_rows <= {(cfg.max_seq_len - 1) % pcfg.block_size}
 

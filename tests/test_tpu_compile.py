@@ -106,40 +106,45 @@ def test_kernel_compiles_for_v5e(one_chip, compiled_kernels, preset, dtype,
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def _nano_tick(one_chip):
-    """The batched engine's own decode-tick program at nano_1b and full
-    KV residency, built the way the chip builds it, lowered on shapes
-    (jax.eval_shape params — nothing of the 1B model materializes)."""
+def _pool_program(one_chip, tier, program):
+    """One of the engine's own pool programs, compiled for the described
+    chip, lowered on shapes: ``jax.eval_shape`` weights (nothing of the
+    model materializes) and a pool of shapes at the tier's real size,
+    beside an engine whose own pool — real host memory — stays tiny.
+    ``program`` is ("decode", window), ("chunk", chunk, window) or
+    ("cow",).  Gives (engine, pool shapes, compiled, pool argument)."""
     from distributed_llm_tpu import models
     from distributed_llm_tpu.engine.batching import ContinuousBatchingEngine
     from distributed_llm_tpu.engine.paged_kv import PagedConfig, init_pool
 
-    tier = flagship_cluster(n_devices=1).nano
     cfg = tier.model()
     params = _on(one_chip, jax.eval_shape(
         partial(models.init_params, cfg, seed=0)))
-    # The engine's own pool stays tiny (it is real host memory); the
-    # program is lowered against the pool shape under test.
-    engine = ContinuousBatchingEngine(
-        dataclasses.replace(tier, kv_pool_blocks=40), params=params)
     paged = PagedConfig(block_size=tier.kv_block_size,
                         max_slots=tier.decode_batch,
-                        max_seq_len=cfg.max_seq_len)
+                        max_seq_len=cfg.max_seq_len,
+                        pool_blocks=tier.kv_pool_blocks)
     pool = _on(one_chip, jax.eval_shape(
         lambda: init_pool(cfg, paged, tier.kv_quantize)))
-    b = tier.decode_batch
-    wb = (paged.blocks_per_slot if engine.ragged
-          else engine._buckets[0] // tier.kv_block_size)
-
-    def arg(shape, dtype=jnp.int32):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
-
+    tiny = max(tier.prefill_buckets) // tier.kv_block_size + 2
+    engine = ContinuousBatchingEngine(
+        dataclasses.replace(tier, kv_pool_blocks=tiny), params=params)
     try:
-        compiled = engine._decode_step().lower(
-            params, pool, arg((b, wb)), arg((b,)), arg((b,)),
-            arg((b,), jnp.float32), arg((2,), jnp.uint32)).compile()
+        kind, *sizes = program
+        (compiled, pool_arg), = chip_smoke.pool_programs(
+            engine, pool, sizes if kind == "decode" else [],
+            [tuple(sizes)] if kind == "chunk" else [],
+            cow=kind == "cow").values()
     finally:
         engine.stop()
+    return engine, pool, compiled, pool_arg
+
+
+def _nano_tick(one_chip):
+    """The batched engine's own decode-tick program at nano_1b and full
+    KV residency, built the way the chip builds it."""
+    engine, _, compiled, _ = _pool_program(
+        one_chip, flagship_cluster(n_devices=1).nano, ("decode", 256))
     return engine, compiled
 
 
@@ -177,3 +182,98 @@ def test_ragged_pallas_nano_tick_compiles_for_v5e(one_chip, as_on_tpu,
     engine, compiled = _nano_tick(one_chip)
     assert engine.ragged is True
     assert compiled.as_text().count("tpu_custom_call") == 1
+
+
+# -- the KV pool stays in place (ISSUE 27) -------------------------------------
+
+def _bench_smollm2_tier(monkeypatch):
+    """The benchmark's own SmolLM2-1.7B tier, read from its configuration
+    file the way benchmark/cluster.py builds it: 24 layers, 32/32 heads,
+    head_dim 64, 144 + 1 blocks of 64 tokens, 8 slots, 4 steps a tick."""
+    import importlib.util
+    import json
+    from distributed_llm_tpu.config import TierConfig
+    bench = os.path.join(REPO, "benchmark")
+    spec = importlib.util.spec_from_file_location(
+        "_benchmark_cluster", os.path.join(bench, "cluster.py"))
+    cluster = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(cluster)
+    with open(os.path.join(bench, "configs", "smollm2-1.7b.json")) as f:
+        entry = cluster.tier_entries(json.load(f), False)["nano"]
+    monkeypatch.setitem(MODEL_PRESETS, entry["preset"], cluster.model_config(
+        entry["preset"], entry["model"]))
+    kw = dict(entry["tier"], prefill_buckets=tuple(
+        entry["tier"]["prefill_buckets"]))
+    return TierConfig(name="nano", model_preset=entry["preset"], **kw)
+
+
+def _flagship_nano(preset):
+    return dataclasses.replace(flagship_cluster(n_devices=1).nano,
+                               model_preset=preset)
+
+
+GB = 1e9
+# (tier, program, temporaries allowed in GB, forced onto the ragged Pallas
+# tick).  At the benchmark's sizes the commit before compiled to 11.30 GB
+# of temporaries for a tick and 3.51 for a chunk program, with 6
+# pool-sized copies, 2 update-slices and 2 slice fusions (compile for a
+# described v5e, PR 27); the issue asks for under 1 GB and no pool-sized
+# move at all.  The others who run the same code are held to what the
+# commit before compiled to: GQA at head_dim 64 and head_dim 128 on the
+# served XLA path (13.10 and 1.347 GB), and the same two on the HOOKED
+# path — the fused ragged tick on its Pallas kernel, which gets a layer's
+# head-major view from ``ops.attention._layer_views`` (5.073 and 1.213
+# GB).  No cell runs a hooked tier: these two cases are all that holds it.
+POOL_PROGRAMS = {
+    "smollm2-decode-256":
+        (_bench_smollm2_tier, ("decode", 256), 1.0, False),
+    "smollm2-decode-2048":
+        (_bench_smollm2_tier, ("decode", 2048), 1.0, False),
+    "smollm2-chunk-256-256":
+        (_bench_smollm2_tier, ("chunk", 256, 256), 1.0, False),
+    "smollm2-chunk-256-1024":
+        (_bench_smollm2_tier, ("chunk", 256, 1024), 1.0, False),
+    "smollm2-copy_block":
+        (_bench_smollm2_tier, ("cow",), 1.0, False),
+    "nano_1b-gqa-decode-256":
+        (lambda _: _flagship_nano("nano_1b"), ("decode", 256), 13.10, False),
+    "orin_bench-d128-decode-256":
+        (lambda _: _flagship_nano("orin_bench"), ("decode", 256), 1.347,
+         False),
+    "nano_1b-gqa-ragged-pallas":
+        (lambda _: _flagship_nano("nano_1b"), ("decode", 0), 5.073, True),
+    "orin_bench-d128-ragged-pallas":
+        (lambda _: _flagship_nano("orin_bench"), ("decode", 0), 1.213, True),
+}
+
+
+@pytest.mark.parametrize("case", list(POOL_PROGRAMS))
+def test_pool_program_leaves_the_pool_in_place(one_chip, as_on_tpu,
+                                               monkeypatch, case):
+    """Every program that takes the pool updates the one buffer in place:
+    the pool aliased input to output, in the same format on both sides
+    (the device's default: nothing is pinned, so a program loaded from
+    the persistent compile cache agrees), nothing pool-shaped produced
+    but by an in-place write (no ``copy``, no stacked ``ys``, no layer
+    slice), temporaries small."""
+    make_tier, program, temp_limit_gb, ragged = POOL_PROGRAMS[case]
+    if ragged:
+        monkeypatch.setenv("DLLM_RAGGED", "1")
+        monkeypatch.setenv("DLLM_ATTENTION", "pallas")
+    engine, pool, compiled, pool_arg = _pool_program(
+        one_chip, make_tier(monkeypatch), program)
+    assert engine.ragged is ragged
+    assert compiled.as_text().count("tpu_custom_call") == int(ragged)
+    facts = chip_smoke.pool_program_facts(compiled, pool_arg, pool)
+    pool_bytes = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(pool))
+    assert facts["formats_match"], facts
+    # Aliased whole, and no padding: the merged head axis fills the lanes.
+    assert facts["alias_bytes"] == pool_bytes, facts
+    assert facts["output_bytes"] - facts["alias_bytes"] < 1 << 20, facts
+    assert facts["pool_sized_moves"] == {}, facts
+    assert facts["temp_bytes"] < temp_limit_gb * GB, facts
+    if program[0] != "cow":
+        # The loop structure the benchmark files programs by: steps and
+        # layers for a tick, layers alone for a chunk program.
+        assert compiled.as_text().count(" while(") == (
+            2 if program[0] == "decode" else 1)
