@@ -1,16 +1,27 @@
 """Build the system under test from a configuration file.
 
 Everything here goes through the program's own entry points: model
-presets are REGISTERED at run time (``MODEL_PRESETS[name] = ...``), the
-tiers are plain ``TierConfig``s, the cluster is served by ``Router`` +
+presets are REGISTERED at run time (``MODEL_PRESETS[name] = ...``, the
+``ModelConfig`` the tier's family makes of the file's published keys:
+``families/<family>.py``; no model key is named here), the tiers are
+plain ``TierConfig``s, the cluster is served by ``Router`` +
 ``create_app`` and driven through the app's test client.  Nothing in
 ``distributed_llm_tpu/`` is edited.
 """
 
 from __future__ import annotations
 
+import os
+import sys
 import time
 from typing import Any, Dict, List, Sequence
+
+# tests/test_tpu_compile.py loads this file by its path alone.
+_HERE = os.path.dirname(os.path.abspath(__file__))
+if _HERE not in sys.path:
+    sys.path.insert(0, _HERE)
+
+import manifest as mf                      # noqa: E402
 
 # Ids of the program's byte scheme (engine/tokenizer.py).
 PAD_ID, EOS_ID = 256, 258
@@ -24,17 +35,17 @@ def say(phase: str, msg: str) -> None:
 def tier_entries(config: Dict[str, Any], rehearsal: bool
                  ) -> Dict[str, Dict[str, Any]]:
     """Per tier: the model's sizes and the tier's settings as they are
-    run.  ``rehearsal`` swaps in the file's tiny CPU sizes (control flow
-    only; a rehearsal prints no result line)."""
+    run.  ``rehearsal`` swaps in the file's tiny CPU sizes by the rule of
+    the tier's family (control flow only; a rehearsal prints no result
+    line)."""
     out = {}
     for name, entry in config["tiers"].items():
         key = entry.get("model_key")
         model = dict(config[key] if key else config)
         tier = dict(entry["tier"])
         if rehearsal:
-            model.update(entry["rehearsal_model"])
-            model["head_dim"] = (model["hidden_size"]
-                                 // model["num_attention_heads"])
+            model = mf.load_family(entry["family"]).rehearsal_model(
+                model, entry["rehearsal_model"])
             tier.update(entry.get("rehearsal_tier", {}))
         out[name] = {"model": model, "tier": tier,
                      "preset": entry["preset"] + ("_rehearsal"
@@ -43,27 +54,21 @@ def tier_entries(config: Dict[str, Any], rehearsal: bool
     return out
 
 
+def program_config(entry: Dict[str, Any]):
+    """The program's ModelConfig for one entry of ``tier_entries``: the
+    mapping from the configuration file's published keys is the family's
+    (``families/<family>.py``).  The one lookup; ``build``, the sweep and
+    the compile check all come through here."""
+    return mf.load_family(entry["family"]).model_config(entry["preset"],
+                                                        entry["model"])
+
+
 def model_config(preset: str, model: Dict[str, Any]):
-    """The program's ModelConfig at the published sizes.  ``tokenizer``
-    is the byte scheme so that any vocabulary size passes
-    ``get_tokenizer``."""
-    from distributed_llm_tpu.config import ModelConfig
-    cfg = ModelConfig(
-        name=preset, tokenizer="byte",
-        vocab_size=model["vocab_size"],
-        hidden_size=model["hidden_size"],
-        num_layers=model["num_hidden_layers"],
-        num_heads=model["num_attention_heads"],
-        num_kv_heads=model["num_key_value_heads"],
-        ffn_size=model["intermediate_size"],
-        max_seq_len=model["max_position_embeddings"],
-        rope_theta=float(model.get("rope_theta", 10000.0)),
-        norm_eps=float(model.get("rms_norm_eps", 1e-5)),
-        dtype=model.get("torch_dtype", "bfloat16"))
-    if cfg.head_dim != model.get("head_dim", cfg.head_dim):
-        raise ValueError(f"{preset}: head_dim {model['head_dim']} is not "
-                         f"hidden/heads = {cfg.head_dim}")
-    return cfg
+    """The dense family's mapping under the name it had before families
+    were files: ``tests/test_tpu_compile.py`` calls it, and a benchmark PR
+    may not edit a test.  Goes with that call (PERF.md section 7)."""
+    return program_config({"family": "dense_decoder", "preset": preset,
+                           "model": model})
 
 
 class Served:
@@ -104,7 +109,7 @@ def build(config: Dict[str, Any], seed: int, rehearsal: bool,
     entries = tier_entries(config, rehearsal)
     tiers = {}
     for name, e in entries.items():
-        MODEL_PRESETS[e["preset"]] = model_config(e["preset"], e["model"])
+        MODEL_PRESETS[e["preset"]] = program_config(e)
         kw = dict(e["tier"])
         kw["prefill_buckets"] = tuple(kw["prefill_buckets"])
         tiers[name] = TierConfig(name=name, model_preset=e["preset"], **kw)
@@ -128,8 +133,8 @@ def build(config: Dict[str, Any], seed: int, rehearsal: bool,
             t0 = time.perf_counter()
             router.tiers[name].server_manager.start_server()
             served.warm_s[name] = time.perf_counter() - t0
-            install_token_table(served.engine(name).tokenizer,
-                                e["model"]["vocab_size"])
+            engine = served.engine(name)
+            install_token_table(engine.tokenizer, engine.cfg.vocab_size)
             say("build", f"tier {name} = {e['preset']} up in "
                          f"{served.warm_s[name]:.1f} s on device(s) "
                          f"{sorted(d.id for d in served.tier_devices(name))}")

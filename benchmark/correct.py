@@ -1,24 +1,26 @@
 """``correct``: the serving path's logits against the plain reference.
 
 Decided before the timed window and from nothing the traffic did.  Per
-tier, a fixed sample drawn whole from the seed (``LENGTHS``: one sequence
-of ids per length) goes
+tier, a fixed sample drawn whole from the seed (one sequence of ids per
+length; the lengths and ``n_decode`` are the configuration's
+``correct.sample``, or ``LENGTHS`` and ``N_DECODE`` where it states none)
+goes
 
   * through the program's own serving functions, called as the engine
-    calls them.  All but the last ``N_DECODE`` ids are prefilled chunk by
+    calls them.  All but the last ``n_decode`` ids are prefilled chunk by
     chunk through ``chunk_prefill_paged`` — ``start`` the chunk's first
     position, ``true_len`` the whole prompt, the slot's full table row,
     ``window`` the smallest rung of the engine's chunk-window ladder that
     holds the chunk's end — so the sample runs 3 to 5 chunks a sequence,
     continuation chunks (``start > 0``, earlier blocks gathered) on every
-    rung of the ladder.  The last ``N_DECODE`` ids go one at a time,
+    rung of the ladder.  The last ``n_decode`` ids go one at a time,
     teacher-forced, through ``decode_step_paged`` as one fixed batch on
     tables cut to the decode window rung, as the dense tick cuts them.
     The ENGINE'S weights, attention choice, block size, chunk size and
     mesh; a small paged pool of the engine's geometry made for the check
     (the engine's allocator and pool are never touched); and
-  * through ``reference/<family>.py``: float32, full forward, its own
-    weights from the seed.
+  * through ``reference/<family>.py`` (found by the tier's ``family``):
+    float32, full forward, its own weights from the seed.
 
 The statistic is the relative Frobenius error of the logits over all kept
 positions of all sequences together; beside it, the engine's weights and
@@ -33,47 +35,88 @@ upload), its scheduler and its allocator.
 from __future__ import annotations
 
 import dataclasses
-import importlib
-import os
-import sys
-from typing import Any, Dict, List, Sequence
+from typing import Any, Dict, List, Sequence, Tuple
 
 import numpy as np
 
-# Prompts of 600..1272 ids at a chunk of 256: 3, 4, 5 and 5 chunks, ends
-# on the 256, the 1024 and the full-span window rungs.
+import manifest as mf
+
+# Where a configuration states no ``correct.sample``.  Prompts of
+# 600..1272 ids at a chunk of 256: 3, 4, 5 and 5 chunks, ends on the 256,
+# the 1024 and the full-span window rungs.
 LENGTHS, N_DECODE = (616, 808, 1064, 1288), 16
 TRASH_BLOCK = 0
 
-HERE = os.path.dirname(os.path.abspath(__file__))
+
+def sample_sizes(config: Dict[str, Any]) -> Tuple[Tuple[int, ...], int]:
+    """(lengths, n_decode) of a configuration's sample: its
+    ``correct.sample``, so that a cell is judged at the cache lengths its
+    traffic reaches.  The same for every seed."""
+    stated = config.get("correct", {}).get("sample", {})
+    unknown = sorted(set(stated) - {"lengths", "n_decode"})
+    if unknown:
+        raise mf.ManifestError(f"correct.sample has {unknown}; it may give "
+                               f"'lengths' and 'n_decode'")
+    lengths = tuple(int(n) for n in stated.get("lengths", LENGTHS))
+    n_decode = int(stated.get("n_decode", N_DECODE))
+    if not lengths or n_decode < 1 or min(lengths) <= n_decode:
+        raise mf.ManifestError(f"correct.sample: lengths {lengths} have to "
+                               f"be longer than n_decode {n_decode} >= 1")
+    return lengths, n_decode
 
 
-def draw_sample(seed: int, vocab_size: int) -> List[np.ndarray]:
-    """The fixed sample: a pure function of seed and vocabulary; every
-    seed draws the same lengths.  Ids avoid 256..258 (PAD, BOS, EOS of
-    the program's byte scheme)."""
+# The sweep's controls (``tools/sweep_correct.py``): the program's own
+# paths one precision below the stated one, each switched on alone.
+CONTROLS = ("int8_weights", "int8_kv")
+
+
+def controls_of(config: Dict[str, Any]) -> Tuple[str, ...]:
+    """Which of the sweep's controls a configuration runs: its
+    ``correct.controls``, all of them where it states none.  A family
+    whose program has neither path runs none, and then has to say in
+    ``correct.control`` what else shows that a lower precision would be
+    caught (``run.py`` judges ``narrow_leaves`` for every
+    configuration)."""
+    stated = config.get("correct", {})
+    names = tuple(stated.get("controls", CONTROLS))
+    unknown = sorted(set(names) - set(CONTROLS))
+    if unknown:
+        raise mf.ManifestError(f"correct.controls names {unknown}; the "
+                               f"sweep has {list(CONTROLS)}")
+    if not names and not str(stated.get("control", "")).strip():
+        raise mf.ManifestError(
+            "correct.controls is empty: correct.control has to say what "
+            "else shows that a lower precision would be caught")
+    return names
+
+
+def draw_sample(seed: int, vocab_size: int,
+                lengths: Sequence[int] = LENGTHS) -> List[np.ndarray]:
+    """The fixed sample: a pure function of seed, vocabulary and
+    ``lengths``; every seed draws the same lengths.  Ids avoid 256..258
+    (PAD, BOS, EOS of the program's byte scheme)."""
     rng = np.random.default_rng([int(seed) % (2 ** 63), 0xC0221EC7])
     out = []
-    for n in LENGTHS:
+    for n in lengths:
         ids = rng.integers(0, vocab_size - 3, size=int(n))
         out.append(np.where(ids >= 256, ids + 3, ids).astype(np.int32))
     return out
 
 
-def kept_positions(seqs: List[np.ndarray]) -> np.ndarray:
-    """[sequences, N_DECODE + 1]: the last prompt position and every
+def kept_positions(seqs: List[np.ndarray], n_decode: int = N_DECODE
+                   ) -> np.ndarray:
+    """[sequences, n_decode + 1]: the last prompt position and every
     decode step's position (logits at p predict the id at p + 1)."""
-    return np.stack([np.arange(len(s) - N_DECODE - 1, len(s))
+    return np.stack([np.arange(len(s) - n_decode - 1, len(s))
                      for s in seqs]).astype(np.int32)
 
 
 def reference_logits(family: str, model: Dict[str, Any], seed: int,
-                     seqs: List[np.ndarray], device=None) -> np.ndarray:
+                     seqs: List[np.ndarray], device=None,
+                     n_decode: int = N_DECODE) -> np.ndarray:
     import jax
     import jax.numpy as jnp
-    if HERE not in sys.path:
-        sys.path.insert(0, HERE)
-    ref = importlib.import_module(f"reference.{family}")
+    ref = mf.load_module("reference", family)
     sharding = (jax.sharding.SingleDeviceSharding(device)
                 if device is not None else None)
     weights = ref.init_weights(model, int(seed) % (2 ** 31), sharding)
@@ -82,7 +125,8 @@ def reference_logits(family: str, model: Dict[str, Any], seed: int,
     for i, s in enumerate(seqs):
         tokens[i, :len(s)] = s
     put = (lambda a: jax.device_put(a, sharding)) if sharding else jnp.asarray
-    out = ref.logits(model, weights, put(tokens), put(kept_positions(seqs)))
+    out = ref.logits(model, weights, put(tokens),
+                     put(kept_positions(seqs, n_decode)))
     return np.asarray(out, np.float32)
 
 
@@ -163,9 +207,9 @@ def _programs(cfg, block_size: int, kv_quantize: str, mesh, ragged: bool):
 def system_logits(cfg, params, seqs: List[np.ndarray], *, block_size: int,
                   chunk: int, span: int, decode_rungs: Sequence[int],
                   windows: Sequence[int] = (), kv_quantize: str = "none",
-                  mesh=None, ragged: bool = False, device=None
-                  ) -> np.ndarray:
-    """[sequences, N_DECODE + 1, V] float32 through the program's paged
+                  mesh=None, ragged: bool = False, device=None,
+                  n_decode: int = N_DECODE) -> np.ndarray:
+    """[sequences, n_decode + 1, V] float32 through the program's paged
     prefill and decode.  ``cfg`` is the engine's ModelConfig (attention
     choice included), ``params`` its weights as served, ``span`` the
     positions a slot's table row covers, ``windows`` the engine's
@@ -200,7 +244,7 @@ def system_logits(cfg, params, seqs: List[np.ndarray], *, block_size: int,
 
     first = []
     for i, s in enumerate(seqs):
-        total = len(s) - N_DECODE
+        total = len(s) - n_decode
         if total <= 2 * chunk:
             raise ValueError(f"sample prompt of {total} ids spans fewer "
                              f"than 3 chunks of {chunk}")
@@ -216,10 +260,13 @@ def system_logits(cfg, params, seqs: List[np.ndarray], *, block_size: int,
                 jnp.asarray([start], np.int32),
                 jnp.asarray([total], np.int32), row)
         first.append(np.asarray(lg, np.float32))
-    ids = np.stack([s[len(s) - N_DECODE:] for s in seqs], axis=1)  # [T, B]
-    pos0 = np.asarray([len(s) - N_DECODE for s in seqs], np.int32)
-    rung = next(r for r in sorted(decode_rungs)
-                if r >= max(len(s) for s in seqs))
+    ids = np.stack([s[len(s) - n_decode:] for s in seqs], axis=1)  # [T, B]
+    pos0 = np.asarray([len(s) - n_decode for s in seqs], np.int32)
+    longest = max(len(s) for s in seqs)
+    rung = next((r for r in sorted(decode_rungs) if r >= longest), None)
+    if rung is None:
+        raise ValueError(f"no decode window rung of {sorted(decode_rungs)} "
+                         f"holds the sample's {longest} positions")
     cut = tables if ragged else tables[:, :rung // block_size]
     steps = np.asarray(decode(params, pool, jnp.asarray(cut),
                               jnp.asarray(ids), jnp.asarray(pos0)),
@@ -244,9 +291,13 @@ def tier_settings(tier, cfg) -> Dict[str, Any]:
 
 
 def engine_statistic(engine, family: str, model: Dict[str, Any], seed: int,
-                     ref_device=None) -> Dict[str, Any]:
-    """The statistic for one tier as it is served."""
-    seqs = draw_sample(seed, model["vocab_size"])
+                     ref_device=None,
+                     sample: Tuple[Sequence[int], int] = (LENGTHS, N_DECODE)
+                     ) -> Dict[str, Any]:
+    """The statistic for one tier as it is served; ``sample`` is the
+    configuration's ``sample_sizes``."""
+    lengths, n_decode = sample
+    seqs = draw_sample(seed, engine.cfg.vocab_size, lengths)
     dev = engine.devices[0] if (engine.mesh is None and engine.devices) \
         else None
     got = system_logits(
@@ -254,9 +305,9 @@ def engine_statistic(engine, family: str, model: Dict[str, Any], seed: int,
         **tier_settings(engine.tier, engine.cfg),
         windows=getattr(engine, "_chunk_windows", ()),
         kv_quantize=engine.tier.kv_quantize, mesh=engine.mesh,
-        ragged=engine.ragged, device=dev)
+        ragged=engine.ragged, device=dev, n_decode=n_decode)
     want = reference_logits(family, model, seed, seqs,
-                            device=ref_device or dev)
+                            device=ref_device or dev, n_decode=n_decode)
     return {"rel_err": rel_frobenius(got, want),
             "finite": bool(np.isfinite(got).all()),
             "positions": int(got.shape[0] * got.shape[1]),

@@ -1,58 +1,50 @@
 """Bytes of a decode step, from the configuration's sizes alone.
 
 These are the numerators of every roofline share the benchmark reports;
-they live here so that no PR that claims a gain can change them, and
-``tests/test_costs.py`` holds them to hand-worked sizes for both models.
-A share over 100% means a count here is too high or a time leaves out
-part of the work — never clamp it.
+they live under ``benchmark/`` so that no PR that claims a gain can
+change them.  What is counted depends on the model family (a dense layer,
+a latent cache, routed experts), so each count is the family's own,
+``families/<family>.py``, found by the tier's ``family``; the names here
+are the one way to them.  ``family`` defaults to the family these names
+counted before there were several: a reader passes the tier's own.
+``tests/test_costs.py`` holds the counts to hand-worked sizes for two
+models.  A share over 100% means a count is too high or a time leaves
+out part of the work — never clamp it.
 """
 from __future__ import annotations
 
 from typing import Any, Dict, Sequence
 
+import manifest as mf
+
 BYTES = {"bfloat16": 2, "float16": 2, "float32": 4, "int8": 1}
-
-
-def _dims(model: Dict[str, Any]):
-    h = model["hidden_size"]
-    nq = model["num_attention_heads"]
-    nkv = model["num_key_value_heads"]
-    d = model.get("head_dim") or h // nq
-    return (h, nq, nkv, d, model["intermediate_size"],
-            model["num_hidden_layers"], model["vocab_size"])
+DENSE = "dense_decoder"
 
 
 def layer_params(model: Dict[str, Any]) -> int:
-    """Matrix parameters of one decoder layer (norm gains left out)."""
-    h, nq, nkv, d, f, _, _ = _dims(model)
-    return h * nq * d + 2 * h * nkv * d + nq * d * h + 3 * h * f
+    """Matrix parameters of one layer of the dense family."""
+    return mf.load_family(DENSE).layer_params(model)
 
 
 def embed_params(model: Dict[str, Any]) -> int:
-    return model["vocab_size"] * model["hidden_size"]
+    return mf.load_family(DENSE).embed_params(model)
 
 
-def weight_bytes_per_chip(model: Dict[str, Any], tp: int = 1) -> int:
-    """Weight bytes one chip reads in one decode step: its 1/tp share of
-    every layer matrix, and the whole embedding once as the tied output
-    head (held whole on every chip of a tensor-parallel tier)."""
-    b = BYTES[model.get("torch_dtype", "bfloat16")]
-    layers = model["num_hidden_layers"] * layer_params(model)
-    return (layers // tp + embed_params(model)) * b
+def weight_bytes_per_chip(model: Dict[str, Any], tp: int = 1,
+                          family: str = DENSE) -> int:
+    """Weight bytes one chip reads in one decode step."""
+    return mf.load_family(family).weight_bytes_per_chip(model, tp)
 
 
-def kv_bytes_per_token(model: Dict[str, Any]) -> int:
-    """K and V of one position over all layers, in the served dtype."""
-    _, _, nkv, d, _, n_layers, _ = _dims(model)
-    return 2 * n_layers * nkv * d * BYTES[model.get("torch_dtype",
-                                                    "bfloat16")]
+def kv_bytes_per_token(model: Dict[str, Any], family: str = DENSE) -> int:
+    """What one position keeps in the cache over all layers."""
+    return mf.load_family(family).kv_bytes_per_token(model)
 
 
 def decode_step_bytes_per_chip(model: Dict[str, Any],
-                               contexts: Sequence[float],
-                               tp: int = 1) -> float:
+                               contexts: Sequence[float], tp: int = 1,
+                               family: str = DENSE) -> float:
     """The least one chip must read for one decode step of a batch whose
-    sequences hold ``contexts`` positions: its weights once, and its share
-    of every sequence's K/V."""
-    kv = sum(contexts) * kv_bytes_per_token(model) / tp
-    return weight_bytes_per_chip(model, tp) + kv
+    sequences hold ``contexts`` positions."""
+    return mf.load_family(family).decode_step_bytes_per_chip(model, contexts,
+                                                             tp)

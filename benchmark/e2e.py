@@ -2,7 +2,8 @@
 
 Each end-to-end metric is a data file ``end_to_end/<name>.json``:
 
-    {"kind": "percentile", "of": "ttft_ms" | "tpot_ms", "q": 0..100}
+    {"kind": "percentile", "of": "ttft_ms" | "tpot_ms" | "latency_ms",
+     "q": 0..100}
     {"kind": "token_rate"}
     {"kind": "setup"}
 
@@ -76,7 +77,17 @@ def tpot_ms(rec: Dict[str, Any]) -> Optional[float]:
     return (b[-2][0] - b[0][0]) * 1000.0 / tokens
 
 
-QUANTITIES = {"ttft_ms": ttft_ms, "tpot_ms": tpot_ms}
+def latency_ms(rec: Dict[str, Any]) -> Optional[float]:
+    """From when the client sent the request to the arrival of the
+    reply's last token: what a caller that waits for the whole reply
+    waits."""
+    if not rec["ok"] or not rec["stamps"]:
+        return None
+    return (rec["stamps"][-1] - rec["due"]) * 1000.0
+
+
+QUANTITIES = {"ttft_ms": ttft_ms, "tpot_ms": tpot_ms,
+              "latency_ms": latency_ms}
 
 
 def tokens_in_window(records: List[Dict[str, Any]], t0: float,

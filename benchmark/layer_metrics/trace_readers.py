@@ -1,9 +1,11 @@
 """Readers over the device trace (``tracing.py`` does the reduction; the
-numerators of the shares come from ``costs.py`` and ``peaks.json``)."""
+numerators of the shares come from ``costs.py``, which finds the counts
+of the tier's own family, and ``peaks.json``)."""
 from __future__ import annotations
 
 import costs
 import tracing
+from cluster import say
 
 
 def _modules(ctx, tier, kinds):
@@ -76,8 +78,14 @@ def decode_hbm_share(ctx, tier):
     if step is None or ctx.peaks is None:
         return None
     entry = ctx.served.entries[tier]
+    contexts = _contexts(ctx, tier)
     need = costs.decode_step_bytes_per_chip(
-        entry["model"], _contexts(ctx, tier), int(entry["tier"].get("tp", 1)))
+        entry["model"], contexts, int(entry["tier"].get("tp", 1)),
+        family=entry["family"])
+    say("costs", f"tier {tier} ({entry['family']}): a decode step of "
+                 f"{len(contexts)} sequences holding {sum(contexts)} "
+                 f"positions reads at least {need!r} bytes a chip; the step "
+                 f"took {step!r} ms")
     return 100.0 * (need / ctx.peaks["hbm_bytes_per_s"]) / (step / 1000.0)
 
 

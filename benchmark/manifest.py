@@ -7,6 +7,8 @@ and metrics; everything else is looked up BY NAME under ``benchmark/``:
     traffic/<mix>.json             one per traffic mix
     end_to_end/<metric>.json       one per end-to-end metric
     layer_metrics/<metric>.json    one per per-layer metric (names its reader)
+    families/<family>.py           the program's ModelConfig and the byte
+                                   counts of a model family
     reference/<family>.py          the plain float32 forward pass
 
 A name that cannot be found is an error that lists what was looked for.
@@ -15,6 +17,7 @@ A name that cannot be found is an error that lists what was looked for.
 from __future__ import annotations
 
 import importlib
+import importlib.util
 import json
 import os
 import sys
@@ -72,6 +75,49 @@ def metrics_for(manifest: Dict[str, Any], cell_name: str, kind: str
     the cell."""
     return [m for m in manifest[kind]
             if "workloads" not in m or cell_name in m["workloads"]]
+
+
+_MODULES: Dict[str, Any] = {}
+
+
+def load_module(package: str, name: str):
+    """``benchmark/<package>/<name>.py`` as a module, loaded from its file
+    once a process.  The two packages found this way are keyed by a tier's
+    ``family``: ``families`` (what the harness knows of a model family)
+    and ``reference`` (the plain forward pass, which knows nothing of the
+    harness)."""
+    path = os.path.join(BENCH_DIR, package, name + ".py")
+    if path in _MODULES:
+        return _MODULES[path]
+    if not os.path.isfile(path):
+        have = sorted(f[:-3] for f in os.listdir(os.path.dirname(path))
+                      if f.endswith(".py")) \
+            if os.path.isdir(os.path.dirname(path)) else []
+        raise ManifestError(f"{package} {name!r}: looked for "
+                            f"{os.path.relpath(path, ROOT)} and did not "
+                            f"find it; that directory holds {have}")
+    spec = importlib.util.spec_from_file_location(f"{package}.{name}", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    _MODULES[path] = mod
+    return mod
+
+
+FAMILY_NAMES = ("model_config", "rehearsal_model", "weight_bytes_per_chip",
+                "kv_bytes_per_token", "decode_step_bytes_per_chip")
+
+
+def load_family(family: str):
+    """What the harness knows of a model family
+    (``families/<family>.py``): ``model_config``, ``rehearsal_model`` and
+    the byte counts.  A name a family's file lacks is an error here, not
+    in the middle of a run."""
+    mod = load_module("families", family)
+    missing = [n for n in FAMILY_NAMES if not callable(getattr(mod, n, None))]
+    if missing:
+        raise ManifestError(f"families/{family}.py has no {missing}; a "
+                            f"family gives {list(FAMILY_NAMES)}")
+    return mod
 
 
 def load_callable(spec: str, package: str):

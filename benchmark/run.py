@@ -125,9 +125,11 @@ def main(argv=None) -> int:
     import correct
     import drive
     import e2e
+    import hoststalls
     import tracing
     from trafficgen import expand_grid
 
+    sample = correct.sample_sizes(config)
     served = cluster.build(config, args.seed, args.rehearse, devices)
     try:
         lengths = sorted({it["tokens"] for it in expand_grid(mix)})
@@ -144,7 +146,7 @@ def main(argv=None) -> int:
             t_c = time.perf_counter()
             stat = correct.engine_statistic(
                 served.engine(name), e["family"], e["model"], args.seed,
-                ref_device=ref_device)
+                ref_device=ref_device, sample=sample)
             limit = limits.get(name)
             tier_ok = stat["finite"] and not stat["narrow"] and (
                 args.rehearse or (limit is not None
@@ -181,7 +183,8 @@ def main(argv=None) -> int:
                 time.sleep(span)
                 traced["host_hi"] = time.perf_counter()
 
-        run.run(in_window)
+        with hoststalls.recording() as stalls:
+            run.run(in_window)
         t0 = run.t0
         setup_s = t0 - T_PROCESS
         stats_after = served.get_json("/stats?timeline=1")
@@ -217,6 +220,9 @@ def main(argv=None) -> int:
         say("window", f"engine programs minted since warm-up: {n_programs}; "
                       f"XLA compilations or cache loads: {n_compiles} "
                       f"{compiles.names[len(compiles.names) - n_compiles:] if n_compiles else ''}")
+        for who, line in hoststalls.summary(stalls, t0, args.seconds,
+                                            WALL_OFFSET).items():
+            say("host", f"{who}: {line}")
         # Client against server, per request: the client can only be later.
         worst = min((e2e.ttft_ms(r) - r["server"]["ttft_ms"]
                      for r in due if r["ok"] and r["server"]

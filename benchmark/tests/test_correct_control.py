@@ -22,7 +22,7 @@ def test_int8_control_reads_worse_than_bf16_and_repeats():
     with open(os.path.join(HERE, "configs", "smollm2-1.7b.json")) as f:
         config = json.load(f)
     e = cluster.tier_entries(config, rehearsal=True)["nano"]
-    cfg = cluster.model_config(e["preset"], e["model"])
+    cfg = cluster.program_config(e)
     MODEL_PRESETS[e["preset"]] = cfg
     kw = dict(e["tier"], prefill_buckets=tuple(e["tier"]["prefill_buckets"]))
     tier = TierConfig(name="nano", model_preset=e["preset"], **kw)
@@ -59,7 +59,7 @@ def test_storage_under_16_bits_is_found_in_each_int8_control():
     with open(os.path.join(HERE, "configs", "smollm2-1.7b.json")) as f:
         config = json.load(f)
     e = cluster.tier_entries(config, rehearsal=True)["nano"]
-    cfg = cluster.model_config(e["preset"], e["model"])
+    cfg = cluster.program_config(e)
     MODEL_PRESETS[e["preset"]] = cfg
     kw = dict(e["tier"], prefill_buckets=tuple(e["tier"]["prefill_buckets"]))
     tier = TierConfig(name="nano", model_preset=e["preset"], **kw)

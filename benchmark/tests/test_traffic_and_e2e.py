@@ -55,6 +55,17 @@ def test_tokens_per_s_counts_stamps_inside_the_window_only():
     assert e2e.compute(spec, records, t0, seconds, 0.0) == 3 / 40.0
 
 
+def test_latency_is_sent_to_last_token_and_its_median_ignores_a_stalled_few():
+    # 9 replies of 1.4 s and one that a 5 s stall of the machine reached
+    records = [rec([k + 0.2, k + 1.4], due=float(k)) for k in range(9)]
+    records.append(rec([9.2, 15.4], due=9.0))
+    records.append(rec([], due=5.0, ok=False))       # failed: no latency
+    spec = {"kind": "percentile", "of": "latency_ms", "q": 50}
+    assert abs(e2e.compute(spec, records, 0.0, 50.0, 0.0) - 1400.0) < 1e-6
+    assert abs(e2e.latency_ms(records[9]) - 6400.0) < 1e-6
+    assert e2e.latency_ms(records[10]) is None
+
+
 def test_latencies_are_of_requests_due_in_the_window_that_finished():
     t0 = 10.0
     records = [rec([11.0, 11.5, 12.0, 12.5], due=10.5),    # in: 500 ms

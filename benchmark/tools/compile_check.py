@@ -74,7 +74,7 @@ def main() -> int:
             print(f"[compile:{name}] tp={tp}: skipped (the engine places "
                   f"its pool on its mesh when it is built)", flush=True)
             continue
-        cfg = cluster.model_config(e["preset"], e["model"])
+        cfg = cluster.program_config(e)
         MODEL_PRESETS[e["preset"]] = cfg
         kw = dict(e["tier"])
         kw["prefill_buckets"] = tuple(kw["prefill_buckets"])
