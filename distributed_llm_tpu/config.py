@@ -155,10 +155,63 @@ class ModelConfig:
     num_experts: int = 1
     moe_capacity_factor: float = 1.25
     moe_aux_weight: float = 0.01
+    # The output head is the embedding transposed; False gives the tree
+    # a "head" [V, H] of its own (models/transformer.logits_from_hidden
+    # serves whichever the tree holds).
+    tie_embeddings: bool = True
+    # -- The latent-attention, routed-expert, multi-stream family
+    # (models/latent_moe.py; ``kv_lora_rank > 0`` selects it).  Every
+    # default is the dense family's, so older presets are unchanged.
+    # Latent attention: queries through a rank-``q_lora_rank``
+    # bottleneck, keys and values up-projected from one cached row of
+    # ``kv_lora_rank`` latent numbers + ``qk_rope_head_dim`` rotary ones
+    # shared by all heads; a head is ``qk_nope_head_dim`` +
+    # ``qk_rope_head_dim`` wide for scores and ``v_head_dim`` for values.
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # YaRN (the DeepSeek-V3 reading): factor 1 = plain rotary.
+    rope_factor: float = 1.0
+    rope_original_max_pos: int = 0
+    rope_beta_fast: float = 32.0
+    rope_beta_slow: float = 1.0
+    rope_mscale: float = 1.0
+    rope_mscale_all_dim: float = 0.0
+    # The first ``dense_lead_layers`` of ``num_layers`` carry a dense FFN
+    # of ``ffn_size``; the rest route ``experts_per_token`` of
+    # ``num_experts`` experts of ``moe_ffn_size`` a token, dropless, and
+    # add ``shared_experts`` of that width for every token.
+    dense_lead_layers: int = 0
+    moe_ffn_size: int = 0
+    experts_per_token: int = 2
+    shared_experts: int = 0
+    router_scale: float = 1.0
+    # Hyper-connections: the residual is ``residual_streams`` copies of
+    # the hidden width, mixed by a doubly-stochastic map (Sinkhorn).
+    residual_streams: int = 1
+    hc_sinkhorn_iters: int = 20
+    hc_eps: float = 1e-6
+    hc_clamp: float = 30.0
 
     @property
     def head_dim(self) -> int:
         return self.hidden_size // self.num_heads
+
+    @property
+    def latent(self) -> bool:
+        """The family of models/latent_moe.py."""
+        return self.kv_lora_rank > 0
+
+    @property
+    def cache_row_width(self) -> int:
+        """Numbers one position keeps in one array of the paged pool, a
+        layer: K (and V) rows of all kv heads, or the one head-less
+        latent row."""
+        if self.latent:
+            return self.kv_lora_rank + self.qk_rope_head_dim
+        return self.num_kv_heads * self.head_dim
 
     def param_count(self) -> int:
         """Approximate parameter count (embeddings counted once, tied head)."""
@@ -217,6 +270,19 @@ MODEL_PRESETS: Dict[str, ModelConfig] = {
     "moe_8x1b": ModelConfig(
         name="moe_8x1b", hidden_size=2048, num_layers=16, num_heads=32,
         num_kv_heads=8, ffn_size=8192, max_seq_len=8192, num_experts=8,
+    ),
+    # The latent-attention / routed-expert / multi-stream family at unit
+    # -test size (models/latent_moe.py): 1 dense + 2 expert layers.
+    "latent_test": ModelConfig(
+        name="latent_test", tokenizer="byte", vocab_size=512,
+        hidden_size=64, num_layers=3, num_heads=4, num_kv_heads=4,
+        ffn_size=128, max_seq_len=256, tie_embeddings=False,
+        q_lora_rank=32, kv_lora_rank=24, qk_nope_head_dim=16,
+        qk_rope_head_dim=8, v_head_dim=16, rope_factor=4.0,
+        rope_original_max_pos=64, rope_mscale=1.0, rope_mscale_all_dim=1.0,
+        dense_lead_layers=1, num_experts=8, moe_ffn_size=32,
+        experts_per_token=2, shared_experts=1, router_scale=2.0,
+        residual_streams=4,
     ),
     "orin_test": ModelConfig(
         name="orin_test", hidden_size=128, num_layers=2, num_heads=8,

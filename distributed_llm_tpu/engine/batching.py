@@ -230,6 +230,48 @@ class _Prefill:
     t_start: float = dataclasses.field(default_factory=time.perf_counter)
 
 
+class _ExpertLoad:
+    """Assignments of tokens to routed experts, summed over an engine's
+    life by stage (``decode`` steps, ``prefill`` chunks): a count a
+    (layer, expert), and how many (step, layer, expert) cells had at
+    least one token — the experts a step had to read.  Every row the
+    program computed counts, an idle slot's and a chunk's padding too:
+    the device read their experts all the same.  Scheduler-thread writes
+    only; ``stats`` reads whole arrays under the GIL."""
+
+    STAGES = ("decode", "prefill")
+
+    def __init__(self, tier_name: str, n_layers: int, n_experts: int):
+        self.tier_name = tier_name
+        self.tokens = {s: np.zeros((n_layers, n_experts), np.int64)
+                       for s in self.STAGES}
+        self.steps = dict.fromkeys(self.STAGES, 0)
+        self.touched = dict.fromkeys(self.STAGES, 0)
+
+    def note(self, stage: str, counts) -> None:
+        """``counts`` [steps, layers, experts] as fetched."""
+        counts = np.asarray(counts)
+        touched = int(np.count_nonzero(counts))
+        per_expert = counts.sum(axis=0)
+        self.tokens[stage] += per_expert
+        self.steps[stage] += counts.shape[0]
+        self.touched[stage] += touched
+        try:
+            from ..obs import get_observability
+            m = get_observability().m
+            m.moe_assignments.labels(self.tier_name, stage).inc(
+                int(per_expert.sum()))
+            m.moe_experts_touched.labels(self.tier_name, stage).inc(touched)
+        except Exception:
+            pass
+
+    def stats(self) -> Dict[str, Any]:
+        return {"expert_tokens": {s: self.tokens[s].tolist()
+                                  for s in self.STAGES},
+                "steps": dict(self.steps),
+                "experts_touched": dict(self.touched)}
+
+
 class ContinuousBatchingEngine:
     """Drop-in for InferenceEngine (same generate()/warmup() surface) with
     a shared batched decode loop behind it.  Built by EngineManager when
@@ -254,6 +296,26 @@ class ContinuousBatchingEngine:
         # Under a mesh, "auto" stays on the GSPMD-partitionable XLA path
         # (upgrade_attention_impl only opts unsharded engines into Pallas).
         self.cfg = upgrade_attention_impl(tier.model(), mesh)
+        if self.cfg.latent:
+            # What the latent-attention family (models/latent_moe.py)
+            # does not run yet is refused here, by name, rather than run
+            # wrong: its pool has no heads to quantize by or shard on,
+            # and no draft or spill path was written for its rows.
+            from ..config_registry import env_int
+            unsupported = {
+                "kv_quantize='int8'": tier.kv_quantize != "none",
+                "a tensor-parallel mesh (tp > 1)": mesh is not None,
+                "draft_preset (speculative decoding)":
+                    bool(tier.draft_preset),
+                "host_kv_bytes (KV spill)": env_int(
+                    "DLLM_HOST_KV_BYTES", int(tier.host_kv_bytes or 0)) > 0,
+            }
+            bad = [what for what, on in unsupported.items() if on]
+            if bad:
+                raise ValueError(
+                    f"tier {tier.name}: model {self.cfg.name} is of the "
+                    f"latent-attention family, which does not support "
+                    f"{', '.join(bad)}")
         bad = [b for b in tier.prefill_buckets if b % tier.kv_block_size]
         if bad:
             raise ValueError(
@@ -307,6 +369,13 @@ class ContinuousBatchingEngine:
         # compile-churn surface ISSUE 6 bounds: logged on growth and
         # mirrored to the dllm_compiled_programs gauge.
         self._compiled: Dict[str, set] = {}
+        # Routed-expert load (the latent family): what the tick and the
+        # chunk program return beside their tokens, summed on the host.
+        self._moe = (_ExpertLoad(tier.name, self.cfg.num_layers
+                                 - self.cfg.dense_lead_layers,
+                                 self.cfg.num_experts)
+                     if self.cfg.latent and self.cfg.num_experts > 1
+                     else None)
         if tier.kv_pool_blocks is not None:
             # A constrained pool must still fit ONE largest-bucket prefill
             # plus a decode tick, or no request could ever admit.
@@ -331,6 +400,11 @@ class ContinuousBatchingEngine:
         # stack up on chip 0.
         own = (jax.sharding.SingleDeviceSharding(self.devices[0])
                if mesh is None and self.devices else None)
+        if params is None and self.cfg.latent:
+            # The seed is an ARGUMENT of the jitted maker: one compiled
+            # program for every seed, and nothing folded at compile time.
+            params = jax.jit(partial(models.init_params, self.cfg),
+                             out_shardings=own)(jnp.int32(seed))
         if params is None:
             if mesh is not None:
                 from ..parallel.sharding import param_shardings
@@ -675,6 +749,11 @@ class ContinuousBatchingEngine:
         compile churn dominates the tiny gather), and the whole point of
         the table is that an on-chip A/B flipping ragged_decode to
         'pallas' flips this engine to the kernel with no code change."""
+        if self.cfg.latent:
+            # The latent family attends whatever tables it is given; the
+            # windowed tick bounds its gather (no fused ragged kernel
+            # reads a head-less pool).
+            return False
         if self.mesh is not None:
             from ..parallel.tp_attention import _tp_ragged_ok
             if not _tp_ragged_ok(self.mesh, self.cfg):
@@ -827,12 +906,14 @@ class ContinuousBatchingEngine:
         def cold_prefill(params, tokens, true_len, rng, temp):
             b, s = tokens.shape
             positions = jnp.broadcast_to(jnp.arange(s)[None], (b, s))
-            hidden, (k_all, v_all) = models.serving_prefill(
+            hidden, rows = models.serving_prefill(
                 cfg, params, tokens, positions, attn=attn)
             last = hidden[jnp.arange(b), true_len - 1]
             logits = transformer.logits_from_hidden(params, last)
             first = _sample_batched(logits, rng, temp[None])[0]
-            return first, k_all[:, 0], v_all[:, 0]       # squeeze batch
+            # One array a pool array (K and V, or the latent family's
+            # one), batch squeezed: what ``write_prefill_blocks`` takes.
+            return (first,) + tuple(r[:, 0] for r in rows)
 
         fn = jax.jit(cold_prefill)
         self._prefill_fns[bucket] = fn
@@ -853,6 +934,7 @@ class ContinuousBatchingEngine:
         mesh = self.mesh
         ragged = self.ragged
         quantized = self.tier.kv_quantize == "int8"
+        moe_counts = self._moe is not None
 
         def decode_tick(params, pool, tables, pos, cur, temps, rng):
             # TP tiers: ragged ticks wrap the DISPATCHING ragged decode
@@ -874,18 +956,21 @@ class ContinuousBatchingEngine:
 
             def step(carry, _):
                 pool, pos, cur, rng = carry
-                logits, pool = decode_step_paged(cfg, params, cur, pos, pool,
-                                                 tables, attn=attn,
-                                                 ragged=ragged)
+                logits, pool, *n_exp = decode_step_paged(
+                    cfg, params, cur, pos, pool, tables, attn=attn,
+                    ragged=ragged, counts=moe_counts)
                 rng, sub = jax.random.split(rng)
                 nxt = _sample_batched(logits, sub, temps)
                 # Clamp: finished/overshooting slots keep writing into
                 # their own last cell instead of indexing past the table.
-                return (pool, jnp.minimum(pos + 1, max_pos), nxt, rng), nxt
+                return ((pool, jnp.minimum(pos + 1, max_pos), nxt, rng),
+                        (nxt, *n_exp))
 
             (pool, _, _, _), toks = jax.lax.scan(
                 step, (pool, pos, cur, rng), None, length=steps)
-            return toks, pool                      # [T, B]
+            # [T, B] tokens and, for a routed-expert model, the steps'
+            # assignments an expert [T, expert layers, E]: one fetch.
+            return (toks if moe_counts else toks[0]), pool
 
         self._decode_fn = self._pool_program(decode_tick, 1, lead=1)
         return self._decode_fn
@@ -898,15 +983,17 @@ class ContinuousBatchingEngine:
             return self._prefill_fns[key]
         self._note_compile("chunk_prefill", (bucket, window))
         cfg = self.cfg
+        moe_counts = self._moe is not None
 
         def chunk_prefill(params, pool, tokens, start, true_len, table,
                           rng, temp):
-            hidden, pool = chunk_prefill_paged(
-                cfg, params, tokens, start, true_len, pool, table, window)
+            hidden, pool, *n_exp = chunk_prefill_paged(
+                cfg, params, tokens, start, true_len, pool, table, window,
+                counts=moe_counts)
             last = hidden[0, true_len[0] - start[0] - 1]
             logits = transformer.logits_from_hidden(params, last)
             first = _sample_batched(logits[None], rng, temp[None])[0]
-            return first, pool
+            return ((first, n_exp[0][None]) if moe_counts else first), pool
 
         fn = self._pool_program(chunk_prefill, 1, lead=1)
         self._prefill_fns[key] = fn
@@ -1579,7 +1666,8 @@ class ContinuousBatchingEngine:
                             jnp.asarray([m], np.int32),
                             jnp.asarray([n], np.int32), jnp.asarray(row))
                     # dllm-lint: disable=transfer-host-sync -- sanctioned: the FIRST token must reach the host NOW (TTFT is the SLO and the value seeds the slot) — one sync per admission, never per tick
-                    first = int(jax.block_until_ready(first))
+                    first = jax.block_until_ready(first)
+                    first = int(self._chunk_result(first))
                 self.profiler.event("host_sync",
                                     site="prefill_first_token")
                 self.phases.add_work("prefill", **roofline.prefill_work(
@@ -1614,14 +1702,14 @@ class ContinuousBatchingEngine:
                 with obs_spans.span(req.trace, "prefill", bucket=bucket), \
                         self.phases.phase("prefill"), \
                         self.profiler.phase("prefill"):
-                    first, k_all, v_all = self._prefill_fn(bucket)(
+                    first, *rows = self._prefill_fn(bucket)(
                         self.params, jnp.asarray(tokens),
                         jnp.asarray([n], np.int32), rng, jnp.float32(temp))
                     # Page the prefilled bucket into this slot's blocks.
                     nb_prefill = bucket // bs
                     blk_dev = jnp.asarray(blocks[:nb_prefill], np.int32)  # dllm-lint: disable=retrace-dynamic-shape -- bounded: nb_prefill only takes values from the validated prefill bucket set (one writer program per bucket, pinned by _note_compile's "writer" stage)
                     self.pool = self._writer_fn(nb_prefill)(
-                        self.pool, blk_dev, k_all, v_all)
+                        self.pool, blk_dev, *rows)
                     if self.spec:
                         # Seed the DRAFT pool with the prompt's K/V so
                         # this slot can speculate (ISSUE 15): same
@@ -1733,14 +1821,14 @@ class ContinuousBatchingEngine:
                                 replayed_tokens=len(gen)), \
                     self.phases.phase("prefill"), \
                     self.profiler.phase("prefill"):
-                first, k_all, v_all = self._prefill_fn(bucket)(
+                first, *rows = self._prefill_fn(bucket)(
                     self.params, jnp.asarray(tokens),
                     jnp.asarray([len(seq)], np.int32), rng,
                     jnp.float32(temp))
                 nb_prefill = bucket // bs
                 blk_dev = jnp.asarray(blocks[:nb_prefill], np.int32)  # dllm-lint: disable=retrace-dynamic-shape -- bounded: nb_prefill only takes values from the validated prefill bucket set (one writer program per bucket)
                 self.pool = self._writer_fn(nb_prefill)(
-                    self.pool, blk_dev, k_all, v_all)
+                    self.pool, blk_dev, *rows)
                 if self.spec:
                     # Replay rebuilds the draft prefix too (same cold
                     # prefill shape), so a preempted speculating slot
@@ -1894,7 +1982,7 @@ class ContinuousBatchingEngine:
                         jnp.asarray(self._table_row(pf.blocks)), pf.rng,
                         jnp.float32(pf.temperature))
                     # dllm-lint: disable=transfer-host-sync -- sanctioned: the chunk IS the budgeted stall unit — its device time is exactly the TBT bound this design promises (and the histogram evidences), and the final chunk's sampled token must reach the host regardless; an async chunk would just move the same wait into the next decode tick's sync
-                    first = jax.block_until_ready(first)
+                    first = self._chunk_result(jax.block_until_ready(first))
                 chunk_ms = (time.perf_counter() - t_chunk) * 1000.0
                 from ..utils import roofline
                 self.phases.add_work("prefill", **roofline.prefill_work(
@@ -2839,6 +2927,10 @@ class ContinuousBatchingEngine:
                                 pos_dev, cur_dev, temps_dev, rng)
                         with self.profiler.phase("fetch"):
                             toks = _fetch_tick(toks)           # [T, B]
+                    if self._moe is not None:
+                        # The same fetch brought the steps' assignments
+                        # an expert: counted in ``account``.
+                        toks, n_exp = toks
                 tick_ms = (time.perf_counter() - t_tick) * 1000.0
                 # Everything between the fetch and the emit, with the
                 # device idle: ``account``.  What it holds is priced in
@@ -2846,6 +2938,8 @@ class ContinuousBatchingEngine:
                 # lookups and float adds.
                 with self.profiler.phase("account"):
                     self._account_tick(active, tick_ms, wb, spec_gb)
+                    if self._moe is not None and not spec_tick:
+                        self._moe.note("decode", n_exp)
             except BaseException as exc:
                 # A dead tick must not become a dead scheduler: fail the
                 # in-flight requests and keep serving new ones.
@@ -3305,6 +3399,21 @@ class ContinuousBatchingEngine:
 
         return {"n": len(ticks), "p50_ms": pct(0.5), "p95_ms": pct(0.95)}
 
+    def moe_stats(self) -> Optional[Dict[str, Any]]:
+        """Cumulative routed-expert load (GET /stats ``moe``), or None
+        for a model without routed experts on this path."""
+        return self._moe.stats() if self._moe is not None else None
+
+    def _chunk_result(self, out):
+        """The sampled token of a chunk program's (already synced) first
+        output; a routed-expert model's chunk brings its assignments an
+        expert in the same output, counted here."""
+        if self._moe is None:
+            return out
+        first, n_exp = out
+        self._moe.note("prefill", n_exp)
+        return first
+
     def slot_stats(self) -> Dict[str, Any]:
         """Live occupancy snapshot for health()/telemetry: queued
         requests, busy batch slots, and occupancy in [0,1].  Read from
@@ -3494,7 +3603,7 @@ class ContinuousBatchingEngine:
                     self.pool = self._cow_copy_fn()(
                         self.pool, jnp.asarray(blks[0], jnp.int32),
                         jnp.asarray(blks[1], jnp.int32))
-                    jax.block_until_ready(self.pool["k"])
+                    jax.block_until_ready(self.pool)
                 finally:
                     # A warmup compile failure must not strand the pair
                     # for the engine's whole lifetime.

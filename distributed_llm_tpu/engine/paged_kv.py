@@ -5,9 +5,12 @@ context per server process (SURVEY.md §2.1); a continuous-batching engine
 needs many sequences of very different lengths resident at once, so the
 TPU-native design is vLLM-style paging adapted to XLA's static shapes:
 
-- One HBM **pool** per tier, ``[L, num_blocks, block_size, N_kv * D]``:
-  token-major, a block a contiguous run of tokens and a token its kv
-  heads side by side on ONE axis.  That is the order the step programs
+- One HBM **pool** per tier, arrays of ROWS ``[L, num_blocks,
+  block_size, cfg.cache_row_width]``: token-major, a block a contiguous
+  run of tokens and a token's row whatever the model caches of it — its
+  kv heads side by side on ONE axis (``N_kv * D``, one array for K and
+  one for V), or the latent family's one head-less row (``"c"``, the
+  only array: models/latent_moe.py).  That is the order the step programs
   below use inside their layer loop (a row write and the table gather
   both move whole tokens), and with the heads merged the last axis fills
   the chip's 128 lanes at any head_dim — so the device's DEFAULT layout
@@ -39,7 +42,11 @@ TPU-native design is vLLM-style paging adapted to XLA's static shapes:
   varies at runtime only through ``pos`` and the table contents.
 
 The transformer math (RMSNorm/RoPE/GQA/SwiGLU) is imported from
-models/transformer.py — this module only changes where K/V live.
+models/transformer.py — this module only changes where the rows live.
+The latent family's layer is models/latent_moe.py's; the three step
+functions hand it the cells to write and the tables to read, and the
+block programs (``copy_block``, ``gather_blocks``, ``scatter_blocks``)
+work on whatever arrays the pool has.
 """
 
 from __future__ import annotations
@@ -52,11 +59,12 @@ import jax
 import jax.numpy as jnp
 
 from ..config import ModelConfig
-from ..models import transformer
+from ..models import latent_moe, transformer
 from ..ops import attention, quant
 
 KVPool = Dict[str, jax.Array]    # {"k","v": [L, NB, bs, N_kv * D]}
-# int8 pools add {"ks","vs": [L, NB, bs, N_kv]} per-row dequant scales.
+# int8 pools add {"ks","vs": [L, NB, bs, N_kv]} per-row dequant scales;
+# the latent family's pool is {"c": [L, NB, bs, kv_lora + rope]} alone.
 
 TRASH_BLOCK = 0
 
@@ -96,7 +104,14 @@ def init_pool(cfg: ModelConfig, pcfg: PagedConfig,
     context × batch).  Writes quantize, reads dequantize at the attention
     op (ops/attention.py paged paths)."""
     rows = (cfg.num_layers, pcfg.num_blocks, pcfg.block_size)
-    shape = rows + (cfg.num_kv_heads * cfg.head_dim,)
+    shape = rows + (cfg.cache_row_width,)
+    if cfg.latent:
+        if kv_quantize != "none":
+            raise ValueError(
+                f"kv_quantize={kv_quantize!r}: the latent-attention family "
+                f"({cfg.name}) has no int8 pool — its one cached row a "
+                f"token is not rows by heads; use 'none'")
+        return {"c": jnp.zeros(shape, jnp.dtype(cfg.dtype))}
     if kv_quantize == "int8":
         scales = rows + (cfg.num_kv_heads,)
         return {"k": jnp.zeros(shape, jnp.int8),
@@ -219,18 +234,21 @@ class BlockAllocator:
 
 
 def write_prefill_blocks(pool: KVPool, blocks: jax.Array,
-                         k_all: jax.Array, v_all: jax.Array) -> KVPool:
-    """Scatter a prefilled prompt's K/V into its allocated blocks.
+                         *rows_all: jax.Array) -> KVPool:
+    """Scatter a prefilled prompt's rows into its allocated blocks.
 
-    blocks: [nb] pool block ids; k_all/v_all: [L, S, N_kv, D] with
-    S == nb * block_size (bucketed prompts divide evenly).
+    blocks: [nb] pool block ids; ``rows_all``: what the cold prefill
+    returned, one array a pool array — K and V ``[L, S, N_kv, D]``, or
+    the latent family's ``[L, S, R]`` alone — with S == nb * block_size
+    (bucketed prompts divide evenly).
     """
-    l, s, nkv, d = k_all.shape
+    l, s = rows_all[0].shape[:2]
     nb = blocks.shape[0]
     bs = s // nb
-    # [L, S, N_kv, D] -> [L, nb, bs, N_kv(, D)]: already token-major.
-    rows = {"k": k_all.reshape(l, nb, bs, nkv, d),
-            "v": v_all.reshape(l, nb, bs, nkv, d)}
+    # [L, S, ...] -> [L, nb, bs, ...]: already token-major.
+    rows = {key: r.reshape(l, nb, bs, *r.shape[2:])
+            for key, r in zip(("c",) if "c" in pool else ("k", "v"),
+                              rows_all)}
     if "ks" in pool:                       # int8 pool: quantize on write
         rows["k"], rows["ks"] = quantize_kv_rows(rows["k"])
         rows["v"], rows["vs"] = quantize_kv_rows(rows["v"])
@@ -239,7 +257,7 @@ def write_prefill_blocks(pool: KVPool, blocks: jax.Array,
 
 
 def copy_block(pool: KVPool, src: jax.Array, dst: jax.Array) -> KVPool:
-    """Copy one pool block's K/V (and int8 scales) from ``src`` to
+    """Copy one pool block's rows (and int8 scales) from ``src`` to
     ``dst`` — the copy-on-write boundary step of shared-prefix KV
     (engine/prefix_cache.py): a slot joining a shared prefix whose
     matched length ends mid-block gets a PRIVATE copy of that partial
@@ -258,8 +276,8 @@ def copy_block(pool: KVPool, src: jax.Array, dst: jax.Array) -> KVPool:
 
 
 def gather_blocks(pool: KVPool, blocks: jax.Array) -> KVPool:
-    """Snapshot ``blocks``' K/V tiles (and int8 scales) out of the pool:
-    ``[L, nb, bs, N_kv * D]`` — the DEMOTE copy of the hierarchical KV
+    """Snapshot ``blocks``' tiles of rows (and int8 scales) out of the
+    pool: ``[L, nb, bs, row width]`` — the DEMOTE copy of the hierarchical KV
     spill tier (engine/kv_spill.py).  The output is a fresh functional
     array that owns its data, so the source blocks may return to the
     free list the moment this gather is *issued*: later pool writes
@@ -272,7 +290,7 @@ def gather_blocks(pool: KVPool, blocks: jax.Array) -> KVPool:
 
 def scatter_blocks(pool: KVPool, blocks: jax.Array,
                    tiles: KVPool) -> KVPool:
-    """Write previously gathered ``[L, nb, bs, N_kv * D]`` tiles back
+    """Write previously gathered ``[L, nb, bs, row width]`` tiles back
     into ``blocks`` — the PROMOTE copy of the hierarchical KV spill
     tier.  The exact inverse of ``gather_blocks`` (bit-identical round
     trip, int8 scales included), so a promoted prefix serves decode
@@ -282,10 +300,13 @@ def scatter_blocks(pool: KVPool, blocks: jax.Array,
 
 def pool_block_bytes(cfg: ModelConfig, block_size: int,
                      kv_quantize: str = "none") -> int:
-    """Host bytes one pool block costs when spilled (k + v tiles, plus
-    int8 scales) — the unit ``TierConfig.host_kv_bytes`` budgets in.
+    """Host bytes one pool block costs when spilled (its tiles of rows,
+    plus int8 scales) — the unit ``TierConfig.host_kv_bytes`` budgets in.
     Shared by the engine's spill accounting and the bench's budget
     sizing so the two can never drift."""
+    if cfg.latent:
+        return (cfg.num_layers * block_size * cfg.cache_row_width
+                * jnp.dtype(cfg.dtype).itemsize)
     d = cfg.head_dim
     per_row = cfg.num_layers * cfg.num_kv_heads * block_size
     if kv_quantize == "int8":
@@ -296,6 +317,10 @@ def pool_block_bytes(cfg: ModelConfig, block_size: int,
 
 
 _POOL_KEYS = ("k", "v", "ks", "vs")
+
+
+def _block_size(pool: KVPool) -> int:
+    return next(iter(pool.values())).shape[2]
 
 
 def _write_rows(pools, i, blk, off, k, v):
@@ -343,7 +368,7 @@ def _scan_layers(layer, x, layers, pool: KVPool):
     def body(carry, scanned):
         return layer(*carry, *scanned), None
 
-    n_layers = pool["k"].shape[0]
+    n_layers = pool[keys[0]].shape[0]
     (x, pools), _ = jax.lax.scan(
         body, (x, tuple(pool[key] for key in keys)),
         (layers, jnp.arange(n_layers)))
@@ -359,28 +384,36 @@ def chunk_prefill_paged(
     pool: KVPool,
     table: jax.Array,          # [MB] the slot's block-table row
     window: int,               # static: attended positions, multiple of bs
+    counts: bool = False,      # latent family: also return expert counts
 ) -> Tuple[jax.Array, KVPool]:
     """Prefill a prompt SUFFIX directly into pool blocks — the paged twin
     of ``transformer.chunk_prefill``, enabling session prefix reuse in the
     continuous-batching engine: a reclaimed entry's blocks become the
     slot's leading table rows and only the new turn runs here.
 
-    Returns (hidden [1, S_c, H], updated pool).  The chunk's K/V scatter to
-    (table[p//bs], p%bs) per position; attention gathers the first
+    Returns (hidden [1, S_c, H], updated pool).  The chunk's rows scatter
+    to (table[p//bs], p%bs) per position; attention gathers the first
     window//bs table blocks, so cost is O(window), not O(max_seq).
+    ``counts`` (the latent family's engine programs): a third result,
+    the chunk's assignments an expert ``[expert layers, num_experts]``.
     """
     b, s_c = tokens.shape
     d = cfg.head_dim
-    bs = pool["k"].shape[2]
+    bs = _block_size(pool)
 
-    x = quant.embed_rows(params["embed"], tokens)            # [1, S_c, H]
     positions = start[:, None] + jnp.arange(s_c)[None, :]    # [1, S_c]
     q_pos = jnp.minimum(positions, jnp.maximum(true_len, 1)[:, None] - 1)
-    sin, cos = transformer.rope_sincos(positions, d, cfg.rope_theta)
-
     flat_pos = positions[0]                                  # [S_c]
     blk = table[flat_pos // bs]                              # [S_c]
     off = flat_pos % bs
+    if cfg.latent:
+        hidden, new_pool, n_exp = latent_moe.forward_paged(
+            cfg, params, tokens, positions, q_pos, pool, blk[None],
+            off[None], table[None, :window // bs])
+        return (hidden, new_pool, n_exp) if counts else (hidden, new_pool)
+
+    x = quant.embed_rows(params["embed"], tokens)            # [1, S_c, H]
+    sin, cos = transformer.rope_sincos(positions, d, cfg.rope_theta)
 
     def layer(x, pools, lp, i):
         h_in = transformer.rms_norm(x, lp["ln1"], cfg.norm_eps)
@@ -446,9 +479,13 @@ def verify_step_paged(
     Positions past ``max_seq_len`` (a slot finishing at the context
     edge mid-chunk) scatter into the trash block instead of clamping
     onto live KV."""
+    if cfg.latent:
+        raise NotImplementedError(
+            f"{cfg.name}: the latent-attention family has no speculative "
+            f"verify step (a draft model is refused at engine build)")
     b, g = tokens.shape
     d = cfg.head_dim
-    bs = pool["k"].shape[2]
+    bs = _block_size(pool)
     max_pos = cfg.max_seq_len - 1
 
     x = quant.embed_rows(params["embed"], tokens)      # [B, G, H]
@@ -514,6 +551,7 @@ def decode_step_paged(
     tables: jax.Array,         # [B, MB] block ids per slot
     attn=None,                 # (q, kp, vp, tables, pos, ks, vs) override
     ragged: bool = False,      # fused ragged decode over FULL tables
+    counts: bool = False,      # latent family: also return expert counts
 ) -> Tuple[jax.Array, KVPool]:
     """One batched autoregressive step over paged caches.
 
@@ -529,16 +567,26 @@ def decode_step_paged(
     one fused ``attention.ragged_decode`` call with true per-slot
     lengths — the Pallas kernel streams each slot's own frontier, so the
     padding costs nothing and one compiled step serves every width.
+    The latent family attends the tables it is given under either
+    contract (masked by ``pos``), in the absorbed form; ``counts`` adds
+    a third result, the step's assignments an expert ``[expert layers,
+    num_experts]``.
     """
     b = token.shape[0]
     d = cfg.head_dim
-    bs = pool["k"].shape[2]
-
-    x = quant.embed_rows(params["embed"], token)       # [B, H]
-    sin, cos = transformer.rope_sincos(pos, d, cfg.rope_theta)
+    bs = _block_size(pool)
 
     blk = jnp.take_along_axis(tables, (pos // bs)[:, None], axis=1)[:, 0]
     off = pos % bs                                     # [B]
+    if cfg.latent:
+        hidden, new_pool, n_exp = latent_moe.forward_paged(
+            cfg, params, token[:, None], pos[:, None], pos[:, None], pool,
+            blk[:, None], off[:, None], tables)
+        logits = transformer.logits_from_hidden(params, hidden[:, 0])
+        return (logits, new_pool, n_exp) if counts else (logits, new_pool)
+
+    x = quant.embed_rows(params["embed"], token)       # [B, H]
+    sin, cos = transformer.rope_sincos(pos, d, cfg.rope_theta)
     attn_op = attention.ragged_decode if ragged else attention.paged_decode
 
     def layer(x, pools, lp, i):

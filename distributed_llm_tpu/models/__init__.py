@@ -1,4 +1,6 @@
-"""Model families: dense LLaMA-style (transformer.py) and MoE (moe.py).
+"""Model families: dense LLaMA-style (transformer.py), MoE (moe.py) and
+the latent-attention, routed-expert, multi-stream family (latent_moe.py,
+``cfg.latent``; it serves through the paged pool only).
 
 ``model_module(cfg)`` dispatches on ModelConfig.num_experts so the engine,
 trainer, and checkpoint code serve either family through one surface:
@@ -11,17 +13,22 @@ and are shared.
 from __future__ import annotations
 
 from ..config import ModelConfig
-from . import moe, transformer  # noqa: F401
+from . import latent_moe, moe, transformer  # noqa: F401
 
 
 def model_module(cfg: ModelConfig):
+    if cfg.latent:
+        return latent_moe
     return moe if cfg.num_experts > 1 else transformer
 
 
 def serving_prefill(cfg: ModelConfig, params, tokens, positions, attn=None):
-    """(hidden, (k_all, v_all)) for either family (drops MoE aux loss).
+    """(hidden, (k_all, v_all)) for either family (drops MoE aux loss);
+    the latent family gives (hidden, (rows,)), one array a pool array.
     ``attn`` (dense only): attention-op override — see transformer.prefill."""
-    if cfg.num_experts > 1:
+    if cfg.latent:
+        out = latent_moe.prefill(cfg, params, tokens, positions)
+    elif cfg.num_experts > 1:
         out = moe.prefill(cfg, params, tokens, positions)
     else:
         out = transformer.prefill(cfg, params, tokens, positions, attn=attn)

@@ -140,8 +140,10 @@ def prefill(cfg: ModelConfig, params: Params, tokens: jax.Array,
 
 
 def logits_from_hidden(params: Params, hidden: jax.Array) -> jax.Array:
-    """Tied LM head: [..., H] -> [..., V] in float32."""
-    return quant.tied_head(params["embed"], hidden)
+    """LM head: [..., H] -> [..., V] in float32.  The tree's own "head"
+    [V, H] where it holds one (``ModelConfig.tie_embeddings`` False),
+    else the embedding, tied."""
+    return quant.tied_head(params.get("head", params["embed"]), hidden)
 
 
 # =============================================================================

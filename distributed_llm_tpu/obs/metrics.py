@@ -391,6 +391,20 @@ METRIC_REGISTRY: Tuple[Tuple[str, str, str, Tuple[str, ...], str], ...] = (
      "Device time of one interleaved prefill chunk — the upper "
      "bound a chunked admission adds to active streams' "
      "time-between-tokens per tick"),
+    # Routed-expert family (models/latent_moe.py): what the tick and
+    # the chunk program count beside their tokens — how many expert
+    # assignments a stage computed, and how many experts those touched
+    # (an expert touched in a step is an expert's weights read).
+    ("moe_assignments", "counter", "dllm_moe_assignments_total",
+     ("tier", "stage"),
+     "Token-to-expert assignments computed, by stage (decode|prefill): "
+     "rows x experts_per_token x expert layers, idle slots and chunk "
+     "padding included"),
+    ("moe_experts_touched", "counter", "dllm_moe_experts_touched_total",
+     ("tier", "stage"),
+     "Routed experts with at least one token, summed over expert layers "
+     "and over steps (decode) or chunks (prefill): the experts whose "
+     "weights a step had to read"),
     # Batched-speculation family (ISSUE 15): drafted vs accepted
     # draft tokens per tier (the counter pair whose ratio IS the
     # realized acceptance rate) and the engine's running acceptance

@@ -129,7 +129,11 @@ def tied_head(embed: Any, hidden: jax.Array) -> jax.Array:
 
 # Leaves quantized in a transformer params tree; norms stay full precision
 # (tiny, and rsqrt precision matters).
-_QUANT_LAYER_KEYS = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down")
+_QUANT_LAYER_KEYS = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down",
+                     # the latent family's (models/latent_moe.py): its
+                     # attention projections, shared and routed experts
+                     "w_qa", "w_qb", "w_kva", "w_kvb", "ws_gate", "ws_up",
+                     "ws_down", "we_gate", "we_up", "we_down")
 
 
 def maybe_quantize(params: Dict[str, Any], tier, cfg,
@@ -168,9 +172,15 @@ def quantize_params(params: Dict[str, Any]) -> Dict[str, Any]:
     if not is_quantized(params["embed"]):
         # Per-ROW scales for the embedding table (see embed_rows/tied_head).
         out["embed"] = quantize_tensor(params["embed"], contract_axis=-1)
-    layers = dict(params["layers"])
-    for k in _QUANT_LAYER_KEYS:
-        if k in layers and not is_quantized(layers[k]):
-            layers[k] = quantize_tensor(layers[k])
-    out["layers"] = layers
+    if "head" in params and not is_quantized(params["head"]):
+        out["head"] = quantize_tensor(params["head"], contract_axis=-1)
+    # "lead": the latent family's dense lead layers, a second stack.
+    for group in ("layers", "lead"):
+        if group not in params:
+            continue
+        layers = dict(params[group])
+        for k in _QUANT_LAYER_KEYS:
+            if k in layers and not is_quantized(layers[k]):
+                layers[k] = quantize_tensor(layers[k])
+        out[group] = layers
     return out

@@ -59,36 +59,68 @@ def chip_peaks(device=None) -> Optional[Dict[str, Any]]:
             f"utils/roofline.CHIP_PEAKS with its source") from None
 
 
-def active_matmul_params(cfg) -> int:
-    """Matmul params touched per token: attention + active FFN experts
-    (top-2 routing for MoE) + the tied LM head.  Embedding lookup is a
-    gather, not a matmul."""
-    h, f, l = cfg.hidden_size, cfg.ffn_size, cfg.num_layers
+def _attention_params(cfg) -> int:
+    """One layer's attention matrices."""
+    h = cfg.hidden_size
+    if cfg.latent:
+        nh = cfg.num_heads
+        dn, dr, dv = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
+                      cfg.v_head_dim)
+        return (h * cfg.q_lora_rank + cfg.q_lora_rank * nh * (dn + dr)
+                + h * cfg.cache_row_width + cfg.kv_lora_rank * nh * (dn + dv)
+                + nh * dv * h)
     kv = cfg.num_kv_heads * cfg.head_dim
-    attn = h * h + 2 * h * kv + h * h
-    ffn = 3 * h * f
-    if cfg.num_experts > 1:
-        ffn *= 2                       # top-2 of E experts per token
-    return l * (attn + ffn) + cfg.vocab_size * h
+    return h * h + 2 * h * kv + h * h
+
+
+def _layer_counts(cfg):
+    """(dense layers, expert layers)."""
+    if cfg.num_experts <= 1:
+        return cfg.num_layers, 0
+    return cfg.dense_lead_layers, cfg.num_layers - cfg.dense_lead_layers
+
+
+def active_matmul_params(cfg) -> int:
+    """Matmul params touched per token: attention + the FFN it goes
+    through — a dense one, or ``cfg.experts_per_token`` routed experts
+    and ``cfg.shared_experts`` shared ones of the expert width, with the
+    router — + the LM head.  Embedding lookup is a gather, not a
+    matmul."""
+    h = cfg.hidden_size
+    dense, moe = _layer_counts(cfg)
+    expert = 3 * h * (cfg.moe_ffn_size or cfg.ffn_size)
+    routed = (cfg.experts_per_token + cfg.shared_experts) * expert
+    if cfg.latent:
+        routed += h * cfg.num_experts           # the float32 router
+    return (cfg.num_layers * _attention_params(cfg)
+            + dense * 3 * h * cfg.ffn_size + moe * routed
+            + cfg.vocab_size * h)
 
 
 def weight_bytes(cfg, quantize: str = "none") -> int:
     """Resident weight bytes streamed by one decode step.  For MoE this is
-    the FULL expert set: the dense-dispatch einsum reads every expert's
-    weights regardless of routing (models/moe.py)."""
-    h, f, l = cfg.hidden_size, cfg.ffn_size, cfg.num_layers
-    kv = cfg.num_kv_heads * cfg.head_dim
-    attn = h * h + 2 * h * kv + h * h
-    ffn = 3 * h * f * max(1, cfg.num_experts)
+    the FULL set of experts held: the dense-dispatch einsum reads every
+    expert's weights regardless of routing (models/moe.py); the latent
+    family's grouped product reads only the experts a step's tokens chose
+    (models/latent_moe.py), so for it this is an upper bound."""
+    h = cfg.hidden_size
+    dense, moe = _layer_counts(cfg)
+    expert = 3 * h * (cfg.moe_ffn_size or cfg.ffn_size)
+    held = cfg.num_experts + cfg.shared_experts
     per_param = 1 if quantize == "int8" else 2
-    body = l * (attn + ffn) * per_param
+    body = (cfg.num_layers * _attention_params(cfg)
+            + dense * 3 * h * cfg.ffn_size + moe * held * expert) * per_param
     # Embedding/head + norms stay bf16 even under int8 weight-only quant.
-    return body + (cfg.vocab_size * h + (2 * l + 1) * h) * 2
+    heads = 1 if cfg.tie_embeddings else 2
+    return body + (heads * cfg.vocab_size * h
+                   + (2 * cfg.num_layers + 1) * h) * 2
 
 
 def kv_bytes_per_pos(cfg, kv_quantize: str = "none") -> int:
-    """K+V bytes per cached position: bf16, or int8 + f32 per-row scales
-    (engine/paged_kv.py)."""
+    """Cached bytes per position: K+V rows in bf16, or int8 + f32 per-row
+    scales (engine/paged_kv.py); the latent family's one row a layer."""
+    if cfg.latent:
+        return cfg.num_layers * cfg.cache_row_width * 2
     rows = 2 * cfg.num_layers * cfg.num_kv_heads
     if kv_quantize == "int8":
         return rows * (cfg.head_dim + 4)

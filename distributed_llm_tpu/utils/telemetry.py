@@ -132,6 +132,10 @@ def engine_stats(engine) -> Dict[str, Any]:
             entry["kv"] = kv_fn()
         except Exception:
             pass
+    moe_fn = getattr(engine, "moe_stats", None)
+    moe = moe_fn() if callable(moe_fn) else None
+    if moe:
+        entry["moe"] = moe
     if hasattr(engine, "acceptance_rate"):
         entry["speculative_acceptance_rate"] = round(
             engine.acceptance_rate, 4)

@@ -35,6 +35,27 @@ def test_moe_top2_flops_vs_full_weight_bytes():
     assert delta == 2 * 3 * 64 * 128 * (4 - 1) * 2   # l*3hf*(E-1)*2B
 
 
+def test_latent_family_counts_experts_per_token_and_the_shared_one():
+    cfg = MODEL_PRESETS["latent_test"]  # 1 dense + 2 expert layers, top-2
+    h, e, f = 64, 8, 32                 # of 8 experts + 1 shared, width 32
+    attn = (h * 32 + 32 * 4 * (16 + 8) + h * (24 + 8)
+            + 24 * 4 * (16 + 16) + 4 * 16 * h)
+    per_token = (3 * attn + 3 * h * 128
+                 + 2 * ((2 + 1) * 3 * h * f + h * e) + cfg.vocab_size * h)
+    assert roofline.active_matmul_params(cfg) == per_token
+    # Three experts a token instead of two moves the count by two layers'
+    # worth of one expert; nothing assumes top-2.
+    import dataclasses
+    assert (roofline.active_matmul_params(
+        dataclasses.replace(cfg, experts_per_token=3)) - per_token
+        == 2 * 3 * h * f)
+    # Held: all 8 + the shared one; an untied head beside the embedding.
+    body = 3 * attn + 3 * h * 128 + 2 * (8 + 1) * 3 * h * f
+    assert roofline.weight_bytes(cfg) == (
+        body * 2 + (2 * cfg.vocab_size * h + 7 * h) * 2)
+    assert roofline.kv_bytes_per_pos(cfg) == 3 * 32 * 2
+
+
 def test_weight_bytes_int8_halves_body_only():
     bf16 = roofline.weight_bytes(CFG, "none")
     i8 = roofline.weight_bytes(CFG, "int8")
