@@ -421,6 +421,7 @@ def phase_what_ran(served: Served) -> Dict[str, Any]:
             "engine": type(engine).__name__,
             "attention_impl": engine.cfg.attention_impl,
             "tick": "ragged fused" if engine.ragged else "dense windowed",
+            "decode_attention": engine.decode_attention_form(),
             "speculation": bool(engine.spec),
             "span": span,
             "impl_by_kind": impls,

@@ -919,6 +919,44 @@ class ContinuousBatchingEngine:
         self._prefill_fns[bucket] = fn
         return fn
 
+    def _tick_attn_hook(self, window: int):
+        """The decode tick's attention hook at a table window of
+        ``window`` tokens, or None for the dispatching op over the whole
+        pool.  TP tiers: ragged ticks wrap the DISPATCHING ragged decode
+        in shard_map over the kv-head axis (PR 16 — the fused paged path
+        runs sharded, combine is a head concat); dense ticks keep the
+        per-head-shard paged flash decode."""
+        if self.cfg.num_experts != 1:
+            return None
+        quantized = self.tier.kv_quantize == "int8"
+        if self.ragged:
+            from ..parallel.tp_attention import tp_ragged_decode_attn
+            return tp_ragged_decode_attn(self.mesh, self.cfg,
+                                         quantized=quantized)
+        from ..parallel.tp_attention import tp_paged_decode_attn
+        return tp_paged_decode_attn(self.mesh, self.cfg, window,
+                                    quantized=quantized)
+
+    def decode_attention_form(self) -> str:
+        """What this tier's decode tick attends, at its full span — a
+        label, static per tier (chip_smoke.py's what-ran lines, GET
+        /stats): ``merged`` the XLA path over the whole token-major pool
+        (``ops.attention.merged_decode_attention``: the gathered rows as
+        they rest), ``split`` a hook or a Pallas kernel over a layer's
+        head-major view, ``latent`` the latent family's own absorbed
+        attention."""
+        if self.cfg.latent:
+            return "latent"
+        from ..ops import attention as attn_ops
+        kind = (("ragged_decode" if self.ragged else "paged_decode")
+                + ("_q8" if self.tier.kv_quantize == "int8" else ""))
+        span = self.paged.blocks_per_slot * self.paged.block_size
+        if (self._tick_attn_hook(span) is not None
+                or attn_ops._choose(self.cfg.attention_impl, kind,
+                                    span) == "pallas"):
+            return "split"
+        return "merged"
+
     def _decode_step(self):
         """One compiled tick for all slots: ``decode_steps_per_tick``
         sequential decode steps inside a single device call (lax.scan), so
@@ -931,28 +969,14 @@ class ContinuousBatchingEngine:
         cfg = self.cfg
         max_pos = cfg.max_seq_len - 1
         steps = self.steps_per_tick
-        mesh = self.mesh
         ragged = self.ragged
-        quantized = self.tier.kv_quantize == "int8"
         moe_counts = self._moe is not None
 
         def decode_tick(params, pool, tables, pos, cur, temps, rng):
-            # TP tiers: ragged ticks wrap the DISPATCHING ragged decode
-            # in shard_map over the kv-head axis (PR 16 — the fused
-            # paged path runs sharded, combine is a head concat); dense
-            # ticks keep the per-head-shard paged flash decode (the
-            # window width is static per trace, so the hook resolves
-            # here).
-            attn = None
-            if cfg.num_experts == 1 and ragged:
-                from ..parallel.tp_attention import tp_ragged_decode_attn
-                attn = tp_ragged_decode_attn(mesh, cfg,
-                                             quantized=quantized)
-            elif cfg.num_experts == 1:
-                from ..parallel.tp_attention import tp_paged_decode_attn
-                attn = tp_paged_decode_attn(
-                    mesh, cfg, tables.shape[1] * self.paged.block_size,
-                    quantized=quantized)
+            # The window width is static per trace, so a TP tier's hook
+            # resolves here.
+            attn = self._tick_attn_hook(
+                tables.shape[1] * self.paged.block_size)
 
             def step(carry, _):
                 pool, pos, cur, rng = carry

@@ -604,7 +604,13 @@ def decode_step_paged(
 
         # Attend this slot's logical window: position p is
         # (table[p//bs], p%bs).  The Pallas path streams table blocks
-        # through VMEM in-kernel; the XLA path gathers them contiguous.
+        # through VMEM in-kernel; the XLA path gathers whole rows
+        # [B, S, N_kv * D] from the carried pool and contracts over the
+        # merged axis (ops.attention.merged_decode_attention): a query
+        # of one token pays N_kv times the multiplications to never
+        # split the head axis off the window, which on a TPU is a copy.
+        # The chunk and verify steps' queries are long: they keep the
+        # split ([B, S, N_kv, D]) and chunk_attention.
         with jax.named_scope("attention"):
             if attn is not None:
                 attn_out = _hooked(attn, q, pools, i, tables, pos)

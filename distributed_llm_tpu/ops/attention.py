@@ -273,7 +273,7 @@ def _layer_views(layer, head_dim, k_pool, v_pool, k_scale=None,
 
 
 def _gather_pool_seq(q, k_pool, v_pool, tables, k_scale, v_scale,
-                     layer=None):
+                     layer=None, merged=False):
     """The paged fallbacks' ONE table gather: pools + tables [B, MB] ->
     contiguous [B, S, Nkv, D] views in ``q``'s dtype (int8 pools
     dequantized through the gathered scales).  Shared by the decode
@@ -284,8 +284,18 @@ def _gather_pool_seq(q, k_pool, v_pool, tables, k_scale, v_scale,
     with a ``layer`` index, the WHOLE token-major pool ``[L, NB, bs,
     Nkv * D]`` (scales ``[L, NB, bs, Nkv]``), gathered at (layer, block)
     directly: whole blocks of whole tokens, already in the order the
-    attention wants, and no layer-sized slice in between."""
+    attention wants, and no layer-sized slice in between.
+
+    ``merged`` (token-major pool only) leaves the gathered rows as they
+    rest, ``[B, S, Nkv * D]`` with the heads side by side on the lanes,
+    an int8 pool's scale repeated over its head's ``D`` columns: what
+    ``merged_decode_attention`` contracts over.  Splitting the head axis
+    off a window-sized array is a copy on a TPU wherever ``D`` is not a
+    whole number of 128-lane rows (at 64 it is padded to twice its
+    size); the chunk and verify fallbacks still pay it, because their
+    queries are long and the merged form's zeros would be real work."""
     b, mb = tables.shape
+    d = q.shape[-1]
     if layer is None:
         def seq(pool, *_):     # [Nkv, B, MB, bs(, D)] -> [B, S, Nkv(, D)]
             blocks = jnp.moveaxis(pool[:, tables], 0, 3)
@@ -295,25 +305,37 @@ def _gather_pool_seq(q, k_pool, v_pool, tables, k_scale, v_scale,
             return pool[layer, tables].reshape(b, mb * pool.shape[2], -1,
                                                *heads)
 
+    def spread(scale):          # [B, S, Nkv] -> over the rows' columns
+        return jnp.repeat(scale, d, axis=-1) if merged else scale[..., None]
+
+    heads = () if merged else (d,)
     with jax.named_scope("kv_gather"):
-        k_seq, v_seq = seq(k_pool, q.shape[-1]), seq(v_pool, q.shape[-1])
+        k_seq, v_seq = seq(k_pool, *heads), seq(v_pool, *heads)
         if k_scale is not None:
             k_seq = (k_seq.astype(jnp.float32)
-                     * seq(k_scale)[..., None]).astype(q.dtype)
+                     * spread(seq(k_scale))).astype(q.dtype)
             v_seq = (v_seq.astype(jnp.float32)
-                     * seq(v_scale)[..., None]).astype(q.dtype)
+                     * spread(seq(v_scale))).astype(q.dtype)
     return k_seq, v_seq
 
 
 def _gather_decode_paged(q, k_pool, v_pool, tables, pos, k_scale, v_scale,
                          layer=None):
     """XLA fallback shared by ``paged_decode`` and ``ragged_decode``:
-    gather the block table into a contiguous view and reuse
-    ``decode_attention`` (portable / GSPMD-shardable; one code path so
-    the two kinds' fallbacks are byte-identical — the parity reference
-    for the Pallas kernels)."""
+    gather the block table into a contiguous view and attend it (portable
+    / GSPMD-shardable; one code path so the two kinds' fallbacks are
+    byte-identical).  The form follows the representation it is handed:
+    per-layer head-major views (``layer`` None: a hook's shard, a
+    kernel's parity test) gather to ``[B, S, Nkv, D]`` and reuse
+    ``decode_attention``, the parity reference for the Pallas kernels;
+    the WHOLE token-major pool (``layer=i``: the served tick) gathers to
+    merged rows ``[B, S, Nkv * D]`` and ``merged_decode_attention``
+    attends them as they rest, at every ``head_dim``."""
     k_seq, v_seq = _gather_pool_seq(q, k_pool, v_pool, tables,
-                                    k_scale, v_scale, layer)
+                                    k_scale, v_scale, layer,
+                                    merged=layer is not None)
+    if layer is not None:
+        return merged_decode_attention(q, k_seq, v_seq, pos)
     return decode_attention(q, k_seq, v_seq, pos)
 
 
@@ -325,7 +347,7 @@ def paged_decode(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
     (engine/paged_kv.py): q [B, Nq, D], pools [Nkv, NB, bs, D], tables
     [B, MB], pos [B] -> [B, Nq, D].  The Pallas path walks the block table
     in-kernel; the XLA path gathers the table into a contiguous view and
-    reuses ``decode_attention`` (portable / GSPMD-shardable fallback).
+    attends it (portable / GSPMD-shardable fallback).
 
     ``k_scale``/``v_scale`` ([Nkv, NB, bs]) mark an int8 pool: the Pallas
     path streams int8 blocks + scales and dequantizes in VMEM
@@ -336,7 +358,13 @@ def paged_decode(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
     are the WHOLE token-major arrays of engine/paged_kv.py ([L, NB, bs,
     Nkv * D], scales [L, NB, bs, Nkv]) and this is the traced layer to
     attend — the XLA path gathers straight from the whole pool, a kernel
-    gets the layer's head-major view (``_layer_views``)."""
+    gets the layer's head-major view (``_layer_views``).  Which form the
+    XLA path attends in follows from which of the two it was handed
+    (``_gather_decode_paged``): head-major views split by head through
+    ``decode_attention``, the whole pool's rows merged through
+    ``merged_decode_attention``.  Only the two decode ops (query length
+    1) have a merged form; ``ragged_verify`` and ``paged_chunk`` split
+    the head axis off their window whichever they are handed."""
     b, mb = tables.shape
     bs = k_pool.shape[-2]
     if k_scale is None:
@@ -569,3 +597,48 @@ def decode_attention(
 
     probs = jax.nn.softmax(logits, axis=-1).astype(v.dtype)
     return jnp.einsum("bnk,bknd->bnd", probs, v)
+
+
+def merged_decode_attention(
+    q: jax.Array,
+    k_rows: jax.Array,
+    v_rows: jax.Array,
+    pos: jax.Array,
+) -> jax.Array:
+    """``decode_attention`` over rows whose heads are MERGED on the minor
+    axis, as the token-major pool of engine/paged_kv.py keeps them.
+
+    q: [B, N_q, D]; k_rows/v_rows: [B, S, N_kv * D]; pos: [B] as in
+    ``decode_attention``.  Returns [B, N_q, D].
+
+    Both products contract against the rows as they rest, so no head
+    axis is split off a window-sized array: the query is spread
+    block-diagonally (row ``n`` holds ``q[b, n]`` at its kv head's ``D``
+    columns, zeros elsewhere), ``scores = Q · K_rows^T`` and ``full = p ·
+    V_rows`` accumulate in float32, and each query head's own ``D``
+    columns are taken from its row of ``full``.  The zeros add exactly
+    nothing, so this is ``decode_attention``'s mathematics at ``N_kv``
+    times its multiplications — on a step bound by reading the window,
+    for a query of ONE token; a chunk's or a verify step's queries are
+    many, and there the waste would be real (``chunk_attention`` keeps
+    the head split).
+    """
+    b, n_q, d = q.shape
+    n_kv = k_rows.shape[-1] // d
+    # own[n, j]: kv head j serves query head n.
+    own = (jnp.arange(n_q)[:, None] // (n_q // n_kv)
+           == jnp.arange(n_kv)[None, :])[None, :, :, None]
+    q_rows = jnp.where(own, q[:, :, None, :], 0).reshape(b, n_q, n_kv * d)
+
+    scale = d ** -0.5
+    logits = jnp.einsum("bnc,bkc->bnk", q_rows, k_rows,
+                        preferred_element_type=jnp.float32) * scale
+
+    valid = jnp.arange(k_rows.shape[1])[None, :] <= pos[:, None]  # [B, S]
+    logits = jnp.where(valid[:, None, :], logits, NEG_INF)
+
+    probs = jax.nn.softmax(logits, axis=-1).astype(v_rows.dtype)
+    full = jnp.einsum("bnk,bkc->bnc", probs, v_rows,
+                      preferred_element_type=jnp.float32)
+    out = jnp.where(own, full.reshape(b, n_q, n_kv, d), 0).sum(axis=2)
+    return out.astype(v_rows.dtype)

@@ -136,6 +136,9 @@ def engine_stats(engine) -> Dict[str, Any]:
     moe = moe_fn() if callable(moe_fn) else None
     if moe:
         entry["moe"] = moe
+    form_fn = getattr(engine, "decode_attention_form", None)
+    if callable(form_fn):
+        entry["decode_attention"] = form_fn()
     if hasattr(engine, "acceptance_rate"):
         entry["speculative_acceptance_rate"] = round(
             engine.acceptance_rate, 4)

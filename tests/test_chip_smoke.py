@@ -81,6 +81,8 @@ def test_serve_and_what_ran_on_the_tiny_cluster(capsys):
         row = what[tier]
         assert row["engine"] == "ContinuousBatchingEngine"
         assert row["tick"] == "ragged fused"
+        # ... over the whole token-major pool: rows attended merged.
+        assert row["decode_attention"] == "merged"
         assert set(row["impl_by_kind"].values()) == {"xla"}
         assert row["compiled_after_requests"]["decode"] == 1
     out = capsys.readouterr().out

@@ -300,3 +300,99 @@ def test_stale_kernel_gen_starts_clean(tmp_path):
                             kernel_gen=2)
     data = json.loads(open(out).read())
     assert set(data["dispatch"]) == {"prefill", "chunk"}
+
+
+# -- the form follows the representation (ISSUE 30) ---------------------------
+
+# bfloat16: what chip_smoke.KERNEL_TOL holds the Pallas kernels to against
+# their XLA references (2e-2: the two round at different points).
+_FORM_TOL = {"float32": 1e-5, "bfloat16": 2e-2}
+
+
+def _token_major_case(n_q, n_kv, d, dtype, int8, *, batch=4, bs=16, mb=8,
+                      layers=3, seed=0):
+    """A WHOLE token-major pool ``[L, NB, bs, Nkv * D]`` (int8 with its
+    scales ``[L, NB, bs, Nkv]``) and a batch whose slot 0 is idle (``pos``
+    0, a table of trash blocks), slot 1 attends the last column of the
+    window, the others somewhere inside."""
+    import jax
+    import jax.numpy as jnp
+
+    from distributed_llm_tpu.engine.paged_kv import TRASH_BLOCK
+    from distributed_llm_tpu.ops.quant import quantize_kv_rows
+    nb = batch * mb + 1
+    keys = jax.random.split(jax.random.PRNGKey(seed), 3)
+    q = jax.random.normal(keys[0], (batch, n_q, d), jnp.float32).astype(dtype)
+    pools = [jax.random.normal(k, (layers, nb, bs, n_kv, d), jnp.float32)
+             for k in keys[1:]]
+    scales = (None, None)
+    if int8:
+        (k, ks), (v, vs) = (quantize_kv_rows(p) for p in pools)
+        pools, scales = [k, v], (ks, vs)
+    else:
+        pools = [p.astype(dtype) for p in pools]
+    pools = [p.reshape(layers, nb, bs, n_kv * d) for p in pools]
+    tables = jnp.arange(1, nb, dtype=jnp.int32).reshape(batch, mb)
+    tables = tables.at[0].set(TRASH_BLOCK)
+    pos = jnp.array([0, mb * bs - 1, 17, 40][:batch], jnp.int32)
+    return q, pools, scales, tables, pos
+
+
+@pytest.mark.parametrize("pool", ["model-dtype-pool", "int8-pool"])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("n_q,n_kv,d", [(32, 32, 64), (32, 8, 64),
+                                        (32, 8, 128)],
+                         ids=["mha-d64", "gqa-d64", "gqa-d128"])
+def test_merged_form_agrees_with_decode_attention(table, n_q, n_kv, d, dtype,
+                                                  pool):
+    """The served tick's XLA path (``layer=i``: the whole token-major
+    pool, gathered rows attended merged) against ``decode_attention``
+    over the SAME gathered rows with the head axis split off — the
+    reference the Pallas kernels are held to as well."""
+    import jax.numpy as jnp
+    import numpy as np
+    table({})
+    q, (kp, vp), (ks, vs), tables, pos = _token_major_case(
+        n_q, n_kv, d, jnp.dtype(dtype), pool == "int8-pool")
+    layer = jnp.int32(1)
+    got = A.paged_decode(q, kp, vp, tables, pos, impl="xla", k_scale=ks,
+                         v_scale=vs, layer=layer)
+    k_seq, v_seq = A._gather_pool_seq(q, kp, vp, tables, ks, vs, layer)
+    assert k_seq.shape == (*tables.shape[:1], tables.shape[1] * kp.shape[2],
+                           n_kv, d)
+    want = A.decode_attention(q, k_seq, v_seq, pos)
+    assert got.shape == want.shape and got.dtype == want.dtype
+    tol = _FORM_TOL[dtype]
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32),
+                               atol=tol, rtol=tol)
+    # The idle slot attends one trash row: its value, whatever the rest.
+    np.testing.assert_allclose(
+        np.asarray(got[0], np.float32),
+        np.asarray(jnp.repeat(v_seq[0, 0], n_q // n_kv, axis=0), np.float32),
+        atol=tol, rtol=tol)
+    # One code path for both tick shapes: the fused ragged tick's
+    # fallback is byte-identical.
+    ragged = A.ragged_decode(q, kp, vp, tables, pos, impl="xla", k_scale=ks,
+                             v_scale=vs, layer=layer)
+    np.testing.assert_array_equal(np.asarray(ragged), np.asarray(got))
+
+
+def test_head_major_views_keep_the_split_form(table):
+    """Without a ``layer`` the pools are a hook's or a kernel test's
+    per-layer head-major views: gathered ``[B, S, Nkv, D]`` and attended
+    by ``decode_attention`` itself, bit for bit."""
+    import jax.numpy as jnp
+    import numpy as np
+    table({})
+    q, (kp, vp), _, tables, pos = _token_major_case(
+        8, 4, 16, jnp.dtype("float32"), False)
+    k_v, v_v, _, _ = A._layer_views(jnp.int32(2), 16, kp, vp)
+    got = A.paged_decode(q, k_v, v_v, tables, pos, impl="xla")
+    want = A.decode_attention(
+        q, *A._gather_pool_seq(q, k_v, v_v, tables, None, None), pos)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    merged = A.paged_decode(q, kp, vp, tables, pos, impl="xla",
+                            layer=jnp.int32(2))
+    np.testing.assert_allclose(np.asarray(merged), np.asarray(want),
+                               atol=1e-5, rtol=1e-5)

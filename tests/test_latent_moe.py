@@ -340,6 +340,7 @@ def test_engine_generates_the_references_greedy_tokens(engine, ref):
     engine.generate(first, max_new_tokens=4)
     hits = engine.prefix_cache.stats()["hits"]
     before = engine.moe_stats()
+    assert engine.decode_attention_form() == "latent"
     got = engine.generate(prompt, max_new_tokens=8)
     # The second prompt extends the parked first: copy_block + suffix chunk.
     assert engine.prefix_cache.stats()["hits"] == hits + 1

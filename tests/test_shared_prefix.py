@@ -455,6 +455,7 @@ def test_kv_stats_and_prefix_hit_counter_surfaces():
         from distributed_llm_tpu.utils.telemetry import engine_stats
         entry = engine_stats(eng)
         assert "kv" in entry and "shared_blocks" in entry["kv"]
+        assert entry["decode_attention"] == eng.decode_attention_form()
         assert entry["prefix_cache"]["tokens_saved_shared"] > 0
     finally:
         eng.stop()
