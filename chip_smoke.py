@@ -86,7 +86,7 @@ def phase_device(chips: int) -> Dict[str, Any]:
     say("device", "host hot loops (tokenizer merges, routing features): "
                   + ("native library" if native.available()
                      else "Python fallback"))
-    for var in ("DLLM_ATTENTION", "DLLM_RAGGED", "DLLM_TP"):
+    for var in ("DLLM_ATTENTION", "DLLM_RAGGED"):
         check(os.environ.get(var) is None,
               f"{var} is set: the smoke checks the default path")
     return {"platform": d0.platform, "kind": d0.device_kind,

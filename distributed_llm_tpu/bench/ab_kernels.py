@@ -7,8 +7,8 @@ Two modes::
         [--prompt-tokens N] [--max-new N] [--repeat K]
 
     # Per-kernel micro A/B at serving shapes; optionally write the
-    # measured dispatch table ops/attention.py consults (VERDICT r1 #3 —
-    # per-shape dispatch instead of a blanket env pin)
+    # measured dispatch table ops/attention.py consults (per-shape
+    # dispatch instead of a blanket env pin)
     python -m distributed_llm_tpu.bench.ab_kernels micro
         [--tier nano|orin] [--repeat K] [--write-dispatch]
 
@@ -67,12 +67,9 @@ def micro_ab(tier_name: str = "orin", repeat: int = 20,
     """Direct kernel A/B at serving shapes; returns (and optionally
     publishes) the per-(kind, length) winner table.
 
-    ``fast`` trims the grid to the shapes the headline bench actually
-    serves (one mid-ladder length + the model max, batches 1/8) so the
-    A/B fits inside the bench run itself — a bench run can measure its
-    own dispatch table instead of serving un-dispatched.  ``beat`` is
-    called after every case (bench.py's idle watchdog counts it as
-    liveness).  ``kinds`` (an iterable of kind names) restricts the grid
+    ``fast`` trims the grid to one mid-ladder length + the model max,
+    batches 1/8.  ``beat`` is called after every case (a caller's idle
+    watchdog counts it as liveness).  ``kinds`` (an iterable of kind names) restricts the grid
     — used to isolate or exclude a case class (r3: the grid hung on its
     decode_q8@1024 case)."""
     import jax
@@ -360,7 +357,7 @@ def micro_ab(tier_name: str = "orin", repeat: int = 20,
     # gets a "default" (the majority winner across its measured lengths,
     # ties to xla) so off-ladder shapes — e.g. the batched engine's
     # trimmed paged window — inherit a measured demotion instead of
-    # silently staying on Pallas (ADVICE r2).
+    # silently staying on Pallas.
     dispatch = {}
     for kind, per in wins.items():
         owns = {length: all(v) for length, v in per.items()}
@@ -383,9 +380,9 @@ def publish_dispatch(backend: str, model: str, dispatch: dict,
 
     A table measured on real hardware is a committed artifact; a CPU run
     must never clobber it (ops/attention.py would then ignore the file
-    entirely and silently drop the TPU measurements — ADVICE r2), while
+    entirely and silently drop the TPU measurements), while
     a hardware run may always refresh, including replacing a stale cpu
-    table (same policy as bench/tune.py).  A partial (--kinds / fast)
+    table.  A partial (--kinds / fast)
     run MERGES into a same-backend table — unmeasured kinds keep their
     prior winners — but a cross-backend refresh starts clean: mixing
     winners measured on different hardware would make the table

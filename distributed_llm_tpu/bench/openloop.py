@@ -1,8 +1,8 @@
 """Open-loop traffic harness: Poisson arrivals against the real HTTP
 edge, sweeping arrival rate to the knee of the latency-throughput curve.
 
-The closed-loop N-client harness (bench.py headline) cannot see queueing
-collapse: a closed-loop client submits its next request only after the
+A closed-loop N-client harness (benchmark/'s traffic mixes) cannot see
+queueing collapse: a closed-loop client submits its next request only after the
 previous answer lands, so offered load self-throttles to whatever the
 system serves and the queue never grows.  Production traffic does not
 wait its turn — arrivals are ASYNCHRONOUS, and the number that matters
@@ -29,10 +29,8 @@ way; PAPERS.md).  This module:
   collapse shows up as flight-recorded overload incidents carrying a
   system-state timeline slice (obs/sampler.py), not as silence.
 
-Pinned tiny-batched config like the trend/chaos/pressure legs: the leg
-measures the serving machinery under load it did not choose, not model
-speed.  Budget-aware via the ``budget_s`` parameter (bench.py passes its
-remaining DLLM_BENCH_BUDGET_S share): rate points are dropped from the
+Pinned tiny-batched config: the leg measures the serving machinery under
+load it did not choose, not model speed.  Budget-aware via the ``budget_s`` parameter: rate points are dropped from the
 top of the sweep, never measured shorter than ``MIN_POINT_S``.
 """
 
@@ -58,7 +56,7 @@ MAX_SWEEP_POINTS = 9            # ≤ base × 0.75 × 2^8 before giving up
 MAX_RATE_REQ_PER_S = 800.0      # past this the spawn loop itself lies
 MAX_ARRIVALS_PER_POINT = 600    # bounds threads/memory at high rates
 # A point "holds" its offered load when this fraction of completions met
-# the SLO; the knee is the highest such point (BENCHMARKS.md r11).
+# the SLO; the knee is the highest such point.
 KNEE_ATTAINMENT = 0.9
 OVERLOAD_FACTOR = 2.5           # epilogue rate = knee × this (≥2× pinned)
 MIN_POINT_S = 1.0               # never measure a rate point shorter
@@ -87,7 +85,7 @@ def _run_rate_point(client, router, queries, strategy: str,
 
     ``deadline`` (``time.monotonic()``) clamps the straggler join grace
     so a wedged point cannot overrun the leg's budget share by the full
-    JOIN_GRACE_S — bench.py reserves only ~30 s after this leg.
+    JOIN_GRACE_S.
     ``carry`` threads are stragglers a PREVIOUS point left running:
     they are absorbed (briefly joined) before the SLO baseline snapshot,
     because a stale completion landing mid-window would bleed into this
@@ -168,7 +166,7 @@ def _run_rate_point(client, router, queries, strategy: str,
     # Clamp the drain grace by the leg's budget deadline (floor 5 s so
     # hung-client detection still gets a real chance): without the
     # clamp, one wedged point spends up to JOIN_GRACE_S past its budget
-    # share and eats the reserve bench.py keeps for the phases after.
+    # share.
     grace = JOIN_GRACE_S
     if deadline is not None:
         grace = max(5.0, min(grace, deadline - time.monotonic()))
@@ -238,10 +236,9 @@ def openloop_phase(strategies=("heuristic", "perf"),
                    budget_s: Optional[float] = None,
                    point_s: Optional[float] = None,
                    beat=lambda: None) -> Dict[str, Any]:
-    """The bench leg (bench.py wires it after the skew leg): per-strategy
-    open-loop rate sweep → knee + goodput-at-knee, then the overload
-    epilogue on the first strategy.  Returns the artifact dict under the
-    bench's ``openloop`` key; ``knee_req_per_s`` / ``goodput_at_knee`` /
+    """Per-strategy open-loop rate sweep → knee + goodput-at-knee, then
+    the overload epilogue on the first strategy.  Returns the artifact
+    dict; ``knee_req_per_s`` / ``goodput_at_knee`` /
     per-strategy ``slo_attainment`` are the acceptance columns."""
     import sys
 

@@ -140,8 +140,8 @@ def _report(title: str, sections: int, opener: str) -> str:
 # query+context token counts genuinely straddle the reference's
 # 100→4000 threshold sweep (src/tests/routing_chatbot_tester.py:352-367
 # sweeps token_threshold and BASELINE.md shows load shifting
-# continuously across it).  The r4 sweep was degenerate above 500
-# because every query was tiny (VERDICT r4 weak #5); these pasted
+# continuously across it).  A sweep over tiny queries alone is degenerate
+# above 500; these pasted
 # documents put successive queries at roughly 0.3k/0.7k/1.2k/2k/3k
 # tokens (serving BPE), with short follow-ups riding the accumulated
 # context in between.  Serving tiers tail-truncate long prompts to

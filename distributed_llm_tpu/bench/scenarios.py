@@ -2,7 +2,7 @@
 
 ``openloop.py`` sweeps FLAT Poisson rates to find the knee; production
 traffic is not flat.  Capacity economics — the goodput-per-replica-
-second question the elastic leg (bench.py ``elastic_phase``) asks —
+second question an elastic tier (serving/autoscaler.py) raises —
 only shows up under traffic with SHAPE: diurnal ramps where demand
 doubles and halves over a "day", flash crowds that spike an order of
 magnitude for seconds, session-heavy stretches where multi-turn

@@ -130,8 +130,8 @@ class ModelConfig:
     name: str
     # Tokenizer scheme + matching vocabulary size.  "bpe" = the trained
     # subword artifact (engine/bpe.py, vocab 4096 — ~3.5 chars/token on
-    # the bench queries, so ~3.5× fewer decode steps per word of text
-    # than byte-level; VERDICT r2 #3); "byte" = the self-contained
+    # the query sets, so ~3.5× fewer decode steps per word of text
+    # than byte-level); "byte" = the self-contained
     # fallback (vocab 512).  engine.tokenizer.get_tokenizer validates
     # the pair.
     tokenizer: str = "bpe"
@@ -225,8 +225,7 @@ class ModelConfig:
 
 # Tier presets.  The "full" presets mirror the north star (1B vs 8B class);
 # the "bench" presets are sized so both tiers fit one v5e chip (16 GB HBM)
-# at the same time, since the driver benches on a single real chip.  The
-# "test" presets keep CPU-mesh unit tests fast.
+# at the same time.  The "test" presets keep CPU-mesh unit tests fast.
 MODEL_PRESETS: Dict[str, ModelConfig] = {
     "nano_1b": ModelConfig(
         name="nano_1b", hidden_size=2048, num_layers=16, num_heads=32,
@@ -244,9 +243,7 @@ MODEL_PRESETS: Dict[str, ModelConfig] = {
         name="orin_bench", hidden_size=2048, num_layers=16, num_heads=16,
         num_kv_heads=8, ffn_size=8192, max_seq_len=2048,
     ),
-    # Sized so ONE host CPU core can pretrain it to a plateau in ~1 h:
-    # the weak half of the cpu_bench pair (see cpu_bench_cluster), giving
-    # the chipless fallback bench a genuinely quality-asymmetric cluster.
+    # Sized so ONE host CPU core can pretrain it to a plateau in ~1 h.
     "mini_bench": ModelConfig(
         name="mini_bench", hidden_size=512, num_layers=6, num_heads=8,
         num_kv_heads=4, ffn_size=2048, max_seq_len=2048,
@@ -365,10 +362,10 @@ class TierConfig:
     # Per-chip HBM residency budget in GB (utils/hbm_budget.py).  When
     # set, EngineManager.start_server budgets params + KV against the
     # tier's DEPLOYED submesh before building the engine and refuses
-    # cleanly (TierOverCapacityError) when the footprint doesn't fit —
-    # the tp=1-vs-tp=2 capacity demonstration in bench.py's multichip
-    # leg rides this.  None (the default) keeps the historical behavior:
-    # no admission-time budget, OOM surfaces wherever XLA hits it.
+    # cleanly (TierOverCapacityError) when the footprint doesn't fit
+    # (tests/test_tp_parity.py: refused at tp=1, served at tp=2).  None
+    # (the default): no admission-time budget, OOM surfaces wherever XLA
+    # hits it.
     hbm_gb_per_chip: Optional[float] = None
     max_new_tokens: int = 256       # decode cap (reference: num_predict, -1=unbounded)
     temperature: float = 0.0        # greedy by default (src/devices/nano_api.py:21)
@@ -417,8 +414,8 @@ class TierConfig:
     # Disaggregated chunked prefill (engine/batching.py): a cold
     # admission whose prompt bucket exceeds this many tokens no longer
     # prefills in ONE monolithic compiled call on the scheduler thread
-    # (which froze every active decode slot for the whole prompt —
-    # BENCHMARKS.md r6's concurrency ceiling).  Instead the prompt is
+    # (which froze every active decode slot for the whole prompt).
+    # Instead the prompt is
     # split into fixed chunks of this size and the scheduler interleaves
     # them with decode ticks (chunk_prefill_paged writes each chunk's
     # K/V straight into the slot's pool blocks), so time-between-tokens
@@ -499,10 +496,9 @@ class TierConfig:
     # mutating shared/parked blocks — COW first, like admit).  Greedy
     # outputs stay byte-identical to plain decode.  Tri-state: None
     # (default) = AUTO — EngineManager arms it when a tier configures
-    # draft_preset with decode_batch>1 (the PR 1 bypass retired —
-    # speculation no longer forces the sequential engine; the bench
-    # spec leg's tok/s bar was met at 2.0×, BENCHMARKS.md r17); True =
-    # engine-level force-on (tests/bench construct engines directly);
+    # draft_preset with decode_batch>1 (speculation no longer forces
+    # the sequential engine); True = engine-level force-on (tests
+    # construct engines directly);
     # False = the operator KILL SWITCH — a draft tier keeps its config
     # but serves plain batched decode.  Requires the fused ragged tick;
     # unsharded greedy tiers only.
@@ -554,7 +550,7 @@ class TierConfig:
     # lose the race (entry invalidated, copier stalled, blocks starved,
     # drain) fall back to a cold prefill with byte-identical greedy
     # output.  0/None disables the tier (exact pre-spill behavior).
-    # DLLM_HOST_KV_BYTES overrides globally (bench A/B).
+    # DLLM_HOST_KV_BYTES overrides globally.
     host_kv_bytes: Optional[int] = None
     # Fraction of the per-tick chunked-prefill token budget
     # (prefill_chunk_budget) a promotion's host→device grants may spend
@@ -614,7 +610,7 @@ class TierConfig:
     # Per-tier SLO targets (obs/slo.py, fed from the router's exactly-
     # once _finish_request exit): a request is GOODPUT only when it
     # completes ok with TTFT ≤ slo_ttft_ms and per-request p95
-    # time-between-tokens ≤ slo_tbt_ms.  The open-loop bench leg and the
+    # time-between-tokens ≤ slo_tbt_ms.  bench/openloop.py and the
     # online dllm_slo_goodput gauges judge serving by these, and a tier
     # whose windowed goodput collapses raises an overload incident into
     # the flight recorder.  None disables that criterion (error-only
@@ -770,13 +766,12 @@ class ClusterConfig:
     """The two-tier deployment. Tier submeshes are carved from jax.devices()
     in order: nano gets the first `nano.tp` chips, orin the next `orin.tp`.
     If fewer devices exist than requested, tiers share / shrink gracefully
-    (single-chip dev boxes and the one-chip bench environment).
+    (single-chip dev boxes).
     """
 
     # Concurrent-by-default: both tiers serve through the continuous-
     # batching engine (decode_batch slots share one compiled decode
-    # step); the 3.67×-measured batching speedup only reaches traffic
-    # when it is the default path, not a bench-only A/B.
+    # step): batching only reaches traffic when it is the default path.
     nano: TierConfig = dataclasses.field(
         default_factory=lambda: TierConfig(name="nano", model_preset="nano_1b",
                                            tp=1, decode_batch=8))
@@ -810,101 +805,22 @@ class ClusterConfig:
 
 
 def bench_cluster() -> ClusterConfig:
-    """Cluster sized for the single-chip bench environment.
+    """The accelerator default of ``serving/router.default_cluster``: both
+    tiers sized to share one 16 GB chip.
 
     int8 weight-only serving mirrors the reference deployment (Ollama runs
     GGML-quantized models on the Jetsons) and roughly halves decode's HBM
-    weight traffic on the bandwidth-bound decode loop.
-
-    DLLM_BENCH_SPEC_ORIN=1 puts the nano model in front of the orin tier
-    as a speculative draft (greedy-exact): at the measured ~0.5
-    acceptance, the weight-bound orin decode does ~1 full weight pass per
-    ~3 tokens instead of per token.  An A/B flag: no default flips on it
-    without a chip measurement.
+    weight traffic on the bandwidth-bound decode loop.  A literal: no
+    table and no environment variable steers it.
     """
-    from .config_registry import env_flag
-    draft = "nano_bench" if env_flag("DLLM_BENCH_SPEC_ORIN") else None
-    cluster = ClusterConfig(
+    return ClusterConfig(
         nano=TierConfig(name="nano", model_preset="nano_bench", tp=1,
                         max_new_tokens=64, quantize="int8",
                         decode_batch=8),
         orin=TierConfig(name="orin", model_preset="orin_bench", tp=1,
                         max_new_tokens=128, quantize="int8",
-                        decode_batch=4, draft_preset=draft),
+                        decode_batch=4),
     )
-    return _apply_tuning(cluster, draft_override=draft,
-                         draft_preset="nano_bench")
-
-
-def _apply_tuning(cluster: "ClusterConfig", *,
-                  draft_override: "Optional[str]" = None,
-                  draft_preset: str = "nano_bench") -> "ClusterConfig":
-    """Defaults follow measurement (same pattern as the attention
-    dispatch table): a committed bench/tuning.json — written by
-    `python -m distributed_llm_tpu.bench.tune` from real bench
-    artifacts, backend-tagged — overlays quantize/kv_quantize/draft per
-    tier when (and only when) its backend matches the running one.  An
-    explicit ``draft_override`` (the DLLM_BENCH_SPEC_ORIN A/B) still
-    wins over the table's speculative verdict."""
-    try:
-        import jax
-
-        from .bench.tune import load_tuning
-        tiers = load_tuning(jax.default_backend())
-    except Exception:
-        tiers = {}
-    if not tiers:
-        return cluster
-
-    def apply(tier: TierConfig) -> TierConfig:
-        t = tiers.get(tier.name) or {}
-        kw = {k: t[k] for k in ("quantize", "kv_quantize") if k in t}
-        if (tier.name == "orin" and draft_override is None
-                and "speculative" in t):
-            kw["draft_preset"] = draft_preset if t["speculative"] else None
-        return dataclasses.replace(tier, **kw) if kw else tier
-
-    return dataclasses.replace(cluster, nano=apply(cluster.nano),
-                               orin=apply(cluster.orin))
-
-
-def cpu_bench_cluster() -> ClusterConfig:
-    """Quality-consistent tiers for the chipless fallback bench.
-
-    The premise every routing strategy trades on — orin answers BETTER
-    and costs more per token (src/devices/orin_api.py:17-18 llama3 vs
-    nano_api.py:15-21 phi3-mini) — must hold on whatever cluster the
-    headline actually serves (VERDICT r4 missing #2).  On a CPU box the
-    1B orin_bench cannot be trained to quality, so the CPU bench demotes
-    to the largest pair this box CAN train and serve: mini_bench (~26M,
-    pretrained on CPU) as the weak tier under
-    nano_bench (~130M, chip-pretrained, held-out loss 1.257) as the
-    strong one.  Smaller decode caps keep the 1-core sweep bounded.
-    """
-    from .config_registry import env_flag
-    draft = "mini_bench" if env_flag("DLLM_BENCH_SPEC_ORIN") else None
-    # Short bucket ladder: each bucket is a separate XLA program and the
-    # 1-core box pays real compile time per program.  64 stays the
-    # bottom rung — the benchmark sets' median query is ~10-40 tokens
-    # and padding those to 256 would 4x their prefill FLOPs steady-state
-    # — while the middle rungs collapse to one (2048 covers the
-    # long-context probe).
-    cluster = ClusterConfig(
-        nano=TierConfig(name="nano", model_preset="mini_bench", tp=1,
-                        max_new_tokens=48, decode_batch=8,
-                        prefill_buckets=(64, 256, 2048)),
-        orin=TierConfig(name="orin", model_preset="nano_bench", tp=1,
-                        max_new_tokens=64, decode_batch=4,
-                        draft_preset=draft,
-                        prefill_buckets=(64, 256, 2048)),
-    )
-    # A cpu-backend tuning.json (bench.tune over the chipless headline's
-    # artifacts) steers THIS pair's quant/kv/spec defaults the same way
-    # the tpu table steers bench_cluster — the draft is the pair's own
-    # weak tier, and the explicit spec A/B env wins over the table here
-    # too.
-    return _apply_tuning(cluster, draft_override=draft,
-                         draft_preset="mini_bench")
 
 
 def flagship_cluster(n_devices: Optional[int] = None) -> ClusterConfig:
@@ -913,11 +829,8 @@ def flagship_cluster(n_devices: Optional[int] = None) -> ClusterConfig:
 
     On a pod slice (≥5 chips) orin serves bf16 over a tp=4 submesh — the
     layout the HBM-budget test proves out (tests/test_flagship.py).  On
-    the single-chip bench box orin serves int8 (~7 GB weights), which the
-    budget shows fitting 16 GB WITH its KV + parked prefix caches.  The
-    bench's flagship phase drives exactly these tiers (bench.py
-    flagship_phase), so the presets are exercised, not dead config
-    (VERDICT r2 #2)."""
+    a single chip orin serves int8 (~7 GB weights), which the budget
+    shows fitting 16 GB WITH its KV + parked prefix caches."""
     if n_devices is None:
         import jax
         n_devices = len(jax.devices())
@@ -931,15 +844,10 @@ def flagship_cluster(n_devices: Optional[int] = None) -> ClusterConfig:
     else:
         # int8 WEIGHTS are a fit requirement here (14 GB bf16 weights
         # alone overflow the 16 GB chip — tests/test_flagship.py); int8
-        # KV is a PERF knob, and the measurements say it doesn't pay:
-        # r4 measured kv-int8 0.53× the bf16-KV rate, and the r5
-        # re-measure on real-trained tiers landed ~break-even
-        # (0.99×/0.95× — BENCHMARKS.md, bench/tuning.json evidence), so
-        # it defaults OFF like everywhere else (VERDICT r5 #4: no
-        # on-chip tuning table exists to justify it).  Opt back in with
-        # DLLM_FLAGSHIP_KV_INT8=1 (the A/B flag) or a measured TPU
-        # tuning.json; the HBM budget fits with bf16 KV (the budget
-        # test pins it).
+        # KV is a PERF knob that no chip measurement justifies, so it
+        # defaults OFF like everywhere else.  Opt back in with
+        # DLLM_FLAGSHIP_KV_INT8=1 (the A/B flag); the HBM budget fits
+        # with bf16 KV (the budget test pins it).
         from .config_registry import env_flag
         kv = "int8" if env_flag("DLLM_FLAGSHIP_KV_INT8") else "none"
         orin = TierConfig(name="orin", model_preset="orin_8b", tp=1,
@@ -955,8 +863,8 @@ def tiny_cluster() -> ClusterConfig:
     Deliberately sequential (decode_batch=1): hundreds of unit tests
     build these tiers and the sequential engine's warmup is the cheaper
     one; the concurrent-by-default serving path is covered by
-    ``tiny_batched_cluster`` (admission/soak tests and the bench's
-    chipless fallback) and the real serving presets above."""
+    ``tiny_batched_cluster`` (admission/soak tests, the CPU default of
+    ``default_cluster``) and the real serving presets above."""
     return ClusterConfig(
         nano=TierConfig(name="nano", model_preset="nano_test", tp=1,
                         max_new_tokens=8, prefill_buckets=(16, 32, 64),
@@ -971,9 +879,9 @@ def tiny_batched_cluster(nano_slots: int = 4,
                          orin_slots: int = 2) -> ClusterConfig:
     """The tiny tiers with the serving default's continuous-batching
     engines (concurrent-by-default at test scale): used by the
-    admission/soak tests and by the bench's chipless tiny fallback so
-    the concurrent headline exercises the same engine family the real
-    presets serve.  max_new_tokens is raised to a serving-realistic 24
+    admission/soak tests and as ``default_cluster``'s CPU branch, so a
+    CPU box serves the same engine family the real presets do.
+    max_new_tokens is raised to a serving-realistic 24
     (the unit tiers' 8 is a test-speed artifact): batching amortizes the
     DECODE loop, so a cap that makes requests all-prefill would
     understate the default path the real presets (48-128 caps) serve."""

@@ -1,8 +1,8 @@
 """Central registry of every configuration surface the repo exposes.
 
 Two kinds of drift kept hitting review: a ``DLLM_*`` env var would grow a
-new reader with its own inline default (bench.py at one point carried
-three different fallbacks for the same knob), and ``TierConfig`` /
+new reader with its own inline default (one knob at one point had
+three different fallbacks), and ``TierConfig`` /
 ``ClusterConfig`` fields would gain semantics documented only in a commit
 message.  This module is the single source of truth for both:
 
@@ -70,11 +70,6 @@ ENV_VARS: Dict[str, EnvVar] = {v.name: v for v in (
        "keep the dense windowed path regardless of this flag.  The "
        "kernel inside the tick is DLLM_ATTENTION / dispatch-table "
        "territory."),
-    _e("DLLM_TP", None, "parallel/mesh.py",
-       "Forces every tier's REQUESTED tensor-parallel degree for the "
-       "mesh carve (parallel/mesh.requested_tp — the multichip bench "
-       "leg's A/B lever), overriding TierConfig.tp; feasibility clamps "
-       "(head divisibility, available chips) still apply."),
     _e("DLLM_NATIVE", None, "native/__init__.py",
        "'0' disables the g++-built native tokenizer/counter helpers; "
        "behavior is bit-identical to the pure-Python fallback."),
@@ -112,32 +107,11 @@ ENV_VARS: Dict[str, EnvVar] = {v.name: v for v in (
        "(obs/slo.py); unset = each tier's TierConfig.slo_tbt_ms."),
     _e("DLLM_FLAGSHIP_KV_INT8", None, "config.py",
        "'1' opts the single-chip flagship orin tier into int8 KV cache "
-       "(measured ~break-even r5; default off, VERDICT r5 #4)."),
-    _e("DLLM_BENCH_BUDGET_S", "1200", "bench.py",
-       "Wall-clock budget for the whole bench run (s); phases are skipped "
-       "with a stamped reason once it runs dry."),
-    _e("DLLM_BENCH_WATCHDOG_S", "900", "bench.py",
-       "Bench idle watchdog (s): no liveness beat for this long flushes "
-       "the partial artifact and exits (hung-device insurance)."),
-    _e("DLLM_BENCH_NO_AB", None, "bench.py",
-       "'1' skips the in-process kernel A/B that bench.run() makes when "
-       "no same-backend dispatch table exists."),
-    _e("DLLM_BENCH_REPEATS", "3", "bench.py",
-       "Headline sweep repeats; the artifact reports {median, iqr, n}."),
-    _e("DLLM_BENCH_CLIENTS", "4", "bench.py",
-       "Closed-loop concurrent clients for the headline leg (min 2)."),
-    _e("DLLM_BENCH_SPEC_ORIN", None, "config.py, bench/tune.py, bench.py",
-       "'1' serves the orin tier speculatively (nano-class draft) for the "
-       "spec A/B leg; wins over the tuning table's verdict."),
-    _e("DLLM_BENCH_FLAGSHIP", None, "bench.py",
-       "'1' forces the flagship phase on the CPU fallback backend "
-       "(normally skipped: a 1B model on one host core is not a "
-       "measurement)."),
+       "(default off: no chip measurement justifies it)."),
     _e("DLLM_HOST_KV_BYTES", None, "engine/batching.py",
        "Global override of TierConfig.host_kv_bytes — the host-RAM "
        "budget of the hierarchical KV spill tier in bytes ('0' disables "
-       "it everywhere); unset = each tier's config decides.  The bench "
-       "spill leg A/Bs through this."),
+       "it everywhere); unset = each tier's config decides."),
     _e("DLLM_KV_LEAK_CHECK", None, "engine/batching.py",
        "'1' arms the dynamic twin of the lint's ownership rules: engine "
        "stop() asserts zero allocated pool blocks and zero live spill "
@@ -170,8 +144,8 @@ ENV_VARS: Dict[str, EnvVar] = {v.name: v for v in (
        "Global replica-dispatch policy override for replicated tiers "
        "('affinity' | 'load' | 'random'); unset = "
        "TierConfig.replica_affinity decides (affinity when True, else "
-       "least-loaded).  'random' exists for the bench's dilution "
-       "comparison, not production."),
+       "least-loaded).  'random' exists for the dilution comparison "
+       "(tests/test_replicas.py), not production."),
     _e("DLLM_AUTOSCALE", "1", "serving/router.py",
        "Elastic-capacity kill switch: '0' disarms every tier's "
        "ReplicaAutoscaler (no controller threads, membership stays the "
@@ -439,8 +413,8 @@ def env_flag(name: str) -> bool:
 
 
 def env_float(name: str, default: float) -> float:
-    """Float read that never throws on garbage (bench convention: a bad
-    value must not lose the run — fall back and keep going)."""
+    """Float read that never throws on garbage: a bad value must not
+    lose the run — fall back and keep going."""
     raw = os.environ.get(_entry(name).name)
     if raw is None:
         return default

@@ -3570,8 +3570,8 @@ class ContinuousBatchingEngine:
         suffix buckets so the first prefix-reuse admission doesn't pay an
         XLA trace.  Runs before serving traffic: the scheduler is idle
         (no active slots), so mutating the pool here doesn't race a tick.
-        ``beat`` fires after each compiled program (liveness for bench.py's
-        wedge watchdog through multi-minute on-chip warmups)."""
+        ``beat`` fires after each compiled program (liveness for a
+        caller's wedge watchdog through multi-minute on-chip warmups)."""
         beat = beat or (lambda: None)
         self.generate("warmup", max_new_tokens=2)
         beat()

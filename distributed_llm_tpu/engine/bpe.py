@@ -1,4 +1,4 @@
-"""Trained byte-level BPE subword tokenizer (VERDICT r2 #3).
+"""Trained byte-level BPE subword tokenizer.
 
 The reference's tiers serve real subword-vocab models through Ollama
 (phi3-mini / llama3, /root/reference/src/devices/nano_api.py:15-16), and

@@ -809,7 +809,7 @@ class InferenceEngine:
 
         ``beat`` (liveness callback) fires after every compiled program:
         a full warmup is dozens of 20-40 s compiles on chip — far past
-        bench.py's 900 s wedge watchdog if warmup were silent."""
+        a caller's wedge watchdog if warmup were silent."""
         beat = beat or (lambda: None)
         from ..utils.telemetry import PhaseTimer
         self.generate("warmup", max_new_tokens=1)

@@ -74,7 +74,7 @@ class EngineManager:
         """Idempotent: build the engine and compile/warm the hot paths.
         ``beat`` (optional liveness callback) is forwarded to the
         engine's warmup — on chip a full warmup is many multi-10s
-        compiles, longer than bench.py's wedge watchdog window.
+        compiles, longer than a caller's wedge watchdog may wait.
 
         The lifecycle lock is held through the whole build/compile ON
         PURPOSE: it exists to serialize start/stop, and concurrent

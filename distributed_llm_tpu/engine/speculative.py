@@ -122,8 +122,8 @@ class SpeculativeEngine:
         # the verify chunk and every draft step attend over the ALLOCATED
         # span, so sizing both caches to the conversation instead of
         # max_seq cuts verify compute and HBM traffic alike for short
-        # chats (ADVICE r2: the old flat _max_seq allocation also made
-        # the roofline charge severalfold too high).
+        # chats (a flat _max_seq allocation would also make the
+        # roofline charge severalfold too high).
         self._cache_lens = sorted(
             {c for c in (256, 1024) if c < self._max_seq} | {self._max_seq})
 
@@ -162,8 +162,8 @@ class SpeculativeEngine:
     def params(self):
         """InferenceEngine surface parity: the TARGET's weights — spec
         decoding is greedy-exact, so served answer quality IS the
-        target model's (bench.py's tier_quality probe scores
-        eng.cfg/eng.params for any engine)."""
+        target model's (training/evaluate.py scores eng.cfg/eng.params
+        for any engine)."""
         return self.params_t
 
     # -- compiled stages ---------------------------------------------------
@@ -328,9 +328,9 @@ class SpeculativeEngine:
     def _prepare_and_prefill(self, history, max_new_tokens):
         """Shared front half of generate()/generate_stream(): tokenize,
         clamp the budget, size both caches to the conversation (prompt +
-        decode budget + one speculative round of headroom — ADVICE r2:
-        the old flat max_seq allocation made every draft step and verify
-        compute over the full span), prefill both models, account the
+        decode budget + one speculative round of headroom: a flat
+        max_seq allocation makes every draft step and verify compute
+        over the full span), prefill both models, account the
         roofline work.  Returns (first [1] device array, cache_t,
         cache_d, cache_len, n, budget, ttft_ms, t0)."""
         from ..utils import roofline
@@ -444,8 +444,7 @@ class SpeculativeEngine:
                     # Draft: γ+1 sequential full-span decode steps.  Target
                     # verify: ONE chunked forward — γ+1 query tokens share
                     # a single read of the target cache (kv_batch=1), over
-                    # the ALLOCATED (bucketed) span, not max_seq
-                    # (ADVICE r2).
+                    # the ALLOCATED (bucketed) span, not max_seq.
                     self.phases.add_work("decode", **roofline.decode_work(
                         self.cfg_d, self.gamma + 1, cache_len,
                         wbytes=self._wbytes_d))
@@ -494,7 +493,7 @@ class SpeculativeEngine:
         # traffic prefers streaming (serving/tiers.py process_stream) —
         # at EVERY cache rung a conversation can grow into, so no request
         # ever pays a mid-serve trace of the speculative graph.  ``beat``
-        # fires per compiled program (bench.py watchdog liveness).
+        # fires per compiled program (a caller's watchdog liveness).
         beat = beat or (lambda: None)
         self.generate("warmup", max_new_tokens=self.gamma + 2)
         beat()

@@ -3,8 +3,8 @@ byte-level fallback.
 
 The reference gets tokenization for free from Ollama/llama.cpp; in this
 zero-egress environment no pretrained BPE vocabulary can be fetched, so the
-framework trains its own byte-level BPE over its corpus (engine/bpe.py,
-VERDICT r2 #3) and keeps this self-contained byte-level scheme as the
+framework trains its own byte-level BPE over its corpus (engine/bpe.py)
+and keeps this self-contained byte-level scheme as the
 fallback: ids 0-255 are raw UTF-8 bytes, followed by PAD/BOS/EOS specials,
 padded to a 512 vocab so the embedding table tiles the MXU's 128-lane
 layout cleanly.  Both tokenizers share the special ids and the

@@ -8,7 +8,7 @@ drift from the table.  What CAN drift:
 
 - an ad-hoc creation or lookup somewhere else —
   ``registry.counter("dllm_new_thing_total", …)`` in a serving module,
-  ``metrics.get("dllm_renamed_total")`` in bench.py — whose name,
+  ``metrics.get("dllm_renamed_total")`` in a script — whose name,
   kind, or label set the registry never heard of
   (``metrics-unregistered``);
 - a registry row minting a label name with no entry in
@@ -100,8 +100,7 @@ def _registry_tables(mod) -> Tuple[Optional[ast.expr], Optional[ast.expr]]:
 class MetricsDisciplineChecker(Checker):
     name = "metrics_discipline"
     rules = ("metrics-unregistered", "metrics-label-cardinality")
-    scope = ("distributed_llm_tpu", "scripts", "bench.py",
-             "tests/conftest.py")
+    scope = ("distributed_llm_tpu", "scripts", "tests/conftest.py")
     # A new emission anywhere must be checked against the (unchanged)
     # registry module, so --changed must not narrow the project.
     whole_project = True

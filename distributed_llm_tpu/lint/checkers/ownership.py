@@ -979,8 +979,7 @@ class OwnershipChecker(Checker):
 
     name = "ownership"
     rules = (OWN_LEAK, OWN_DOUBLE, OWN_UAT, OWN_PIN)
-    scope = ("distributed_llm_tpu", "scripts", "bench.py",
-             "tests/conftest.py")
+    scope = ("distributed_llm_tpu", "scripts", "tests/conftest.py")
     whole_project = True
 
     def check(self, project: Project) -> List[Finding]:

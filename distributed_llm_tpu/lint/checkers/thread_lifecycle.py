@@ -153,9 +153,8 @@ class ThreadLifecycleChecker(Checker):
     name = "thread_lifecycle"
     rules = ("thread-no-reclaim", "thread-acquire-leak",
              "thread-ring-no-stop")
-    # The whole default project: bench.py and scripts spawn threads too.
-    scope = ("distributed_llm_tpu", "scripts", "bench.py",
-             "tests/conftest.py")
+    # The whole default project: scripts spawn threads too.
+    scope = ("distributed_llm_tpu", "scripts", "tests/conftest.py")
     whole_project = True
 
     def check(self, project: Project) -> List[Finding]:

@@ -167,7 +167,6 @@ class Project:
 DEFAULT_TARGETS: Tuple[str, ...] = (
     "distributed_llm_tpu",
     "scripts",
-    "bench.py",
     "tests/conftest.py",
 )
 

@@ -249,7 +249,7 @@ def resolve_local_callable(syms: ModuleSymbols, scope_qual: Optional[str],
 def module_dotted_name(relpath: str) -> str:
     """``distributed_llm_tpu/serving/router.py`` ->
     ``distributed_llm_tpu.serving.router``; ``pkg/__init__.py`` ->
-    ``pkg``; top-level ``bench.py`` -> ``bench``."""
+    ``pkg``; top-level ``chip_smoke.py`` -> ``chip_smoke``."""
     p = relpath[:-3] if relpath.endswith(".py") else relpath
     if p.endswith("/__init__"):
         p = p[: -len("/__init__")]

@@ -5,8 +5,8 @@ The serving stack's internal signals (queue depth, breaker state, wedge
 flags, admission rejects — PRs 1 and 2) previously surfaced only as an
 untyped ``GET /stats`` dict; this registry gives them a typed, scrapeable
 shape, served as Prometheus exposition text at ``GET /metrics``
-(serving/app.py) and read programmatically by bench.py for the
-trace-derived headline columns.
+(serving/app.py) and read programmatically by the benchmark's
+per-layer readers (benchmark/layer_metrics/).
 
 Shape notes:
 
@@ -637,7 +637,7 @@ BOUNDED_LABELS: Dict[str, str] = {
 class ServingMetrics:
     """The serving stack's standard metric set, materialized from
     METRIC_REGISTRY so the router, breaker hooks, engine managers,
-    /metrics, and bench.py all read/write the same families (one
+    /metrics, and the benchmark all read/write the same families (one
     assembler, no name drift — the table above is the only place a
     family is declared)."""
 

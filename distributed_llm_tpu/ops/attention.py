@@ -25,7 +25,8 @@ NEG_INF = -1e30
 
 # Measured per-kernel dispatch table, written by
 # ``python -m distributed_llm_tpu.bench.ab_kernels micro --write-dispatch``
-# on real hardware: {"decode": {"default": "pallas", "2048": "xla"}, ...}.
+# on real hardware and by nothing else:
+# {"decode": {"default": "pallas", "2048": "xla"}, ...}.
 # Consulted only when an engine opted into the Pallas family ('pallas'
 # resolved, no DLLM_ATTENTION override): a kernel kind/length the A/B
 # showed losing is demoted back to XLA per shape, instead of the round-1
@@ -41,8 +42,7 @@ _DISPATCH_META: Optional[dict] = None
 # measurable case classes (ALL_KINDS) from it, and
 # tests/test_kernel_dispatch.py asserts the committed ab_dispatch.json
 # covers every entry, so a new kernel kind cannot ship without a table
-# row (VERDICT r5 weak #2: the table had silently fallen behind the
-# kernels).
+# row (the table had once silently fallen behind the kernels).
 DISPATCH_KINDS = ("prefill", "decode", "decode_q8", "chunk", "chunk_q8",
                   "paged_decode", "paged_decode_q8", "paged_chunk",
                   "ragged_decode", "ragged_decode_q8",
@@ -54,8 +54,7 @@ def _load_dispatch() -> None:
     whose ``kernel_gen`` is absent or behind the current Pallas kernels
     still dispatches — re-measuring needs hardware — but the staleness is
     logged and surfaced via ``dispatch_provenance`` (/stats), so old
-    hardware conclusions read as provisional, not authoritative
-    (VERDICT r4 #8)."""
+    hardware conclusions read as provisional, not authoritative."""
     global _DISPATCH_TABLE, _DISPATCH_META
     if _DISPATCH_TABLE is not None:
         return
@@ -115,7 +114,7 @@ def _measured_impl(kind: str, length: Optional[int]) -> Optional[str]:
         if hit is None and length is not None:
             # Off-ladder shape (e.g. the batched engine's trimmed paged
             # window, which takes many values): snap to the nearest
-            # measured rung so demotions cover it (ADVICE r2).
+            # measured rung so demotions cover it.
             rungs = [int(k) for k in entry if str(k).isdigit()]
             if rungs:
                 hit = entry[str(min(rungs,
@@ -144,7 +143,7 @@ def decode_kv_span(kind: str, length: int, positions, impl: str = "auto",
     clamp their grid onto the causal frontier and stream only
     ceil((pos+1)/block) tiles (pallas_attention.py ``_decode_kernel`` /
     paged index maps), so charging the allocated span would overstate
-    hbm_util — the judged decode metric — past 1.0 (ADVICE r2).
+    hbm_util — the judged decode metric — past 1.0.
 
     ``positions`` iterates the 0-based query positions of the accounted
     steps (per step for a single sequence, per row for a batched tick);

@@ -39,8 +39,8 @@ from jax.experimental.pallas import tpu as pltpu
 from .attention import NEG_INF, causal_attention, decode_attention
 
 # Bump when any kernel IMPLEMENTATION changes: a dispatch table measured
-# against older kernels is stale, and bench.py's pre-measure re-runs the
-# A/B when the table's kernel_gen doesn't match.  Gen 2 = the in-place
+# against older kernels is stale (ops/attention.dispatch_provenance says
+# so) until bench/ab_kernels.py's micro A/B rewrites it.  Gen 2 = the in-place
 # serving-layout decode/chunk kernels (the gen-1 family transposed the
 # cache per call — see _decode_kernel).
 KERNEL_GEN = 2

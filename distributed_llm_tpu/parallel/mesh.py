@@ -164,13 +164,9 @@ def _fit_sp(tier: TierConfig, available: int, tp: int) -> int:
 
 
 def requested_tp(tier: TierConfig) -> int:
-    """The tier's requested tensor-parallel degree with the ``DLLM_TP``
-    env override applied — the bench A/B lever (multichip leg): force
-    every tier's carve to one tp degree without editing presets.
-    Feasibility clamps (head divisibility, available chips) still run
-    after this in ``_fit_tp``."""
-    from ..config_registry import env_int
-    return max(1, env_int("DLLM_TP", tier.tp))
+    """The tier's requested tensor-parallel degree.  Feasibility clamps
+    (head divisibility, available chips) run after this in ``_fit_tp``."""
+    return max(1, tier.tp)
 
 
 def _fit_tp(tier: TierConfig, available: int) -> int:

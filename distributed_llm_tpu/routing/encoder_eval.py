@@ -1,4 +1,4 @@
-"""Off-generator generalization eval for the embedding space (VERDICT r4 #7).
+"""Off-generator generalization eval for the embedding space.
 
 The r4 hybrid encoder's 0.963 separation was measured on held-out groups
 from the SAME template generator that produced its training data
@@ -19,7 +19,7 @@ and hit/false-hit rates at the SHIPPED cache threshold — the number that
 decides whether a production cache would actually fire on these pairs.
 
 Run:  python -m distributed_llm_tpu.routing.encoder_eval \
-          --out bench/results_r5/offgen_eval.json
+          --out distributed_llm_tpu/routing/offgen_eval.json
 """
 
 from __future__ import annotations

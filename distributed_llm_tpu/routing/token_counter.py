@@ -5,9 +5,8 @@ Reference parity: src/token_counter.py (litellm ``token_counter`` with model
 (src/query_router_engine.py:96).  The reference counts with the SERVED
 model's real BPE tokenizer; since round 3 the engine serves a trained
 subword BPE vocabulary of its own (engine/bpe.py, ~3.5 chars/token on the
-bench queries — the same regime the thresholds were tuned for), so the
-counter uses the EXACT serving tokenizer when the artifact is present
-(VERDICT r2 #3: "makes token_counter exact instead of calibrated").  The
+query sets — the same regime the thresholds were tuned for), so the
+counter uses the EXACT serving tokenizer when the artifact is present.  The
 calibrated estimate — word pieces of ~4 chars plus punctuation, tracking
 the reference's fallback — remains as the artifact-less fallback.
 """

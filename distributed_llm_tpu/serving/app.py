@@ -399,9 +399,9 @@ def create_app(router: Optional[Router] = None,
             cache_stats = router_.query_router.get_cache_stats()
         except Exception:
             cache_stats = None
-        # Measurement provenance: which measured tables steer serving on
-        # THIS backend (attention dispatch, tier tuning) — "none" means
-        # the corresponding defaults are in effect.
+        # Measurement provenance: whether the measured attention-dispatch
+        # table steers serving on THIS backend — "none" means the
+        # defaults are in effect.
         import jax as _jax
         backend = _jax.default_backend()
         provenance = {"backend": backend}
@@ -412,7 +412,7 @@ def create_app(router: Optional[Router] = None,
                 provenance["dispatch"] = disp["backend"]
                 # A table measured against older kernels still dispatches
                 # (re-measuring needs hardware) but must read as
-                # provisional (VERDICT r4 #8).
+                # provisional.
                 provenance["dispatch_kernel_gen"] = disp["kernel_gen"]
                 provenance["dispatch_stale_kernel_gen"] = (
                     disp["stale_kernel_gen"])
@@ -422,12 +422,6 @@ def create_app(router: Optional[Router] = None,
                 provenance["dispatch"] = "none"
         except Exception:
             provenance["dispatch"] = "none"
-        try:
-            from ..bench.tune import load_tuning
-            provenance["tuning"] = (backend if load_tuning(backend)
-                                    else "none")
-        except Exception:
-            provenance["tuning"] = "none"
         payload = {
             "strategy": strategy,
             "sessions": sessions,

@@ -1,7 +1,7 @@
 """Per-tier circuit breaker — failure isolation ahead of the admission queue.
 
-Round 5's on-chip run died wedged (VERDICT.md: 228/228 failed probes) and
-until now the only recovery mechanism was the Router's one-shot failover,
+An early on-chip run died wedged (228/228 failed probes) and
+until then the only recovery mechanism was the Router's one-shot failover,
 applied per request at dispatch time: a flapping tier kept receiving (and
 timing out) its full share of traffic, each failed request burning a
 serving thread for up to ``request_timeout_s`` before failover fired.

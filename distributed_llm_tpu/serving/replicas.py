@@ -554,7 +554,7 @@ class ReplicatedTierClient:
         self._devices = (list(mesh.devices.flat) if mesh is not None
                          else list(devices or []))
         from ..parallel.mesh import requested_tp
-        self._tp_req = requested_tp(tier)  # honors the DLLM_TP override
+        self._tp_req = requested_tp(tier)
         self._seed = seed
         self._warmup_on_start = warmup_on_start
         groups = _split_devices(self._devices, n, self._tp_req)

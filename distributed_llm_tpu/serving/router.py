@@ -65,31 +65,15 @@ _TRANSIENT_MARKERS = (
 )
 
 
-def default_cluster(cpu_bench: bool = False) -> ClusterConfig:
-    """Bench-sized tiers on an accelerator.  On host CPU: the tiny test
-    tiers — unless ``cpu_bench`` is set (the headline bench opts in),
-    where the quality-asymmetric cpu_bench pair (mini_bench under
-    nano_bench-as-orin, config.cpu_bench_cluster) serves when both
-    presets have published checkpoints, so the chipless headline runs
-    genuinely trained, premise-consistent tiers (VERDICT r4 #2).  The
-    opt-in is an explicit parameter, not ambient state: the ~26M/130M
-    pair would make the unit suite's hundreds of default Routers
-    unusably slow on one core.  Either way the tiers serve published
+def default_cluster() -> ClusterConfig:
+    """``bench_cluster()`` on an accelerator, the tiny batched tiers on
+    host CPU (the unit suite builds ``tiny_cluster()`` directly and keeps
+    the cheaper sequential warmup).  Either way the tiers serve published
     pretrained weights when ``checkpoints/<preset>`` exists
     (training/pretrain.py)."""
-    from ..config import (cpu_bench_cluster, default_checkpoint,
-                          tiny_batched_cluster, with_default_checkpoints)
+    from ..config import tiny_batched_cluster, with_default_checkpoints
     if jax.default_backend() != "cpu":
         return with_default_checkpoints(bench_cluster())
-    if cpu_bench:
-        cpu_pair = cpu_bench_cluster()
-        if all(default_checkpoint(t.model_preset)
-               for t in cpu_pair.tiers()):
-            return with_default_checkpoints(cpu_pair)
-    # Concurrent-by-default even on the tiny CPU fallback: serving entry
-    # points and the chipless bench get batched tiers (the unit suite
-    # builds tiny_cluster() directly and keeps the cheaper sequential
-    # warmup).
     return with_default_checkpoints(tiny_batched_cluster())
 
 
@@ -109,7 +93,7 @@ class Router:
         benchmark_mode: True → BENCHMARK_CFG (cache off), False →
         PRODUCTION_CFG, unless ``config`` overrides (src/router.py:37-40).
         observability: metric/trace/flight-recorder bundle (obs/); None =
-        the process-global default — injectable so bench legs and tests
+        the process-global default — injectable so the benchmark and tests
         read registries no other traffic writes to."""
         self.token_counter = TokenCounter()
         self.obs = (observability if observability is not None

@@ -39,9 +39,8 @@ History = Union[str, List[Dict[str, Any]]]
 # eager first-delta pull before ClippedStream releases the primer with an
 # empty delta: small enough that priming never stalls ~a whole
 # generation, large enough that ordinary clipped turns finish their
-# drain inside the prime.  WORST-CASE PRIME-DRAIN BOUND (ADVICE r5
-# tiers.py:204): a stream whose model emits a role marker from token one
-# drains at most THIS many characters — ≈ PRIME_DRAIN_CHARS / 3.5 ≈ 74
+# drain inside the prime.  WORST-CASE PRIME-DRAIN BOUND: a stream
+# whose model emits a role marker from token one drains at most THIS many characters — ≈ PRIME_DRAIN_CHARS / 3.5 ≈ 74
 # BPE tokens of decoding (~3.5 chars/token on the bench sets) — inside
 # ``process_stream`` while holding a sequential engine's lock, before
 # the "" sentinel releases the primer; without the cap the same prime

@@ -19,9 +19,8 @@ from typing import Iterator, Optional
 # (engine/tokenizer.py format_history): "role: content" lines.
 _ROLES = ("user:", "assistant:", "system:")
 # Longest text a marker can span, for the streaming hold-back (11 chars:
-# "assistant:" + newline).  WORST CASE of the hold-back (ADVICE r5
-# tiers.py:204): nothing is emitted until >HOLDBACK chars accumulate,
-# and a stream whose model emits a role marker from token one NEVER
+# "assistant:" + newline).  WORST CASE of the hold-back: nothing is
+# emitted until >HOLDBACK chars accumulate, and a stream whose model emits a role marker from token one NEVER
 # emits — ClippedStream then silently drains the rest of the generation
 # for its result/lock, so an eager first-delta primer
 # (serving/tiers.py _PrimedStream) would block a serving thread for the

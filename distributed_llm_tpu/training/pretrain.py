@@ -1,5 +1,5 @@
 """Pretrain a model preset on the synthetic corpus to a loss plateau and
-publish a serving checkpoint (VERDICT r1 Missing #1 / Next #4).
+publish a serving checkpoint.
 
 The reference never trains anything — its tiers serve Ollama-pulled
 pretrained models (src/devices/nano_api.py:15-16, orin_api.py:17-18).

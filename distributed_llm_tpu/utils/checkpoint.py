@@ -118,7 +118,7 @@ def save_train_state(path: str, trainer) -> Optional[str]:
     because this exact step is already the published ``latest`` — the
     caller can then advance a step and retry if its state genuinely
     differs (resume from an older version reached by a different path);
-    a log warning alone gave no programmatic signal (ADVICE r5)."""
+    a log warning alone gave no programmatic signal."""
     root = _abspath(path)
     os.makedirs(root, exist_ok=True)
     version_dir = os.path.join(root, f"v{trainer.step_count}")

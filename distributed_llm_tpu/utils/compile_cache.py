@@ -15,7 +15,7 @@ directory inside the checkout (the path is part of the cache key's
 surroundings — a directory that moves never hits).
 
 The test suite wires the same rule in tests/conftest.py; this helper is
-for the runtime entry points (serving/app.py, chip_smoke.py, bench.py,
+for the runtime entry points (serving/app.py, chip_smoke.py,
 bench.tester, ab_kernels, training.pretrain).  Call before the first
 device computation.
 """

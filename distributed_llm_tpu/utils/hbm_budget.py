@@ -1,7 +1,7 @@
 """Serving HBM budget: does a tier's model + KV actually fit its submesh?
 
-VERDICT r2 #2: the flagship presets (nano_1b / orin_8b / moe_8x1b) were
-"dead config" — nothing ever verified that orin_8b (~7B params, ~14 GB
+The flagship presets (nano_1b / orin_8b / moe_8x1b) were once
+"dead config": nothing verified that orin_8b (~7B params, ~14 GB
 bf16) plus a KV pool fits its tp=4 submesh at 16 GB/chip.  This module
 budgets a tier with ``jax.eval_shape`` over the REAL code paths — the
 model family's init (models/__init__.py), the serving quantizer

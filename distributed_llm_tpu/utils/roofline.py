@@ -5,8 +5,9 @@ arithmetic (src/devices/nano_api.py:76 just forwards a JSON blob), so its
 benchmarks report wall-clock only.  Here every engine phase also accounts
 the work the hardware did — matmul FLOPs and HBM bytes, derived from the
 model config and the *computed* shapes (padded buckets, masked cache
-spans), not the logical token counts — so the bench can report MFU and
-HBM-bandwidth utilization against chip peaks and place each phase on the
+spans), not the logical token counts — so a reader of GET /stats can
+set it against the chip's peaks (the benchmark keeps those, with its own
+byte counts: benchmark/peaks.json) and place each phase on the
 roofline: prefill is compute-bound (judge by MFU), decode is
 bandwidth-bound (judge by HBM utilization).
 
@@ -23,41 +24,7 @@ Conventions (How-to-Scale-Your-Model accounting):
 
 from __future__ import annotations
 
-from typing import Any, Dict, Optional
-
-# Chip peaks for utilization denominators, keyed by the ``device_kind``
-# jax reports.  A kind that is not in the table is an error, not a
-# default: a utilization against guessed peaks reads like a measurement
-# and is not one.
-CHIP_PEAKS: Dict[str, Dict[str, Any]] = {
-    "TPU v5 lite": {
-        "chip": "tpu_v5e",
-        "peak_flops": 197e12,               # bf16 on the MXU
-        "peak_hbm_bytes_per_s": 819e9,
-        "source": "Google Cloud documentation, \"TPU v5e\": 197 TFLOP/s "
-                  "bf16, 16 GB HBM at 819 GB/s per chip",
-    },
-}
-
-
-def chip_peaks(device=None) -> Optional[Dict[str, Any]]:
-    """Peak FLOP/s and HBM B/s of ``device`` (default: the process's
-    first jax device), looked up by its ``device_kind``.  None on the
-    host CPU, which has no roofline here (tests); any other device whose
-    kind is not in ``CHIP_PEAKS`` raises."""
-    if device is None:
-        import jax
-        device = jax.devices()[0]
-    if device.platform == "cpu":
-        return None
-    try:
-        return dict(CHIP_PEAKS[device.device_kind])
-    except KeyError:
-        raise ValueError(
-            f"no published peaks for device_kind={device.device_kind!r} "
-            f"(platform {device.platform!r}); add it to "
-            f"utils/roofline.CHIP_PEAKS with its source") from None
-
+from typing import Dict, Optional
 
 def _attention_params(cfg) -> int:
     """One layer's attention matrices."""
@@ -171,21 +138,3 @@ def decode_work(cfg, steps: int, ctx: int, batch: int = 1,
     hbm = float(steps) * (wbytes + kvb
                           * kv_bytes_per_pos(cfg, kv_quantize) * span)
     return {"flops": flops, "hbm_bytes": hbm, "tokens": steps * batch}
-
-
-def utilization(work: Dict[str, Any], seconds: float,
-                peaks: Optional[Dict[str, float]]) -> Dict[str, Any]:
-    """MFU + HBM utilization for accumulated work over measured seconds."""
-    out: Dict[str, Any] = {
-        "tflops_per_s": round(work.get("flops", 0.0) / max(seconds, 1e-9)
-                              / 1e12, 4),
-        "hbm_gb_per_s": round(work.get("hbm_bytes", 0.0) / max(seconds, 1e-9)
-                              / 1e9, 3),
-    }
-    if peaks:
-        out["mfu"] = round(work.get("flops", 0.0)
-                           / max(seconds, 1e-9) / peaks["peak_flops"], 4)
-        out["hbm_util"] = round(work.get("hbm_bytes", 0.0)
-                                / max(seconds, 1e-9)
-                                / peaks["peak_hbm_bytes_per_s"], 4)
-    return out

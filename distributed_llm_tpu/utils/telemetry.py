@@ -108,9 +108,8 @@ class PhaseTimer:
 
 
 def engine_stats(engine) -> Dict[str, Any]:
-    """Per-engine observability snapshot shared by GET /stats
-    (serving/app.py) and bench.py's tier section — one assembler so the
-    two surfaces cannot drift.  Tolerates any engine type (remote tiers
+    """Per-engine observability snapshot behind GET /stats
+    (serving/app.py: tier and replica entries alike).  Tolerates any engine type (remote tiers
     have none; batching/speculative engines expose different subsets)."""
     entry: Dict[str, Any] = {}
     if engine is None:

@@ -1,4 +1,4 @@
-"""Trained subword BPE tokenizer (engine/bpe.py, VERDICT r2 #3).
+"""Trained subword BPE tokenizer (engine/bpe.py).
 
 The engine serves subword ids end-to-end since round 3; these tests pin
 the training algorithm (deterministic, word-bounded merges), the encode/

@@ -146,8 +146,8 @@ def test_save_replaces_stale_same_step_version(tmp_path):
 
 def test_peek_vocab_size_reads_metadata_only():
     """A stale-vocab guard before serving or resuming a checkpoint
-    depends on this returning the real embed row count (ADVICE-style regression: the orbax metadata
-    pytree lives under item_metadata.tree)."""
+    depends on this returning the real embed row count (the orbax
+    metadata pytree lives under item_metadata.tree)."""
     from distributed_llm_tpu.config import MODEL_PRESETS, default_checkpoint
     from distributed_llm_tpu.utils.checkpoint import peek_vocab_size
     ckpt = default_checkpoint("nano_test")

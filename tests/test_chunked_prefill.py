@@ -5,8 +5,8 @@ is a first-class scheduler citizen — cancel-and-requeue under KV
 pressure, KV-aware admission accounting, drain, and stop all treat it
 like admitted work.
 
-Fast deterministic tests only; the timing-sensitive interference
-measurement lives in bench.py's mixed_phase leg.
+Fast deterministic tests only; the interference measurement is the
+chip benchmark's long-prompt cell (benchmark/traffic/long-prompt.json).
 """
 
 import dataclasses

@@ -1,7 +1,6 @@
 """Trained semantic encoder + hybrid embedding space
 (routing/encoder.py, routing/embedder.py HybridEmbedder): the in-repo
-MiniLM stand-in for the semantic strategy and cache (VERDICT r3
-missing #1).
+MiniLM stand-in for the semantic strategy and cache.
 
 The decisive capability: a paraphrase with (near-)disjoint wording must
 hit the semantic cache under the shipped (hybrid) embedder and MISS
@@ -135,7 +134,7 @@ def test_cache_survives_cross_embedder_persistence(tmp_path):
 
 
 def test_offgen_eval_artifact_in_sync_and_honest():
-    """The off-generator generalization eval (VERDICT r4 #7): the
+    """The off-generator generalization eval: the
     committed artifact must match a live re-run (same pairs, same
     embedders), and its headline finding — NO shipped embedder
     generalizes to hand-written off-domain pairs the way MiniLM would
@@ -151,7 +150,7 @@ def test_offgen_eval_artifact_in_sync_and_honest():
     assert len(pos) >= 50 and len(neg) >= 50
     live = run_eval()
     art_path = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "bench", "results_r5",
+        os.path.abspath(__file__))), "distributed_llm_tpu", "routing",
         "offgen_eval.json")
     with open(art_path) as f:
         committed = json.load(f)

@@ -235,8 +235,8 @@ def test_long_suffix_reuse_chunks_from_matched_prefix():
 def test_warmup_feeds_liveness_beats():
     """Every engine warmup fires its beat callback per compiled program
     (and EngineManager forwards it): on chip a full warmup is dozens of
-    20-40 s compiles — silent, it would idle out bench.py's 900 s wedge
-    watchdog and abort the headline before serving starts."""
+    20-40 s compiles — silent, it would idle out a caller's wedge
+    watchdog before serving starts."""
     from distributed_llm_tpu.config import tiny_cluster
     from distributed_llm_tpu.engine.manager import EngineManager
 

@@ -1,4 +1,4 @@
-"""Frontend ↔ server contract (VERDICT r4 #9).
+"""Frontend ↔ server contract.
 
 No JS runtime ships in this image, so ``frontend/app.js`` cannot be
 EXECUTED against the server the way the reference React app runs in a

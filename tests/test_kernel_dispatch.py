@@ -1,4 +1,4 @@
-"""Measured per-(kind, length) kernel dispatch (VERDICT r1 #3).
+"""Measured per-(kind, length) kernel dispatch.
 
 ops/attention.py's dispatching wrappers consult bench/ab_dispatch.json —
 written by `ab_kernels micro --write-dispatch` on real hardware — instead
@@ -26,7 +26,7 @@ def test_measured_table_demotes_per_length(table):
     # Exact rung wins.
     assert A._choose("pallas", "decode", 256) == "pallas"
     assert A._choose("pallas", "decode", 2048) == "xla"
-    # Off-ladder shapes snap to the NEAREST measured rung (ADVICE r2: the
+    # Off-ladder shapes snap to the NEAREST measured rung (the
     # batched engine's trimmed paged windows take many values; nearest
     # rung beats the kind-wide default when rungs exist).
     assert A._choose("pallas", "decode", 320) == "pallas"
@@ -82,8 +82,8 @@ def test_registry_matches_consulted_kinds_and_ab_grid():
 
 def test_committed_table_covers_every_registered_kernel():
     """The shipped ab_dispatch.json must carry an entry (with a default)
-    for EVERY registered dispatch kind — VERDICT r5 weak #2 was exactly
-    this table silently falling behind the shipped kernels (paged_chunk
+    for EVERY registered dispatch kind — the table once fell silently
+    behind the shipped kernels (paged_chunk
     had no row; chunk's pallas verdict predated the gen-2 rewrite)."""
     with open(A._DISPATCH_PATH) as f:
         data = json.load(f)
@@ -126,7 +126,7 @@ def test_micro_ab_writes_dispatch(tmp_path, monkeypatch):
 
 
 def test_micro_ab_fast_mode_covers_all_kinds(tmp_path, monkeypatch):
-    """The in-bench fast A/B (bench.py's self-measuring path) must still
+    """The fast A/B (``ab_kernels micro --fast``) must still
     produce a table covering every dispatch kind, with per-kind defaults,
     and beat its liveness callback per case."""
     from distributed_llm_tpu.bench import ab_kernels
@@ -239,8 +239,7 @@ def test_loader_provenance_flags_stale_kernel_gen(tmp_path, monkeypatch,
     """A same-backend table whose kernel_gen is absent or behind the
     current Pallas kernels still dispatches, but the loader logs the
     staleness and dispatch_provenance() (surfaced at /stats) reports it —
-    stale hardware conclusions must be visibly provisional (VERDICT r4
-    #8)."""
+    stale hardware conclusions must be visibly provisional."""
     import logging
 
     from distributed_llm_tpu.ops import pallas_attention as PA

@@ -18,7 +18,7 @@ The race matrix this file pins (the ISSUE 14 satellite):
   out (drain/stop flush);
 - host-LRU eviction never drops an entry with a promotion in flight.
 
-Timing-sensitive throughput claims live in bench.py's spill leg; these
+Throughput claims belong to the chip benchmark (benchmark/); these
 are fast deterministic tests (the copier pause/resume hook makes the
 races schedulable instead of probabilistic).
 """

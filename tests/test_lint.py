@@ -755,7 +755,7 @@ def test_config_drift_flags_unregistered_env_read():
 
         VAL = os.environ.get("DLLM_DEFINITELY_NOT_REGISTERED", "x")
     """
-    result = _lint(ConfigDriftChecker(), {"bench.py": src})
+    result = _lint(ConfigDriftChecker(), {"scripts/probe.py": src})
     unregistered = [f for f in result.findings
                     if f.rule == "config-env-unregistered"]
     assert len(unregistered) == 1
@@ -766,30 +766,30 @@ def test_config_drift_near_miss_registered_read():
     src = """
         import os
 
-        VAL = os.environ.get("DLLM_BENCH_REPEATS", "3")
+        VAL = os.environ.get("DLLM_PROFILE_TICKS", "256")
     """
-    result = _lint(ConfigDriftChecker(), {"bench.py": src})
+    result = _lint(ConfigDriftChecker(), {"scripts/probe.py": src})
     assert not [f for f in result.findings
                 if f.rule == "config-env-unregistered"]
 
 
 def test_registry_accessors_fail_loudly_on_typo():
     with pytest.raises(UnknownConfigError):
-        env_int("DLLM_BENCH_REPEAT", 3)          # typo'd name
+        env_int("DLLM_PROFILE_TICK", 3)         # typo'd name
     with pytest.raises(UnknownConfigError):
         env_str("DLLM_NOT_A_KNOB")
-    assert env_int("DLLM_BENCH_REPEATS", 3) == 3  # unset -> default
+    assert env_int("DLLM_PROFILE_TICKS", 3) == 3  # unset -> default
 
 
 def test_registry_accessors_read_environment(monkeypatch):
-    monkeypatch.setenv("DLLM_BENCH_REPEATS", "7")
-    assert env_int("DLLM_BENCH_REPEATS", 3) == 7
-    monkeypatch.setenv("DLLM_BENCH_REPEATS", "garbage")
-    assert env_int("DLLM_BENCH_REPEATS", 3) == 3  # never lose the run
-    monkeypatch.setenv("DLLM_BENCH_SPEC_ORIN", "1")
-    assert env_flag("DLLM_BENCH_SPEC_ORIN")
-    monkeypatch.delenv("DLLM_BENCH_SPEC_ORIN")
-    assert not env_flag("DLLM_BENCH_SPEC_ORIN")
+    monkeypatch.setenv("DLLM_PROFILE_TICKS", "7")
+    assert env_int("DLLM_PROFILE_TICKS", 3) == 7
+    monkeypatch.setenv("DLLM_PROFILE_TICKS", "garbage")
+    assert env_int("DLLM_PROFILE_TICKS", 3) == 3  # never lose the run
+    monkeypatch.setenv("DLLM_FLAGSHIP_KV_INT8", "1")
+    assert env_flag("DLLM_FLAGSHIP_KV_INT8")
+    monkeypatch.delenv("DLLM_FLAGSHIP_KV_INT8")
+    assert not env_flag("DLLM_FLAGSHIP_KV_INT8")
 
 
 def test_config_md_in_sync_with_registry():
@@ -2288,6 +2288,38 @@ def test_repo_suppressions_all_reference_real_rules():
         for rules in mod.suppressions.by_line.values():
             assert rules <= known, (rel, rules)
         assert mod.suppressions.file_level <= known, rel
+
+
+@pytest.fixture(scope="module")
+def default_modules():
+    from distributed_llm_tpu.lint import load_project
+    return load_project(repo_root()).modules
+
+
+@pytest.mark.parametrize("checker", all_checkers(),
+                         ids=lambda c: c.name)
+def test_every_checker_scope_names_something_the_run_parses(
+        checker, default_modules):
+    """A scope entry is a path prefix; one that matches no module of the
+    default project (a file renamed or deleted since) examines nothing
+    and reports nothing, forever."""
+    modules = default_modules
+    for prefix in checker.scope:
+        assert any(rel == prefix or rel.startswith(prefix.rstrip("/") + "/")
+                   for rel in modules), (checker.name, prefix)
+
+
+def test_every_registered_env_var_names_a_consumer_that_exists():
+    """The ``consumer`` column of CONFIG.md points at files of the
+    package (or of the repo's root): a row whose reader was deleted must
+    go with it, not keep naming it."""
+    root = repo_root()
+    for name, entry in ENV_VARS.items():
+        for consumer in entry.consumer.split(","):
+            consumer = consumer.strip()
+            assert any(os.path.isfile(os.path.join(root, base, consumer))
+                       for base in ("distributed_llm_tpu", "")), (
+                name, consumer)
 
 
 # -- ownership & lifecycle dataflow (ISSUE 19 tentpole) ----------------------

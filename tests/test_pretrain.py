@@ -1,5 +1,5 @@
 """training/pretrain.py: plateau detection, mid-run checkpointing, and
-the published-artifact layout serving reads (VERDICT r1 #4 machinery)."""
+the published-artifact layout serving reads."""
 
 import jax
 
@@ -59,8 +59,8 @@ def test_resume_extends_lr_schedule_past_horizon(tmp_path):
     """A resume whose restored step counter sits at/past the cosine
     horizon must NOT train at the schedule floor: pretrain stretches the
     horizon to resumed_from + max_steps so the extension run decays over
-    its own steps (ADVICE r4 medium — the quality-gate extensions were
-    0-LR no-ops)."""
+    its own steps (the quality-gate extensions were once 0-LR
+    no-ops)."""
     import numpy as np
 
     from distributed_llm_tpu.config import MODEL_PRESETS
@@ -133,7 +133,7 @@ def test_heldout_eval_deterministic_and_seed_disjoint(tmp_path):
 
 
 def test_tier_quality_asymmetry_on_committed_checkpoints():
-    """The routing premise, measured (VERDICT r3 missing #2): the bigger
+    """The routing premise, measured: the bigger
     orin_test checkpoint beats nano_test on held-out per-token loss over
     the identical token stream."""
     from distributed_llm_tpu.config import MODEL_PRESETS

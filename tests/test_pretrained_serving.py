@@ -1,4 +1,4 @@
-"""Serving from published pretrained checkpoints (VERDICT r1 Missing #1).
+"""Serving from published pretrained checkpoints.
 
 The reference's tiers serve real pretrained models via Ollama
 (src/devices/nano_api.py:15-16); round 1 here served random weights, so

@@ -344,64 +344,3 @@ def test_sampler_exports_tick_phase_gauges():
         "nano", "emit").value == pytest.approx(0.1)
     assert obs.metrics.get("dllm_profile_coverage").labels(
         "nano").value == pytest.approx(0.97)
-
-
-# -- bench trend satellite ---------------------------------------------------
-
-def _load_bench_trend():
-    import importlib.util
-    import os
-    spec = importlib.util.spec_from_file_location(
-        "bench_trend",
-        os.path.join(os.path.dirname(os.path.dirname(
-            os.path.abspath(__file__))), "scripts", "bench_trend.py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-def test_bench_trend_table_and_regression_flags(tmp_path):
-    """scripts/bench_trend.py: reads round captures AND a finalized
-    partial, skips a dead partial, extracts both artifact shapes, and
-    flags regressions on the pinned keys with correct direction."""
-    bt = _load_bench_trend()
-    # Two driver-shape rounds (compact FINAL under "parsed").
-    (tmp_path / "BENCH_r01.json").write_text(json.dumps({
-        "rc": 0, "parsed": {"trend_req_per_s": 30.0,
-                            "skew_tick_ratio": 0.9,
-                            "openloop": {"knee": 25.0}, "value": 40.0}}))
-    (tmp_path / "BENCH_r02.json").write_text(json.dumps({
-        "rc": 0, "parsed": None}))              # unparsed round: skipped
-    (tmp_path / "BENCH_r03.json").write_text(json.dumps({
-        "rc": 0, "parsed": {"trend_req_per_s": 32.0,
-                            "skew_tick_ratio": 0.88,
-                            "openloop": {"knee": 27.0}}}))
-    # Finalized partial in DETAIL shape: regressed trend + skew.
-    (tmp_path / "BENCH_partial.json").write_text(json.dumps({
-        "final": True,
-        "trend": {"trend_req_per_s": 10.0},
-        "skew": {"tick_p50_ratio_ragged_over_dense": 1.4},
-        "openloop": {"knee_req_per_s": 26.0},
-    }))
-    rounds, notes = bt.load_rounds(str(tmp_path))
-    assert [label for label, _ in rounds] == ["r01", "r03", "partial"]
-    assert any("r02" in n for n in notes)
-    assert rounds[-1][1]["trend_req_per_s"] == 10.0
-    assert rounds[-1][1]["openloop.knee"] == 26.0   # detail-shape path
-    flags = bt.flag_regressions(rounds, threshold=0.25)
-    assert len(flags) == 2
-    assert any("trend_req_per_s" in f for f in flags)
-    assert any("skew_tick_ratio" in f for f in flags)
-    assert not any("openloop.knee" in f for f in flags)  # within bound
-    table = bt.trend_table(rounds)
-    assert "trend_req_per_s" in table and "r03" in table
-    assert bt.main(["--dir", str(tmp_path)]) == 1   # regression exit
-
-    # A dead partial (no final marker) is skipped with a note.
-    (tmp_path / "BENCH_partial.json").write_text(json.dumps({
-        "trend": {"trend_req_per_s": 1.0}}))
-    rounds2, notes2 = bt.load_rounds(str(tmp_path))
-    assert [label for label, _ in rounds2] == ["r01", "r03"]
-    assert any("final" in n for n in notes2)
-    assert bt.flag_regressions(rounds2, threshold=0.25) == []
-    assert bt.main(["--dir", str(tmp_path)]) == 0

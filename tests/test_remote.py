@@ -171,7 +171,7 @@ def test_health_monitor_survives_dead_remote_tier():
 
 
 def test_remote_revival_dead_to_serving(tmp_path):
-    """The supervisor contract end to end (VERDICT r3 #9): a spawn_cmd-
+    """The supervisor contract end to end: a spawn_cmd-
     equipped RemoteServerManager starts the tier server process, the
     process is killed out from under it (remote host crash), the health
     monitor counts the dead /health as failures and auto-restart

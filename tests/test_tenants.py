@@ -290,6 +290,55 @@ def test_quotas_off_and_on_outputs_byte_identical():
     assert ids["off"] == ids["on"]
 
 
+def test_flooders_quota_sheds_the_flooder_never_the_quiet_tenant():
+    """Noisy neighbour: a tenant held to one seat and no queue on BOTH
+    tiers floods from three closed-loop clients beside a quiet tenant on
+    the unset default.  Every quiet request is answered, and every
+    tenant-shaped rejection lands on the flooder (failover cannot launder
+    the flood onto the other tier)."""
+    flood = {"flood": TenantQuota(weight=0.25, max_inflight=1, max_queued=0)}
+    base = tiny_batched_cluster(nano_slots=2, orin_slots=2)
+    cluster = dataclasses.replace(
+        base,
+        nano=dataclasses.replace(base.nano, tenant_quotas=flood),
+        orin=dataclasses.replace(base.orin, tenant_quotas=flood))
+    obs = Observability(slow_ms=None)
+    router = Router(strategy="heuristic", benchmark_mode=True,
+                    cluster=cluster, observability=obs)
+    answers = {"flood": [], "quiet": []}
+
+    def client(tenant, i):
+        for turn in range(3):
+            doc, _, _dev = router.route_query(
+                [{"role": "user",
+                  "content": f"{tenant} client {i} turn {turn}: tell me "
+                             f"about rivers and lakes and oceans please"}],
+                tenant_id=tenant)
+            answers[tenant].append(bool(doc.get("ok")))
+
+    try:
+        for tier in router.tiers.values():
+            tier.server_manager.start_server()
+        threads = [threading.Thread(target=client, args=("flood", i),
+                                    daemon=True) for i in range(3)]
+        threads.append(threading.Thread(target=client, args=("quiet", 0),
+                                        daemon=True))
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=120)
+        assert not any(t.is_alive() for t in threads)
+    finally:
+        for tier in router.tiers.values():
+            tier.server_manager.stop_server()
+    assert answers["quiet"] == [True] * 3
+    assert len(answers["flood"]) == 9 and any(answers["flood"])
+    fam = obs.metrics.get("dllm_tenant_rejected_total")
+    rejected = {tenant: c.value
+                for (_tier_name, tenant), c in fam.children().items()}
+    assert set(rejected) == {"flood"} and rejected["flood"] >= 1, rejected
+
+
 # -- serving edge: tenant_id validation and plumbing -------------------------
 
 @pytest.fixture(scope="module")

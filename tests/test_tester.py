@@ -155,7 +155,7 @@ def test_ab_kernels_smoke(capsys):
 
 def test_long_context_set_straddles_threshold_sweep():
     """The long_context query set exists to de-degenerate the reference's
-    signature token-threshold sweep (VERDICT r4 weak #5): its query+context
+    signature token-threshold sweep: its query+context
     token counts must straddle the swept 100→4000 range so orin's share
     varies across at least 4 threshold points instead of collapsing to
     zero past 500."""

@@ -1,5 +1,5 @@
-"""Shard-mapped flash prefill on tensor-parallel meshes (VERDICT r1
-weak #2: sharded tiers previously never took the Pallas path).
+"""Shard-mapped flash prefill on tensor-parallel meshes (sharded tiers
+previously never took the Pallas path).
 
 The flash kernel runs per head-shard under shard_map with zero added
 collectives; these tests force the Pallas preference with

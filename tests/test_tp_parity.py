@@ -226,3 +226,41 @@ def test_tp2_self_draft_accepts_everything():
         eng.stop()
     base, _ = _outputs(_tier(), 1, PROMPTS[:2])
     assert out == base
+
+
+# -- capacity is enforced, not discovered --------------------------------------
+
+@pytest.mark.parametrize("tp", [1, 2])
+def test_hbm_budget_refuses_at_tp1_and_serves_at_tp2(tp):
+    """``TierConfig.hbm_gb_per_chip`` set between the two per-chip
+    footprints: the unsharded tier is refused by ``start_server`` before
+    a weight exists (TierOverCapacityError, no engine), the tp=2 tier of
+    the same model fits and serves.  mini_bench, because nano_test's
+    footprint vanishes under the budget's rounding."""
+    from distributed_llm_tpu.engine.manager import (EngineManager,
+                                                    TierOverCapacityError)
+    from distributed_llm_tpu.utils.hbm_budget import tier_hbm_budget
+
+    mesh2 = _mesh(2)
+    base = dataclasses.replace(
+        _tier(max_new_tokens=4), model_preset="mini_bench", decode_batch=2,
+        prefill_buckets=(16, 32, 64))
+    b1 = tier_hbm_budget(base)["total_gb_per_chip"]
+    b2 = tier_hbm_budget(dataclasses.replace(base, tp=2),
+                         mesh=mesh2)["total_gb_per_chip"]
+    assert b2 < b1
+    budget = (b1 + b2) / 2 + 0.75           # + the activation headroom
+    tier = dataclasses.replace(base, tp=tp, hbm_gb_per_chip=budget)
+    mgr = EngineManager(tier, mesh=mesh2 if tp == 2 else None,
+                        devices=None if tp == 2 else jax.devices()[:1],
+                        warmup_on_start=False)
+    try:
+        if tp == 1:
+            with pytest.raises(TierOverCapacityError, match="raise tp"):
+                mgr.start_server()
+            assert mgr._engine is None and not mgr.is_server_running()
+        else:
+            mgr.start_server()
+            assert mgr.engine().generate("hello rivers").token_ids
+    finally:
+        mgr.stop_server()

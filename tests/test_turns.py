@@ -94,7 +94,7 @@ def test_clipped_stream_quoted_marker_on_cut_boundary_not_clipped():
 
 
 def test_clipped_stream_prime_drain_cap_releases_early():
-    """ADVICE r5 tiers.py:204: a marker from token one makes the clipped
+    """A marker from token one makes the clipped
     drain consume the WHOLE generation inside a single next() — with
     ``prime_drain_chars`` the stream yields one empty delta once that
     many chars have drained, so an eager primer returns early; the rest
