@@ -228,6 +228,13 @@ class _Prefill:
     promote_done: int = 0
     promote_waits: int = 0
     t_start: float = dataclasses.field(default_factory=time.perf_counter)
+    # The ONE chunk whose outputs the host has not waited for yet (the
+    # chunk program's first output as dispatched: the sampled token,
+    # with a routed-expert model's assignment counts) and when it was
+    # dispatched.  ``_settle_chunk`` resolves it: before the next chunk
+    # is dispatched, or when the prefill ends either way.
+    pending: Optional[Any] = None
+    pending_t: float = 0.0
 
 
 class _ExpertLoad:
@@ -504,6 +511,16 @@ class ContinuousBatchingEngine:
         # Cancel-and-requeue count over the engine's life (the prefill
         # twin of preempted_total; prefill_stats exposes it).
         self.prefill_cancelled_total = 0
+        # Chunks dispatched over the engine's life, and how many of them
+        # behind a tick whose tokens the host had not fetched yet
+        # (``_ride_chunk``): how often the overlap engages
+        # (prefill_stats, dllm_prefill_chunks_total).
+        self.prefill_chunks_total = 0
+        self.prefill_chunks_overlapped_total = 0
+        # perf_counter of the last plain tick's fetch return: the
+        # latest moment the host saw the device reach whatever was
+        # queued behind that tick (``_settle_chunk``'s clock).
+        self._tick_fetched_t = 0.0
 
         # Session prefix reuse over pool blocks: a finished request's
         # prompt blocks are parked (ownership moves to the store) and a
@@ -1942,26 +1959,26 @@ class ContinuousBatchingEngine:
         # caller's exception handler must not also release it.
         self._prefill = pf
 
-    def _advance_prefill(self) -> bool:
-        """Spend up to ``chunk_budget`` tokens advancing the in-flight
-        prefill — the tail half of a scheduler tick (decode slots were
-        served first, so active streams stall at most one budget grant).
-        Each chunk scatters its K/V straight into the slot's pool
-        blocks via the SAME compiled (chunk, window-rung) program family
-        the prefix-reuse suffix path uses; a dry pool stalls the prefill
-        (retry next tick) rather than starving decode growth.  Returns
-        whether any chunk landed (False = stalled dry), so a solo
-        prefill's loop can back off instead of hot-spinning on an
-        allocator that nothing will refill."""
+    def _advance_prefill(self, budget: Optional[int] = None) -> bool:
+        """Spend up to ``budget`` tokens (default ``chunk_budget``)
+        advancing the in-flight prefill — what a scheduler pass does
+        for it outside the tick: all of a solo prefill's pass, and in a
+        pass with a tick whatever ``_ride_chunk`` left of the budget,
+        after the emit.  Each chunk scatters its K/V straight into the
+        slot's pool blocks via the SAME compiled (chunk, window-rung)
+        program family the prefix-reuse suffix path uses, and is NOT
+        waited for (``_dispatch_chunk``); a prompt's last chunk is
+        settled here and its slot goes live.  A dry pool stalls the
+        prefill (retry next tick) rather than starving decode growth.
+        Returns whether anything landed (False = stalled dry, or no
+        budget), so a solo prefill's loop can back off instead of
+        hot-spinning on an allocator that nothing will refill."""
         pf = self._prefill
         if pf is None:
             return True
         progressed = False
-        req = pf.request
         c = self.chunk_tokens
-        bs = self.paged.block_size
-        span = self.paged.blocks_per_slot * bs
-        budget_left = self.chunk_budget
+        budget_left = self.chunk_budget if budget is None else budget
         try:
             if pf.promote_entry is not None:
                 moved, budget_left = self._advance_promotion(pf,
@@ -1973,78 +1990,167 @@ class ContinuousBatchingEngine:
                     # retry next tick — decode never waits on it.
                     return progressed
             while pf.consumed < pf.total and budget_left >= c:
-                start = pf.consumed
-                if start + c > span:
-                    # Final sliver near the table's end: slide the chunk
-                    # back so every position stays inside the table (an
-                    # overflowing pad position would CLAMP its block
-                    # index onto a real block and corrupt live KV).  The
-                    # overlap recomputes identical K/V — harmless.
-                    start = span - c
-                end = start + c
-                need = min(pf.max_blocks, -(-min(end, pf.total) // bs))
-                if len(pf.blocks) < need:
-                    extra = self._alloc_evicting(need - len(pf.blocks))
-                    if extra is None:
-                        # Pool dry: stall, retry next tick.
-                        return progressed
-                    pf.blocks.extend(extra)
-                window = next(w for w in self._chunk_windows if w >= end)
-                k = min(end, pf.total) - start
-                tokens = np.full((1, c), self.tokenizer.pad_id, np.int32)
-                tokens[0, :k] = pf.seq[start:start + k]
-                t_chunk = time.perf_counter()
-                with obs_spans.span(req.trace, "prefill_chunk",
-                                    start=start, tokens=k,
-                                    window=window), \
-                        self.phases.phase("prefill"), \
-                        self.profiler.phase("chunk_prefill"):
-                    first, self.pool = self._chunk_prefill_fn(c, window)(
-                        self.params, self.pool, jnp.asarray(tokens),
-                        jnp.asarray([start], np.int32),
-                        jnp.asarray([pf.total], np.int32),
-                        jnp.asarray(self._table_row(pf.blocks)), pf.rng,
-                        jnp.float32(pf.temperature))
-                    # dllm-lint: disable=transfer-host-sync -- sanctioned: the chunk IS the budgeted stall unit — its device time is exactly the TBT bound this design promises (and the histogram evidences), and the final chunk's sampled token must reach the host regardless; an async chunk would just move the same wait into the next decode tick's sync
-                    first = self._chunk_result(jax.block_until_ready(first))
-                chunk_ms = (time.perf_counter() - t_chunk) * 1000.0
-                from ..utils import roofline
-                self.phases.add_work("prefill", **roofline.prefill_work(
-                    self.cfg, end, start, wbytes=self._wbytes))
-                try:
-                    # No injection path on the engine (same pattern as
-                    # the tick histogram): the process-global registry.
-                    from ..obs import get_observability
-                    get_observability().m.prefill_chunk_ms.labels(
-                        self.tier.name).observe(chunk_ms)
-                except Exception:
-                    pass
-                pf.consumed = min(end, pf.total)
-                pf.chunks_done += 1
+                if not self._dispatch_chunk(pf, overlapped=False):
+                    return progressed          # pool dry: retry next tick
                 progressed = True
                 budget_left -= c
-                self._progress_t = time.monotonic()
-                if pf.consumed >= pf.total:
-                    self._finish_prefill(pf, int(first))
-                    return True
-        except BaseException as exc:       # surface to the caller
-            self._prefill = None
-            if pf.promote_entry is not None and self.kv_spill is not None:
-                self.kv_spill.release(pf.promote_entry, promoted=False)
-                pf.promote_entry = None
-            slot = self._slots[pf.slot_ix]
-            if slot is not None and slot.request is req:
-                # The final chunk had already gone live as a slot when
-                # the failure surfaced: the SLOT owns the blocks now.
-                self._fail_slot(pf.slot_ix, exc)
+            if pf.consumed >= pf.total:
+                # The prompt's last chunk is out: the one wait a prefill
+                # cannot do without, for the token its slot starts from.
+                with self.profiler.phase("chunk_prefill"):
+                    first = self._settle_chunk(pf)
+                self._finish_prefill(pf, int(first))
                 return True
-            self.allocator.free(pf.blocks)
-            req.error = exc
-            if req.token_queue is not None:
-                req.token_queue.put(None)
-            req.done.set()
+        except BaseException as exc:       # surface to the caller
+            self._fail_prefill(pf, exc)
             return True
         return progressed
+
+    def _ride_chunk(self) -> int:
+        """Between a plain tick's dispatch and its fetch: enqueue the
+        in-flight prefill's next chunk on the device BEHIND the tick, so
+        the device runs tick -> chunk back to back while the host
+        fetches, accounts and emits the tick.  One chunk at most (a
+        larger ``chunk_budget`` spends its rest after the emit, where a
+        wait for this chunk no longer stands between the tick and its
+        tokens); a promotion in flight, and a prompt's landing, stay
+        with ``_advance_prefill``.  Returns the budget tokens spent.  A
+        failure fails the prefill's own request here and never reaches
+        the tick's handler around this call."""
+        pf = self._prefill
+        if (pf is None or pf.promote_entry is not None
+                or pf.consumed >= pf.total):
+            # (All chunks out but not landed: the tick the last one rode
+            # behind failed, and its handler skipped the landing.)
+            return 0
+        try:
+            return (self.chunk_tokens
+                    if self._dispatch_chunk(pf, overlapped=True) else 0)
+        except BaseException as exc:       # surface to the caller
+            self._fail_prefill(pf, exc)
+            return 0
+
+    def _dispatch_chunk(self, pf: _Prefill, overlapped: bool) -> bool:
+        """One chunk of the in-flight prefill: its host work (blocks,
+        with their evictions; the table row; the uploads), then the
+        wait for the chunk BEFORE it, then its own dispatch, which is
+        not waited for.  At most one chunk is unresolved at any time, so
+        the host runs at most one chunk ahead of the device.  Ordering
+        against the tick before it and whatever follows is the pool's
+        data dependence (``self.pool``), never a host wait.  False on a
+        dry pool (nothing dispatched).  ``overlapped``: dispatched
+        behind a tick whose tokens the host has not fetched yet."""
+        req = pf.request
+        c = self.chunk_tokens
+        bs = self.paged.block_size
+        span = self.paged.blocks_per_slot * bs
+        with self.profiler.phase("chunk_prefill"):
+            start = pf.consumed
+            if start + c > span:
+                # Final sliver near the table's end: slide the chunk
+                # back so every position stays inside the table (an
+                # overflowing pad position would CLAMP its block
+                # index onto a real block and corrupt live KV).  The
+                # overlap recomputes identical K/V — harmless.
+                start = span - c
+            end = start + c
+            need = min(pf.max_blocks, -(-min(end, pf.total) // bs))
+            if len(pf.blocks) < need:
+                extra = self._alloc_evicting(need - len(pf.blocks))
+                if extra is None:
+                    return False
+                pf.blocks.extend(extra)
+            window = next(w for w in self._chunk_windows if w >= end)
+            k = min(end, pf.total) - start
+            tokens = np.full((1, c), self.tokenizer.pad_id, np.int32)
+            tokens[0, :k] = pf.seq[start:start + k]
+            fn = self._chunk_prefill_fn(c, window)
+            args = (jnp.asarray(tokens), jnp.asarray([start], np.int32),
+                    jnp.asarray([pf.total], np.int32),
+                    jnp.asarray(self._table_row(pf.blocks)), pf.rng,
+                    jnp.float32(pf.temperature))
+            # Everything this chunk needs is on its way: only now wait
+            # for the chunk before it (if the device still runs it, the
+            # launch below finds the stream busy, not idle).
+            self._settle_chunk(pf)
+            with obs_spans.span(req.trace, "prefill_chunk", start=start,
+                                tokens=k, window=window):
+                pf.pending, self.pool = fn(self.params, self.pool, *args)
+            pf.pending_t = time.perf_counter()
+        from ..utils import roofline
+        self.phases.add_work("prefill", **roofline.prefill_work(
+            self.cfg, end, start, wbytes=self._wbytes))
+        self.prefill_chunks_total += 1
+        self.prefill_chunks_overlapped_total += int(overlapped)
+        try:
+            # No injection path on the engine (same pattern as the tick
+            # histogram): the process-global registry.
+            from ..obs import get_observability
+            get_observability().m.prefill_chunks.labels(
+                self.tier.name,
+                "behind_tick" if overlapped else "alone").inc()
+        except Exception:
+            pass
+        pf.consumed = min(end, pf.total)
+        pf.chunks_done += 1
+        return True
+
+    def _settle_chunk(self, pf: _Prefill):
+        """Resolve the prefill's one unresolved chunk, if it has one:
+        wait for its outputs and return its sampled token (None if
+        nothing was pending).  THE one place a chunk is waited for —
+        before the next chunk's dispatch, at a prompt's landing, and
+        before anything that ends the prefill early — so every
+        dispatched chunk is settled exactly once, and a routed-expert
+        model's assignments are counted exactly once, here; a chunk
+        whose settle raises counts nothing and fails its own request
+        in the caller's handler.  The caller stamps ``chunk_prefill``.
+
+        ``dllm_prefill_chunk_ms`` is taken here, where the wait is: from
+        when the host last saw the device reach the chunk (the fetch of
+        the tick it was queued behind, or its own dispatch if that came
+        later) to its outputs being ready — the chunk's device time,
+        plus however late the host came to look."""
+        out = pf.pending
+        if out is None:
+            return None
+        pf.pending = None
+        # dllm-lint: disable=transfer-host-sync -- sanctioned: the ONE wait per chunk, taken one chunk late (the host runs at most one chunk ahead of the device, so a failed chunk still fails its own request and the TBT bound stays one budget grant a tick); only a prompt's last chunk is waited for in its own pass, for the token its slot starts from
+        out = jax.block_until_ready(out)
+        chunk_ms = (time.perf_counter()
+                    - max(pf.pending_t, self._tick_fetched_t)) * 1000.0
+        self.phases.add_time("prefill", chunk_ms / 1000.0)
+        try:
+            from ..obs import get_observability
+            get_observability().m.prefill_chunk_ms.labels(
+                self.tier.name).observe(chunk_ms)
+        except Exception:
+            pass
+        self._progress_t = time.monotonic()
+        return self._chunk_result(out)
+
+    def _fail_prefill(self, pf: _Prefill, exc: BaseException) -> None:
+        """A chunk (its host work, its dispatch or its settle) raised:
+        fail the prefill's OWN request and free what it held.  An
+        unresolved chunk is dropped unwaited and uncounted: whatever
+        reuses its blocks is ordered behind it by the pool."""
+        req = pf.request
+        self._prefill = None
+        pf.pending = None
+        if pf.promote_entry is not None and self.kv_spill is not None:
+            self.kv_spill.release(pf.promote_entry, promoted=False)
+            pf.promote_entry = None
+        slot = self._slots[pf.slot_ix]
+        if slot is not None and slot.request is req:
+            # The final chunk had already gone live as a slot when
+            # the failure surfaced: the SLOT owns the blocks now.
+            self._fail_slot(pf.slot_ix, exc)
+            return
+        self.allocator.free(pf.blocks)
+        req.error = exc
+        if req.token_queue is not None:
+            req.token_queue.put(None)
+        req.done.set()
 
     def _advance_promotion(self, pf: _Prefill, budget_left: int):
         """Spend part of this tick's chunk budget landing host→device
@@ -2161,13 +2267,27 @@ class ContinuousBatchingEngine:
         """Cancel-and-requeue the in-flight prefill: under pool
         starvation the prefill yields FIRST — it has emitted nothing, so
         requeueing it is free, while preempting a DECODING slot forces a
-        full replay.  Blocks return to the pool immediately; the request
-        re-enters at the scheduler head and restarts from chunk 0 (a
-        replay's parked tokens survive untouched, so the eventual stream
-        is still byte-identical)."""
+        full replay.  Its unresolved chunk is settled, then the blocks
+        return to the pool (what reuses them runs behind the chunk on
+        the device: the pool orders it); the request re-enters at the
+        scheduler head and restarts from chunk 0 (a replay's parked
+        tokens survive untouched, so the eventual stream is still
+        byte-identical)."""
         pf = self._prefill
         if pf is None:
             return
+        if pf.pending is not None:
+            # Settle the unresolved chunk first (one chunk's device
+            # time at most): its experts' assignments count like every
+            # dispatched chunk's, and a chunk that failed fails its own
+            # request instead of re-queueing it.  The blocks go back
+            # through the allocator below either way.
+            try:
+                with self.profiler.phase("chunk_prefill"):
+                    self._settle_chunk(pf)
+            except BaseException as exc:   # surface to the caller
+                self._fail_prefill(pf, exc)
+                return
         self._prefill = None
         if pf.promote_entry is not None and self.kv_spill is not None:
             # Mid-promotion cancel (starvation/stop): drop the pin so
@@ -2872,6 +2992,7 @@ class ContinuousBatchingEngine:
                     self.profiler.commit(0)
                 continue
 
+            chunk_spent, ride_s = 0, 0.0
             try:
                 spec_tick = spec_gb is not None
                 with self.profiler.phase("prepare"):
@@ -2943,19 +3064,32 @@ class ContinuousBatchingEngine:
                     # ``decode`` is the tick as the device sees it; its
                     # children split it into the launch (host work, the
                     # device idle unless the last program still runs)
-                    # and the one sanctioned sync.
+                    # and the one sanctioned sync — and between the
+                    # two, while a chunked prefill is in flight, its
+                    # chunk of this pass (``chunk_prefill``): enqueued
+                    # behind the tick, so the device goes from the tick
+                    # straight into the chunk while the host fetches,
+                    # accounts and emits.
                     with self.profiler.phase("decode"):
                         with self.profiler.phase("dispatch"):
                             toks, self.pool = self._decode_step()(
                                 self.params, self.pool, tables_arg,
                                 pos_dev, cur_dev, temps_dev, rng)
+                        if self._prefill is not None:
+                            t_ride = time.perf_counter()
+                            chunk_spent = self._ride_chunk()
+                            ride_s = time.perf_counter() - t_ride
                         with self.profiler.phase("fetch"):
                             toks = _fetch_tick(toks)           # [T, B]
                     if self._moe is not None:
                         # The same fetch brought the steps' assignments
                         # an expert: counted in ``account``.
                         toks, n_exp = toks
-                tick_ms = (time.perf_counter() - t_tick) * 1000.0
+                # The tick's launch and the wait for its tokens; a
+                # riding chunk's section between the two is the
+                # chunk's (dllm_prefill_chunk_ms), not the tick's.
+                self._tick_fetched_t = time.perf_counter()
+                tick_ms = (self._tick_fetched_t - t_tick - ride_s) * 1000.0
                 # Everything between the fetch and the emit, with the
                 # device idle: ``account``.  What it holds is priced in
                 # PERF.md ("what tracing costs") — keep it to dict
@@ -3009,10 +3143,14 @@ class ContinuousBatchingEngine:
                         if hit_cap or hit_end:
                             self._finish(ix)
             if self._prefill is not None:
-                # Decode slots served: spend the tick's prefill budget —
-                # the interleave that bounds active streams' TBT by one
-                # chunk grant instead of one whole prompt.
-                self._advance_prefill()
+                # Decode slots served: what is left of the tick's
+                # prefill budget after the chunk that rode behind the
+                # tick (all of it if none could: a promotion, a pool
+                # that was dry before this emit freed blocks), and a
+                # prompt whose last chunk is out lands here — the
+                # interleave that bounds active streams' TBT by one
+                # budget grant instead of one whole prompt.
+                self._advance_prefill(self.chunk_budget - chunk_spent)
             self._progress_t = time.monotonic()  # tick completed
             self.profiler.commit(len(active))
 
@@ -3496,19 +3634,27 @@ class ContinuousBatchingEngine:
 
     def prefill_stats(self) -> Dict[str, Any]:
         """In-flight chunked-prefill snapshot: whether one is being
-        absorbed, how many prompt tokens remain (the backlog the
-        ``dllm_prefill_backlog`` gauge samples), chunk progress, and the
-        engine-life cancel count.  Advisory GIL-safe reads of state the
-        scheduler thread owns — same discipline as slot_stats."""
+        absorbed, how many prompt tokens remain to dispatch (the backlog
+        the ``dllm_prefill_backlog`` gauge samples), chunk progress, the
+        engine-life cancel count, and how often a chunk rode behind an
+        unfetched tick (``chunks_overlapped_total`` of ``chunks_total``;
+        ``overlap_share`` None before the first chunk).  Advisory
+        GIL-safe reads of state the scheduler thread owns — same
+        discipline as slot_stats."""
         pf = self._prefill
-        if pf is None:
-            return {"inflight": 0, "backlog_tokens": 0, "chunks_done": 0,
-                    "cancelled_total": self.prefill_cancelled_total}
-        return {"inflight": 1,
-                "backlog_tokens": max(0, pf.total - min(pf.consumed,
-                                                        pf.total)),
-                "chunks_done": pf.chunks_done,
-                "cancelled_total": self.prefill_cancelled_total}
+        chunks = self.prefill_chunks_total
+        overlapped = self.prefill_chunks_overlapped_total
+        out = {"inflight": 0, "backlog_tokens": 0, "chunks_done": 0,
+               "cancelled_total": self.prefill_cancelled_total,
+               "chunks_total": chunks,
+               "chunks_overlapped_total": overlapped,
+               "overlap_share": (round(overlapped / chunks, 4)
+                                 if chunks else None)}
+        if pf is not None:
+            out.update(inflight=1, chunks_done=pf.chunks_done,
+                       backlog_tokens=max(0, pf.total - min(pf.consumed,
+                                                            pf.total)))
+        return out
 
     def prefix_affinity(self, history) -> int:
         """Longest parked-prefix token match in the paged pool for

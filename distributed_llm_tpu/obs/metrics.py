@@ -370,8 +370,12 @@ METRIC_REGISTRY: Tuple[Tuple[str, str, str, Tuple[str, ...], str], ...] = (
     # what each tick costs — cross-round perf deltas get attributed
     # to a kernel, not guessed.
     ("decode_tick_ms", "histogram", "dllm_decode_tick_ms", ("tier",),
-     "Batched decode tick device time (decode_steps_per_tick "
-     "fused steps per observation)"),
+     "Batched decode tick as the host waits for it: its launch plus "
+     "the fetch of its tokens (decode_steps_per_tick fused steps per "
+     "observation).  The host section of a prefill chunk that rides "
+     "between the two is not in it; the fetch then starts that much "
+     "into the tick, and a tick queued behind a chunk nobody has "
+     "waited for yet carries that chunk's tail"),
     ("decode_ticks", "counter", "dllm_decode_ticks_total",
      ("tier", "kind", "impl"),
      "Batched decode ticks, by attention dispatch kind "
@@ -388,9 +392,21 @@ METRIC_REGISTRY: Tuple[Tuple[str, str, str, Tuple[str, ...], str], ...] = (
     # most one chunk grant), and the backlog gauge shows a long
     # prompt mid-absorption behind a TTFT spike.
     ("prefill_chunk_ms", "histogram", "dllm_prefill_chunk_ms", ("tier",),
-     "Device time of one interleaved prefill chunk — the upper "
-     "bound a chunked admission adds to active streams' "
-     "time-between-tokens per tick"),
+     "One interleaved prefill chunk, taken where the host waits for "
+     "it (a chunk is settled one chunk late, a prompt's last in its "
+     "own pass): from when the host last saw the device reach it (the "
+     "fetch of the tick it was queued behind, or its own dispatch if "
+     "later) to its outputs being ready — its device time plus "
+     "however late the host came to look; the upper bound a chunked "
+     "admission adds to active streams' time-between-tokens per tick"),
+    ("prefill_chunks", "counter", "dllm_prefill_chunks_total",
+     ("tier", "kind"),
+     "Prefill chunks dispatched, by whether the chunk was enqueued "
+     "behind a decode tick whose tokens the host had not fetched yet "
+     "(kind=behind_tick: the device runs tick then chunk back to back "
+     "under the host's fetch and emit) or not (kind=alone: a solo "
+     "prefill's chunks, the rest of a budget above one chunk, a chunk "
+     "that stalled on a dry pool until the emit)"),
     # Routed-expert family (models/latent_moe.py): what the tick and
     # the chunk program count beside their tokens — how many expert
     # assignments a stage computed, and how many experts those touched

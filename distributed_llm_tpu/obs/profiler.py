@@ -13,7 +13,10 @@ decode tick (``decode``, whose children are ``dispatch`` — until the
 jitted call returns — and ``fetch`` — the tick's one sanctioned device
 sync), what follows the fetch (``account``: cost attribution and the
 tick's counters), token fanout/detokenize (``emit``), and interleaved
-chunk-prefill grants (``chunk_prefill``) — as a bounded ring of typed
+chunk-prefill grants (``chunk_prefill``: a chunk's host work, the wait
+for the chunk before it and its launch — between the tick's
+``dispatch`` and ``fetch`` while slots decode, so nested in ``decode``
+without being part of what the tick cost) — as a bounded ring of typed
 tick records.  The scheduler's idle backoff (``idle_wait``) is an
 annotation and a lifetime total only: it leaves no ring record, so an
 idle engine does not flush its ring at 20 Hz.  Compile
@@ -111,8 +114,13 @@ SAMPLED_PHASES = ("admit", "prefill", "cow_copy", "table_upload", "decode",
                   "promote")
 # ``decode``'s time is all in its children now (``dispatch``,
 # ``fetch``): wherever it is read as "what the decode tick cost" it is
-# held to its full duration, not its emptied self-time.
-FULL_DURATION_PHASES = ("decode",)
+# held to its own time plus theirs, not its emptied self-time.  Named,
+# not "everything nested": a prefill chunk that rides between the two
+# (``chunk_prefill``) nests in ``decode`` and is no part of the tick.
+FULL_DURATION_PHASES = {"decode": ("decode", "dispatch", "fetch")}
+_FULL_DURATION_OF = {part: whole
+                     for whole, parts in FULL_DURATION_PHASES.items()
+                     for part in parts}
 
 # 120 s at 30 scheduler passes a second, rounded up: the benchmark
 # reads the traced span out of the ring about 50 s after it happened.
@@ -378,8 +386,10 @@ class TickProfiler:
             dur_by_name: Dict[str, float] = {}
             for name, _rel, dur_ms, self_ms in rec["spans"]:
                 by_name[name] = by_name.get(name, 0.0) + self_ms
-                if name in FULL_DURATION_PHASES:
-                    dur_by_name[name] = dur_by_name.get(name, 0.0) + dur_ms
+                whole = _FULL_DURATION_OF.get(name)
+                if whole is not None:
+                    dur_by_name[whole] = dur_by_name.get(whole, 0.0) \
+                        + self_ms
                 covered += self_ms
             for name, ms in by_name.items():
                 per_phase.setdefault(name, []).append(ms)
@@ -420,8 +430,13 @@ class TickProfiler:
 
     def total_ms(self, phase: str) -> float:
         """Lifetime FULL-duration total for one phase, children
-        included (the attribution-conservation denominator in tests
-        and the bench leg: what the decode ticks cost)."""
+        included — for ``decode`` its own children only
+        (``FULL_DURATION_PHASES``): the attribution-conservation
+        denominator in tests, what the decode ticks cost."""
+        parts = FULL_DURATION_PHASES.get(phase)
+        if parts is not None:
+            totals = dict(self._totals)
+            return float(sum(totals[p][1] for p in parts if p in totals))
         acc = self._totals.get(phase)
         return float(acc[2]) if acc else 0.0
 

@@ -131,6 +131,11 @@ def engine_stats(engine) -> Dict[str, Any]:
             entry["kv"] = kv_fn()
         except Exception:
             pass
+    pf_fn = getattr(engine, "prefill_stats", None)
+    if callable(pf_fn):
+        # Chunked prefill: what is in flight, and of the chunks
+        # dispatched so far how many rode behind an unfetched tick.
+        entry["prefill"] = pf_fn()
     moe_fn = getattr(engine, "moe_stats", None)
     moe = moe_fn() if callable(moe_fn) else None
     if moe:

@@ -10,6 +10,7 @@ chip benchmark's long-prompt cell (benchmark/traffic/long-prompt.json).
 """
 
 import dataclasses
+import queue
 import threading
 import time
 
@@ -387,3 +388,365 @@ def test_sampler_gauge_field_covers_prefill_backlog():
         lambda: {"nano": {"prefill_backlog_tokens": 37}}, metrics=m)
     sampler.sample_once()
     assert m.prefill_backlog_g.labels("nano").value == 37.0
+
+
+# -- a chunk rides behind the tick (ISSUE 32) ---------------------------------
+#
+# Tier 1 serves the fused ragged tick by default and the chip's cells the
+# dense windowed one: every case below runs on both.
+
+TICKS = pytest.mark.parametrize("ragged", [True, False],
+                                ids=["ragged", "dense"])
+
+
+def _overlap_engine(ragged, **kw):
+    defaults = dict(prefill_chunk_tokens=16, max_new_tokens=40,
+                    decode_batch=4, attention_ragged=ragged)
+    defaults.update(kw)
+    return _engine(**defaults)
+
+
+def _neighbour_prompt(i):
+    return f"user: hi {i}"                    # under one chunk: monolithic
+
+
+def _prime_neighbours(eng, n):
+    """``n`` requests that are decoding when this returns (each has
+    produced its first token)."""
+    reqs = []
+    for i in range(n):
+        req = eng.submit(_neighbour_prompt(i), token_queue=queue.Queue())
+        req.token_queue.get(timeout=120)
+        reqs.append(req)
+    return reqs
+
+
+def _finished(reqs):
+    """Wait the requests out; whether all ended without an error."""
+    return all(r.done.wait(timeout=120) and r.error is None for r in reqs)
+
+
+def _watch_chunks(eng):
+    """Wrap every chunk program the engine hands out: records, at each
+    dispatch, whether the prefill already had an unresolved chunk."""
+    seen = []
+    real = eng._chunk_prefill_fn
+
+    def watched(bucket, window):
+        fn = real(bucket, window)
+
+        def call(*args):
+            pf = eng._prefill
+            seen.append(pf is not None and pf.pending is not None)
+            return fn(*args)
+        return call
+    eng._chunk_prefill_fn = watched
+    return seen
+
+
+@TICKS
+def test_chunk_rides_between_the_ticks_dispatch_and_its_fetch(ragged):
+    """(a) With a prefill in flight beside active slots, a tick's record
+    reads dispatch -> chunk_prefill -> fetch, the chunk's section nests
+    in ``decode`` without entering what the tick cost, and no chunk is
+    dispatched while another is unresolved."""
+    eng = _overlap_engine(ragged)
+    try:
+        eng.generate(LONG_Q)                  # compile outside the watch
+        unresolved_at_dispatch = _watch_chunks(eng)
+        mark = eng.profiler._seq
+        neighbours = _prime_neighbours(eng, 2)
+        assert _finished([eng.submit(LONG_Q)] + neighbours)
+        assert unresolved_at_dispatch and not any(unresolved_at_dispatch)
+        ticks = [r for r in eng.profiler.records()
+                 if r["seq"] > mark and r["slots"]]
+        riding = 0
+        for rec in ticks:
+            spans = sorted(rec["spans"], key=lambda s: s[1])
+            names = [n for n, *_ in spans
+                     if n in ("dispatch", "chunk_prefill", "fetch")]
+            if "chunk_prefill" not in names:
+                continue
+            if names[:3] != ["dispatch", "chunk_prefill", "fetch"]:
+                # A landing or a budget's rest after the emit only.
+                assert names[:2] == ["dispatch", "fetch"], rec
+                continue
+            riding += 1
+            _, lo, dur, _ = next(s for s in spans if s[0] == "decode")
+            _, at, took, _ = next(s for s in spans
+                                  if s[0] == "chunk_prefill")
+            assert lo <= at and at + took <= lo + dur + 1e-6
+        assert riding >= 2, [r["spans"] for r in ticks]
+        # What the ticks cost is their launch and their fetch: the
+        # chunk's section inside ``decode`` is no part of it.
+        prof = eng.profiler
+        totals = prof.self_totals()
+        assert prof.total_ms("decode") == pytest.approx(
+            totals["decode"] + totals["dispatch"] + totals["fetch"])
+        assert prof.total_ms("decode") == pytest.approx(
+            sum(eng.tick_ms), rel=0.05)
+    finally:
+        eng.stop()
+
+
+@TICKS
+@pytest.mark.parametrize("neighbours,budget", [(0, None), (1, None),
+                                               (3, None), (2, 32)])
+def test_overlapped_admission_is_byte_identical(ragged, neighbours, budget):
+    """(b) The chunked admission's stream equals the monolithic one with
+    0, 1 and several decoding neighbours, and with a budget of two
+    chunks a pass; the neighbours' streams equal their solo runs."""
+    mono = _overlap_engine(ragged, prefill_chunk_tokens=None)
+    try:
+        ref = mono.generate(LONG_Q).token_ids
+        ref_n = [mono.generate(_neighbour_prompt(i)).token_ids
+                 for i in range(neighbours)]
+    finally:
+        mono.stop()
+    eng = _overlap_engine(ragged, prefill_chunk_budget=budget)
+    try:
+        reqs = _prime_neighbours(eng, neighbours)
+        long_req = eng.submit(LONG_Q)
+        assert _finished([long_req] + reqs)
+        assert long_req.result.token_ids == ref
+        assert [r.result.token_ids for r in reqs] == ref_n
+        st = eng.prefill_stats()
+        assert st["chunks_total"] >= 2
+        if not neighbours:
+            assert st["chunks_overlapped_total"] == 0
+    finally:
+        eng.stop()                            # leak check inside
+
+
+def _half_prefilled(eng):
+    """An engine (scheduler not running) whose in-flight prefill has one
+    chunk dispatched and NOT resolved; returns the request."""
+    req = _Request(history=LONG_Q, max_new_tokens=None, temperature=None)
+    assert eng._admit(req, 0) and eng._prefill is not None
+    assert eng._dispatch_chunk(eng._prefill, overlapped=False)
+    assert eng._prefill.pending is not None and eng._prefill.blocks
+    return req
+
+
+@TICKS
+@pytest.mark.parametrize("how", ["cancel", "capture", "stop"])
+def test_early_end_settles_the_unresolved_chunk(ragged, how):
+    """(c) Whatever ends a prefill early first settles its unresolved
+    chunk: the allocator comes back clean (DLLM_KV_LEAK_CHECK is armed
+    suite-wide, inside stop()), and a re-queued request re-admits to the
+    bytes of an undisturbed run."""
+    from distributed_llm_tpu.engine.batching import EngineStoppedError
+
+    ref_eng = _overlap_engine(ragged)
+    try:
+        ref = ref_eng.generate(LONG_Q).token_ids
+    finally:
+        ref_eng.stop()
+    eng = _overlap_engine(ragged)
+    try:
+        req = _half_prefilled(eng)
+        pf = eng._prefill
+        if how == "cancel":
+            eng._cancel_prefill("kv pressure (test)")
+            assert eng._prefill is None and pf.pending is None
+            assert eng.allocator.ref_stats()["allocated_blocks"] == 0
+            assert eng._head[0] is req
+            eng.start()                       # re-admits from chunk 0
+            assert req.done.wait(timeout=120) and req.error is None
+            assert req.result.token_ids == ref
+        elif how == "capture":
+            got = eng.capture_requests()
+            assert got == [req] and pf.pending is None
+            assert eng.allocator.ref_stats()["allocated_blocks"] == 0
+            assert eng.adopt_requests(got) == 1
+            assert req.done.wait(timeout=120) and req.error is None
+            assert req.result.token_ids == ref
+        else:
+            eng.stop()
+            assert pf.pending is None and req.done.is_set()
+            assert isinstance(req.error, EngineStoppedError)
+    finally:
+        eng.stop()
+    assert eng.allocator.ref_stats()["allocated_blocks"] == 0
+
+
+class _Poisoned:
+    """A chunk output whose device work "failed": the error surfaces at
+    the wait, as a runtime failure of an asynchronous program does."""
+
+    def block_until_ready(self):
+        raise RuntimeError("chunk program failed on the device")
+
+
+@TICKS
+@pytest.mark.parametrize("where", ["dispatch", "settle"])
+def test_failed_chunk_fails_its_own_request_only(ragged, where):
+    """(d) A chunk program that raises — at its launch, or one chunk
+    late at the wait for it — fails the prefill's own request; the
+    decoding neighbour finishes with its solo bytes and the engine
+    keeps serving."""
+    eng = _overlap_engine(ragged)
+    try:
+        solo_long = eng.generate(LONG_Q).token_ids
+        solo_n = eng.generate(_neighbour_prompt(0)).token_ids
+        real = eng._chunk_prefill_fn
+        calls = []
+
+        def failing(bucket, window):
+            fn = real(bucket, window)
+
+            def call(params, pool, *args):
+                calls.append(where)
+                if len(calls) == 2:
+                    if where == "dispatch":
+                        raise RuntimeError("chunk program failed to launch")
+                    _, pool = fn(params, pool, *args)
+                    return _Poisoned(), pool
+                return fn(params, pool, *args)
+            return call
+        eng._chunk_prefill_fn = failing
+        neighbours = _prime_neighbours(eng, 1)
+        req = eng.submit(LONG_Q)
+        assert req.done.wait(timeout=120)
+        assert isinstance(req.error, RuntimeError)
+        assert "chunk program failed" in str(req.error)
+        assert _finished(neighbours)
+        assert neighbours[0].result.token_ids == solo_n
+        eng._chunk_prefill_fn = real
+        assert eng._prefill is None
+        assert eng.generate(LONG_Q).token_ids == solo_long
+    finally:
+        eng.stop()                            # leak check inside
+
+
+@TICKS
+def test_failed_tick_does_not_take_the_riding_prefill_with_it(ragged,
+                                                              monkeypatch):
+    """The other direction of (d): the tick whose fetch fails takes its
+    decoding slots, not the prefill whose LAST chunk rode behind it —
+    the landing its handler skipped happens in the next pass, no chunk
+    is dispatched past the prompt's end, and the bytes are the solo
+    run's."""
+    from distributed_llm_tpu.engine import batching
+
+    eng = _overlap_engine(ragged)
+    try:
+        solo_long = eng.generate(LONG_Q).token_ids
+        chunks = eng.prefill_stats()["chunks_total"]
+        real = batching._fetch_tick
+        fired = []
+
+        def fetch(x):
+            pf = eng._prefill
+            if (not fired and pf is not None and pf.pending is not None
+                    and pf.consumed >= pf.total):
+                # A request that the next pass admits: that pass has an
+                # active slot again, so it reaches the ride.
+                fired.append(eng.submit(_neighbour_prompt(1)))
+                raise RuntimeError("tick failed on the device")
+            return real(x)
+        monkeypatch.setattr(batching, "_fetch_tick", fetch)
+        r, = _prime_neighbours(eng, 1)
+        req = eng.submit(LONG_Q)
+        assert req.done.wait(timeout=120) and r.done.wait(timeout=120)
+        assert fired and isinstance(r.error, RuntimeError)
+        assert fired[0].done.wait(timeout=120) and fired[0].error is None
+        assert req.error is None and req.result.token_ids == solo_long
+        assert eng.prefill_stats()["chunks_total"] == 2 * chunks
+    finally:
+        eng.stop()                            # leak check inside
+
+
+def test_latent_prefill_expert_counts_survive_the_overlap():
+    """(e) The latent family's chunk brings its experts' assignment
+    counts with its token: a fixed prompt's ``expert_tokens.prefill``
+    are what the chunk programs return one at a time, each waited for
+    (the order before ISSUE 32), solo and beside a decoding neighbour."""
+    import numpy as np
+    from distributed_llm_tpu.config import TierConfig
+
+    def build():
+        return ContinuousBatchingEngine(TierConfig(
+            name="nano", model_preset="latent_test", decode_batch=4,
+            kv_block_size=16, prefill_buckets=(16, 32, 64, 128),
+            prefill_chunk_tokens=16, max_new_tokens=24,
+            enable_prefix_cache=False), seed=3)
+
+    def prefill_counts(eng):
+        return np.asarray(eng.moe_stats()["expert_tokens"]["prefill"])
+
+    # One at a time, each waited for before the next is dispatched.
+    serial = build()
+    try:
+        req = _Request(history=LONG_Q, max_new_tokens=None,
+                       temperature=None)
+        assert serial._admit(req, 0)
+        pf = serial._prefill
+        chunks = 0
+        while pf.consumed < pf.total:
+            assert serial._dispatch_chunk(pf, overlapped=False)
+            serial._settle_chunk(pf)
+            chunks += 1
+        want = prefill_counts(serial)
+        assert serial.moe_stats()["steps"]["prefill"] == chunks >= 2
+        serial._cancel_prefill("test")
+    finally:
+        serial.stop()
+    cfg = serial.cfg
+    assert want.sum() == chunks * 16 * cfg.experts_per_token \
+        * want.shape[0]
+
+    for neighbours in (0, 1):
+        eng = build()
+        try:
+            reqs = _prime_neighbours(eng, neighbours)
+            assert eng.generate(LONG_Q).gen_tokens > 0
+            assert _finished(reqs)
+            np.testing.assert_array_equal(prefill_counts(eng), want)
+            assert eng.moe_stats()["steps"]["prefill"] == chunks
+            if neighbours:
+                assert eng.prefill_stats()["chunks_overlapped_total"] >= 1
+        finally:
+            eng.stop()
+
+
+# -- the counter that says how often the overlap engages ----------------------
+
+@TICKS
+def test_overlap_counter(ragged):
+    """0 of 0 for prompts under one chunk; a solo prefill's chunks count
+    as dispatched but not overlapped; with active slots every chunk of
+    the prompt rides behind a tick.  /stats and /metrics carry both."""
+    from distributed_llm_tpu.obs import get_observability
+    from distributed_llm_tpu.utils.telemetry import engine_stats
+
+    m = get_observability().m
+    rode = m.prefill_chunks.labels("nano", "behind_tick")
+    alone = m.prefill_chunks.labels("nano", "alone")
+    rode0, alone0 = rode.value, alone.value
+    # The neighbours outlive the prompt's absorption: 4 ticks of 4
+    # steps hold its chunks, the cap is 64 tokens.
+    eng = _overlap_engine(ragged, max_new_tokens=64)
+    try:
+        eng.generate(_neighbour_prompt(9))
+        st = eng.prefill_stats()
+        assert (st["chunks_total"], st["chunks_overlapped_total"],
+                st["overlap_share"]) == (0, 0, None)
+        eng.generate(LONG_Q, max_new_tokens=4)    # solo
+        st = eng.prefill_stats()
+        solo_chunks = st["chunks_total"]
+        assert solo_chunks >= 2 and st["chunks_overlapped_total"] == 0
+        assert st["overlap_share"] == 0.0
+        neighbours = _prime_neighbours(eng, 2)
+        assert _finished([eng.submit(LONG_Q, max_new_tokens=4)])
+        st = eng.prefill_stats()
+        assert _finished(neighbours)
+        assert st["chunks_total"] == 2 * solo_chunks
+        assert st["chunks_overlapped_total"] == solo_chunks
+        assert st["overlap_share"] == 0.5
+        assert engine_stats(eng)["prefill"]["chunks_total"] \
+            == 2 * solo_chunks
+        assert rode.value - rode0 == solo_chunks
+        assert alone.value - alone0 == solo_chunks
+    finally:
+        eng.stop()
