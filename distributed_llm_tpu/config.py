@@ -194,15 +194,79 @@ class ModelConfig:
     hc_sinkhorn_iters: int = 20
     hc_eps: float = 1e-6
     hc_clamp: float = 30.0
+    # -- The state-space / attention / routed-expert hybrid family
+    # (models/hybrid_ssm.py; a non-empty ``layer_pattern`` selects it).
+    # One character a layer, ``num_layers`` of them: "M" a Mamba-2
+    # state-space mixer, "*" attention, "E" routed experts; EACH layer is
+    # ONE pre-norm mixer.  The layer loop scans the pattern's shortest
+    # repeating period (``layer_period``).
+    layer_pattern: str = ""
+    # Mamba-2: ``ssm_heads`` heads of ``ssm_head_dim`` channels, a state
+    # of ``ssm_state`` numbers a channel, B and C shared by groups of
+    # heads (``ssm_groups``), a causal depthwise conv of ``ssm_conv``
+    # taps.  The time-step range is the published init's (A in [1, 16]).
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_state: int = 0
+    ssm_groups: int = 1
+    ssm_conv: int = 4
+    ssm_dt_min: float = 0.001
+    ssm_dt_max: float = 0.1
+    ssm_dt_floor: float = 1e-4
+    # Attention heads of a width of their own (0: hidden / heads) and,
+    # for the hybrid family, no rotary embedding (position comes from the
+    # state-space layers).
+    attn_head_dim: int = 0
+    rotary: bool = True
+    # The hybrid family's experts: the router scores ``num_experts``
+    # outputs; THIS program holds ``experts_count`` of them from
+    # ``experts_first`` on (0: all) and computes their part of a layer's
+    # result — what the absent ones would add is left out (the other
+    # rank of an expert-parallel pair adds it; nothing here stands in for
+    # that rank).  "relu2": ``W_2 relu(W_1 x)^2``, no gate.  The shared
+    # expert is ``shared_ffn_size`` wide (0: none).
+    experts_first: int = 0
+    experts_count: int = 0
+    expert_act: str = "swiglu"
+    shared_ffn_size: int = 0
 
     @property
     def head_dim(self) -> int:
-        return self.hidden_size // self.num_heads
+        return self.attn_head_dim or self.hidden_size // self.num_heads
 
     @property
     def latent(self) -> bool:
         """The family of models/latent_moe.py."""
         return self.kv_lora_rank > 0
+
+    @property
+    def hybrid(self) -> bool:
+        """The family of models/hybrid_ssm.py."""
+        return bool(self.layer_pattern)
+
+    @property
+    def layer_period(self) -> str:
+        """The shortest string whose repetition is ``layer_pattern``."""
+        p = self.layer_pattern
+        return next(p[:n] for n in range(1, len(p) + 1)
+                    if len(p) % n == 0 and p[:n] * (len(p) // n) == p)
+
+    def layers_of(self, kind: str) -> int:
+        """Layers of one kind ("M", "*", "E") in ``layer_pattern``."""
+        return self.layer_pattern.count(kind)
+
+    @property
+    def experts_held(self) -> int:
+        return self.experts_count or self.num_experts
+
+    @property
+    def ssm_inner(self) -> int:
+        return self.ssm_heads * self.ssm_head_dim
+
+    @property
+    def ssm_conv_width(self) -> int:
+        """Channels the conv runs over: x, B and C side by side."""
+        return self.ssm_inner + 2 * self.ssm_groups * self.ssm_state
 
     @property
     def cache_row_width(self) -> int:
@@ -280,6 +344,19 @@ MODEL_PRESETS: Dict[str, ModelConfig] = {
         dense_lead_layers=1, num_experts=8, moe_ffn_size=32,
         experts_per_token=2, shared_experts=1, router_scale=2.0,
         residual_streams=4,
+    ),
+    # The state-space / attention / routed-expert hybrid at unit-test
+    # size (models/hybrid_ssm.py): two periods of "MEM*E", the first half
+    # of 8 router outputs held.
+    "hybrid_test": ModelConfig(
+        name="hybrid_test", tokenizer="byte", vocab_size=512,
+        hidden_size=64, num_layers=10, num_heads=4, num_kv_heads=2,
+        attn_head_dim=32, max_seq_len=256, tie_embeddings=False,
+        rotary=False, layer_pattern="MEM*EMEM*E", ssm_heads=8,
+        ssm_head_dim=16, ssm_state=16, ssm_groups=2, ssm_conv=4,
+        num_experts=8, experts_first=0, experts_count=4, moe_ffn_size=32,
+        shared_ffn_size=48, experts_per_token=3, router_scale=2.5,
+        expert_act="relu2",
     ),
     "orin_test": ModelConfig(
         name="orin_test", hidden_size=128, num_layers=2, num_heads=8,

@@ -243,8 +243,12 @@ class _ExpertLoad:
     (layer, expert), and how many (step, layer, expert) cells had at
     least one token — the experts a step had to read.  Every row the
     program computed counts, an idle slot's and a chunk's padding too:
-    the device read their experts all the same.  Scheduler-thread writes
-    only; ``stats`` reads whole arrays under the GIL."""
+    the device read their experts all the same.  The experts are the
+    ones this program HOLDS; a program that holds a share of a layer's
+    experts (models/hybrid_ssm.py) returns one column more, the
+    assignments that went to absent experts, summed as ``absent``.
+    Scheduler-thread writes only; ``stats`` reads whole arrays under the
+    GIL."""
 
     STAGES = ("decode", "prefill")
 
@@ -254,10 +258,15 @@ class _ExpertLoad:
                        for s in self.STAGES}
         self.steps = dict.fromkeys(self.STAGES, 0)
         self.touched = dict.fromkeys(self.STAGES, 0)
+        self.absent = dict.fromkeys(self.STAGES, 0)
 
     def note(self, stage: str, counts) -> None:
-        """``counts`` [steps, layers, experts] as fetched."""
+        """``counts`` [steps, layers, experts (+ 1: absent)] as fetched."""
         counts = np.asarray(counts)
+        held = self.tokens[stage].shape[1]
+        absent = int(counts[..., held:].sum())
+        counts = counts[..., :held]
+        self.absent[stage] += absent
         touched = int(np.count_nonzero(counts))
         per_expert = counts.sum(axis=0)
         self.tokens[stage] += per_expert
@@ -269,6 +278,9 @@ class _ExpertLoad:
             m.moe_assignments.labels(self.tier_name, stage).inc(
                 int(per_expert.sum()))
             m.moe_experts_touched.labels(self.tier_name, stage).inc(touched)
+            if absent:
+                m.moe_absent_assignments.labels(self.tier_name,
+                                                stage).inc(absent)
         except Exception:
             pass
 
@@ -276,7 +288,8 @@ class _ExpertLoad:
         return {"expert_tokens": {s: self.tokens[s].tolist()
                                   for s in self.STAGES},
                 "steps": dict(self.steps),
-                "experts_touched": dict(self.touched)}
+                "experts_touched": dict(self.touched),
+                "absent_assignments": dict(self.absent)}
 
 
 class ContinuousBatchingEngine:
@@ -303,26 +316,7 @@ class ContinuousBatchingEngine:
         # Under a mesh, "auto" stays on the GSPMD-partitionable XLA path
         # (upgrade_attention_impl only opts unsharded engines into Pallas).
         self.cfg = upgrade_attention_impl(tier.model(), mesh)
-        if self.cfg.latent:
-            # What the latent-attention family (models/latent_moe.py)
-            # does not run yet is refused here, by name, rather than run
-            # wrong: its pool has no heads to quantize by or shard on,
-            # and no draft or spill path was written for its rows.
-            from ..config_registry import env_int
-            unsupported = {
-                "kv_quantize='int8'": tier.kv_quantize != "none",
-                "a tensor-parallel mesh (tp > 1)": mesh is not None,
-                "draft_preset (speculative decoding)":
-                    bool(tier.draft_preset),
-                "host_kv_bytes (KV spill)": env_int(
-                    "DLLM_HOST_KV_BYTES", int(tier.host_kv_bytes or 0)) > 0,
-            }
-            bad = [what for what, on in unsupported.items() if on]
-            if bad:
-                raise ValueError(
-                    f"tier {tier.name}: model {self.cfg.name} is of the "
-                    f"latent-attention family, which does not support "
-                    f"{', '.join(bad)}")
+        self._refuse_unsupported(tier, mesh)
         bad = [b for b in tier.prefill_buckets if b % tier.kv_block_size]
         if bad:
             raise ValueError(
@@ -376,13 +370,22 @@ class ContinuousBatchingEngine:
         # compile-churn surface ISSUE 6 bounds: logged on growth and
         # mirrored to the dllm_compiled_programs gauge.
         self._compiled: Dict[str, set] = {}
-        # Routed-expert load (the latent family): what the tick and the
-        # chunk program return beside their tokens, summed on the host.
-        self._moe = (_ExpertLoad(tier.name, self.cfg.num_layers
-                                 - self.cfg.dense_lead_layers,
-                                 self.cfg.num_experts)
-                     if self.cfg.latent and self.cfg.num_experts > 1
-                     else None)
+        # Routed-expert load (the latent and hybrid families): what the
+        # tick and the chunk program return beside their tokens, summed on
+        # the host, over the family's expert layers and the experts held.
+        expert_layers = 0
+        if self.cfg.hybrid:
+            expert_layers = self.cfg.layers_of("E")
+        elif self.cfg.latent and self.cfg.num_experts > 1:
+            expert_layers = self.cfg.num_layers - self.cfg.dense_lead_layers
+        self._moe = (_ExpertLoad(tier.name, expert_layers,
+                                 self.cfg.experts_held)
+                     if expert_layers else None)
+        # The hybrid family's recurrent rows: whose each is
+        # (``_sync_state_owner``) and how many sequences started one.
+        self._state_owner = (np.zeros(tier.decode_batch, np.int32)
+                             if self.cfg.hybrid else None)
+        self.state_resets_total = 0
         if tier.kv_pool_blocks is not None:
             # A constrained pool must still fit ONE largest-bucket prefill
             # plus a decode tick, or no request could ever admit.
@@ -407,7 +410,7 @@ class ContinuousBatchingEngine:
         # stack up on chip 0.
         own = (jax.sharding.SingleDeviceSharding(self.devices[0])
                if mesh is None and self.devices else None)
-        if params is None and self.cfg.latent:
+        if params is None and (self.cfg.latent or self.cfg.hybrid):
             # The seed is an ARGUMENT of the jitted maker: one compiled
             # program for every seed, and nothing folded at compile time.
             params = jax.jit(partial(models.init_params, self.cfg),
@@ -715,6 +718,57 @@ class ContinuousBatchingEngine:
         self._tick_kind_spec = "ragged_verify" + q8
         self._tick_sink_cache: Dict[tuple, tuple] = {}
 
+    # What a model family does not run is refused at build, by name,
+    # rather than run wrong.  The latent family's pool has no heads to
+    # quantize by or shard on, and no draft or spill path was written for
+    # its rows.  The hybrid family keeps a recurrent row a slot beside
+    # its K/V blocks: whatever rewinds or shares BY POSITION (a prefix
+    # hit, a shared prefix, a spilled block, a draft's rejected tail)
+    # would part a sequence from its state, and its state has no shards.
+    _FAMILY_REFUSALS = {
+        "latent": ("latent-attention",
+                   ("kv_quantize", "tp", "draft_preset", "host_kv_bytes")),
+        "hybrid": ("state-space hybrid",
+                   ("kv_quantize", "tp", "draft_preset", "host_kv_bytes",
+                    "enable_prefix_cache", "prefill_chunk_tokens")),
+    }
+
+    def _refuse_unsupported(self, tier: TierConfig, mesh) -> None:
+        family = next((f for f in self._FAMILY_REFUSALS
+                       if getattr(self.cfg, f)), None)
+        if family is None:
+            return
+        from ..config_registry import env_int
+        span = -(-self.cfg.max_seq_len // tier.kv_block_size) \
+            * tier.kv_block_size
+        chunk = int(tier.prefill_chunk_tokens or 0)
+        on = {
+            "kv_quantize": ("kv_quantize='int8'",
+                            tier.kv_quantize != "none"),
+            "tp": ("a tensor-parallel mesh (tp > 1)", mesh is not None),
+            "draft_preset": ("draft_preset (speculative decoding)",
+                             bool(tier.draft_preset)),
+            "host_kv_bytes": ("host_kv_bytes (KV spill)", env_int(
+                "DLLM_HOST_KV_BYTES", int(tier.host_kv_bytes or 0)) > 0),
+            "enable_prefix_cache": (
+                "enable_prefix_cache (prefix reuse and share_prefix_kv)",
+                bool(tier.enable_prefix_cache
+                     and tier.prefix_cache_entries > 0)),
+            # Every prompt goes through the chunk program, which carries
+            # the state; a chunk slid back at the table's end would feed
+            # its overlap to the state twice.
+            "prefill_chunk_tokens": (
+                f"prefill_chunk_tokens={chunk} (it needs a chunk that "
+                f"divides the slot's span of {span})",
+                chunk <= 0 or span % chunk != 0),
+        }
+        name, keys = self._FAMILY_REFUSALS[family]
+        bad = [on[key][0] for key in keys if on[key][1]]
+        if bad:
+            raise ValueError(
+                f"tier {tier.name}: model {self.cfg.name} is of the "
+                f"{name} family, which does not support {', '.join(bad)}")
+
     def _new_pool(self, cfg, home):
         """A zeroed paged pool for ``cfg``, made in place on the engine's
         device(s) ``home`` (one sharding, or a dict of them under a
@@ -766,10 +820,11 @@ class ContinuousBatchingEngine:
         compile churn dominates the tiny gather), and the whole point of
         the table is that an on-chip A/B flipping ragged_decode to
         'pallas' flips this engine to the kernel with no code change."""
-        if self.cfg.latent:
+        if self.cfg.latent or self.cfg.hybrid:
             # The latent family attends whatever tables it is given; the
             # windowed tick bounds its gather (no fused ragged kernel
-            # reads a head-less pool).
+            # reads a head-less pool).  The hybrid family's two attention
+            # layers keep the windowed tick too.
             return False
         if self.mesh is not None:
             from ..parallel.tp_attention import _tp_ragged_ok
@@ -1366,6 +1421,41 @@ class ContinuousBatchingEngine:
         # lazily at the next attribution pass.
         self._kv_weights.clear()
 
+    def _sync_state_owner(self) -> None:
+        """The hybrid family: before a program that reads the recurrent
+        rows, make ``pool["owner"]`` say row = slot — a live slot's first
+        block, the in-flight prefill's, 0 for a free slot — so a finished
+        or preempted sequence's row is free the moment its blocks are,
+        and a sequence admitted into the slot later claims (and zeroes)
+        the same row.  A [slots] int32 upload, and only when it changed."""
+        if self._state_owner is None:
+            return
+        owner = self._rows_owned()
+        if not np.array_equal(owner, self._state_owner):
+            self._state_owner = owner
+            self.pool = {**self.pool, "owner": jax.device_put(
+                owner, self.pool["owner"].sharding)}
+
+    def _rows_owned(self) -> np.ndarray:
+        """[slots] the first block of each slot's sequence, 0 if none."""
+        owner = self._tables[:, 0].copy()
+        pf = self._prefill
+        if pf is not None and pf.blocks:
+            owner[pf.slot_ix] = pf.blocks[0]
+        return owner
+
+    def state_stats(self) -> Optional[Dict[str, int]]:
+        """The recurrent rows (GET /stats ``state``), or None for a model
+        without them: how many there are, how many name a sequence, what
+        one holds, and how many sequences started one from zero."""
+        if self._state_owner is None:
+            return None
+        from ..utils.roofline import state_row_bytes
+        return {"rows": int(self._state_owner.size),
+                "rows_in_use": int(np.count_nonzero(self._rows_owned())),
+                "row_bytes": int(state_row_bytes(self.cfg)),
+                "resets_total": int(self.state_resets_total)}
+
     def _alloc_evicting(self, n_blocks: int) -> Optional[List[int]]:
         """Allocate, evicting parked prefix entries (LRU) under pressure:
         live admissions always outrank parked caches.  Quotas ON adds a
@@ -1908,7 +1998,11 @@ class ContinuousBatchingEngine:
         """Whether an admission prefills CHUNKED: only prompts whose
         bucket exceeds one chunk — a smaller prompt's monolithic prefill
         already meets the one-chunk TBT bound, and keeps the warm
-        prefill-bucket program path."""
+        prefill-bucket program path.  (The hybrid family: always.)"""
+        if self.cfg.hybrid:
+            # The chunk program carries the recurrent state: this family
+            # has no other prefill, whatever the prompt's length.
+            return True
         return bool(self.chunk_tokens) and bucket > self.chunk_tokens
 
     def _start_prefill(self, req: _Request, slot_ix: int, ids: List[int],
@@ -2069,6 +2163,15 @@ class ContinuousBatchingEngine:
                     jnp.asarray([pf.total], np.int32),
                     jnp.asarray(self._table_row(pf.blocks)), pf.rng,
                     jnp.float32(pf.temperature))
+            self._sync_state_owner()
+            if start == 0 and self._state_owner is not None:
+                self.state_resets_total += 1
+                try:
+                    from ..obs import get_observability
+                    get_observability().m.state_resets.labels(
+                        self.tier.name).inc()
+                except Exception:
+                    pass
             # Everything this chunk needs is on its way: only now wait
             # for the chunk before it (if the device still runs it, the
             # launch below finds the stream busy, not idle).
@@ -3032,6 +3135,7 @@ class ContinuousBatchingEngine:
                     pos_dev = jnp.asarray(self._pos)
                     cur_dev = jnp.asarray(self._cur)
                     temps_dev = jnp.asarray(self._temps)
+                    self._sync_state_owner()
                     if spec_tick:
                         gammas = np.zeros(self.paged.max_slots, np.int32)
                         for ix in active:

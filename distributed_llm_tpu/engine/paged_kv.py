@@ -46,7 +46,12 @@ models/transformer.py — this module only changes where the rows live.
 The latent family's layer is models/latent_moe.py's; the three step
 functions hand it the cells to write and the tables to read, and the
 block programs (``copy_block``, ``gather_blocks``, ``scatter_blocks``)
-work on whatever arrays the pool has.
+work on whatever arrays the pool has.  The hybrid family's
+(models/hybrid_ssm.py) pool holds K/V blocks for its ATTENTION layers
+only and, beside them, one recurrent row a slot a state-space layer
+(``"s"``, ``"t"``) with the vector that says whose each row is
+(``"owner"``): rows are not blocks, so the block programs refuse that
+pool by name.
 """
 
 from __future__ import annotations
@@ -59,7 +64,7 @@ import jax
 import jax.numpy as jnp
 
 from ..config import ModelConfig
-from ..models import latent_moe, transformer
+from ..models import hybrid_ssm, latent_moe, transformer
 from ..ops import attention, quant
 
 KVPool = Dict[str, jax.Array]    # {"k","v": [L, NB, bs, N_kv * D]}
@@ -112,6 +117,23 @@ def init_pool(cfg: ModelConfig, pcfg: PagedConfig,
                 f"({cfg.name}) has no int8 pool — its one cached row a "
                 f"token is not rows by heads; use 'none'")
         return {"c": jnp.zeros(shape, jnp.dtype(cfg.dtype))}
+    if cfg.hybrid:
+        if kv_quantize != "none":
+            raise ValueError(
+                f"kv_quantize={kv_quantize!r}: the state-space hybrid "
+                f"family ({cfg.name}) has no int8 pool — the dense int8 "
+                f"rows are not wired to its attention layers; use 'none'")
+        dtype = jnp.dtype(cfg.dtype)
+        kv = (cfg.layers_of("*"),) + shape[1:]
+        n_m, r = cfg.layers_of("M"), pcfg.max_slots
+        # "k" first: ``_block_size`` reads the first array.  The state is
+        # float32 at rest; the conv tail in the model's dtype.
+        return {"k": jnp.zeros(kv, dtype), "v": jnp.zeros(kv, dtype),
+                "s": jnp.zeros((n_m, r, cfg.ssm_heads, cfg.ssm_head_dim,
+                                cfg.ssm_state), jnp.float32),
+                "t": jnp.zeros((n_m, r, cfg.ssm_conv - 1,
+                                cfg.ssm_conv_width), dtype),
+                "owner": jnp.zeros((r,), jnp.int32)}
     if kv_quantize == "int8":
         scales = rows + (cfg.num_kv_heads,)
         return {"k": jnp.zeros(shape, jnp.int8),
@@ -242,6 +264,7 @@ def write_prefill_blocks(pool: KVPool, blocks: jax.Array,
     the latent family's ``[L, S, R]`` alone — with S == nb * block_size
     (bucketed prompts divide evenly).
     """
+    _blocks_only(pool, "write_prefill_blocks")
     l, s = rows_all[0].shape[:2]
     nb = blocks.shape[0]
     bs = s // nb
@@ -256,6 +279,19 @@ def write_prefill_blocks(pool: KVPool, blocks: jax.Array,
         l, nb, bs, x.shape[-1])) for key, x in pool.items()}
 
 
+def _blocks_only(pool: KVPool, what: str) -> None:
+    """The block programs move BLOCKS; a pool with recurrent rows beside
+    them (the state-space hybrid family) holds state no block carries,
+    so sharing, copying or spilling its blocks would part a sequence
+    from its state."""
+    if "owner" in pool:
+        raise NotImplementedError(
+            f"{what}: the state-space hybrid family's pool keeps a "
+            f"recurrent row a slot beside its K/V blocks; no block "
+            f"program moves it (prefix reuse, sharing, spill and cold "
+            f"prefill are refused at engine build)")
+
+
 def copy_block(pool: KVPool, src: jax.Array, dst: jax.Array) -> KVPool:
     """Copy one pool block's rows (and int8 scales) from ``src`` to
     ``dst`` — the copy-on-write boundary step of shared-prefix KV
@@ -268,6 +304,8 @@ def copy_block(pool: KVPool, src: jax.Array, dst: jax.Array) -> KVPool:
     bounded exactly like the prefill writers (a per-pair or per-length
     wrap would re-trace on the admit path; the retrace lint fixtures in
     tests/test_lint.py pin the idiom)."""
+    _blocks_only(pool, "copy_block")
+
     def copy(x):       # one block-sized slice out, one in-place update in
         tile = jax.lax.dynamic_slice_in_dim(x, src, 1, axis=1)
         return jax.lax.dynamic_update_slice_in_dim(x, tile, dst, axis=1)
@@ -285,6 +323,7 @@ def gather_blocks(pool: KVPool, blocks: jax.Array) -> KVPool:
     donating backends the enqueued gather reads its input before the
     donated update may alias it.  The device→host pull of the snapshot
     happens on the spill copier thread, never here."""
+    _blocks_only(pool, "gather_blocks")
     return {key: x[:, blocks] for key, x in pool.items()}
 
 
@@ -295,6 +334,7 @@ def scatter_blocks(pool: KVPool, blocks: jax.Array,
     tier.  The exact inverse of ``gather_blocks`` (bit-identical round
     trip, int8 scales included), so a promoted prefix serves decode
     exactly like one that never left the pool."""
+    _blocks_only(pool, "scatter_blocks")
     return {key: x.at[:, blocks].set(tiles[key]) for key, x in pool.items()}
 
 
@@ -308,7 +348,8 @@ def pool_block_bytes(cfg: ModelConfig, block_size: int,
         return (cfg.num_layers * block_size * cfg.cache_row_width
                 * jnp.dtype(cfg.dtype).itemsize)
     d = cfg.head_dim
-    per_row = cfg.num_layers * cfg.num_kv_heads * block_size
+    n_layers = cfg.layers_of("*") if cfg.hybrid else cfg.num_layers
+    per_row = n_layers * cfg.num_kv_heads * block_size
     if kv_quantize == "int8":
         # int8 k/v (1 byte) + float32 per-row scales.
         return per_row * (d * 2 + 4 * 2)
@@ -394,8 +435,12 @@ def chunk_prefill_paged(
     Returns (hidden [1, S_c, H], updated pool).  The chunk's rows scatter
     to (table[p//bs], p%bs) per position; attention gathers the first
     window//bs table blocks, so cost is O(window), not O(max_seq).
-    ``counts`` (the latent family's engine programs): a third result,
-    the chunk's assignments an expert ``[expert layers, num_experts]``.
+    ``counts`` (the latent and hybrid families' engine programs): a
+    third result, the chunk's assignments an expert ``[expert layers,
+    num_experts]`` (hybrid: the held experts, then one column of the
+    assignments that went to absent ones).  The hybrid family's chunk
+    also finds or, at ``start == 0``, claims and zeroes the sequence's
+    recurrent row (models/hybrid_ssm.py).
     """
     b, s_c = tokens.shape
     d = cfg.head_dim
@@ -410,6 +455,12 @@ def chunk_prefill_paged(
         hidden, new_pool, n_exp = latent_moe.forward_paged(
             cfg, params, tokens, positions, q_pos, pool, blk[None],
             off[None], table[None, :window // bs])
+        return (hidden, new_pool, n_exp) if counts else (hidden, new_pool)
+    if cfg.hybrid:
+        ctx, pool = hybrid_ssm.chunk_ctx(pool, table, start, true_len, s_c,
+                                         window, blk, off, q_pos)
+        hidden, new_pool, n_exp = hybrid_ssm.forward_paged(
+            cfg, params, tokens, pool, ctx)
         return (hidden, new_pool, n_exp) if counts else (hidden, new_pool)
 
     x = quant.embed_rows(params["embed"], tokens)            # [1, S_c, H]
@@ -479,10 +530,11 @@ def verify_step_paged(
     Positions past ``max_seq_len`` (a slot finishing at the context
     edge mid-chunk) scatter into the trash block instead of clamping
     onto live KV."""
-    if cfg.latent:
+    if cfg.latent or cfg.hybrid:
         raise NotImplementedError(
-            f"{cfg.name}: the latent-attention family has no speculative "
-            f"verify step (a draft model is refused at engine build)")
+            f"{cfg.name}: the latent-attention and the state-space hybrid "
+            f"families have no speculative verify step (a draft model is "
+            f"refused at engine build)")
     b, g = tokens.shape
     d = cfg.head_dim
     bs = _block_size(pool)
@@ -570,7 +622,9 @@ def decode_step_paged(
     The latent family attends the tables it is given under either
     contract (masked by ``pos``), in the absorbed form; ``counts`` adds
     a third result, the step's assignments an expert ``[expert layers,
-    num_experts]``.
+    num_experts]``.  The hybrid family (windowed tables) also advances
+    the recurrent rows whose first block leads one of ``tables``, and no
+    other (models/hybrid_ssm.py).
     """
     b = token.shape[0]
     d = cfg.head_dim
@@ -582,6 +636,12 @@ def decode_step_paged(
         hidden, new_pool, n_exp = latent_moe.forward_paged(
             cfg, params, token[:, None], pos[:, None], pos[:, None], pool,
             blk[:, None], off[:, None], tables)
+        logits = transformer.logits_from_hidden(params, hidden[:, 0])
+        return (logits, new_pool, n_exp) if counts else (logits, new_pool)
+    if cfg.hybrid:
+        hidden, new_pool, n_exp = hybrid_ssm.forward_paged(
+            cfg, params, token[:, None], pool,
+            hybrid_ssm.decode_ctx(pool, tables, pos, blk, off))
         logits = transformer.logits_from_hidden(params, hidden[:, 0])
         return (logits, new_pool, n_exp) if counts else (logits, new_pool)
 

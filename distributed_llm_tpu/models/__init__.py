@@ -1,6 +1,9 @@
 """Model families: dense LLaMA-style (transformer.py), MoE (moe.py) and
 the latent-attention, routed-expert, multi-stream family (latent_moe.py,
-``cfg.latent``; it serves through the paged pool only).
+``cfg.latent``) and the state-space / attention / routed-expert hybrid
+(hybrid_ssm.py, ``cfg.hybrid``); the last two serve through the paged
+pool only, and the hybrid has no cold prefill: every prompt goes through
+the chunk program, which carries the recurrent state.
 
 ``model_module(cfg)`` dispatches on ModelConfig.num_experts so the engine,
 trainer, and checkpoint code serve either family through one surface:
@@ -13,12 +16,14 @@ and are shared.
 from __future__ import annotations
 
 from ..config import ModelConfig
-from . import latent_moe, moe, transformer  # noqa: F401
+from . import hybrid_ssm, latent_moe, moe, transformer  # noqa: F401
 
 
 def model_module(cfg: ModelConfig):
     if cfg.latent:
         return latent_moe
+    if cfg.hybrid:
+        return hybrid_ssm
     return moe if cfg.num_experts > 1 else transformer
 
 
@@ -26,6 +31,10 @@ def serving_prefill(cfg: ModelConfig, params, tokens, positions, attn=None):
     """(hidden, (k_all, v_all)) for either family (drops MoE aux loss);
     the latent family gives (hidden, (rows,)), one array a pool array.
     ``attn`` (dense only): attention-op override — see transformer.prefill."""
+    if cfg.hybrid:
+        raise NotImplementedError(
+            f"{cfg.name}: the state-space hybrid family has no cold "
+            f"prefill; the engine chunk-prefills every prompt")
     if cfg.latent:
         out = latent_moe.prefill(cfg, params, tokens, positions)
     elif cfg.num_experts > 1:

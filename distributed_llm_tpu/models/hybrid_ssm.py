@@ -1,0 +1,550 @@
+"""The state-space / attention / routed-expert hybrid decoder family.
+
+A non-empty ``ModelConfig.layer_pattern`` selects it: one character a
+layer, and EACH layer is ONE pre-norm mixer, ``x <- x + mixer(RMSNorm(x))``,
+the residual in the model's dtype.
+
+- ``M``, **Mamba-2**: ``[z | xBC | dt] = x W_in``; a causal depthwise conv
+  of ``ssm_conv`` taps (with bias) and silu over ``xBC``; per head ``h`` a
+  state ``S_h [head_dim, state]``, ``S_t = exp(dt_t A_h) S_{t-1} + dt_t
+  x_t[h] (x) B_t[group of h]``, ``y_t[h] = S_t C_t[group of h] + D_h
+  x_t[h]``, ``dt = softplus(dt + dt_bias)``, ``A = -exp(A_log)``; then
+  ``y * silu(z)``, an RMSNorm over each group's channels, ``W_out``.  A
+  sequence keeps ``S`` (float32) and the conv's last ``ssm_conv - 1``
+  input rows a layer: constant in the context length, a ROW of
+  ``pool["s"]`` / ``pool["t"]`` and never a block of the paged pool.
+- ``*``, **attention**: grouped-query heads of ``cfg.head_dim``, no rotary
+  embedding (``cfg.rotary`` False: position comes from the state-space
+  layers), over the K/V blocks of the paged pool — which holds the
+  attention layers ONLY.
+- ``E``, **experts**: a float32 sigmoid router over ``num_experts``
+  outputs, the top ``experts_per_token`` of score + bias chosen (the bias
+  moves the choice only), weighed by their normalised scores times
+  ``router_scale`` — ``latent_moe.route``, the same form.  This program
+  HOLDS experts ``experts_first`` .. ``+ experts_count`` and computes their
+  part of the sum; an assignment to an absent expert adds nothing here
+  (its rank of the expert-parallel pair adds it), in the plain reference
+  alike.  Experts are non-gated, ``W_2 relu(W_1 x)^2``; one shared expert
+  of ``shared_ffn_size`` adds for every token.
+
+Whose row: ``pool["owner"][r]`` is the FIRST BLOCK of the sequence that
+holds recurrent row ``r`` (0, the trash block: free).  The step functions
+of engine/paged_kv.py are handed block tables and never a slot, so a
+sequence's row is looked up by its table's first block.  A chunk with
+``start == 0`` takes the row that names its block, else the first free
+one, and zeroes it; a decode step updates the rows whose block leads one
+of its tables and leaves every other row — an idle slot's, a slot's still
+in prefill — bit-identical.  The engine keeps the vector itself (row =
+slot: ``ContinuousBatchingEngine._sync_state_owner``), so on its path the
+lookup only ever finds.
+
+ONE body a layer kind serves the chunk program and the decode tick; the
+layer loop is a ``scan`` over PERIODS of the pattern with the period's
+kinds inline, and nothing in a body lowers to a loop (the benchmark tells
+a decode tick from a prefill program by how deep its ``while``s nest): the
+chunk's recurrence is in matrix form — one block, quadratic in the chunk
+length, made for chunks of a few hundred tokens.
+"""
+
+from __future__ import annotations
+
+from typing import Any, Dict, Optional
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from ..config import ModelConfig
+from ..ops import attention, quant
+from . import latent_moe, transformer
+from .latent_moe import EMBED_STD, HIGHEST, ROUTER_BIAS_STD, _normal, _table
+
+Params = Dict[str, Any]
+KINDS = "M*E"
+EXPERT_KEYS = ("we_up", "we_down")
+LANES = 128
+
+
+EXPERT_ALIGN = 256
+
+
+def expert_dims_stored(cfg: ModelConfig):
+    """(hidden, width) of the routed experts' matrices as STORED: each
+    rounded up to a multiple of 256 with zero rows and columns (a zero
+    input row adds nothing, ``relu(0)^2 = 0``, a zero output column is cut
+    off: the same numbers).  Two things hang on it at the published 2688 x
+    1856.  The grouped product's kernel tiles by divisors of its
+    dimensions: 41 GB/s of the touched experts' bytes at 2688 x 1856, 112
+    at 2688 x 1920, 305 at 2688 x 2048, 460 at 2816 x 2048, 581 at 3072 x
+    2048 (my chip runs, PR 33: 96 rows over 26 of 128 groups).  And at a
+    minor width that is not a multiple of the chip's 128 lanes the device
+    rests ``W_1`` [.., H, F] with H minor while the kernel wants F minor,
+    so every program copied every held expert at its entry (3 x 1.28 GB a
+    tick: compile for a described v5e, PR 33)."""
+    def up(n):
+        return -(-n // EXPERT_ALIGN) * EXPERT_ALIGN
+    return up(cfg.hidden_size), up(cfg.moe_ffn_size)
+
+
+def check(cfg: ModelConfig) -> None:
+    """The pattern and the sizes that have to agree with it."""
+    bad = sorted(set(cfg.layer_pattern) - set(KINDS))
+    if bad or len(cfg.layer_pattern) != cfg.num_layers:
+        raise ValueError(
+            f"{cfg.name}: layer_pattern {cfg.layer_pattern!r} has to be "
+            f"num_layers = {cfg.num_layers} characters of {KINDS!r}")
+    if cfg.ssm_heads % cfg.ssm_groups:
+        raise ValueError(f"{cfg.name}: ssm_heads {cfg.ssm_heads} is not a "
+                         f"multiple of ssm_groups {cfg.ssm_groups}")
+    if not 0 <= cfg.experts_first <= cfg.num_experts - cfg.experts_held:
+        raise ValueError(
+            f"{cfg.name}: experts {cfg.experts_first}..+{cfg.experts_held} "
+            f"are not among the router's {cfg.num_experts}")
+    if cfg.expert_act != "relu2" or cfg.rotary or cfg.tie_embeddings:
+        raise ValueError(f"{cfg.name}: the hybrid family is written for "
+                         f"relu2 experts, no rotary embedding and a head "
+                         f"of its own")
+
+
+def kind_index(cfg: ModelConfig, kind: str):
+    """Per position of the period: how many layers of ``kind`` come before
+    it in the period, and how many the period has."""
+    period = cfg.layer_period
+    return ([period[:j].count(kind) for j in range(len(period))],
+            period.count(kind))
+
+
+# =============================================================================
+# Init: the seed is data, never a constant of the program
+# =============================================================================
+
+def _uniform(key, shape, dtype, bound):
+    return jax.random.uniform(key, shape, jnp.float32, -bound,
+                              bound).astype(dtype)
+
+
+def init_layer(cfg: ModelConfig, key, kind: str) -> Params:
+    """One layer from its own key, split 8 ways."""
+    dtype = jnp.dtype(cfg.dtype)
+    h = cfg.hidden_size
+    ks = jax.random.split(key, 8)
+    lp = {"ln": jnp.ones((h,), dtype)}
+    if kind == "M":
+        nh, di, c, k = (cfg.ssm_heads, cfg.ssm_inner, cfg.ssm_conv_width,
+                        cfg.ssm_conv)
+        # The published init: dt log-uniform in [dt_min, dt_max], floored,
+        # stored as the inverse of softplus; A uniform in [1, 16]; D = 1.
+        dt = jnp.exp(jax.random.uniform(ks[3], (nh,), jnp.float32)
+                     * (np.log(cfg.ssm_dt_max) - np.log(cfg.ssm_dt_min))
+                     + np.log(cfg.ssm_dt_min))
+        dt = jnp.maximum(dt, cfg.ssm_dt_floor)
+        lp.update(
+            # [z | xBC | dt], then zero columns up to the chip's lanes:
+            # at the published 10304 (80.5 x 128) the device rests the
+            # matrix transposed and the tick copied it at its entry.
+            w_in=jnp.pad(_normal(ks[0], (h, di + c + nh), dtype),
+                         ((0, 0), (0, -(di + c + nh) % LANES))),
+            # A depthwise conv's default init: uniform in +-1/sqrt(taps).
+            conv_w=_uniform(ks[1], (k, c), dtype, k ** -0.5),
+            conv_b=_uniform(ks[2], (c,), dtype, k ** -0.5),
+            dt_bias=dt + jnp.log(-jnp.expm1(-dt)),
+            a_log=jnp.log(jax.random.uniform(ks[4], (nh,), jnp.float32,
+                                             1.0, 16.0)),
+            d=jnp.ones((nh,), jnp.float32),
+            gn=jnp.ones((di,), dtype),
+            w_out=_normal(ks[5], (di, h), dtype))
+    elif kind == "*":
+        d, nq, nkv = cfg.head_dim, cfg.num_heads, cfg.num_kv_heads
+        lp.update(wq=_normal(ks[0], (h, nq * d), dtype),
+                  wk=_normal(ks[1], (h, nkv * d), dtype),
+                  wv=_normal(ks[2], (h, nkv * d), dtype),
+                  wo=_normal(ks[3], (nq * d, h), dtype))
+    else:
+        f, e = cfg.moe_ffn_size, cfg.num_experts
+        held = slice(cfg.experts_first, cfg.experts_first + cfg.experts_held)
+        h_st, f_st = expert_dims_stored(cfg)
+
+        def experts(key, shape, stored):
+            # A key a ROUTER OUTPUT, the held ones taken: an expert's
+            # matrix is the same whichever share holds it.  One expert at
+            # a time (the float32 draws beside the result are one's),
+            # zero-padded to ``expert_dims_stored``.
+            pad = [(0, st - n) for n, st in zip(shape, stored)]
+            return jax.lax.map(
+                lambda k: jnp.pad(_normal(k, shape, dtype), pad),
+                jax.random.split(key, e)[held])
+
+        lp.update(router=_normal(ks[0], (h, e), dtype),
+                  router_bias=ROUTER_BIAS_STD * jax.random.normal(
+                      ks[1], (e,), jnp.float32),
+                  we_up=experts(ks[2], (h, f), (h_st, f_st)),
+                  we_down=experts(ks[3], (f, h), (f_st, h_st)))
+        if cfg.shared_ffn_size:
+            lp.update(ws_up=_normal(ks[4], (h, cfg.shared_ffn_size), dtype),
+                      ws_down=_normal(ks[5], (cfg.shared_ffn_size, h),
+                                      dtype))
+    return lp
+
+
+def init_params(cfg: ModelConfig, seed=0) -> Params:
+    """``seed`` may be traced (jit this with the seed as an ARGUMENT: one
+    compiled program makes every seed's weights).  ``periods[j]`` holds
+    position ``j`` of the period for every period, stacked — what the
+    layer loop scans; layer ``l`` draws from key ``l`` of ``num_layers``."""
+    check(cfg)
+    dtype = jnp.dtype(cfg.dtype)
+    k_embed, k_head, k_layers = jax.random.split(jax.random.PRNGKey(seed), 3)
+    lkeys = jax.random.split(k_layers, cfg.num_layers)
+    period = cfg.layer_period
+    return {
+        "embed": _table(k_embed, cfg.vocab_size, cfg.hidden_size, dtype,
+                        EMBED_STD),
+        "head": _table(k_head, cfg.vocab_size, cfg.hidden_size, dtype),
+        "final_ln": jnp.ones((cfg.hidden_size,), dtype),
+        "periods": [jax.lax.map(lambda k, c=kind: init_layer(cfg, k, c),
+                                lkeys[j::len(period)])
+                    for j, kind in enumerate(period)],
+    }
+
+
+# =============================================================================
+# Whose recurrent row
+# =============================================================================
+
+def claim_row(owner: jax.Array, first_block: jax.Array, fresh: jax.Array):
+    """(row, owner) for the sequence whose table starts at
+    ``first_block``: the row that names it, else — a ``fresh`` sequence
+    only — the first free row, which then names it."""
+    named = owner == first_block
+    row = jnp.where(jnp.any(named), jnp.argmax(named),
+                    jnp.argmax(owner == 0)).astype(jnp.int32)
+    return row, jnp.where(fresh, owner.at[row].set(first_block), owner)
+
+
+def rows_of(owner: jax.Array, first_blocks: jax.Array):
+    """For a decode batch whose tables start at ``first_blocks`` [B]:
+    (the batch index a row takes its input from [R], whether any does
+    [R], the row a batch index reads its output from [B])."""
+    named = (owner[:, None] == first_blocks[None, :]) & (owner[:, None] != 0)
+    return (jnp.argmax(named, axis=1), jnp.any(named, axis=1),
+            jnp.argmax(named, axis=0))
+
+
+# =============================================================================
+# The three mixers
+# =============================================================================
+
+def _split_in(cfg: ModelConfig, zxbcdt: jax.Array):
+    di, c = cfg.ssm_inner, cfg.ssm_conv_width
+    return (zxbcdt[..., :di], zxbcdt[..., di:di + c],
+            zxbcdt[..., di + c:di + c + cfg.ssm_heads])
+
+
+def _heads(cfg: ModelConfig, u: jax.Array):
+    """Conv output [..., C] float32 -> x [..., G, heads a group, P],
+    B and C [..., G, N]."""
+    g, n, p = cfg.ssm_groups, cfg.ssm_state, cfg.ssm_head_dim
+    di = cfg.ssm_inner
+    lead = u.shape[:-1]
+    return (u[..., :di].reshape(*lead, g, cfg.ssm_heads // g, p),
+            u[..., di:di + g * n].reshape(*lead, g, n),
+            u[..., di + g * n:].reshape(*lead, g, n))
+
+
+def _by_group(cfg: ModelConfig, a: jax.Array):
+    """[..., heads] -> [..., G, heads a group]."""
+    return a.reshape(*a.shape[:-1], cfg.ssm_groups,
+                     cfg.ssm_heads // cfg.ssm_groups)
+
+
+def ssm_step(cfg: ModelConfig, lp: Params, xbc, dt, state, tail, valid):
+    """The one-step recurrence over ROWS: xbc [R, C] and dt [R, heads] the
+    row's token, state [R, heads, P, N] float32, tail [R, K-1, C], valid
+    [R].  Returns (y [R, inner] float32, state, tail); a row that is not
+    ``valid`` keeps both bit-identical."""
+    r = xbc.shape[0]
+    g = cfg.ssm_groups
+    with jax.named_scope("ssm_conv"):
+        window = jnp.concatenate([tail, xbc[:, None]], axis=1)   # [R, K, C]
+        u = jnp.sum(window.astype(jnp.float32)
+                    * lp["conv_w"].astype(jnp.float32), axis=1)
+        u = jax.nn.silu(u + lp["conv_b"].astype(jnp.float32))
+        tail = jnp.where(valid[:, None, None], window[:, 1:], tail)
+    with jax.named_scope("ssm_step"):
+        x, b, c = _heads(cfg, u)
+        dt = _by_group(cfg, jax.nn.softplus(dt.astype(jnp.float32)
+                                            + lp["dt_bias"]))
+        decay = jnp.exp(dt * _by_group(cfg, -jnp.exp(lp["a_log"])))
+        s = state.reshape(r, g, -1, *state.shape[2:])      # [R, G, k, P, N]
+        new = (s * decay[..., None, None]
+               + (dt[..., None] * x)[..., None] * b[:, :, None, None, :])
+        new = jnp.where(valid[:, None, None, None, None], new, s)
+        y = (jnp.sum(new * c[:, :, None, None, :], axis=-1)
+             + _by_group(cfg, lp["d"])[..., None] * x)
+    return y.reshape(r, -1), new.reshape(state.shape), tail
+
+
+def ssm_scan(cfg: ModelConfig, lp: Params, xbc, dt, state, tail, n_valid):
+    """The same recurrence over a CHUNK of one sequence, in matrix form:
+    xbc [S, C], dt [S, heads], from ``state`` [heads, P, N] and ``tail``
+    [K-1, C]; positions ``>= n_valid`` are right padding — their time step
+    is 0, so they neither decay nor feed the state, and the tail is taken
+    from the last valid rows.  Returns (y [S, inner] float32, state,
+    tail).  With ``a_t = dt_t A`` and ``cum`` its running sum,
+
+        y_t = sum_{s<=t} exp(cum_t - cum_s) (C_t . B_s) dt_s x_s
+              + exp(cum_t) C_t . S_0 + D x_t
+        S_end = exp(cum_T) S_0 + sum_s exp(cum_T - cum_s) dt_s x_s (x) B_s
+
+    every product a float32 einsum at the highest precision (under 1
+    GFLOP a layer a chunk of 256)."""
+    s_c, k = xbc.shape[0], cfg.ssm_conv
+    g = cfg.ssm_groups
+    with jax.named_scope("ssm_conv"):
+        seq = jnp.concatenate([tail, xbc], axis=0)               # [S+K-1, C]
+        w = lp["conv_w"].astype(jnp.float32)
+        u = sum(seq[j:j + s_c].astype(jnp.float32) * w[j] for j in range(k))
+        u = jax.nn.silu(u + lp["conv_b"].astype(jnp.float32))
+        tail = jax.lax.dynamic_slice_in_dim(seq, n_valid, k - 1, axis=0)
+    with jax.named_scope("ssm_scan"):
+        x, b, c = _heads(cfg, u)              # [S, G, k, P], [S, G, N] x 2
+        live = (jnp.arange(s_c) < n_valid)[:, None]
+        dt = jnp.where(live, jax.nn.softplus(dt.astype(jnp.float32)
+                                             + lp["dt_bias"]), 0.0)
+        a = dt * -jnp.exp(lp["a_log"])                           # [S, heads]
+        causal = jnp.tril(jnp.ones((s_c, s_c), bool))
+        # Heads lead and the chunk's positions are minor: [.., t, s].
+        cum = jnp.einsum("ts,sh->ht", causal.astype(jnp.float32), a,
+                         precision=HIGHEST)                      # [heads, S]
+        # exp(cum_t - cum_s) for s <= t: never above 1.
+        gap = jnp.where(causal, cum[:, :, None] - cum[:, None, :], -jnp.inf)
+        cb = jnp.einsum("tgn,sgn->gts", c, b, precision=HIGHEST)
+        mix = (jnp.exp(gap).reshape(g, -1, s_c, s_c) * cb[:, None])
+        xdt = x * _by_group(cfg, dt)[..., None]               # [S, G, k, P]
+        s0 = state.reshape(g, -1, *state.shape[1:])           # [G, k, P, N]
+        cum_g = _by_group(cfg, cum.T)                            # [S, G, k]
+        y = (jnp.einsum("gkts,sgkp->tgkp", mix, xdt, precision=HIGHEST)
+             + jnp.einsum("tgn,gkpn->tgkp", c, s0, precision=HIGHEST)
+             * jnp.exp(cum_g)[..., None]
+             + _by_group(cfg, lp["d"])[..., None] * x)
+        to_end = jnp.exp(cum_g[-1][None] - cum_g)                # [S, G, k]
+        new = (jnp.exp(cum_g[-1])[..., None, None] * s0
+               + jnp.einsum("sgkp,sgn->gkpn", xdt * to_end[..., None], b,
+                            precision=HIGHEST))
+    return y.reshape(s_c, -1), new.reshape(state.shape), tail
+
+
+def _gate_norm(cfg: ModelConfig, lp: Params, y, z):
+    """``y * silu(z)``, an RMSNorm over each group's channels, the gain;
+    float32 in, the model's dtype out."""
+    with jax.named_scope("ssm_gate_norm"):
+        y = y * jax.nn.silu(z.astype(jnp.float32))
+        grouped = y.reshape(*y.shape[:-1], cfg.ssm_groups, -1)
+        grouped = grouped * jax.lax.rsqrt(
+            jnp.mean(grouped * grouped, axis=-1, keepdims=True)
+            + cfg.norm_eps)
+        return grouped.reshape(y.shape).astype(z.dtype) * lp["gn"]
+
+
+def _mamba(cfg: ModelConfig, lp: Params, h_in, pool, li, ctx):
+    """h_in [B, S, H] -> (mixer output, pool); ``li`` the layer's index
+    among the state-space layers."""
+    with jax.named_scope("ssm_in_proj"):
+        z, xbc, dt = _split_in(cfg, quant.matmul(h_in, lp["w_in"]))
+    s_all, t_all = pool["s"], pool["t"]
+    if "row" in ctx:                               # a chunk of one sequence
+        row, fresh = ctx["row"], ctx["fresh"]
+        state = jnp.where(fresh, 0.0, s_all[li, row])
+        tail = jnp.where(fresh, jnp.zeros((), t_all.dtype), t_all[li, row])
+        y, state, tail = ssm_scan(cfg, lp, xbc[0], dt[0], state, tail,
+                                  ctx["n_valid"])
+        pool = {**pool, "s": s_all.at[li, row].set(state),
+                "t": t_all.at[li, row].set(tail)}
+        y = y[None]
+    else:                                          # a decode step, by rows
+        src, valid, dst = ctx["rows"]
+        y, state, tail = ssm_step(cfg, lp, xbc[src, 0], dt[src, 0],
+                                  s_all[li], t_all[li], valid)
+        pool = {**pool, "s": s_all.at[li].set(state),
+                "t": t_all.at[li].set(tail)}
+        y = y[dst][:, None]
+    return quant.matmul(_gate_norm(cfg, lp, y, z), lp["w_out"]), pool
+
+
+def _attention(cfg: ModelConfig, lp: Params, h_in, pool, li, ctx):
+    """h_in [B, S, H] -> (mixer output, pool); ``li`` the layer's index
+    among the attention layers, which are the K/V pool's layers."""
+    b, s, _ = h_in.shape
+    d = cfg.head_dim
+    q = quant.matmul(h_in, lp["wq"]).reshape(b, s, cfg.num_heads, d)
+    k = quant.matmul(h_in, lp["wk"])
+    v = quant.matmul(h_in, lp["wv"])
+    blk, off = ctx["blk"], ctx["off"]
+    with jax.named_scope("kv_write"):
+        k_p = pool["k"].at[li, blk, off].set(k)
+        v_p = pool["v"].at[li, blk, off].set(v)
+    with jax.named_scope("attention"):
+        if "row" in ctx:
+            out = attention.paged_chunk(
+                q, k_p, v_p, ctx["table"], ctx["start"], ctx["q_pos"],
+                ctx["window"], impl=cfg.attention_impl, layer=li)
+        else:
+            out = attention.paged_decode(
+                q[:, 0], k_p, v_p, ctx["tables"], ctx["pos"],
+                impl=cfg.attention_impl, layer=li)
+    out = quant.matmul(out.reshape(b, s, cfg.num_heads * d), lp["wo"])
+    return out, {**pool, "k": k_p, "v": v_p}
+
+
+def _relu2_mlp(x, up, down):
+    a = jax.nn.relu(quant.matmul(x, up))
+    return quant.matmul(a * a, down)
+
+
+def shared_expert(lp: Params, x: jax.Array) -> jax.Array:
+    with jax.named_scope("shared_expert"):
+        return _relu2_mlp(x, lp["ws_up"], lp["ws_down"])
+
+
+def routed_experts(cfg: ModelConfig, lp: Params, x: jax.Array,
+                   stacked: Optional[Params] = None, period=None):
+    """x [T, H] float32 -> (the HELD experts' part of the routed sum [T, H]
+    in the model's dtype, counts [experts_held + 1] int32: assignments a
+    held expert, then those that went to absent ones).  The router scores
+    all ``num_experts`` outputs and weighs the chosen over ALL of them,
+    whoever holds them.  Dropless and sorted by expert as
+    ``latent_moe.routed_experts``; ``stacked`` [periods, held, in, out]
+    with ``period`` the traced index, for the same reason as there."""
+    t, h = x.shape
+    k, held = cfg.experts_per_token, cfg.experts_held
+    xd = x.astype(jnp.dtype(cfg.dtype))
+    with jax.named_scope("moe_router"):
+        choice, w = latent_moe.route(cfg, lp, x)
+        local = choice - cfg.experts_first
+        # Absent experts sort last, as one group nothing multiplies.
+        flat = jnp.where((local >= 0) & (local < held), local,
+                         held).reshape(-1)
+        counts = jnp.sum(flat[:, None] == jnp.arange(held + 1), axis=0,
+                         dtype=jnp.int32)
+        order = jnp.argsort(flat, stable=True)
+        mats, sizes = lp, counts[:held]
+        if stacked is not None:
+            n = stacked[EXPERT_KEYS[0]].shape[0]
+            mats = {key: stacked[key].reshape(n * held,
+                                              *stacked[key].shape[2:])
+                    for key in EXPERT_KEYS}
+            sizes = jax.lax.dynamic_update_slice(
+                jnp.zeros(n * held, jnp.int32), sizes, (period * held,))
+    with jax.named_scope("moe_experts"):
+        expert = flat[order]
+        here = (expert < held)[:, None]
+        group = jnp.minimum(expert, held - 1)
+        xs = xd[order // k]                                    # [T*k, H]
+        xs = jnp.pad(xs, ((0, 0), (0, expert_dims_stored(cfg)[0] - h)))
+        a = jax.nn.relu(latent_moe._grouped(xs, mats["we_up"], sizes, group))
+        y = latent_moe._grouped((a * a).astype(xd.dtype), mats["we_down"],
+                                sizes, group)[:, :h]
+        # Rows past the held groups belong to no group: whatever the
+        # grouped product left there is not a number of this layer.
+        y = jnp.where(here, y, 0)
+        y = y[jnp.argsort(order)].reshape(t, k, h)
+        out = jnp.einsum("tkh,tk->th", y.astype(jnp.float32), w)
+    return out.astype(xd.dtype), counts
+
+
+def _experts(cfg: ModelConfig, lp: Params, h_f32, stacked, period):
+    """h_f32 [B, S, H], the float32 normed input -> (output in the
+    model's dtype, counts)."""
+    b, s, h = h_f32.shape
+    out, counts = routed_experts(cfg, lp, h_f32.reshape(b * s, h), stacked,
+                                 period)
+    out = out.reshape(b, s, h)
+    if "ws_up" in lp:
+        out = out + shared_expert(lp, h_f32.astype(out.dtype))
+    return out, counts
+
+
+# =============================================================================
+# The forward pass over the paged pool
+# =============================================================================
+
+def _norm_f32(x, w, eps):
+    """RMSNorm with the gain, in float32 (the router reads this; the
+    mixers its rounding to the model's dtype)."""
+    xf = x.astype(jnp.float32)
+    return (xf * jax.lax.rsqrt(jnp.mean(xf * xf, -1, keepdims=True) + eps)
+            * w.astype(jnp.float32))
+
+
+def forward_paged(cfg: ModelConfig, params: Params, tokens: jax.Array,
+                  pool, ctx: Dict[str, Any]):
+    """tokens [B, S]; ``pool`` {"k", "v": [attention layers, NB, bs,
+    N_kv * D], "s": [state-space layers, R, heads, P, N] float32, "t":
+    [state-space layers, R, K-1, C], "owner": [R]}.  ``ctx`` is what the
+    mixers need of where the tokens sit (``chunk_ctx`` / ``decode_ctx``).
+    Returns (hidden [B, S, H] after the final norm, pool, counts [expert
+    layers, experts_held + 1])."""
+    dtype = jnp.dtype(cfg.dtype)
+    period = cfg.layer_period
+    index = {kind: kind_index(cfg, kind) for kind in KINDS}
+    x = quant.embed_rows(params["embed"], tokens).astype(dtype)
+    owner = pool["owner"]
+    carried = {key: pool[key] for key in ("k", "v", "s", "t")}
+
+    # The experts' matrices stay OUT of what the loop slices a period
+    # (``latent_moe.routed_experts``); int8 ones are widened a layer at a
+    # time.
+    layers = [dict(lp) for lp in params["periods"]]
+    stacked = [None] * len(period)
+    for j, kind in enumerate(period):
+        if kind == "E" and not quant.is_quantized(layers[j]["we_up"]):
+            stacked[j] = {key: layers[j].pop(key) for key in EXPERT_KEYS}
+
+    def body(carry, scanned):
+        x, carried = carry
+        lps, p = scanned
+        counts = []
+        for j, kind in enumerate(period):
+            lp = lps[j]
+            before, per_period = index[kind]
+            li = p * per_period + before[j]
+            h_f32 = _norm_f32(x, lp["ln"], cfg.norm_eps)
+            if kind == "M":
+                out, carried = _mamba(cfg, lp, h_f32.astype(dtype), carried,
+                                      li, ctx)
+            elif kind == "*":
+                out, carried = _attention(cfg, lp, h_f32.astype(dtype),
+                                          carried, li, ctx)
+            else:
+                out, n = _experts(cfg, lp, h_f32, stacked[j], p)
+                counts.append(n)
+            x = x + out
+        return (x, carried), jnp.stack(counts) if counts else None
+
+    n_periods = cfg.num_layers // len(period)
+    (x, carried), counts = jax.lax.scan(
+        body, (x, carried), (layers, jnp.arange(n_periods)))
+    hidden = transformer.rms_norm(x, params["final_ln"], cfg.norm_eps)
+    if counts is None:
+        counts = jnp.zeros((0, cfg.experts_held + 1), jnp.int32)
+    else:
+        counts = counts.reshape(-1, counts.shape[-1])
+    return hidden, {**carried, "owner": owner}, counts
+
+
+def chunk_ctx(pool, table, start, true_len, s_c: int, window: int,
+              blk, off, q_pos):
+    """One sequence's chunk: claims (``start == 0``) or finds its row.
+    Returns (ctx, the pool with the row named)."""
+    fresh = start[0] == 0
+    row, owner = claim_row(pool["owner"], table[0], fresh)
+    return {"row": row, "fresh": fresh,
+            "n_valid": jnp.clip(true_len[0] - start[0], 0, s_c),
+            "table": table, "start": start, "q_pos": q_pos,
+            "window": window, "blk": blk[None], "off": off[None]}, {
+                **pool, "owner": owner}
+
+
+def decode_ctx(pool, tables, pos, blk, off):
+    return {"rows": rows_of(pool["owner"], tables[:, 0]), "tables": tables,
+            "pos": pos, "blk": blk[:, None], "off": off[:, None]}

@@ -415,12 +415,24 @@ METRIC_REGISTRY: Tuple[Tuple[str, str, str, Tuple[str, ...], str], ...] = (
      ("tier", "stage"),
      "Token-to-expert assignments computed, by stage (decode|prefill): "
      "rows x experts_per_token x expert layers, idle slots and chunk "
-     "padding included"),
+     "padding included; of a program that holds a share of a layer's "
+     "experts (models/hybrid_ssm.py), those to the experts it holds"),
     ("moe_experts_touched", "counter", "dllm_moe_experts_touched_total",
      ("tier", "stage"),
      "Routed experts with at least one token, summed over expert layers "
      "and over steps (decode) or chunks (prefill): the experts whose "
      "weights a step had to read"),
+    ("moe_absent_assignments", "counter",
+     "dllm_moe_absent_assignments_total", ("tier", "stage"),
+     "Token-to-expert assignments the router made to experts this "
+     "program does not hold (ModelConfig.experts_first/experts_count: "
+     "the other rank of an expert-parallel pair computes them), by "
+     "stage; held + absent = every assignment"),
+    # The state-space hybrid family (models/hybrid_ssm.py): a sequence's
+    # recurrent row is zeroed when its prompt's first chunk starts.
+    ("state_resets", "counter", "dllm_state_resets_total", ("tier",),
+     "Recurrent rows started from zero: prompts (and preemption "
+     "replays) whose first chunk was dispatched"),
     # Batched-speculation family (ISSUE 15): drafted vs accepted
     # draft tokens per tier (the counter pair whose ratio IS the
     # realized acceptance rate) and the engine's running acceptance

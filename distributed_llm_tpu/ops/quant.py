@@ -133,7 +133,11 @@ _QUANT_LAYER_KEYS = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down",
                      # the latent family's (models/latent_moe.py): its
                      # attention projections, shared and routed experts
                      "w_qa", "w_qb", "w_kva", "w_kvb", "ws_gate", "ws_up",
-                     "ws_down", "we_gate", "we_up", "we_down")
+                     "ws_down", "we_gate", "we_up", "we_down",
+                     # the hybrid family's state-space projections
+                     # (models/hybrid_ssm.py); its conv, time-step and
+                     # decay parameters and its router stay as made
+                     "w_in", "w_out")
 
 
 def maybe_quantize(params: Dict[str, Any], tier, cfg,
@@ -174,13 +178,18 @@ def quantize_params(params: Dict[str, Any]) -> Dict[str, Any]:
         out["embed"] = quantize_tensor(params["embed"], contract_axis=-1)
     if "head" in params and not is_quantized(params["head"]):
         out["head"] = quantize_tensor(params["head"], contract_axis=-1)
-    # "lead": the latent family's dense lead layers, a second stack.
-    for group in ("layers", "lead"):
-        if group not in params:
-            continue
-        layers = dict(params[group])
+    def stack(layers):
+        layers = dict(layers)
         for k in _QUANT_LAYER_KEYS:
             if k in layers and not is_quantized(layers[k]):
                 layers[k] = quantize_tensor(layers[k])
-        out[group] = layers
+        return layers
+
+    # "lead": the latent family's dense lead layers, a second stack;
+    # "periods": the hybrid family's, a stack a position of its period.
+    for group in ("layers", "lead"):
+        if group in params:
+            out[group] = stack(params[group])
+    if "periods" in params:
+        out["periods"] = [stack(lp) for lp in params["periods"]]
     return out

@@ -40,6 +40,25 @@ def _attention_params(cfg) -> int:
     return h * h + 2 * h * kv + h * h
 
 
+def _hybrid_layer_params(cfg):
+    """Matrix parameters of one layer of each kind of the hybrid family
+    (models/hybrid_ssm.py): (state-space, attention, an expert layer
+    without its routed experts, one routed expert)."""
+    h, d = cfg.hidden_size, cfg.head_dim
+    ssm = (h * (cfg.ssm_inner + cfg.ssm_conv_width + cfg.ssm_heads)
+           + cfg.ssm_inner * h)
+    attn = 2 * h * cfg.num_heads * d + 2 * h * cfg.num_kv_heads * d
+    fixed = h * cfg.num_experts + 2 * h * cfg.shared_ffn_size
+    return ssm, attn, fixed, 2 * h * cfg.moe_ffn_size
+
+
+def _hybrid_params(cfg, experts: float) -> float:
+    """All layers' matrices with ``experts`` routed experts a layer."""
+    ssm, attn, fixed, expert = _hybrid_layer_params(cfg)
+    return (cfg.layers_of("M") * ssm + cfg.layers_of("*") * attn
+            + cfg.layers_of("E") * (fixed + experts * expert))
+
+
 def _layer_counts(cfg):
     """(dense layers, expert layers)."""
     if cfg.num_experts <= 1:
@@ -54,6 +73,11 @@ def active_matmul_params(cfg) -> int:
     router — + the LM head.  Embedding lookup is a gather, not a
     matmul."""
     h = cfg.hidden_size
+    if cfg.hybrid:
+        # Of a token's ``experts_per_token`` choices, the share this
+        # program holds computes its fraction (uniform routing).
+        held = cfg.experts_per_token * cfg.experts_held / cfg.num_experts
+        return int(_hybrid_params(cfg, held)) + cfg.vocab_size * h
     dense, moe = _layer_counts(cfg)
     expert = 3 * h * (cfg.moe_ffn_size or cfg.ffn_size)
     routed = (cfg.experts_per_token + cfg.shared_experts) * expert
@@ -71,10 +95,14 @@ def weight_bytes(cfg, quantize: str = "none") -> int:
     family's grouped product reads only the experts a step's tokens chose
     (models/latent_moe.py), so for it this is an upper bound."""
     h = cfg.hidden_size
+    per_param = 1 if quantize == "int8" else 2
+    if cfg.hybrid:
+        # Every held expert (an upper bound, as for the latent family).
+        return (int(_hybrid_params(cfg, cfg.experts_held)) * per_param
+                + (2 * cfg.vocab_size * h + (cfg.num_layers + 1) * h) * 2)
     dense, moe = _layer_counts(cfg)
     expert = 3 * h * (cfg.moe_ffn_size or cfg.ffn_size)
     held = cfg.num_experts + cfg.shared_experts
-    per_param = 1 if quantize == "int8" else 2
     body = (cfg.num_layers * _attention_params(cfg)
             + dense * 3 * h * cfg.ffn_size + moe * held * expert) * per_param
     # Embedding/head + norms stay bf16 even under int8 weight-only quant.
@@ -88,10 +116,28 @@ def kv_bytes_per_pos(cfg, kv_quantize: str = "none") -> int:
     scales (engine/paged_kv.py); the latent family's one row a layer."""
     if cfg.latent:
         return cfg.num_layers * cfg.cache_row_width * 2
-    rows = 2 * cfg.num_layers * cfg.num_kv_heads
+    layers = cfg.layers_of("*") if cfg.hybrid else cfg.num_layers
+    rows = 2 * layers * cfg.num_kv_heads
     if kv_quantize == "int8":
         return rows * (cfg.head_dim + 4)
     return rows * cfg.head_dim * 2
+
+
+def _attention_width_layers(cfg):
+    """(width summed over query heads, layers) of the layers that attend
+    over positions: every layer, or the hybrid family's attention ones."""
+    if cfg.hybrid:
+        return cfg.num_heads * cfg.head_dim, cfg.layers_of("*")
+    return cfg.hidden_size, cfg.num_layers
+
+
+def state_row_bytes(cfg) -> int:
+    """What one sequence of the hybrid family keeps beside its K/V: the
+    float32 state and the conv tail of every state-space layer."""
+    return cfg.layers_of("M") * (
+        cfg.ssm_inner * cfg.ssm_state * 4
+        + (cfg.ssm_conv - 1) * cfg.ssm_conv_width
+        * (4 if cfg.dtype == "float32" else 2))
 
 
 def prefill_work(cfg, end: int, start: int = 0,
@@ -101,7 +147,7 @@ def prefill_work(cfg, end: int, start: int = 0,
     prompt length).  Causal attention: position p attends to p+1 keys."""
     pm = active_matmul_params(cfg)
     n = max(0, end - start)
-    h, l = cfg.hidden_size, cfg.num_layers
+    h, l = _attention_width_layers(cfg)
     flops = 2.0 * pm * n + 2.0 * h * l * float(end**2 - start**2)
     if wbytes is None:
         wbytes = weight_bytes(cfg)
@@ -129,7 +175,7 @@ def decode_work(cfg, steps: int, ctx: int, batch: int = 1,
     DISTINCT cache streams one step reads: a chunked verify of γ+1 queries
     reads its shared cache once, not γ+1 times (engine/speculative.py)."""
     pm = active_matmul_params(cfg)
-    h, l = cfg.hidden_size, cfg.num_layers
+    h, l = _attention_width_layers(cfg)
     span = float(ctx) if kv_ctx is None else min(float(kv_ctx), float(ctx))
     kvb = batch if kv_batch is None else kv_batch
     flops = float(steps) * batch * (2.0 * pm + 4.0 * h * l * span)
@@ -137,4 +183,7 @@ def decode_work(cfg, steps: int, ctx: int, batch: int = 1,
         wbytes = weight_bytes(cfg)
     hbm = float(steps) * (wbytes + kvb
                           * kv_bytes_per_pos(cfg, kv_quantize) * span)
+    if cfg.hybrid:
+        # The recurrent rows: read and written whole, every step.
+        hbm += float(steps) * batch * 2 * state_row_bytes(cfg)
     return {"flops": flops, "hbm_bytes": hbm, "tokens": steps * batch}

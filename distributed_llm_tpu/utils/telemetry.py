@@ -140,6 +140,11 @@ def engine_stats(engine) -> Dict[str, Any]:
     moe = moe_fn() if callable(moe_fn) else None
     if moe:
         entry["moe"] = moe
+    state_fn = getattr(engine, "state_stats", None)
+    state = state_fn() if callable(state_fn) else None
+    if state:
+        # The hybrid family's recurrent rows beside the K/V blocks.
+        entry["state"] = state
     form_fn = getattr(engine, "decode_attention_form", None)
     if callable(form_fn):
         entry["decode_attention"] = form_fn()
