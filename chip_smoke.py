@@ -32,7 +32,11 @@ from typing import (Any, Callable, Dict, List, NamedTuple, Optional, Sequence,
 # of the same bf16-valued inputs, bounded by the dtype: the kernel rounds
 # the softmax weights and the output to 8 mantissa bits (2^-8 = 3.9e-3
 # relative each).
-KERNEL_TOL = {"float32": 2e-5, ("float32", "chunk"): 2e-3, "bfloat16": 2e-2}
+KERNEL_TOL = {"float32": 2e-5, ("float32", "chunk"): 2e-3, "bfloat16": 2e-2,
+              # Two chained products over thousands of terms, the one
+              # between them rounded to the dtype; another summation order.
+              ("float32", "grouped_product"): 2e-4,
+              ("bfloat16", "grouped_product"): 5e-2}
 
 LONG_SENTENCE = ("Rivers carry sediment from the mountains to the delta, "
                  "where the channels split, slow down and drop their load. ")
@@ -602,16 +606,22 @@ def kernel_cases(nq: int, nkv: int, d: int, dtype, *, batch: int = 8,
                  block: int = 64, blocks_per_slot: int = 32,
                  prefill_len: int = 1024, decode_len: int = 2048,
                  chunk: int = 64, chunk_window: int = 2048,
-                 verify_q: int = 5) -> Dict[str, KernelCase]:
+                 verify_q: int = 5,
+                 grouped: Optional[Dict[str, tuple]] = None
+                 ) -> Dict[str, KernelCase]:
     """The main path's Pallas kernels at one head geometry, each with
     its XLA reference and a seeded argument builder.  chip_smoke runs
     them on the chip; tests/test_tpu_compile.py compiles the same
-    entries for a described chip from ``jax.eval_shape(make_args)``."""
+    entries for a described chip from ``jax.eval_shape(make_args)``.
+    ``grouped``: the routed experts' grouped product (no heads in it) as
+    {cell: (rows, groups a layer, in, out)}; by default the decode ticks
+    of the benchmark's two routed cells at the widths they store."""
     import jax
     import jax.numpy as jnp
     import numpy as np
 
     from distributed_llm_tpu.ops import attention as A
+    from distributed_llm_tpu.ops import grouped_product as GP
     from distributed_llm_tpu.ops import pallas_attention as PA
     from distributed_llm_tpu.ops import ragged_attention as RA
     from distributed_llm_tpu.ops.quant import quantize_kv_rows
@@ -643,6 +653,39 @@ def kernel_cases(nq: int, nkv: int, d: int, dtype, *, batch: int = 8,
         return (rand(keys[0], q_shape),
                 rand(keys[1], (q_shape[0], length, nkv, d)),
                 rand(keys[2], (q_shape[0], length, nkv, d)), q_pos)
+
+    def experts(rows, per_layer, k, n):
+        """Rows over a third of the MIDDLE layer's groups of a stack of
+        three, a third of the rows in no group; an up and a down
+        matrix a group."""
+        sizes = np.zeros(3 * per_layer, np.int32)
+        live = per_layer + np.arange(0, per_layer, 3)
+        np.add.at(sizes, np.random.default_rng(0).choice(
+            live, rows - rows // 3), 1)
+        return (rand(keys[0], (rows, k)),
+                rand(keys[1], (3 * per_layer, k, n)) * k ** -0.5,
+                rand(keys[2], (3 * per_layer, n, k)) * n ** -0.5,
+                jnp.asarray(sizes))
+
+    def up_and_down(product):
+        """Both products of an expert: [in, out] then [out, in]; only
+        rows in a group compare (``ragged_dot`` defines no others)."""
+        def run(x, up, down, sizes):
+            a = product(x, up, sizes)
+            y = product(jax.nn.relu(a).astype(x.dtype), down, sizes)
+            in_group = jnp.arange(x.shape[0]) < jnp.sum(sizes)
+            return jnp.where(in_group[:, None], y, 0)
+        return run
+
+    if grouped is None:
+        grouped = {"wide-reasoning": (96, 16, 2816, 2048),
+                   "reasoned-reply": (32, 16, 3584, 1024)}
+    grouped_cases = {
+        f"grouped_product.{cell}": KernelCase(
+            "grouped_product", up_and_down(GP.grouped_product),
+            up_and_down(jax.lax.ragged_dot),
+            lambda shape=shape: experts(*shape))
+        for cell, shape in grouped.items()}
 
     chunk_start = chunk_window - chunk - 5
     return {
@@ -678,6 +721,7 @@ def kernel_cases(nq: int, nkv: int, d: int, dtype, *, batch: int = 8,
             "ragged_verify", RA.ragged_paged_verify_attention,
             lambda *a: A.ragged_verify(*a, impl="xla"),
             lambda: pool((batch, verify_q, nq, d), last_q=verify_q)),
+        **grouped_cases,
     }
 
 

@@ -3668,7 +3668,24 @@ class ContinuousBatchingEngine:
     def moe_stats(self) -> Optional[Dict[str, Any]]:
         """Cumulative routed-expert load (GET /stats ``moe``), or None
         for a model without routed experts on this path."""
-        return self._moe.stats() if self._moe is not None else None
+        if self._moe is None:
+            return None
+        return {**self._moe.stats(),
+                "grouped_product": self.grouped_product_form()}
+
+    def grouped_product_form(self) -> Dict[str, str]:
+        """What the tick (``decode``) and the chunk program (``prefill``)
+        were traced with for the routed experts' grouped products:
+        ``pallas`` (ops/grouped_product.py) or ``ragged_dot`` — the
+        static test ``latent_moe._grouped`` makes, on these programs'
+        shapes; a fact of each compiled program, like
+        ``decode_attention_form``."""
+        stacks = models.model_module(self.cfg).expert_stacks(self.params)
+        return {stage: models.latent_moe.grouped_product_form(
+                    self.cfg, stacks, tokens)
+                for stage, tokens in (("decode", self.paged.max_slots),
+                                      ("prefill", self.chunk_tokens))
+                if tokens}
 
     def _chunk_result(self, out):
         """The sampled token of a chunk program's (already synced) first

@@ -72,18 +72,32 @@ def expert_dims_stored(cfg: ModelConfig):
     """(hidden, width) of the routed experts' matrices as STORED: each
     rounded up to a multiple of 256 with zero rows and columns (a zero
     input row adds nothing, ``relu(0)^2 = 0``, a zero output column is cut
-    off: the same numbers).  Two things hang on it at the published 2688 x
-    1856.  The grouped product's kernel tiles by divisors of its
-    dimensions: 41 GB/s of the touched experts' bytes at 2688 x 1856, 112
-    at 2688 x 1920, 305 at 2688 x 2048, 460 at 2816 x 2048, 581 at 3072 x
-    2048 (my chip runs, PR 33: 96 rows over 26 of 128 groups).  And at a
-    minor width that is not a multiple of the chip's 128 lanes the device
-    rests ``W_1`` [.., H, F] with H minor while the kernel wants F minor,
-    so every program copied every held expert at its entry (3 x 1.28 GB a
-    tick: compile for a described v5e, PR 33)."""
+    off: the same numbers).  Of the two reasons PR 33 had for it at the
+    published 2688 x 1856, one still holds.  At a minor width that is not
+    a multiple of the chip's 128 lanes the device rests ``W_1`` [.., H,
+    F] with H minor while a kernel wants F minor, so every program copied
+    every held expert at its entry (3 x 1.28 GB a tick: compile for a
+    described v5e, PR 33); and the chip's compiler cannot cut a matrix of
+    such a stack out by index, so ``ops.grouped_product.serves`` asks for
+    whole lane-widths of ``out`` and ``ragged_dot`` would take the rest.
+    Whole lane-widths are enough for that: 2688 x 1920 (21 and 15).  The
+    other reason went with the product becoming the repo's own kernel (PR
+    34): XLA's ``ragged_dot`` tiles by divisors of its dimensions (41 GB/s
+    of the touched experts' bytes at 2688 x 1856, 112 at 2688 x 1920, 305
+    at 2688 x 2048, 460 at 2816 x 2048, 581 at 3072 x 2048: my chip runs,
+    PR 33: 96 rows over 26 of 128 groups), ``ops/grouped_product.py``
+    reads whole matrices whatever their factors.  Taking the padding back
+    to 2688 x 1920 (8.86 -> 7.93 GB of experts) is the follow-up's."""
     def up(n):
         return -(-n // EXPERT_ALIGN) * EXPERT_ALIGN
     return up(cfg.hidden_size), up(cfg.moe_ffn_size)
+
+
+def expert_stacks(params: Params):
+    """The routed experts' arrays as the tree holds them: those of the
+    period's first ``E`` position (every position's are alike)."""
+    first = next(lp for lp in params["periods"] if EXPERT_KEYS[0] in lp)
+    return [first[key] for key in EXPERT_KEYS]
 
 
 def check(cfg: ModelConfig) -> None:

@@ -44,7 +44,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..config import ModelConfig
-from ..ops import quant
+from ..ops import grouped_product, quant
 from . import transformer
 
 Params = Dict[str, Any]
@@ -356,16 +356,50 @@ def route(cfg: ModelConfig, lp: Params, x: jax.Array):
     return choice.astype(jnp.int32), w
 
 
+def grouped_impl(rows: int, w) -> str:
+    """Which grouped product ``_grouped`` traces for ``rows`` rows against
+    ``w`` [G, in, out] (an array, its shape, or int8): ``pallas``, the
+    repo's kernel for few rows a group (``ops/grouped_product.py``), or
+    ``ragged_dot``, XLA's.  A test on static shapes and nothing else."""
+    if quant.is_quantized(w):
+        return "ragged_dot"
+    groups, k, n = w.shape
+    return ("pallas" if grouped_product.serves(rows, groups, k, n, w.dtype)
+            else "ragged_dot")
+
+
 def _grouped(x, w, sizes, group_of_row):
     """Rows of ``x`` sorted by group, times their group's matrix of
-    ``w`` [G, in, out] (plain or int8)."""
+    ``w`` [G, in, out] (plain or int8).  Rows past the groups' (the
+    hybrid family's absent-expert rows) are zeros from the kernel and
+    whatever ``ragged_dot`` leaves there: the caller masks them."""
+    if grouped_impl(x.shape[0], w) == "pallas":
+        return grouped_product.grouped_product(x, w, sizes)
     if not quant.is_quantized(w):
         return jax.lax.ragged_dot(x, w, sizes)
     y = jax.lax.ragged_dot(x, w["q"].astype(x.dtype), sizes)
     return y * w["s"][:, 0][group_of_row]
 
 
+def grouped_product_form(cfg: ModelConfig, stacks, tokens: int) -> str:
+    """What a program over ``tokens`` tokens traces its routed experts'
+    products with (GET /stats ``moe.grouped_product``): ``stacks`` the
+    experts' arrays as the tree holds them, each [layers, experts, in,
+    out] or int8 (then a layer at a time, XLA's)."""
+    rows = tokens * cfg.experts_per_token
+    return "+".join(sorted({
+        grouped_impl(rows, w if quant.is_quantized(w) else
+                     jax.ShapeDtypeStruct((w.shape[0] * w.shape[1],
+                                           *w.shape[2:]), w.dtype))
+        for w in stacks}))
+
+
 EXPERT_KEYS = ("we_gate", "we_up", "we_down")
+
+
+def expert_stacks(params: Params):
+    """The routed experts' arrays as the tree holds them."""
+    return [params["layers"][key] for key in EXPERT_KEYS]
 
 
 def routed_experts(cfg: ModelConfig, lp: Params, x: jax.Array,

@@ -1,0 +1,242 @@
+"""The routed experts' grouped product (ops/grouped_product.py), on the
+CPU in interpreter mode (``ops.pallas_attention._interpret``): against
+``jax.lax.ragged_dot`` and against a plain per-row ``x[i] @ w[g[i]]``,
+and the static test that chooses between kernel and ``ragged_dot`` inside
+``latent_moe._grouped``.  tests/test_tpu_compile.py compiles the kernel
+for a described v5e at the cells' published widths; chip_smoke.py runs it
+on the chip.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from distributed_llm_tpu.config import MODEL_PRESETS, TierConfig
+from distributed_llm_tpu.models import latent_moe
+from distributed_llm_tpu.ops import grouped_product as GP
+from distributed_llm_tpu.ops import quant
+
+
+def _sizes(rng, groups: int, first: int, span: int, rows_in: int,
+           touched: int) -> np.ndarray:
+    """``rows_in`` rows over ``touched`` of the groups ``first`` ..
+    ``first + span``, at least one each; every other group empty."""
+    sizes = np.zeros(groups, np.int32)
+    ids = first + rng.choice(span, touched, replace=False)
+    sizes[ids] = 1
+    for g in rng.choice(ids, rows_in - touched):
+        sizes[g] += 1
+    return sizes
+
+
+# name: (rows, layers, groups a layer, layer with the rows, rows in groups,
+#        groups touched, in, out, kernel options).  The tick shapes keep
+# the awkward factors of the published widths at a fraction of the size:
+# 2688 is 21 lane-widths, 384 and 896 are 3 and 7 (no multiple of 256),
+# 464 = 1856 / 4 is 3.625 lane-widths as an ``in``.
+CASES = {
+    "wide-reasoning-tick-up": (96, 2, 64, 1, 48, 26, 2688, 384, {}),
+    "wide-reasoning-tick-down": (96, 2, 64, 1, 48, 26, 384, 2688, {}),
+    "wide-reasoning-tick-in-464": (96, 2, 64, 0, 51, 30, 464, 640, {}),
+    "reasoned-reply-tick-up": (32, 5, 64, 2, 32, 23, 896, 256, {}),
+    "reasoned-reply-tick-down": (32, 5, 64, 2, 32, 23, 256, 896, {}),
+    "stacked-first-layer": (32, 5, 64, 0, 32, 20, 256, 128, {}),
+    "stacked-last-layer": (32, 5, 64, 4, 32, 20, 256, 128, {}),
+    "every-row-in-one-group": (96, 2, 64, 1, 96, 1, 256, 384, {}),
+    "every-row-in-the-last-group": (32, 1, 8, 0, 32, 1, 128, 128,
+                                    {"last": True}),
+    "no-row-in-any-group": (32, 2, 8, 0, 0, 0, 128, 128, {}),
+    "trailing-rows-in-no-group": (96, 2, 64, 0, 7, 5, 256, 256, {}),
+    "one-row-in-all": (1, 2, 8, 1, 1, 1, 256, 128, {}),
+    "rows-not-a-sublane-tile": (23, 1, 16, 0, 23, 9, 128, 256, {}),
+    # Past ROW_BLOCK rows a group's rows are blocks that start at any
+    # sublane tile: groups of many blocks, of one, and a matrix read in
+    # several DMAs and multiplied in several pieces.
+    "chunk-many-rows-a-group": (300, 2, 16, 1, 280, 6, 256, 2304,
+                                {"tile_bytes": 64 << 10}),
+    "chunk-few-rows-a-group": (1536, 2, 64, 1, 768, 56, 128, 256, {}),
+    "float32-rows": (32, 2, 8, 1, 30, 6, 128, 128, {"dtype": jnp.float32}),
+}
+
+
+@pytest.mark.parametrize("case", list(CASES))
+def test_kernel_is_the_grouped_product(case):
+    (rows, layers, per, layer, rows_in, touched, k, n, opts) = CASES[case]
+    opts = dict(opts)
+    dtype = opts.pop("dtype", jnp.bfloat16)
+    groups = layers * per
+    rng = np.random.default_rng(len(case))
+    if opts.pop("last", False):
+        sizes = np.zeros(groups, np.int32)
+        sizes[-1] = rows_in
+    else:
+        sizes = _sizes(rng, groups, layer * per, per, rows_in, touched)
+    assert sizes.sum() == rows_in and np.count_nonzero(sizes) == touched
+    x = jnp.asarray(rng.standard_normal((rows, k)), dtype)
+    w = jnp.asarray(rng.standard_normal((groups, k, n)) * k ** -0.5, dtype)
+    assert GP.serves(rows, groups, k, n, dtype)
+
+    got = GP.grouped_product(x, w, jnp.asarray(sizes), **opts)
+    assert got.shape == (rows, n) and got.dtype == dtype
+    got = np.asarray(got, np.float32)
+
+    group_of_row = np.repeat(np.arange(groups), sizes)
+    xf, wf = np.asarray(x, np.float32), np.asarray(w, np.float32)
+    plain = np.zeros((rows, n), np.float32)
+    for i, g in enumerate(group_of_row):
+        plain[i] = xf[i] @ wf[g]
+    ragged = np.asarray(jax.lax.ragged_dot(x, w, jnp.asarray(sizes)),
+                        np.float32)
+    # Dropless: every row of a non-empty group, to the rounding of the
+    # result's dtype (float32 accumulation in both).
+    tol = 2e-2 if dtype == jnp.bfloat16 else 1e-5
+    np.testing.assert_allclose(got[:rows_in], plain[:rows_in], atol=tol,
+                               rtol=tol)
+    np.testing.assert_allclose(got[:rows_in], ragged[:rows_in], atol=tol,
+                               rtol=tol)
+    # Rows that belong to no group: zeros, a defined value.
+    assert not got[rows_in:].any()
+
+
+def test_the_tiles_follow_the_matrix():
+    """DMAs of whole sublane tiles of rows, a few MB each at most, the
+    last one ending with the matrix; nothing of 2816 x 2048 baked in."""
+    for k, n in ((2816, 2048), (2048, 2816), (3584, 1024), (1024, 3584),
+                 (2688, 1920), (1856, 2688), (48, 128)):
+        tiles = GP.dma_tiles(k, n, 2)
+        assert tiles[0][0] == 0 and sum(h for _, h in tiles) == k
+        assert all(a + h == b for (a, h), (b, _) in zip(tiles, tiles[1:]))
+        assert all(h % GP.ROW_ALIGN == 0 and h * n * 2 <= GP.TILE_BYTES
+                   for _, h in tiles)
+    assert GP._split(1920, 1024, 128) == [(0, 1024), (1024, 896)]
+    with pytest.raises(ValueError, match="whole tiles"):
+        GP.grouped_product(jnp.zeros((8, 200), jnp.bfloat16),
+                           jnp.zeros((4, 200, 128), jnp.bfloat16),
+                           jnp.zeros(4, jnp.int32))
+
+
+W = jax.ShapeDtypeStruct
+
+
+# What ``_grouped`` traces, by shapes alone: (rows, w, implementation).
+BRANCHES = {
+    "wide-reasoning-tick": (96, W((128, 2816, 2048), jnp.bfloat16),
+                            "pallas"),
+    "wide-reasoning-tick-down": (96, W((128, 2048, 2816), jnp.bfloat16),
+                                 "pallas"),
+    "reasoned-reply-tick": (32, W((320, 3584, 1024), jnp.bfloat16),
+                            "pallas"),
+    "reasoned-reply-tick-down": (32, W((320, 1024, 3584), jnp.bfloat16),
+                                 "pallas"),
+    "unpadded-15-lane-widths": (96, W((128, 2688, 1920), jnp.bfloat16),
+                                "pallas"),
+    # A minor width off the lanes: the chip's compiler cannot cut such a
+    # matrix out of the stack by index (and the device rests it
+    # transposed: models/hybrid_ssm.py ``expert_dims_stored``).
+    "out-not-whole-lanes": (96, W((128, 2688, 1856), jnp.bfloat16),
+                            "ragged_dot"),
+    "in-not-whole-sublane-tiles": (8, W((8, 200, 128), jnp.bfloat16),
+                                   "ragged_dot"),
+    "many-rows-a-group": (GP.MAX_ROWS_A_GROUP * 8 + 1,
+                          W((8, 128, 128), jnp.bfloat16), "ragged_dot"),
+    "more-than-vmem-holds": (8192, W((320, 3584, 1024), jnp.bfloat16),
+                             "ragged_dot"),
+    "matrices-larger-than-the-buffer": (8, W((8, 8192, 4096), jnp.bfloat16),
+                                        "ragged_dot"),
+    "int8-weights": (32, "int8", "ragged_dot"),
+    "int-rows": (32, W((8, 128, 128), jnp.int8), "ragged_dot"),
+}
+
+
+@pytest.mark.parametrize("case", list(BRANCHES))
+def test_grouped_chooses_by_static_shapes(case, monkeypatch):
+    rows, w, impl = BRANCHES[case]
+    if isinstance(w, str):
+        w = quant.quantize_tensor(jnp.asarray(
+            np.random.default_rng(0).standard_normal((8, 128, 128)),
+            jnp.bfloat16))
+        assert quant.is_quantized(w)
+    assert latent_moe.grouped_impl(rows, w) == impl
+    if isinstance(w, jax.ShapeDtypeStruct) and w.shape[0] * w.shape[1] \
+            * w.shape[2] > 1 << 22:
+        return                      # the choice alone: too large to run here
+
+    # And ``_grouped`` goes where ``grouped_impl`` says.
+    called = []
+    real = GP.grouped_product
+    monkeypatch.setattr(
+        GP, "grouped_product",
+        lambda *a, **kw: called.append("pallas") or real(*a, **kw))
+    if isinstance(w, jax.ShapeDtypeStruct):
+        if not jnp.issubdtype(w.dtype, jnp.floating):
+            return                  # ragged_dot has no int8 rows either
+        rng = np.random.default_rng(0)
+        w = jnp.asarray(rng.standard_normal(w.shape), w.dtype)
+        groups, k, n = w.shape
+        dense = np.asarray(w, np.float32)
+    else:
+        groups, k, n = w["q"].shape
+        dense = np.asarray(quant.dequantize(w), np.float32)
+    sizes = np.zeros(groups, np.int32)
+    sizes[[1, groups - 1]] = (rows // 2, rows - rows // 2)
+    group_of_row = jnp.asarray(np.repeat(np.arange(groups), sizes))
+    x = jnp.asarray(np.random.default_rng(1).standard_normal((rows, k)),
+                    jnp.bfloat16)
+    got = latent_moe._grouped(x, w, jnp.asarray(sizes), group_of_row)
+    assert called == (["pallas"] if impl == "pallas" else [])
+    want = np.einsum("rk,rkn->rn", np.asarray(x, np.float32),
+                     dense[np.asarray(group_of_row)])
+    np.testing.assert_allclose(np.asarray(got, np.float32), want,
+                               atol=0.1, rtol=5e-2)
+
+
+def test_latent_experts_agree_through_either_product(monkeypatch):
+    """The latent family's routed experts at widths of whole lanes run
+    the kernel; the same call with the static test turned down runs
+    ``ragged_dot``: the same experts chosen, the same sum to bfloat16's
+    rounding.  (``latent_test`` itself is 64 x 32: ``ragged_dot``.)"""
+    cfg = dataclasses.replace(MODEL_PRESETS["latent_test"], hidden_size=128,
+                              moe_ffn_size=128)
+    params = latent_moe.init_params(cfg, seed=3)
+    layers = params["layers"]
+    stacked = {key: layers[key] for key in latent_moe.EXPERT_KEYS}
+    lp = jax.tree.map(lambda a: a[1], layers)
+    x = jnp.asarray(np.random.default_rng(2).standard_normal(
+        (8, cfg.hidden_size)), jnp.float32)
+    assert latent_moe.grouped_product_form(
+        cfg, latent_moe.expert_stacks(params), 8) == "pallas"
+    out_k, counts_k = latent_moe.routed_experts(cfg, lp, x, stacked, 1)
+    monkeypatch.setattr(GP, "serves", lambda *a: False)
+    assert latent_moe.grouped_product_form(
+        cfg, latent_moe.expert_stacks(params), 8) == "ragged_dot"
+    out_r, counts_r = latent_moe.routed_experts(cfg, lp, x, stacked, 1)
+    assert np.array_equal(np.asarray(counts_k), np.asarray(counts_r))
+    np.testing.assert_allclose(np.asarray(out_k, np.float32),
+                               np.asarray(out_r, np.float32), atol=2e-2,
+                               rtol=2e-2)
+
+
+@pytest.mark.parametrize("preset,form", [
+    # Stored 256 x 256 (``expert_dims_stored``): 12 and 48 rows over 8
+    # stacked groups.
+    ("hybrid_test", {"decode": "pallas", "prefill": "pallas"}),
+    # 64 x 32: widths off the lanes.
+    ("latent_test", {"decode": "ragged_dot", "prefill": "ragged_dot"}),
+])
+def test_stats_name_the_implementation_a_program_was_traced_with(preset,
+                                                                 form):
+    from distributed_llm_tpu.engine.batching import ContinuousBatchingEngine
+    tier = TierConfig(name="nano", model_preset=preset, decode_batch=4,
+                      kv_block_size=16, prefill_buckets=(16, 32, 64, 128),
+                      prefill_chunk_tokens=16, enable_prefix_cache=False)
+    engine = ContinuousBatchingEngine(tier)
+    try:
+        assert engine.grouped_product_form() == form
+        assert engine.moe_stats()["grouped_product"] == form
+    finally:
+        engine.stop()

@@ -40,7 +40,12 @@ KERNELS = ["flash_causal_attention", "flash_decode_attention",
            "flash_chunk_attention", "paged_decode_attention",
            "ragged_paged_decode_attention",
            "ragged_paged_decode_attention_q8",
-           "ragged_paged_verify_attention"]
+           "ragged_paged_verify_attention",
+           # The routed experts' grouped product (no heads in it: the
+           # same case under every preset): an up and a down product at
+           # the decode ticks' rows and the widths the cells store.
+           "grouped_product.wide-reasoning",
+           "grouped_product.reasoned-reply"]
 
 
 @pytest.fixture(scope="module")
@@ -187,10 +192,11 @@ def test_ragged_pallas_nano_tick_compiles_for_v5e(one_chip, as_on_tpu,
 
 # -- the KV pool stays in place (ISSUE 27) -------------------------------------
 
-def _bench_smollm2_tier(monkeypatch):
-    """The benchmark's own SmolLM2-1.7B tier, read from its configuration
-    file the way benchmark/cluster.py builds it: 24 layers, 32/32 heads,
-    head_dim 64, 144 + 1 blocks of 64 tokens, 8 slots, 4 steps a tick."""
+def _bench_tier(monkeypatch, config: str = "smollm2-1.7b"):
+    """A benchmark configuration's own nano tier, read from its file the
+    way benchmark/cluster.py builds it (SmolLM2-1.7B: 24 layers, 32/32
+    heads, head_dim 64, 144 + 1 blocks of 64 tokens, 8 slots, 4 steps a
+    tick)."""
     import importlib.util
     import json
     from distributed_llm_tpu.config import TierConfig
@@ -199,10 +205,10 @@ def _bench_smollm2_tier(monkeypatch):
         "_benchmark_cluster", os.path.join(bench, "cluster.py"))
     cluster = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(cluster)
-    with open(os.path.join(bench, "configs", "smollm2-1.7b.json")) as f:
+    with open(os.path.join(bench, "configs", config + ".json")) as f:
         entry = cluster.tier_entries(json.load(f), False)["nano"]
-    monkeypatch.setitem(MODEL_PRESETS, entry["preset"], cluster.model_config(
-        entry["preset"], entry["model"]))
+    monkeypatch.setitem(MODEL_PRESETS, entry["preset"],
+                        cluster.program_config(entry))
     kw = dict(entry["tier"], prefill_buckets=tuple(
         entry["tier"]["prefill_buckets"]))
     return TierConfig(name="nano", model_preset=entry["preset"], **kw)
@@ -229,15 +235,15 @@ GB = 1e9
 # GB).  No cell runs a hooked tier: these two cases are all that holds it.
 POOL_PROGRAMS = {
     "smollm2-decode-256":
-        (_bench_smollm2_tier, ("decode", 256), 0.41, False),
+        (_bench_tier, ("decode", 256), 0.41, False),
     "smollm2-decode-2048":
-        (_bench_smollm2_tier, ("decode", 2048), 0.41, False),
+        (_bench_tier, ("decode", 2048), 0.41, False),
     "smollm2-chunk-256-256":
-        (_bench_smollm2_tier, ("chunk", 256, 256), 1.0, False),
+        (_bench_tier, ("chunk", 256, 256), 1.0, False),
     "smollm2-chunk-256-1024":
-        (_bench_smollm2_tier, ("chunk", 256, 1024), 1.0, False),
+        (_bench_tier, ("chunk", 256, 1024), 1.0, False),
     "smollm2-copy_block":
-        (_bench_smollm2_tier, ("cow",), 1.0, False),
+        (_bench_tier, ("cow",), 1.0, False),
     "nano_1b-gqa-decode-256":
         (lambda _: _flagship_nano("nano_1b"), ("decode", 256), 0.14, False),
     "orin_bench-d128-decode-256":
@@ -316,3 +322,37 @@ def test_pool_program_leaves_the_pool_in_place(one_chip, as_on_tpu,
         window = (engine.paged.max_slots * program[1]
                   * cfg.num_kv_heads * cfg.head_dim)
         assert window_passes(compiled.as_text(), window) == []
+
+
+# -- the routed experts stay where they rest (ISSUE 34) ------------------------
+
+# configuration: (grouped products in the tick's one layer body, the
+# stacked experts of one key in GB, temporaries allowed in GB).  PR 33's
+# trap: at a minor width off the lanes the entry of every program copied
+# every held expert ([2, 64, 2688, 1856] x 3: 4.36 GB of temporaries).
+ROUTED_TICKS = {
+    "xing4.0-29b-a4b": (3, 2.35, 0.5),
+    "nemotron-3-nano-30b-a3b": (6, 1.48, 0.5),
+}
+
+
+@pytest.mark.parametrize("config", list(ROUTED_TICKS))
+def test_routed_tick_reads_the_experts_where_they_rest(one_chip, as_on_tpu,
+                                                       monkeypatch, config):
+    """The decode ticks of the benchmark's two routed-expert
+    configurations, at their real sizes: every grouped product of the
+    layer body is the repo's kernel, handed the STACKED experts whole —
+    no copy of them at the program's entry, no per-layer slice: the
+    temporaries stay far under one key's stack."""
+    products, stack_gb, temp_limit_gb = ROUTED_TICKS[config]
+    tier = _bench_tier(monkeypatch, config)
+    engine, _, compiled, _ = _pool_program(one_chip, tier, ("decode", 256))
+    assert engine.grouped_product_form()["decode"] == "pallas"
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == products
+    assert "grouped_product" in text and "ragged-dot" not in text
+    temp_gb = compiled.memory_analysis().temp_size_in_bytes / GB
+    assert temp_gb < temp_limit_gb < stack_gb, temp_gb
+    # The kernel is one operation of the layer body: the tick keeps the
+    # two nested loops the benchmark files it by (steps, layers).
+    assert text.count(" while(") == 2
