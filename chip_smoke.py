@@ -607,7 +607,8 @@ def kernel_cases(nq: int, nkv: int, d: int, dtype, *, batch: int = 8,
                  prefill_len: int = 1024, decode_len: int = 2048,
                  chunk: int = 64, chunk_window: int = 2048,
                  verify_q: int = 5,
-                 grouped: Optional[Dict[str, tuple]] = None
+                 grouped: Optional[Dict[str, tuple]] = None,
+                 scan: tuple = (256, 16, 5120)
                  ) -> Dict[str, KernelCase]:
     """The main path's Pallas kernels at one head geometry, each with
     its XLA reference and a seeded argument builder.  chip_smoke runs
@@ -615,7 +616,10 @@ def kernel_cases(nq: int, nkv: int, d: int, dtype, *, batch: int = 8,
     entries for a described chip from ``jax.eval_shape(make_args)``.
     ``grouped``: the routed experts' grouped product (no heads in it) as
     {cell: (rows, groups a layer, in, out)}; by default the decode ticks
-    of the benchmark's two routed cells at the widths they store."""
+    of the benchmark's two routed cells at the widths they store.
+    ``scan``: Mamba-1's recurrence over a chunk (no heads in it either,
+    float32 whatever ``dtype``) as (positions, state, inner); by default
+    a chunk of the shared-K/V family's benchmark cell."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -624,6 +628,7 @@ def kernel_cases(nq: int, nkv: int, d: int, dtype, *, batch: int = 8,
     from distributed_llm_tpu.ops import grouped_product as GP
     from distributed_llm_tpu.ops import pallas_attention as PA
     from distributed_llm_tpu.ops import ragged_attention as RA
+    from distributed_llm_tpu.ops import ssm_chunk_scan as SC
     from distributed_llm_tpu.ops.quant import quantize_kv_rows
 
     span = block * blocks_per_slot
@@ -677,6 +682,30 @@ def kernel_cases(nq: int, nkv: int, d: int, dtype, *, batch: int = 8,
             return jnp.where(in_group[:, None], y, 0)
         return run
 
+    def scan_args():
+        """Time steps in the published range, the last eighth padding
+        (0), decays A = -1..-state a channel."""
+        t, n, c = scan
+        f32 = jnp.float32
+        dt = jnp.exp(jax.random.uniform(keys[0], (t, c), f32, -6.9, -2.3))
+        dt = dt.at[t - t // 8:].set(0.0)
+        return (dt, jax.random.normal(keys[1], (t, c), f32),
+                jax.random.normal(keys[2], (t, n), f32),
+                jax.random.normal(keys[3], (t, n), f32),
+                -jnp.broadcast_to(jnp.arange(1, n + 1, dtype=f32)[:, None],
+                                  (n, c)),
+                jax.random.normal(jax.random.fold_in(keys[0], 1), (n, c),
+                                  f32))
+
+    def scan_reference(dt, u, b, c, a, state):
+        def step(state, x):
+            dt_t, u_t, b_t, c_t = x
+            state = (jnp.exp(dt_t[None, :] * a) * state
+                     + (dt_t * u_t)[None, :] * b_t[:, None])
+            return state, jnp.sum(state * c_t[:, None], axis=0)
+        state, y = jax.lax.scan(step, state, (dt, u, b, c))
+        return jnp.concatenate([y, state])
+
     if grouped is None:
         grouped = {"wide-reasoning": (96, 16, 2816, 2048),
                    "reasoned-reply": (32, 16, 3584, 1024)}
@@ -722,6 +751,10 @@ def kernel_cases(nq: int, nkv: int, d: int, dtype, *, batch: int = 8,
             lambda *a: A.ragged_verify(*a, impl="xla"),
             lambda: pool((batch, verify_q, nq, d), last_q=verify_q)),
         **grouped_cases,
+        "ssm_chunk_scan": KernelCase(
+            "ssm_scan",
+            lambda *a: jnp.concatenate(SC.ssm_chunk_scan(*a)),
+            scan_reference, scan_args),
     }
 
 

@@ -229,31 +229,97 @@ class ModelConfig:
     experts_count: int = 0
     expert_act: str = "swiglu"
     shared_ffn_size: int = 0
+    # -- The state-space / window-attention / shared-K/V family
+    # (models/shared_kv_hybrid.py; an "F" in ``layer_pattern`` selects
+    # it).  Its kinds: "M" a MAMBA-1 mixer (``ssm_dt_rank`` > 0: decay a
+    # channel AND state, ``ssm_heads`` channels of ``ssm_head_dim`` 1),
+    # "W" attention over the last ``attn_window`` positions (a ring a
+    # slot), "F" full attention whose K/V are the only ones the paged
+    # pool holds, "X" attention with a query projection only, over "F"'s
+    # K/V, "G" a gated memory unit over the last "M" layer's scan
+    # output.  EACH layer is a mixer and then a gated MLP of
+    # ``ffn_size``; the family's norm is LayerNorm with gain and bias and
+    # its attention heads pair differentially (no option: the pattern
+    # selects both).
+    attn_window: int = 0
+    ssm_dt_rank: int = 0
 
     @property
     def head_dim(self) -> int:
         return self.attn_head_dim or self.hidden_size // self.num_heads
 
     @property
+    def family(self) -> str:
+        """The model family, by what selects it: "latent"
+        (models/latent_moe.py: a latent cache row), "shared_kv"
+        (models/shared_kv_hybrid.py: an "F" in ``layer_pattern``),
+        "hybrid" (models/hybrid_ssm.py: any other ``layer_pattern``), else
+        "dense" (transformer.py, moe.py).  What differs by family
+        dispatches on this one name."""
+        if self.kv_lora_rank > 0:
+            return "latent"
+        if "F" in self.layer_pattern:
+            return "shared_kv"
+        return "hybrid" if self.layer_pattern else "dense"
+
+    @property
     def latent(self) -> bool:
-        """The family of models/latent_moe.py."""
-        return self.kv_lora_rank > 0
+        return self.family == "latent"
+
+    @property
+    def shared_kv(self) -> bool:
+        return self.family == "shared_kv"
 
     @property
     def hybrid(self) -> bool:
-        """The family of models/hybrid_ssm.py."""
+        """A family whose sequences keep a recurrent ROW a slot beside
+        their paged K/V ("hybrid" and "shared_kv"): what the engine does
+        for rows it does for both."""
         return bool(self.layer_pattern)
 
     @property
+    def layer_segments(self) -> Tuple[Tuple[str, int], ...]:
+        """``layer_pattern`` as maximal periodic segments, (period,
+        repeats) each, greedily from the left: at each point the period
+        that covers most layers by repeating at least twice (the shortest
+        such on a tie), else one layer alone.  "MEMEM*EMEMEM*E" is one
+        segment, (("MEMEM*E", 2),); a pattern whose kinds change along
+        the depth is several — the layer loop scans each."""
+        p, out, i = self.layer_pattern, [], 0
+        while i < len(p):
+            best = (p[i], 1)
+            for n in range(1, (len(p) - i) // 2 + 1):
+                r = 1
+                while p[i + r * n:i + (r + 1) * n] == p[i:i + n]:
+                    r += 1
+                if r > 1 and n * r > len(best[0]) * best[1]:
+                    best = (p[i:i + n], r)
+            out.append(best)
+            i += len(best[0]) * best[1]
+        return tuple(out)
+
+    @property
     def layer_period(self) -> str:
-        """The shortest string whose repetition is ``layer_pattern``."""
-        p = self.layer_pattern
-        return next(p[:n] for n in range(1, len(p) + 1)
-                    if len(p) % n == 0 and p[:n] * (len(p) // n) == p)
+        """What models/hybrid_ssm.py scans, its whole depth ONE loop: the
+        period of a pattern that is one segment, else (nothing repeats
+        all the way down) the pattern itself — the shortest string whose
+        repetition is ``layer_pattern``."""
+        segments = self.layer_segments
+        return (segments[0][0] if len(segments) == 1
+                else self.layer_pattern)
 
     def layers_of(self, kind: str) -> int:
-        """Layers of one kind ("M", "*", "E") in ``layer_pattern``."""
+        """Layers of one kind ("M", "*", "E", ...) in ``layer_pattern``."""
         return self.layer_pattern.count(kind)
+
+    @property
+    def kv_layers(self) -> int:
+        """Layers whose K/V the paged pool holds by position: every layer,
+        or a hybrid family's attention layers ("*"; the shared-K/V
+        family's ONE "F")."""
+        if self.hybrid:
+            return self.layers_of("*") + self.layers_of("F")
+        return self.num_layers
 
     @property
     def experts_held(self) -> int:
@@ -265,7 +331,10 @@ class ModelConfig:
 
     @property
     def ssm_conv_width(self) -> int:
-        """Channels the conv runs over: x, B and C side by side."""
+        """Channels the conv runs over: x, B and C side by side
+        (Mamba-1, ``ssm_dt_rank`` > 0: x alone)."""
+        if self.ssm_dt_rank:
+            return self.ssm_inner
         return self.ssm_inner + 2 * self.ssm_groups * self.ssm_state
 
     @property
@@ -357,6 +426,16 @@ MODEL_PRESETS: Dict[str, ModelConfig] = {
         num_experts=8, experts_first=0, experts_count=4, moe_ffn_size=32,
         shared_ffn_size=48, experts_per_token=3, router_scale=2.5,
         expert_act="relu2",
+    ),
+    # The state-space / window-attention / shared-K/V family at unit-test
+    # size (models/shared_kv_hybrid.py): 3 x "MW", "M", "F", 2 x "GX";
+    # a window (and ring) of 16 positions.
+    "shared_kv_test": ModelConfig(
+        name="shared_kv_test", tokenizer="byte", vocab_size=512,
+        hidden_size=64, num_layers=12, num_heads=8, num_kv_heads=4,
+        ffn_size=96, max_seq_len=256, rotary=False,
+        layer_pattern="MWMWMWMFGXGX", ssm_heads=128, ssm_head_dim=1,
+        ssm_state=8, ssm_conv=4, ssm_dt_rank=4, attn_window=16,
     ),
     "orin_test": ModelConfig(
         name="orin_test", hidden_size=128, num_layers=2, num_heads=8,

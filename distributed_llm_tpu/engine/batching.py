@@ -519,6 +519,10 @@ class ContinuousBatchingEngine:
         # (``_ride_chunk``): how often the overlap engages
         # (prefill_stats, dllm_prefill_chunks_total).
         self.prefill_chunks_total = 0
+        # Of those, the shared-K/V family's chunks that did not hold
+        # their prompt's last token: run to the one cached layer's K/V
+        # write and no deeper (models/shared_kv_hybrid.py).
+        self.prefill_self_only_chunks_total = 0
         self.prefill_chunks_overlapped_total = 0
         # perf_counter of the last plain tick's fetch return: the
         # latest moment the host saw the device reach whatever was
@@ -725,23 +729,29 @@ class ContinuousBatchingEngine:
     # its K/V blocks: whatever rewinds or shares BY POSITION (a prefix
     # hit, a shared prefix, a spilled block, a draft's rejected tail)
     # would part a sequence from its state, and its state has no shards.
+    # The shared-K/V family's rings are rows of the same kind (and a
+    # chunk longer than a ring would write a slot twice).  Keyed by
+    # ``ModelConfig.family``.
     _FAMILY_REFUSALS = {
         "latent": ("latent-attention",
                    ("kv_quantize", "tp", "draft_preset", "host_kv_bytes")),
+        "shared_kv": ("shared-K/V hybrid",
+                      ("kv_quantize", "tp", "draft_preset", "host_kv_bytes",
+                       "enable_prefix_cache", "prefill_chunk_tokens")),
         "hybrid": ("state-space hybrid",
                    ("kv_quantize", "tp", "draft_preset", "host_kv_bytes",
                     "enable_prefix_cache", "prefill_chunk_tokens")),
     }
 
     def _refuse_unsupported(self, tier: TierConfig, mesh) -> None:
-        family = next((f for f in self._FAMILY_REFUSALS
-                       if getattr(self.cfg, f)), None)
-        if family is None:
+        family = self.cfg.family
+        if family not in self._FAMILY_REFUSALS:
             return
         from ..config_registry import env_int
         span = -(-self.cfg.max_seq_len // tier.kv_block_size) \
             * tier.kv_block_size
         chunk = int(tier.prefill_chunk_tokens or 0)
+        ring = self.cfg.attn_window     # a window layer's ring a slot, or 0
         on = {
             "kv_quantize": ("kv_quantize='int8'",
                             tier.kv_quantize != "none"),
@@ -759,8 +769,10 @@ class ContinuousBatchingEngine:
             # its overlap to the state twice.
             "prefill_chunk_tokens": (
                 f"prefill_chunk_tokens={chunk} (it needs a chunk that "
-                f"divides the slot's span of {span})",
-                chunk <= 0 or span % chunk != 0),
+                f"divides the slot's span of {span}"
+                + (f" and fits the window's ring of {ring}" if ring else "")
+                + ")",
+                chunk <= 0 or span % chunk != 0 or 0 < ring < chunk),
         }
         name, keys = self._FAMILY_REFUSALS[family]
         bad = [on[key][0] for key in keys if on[key][1]]
@@ -1019,6 +1031,8 @@ class ContinuousBatchingEngine:
         attention."""
         if self.cfg.latent:
             return "latent"
+        if self.cfg.shared_kv:
+            return "merged"        # its own (shared_kv_hybrid.diff_merged)
         from ..ops import attention as attn_ops
         kind = (("ragged_decode" if self.ragged else "paged_decode")
                 + ("_q8" if self.tier.kv_quantize == "int8" else ""))
@@ -1422,10 +1436,12 @@ class ContinuousBatchingEngine:
         self._kv_weights.clear()
 
     def _sync_state_owner(self) -> None:
-        """The hybrid family: before a program that reads the recurrent
-        rows, make ``pool["owner"]`` say row = slot — a live slot's first
-        block, the in-flight prefill's, 0 for a free slot — so a finished
-        or preempted sequence's row is free the moment its blocks are,
+        """The hybrid families: before a program that reads the recurrent
+        rows (the shared-K/V family's window rings are rows too: a ring
+        is its slot's like a state), make ``pool["owner"]`` say row =
+        slot — a live slot's first block, the in-flight prefill's, 0 for
+        a free slot — so a finished or preempted sequence's row is free
+        the moment its blocks are,
         and a sequence admitted into the slot later claims (and zeroes)
         the same row.  A [slots] int32 upload, and only when it changed."""
         if self._state_owner is None:
@@ -1450,11 +1466,18 @@ class ContinuousBatchingEngine:
         one holds, and how many sequences started one from zero."""
         if self._state_owner is None:
             return None
-        from ..utils.roofline import state_row_bytes
-        return {"rows": int(self._state_owner.size),
-                "rows_in_use": int(np.count_nonzero(self._rows_owned())),
-                "row_bytes": int(state_row_bytes(self.cfg)),
-                "resets_total": int(self.state_resets_total)}
+        from ..utils.roofline import ring_row_bytes, state_row_bytes
+        out = {"rows": int(self._state_owner.size),
+               "rows_in_use": int(np.count_nonzero(self._rows_owned())),
+               "row_bytes": int(state_row_bytes(self.cfg)),
+               "resets_total": int(self.state_resets_total)}
+        if self.cfg.shared_kv:
+            # The window layers' rings: a row a slot like the state, of
+            # the window's positions whatever the sequence's length.
+            out.update(ring_layers=self.cfg.layers_of("W"),
+                       ring_positions=self.cfg.attn_window,
+                       ring_bytes=int(ring_row_bytes(self.cfg)))
+        return out
 
     def _alloc_evicting(self, n_blocks: int) -> Optional[List[int]]:
         """Allocate, evicting parked prefix entries (LRU) under pressure:
@@ -2185,13 +2208,18 @@ class ContinuousBatchingEngine:
             self.cfg, end, start, wbytes=self._wbytes))
         self.prefill_chunks_total += 1
         self.prefill_chunks_overlapped_total += int(overlapped)
+        self_only = self.cfg.shared_kv and end < pf.total
+        self.prefill_self_only_chunks_total += int(self_only)
         try:
             # No injection path on the engine (same pattern as the tick
             # histogram): the process-global registry.
             from ..obs import get_observability
-            get_observability().m.prefill_chunks.labels(
+            m = get_observability().m
+            m.prefill_chunks.labels(
                 self.tier.name,
                 "behind_tick" if overlapped else "alone").inc()
+            if self_only:
+                m.prefill_self_only_chunks.labels(self.tier.name).inc()
         except Exception:
             pass
         pf.consumed = min(end, pf.total)
@@ -3575,6 +3603,8 @@ class ContinuousBatchingEngine:
                 "demote_inflight": ss["copying_entries"],
                 "promote_backlog_blocks": backlog,
             }
+        from ..utils.roofline import kv_readers
+        cached = self.cfg.kv_layers
         return {
             **spill_fields,
             "free_blocks": self.allocator.available,
@@ -3589,6 +3619,10 @@ class ContinuousBatchingEngine:
                                   / rs["allocated_blocks"], 4)
                             if rs["allocated_blocks"] else 1.0),
             "pinned_entries": pinned,
+            # Layers whose K/V the blocks hold, and layers that read them
+            # (the shared-K/V family caches ONE layer for all after it).
+            "cached_layers": cached,
+            "cache_readers": cached * kv_readers(self.cfg),
         }
 
     def max_demand_blocks(self) -> int:
@@ -3771,6 +3805,9 @@ class ContinuousBatchingEngine:
                "chunks_overlapped_total": overlapped,
                "overlap_share": (round(overlapped / chunks, 4)
                                  if chunks else None)}
+        if self.cfg.shared_kv:
+            out["chunks_self_only_total"] = \
+                self.prefill_self_only_chunks_total
         if pf is not None:
             out.update(inflight=1, chunks_done=pf.chunks_done,
                        backlog_tokens=max(0, pf.total - min(pf.consumed,

@@ -51,7 +51,11 @@ work on whatever arrays the pool has.  The hybrid family's
 only and, beside them, one recurrent row a slot a state-space layer
 (``"s"``, ``"t"``) with the vector that says whose each row is
 (``"owner"``): rows are not blocks, so the block programs refuse that
-pool by name.
+pool by name.  The shared-K/V family's (models/shared_kv_hybrid.py) pool
+is of that kind with three sorts of per-sequence memory: K/V blocks for
+ONE layer (read by every layer after it), a ring of the window's
+positions a slot a window layer (``"rk"``, ``"rv"``) and Mamba-1's row a
+slot a state-space layer.
 """
 
 from __future__ import annotations
@@ -64,7 +68,8 @@ import jax
 import jax.numpy as jnp
 
 from ..config import ModelConfig
-from ..models import hybrid_ssm, latent_moe, transformer
+from ..models import (hybrid_ssm, latent_moe, shared_kv_hybrid,
+                      transformer)
 from ..ops import attention, quant
 
 KVPool = Dict[str, jax.Array]    # {"k","v": [L, NB, bs, N_kv * D]}
@@ -124,6 +129,24 @@ def init_pool(cfg: ModelConfig, pcfg: PagedConfig,
                 f"family ({cfg.name}) has no int8 pool — the dense int8 "
                 f"rows are not wired to its attention layers; use 'none'")
         dtype = jnp.dtype(cfg.dtype)
+        if cfg.shared_kv:
+            # ONE cached layer ("F"), a ring of the window's positions a
+            # slot a window layer, and Mamba-1's row a slot a state-space
+            # layer: the state [state, inner] float32 (channels on the
+            # lanes), the conv tail in the model's dtype.
+            n_m, n_w, r = (cfg.layers_of("M"), cfg.layers_of("W"),
+                           pcfg.max_slots)
+            # The ring is exactly the window (models/shared_kv_hybrid.py).
+            ring = (n_w, r, cfg.attn_window, cfg.cache_row_width)
+            return {"k": jnp.zeros((1,) + shape[1:], dtype),
+                    "v": jnp.zeros((1,) + shape[1:], dtype),
+                    "rk": jnp.zeros(ring, dtype),
+                    "rv": jnp.zeros(ring, dtype),
+                    "s": jnp.zeros((n_m, r, cfg.ssm_state, cfg.ssm_inner),
+                                   jnp.float32),
+                    "t": jnp.zeros((n_m, r, cfg.ssm_conv - 1,
+                                    cfg.ssm_inner), dtype),
+                    "owner": jnp.zeros((r,), jnp.int32)}
         kv = (cfg.layers_of("*"),) + shape[1:]
         n_m, r = cfg.layers_of("M"), pcfg.max_slots
         # "k" first: ``_block_size`` reads the first array.  The state is
@@ -348,8 +371,7 @@ def pool_block_bytes(cfg: ModelConfig, block_size: int,
         return (cfg.num_layers * block_size * cfg.cache_row_width
                 * jnp.dtype(cfg.dtype).itemsize)
     d = cfg.head_dim
-    n_layers = cfg.layers_of("*") if cfg.hybrid else cfg.num_layers
-    per_row = n_layers * cfg.num_kv_heads * block_size
+    per_row = cfg.kv_layers * cfg.num_kv_heads * block_size
     if kv_quantize == "int8":
         # int8 k/v (1 byte) + float32 per-row scales.
         return per_row * (d * 2 + 4 * 2)
@@ -440,7 +462,10 @@ def chunk_prefill_paged(
     num_experts]`` (hybrid: the held experts, then one column of the
     assignments that went to absent ones).  The hybrid family's chunk
     also finds or, at ``start == 0``, claims and zeroes the sequence's
-    recurrent row (models/hybrid_ssm.py).
+    recurrent row (models/hybrid_ssm.py).  The shared-K/V family's chunk
+    that does not hold the prompt's last token (``start + S_c <
+    true_len``) runs its layers up to the one cached layer's K/V write
+    and returns zeros for ``hidden`` (models/shared_kv_hybrid.py).
     """
     b, s_c = tokens.shape
     d = cfg.head_dim
@@ -451,12 +476,17 @@ def chunk_prefill_paged(
     flat_pos = positions[0]                                  # [S_c]
     blk = table[flat_pos // bs]                              # [S_c]
     off = flat_pos % bs
-    if cfg.latent:
+    family = cfg.family
+    if family == "latent":
         hidden, new_pool, n_exp = latent_moe.forward_paged(
             cfg, params, tokens, positions, q_pos, pool, blk[None],
             off[None], table[None, :window // bs])
         return (hidden, new_pool, n_exp) if counts else (hidden, new_pool)
-    if cfg.hybrid:
+    if family == "shared_kv":
+        ctx, pool = shared_kv_hybrid.chunk_ctx(
+            pool, table, start, true_len, s_c, window, blk, off, q_pos)
+        return shared_kv_hybrid.forward_paged(cfg, params, tokens, pool, ctx)
+    if family == "hybrid":
         ctx, pool = hybrid_ssm.chunk_ctx(pool, table, start, true_len, s_c,
                                          window, blk, off, q_pos)
         hidden, new_pool, n_exp = hybrid_ssm.forward_paged(
@@ -632,13 +662,19 @@ def decode_step_paged(
 
     blk = jnp.take_along_axis(tables, (pos // bs)[:, None], axis=1)[:, 0]
     off = pos % bs                                     # [B]
-    if cfg.latent:
+    family = cfg.family
+    if family == "latent":
         hidden, new_pool, n_exp = latent_moe.forward_paged(
             cfg, params, token[:, None], pos[:, None], pos[:, None], pool,
             blk[:, None], off[:, None], tables)
         logits = transformer.logits_from_hidden(params, hidden[:, 0])
         return (logits, new_pool, n_exp) if counts else (logits, new_pool)
-    if cfg.hybrid:
+    if family == "shared_kv":
+        hidden, new_pool = shared_kv_hybrid.forward_paged(
+            cfg, params, token[:, None], pool,
+            shared_kv_hybrid.decode_ctx(pool, tables, pos, blk, off))
+        return transformer.logits_from_hidden(params, hidden[:, 0]), new_pool
+    if family == "hybrid":
         hidden, new_pool, n_exp = hybrid_ssm.forward_paged(
             cfg, params, token[:, None], pool,
             hybrid_ssm.decode_ctx(pool, tables, pos, blk, off))

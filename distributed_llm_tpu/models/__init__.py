@@ -1,9 +1,11 @@
 """Model families: dense LLaMA-style (transformer.py), MoE (moe.py) and
 the latent-attention, routed-expert, multi-stream family (latent_moe.py,
 ``cfg.latent``) and the state-space / attention / routed-expert hybrid
-(hybrid_ssm.py, ``cfg.hybrid``); the last two serve through the paged
-pool only, and the hybrid has no cold prefill: every prompt goes through
-the chunk program, which carries the recurrent state.
+(hybrid_ssm.py) and the state-space / window-attention / shared-K/V
+hybrid (shared_kv_hybrid.py), each named by ``cfg.family``; the last three
+serve through the paged pool only, and the two that keep a recurrent row
+a slot (``cfg.hybrid``) have no cold prefill: every prompt goes through
+the chunk program, which carries the state.
 
 ``model_module(cfg)`` dispatches on ModelConfig.num_experts so the engine,
 trainer, and checkpoint code serve either family through one surface:
@@ -16,15 +18,14 @@ and are shared.
 from __future__ import annotations
 
 from ..config import ModelConfig
-from . import hybrid_ssm, latent_moe, moe, transformer  # noqa: F401
+from . import (hybrid_ssm, latent_moe, moe, shared_kv_hybrid,  # noqa: F401
+               transformer)
 
 
 def model_module(cfg: ModelConfig):
-    if cfg.latent:
-        return latent_moe
-    if cfg.hybrid:
-        return hybrid_ssm
-    return moe if cfg.num_experts > 1 else transformer
+    module = {"latent": latent_moe, "shared_kv": shared_kv_hybrid,
+              "hybrid": hybrid_ssm}.get(cfg.family)
+    return module or (moe if cfg.num_experts > 1 else transformer)
 
 
 def serving_prefill(cfg: ModelConfig, params, tokens, positions, attn=None):

@@ -57,7 +57,8 @@ import numpy as np
 from ..config import ModelConfig
 from ..ops import attention, quant
 from . import latent_moe, transformer
-from .latent_moe import EMBED_STD, HIGHEST, ROUTER_BIAS_STD, _normal, _table
+from .latent_moe import (EMBED_STD, HIGHEST, ROUTER_BIAS_STD, init_normal,
+                         init_table)
 
 Params = Dict[str, Any]
 KINDS = "M*E"
@@ -132,7 +133,7 @@ def kind_index(cfg: ModelConfig, kind: str):
 # Init: the seed is data, never a constant of the program
 # =============================================================================
 
-def _uniform(key, shape, dtype, bound):
+def init_uniform(key, shape, dtype, bound):
     return jax.random.uniform(key, shape, jnp.float32, -bound,
                               bound).astype(dtype)
 
@@ -156,23 +157,23 @@ def init_layer(cfg: ModelConfig, key, kind: str) -> Params:
             # [z | xBC | dt], then zero columns up to the chip's lanes:
             # at the published 10304 (80.5 x 128) the device rests the
             # matrix transposed and the tick copied it at its entry.
-            w_in=jnp.pad(_normal(ks[0], (h, di + c + nh), dtype),
+            w_in=jnp.pad(init_normal(ks[0], (h, di + c + nh), dtype),
                          ((0, 0), (0, -(di + c + nh) % LANES))),
             # A depthwise conv's default init: uniform in +-1/sqrt(taps).
-            conv_w=_uniform(ks[1], (k, c), dtype, k ** -0.5),
-            conv_b=_uniform(ks[2], (c,), dtype, k ** -0.5),
+            conv_w=init_uniform(ks[1], (k, c), dtype, k ** -0.5),
+            conv_b=init_uniform(ks[2], (c,), dtype, k ** -0.5),
             dt_bias=dt + jnp.log(-jnp.expm1(-dt)),
             a_log=jnp.log(jax.random.uniform(ks[4], (nh,), jnp.float32,
                                              1.0, 16.0)),
             d=jnp.ones((nh,), jnp.float32),
             gn=jnp.ones((di,), dtype),
-            w_out=_normal(ks[5], (di, h), dtype))
+            w_out=init_normal(ks[5], (di, h), dtype))
     elif kind == "*":
         d, nq, nkv = cfg.head_dim, cfg.num_heads, cfg.num_kv_heads
-        lp.update(wq=_normal(ks[0], (h, nq * d), dtype),
-                  wk=_normal(ks[1], (h, nkv * d), dtype),
-                  wv=_normal(ks[2], (h, nkv * d), dtype),
-                  wo=_normal(ks[3], (nq * d, h), dtype))
+        lp.update(wq=init_normal(ks[0], (h, nq * d), dtype),
+                  wk=init_normal(ks[1], (h, nkv * d), dtype),
+                  wv=init_normal(ks[2], (h, nkv * d), dtype),
+                  wo=init_normal(ks[3], (nq * d, h), dtype))
     else:
         f, e = cfg.moe_ffn_size, cfg.num_experts
         held = slice(cfg.experts_first, cfg.experts_first + cfg.experts_held)
@@ -185,18 +186,18 @@ def init_layer(cfg: ModelConfig, key, kind: str) -> Params:
             # zero-padded to ``expert_dims_stored``.
             pad = [(0, st - n) for n, st in zip(shape, stored)]
             return jax.lax.map(
-                lambda k: jnp.pad(_normal(k, shape, dtype), pad),
+                lambda k: jnp.pad(init_normal(k, shape, dtype), pad),
                 jax.random.split(key, e)[held])
 
-        lp.update(router=_normal(ks[0], (h, e), dtype),
+        lp.update(router=init_normal(ks[0], (h, e), dtype),
                   router_bias=ROUTER_BIAS_STD * jax.random.normal(
                       ks[1], (e,), jnp.float32),
                   we_up=experts(ks[2], (h, f), (h_st, f_st)),
                   we_down=experts(ks[3], (f, h), (f_st, h_st)))
         if cfg.shared_ffn_size:
-            lp.update(ws_up=_normal(ks[4], (h, cfg.shared_ffn_size), dtype),
-                      ws_down=_normal(ks[5], (cfg.shared_ffn_size, h),
-                                      dtype))
+            fs = cfg.shared_ffn_size
+            lp.update(ws_up=init_normal(ks[4], (h, fs), dtype),
+                      ws_down=init_normal(ks[5], (fs, h), dtype))
     return lp
 
 
@@ -211,9 +212,9 @@ def init_params(cfg: ModelConfig, seed=0) -> Params:
     lkeys = jax.random.split(k_layers, cfg.num_layers)
     period = cfg.layer_period
     return {
-        "embed": _table(k_embed, cfg.vocab_size, cfg.hidden_size, dtype,
-                        EMBED_STD),
-        "head": _table(k_head, cfg.vocab_size, cfg.hidden_size, dtype),
+        "embed": init_table(k_embed, cfg.vocab_size, cfg.hidden_size, dtype,
+                            EMBED_STD),
+        "head": init_table(k_head, cfg.vocab_size, cfg.hidden_size, dtype),
         "final_ln": jnp.ones((cfg.hidden_size,), dtype),
         "periods": [jax.lax.map(lambda k, c=kind: init_layer(cfg, k, c),
                                 lkeys[j::len(period)])

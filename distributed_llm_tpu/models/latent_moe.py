@@ -65,23 +65,22 @@ EMBED_STD = 1.0
 # Init: the seed is data, never a constant of the program
 # =============================================================================
 
-def _normal(key, shape, dtype, std=WEIGHT_STD):
+def init_normal(key, shape, dtype, std=WEIGHT_STD):
     return (std * jax.random.normal(key, shape, jnp.float32)).astype(dtype)
 
 
-TABLE_ROWS = 4096       # rows of a vocabulary table drawn at a time
+TABLE_ROWS = 4096       # most rows of a vocabulary table drawn at a time
 
 
-def _table(key, rows: int, width: int, dtype, std=WEIGHT_STD):
-    """A [rows, width] vocabulary table (embedding, head), drawn
-    ``TABLE_ROWS`` rows at a time from a key a block: the float32 draws
-    beside the result are one block's, not the table's 1.9 GB."""
-    block = min(rows, TABLE_ROWS)
-    if rows % block:
-        raise ValueError(f"vocabulary of {rows} rows is not a multiple of "
-                         f"{block}")
+def init_table(key, rows: int, width: int, dtype, std=WEIGHT_STD):
+    """A [rows, width] vocabulary table (embedding, head), drawn a block
+    of rows at a time from a key a block — the largest divisor of ``rows``
+    that is at most ``TABLE_ROWS`` (4096 for a vocabulary that is a
+    multiple of it, 3126 for 200 064): the float32 draws beside the result
+    are one block's, not the table's 1.9 GB."""
+    block = max(n for n in range(1, TABLE_ROWS + 1) if rows % n == 0)
     keys = jax.random.split(key, rows // block)
-    return jax.lax.map(lambda k: _normal(k, (block, width), dtype, std),
+    return jax.lax.map(lambda k: init_normal(k, (block, width), dtype, std),
                        keys).reshape(rows, width)
 
 
@@ -92,7 +91,7 @@ def _hc_params(cfg: ModelConfig, key, dtype) -> Params:
     and ``H_pre`` far from uniform, so a wrong mixing moves the logits."""
     n, h = cfg.residual_streams, cfg.hidden_size
     kp, ka, kb = jax.random.split(key, 3)
-    return {"phi": _normal(kp, (n * h, 2 * n + n * n), dtype),
+    return {"phi": init_normal(kp, (n * h, 2 * n + n * n), dtype),
             "alpha": jax.random.uniform(ka, (3,), jnp.float32, 0.2, 0.4),
             "b": jax.random.normal(kb, (2 * n + n * n,), jnp.float32)}
 
@@ -106,22 +105,22 @@ def init_layer(cfg: ModelConfig, key, moe: bool) -> Params:
     ks = jax.random.split(key, 16)
     lp = {
         "ln1": jnp.ones((h,), dtype),
-        "w_qa": _normal(ks[0], (h, cfg.q_lora_rank), dtype),
+        "w_qa": init_normal(ks[0], (h, cfg.q_lora_rank), dtype),
         "q_ln": jnp.ones((cfg.q_lora_rank,), dtype),
-        "w_qb": _normal(ks[1], (cfg.q_lora_rank, nh * (dn + dr)), dtype),
-        "w_kva": _normal(ks[2], (h, cfg.cache_row_width), dtype),
+        "w_qb": init_normal(ks[1], (cfg.q_lora_rank, nh * (dn + dr)), dtype),
+        "w_kva": init_normal(ks[2], (h, cfg.cache_row_width), dtype),
         "kv_ln": jnp.ones((cfg.kv_lora_rank,), dtype),
-        "w_kvb": _normal(ks[3], (cfg.kv_lora_rank, nh * (dn + dv)), dtype),
-        "wo": _normal(ks[4], (nh * dv, h), dtype),
+        "w_kvb": init_normal(ks[3], (cfg.kv_lora_rank, nh * (dn + dv)), dtype),
+        "wo": init_normal(ks[4], (nh * dv, h), dtype),
         "hc_attn": _hc_params(cfg, ks[5], dtype),
         "hc_ffn": _hc_params(cfg, ks[6], dtype),
         "ln2": jnp.ones((h,), dtype),
     }
     if not moe:
         f = cfg.ffn_size
-        lp.update(w_gate=_normal(ks[7], (h, f), dtype),
-                  w_up=_normal(ks[8], (h, f), dtype),
-                  w_down=_normal(ks[9], (f, h), dtype))
+        lp.update(w_gate=init_normal(ks[7], (h, f), dtype),
+                  w_up=init_normal(ks[8], (h, f), dtype),
+                  w_down=init_normal(ks[9], (f, h), dtype))
         return lp
     f, e = cfg.moe_ffn_size, cfg.num_experts
     fs = f * cfg.shared_experts
@@ -129,19 +128,19 @@ def init_layer(cfg: ModelConfig, key, moe: bool) -> Params:
     def experts(key, shape):
         # One expert at a time: the float32 draws beside the result are
         # one expert's, not the layer's 0.9 GB a matrix.
-        return jax.lax.map(lambda k: _normal(k, shape, dtype),
+        return jax.lax.map(lambda k: init_normal(k, shape, dtype),
                            jax.random.split(key, e))
 
-    lp.update(router=_normal(ks[10], (h, e), dtype),
+    lp.update(router=init_normal(ks[10], (h, e), dtype),
               router_bias=ROUTER_BIAS_STD * jax.random.normal(
                   ks[11], (e,), jnp.float32),
               we_gate=experts(ks[12], (h, f)),
               we_up=experts(ks[13], (h, f)),
               we_down=experts(ks[14], (f, h)))
     if fs:
-        lp.update(ws_gate=_normal(ks[7], (h, fs), dtype),
-                  ws_up=_normal(ks[8], (h, fs), dtype),
-                  ws_down=_normal(ks[9], (fs, h), dtype))
+        lp.update(ws_gate=init_normal(ks[7], (h, fs), dtype),
+                  ws_up=init_normal(ks[8], (h, fs), dtype),
+                  ws_down=init_normal(ks[9], (fs, h), dtype))
     return lp
 
 
@@ -156,8 +155,8 @@ def init_params(cfg: ModelConfig, seed=0) -> Params:
     lkeys = jax.random.split(k_layers, cfg.num_layers)
     n_lead = cfg.dense_lead_layers
     params = {
-        "embed": _table(k_embed, cfg.vocab_size, cfg.hidden_size, dtype,
-                        EMBED_STD),
+        "embed": init_table(k_embed, cfg.vocab_size, cfg.hidden_size, dtype,
+                            EMBED_STD),
         "final_ln": jnp.ones((cfg.hidden_size,), dtype),
         "lead": jax.lax.map(lambda k: init_layer(cfg, k, False),
                             lkeys[:n_lead]),
@@ -165,8 +164,8 @@ def init_params(cfg: ModelConfig, seed=0) -> Params:
                               lkeys[n_lead:]),
     }
     if not cfg.tie_embeddings:
-        params["head"] = _table(k_head, cfg.vocab_size, cfg.hidden_size,
-                                dtype)
+        params["head"] = init_table(k_head, cfg.vocab_size, cfg.hidden_size,
+                                    dtype)
     return params
 
 

@@ -407,6 +407,13 @@ METRIC_REGISTRY: Tuple[Tuple[str, str, str, Tuple[str, ...], str], ...] = (
      "under the host's fetch and emit) or not (kind=alone: a solo "
      "prefill's chunks, the rest of a budget above one chunk, a chunk "
      "that stalled on a dry pool until the emit)"),
+    ("prefill_self_only_chunks", "counter",
+     "dllm_prefill_self_only_chunks_total", ("tier",),
+     "Of dllm_prefill_chunks_total, the shared-K/V family's chunks that "
+     "did not hold their prompt's last token and so ran to the one "
+     "cached layer's K/V write and no deeper "
+     "(models/shared_kv_hybrid.py): the layers after it feed a prompt "
+     "position's own logits only"),
     # Routed-expert family (models/latent_moe.py): what the tick and
     # the chunk program count beside their tokens — how many expert
     # assignments a stage computed, and how many experts those touched

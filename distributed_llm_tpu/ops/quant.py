@@ -137,7 +137,12 @@ _QUANT_LAYER_KEYS = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down",
                      # the hybrid family's state-space projections
                      # (models/hybrid_ssm.py); its conv, time-step and
                      # decay parameters and its router stay as made
-                     "w_in", "w_out")
+                     "w_in", "w_out",
+                     # the shared-K/V family's (models/shared_kv_hybrid.py):
+                     # q|k|v side by side, the memory units' two and the
+                     # gated MLP's; its time-step projections (w_x, w_dt)
+                     # stay as made, with the conv and the decay
+                     "w_qkv", "w_g1", "w_g2", "w1", "w2")
 
 
 def maybe_quantize(params: Dict[str, Any], tier, cfg,
@@ -192,4 +197,8 @@ def quantize_params(params: Dict[str, Any]) -> Dict[str, Any]:
             out[group] = stack(params[group])
     if "periods" in params:
         out["periods"] = [stack(lp) for lp in params["periods"]]
+    # "segments": the shared-K/V family's, a list of such periods.
+    if "segments" in params:
+        out["segments"] = [[stack(lp) for lp in seg]
+                           for seg in params["segments"]]
     return out

@@ -59,6 +59,35 @@ def _hybrid_params(cfg, experts: float) -> float:
             + cfg.layers_of("E") * (fixed + experts * expert))
 
 
+def _shared_kv_params(cfg) -> int:
+    """All layers' matrices of the shared-K/V family
+    (models/shared_kv_hybrid.py): Mamba-1's four, the window and full
+    layers' q|k|v and o, the cross layers' q and o, the memory units' two,
+    and a gated MLP every layer."""
+    h, di = cfg.hidden_size, cfg.ssm_inner
+    nq, kv = cfg.num_heads * cfg.head_dim, cfg.cache_row_width
+    ssm = (h * 2 * di + di * (cfg.ssm_dt_rank + 2 * cfg.ssm_state)
+           + cfg.ssm_dt_rank * di + di * h)
+    return (cfg.layers_of("M") * ssm
+            + (cfg.layers_of("W") + 1) * (h * (nq + 2 * kv) + nq * h)
+            + cfg.layers_of("X") * 2 * h * nq
+            + cfg.layers_of("G") * 2 * h * di
+            + cfg.num_layers * 3 * h * cfg.ffn_size)
+
+
+def kv_readers(cfg) -> int:
+    """Layers that read one cached layer's K/V: itself, or the shared-K/V
+    family's full layer and every cross layer after it."""
+    return 1 + cfg.layers_of("X") if cfg.shared_kv else 1
+
+
+def ring_row_bytes(cfg) -> int:
+    """What one sequence of the shared-K/V family keeps for its window
+    layers whatever its length: K and V of the window's positions."""
+    return (cfg.layers_of("W") * 2 * cfg.attn_window * cfg.cache_row_width
+            * (4 if cfg.dtype == "float32" else 2))
+
+
 def _layer_counts(cfg):
     """(dense layers, expert layers)."""
     if cfg.num_experts <= 1:
@@ -73,7 +102,9 @@ def active_matmul_params(cfg) -> int:
     router — + the LM head.  Embedding lookup is a gather, not a
     matmul."""
     h = cfg.hidden_size
-    if cfg.hybrid:
+    if cfg.family == "shared_kv":
+        return _shared_kv_params(cfg) + cfg.vocab_size * h
+    if cfg.family == "hybrid":
         # Of a token's ``experts_per_token`` choices, the share this
         # program holds computes its fraction (uniform routing).
         held = cfg.experts_per_token * cfg.experts_held / cfg.num_experts
@@ -96,7 +127,11 @@ def weight_bytes(cfg, quantize: str = "none") -> int:
     (models/latent_moe.py), so for it this is an upper bound."""
     h = cfg.hidden_size
     per_param = 1 if quantize == "int8" else 2
-    if cfg.hybrid:
+    if cfg.family == "shared_kv":
+        # The tied table once; gains and biases of two norms a layer.
+        return (_shared_kv_params(cfg) * per_param
+                + (cfg.vocab_size * h + (4 * cfg.num_layers + 2) * h) * 2)
+    if cfg.family == "hybrid":
         # Every held expert (an upper bound, as for the latent family).
         return (int(_hybrid_params(cfg, cfg.experts_held)) * per_param
                 + (2 * cfg.vocab_size * h + (cfg.num_layers + 1) * h) * 2)
@@ -116,8 +151,7 @@ def kv_bytes_per_pos(cfg, kv_quantize: str = "none") -> int:
     scales (engine/paged_kv.py); the latent family's one row a layer."""
     if cfg.latent:
         return cfg.num_layers * cfg.cache_row_width * 2
-    layers = cfg.layers_of("*") if cfg.hybrid else cfg.num_layers
-    rows = 2 * layers * cfg.num_kv_heads
+    rows = 2 * cfg.kv_layers * cfg.num_kv_heads
     if kv_quantize == "int8":
         return rows * (cfg.head_dim + 4)
     return rows * cfg.head_dim * 2
@@ -125,8 +159,12 @@ def kv_bytes_per_pos(cfg, kv_quantize: str = "none") -> int:
 
 def _attention_width_layers(cfg):
     """(width summed over query heads, layers) of the layers that attend
-    over positions: every layer, or the hybrid family's attention ones."""
-    if cfg.hybrid:
+    over positions: every layer, or the hybrid family's attention ones
+    (the shared-K/V family's: the readers of its one cached layer; its
+    window layers' fixed span is not counted)."""
+    if cfg.family == "shared_kv":
+        return cfg.num_heads * cfg.head_dim, kv_readers(cfg)
+    if cfg.family == "hybrid":
         return cfg.num_heads * cfg.head_dim, cfg.layers_of("*")
     return cfg.hidden_size, cfg.num_layers
 
@@ -181,9 +219,12 @@ def decode_work(cfg, steps: int, ctx: int, batch: int = 1,
     flops = float(steps) * batch * (2.0 * pm + 4.0 * h * l * span)
     if wbytes is None:
         wbytes = weight_bytes(cfg)
-    hbm = float(steps) * (wbytes + kvb
+    hbm = float(steps) * (wbytes + kvb * kv_readers(cfg)
                           * kv_bytes_per_pos(cfg, kv_quantize) * span)
     if cfg.hybrid:
-        # The recurrent rows: read and written whole, every step.
+        # The recurrent rows: read and written whole, every step; the
+        # rings read whole.
         hbm += float(steps) * batch * 2 * state_row_bytes(cfg)
+    if cfg.shared_kv:
+        hbm += float(steps) * batch * ring_row_bytes(cfg)
     return {"flops": flops, "hbm_bytes": hbm, "tokens": steps * batch}

@@ -98,10 +98,11 @@ def test_kernel_comparison_at_tiny_widths(dtype):
     cases = chip_smoke.kernel_cases(
         8, 4, 16, dtype, batch=3, block=16, blocks_per_slot=4,
         prefill_len=64, decode_len=128, chunk=16, chunk_window=128,
-        verify_q=5, grouped={"tiny": (24, 6, 256, 128)})
+        verify_q=5, grouped={"tiny": (24, 6, 256, 128)},
+        scan=(16, 8, 256))
     assert {c.kind for c in cases.values()} == {
         "prefill", "decode", "chunk", "paged_decode", "ragged_decode",
-        "ragged_decode_q8", "ragged_verify", "grouped_product"}
+        "ragged_decode_q8", "ragged_verify", "grouped_product", "ssm_scan"}
     errs = chip_smoke.compare_kernels(cases, jnp.dtype(dtype).name)
     assert set(errs) == set(cases)
 

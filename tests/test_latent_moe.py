@@ -282,7 +282,8 @@ def test_yarn_frequencies_and_softmax_scale_follow_the_formula():
 # (7), (8), (9): the engine ---------------------------------------------------
 
 def _while_depth(hlo: str) -> int:
-    """How deep ``while`` loops nest in a compiled module's text."""
+    """How deep ``while`` loops nest in a compiled module's text, through
+    every computation a line names (a conditional's branches too)."""
     bodies = {}
     for m in re.finditer(r"^(?:ENTRY )?%?([\w.\-]+) [^\n]*\{\n(.*?)^\}",
                          hlo, re.M | re.S):
@@ -295,11 +296,13 @@ def _while_depth(hlo: str) -> int:
         best = 0
         for line in bodies[name].splitlines():
             loop = " while(" in line
-            for ref_ in re.findall(
-                    r"(?:body|condition|calls|to_apply|branch_computations)"
-                    r"=\{?%?([\w.\-]+)", line):
-                best = max(best, depth(ref_, seen + (name,))
-                           + (1 if loop else 0))
+            for group in re.findall(
+                    r"(?:body|condition|calls|to_apply|true_computation|"
+                    r"false_computation|branch_computations)="
+                    r"(\{[^}]*\}|%?[\w.\-]+)", line):
+                for ref_ in re.findall(r"%?([\w.\-]+)", group):
+                    best = max(best, depth(ref_, seen + (name,))
+                               + (1 if loop else 0))
         return best
     return depth(entry)
 

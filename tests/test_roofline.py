@@ -56,6 +56,33 @@ def test_latent_family_counts_experts_per_token_and_the_shared_one():
     assert roofline.kv_bytes_per_pos(cfg) == 3 * 32 * 2
 
 
+def test_shared_kv_family_counts_readers_rings_and_rows_a_step():
+    # The hand count of every matrix is in tests/test_shared_kv_hybrid.py;
+    # here: what a decode step MOVES beside the weights, for GET /stats.
+    cfg = MODEL_PRESETS["shared_kv_test"]       # MWMWMWMFGXGX, window 16
+    assert cfg.shared_kv and cfg.hybrid
+    # One cached layer (F): K and V of 4 heads x 8, bfloat16 ...
+    assert roofline.kv_bytes_per_pos(cfg) == 2 * 4 * 8 * 2
+    # ... read by F and the two X layers.
+    assert roofline.kv_readers(cfg) == 3
+    assert roofline.kv_readers(CFG) == 1
+    ring = 3 * 2 * 16 * 32 * 2                  # 3 W layers, K and V
+    row = 4 * (128 * 8 * 4 + 3 * 128 * 2)       # 4 M layers, S + tail
+    assert roofline.ring_row_bytes(cfg) == ring
+    assert roofline.state_row_bytes(cfg) == row
+    one = roofline.decode_work(cfg, steps=1, ctx=64, batch=1)
+    four = roofline.decode_work(cfg, steps=1, ctx=64, batch=4)
+    assert four["hbm_bytes"] - one["hbm_bytes"] == 3 * (
+        3 * 128 * 64 + ring + 2 * row)
+    # A longer context costs the readers' K/V alone: rings and rows do
+    # not grow with the sequence.
+    longer = roofline.decode_work(cfg, steps=1, ctx=128, batch=4)
+    assert longer["hbm_bytes"] - four["hbm_bytes"] == 4 * 3 * 128 * 64
+    # A chunk writes one layer's K/V.
+    assert (roofline.prefill_work(cfg, 32, 16)["hbm_bytes"]
+            - roofline.weight_bytes(cfg)) == 16 * 128
+
+
 def test_weight_bytes_int8_halves_body_only():
     bf16 = roofline.weight_bytes(CFG, "none")
     i8 = roofline.weight_bytes(CFG, "int8")

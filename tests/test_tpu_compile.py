@@ -45,7 +45,10 @@ KERNELS = ["flash_causal_attention", "flash_decode_attention",
            # same case under every preset): an up and a down product at
            # the decode ticks' rows and the widths the cells store.
            "grouped_product.wide-reasoning",
-           "grouped_product.reasoned-reply"]
+           "grouped_product.reasoned-reply",
+           # Mamba-1's recurrence over a chunk (float32 and headless: the
+           # same case under every preset), at the shared-K/V cell's chunk.
+           "ssm_chunk_scan"]
 
 
 @pytest.fixture(scope="module")
@@ -356,3 +359,50 @@ def test_routed_tick_reads_the_experts_where_they_rest(one_chip, as_on_tpu,
     # The kernel is one operation of the layer body: the tick keeps the
     # two nested loops the benchmark files it by (steps, layers).
     assert text.count(" while(") == 2
+
+
+# -- the shared-K/V family's programs (ISSUE 35) --------------------------------
+
+# program: (temporaries allowed in GB, ``while``s nested, chunk-scan
+# kernels in the text).  The tick at the slot's whole span gathers the one
+# cached layer's window 8 times (16 slots x 5120 positions x 1280 x 2 B =
+# 0.21 GB for K and as much for V, alive one reader at a time): 0.56 GB
+# compiled (PR 35); the last-chunk program's full layer holds its scores,
+# 0.48 GB.  The kernel is in the chunk program twice — the scanned MW
+# segment's body and layer 16 inline — and never in the tick.
+SHARED_KV_PROGRAMS = {
+    ("decode", 5120): (0.7, 2, 0),
+    ("chunk", 256, 5120): (0.7, 1, 2),
+}
+
+
+@pytest.mark.parametrize("program", list(SHARED_KV_PROGRAMS))
+def test_shared_kv_programs_nest_their_loops_and_stay_small(
+        one_chip, as_on_tpu, monkeypatch, program):
+    """Phi-4-mini-flash-reasoning's own tick and last-chunk program at
+    their real sizes: the tick nests two ``while``s (steps, a segment's
+    repeats) and every chunk program one, which is how the benchmark files
+    them; the chunk's recurrence is the kernel, not an unrolled loop."""
+    temp_limit_gb, depth, kernels = SHARED_KV_PROGRAMS[program]
+    tier = _bench_tier(monkeypatch, "phi-4-mini-flash-reasoning")
+    engine, pool, compiled, _ = _pool_program(one_chip, tier, program)
+    assert engine.cfg.shared_kv and engine.ragged is False
+    assert pool["k"].shape == (1, 1281, 64, 1280)
+    assert pool["rk"].shape == (8, 16, 512, 1280)
+    assert pool["s"].shape == (9, 16, 16, 5120)
+    from test_latent_moe import _while_depth
+    text = compiled.as_text()
+    assert _while_depth(text) == depth
+    # The chunk program's loops: the segment that repeats before the
+    # cached layer and — under the conditional only a prompt's last chunk
+    # takes — the one after it.  By that second loop the benchmark tells
+    # a self-only chunk from a full-depth one in the device trace
+    # (benchmark/layer_metrics/shared_kv_readers.py); the tick runs both
+    # inside its loop over the steps.
+    chunk = program[0] == "chunk"
+    assert text.count(" while(") == (2 if chunk else 3)
+    assert (" conditional(" in text) is chunk
+    assert text.count("tpu_custom_call") == kernels
+    assert ("ssm_chunk_scan" in text) is bool(kernels)
+    temp_gb = compiled.memory_analysis().temp_size_in_bytes / GB
+    assert temp_gb < temp_limit_gb, temp_gb
