@@ -426,6 +426,9 @@ def phase_what_ran(served: Served) -> Dict[str, Any]:
             "attention_impl": engine.cfg.attention_impl,
             "tick": "ragged fused" if engine.ragged else "dense windowed",
             "decode_attention": engine.decode_attention_form(),
+            # Of the decode ticks so far, the share launched with
+            # everything already on the device (no upload in prepare).
+            "tick_resident_share": engine.tick_stats()["resident_share"],
             "speculation": bool(engine.spec),
             "span": span,
             "impl_by_kind": impls,

@@ -462,6 +462,27 @@ class ContinuousBatchingEngine:
         self._cur = np.zeros(b, np.int32)
         self._temps = np.zeros(b, np.float32)
         self._slots: List[Optional[_Slot]] = [None] * b
+        # The tick's small inputs as the device holds them, by name
+        # (``pos``, ``cur``, ``temps``): what the last tick returned or
+        # ``prepare`` uploaded, absent once a writer other than the
+        # plain emit has touched the mirror above (``_drop_carry``).
+        # The mirrors stay the authority; ``prepare`` uploads what is
+        # absent and nothing else.  Uploads and the key are COMMITTED
+        # where the tick's outputs come out, so a tick fed from the host
+        # and one fed from the last tick are one compiled program.
+        self._carry: Dict[str, Any] = {}
+        self._carry_home = (self._replicated if mesh is not None else
+                            home if isinstance(home, jax.sharding.Sharding)
+                            else None)
+        if self._carry_home is not None:
+            self._rng = jax.device_put(self._rng, self._carry_home)
+        # Decode ticks launched, how many of them with no upload in
+        # ``prepare`` (tick_stats' ``resident_share``), and the uploads
+        # made there by what (dllm_tick_prepare_uploads_total).
+        self.ticks_launched_total = 0
+        self.ticks_resident_total = 0
+        self.prepare_uploads_total: Dict[str, int] = {}
+        self._prepare_upload_sinks: Dict[str, Any] = {}
 
         self._prefill_fns: Dict[Any, Any] = {}
         self._writer_fns: Dict[int, Any] = {}
@@ -1049,7 +1070,15 @@ class ContinuousBatchingEngine:
         the host↔device round trip is amortized over T tokens per slot.
         Returns tokens [T, B]; the host applies budget/EOS per slot and
         discards the ≤T-1 overshoot a mid-tick finisher decodes (its writes
-        land in its own still-allocated blocks, freed on finish)."""
+        land in its own still-allocated blocks, freed on finish).
+
+        ``rng`` is the ENGINE's key: the tick splits it as the host did
+        before each launch (the same ``jax.random.split``, so the stream
+        is the same), steps on one half and returns the other.  Beside
+        the tokens it returns what the next tick starts from — ``pos``
+        and ``cur`` after the last step, and that key — which the engine
+        keeps on the device (``_run_scheduler``): nothing of it is
+        fetched."""
         if self._decode_fn is not None:
             return self._decode_fn
         cfg = self.cfg
@@ -1076,11 +1105,19 @@ class ContinuousBatchingEngine:
                 return ((pool, jnp.minimum(pos + 1, max_pos), nxt, rng),
                         (nxt, *n_exp))
 
-            (pool, _, _, _), toks = jax.lax.scan(
+            key, rng = jax.random.split(rng)
+            (pool, pos, cur, _), toks = jax.lax.scan(
                 step, (pool, pos, cur, rng), None, length=steps)
+            # A slot that holds no sequence (its row starts at the trash
+            # block) starts every tick where the host's mirrors keep
+            # it: position 0, token 0.
+            live = tables[:, 0] != TRASH_BLOCK
             # [T, B] tokens and, for a routed-expert model, the steps'
             # assignments an expert [T, expert layers, E]: one fetch.
-            return (toks if moe_counts else toks[0]), pool
+            # Then the next tick's inputs, which stay on the device.
+            return ((toks if moe_counts else toks[0]),
+                    jnp.where(live, pos, 0), jnp.where(live, cur, 0),
+                    key), pool
 
         self._decode_fn = self._pool_program(decode_tick, 1, lead=1)
         return self._decode_fn
@@ -1435,7 +1472,40 @@ class ContinuousBatchingEngine:
         # lazily at the next attribution pass.
         self._kv_weights.clear()
 
-    def _sync_state_owner(self) -> None:
+    def _drop_carry(self, temps: bool = False) -> None:
+        """A writer other than the plain emit changed ``_pos``/``_cur``
+        (``temps``: ``_temps`` too): what the device holds of them is
+        stale, and the next ``prepare`` uploads the mirrors."""
+        self._carry.pop("pos", None)
+        self._carry.pop("cur", None)
+        if temps:
+            self._carry.pop("temps", None)
+
+    def _upload(self, host: np.ndarray):
+        """A small tick input onto the device, committed where the
+        tick's own small outputs come out (``_carry_home``)."""
+        if self._carry_home is None:
+            return jnp.asarray(host)
+        return jax.device_put(host, self._carry_home)
+
+    def _count_prepare_upload(self, what: str) -> None:
+        """One upload made in ``prepare`` with the device idle."""
+        self.prepare_uploads_total[what] = \
+            self.prepare_uploads_total.get(what, 0) + 1
+        sink = self._prepare_upload_sinks.get(what)
+        if sink is None:
+            try:
+                # No injection path on the engine (same pattern as the
+                # tick histogram): the process-global registry.
+                from ..obs import get_observability
+                sink = get_observability().m.tick_prepare_uploads.labels(
+                    self.tier.name, what)
+            except Exception:
+                return
+            self._prepare_upload_sinks[what] = sink
+        sink.inc()
+
+    def _sync_state_owner(self) -> bool:
         """The hybrid families: before a program that reads the recurrent
         rows (the shared-K/V family's window rings are rows too: a ring
         is its slot's like a state), make ``pool["owner"]`` say row =
@@ -1443,14 +1513,17 @@ class ContinuousBatchingEngine:
         a free slot — so a finished or preempted sequence's row is free
         the moment its blocks are,
         and a sequence admitted into the slot later claims (and zeroes)
-        the same row.  A [slots] int32 upload, and only when it changed."""
+        the same row.  A [slots] int32 upload, and only when it changed
+        (returns whether it was made)."""
         if self._state_owner is None:
-            return
+            return False
         owner = self._rows_owned()
-        if not np.array_equal(owner, self._state_owner):
-            self._state_owner = owner
-            self.pool = {**self.pool, "owner": jax.device_put(
-                owner, self.pool["owner"].sharding)}
+        if np.array_equal(owner, self._state_owner):
+            return False
+        self._state_owner = owner
+        self.pool = {**self.pool, "owner": jax.device_put(
+            owner, self.pool["owner"].sharding)}
+        return True
 
     def _rows_owned(self) -> np.ndarray:
         """[slots] the first block of each slot's sequence, 0 if none."""
@@ -1594,6 +1667,7 @@ class ContinuousBatchingEngine:
         self._pos[slot_ix] = pos
         self._cur[slot_ix] = cur
         self._temps[slot_ix] = temp
+        self._drop_carry(temps=True)
         if gen is None:
             if first == self.tokenizer.eos_id or budget <= 1:
                 self._finish(slot_ix)
@@ -2579,17 +2653,13 @@ class ContinuousBatchingEngine:
         even after evicting parked prefixes — the YOUNGEST slot is
         preempted: freed blocks un-starve the elders, and the victim
         replays on re-admission."""
-        bs = self.paged.block_size
         for ix in active:
             slot = self._slots[ix]
             if slot is None:
                 continue                     # preempted earlier this pass
-            steps = (self.steps_per_tick if spec_gb is None
-                     else self._spec_steps(slot, spec_gb))
-            end = min(int(self._pos[ix]) + steps,
-                      slot.prompt_len + slot.budget,
-                      self.cfg.max_seq_len)
-            need = min(slot.max_blocks, -(-end // bs))
+            need = self._blocks_needed(
+                ix, slot, self.steps_per_tick if spec_gb is None
+                else self._spec_steps(slot, spec_gb))
             while len(slot.blocks) < need:
                 extra = self._alloc_evicting(need - len(slot.blocks))
                 if extra is not None:
@@ -2759,6 +2829,7 @@ class ContinuousBatchingEngine:
         self._set_table_row(slot_ix, TRASH_BLOCK)
         self._pos[slot_ix] = 0
         self._cur[slot_ix] = 0
+        self._drop_carry()
 
     def _fail_slot(self, slot_ix: int, exc: BaseException) -> None:
         slot = self._slots[slot_ix]
@@ -2784,6 +2855,9 @@ class ContinuousBatchingEngine:
         as the plain emit loop (mid-round stoppers discard the rest of
         their round, like a mid-tick finisher discards its overshoot)."""
         tick_drafted = tick_accepted = 0
+        # The mirrors move by what was accepted, not by a tick's steps:
+        # whatever the device holds of them is stale.
+        self._drop_carry()
         with self.profiler.phase("emit"):
             for ix in active:
                 slot = self._slots[ix]
@@ -2847,6 +2921,72 @@ class ContinuousBatchingEngine:
                 m.spec_accepted.labels(self.tier.name).inc(tick_accepted)
             except Exception:
                 pass
+
+    def _tick_tables(self, last_pos: int):
+        """The block tables for a tick whose furthest slot stands at
+        ``last_pos``: (device array, its width in blocks, whether it had
+        to be uploaded now).  One upload per (table change, rung), not
+        one per tick: ``_set_table_row`` clears the cache."""
+        if self.ragged:
+            # Ragged fused tick: the FULL tables go to one
+            # attention.ragged_decode call with true per-slot lengths —
+            # shape-stable, so exactly ONE compiled decode program
+            # serves the engine's life.
+            wb = self.paged.blocks_per_slot
+            if self._tables_dev is not None:
+                return self._tables_dev, wb, False
+            with self.profiler.phase("table_upload"):
+                self._tables_dev = jnp.asarray(self._tables)
+            return self._tables_dev, wb, True
+        # Dense windowed tick: bound the per-step pool gather by a
+        # bucketed high-water mark over active slots (positions written
+        # this tick stay < window); jit retraces per distinct width, one
+        # compile per bucket crossed as conversations grow.
+        wb = (self._suffix_window(last_pos + self.steps_per_tick)
+              // self.paged.block_size)
+        tables = self._tables_dev_w.get(wb)
+        if tables is not None:
+            return tables, wb, False
+        with self.profiler.phase("table_upload"):
+            # dllm-lint: disable=retrace-dynamic-shape -- bounded by design: wb only takes values from the validated bucket ladder, so this is the dense rung-ladder program family PR 6 documents (ragged mode removes it); the cache above bounds the UPLOADS to one per table change
+            tables = jnp.asarray(self._tables[:, :wb])
+        self._tables_dev_w[wb] = tables
+        return tables, wb, True
+
+    def _blocks_needed(self, ix: int, slot: _Slot, steps: int) -> int:
+        """Blocks slot ``ix``'s table must hold for the positions up to
+        ``steps`` past where it stands, bounded by its own budget."""
+        end = min(int(self._pos[ix]) + steps,
+                  slot.prompt_len + slot.budget, self.cfg.max_seq_len)
+        return min(slot.max_blocks, -(-end // self.paged.block_size))
+
+    def _prepare_ahead(self, active: List[int]) -> None:
+        """Between a plain tick's dispatch and its fetch, while the
+        host would only wait: the blocks the NEXT tick's positions need
+        (the mirrors still stand before the running tick, so that span
+        ends two ticks ahead) and the upload of the table rung it will
+        take.  Only what the allocator gives outright: a dry pool is
+        left to the next pass's ``_plan_and_grow`` with its evictions,
+        cancellations and preemptions.  The running tick holds the
+        table it was given; a slot that ends in it frees these blocks
+        with its others.  Stamped ``prepare``/``table_upload`` like the
+        work it takes off the next pass."""
+        with self.profiler.phase("prepare"):
+            for ix in active:
+                slot = self._slots[ix]
+                if slot is None:
+                    continue
+                short = (self._blocks_needed(ix, slot,
+                                             2 * self.steps_per_tick)
+                         - len(slot.blocks))
+                if short <= 0:
+                    continue
+                extra = self.allocator.alloc(short)
+                if extra is not None:           # else: the pass's own growth
+                    slot.blocks.extend(extra)
+                    self._set_table_row(ix, self._table_row(slot.blocks))
+            self._tick_tables(int(self._pos[active].max())
+                              + self.steps_per_tick)
 
     def _plan_and_grow(self, active: List[int]):
         """The host work a tick needs before its uploads: the
@@ -3081,7 +3221,9 @@ class ContinuousBatchingEngine:
             if active:
                 # Host work before the launch, with the device idle:
                 # the first half of the tick's ``prepare`` phase (the
-                # second is the rng split and the uploads below).
+                # second is whatever upload a writer made necessary,
+                # below; the next tick's growth and table ride in this
+                # tick's shadow, ``_prepare_ahead``).
                 with self.profiler.phase("prepare"):
                     active, spec_gb = self._plan_and_grow(active)
             if not active:
@@ -3124,46 +3266,35 @@ class ContinuousBatchingEngine:
                 continue
 
             chunk_spent, ride_s = 0, 0.0
+            key_in = None
             try:
                 spec_tick = spec_gb is not None
                 with self.profiler.phase("prepare"):
-                    self._rng, rng = jax.random.split(self._rng)
-                    if self.ragged:
-                        # Ragged fused tick: the FULL tables go to one
-                        # attention.ragged_decode call with true
-                        # per-slot lengths — shape-stable, so exactly
-                        # ONE compiled decode program serves the
-                        # engine's life, and the upload is cached until
-                        # a table row changes.
-                        wb = self.paged.blocks_per_slot
-                        if self._tables_dev is None:
-                            with self.profiler.phase("table_upload"):
-                                self._tables_dev = jnp.asarray(self._tables)
-                        tables_arg = self._tables_dev
-                    else:
-                        # Dense windowed tick: bound the per-step pool
-                        # gather by a bucketed high-water mark over
-                        # active slots (positions written this tick stay
-                        # < window); jit retraces per distinct width,
-                        # one compile per bucket crossed as
-                        # conversations grow.
-                        w_need = int(max(self._pos[ix] for ix in active)) \
-                            + self.steps_per_tick
-                        wb = self._suffix_window(w_need) \
-                            // self.paged.block_size
-                        tables_arg = self._tables_dev_w.get(wb)
-                        if tables_arg is None:
-                            # One upload per (table-change, rung), not
-                            # one per tick — same policy as the ragged
-                            # cache.
-                            with self.profiler.phase("table_upload"):
-                                # dllm-lint: disable=retrace-dynamic-shape -- bounded by design: wb only takes values from the validated bucket ladder, so this is the dense rung-ladder program family PR 6 documents (ragged mode removes it); the cache above bounds the UPLOADS to one per table change
-                                tables_arg = jnp.asarray(self._tables[:, :wb])
-                            self._tables_dev_w[wb] = tables_arg
-                    pos_dev = jnp.asarray(self._pos)
-                    cur_dev = jnp.asarray(self._cur)
-                    temps_dev = jnp.asarray(self._temps)
-                    self._sync_state_owner()
+                    if spec_tick:
+                        # The speculative round keeps the host's split
+                        # (and its uploads: ``_emit_spec`` moves the
+                        # mirrors by what was accepted, and drops).
+                        self._rng, rng = jax.random.split(self._rng)
+                    tables_arg, wb, uploaded = self._tick_tables(
+                        int(self._pos[active].max()))
+                    if uploaded:
+                        self._count_prepare_upload("tables")
+                    # The mirrors are the authority: upload what a
+                    # writer dropped since the last tick, and only that.
+                    for what, mirror in (("pos", self._pos),
+                                         ("cur", self._cur),
+                                         ("temps", self._temps)):
+                        if what not in self._carry:
+                            self._carry[what] = self._upload(mirror)
+                            self._count_prepare_upload(what)
+                            uploaded = True
+                    pos_dev, cur_dev, temps_dev = (
+                        self._carry[k] for k in ("pos", "cur", "temps"))
+                    if self._sync_state_owner():
+                        self._count_prepare_upload("owner")
+                        uploaded = True
+                    self.ticks_launched_total += 1
+                    self.ticks_resident_total += int(not uploaded)
                     if spec_tick:
                         gammas = np.zeros(self.paged.max_slots, np.int32)
                         for ix in active:
@@ -3204,13 +3335,21 @@ class ContinuousBatchingEngine:
                     # accounts and emits.
                     with self.profiler.phase("decode"):
                         with self.profiler.phase("dispatch"):
-                            toks, self.pool = self._decode_step()(
+                            # The tick splits the engine's key itself
+                            # and hands back what the next one starts
+                            # from; only ``toks`` is ever fetched.
+                            key_in = self._rng
+                            (toks, self._carry["pos"], self._carry["cur"],
+                             self._rng), self.pool = self._decode_step()(
                                 self.params, self.pool, tables_arg,
-                                pos_dev, cur_dev, temps_dev, rng)
+                                pos_dev, cur_dev, temps_dev, key_in)
                         if self._prefill is not None:
                             t_ride = time.perf_counter()
                             chunk_spent = self._ride_chunk()
                             ride_s = time.perf_counter() - t_ride
+                        # The device runs the tick: what the NEXT tick
+                        # needs and this one's tokens do not decide.
+                        self._prepare_ahead(active)
                         with self.profiler.phase("fetch"):
                             toks = _fetch_tick(toks)           # [T, B]
                     if self._moe is not None:
@@ -3232,9 +3371,15 @@ class ContinuousBatchingEngine:
                         self._moe.note("decode", n_exp)
             except BaseException as exc:
                 # A dead tick must not become a dead scheduler: fail the
-                # in-flight requests and keep serving new ones.
+                # in-flight requests and keep serving new ones.  What it
+                # would have handed the next tick is not to be trusted:
+                # the mirrors are uploaded, and the key moves on as if
+                # the tick had split it.
                 for ix in active:
                     self._fail_slot(ix, exc)
+                self._drop_carry()
+                if key_in is not None:
+                    self._rng = jax.random.split(key_in)[0]
                 self.profiler.commit(len(active))
                 continue
 
@@ -3671,8 +3816,9 @@ class ContinuousBatchingEngine:
     def tick_stats(self) -> Dict[str, Any]:
         """Decode-tick latency quantiles over the recent-tick ring
         (``tick_ms``, maxlen 512) — the read API for the obs state
-        sampler and the bench skew/open-loop legs.  Advisory GIL-safe
-        read of a deque the scheduler thread appends to: a concurrent
+        sampler and the bench skew/open-loop legs — and the engine-life
+        resident share (GET /stats ``tiers.<tier>.tick``).  Advisory
+        GIL-safe read of a deque the scheduler thread appends to: a concurrent
         append can abort one iteration pass (RuntimeError), so retry a
         couple of times and report empty rather than block or raise —
         a telemetry read must never synchronize with the decode loop."""
@@ -3683,8 +3829,18 @@ class ContinuousBatchingEngine:
                 break
             except RuntimeError:
                 continue
+        launched = self.ticks_launched_total
+        resident = self.ticks_resident_total
+        # How often the next tick's inputs were already on the device:
+        # decode ticks launched with no upload in ``prepare``, and the
+        # uploads that were made there, by what.
+        out = {"n": len(ticks), "p50_ms": None, "p95_ms": None,
+               "launched_total": launched, "resident_total": resident,
+               "resident_share": (round(resident / launched, 4)
+                                  if launched else None),
+               "prepare_uploads": dict(self.prepare_uploads_total)}
         if not ticks:
-            return {"n": 0, "p50_ms": None, "p95_ms": None}
+            return out
         # ONE snapshot, ONE sort, reused for every quantile: this runs
         # at the sampler's 4 Hz per tier, and nearest_rank's internal
         # sort per quantile re-sorted the whole 512-entry ring twice
@@ -3697,7 +3853,8 @@ class ContinuousBatchingEngine:
             return round(obs_metrics.nearest_rank(ticks, q,
                                                   presorted=True), 3)
 
-        return {"n": len(ticks), "p50_ms": pct(0.5), "p95_ms": pct(0.95)}
+        out.update(p50_ms=pct(0.5), p95_ms=pct(0.95))
+        return out
 
     def moe_stats(self) -> Optional[Dict[str, Any]]:
         """Cumulative routed-expert load (GET /stats ``moe``), or None
@@ -3891,11 +4048,10 @@ class ContinuousBatchingEngine:
         for w in ([] if self.ragged else self._buckets[1:2]):
             wb = min(w // self.paged.block_size, self.paged.blocks_per_slot)
             self._note_compile("decode", (wb, self._tp_degree()))
-            self._rng, rng = jax.random.split(self._rng)
-            toks, self.pool = self._decode_step()(
+            (toks, _, _, self._rng), self.pool = self._decode_step()(
                 self.params, self.pool, jnp.asarray(self._tables[:, :wb]),
-                jnp.asarray(self._pos), jnp.asarray(self._cur),
-                jnp.asarray(self._temps), rng)
+                self._upload(self._pos), self._upload(self._cur),
+                self._upload(self._temps), self._rng)
             jax.block_until_ready(toks)
             beat()
         if self.spec:

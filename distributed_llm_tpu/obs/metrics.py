@@ -381,6 +381,17 @@ METRIC_REGISTRY: Tuple[Tuple[str, str, str, Tuple[str, ...], str], ...] = (
      "Batched decode ticks, by attention dispatch kind "
      "(ragged_decode|paged_decode[+_q8]) and the impl the "
      "measured table chose (xla|pallas)"),
+    ("tick_prepare_uploads", "counter", "dllm_tick_prepare_uploads_total",
+     ("tier", "what"),
+     "Uploads a decode tick's prepare phase made with the device idle, "
+     "by what (pos|cur|temps: a writer other than the plain emit "
+     "touched the host mirror since the last tick — a slot went live "
+     "or ended, a speculative round, a tick that raised; tables: a row "
+     "changed and the rung was not uploaded in the last tick's shadow; "
+     "owner: the hybrid families' row owners changed).  A tick with "
+     "none of them took everything from the tick before it: GET /stats "
+     "tiers.<tier>.tick.resident_share is that share of "
+     "dllm_decode_ticks_total"),
     ("compiled_programs", "gauge", "dllm_compiled_programs",
      ("tier", "stage"),
      "Distinct compiled XLA programs the batched engine has "
@@ -666,6 +677,8 @@ BOUNDED_LABELS: Dict[str, str] = {
     "policy": "closed set: affinity|affinity_overridden|least_loaded|"
               "random|single|breaker_fallback",
     "direction": "closed set: up|down",
+    "what": "closed set: pos|cur|temps|tables|owner (the decode tick's "
+            "small inputs, engine/batching.py _count_prepare_upload)",
 }
 
 

@@ -136,6 +136,11 @@ def engine_stats(engine) -> Dict[str, Any]:
         # Chunked prefill: what is in flight, and of the chunks
         # dispatched so far how many rode behind an unfetched tick.
         entry["prefill"] = pf_fn()
+    tick_fn = getattr(engine, "tick_stats", None)
+    if callable(tick_fn):
+        # The decode tick: recent latency, and how often its inputs
+        # were already on the device when it was launched.
+        entry["tick"] = tick_fn()
     moe_fn = getattr(engine, "moe_stats", None)
     moe = moe_fn() if callable(moe_fn) else None
     if moe:

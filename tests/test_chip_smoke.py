@@ -83,6 +83,9 @@ def test_serve_and_what_ran_on_the_tiny_cluster(capsys):
         assert row["tick"] == "ragged fused"
         # ... over the whole token-major pool: rows attended merged.
         assert row["decode_attention"] == "merged"
+        # The tiny tiers' replies are a few ticks each; the share of them
+        # that took everything from the tick before is a real number.
+        assert 0.0 < row["tick_resident_share"] <= 1.0
         assert set(row["impl_by_kind"].values()) == {"xla"}
         assert row["compiled_after_requests"]["decode"] == 1
     out = capsys.readouterr().out
