@@ -3645,23 +3645,44 @@ class ContinuousBatchingEngine:
         request once exhausted; multi-byte UTF-8 sequences are held back
         until complete."""
         from .tokenizer import StreamDecoder
+        # The consumer's lane on the tick profiler's timeline
+        # (obs/profiler.py "Edge lanes"), made before the submit: it
+        # notes how far the trace's token timeline is written already.
+        lane = self.profiler.edge_lane(obs_spans.current_trace())
         req = self.submit(history, max_new_tokens, temperature,
                           token_queue=queue.Queue(), tenant=tenant)
 
         def deltas():
             decoder = StreamDecoder(self.tokenizer)
-            while True:
-                tok = req.token_queue.get()
-                if tok is None:
-                    break
-                if tok in (self.tokenizer.eos_id, self.tokenizer.pad_id):
-                    continue
-                text = decoder.feed(tok)
-                if text:
-                    yield text
-            tail = decoder.flush()
-            if tail:
-                yield tail
+            tokens = req.token_queue
+            taken = 0
+            lane.open()
+            try:
+                while True:
+                    # One consumer: what is queued stays queued, so a
+                    # get after a non-empty look never waits.  The awake
+                    # slice closes before a wait and opens after it;
+                    # tokens that were queued extend the open slice.
+                    if tokens.empty():
+                        lane.sleep(taken)
+                        tok = tokens.get()
+                        lane.wake()
+                    else:
+                        tok = tokens.get()
+                    if tok is None:
+                        break
+                    taken += 1
+                    if tok in (self.tokenizer.eos_id,
+                               self.tokenizer.pad_id):
+                        continue
+                    text = decoder.feed(tok)
+                    if text:
+                        yield text
+                tail = decoder.flush()
+                if tail:
+                    yield tail
+            finally:
+                lane.close(taken)
             if req.error is not None:
                 raise req.error
 

@@ -139,6 +139,19 @@ class Histogram:
             self.sum += v
             self.count += 1
 
+    def merge(self, counts: Sequence[int], total: float) -> None:
+        """Add observations that were bucketed elsewhere over the same
+        ladder (``counts``: one a bucket and the +Inf tail; ``total``:
+        their sum) — for a writer that may not take a lock an
+        observation (obs/profiler.py edge lanes)."""
+        if len(counts) != len(self.counts):
+            raise ValueError("merge: bucket ladders differ")
+        with self._lock:
+            for ix, c in enumerate(counts):
+                self.counts[ix] += c
+            self.sum += total
+            self.count += sum(counts)
+
     def quantile(self, q: float) -> Optional[float]:
         """Bucket-interpolated quantile estimate (None when empty).
         Matches PromQL histogram_quantile: linear within the winning
@@ -575,6 +588,47 @@ METRIC_REGISTRY: Tuple[Tuple[str, str, str, Tuple[str, ...], str], ...] = (
      "self-times partition the scheduler's stamped time, so phases "
      "add).  Per decode tick: divide a delta by the delta of "
      "dllm_decode_ticks_total summed over kind and impl"),
+    ("tick_phase_cpu_ms", "counter", "dllm_tick_phase_cpu_ms_total",
+     ("tier", "phase"),
+     "Lifetime SELF CPU time of one scheduler phase in ms: the "
+     "scheduler thread's own CPU clock (time.thread_time) read "
+     "beside every stamp of dllm_tick_phase_ms_total and exported "
+     "with it.  A phase's ms_total minus this is the time the "
+     "thread stood in the phase without running: blocked in a "
+     "call (fetch, idle_wait), or waiting for the interpreter's "
+     "lock or for a core.  Where a reading of that clock costs "
+     "over 2 us (a sandboxed kernel) one scheduler pass in five "
+     "reads it and counts fivefold: an estimate there"),
+    ("sched_runqueue_wait_ms", "counter",
+     "dllm_sched_runqueue_wait_ms_total", ("tier",),
+     "Time the tier's scheduler thread stood runnable with no "
+     "core (second field of /proc/thread-self/schedstat, read "
+     "once a scheduler pass): the part of the scheduler's off-CPU "
+     "time that is the machine's, not the interpreter's.  Absent "
+     "where that file cannot be read"),
+    ("edge_awake_ms", "counter", "dllm_edge_awake_ms_total",
+     ("tier", "clock"),
+     "Time the tier's stream consumer threads spent awake, from a "
+     "token_queue.get that had to wait returning to the thread's "
+     "next wait (decoder, turn clipper, SSE framing and whatever "
+     "the consumer does per delta): clock=wall by perf_counter; "
+     "clock=cpu the growth of each thread's own CPU clock, read "
+     "once a second a stream and at its end (a thread that waits "
+     "uses none, so it is the slices' CPU and the wake-ups' own) "
+     "— interpreter time the scheduler thread could not have"),
+    ("edge_wakeups", "counter", "dllm_edge_wakeups_total", ("tier",),
+     "Awake slices of the tier's stream consumer threads: one per "
+     "get that had to wait"),
+    ("edge_tokens", "counter", "dllm_edge_tokens_total", ("tier",),
+     "Tokens the tier's stream consumers took off their queues; "
+     "over dllm_edge_wakeups_total it is the tokens a wake-up "
+     "(decode_steps_per_tick = one wake a slot a tick, near 1 = a "
+     "wake a put)"),
+    ("edge_wake_lag_ms", "histogram", "dllm_edge_wake_lag_ms", ("tier",),
+     "From the engine's stamp of an awake slice's first token "
+     "(the trace's token timeline) to the slice's start: how long "
+     "a token that exists waits for its stream's thread to run; "
+     "one observation per awake slice that took a token"),
     ("profile_coverage_g", "gauge", "dllm_profile_coverage", ("tier",),
      "Fraction of tick wall time covered by stamped phase self-"
      "times (sampled; the bench profile leg pins >= 0.95)"),
@@ -679,6 +733,7 @@ BOUNDED_LABELS: Dict[str, str] = {
     "direction": "closed set: up|down",
     "what": "closed set: pos|cur|temps|tables|owner (the decode tick's "
             "small inputs, engine/batching.py _count_prepare_upload)",
+    "clock": "closed set: wall|cpu",
 }
 
 

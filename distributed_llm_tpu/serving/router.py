@@ -164,10 +164,11 @@ class Router:
                 period_s=sample_ms / 1000.0,
                 capacity=env_int("DLLM_OBS_TIMELINE_SAMPLES", 240))
 
-        # What export_tick_totals last saw per (engine label, phase),
-        # so dllm_tick_phase_ms_total survives an engine rebuilt from 0.
+        # What export_tick_totals last saw per (family, engine label,
+        # further labels), so its counters survive an engine rebuilt
+        # from 0.
         self._tick_totals_lock = threading.Lock()
-        self._tick_totals_seen: Dict[Tuple[str, str], float] = {}
+        self._tick_totals_seen: Dict[Tuple[Any, ...], float] = {}
 
         # Bounded per-(tier, strategy, session) cost ledger (ISSUE 11):
         # the GET /stats-inspectable aggregate of the attribution the
@@ -656,26 +657,55 @@ class Router:
         return obs_profiler.chrome_trace(by_tier, since=since, until=until)
 
     def export_tick_totals(self) -> None:
-        """Raise ``dllm_tick_phase_ms_total{tier,phase}`` to the tick
-        profilers' lifetime self-time totals.  Called from the
-        sampler's collect and from ``GET /metrics``, so a scrape reads
-        the totals as of the scrape and nothing is added to the tick
-        path.  The counter never falls: a total below the last one seen
-        under the same label (an engine rebuilt from 0) counts from
-        there again."""
-        fam = self.obs.m.tick_phase_ms
+        """Raise the counters that mirror the tick profilers' lifetime
+        totals: ``dllm_tick_phase_ms_total`` and
+        ``dllm_tick_phase_cpu_ms_total`` ``{tier,phase}`` (self wall and
+        self CPU), ``dllm_sched_runqueue_wait_ms_total``, and the edge
+        lanes' ``dllm_edge_awake_ms_total{clock}``,
+        ``dllm_edge_wakeups_total``, ``dllm_edge_tokens_total`` and
+        ``dllm_edge_wake_lag_ms``.  Called from the sampler's collect
+        and from ``GET /metrics``, so a scrape reads the totals as of
+        the scrape and nothing is added to the tick path or to a
+        stream's consumer.  A counter never falls: a total below the
+        last one seen under the same label (an engine rebuilt from 0)
+        counts from there again."""
+        m = self.obs.m
         with self._tick_totals_lock:
             for label, prof in self._live_profilers():
                 try:
-                    totals = prof.self_totals()
+                    edge = prof.edge_totals()
+                    runq = prof.runqueue_wait_ms()
+                    rows = [(m.tick_phase_ms, (phase,), total) for
+                            phase, total in prof.self_totals().items()]
+                    rows += [(m.tick_phase_cpu_ms, (phase,), total) for
+                             phase, total in prof.cpu_totals().items()]
                 except Exception:
                     continue
-                for phase, total in totals.items():
-                    seen = self._tick_totals_seen.get((label, phase), 0.0)
-                    delta = total - seen if total >= seen else total
-                    self._tick_totals_seen[(label, phase)] = total
+                rows += [(m.edge_awake_ms, ("wall",), edge["wall_ms"]),
+                         (m.edge_awake_ms, ("cpu",), edge["cpu_ms"]),
+                         (m.edge_wakeups, (), edge["wakeups"]),
+                         (m.edge_tokens, (), edge["tokens"])]
+                if runq is not None:
+                    rows.append((m.sched_runqueue_wait_ms, (), runq))
+                for fam, rest, total in rows:
+                    delta = self._unseen(total, fam.name, label, *rest)
                     if delta > 0:
-                        fam.labels(label, phase).inc(delta)
+                        fam.labels(label, *rest).inc(delta)
+                # The wake-lag histogram, bucket growth by bucket growth
+                # (the lanes bucket it themselves: no lock a slice).
+                counts = [self._unseen(n, "edge_lag_bucket", label, ix)
+                          for ix, n in enumerate(edge["lag_counts"])]
+                lag_ms = self._unseen(edge["lag_ms"], "edge_lag_ms", label)
+                if any(counts):
+                    m.edge_wake_lag_ms.labels(label).merge(counts, lag_ms)
+
+    def _unseen(self, total: float, *key: Any) -> float:
+        """Growth of a lifetime total since ``export_tick_totals`` last
+        saw it under ``key``; all of it after a fall.  Caller holds
+        ``_tick_totals_lock``."""
+        seen = self._tick_totals_seen.get(key, 0)
+        self._tick_totals_seen[key] = total
+        return total - seen if total >= seen else total
 
     def _obs_state_snapshot(self) -> Dict[str, Any]:
         """Cheap serving-state snapshot attached to flight-recorder

@@ -1,40 +1,115 @@
 """Run one benchmark cell (``benchmark/run.py``, same arguments) and,
 before the cluster drains, print what the result line does not carry:
 each tier's GET /stats ``tick`` and ``prefill`` blocks (the resident
-share of PR 36, the riding chunks of PR 32).
+share of PR 36, the riding chunks of PR 32) and, from ``/metrics``
+before the traffic and after it, who held the interpreter (PR 41): the
+scheduler's CPU / off-CPU / run-queue milliseconds a tick, every
+phase's self wall beside its self CPU, and the edge lanes' block, by the
+benchmark's own readers
+(``benchmark/layer_metrics/host_readers.py``), so an untraced run shows
+them too; and, from ``/debug/trace`` (the rings' last two minutes), the
+scheduler phase that stamped each awake slice's first token.
 
     python3 scripts/bench_stats.py --workload smollm2-1.7b.decode-closed \
         --seed 7 --seconds 50 --trace 0
 
 From the root of a checkout, on the chip.  Nothing of ``benchmark/`` is
-edited: ``cluster.Served.drain`` is wrapped in this process only.
+edited: ``cluster.Served.drain`` and ``drive.Run.run`` are wrapped in
+this process only.
 """
 
 import json
 import os
 import runpy
 import sys
+import types
 
 ROOT = os.getcwd()
 sys.path[:0] = [ROOT, os.path.join(ROOT, "benchmark")]
 
 import cluster                                   # noqa: E402
+import drive                                     # noqa: E402
+from layer_metrics import host_readers, span_readers      # noqa: E402
 
 _drain = cluster.Served.drain
+_run = drive.Run.run
+_before = {}
+
+HOST = (("host_cpu_ms_per_tick", host_readers.host_cpu_ms_per_tick),
+        ("host_off_cpu_ms_per_tick", host_readers.host_off_cpu_ms_per_tick),
+        ("fetch_cpu_ms_per_tick", lambda ctx, tier:
+            host_readers.phase_cpu_ms_per_tick(ctx, tier, "fetch")),
+        ("runqueue_wait_ms_per_tick", host_readers.runqueue_wait_ms_per_tick))
+EDGE = (("awake_cpu_ms_per_tick", host_readers.edge_awake_cpu_ms_per_tick),
+        ("tokens_per_wakeup", host_readers.tokens_per_wakeup),
+        ("wake_lag_ms_mean", lambda ctx, tier: span_readers.histogram_mean(
+            ctx, "dllm_edge_wake_lag_ms", tier)),
+        ("wakeups", lambda ctx, tier: span_readers._delta(
+            ctx, "dllm_edge_wakeups_total", tier=tier)))
+
+
+def edge_causes(doc, tier):
+    """Awake slices of the tier's edge lanes by the scheduler phase in
+    whose self-time their first token was stamped (``none``: under no
+    slice the ring still holds); slices that took no token left out."""
+    origin = (doc.get("metadata") or {}).get("ts_origin_perf_counter_s")
+    slices = span_readers.tier_slices(doc, tier)
+    if origin is None or not slices:
+        return None
+    phases = span_readers.self_intervals(slices)
+    lanes = host_readers.edge_lane_tids(doc, tier)
+    stamps = sorted(origin + (e["ts"] - 1e3 * e["args"]["wake_lag_ms"]) / 1e6
+                    for e in doc["traceEvents"] if e["ph"] == "X"
+                    and e["tid"] in lanes and "wake_lag_ms" in e["args"])
+    out, i = {}, 0
+    for t in stamps:
+        while i < len(phases) and phases[i][2] < t - 1e-6:
+            i += 1
+        hit = i < len(phases) and phases[i][1] - 1e-6 <= t
+        name = phases[i][0] if hit else "none"
+        out[name] = out.get(name, 0) + 1
+    return out
+
+
+def phase_split(ctx, tier):
+    """Self wall | self CPU milliseconds a decode tick of every phase
+    the scheduler stamped in the run."""
+    phases = sorted({lab["phase"] for lab, _ in span_readers._series(
+        ctx.metrics_after, host_readers.CPU) if lab.get("tier") == tier})
+    return {p: [round(host_readers._per_tick(ctx, tier, fam, phase=p) or 0.0,
+                      4) for fam in (host_readers.WALL, host_readers.CPU)]
+            for p in phases}
+
+
+def _run_after_metrics(self, *args, **kw):
+    _before["metrics"] = self.client.get("/metrics").text
+    return _run(self, *args, **kw)
 
 
 def _drain_after_stats(self) -> None:
     try:
         tiers = self.get_json("/stats").get("tiers", {})
+        ctx = types.SimpleNamespace(
+            metrics_before=_before.get("metrics", ""),
+            metrics_after=self.client.get("/metrics").text)
         for name in self.entries:
             block = tiers.get(name, {})
             for key in ("tick", "prefill"):
                 print(f"[bench:stats] tiers.{name}.{key} = "
                       f"{json.dumps(block.get(key))}", flush=True)
+            for key, rows in (("host", HOST), ("edge", EDGE)):
+                print(f"[bench:stats] tiers.{name}.{key} = " + json.dumps(
+                    {k: fn(ctx, name) for k, fn in rows}), flush=True)
+            print(f"[bench:stats] tiers.{name}.phase_wall_cpu = "
+                  + json.dumps(phase_split(ctx, name)), flush=True)
+            print(f"[bench:stats] tiers.{name}.edge_causes = " + json.dumps(
+                edge_causes(self.get_json("/debug/trace"), name)),
+                flush=True)
     finally:
         _drain(self)
 
 
 cluster.Served.drain = _drain_after_stats
+drive.Run.run = _run_after_metrics
 sys.argv = [os.path.join("benchmark", "run.py")] + sys.argv[1:]
 runpy.run_path(sys.argv[0], run_name="__main__")

@@ -186,8 +186,8 @@ def _enclosed(rec, child, parent):
     """Every ``child`` span of a tick record lies inside one of its
     ``parent`` spans."""
     spans = rec["spans"]
-    parents = [(rel, rel + dur) for n, rel, dur, _ in spans if n == parent]
-    kids = [(rel, rel + dur) for n, rel, dur, _ in spans if n == child]
+    parents = [(rel, rel + dur) for n, rel, dur, *_ in spans if n == parent]
+    kids = [(rel, rel + dur) for n, rel, dur, *_ in spans if n == child]
     return all(any(a - 1e-6 <= lo and hi <= b + 1e-6 for a, b in parents)
                for lo, hi in kids), kids
 
@@ -316,14 +316,12 @@ def test_tick_phase_counter_equals_the_profilers_totals(timeline_app):
     second = _phase_counter(obs)
     assert all(second[k] >= v for k, v in first.items())
 
-    class _Rebuilt:
-        enabled = True
-
+    class _Rebuilt(P.TickProfiler):
         def self_totals(self):
             return {"emit": 0.25}
 
     real = router._live_profilers
-    router._live_profilers = lambda: iter([("nano", _Rebuilt())])
+    router._live_profilers = lambda: iter([("nano", _Rebuilt("nano"))])
     try:
         router.export_tick_totals()
         router.export_tick_totals()
