@@ -95,6 +95,17 @@ def _fetch_tick(x):
     return jax.tree_util.tree_map(np.asarray, jax.block_until_ready(x))
 
 
+def _window_ladder(span: int, block_size: int, doubling: bool) -> List[int]:
+    """Attention-window rungs of a chunk program over a table of
+    ``span`` positions: 256 and 1024, with ``doubling`` also 2048,
+    4096, ... below the span, block-aligned, then the span itself."""
+    rungs = [256, 1024]
+    while doubling and rungs[-1] * 2 < span:
+        rungs.append(rungs[-1] * 2)
+    return sorted({-(-c // block_size) * block_size
+                   for c in rungs if c < span} | {span})
+
+
 # Per-slot acceptance-rate-adaptive γ (ISSUE 15): EWMA weight of a
 # round's observed acceptance, and the floor under which a slot stops
 # speculating entirely (γ=0 — it rides the verify's first row only, i.e.
@@ -489,19 +500,29 @@ class ContinuousBatchingEngine:
         self._decode_fn = None
         self._buckets = sorted(set(
             b for b in tier.prefill_buckets if b <= self.cfg.max_seq_len))
-        # Suffix-chunk attention windows use a COARSE rung set (same
-        # philosophy as the sequential engine's cache ladder): the chunk
-        # runs once per admission, so a wider gather costs one extra
-        # decode-tick's worth of reads, while a fine ladder multiplies
-        # compiled (sb, window) programs past what warmup can cover —
-        # each miss is a mid-chat XLA trace on the admit path.  The
-        # decode tick keeps the FINE bucket ladder (its gather runs every
-        # tick, where window width is real bandwidth).
+        # Two ladders of attention windows for the chunk programs, one a
+        # path, both block-aligned and both ending in the span (the
+        # slot's whole table, which a chunk slid back against the
+        # table's end takes):
+        # - ``_reuse_windows``, the prefix-reuse SUFFIX chunk's (_admit),
+        #   is COARSE, {256, 1024, span}: that chunk runs once per
+        #   admission, so a wider gather costs one extra decode-tick's
+        #   worth of reads, while a finer ladder multiplies the (reuse
+        #   bucket, window) programs warm-up must cover in full — each
+        #   miss is a mid-chat XLA trace on the admit path.
+        # - ``_chunk_windows``, the chunked-prefill LANE's
+        #   (_dispatch_chunk), DOUBLES from 1024 to the span: the lane
+        #   runs a chunk every ``chunk_tokens`` of every long prompt, so
+        #   the window is real reads and products there, and past 1024 a
+        #   chunk attends less than twice what is written.  One more
+        #   (chunk_tokens, window) program a doubling, all warmed.
+        # The decode tick keeps the FINE bucket ladder (its gather runs
+        # every tick).
         span = self.paged.blocks_per_slot * self.paged.block_size
-        bs = self.paged.block_size
-        self._chunk_windows = sorted(
-            {min(span, -(-c // bs) * bs)          # block-aligned rungs
-             for c in (256, 1024) if c < span} | {span})
+        self._reuse_windows = _window_ladder(
+            span, self.paged.block_size, doubling=False)
+        self._chunk_windows = _window_ladder(
+            span, self.paged.block_size, doubling=True)
         # Suffix buckets an admit will REUSE a prefix for: the first
         # three rungs cover typical chat turns; a longer new turn goes
         # through the (warmed) cold-prefill path instead of minting ever
@@ -545,6 +566,12 @@ class ContinuousBatchingEngine:
         # write and no deeper (models/shared_kv_hybrid.py).
         self.prefill_self_only_chunks_total = 0
         self.prefill_chunks_overlapped_total = 0
+        # Positions the lane's chunks attended (their window rungs) over
+        # positions written when each ran (its end, capped at the
+        # prompt): how close the rung ladder keeps a chunk's gather to
+        # what is there (prefill_stats' ``window_over_written``).
+        self.prefill_window_positions_total = 0
+        self.prefill_written_positions_total = 0
         # perf_counter of the last plain tick's fetch return: the
         # latest moment the host saw the device reach whatever was
         # queued behind that tick (``_settle_chunk``'s clock).
@@ -1873,7 +1900,7 @@ class ContinuousBatchingEngine:
                 row = self._table_row(owned)
                 tokens = np.full((1, sb), self.tokenizer.pad_id, np.int32)
                 tokens[0, :len(suffix)] = suffix
-                window = next(w for w in self._chunk_windows
+                window = next(w for w in self._reuse_windows
                               if w >= m + sb)
                 with obs_spans.span(req.trace, "prefill", reused_tokens=m,
                                     suffix_bucket=sb), \
@@ -2282,6 +2309,9 @@ class ContinuousBatchingEngine:
             self.cfg, end, start, wbytes=self._wbytes))
         self.prefill_chunks_total += 1
         self.prefill_chunks_overlapped_total += int(overlapped)
+        written = min(end, pf.total)
+        self.prefill_window_positions_total += window
+        self.prefill_written_positions_total += written
         self_only = self.cfg.shared_kv and end < pf.total
         self.prefill_self_only_chunks_total += int(self_only)
         try:
@@ -2292,11 +2322,13 @@ class ContinuousBatchingEngine:
             m.prefill_chunks.labels(
                 self.tier.name,
                 "behind_tick" if overlapped else "alone").inc()
+            m.prefill_window_positions.labels(self.tier.name).inc(window)
+            m.prefill_written_positions.labels(self.tier.name).inc(written)
             if self_only:
                 m.prefill_self_only_chunks.labels(self.tier.name).inc()
         except Exception:
             pass
-        pf.consumed = min(end, pf.total)
+        pf.consumed = written
         pf.chunks_done += 1
         return True
 
@@ -3971,18 +4003,26 @@ class ContinuousBatchingEngine:
         the ``dllm_prefill_backlog`` gauge samples), chunk progress, the
         engine-life cancel count, and how often a chunk rode behind an
         unfetched tick (``chunks_overlapped_total`` of ``chunks_total``;
-        ``overlap_share`` None before the first chunk).  Advisory
+        ``overlap_share`` None before the first chunk), and the positions
+        the chunks attended over the positions written when they ran
+        (``window_over_written``, None before the first chunk).  Advisory
         GIL-safe reads of state the scheduler thread owns — same
         discipline as slot_stats."""
         pf = self._prefill
         chunks = self.prefill_chunks_total
         overlapped = self.prefill_chunks_overlapped_total
+        attended = self.prefill_window_positions_total
+        written = self.prefill_written_positions_total
         out = {"inflight": 0, "backlog_tokens": 0, "chunks_done": 0,
                "cancelled_total": self.prefill_cancelled_total,
                "chunks_total": chunks,
                "chunks_overlapped_total": overlapped,
                "overlap_share": (round(overlapped / chunks, 4)
-                                 if chunks else None)}
+                                 if chunks else None),
+               "window_positions_total": attended,
+               "written_positions_total": written,
+               "window_over_written": (round(attended / written, 4)
+                                       if written else None)}
         if self.cfg.shared_kv:
             out["chunks_self_only_total"] = \
                 self.prefill_self_only_chunks_total
@@ -4131,7 +4171,7 @@ class ContinuousBatchingEngine:
             # can hit — the coarse ladders keep this product small enough
             # to warm completely (no mid-chat admit compiles).
             for sb in self._reuse_buckets:
-                for window in self._chunk_windows:
+                for window in self._reuse_windows:
                     if window < sb + 1:
                         continue
                     self._rng, rng = jax.random.split(self._rng)
@@ -4158,10 +4198,11 @@ class ContinuousBatchingEngine:
         if (self.chunk_tokens and self._buckets
                 and max(self._buckets) > self.chunk_tokens):
             # The cold-chunk program family: one (chunk_tokens, window)
-            # program per window rung a chunked admission can cross —
-            # with the coarse rung set this is ≤3 programs, so a long
-            # prompt arriving mid-serve never pays an XLA trace on the
-            # interleave path it exists to keep smooth.
+            # program per rung of the lane's ladder (at an 8192 span 5
+            # programs), ALL of them: a replayed or top-bucket prompt
+            # reaches every rung, so a long prompt arriving mid-serve
+            # never pays an XLA trace on the interleave path it exists
+            # to keep smooth.
             c = self.chunk_tokens
             row = self._table_row([])
             for window in self._chunk_windows:

@@ -438,6 +438,17 @@ METRIC_REGISTRY: Tuple[Tuple[str, str, str, Tuple[str, ...], str], ...] = (
      "cached layer's K/V write and no deeper "
      "(models/shared_kv_hybrid.py): the layers after it feed a prompt "
      "position's own logits only"),
+    ("prefill_window_positions", "counter",
+     "dllm_prefill_window_positions_total", ("tier",),
+     "Positions the chunked-prefill lane's chunks attended: each "
+     "dispatched chunk's window rung (the smallest of 256, 1024, 2048, "
+     "4096, ... and the slot's span that holds the chunk's end)"),
+    ("prefill_written_positions", "counter",
+     "dllm_prefill_written_positions_total", ("tier",),
+     "Positions written when each of those chunks ran (its end, capped "
+     "at the prompt's length): dllm_prefill_window_positions_total over "
+     "this is /stats prefill.window_over_written, under 2 past position "
+     "1024 and 1 for a ladder that followed the prompt exactly"),
     # Routed-expert family (models/latent_moe.py): what the tick and
     # the chunk program count beside their tokens — how many expert
     # assignments a stage computed, and how many experts those touched

@@ -8,7 +8,10 @@ phase's self wall beside its self CPU, and the edge lanes' block, by the
 benchmark's own readers
 (``benchmark/layer_metrics/host_readers.py``), so an untraced run shows
 them too; and, from ``/debug/trace`` (the rings' last two minutes), the
-scheduler phase that stamped each awake slice's first token.
+scheduler phase that stamped each awake slice's first token.  With
+``--trace 1``, the chunk programs' device time by COMPILED PROGRAM (one a
+window rung, PR 42): the whole ``jit_chunk_prefill`` executions of the
+device trace grouped by the fingerprint in their name.
 
     python3 scripts/bench_stats.py --workload smollm2-1.7b.decode-closed \
         --seed 7 --seconds 50 --trace 0
@@ -29,10 +32,13 @@ sys.path[:0] = [ROOT, os.path.join(ROOT, "benchmark")]
 
 import cluster                                   # noqa: E402
 import drive                                     # noqa: E402
-from layer_metrics import host_readers, span_readers      # noqa: E402
+import tracing                                   # noqa: E402
+from layer_metrics import (host_readers, named_readers,   # noqa: E402
+                           span_readers)
 
 _drain = cluster.Served.drain
 _run = drive.Run.run
+_load = tracing.load
 _before = {}
 
 HOST = (("host_cpu_ms_per_tick", host_readers.host_cpu_ms_per_tick),
@@ -81,6 +87,36 @@ def phase_split(ctx, tier):
             for p in phases}
 
 
+def chunk_programs(trace):
+    """Whole ``jit_chunk_prefill`` executions of every device by compiled
+    program, shortest first: ``[fingerprint, executions, mean ms, least,
+    most]``.  The engine compiles one chunk program a window rung, so
+    with one chunk width the rows ARE the rungs, in order."""
+    by_name = {}
+    for dev in trace["devices"].values():
+        for m in dev["modules"]:
+            if m[0].startswith("jit_chunk_prefill("):
+                by_name.setdefault(m[0], []).append(m)
+    rows = []
+    for name, modules in by_name.items():
+        ms = [d / 1e6 for _, d in named_readers.executions(
+            {"modules": modules}, "chunk_prefill",
+            trace["t_lo"], trace["t_hi"])]
+        if ms:
+            rows.append([name[name.index("(") + 1:-1], len(ms),
+                         round(sum(ms) / len(ms), 3), round(min(ms), 3),
+                         round(max(ms), 3)])
+    return sorted(rows, key=lambda r: r[2])
+
+
+def _load_and_print(path):
+    trace = _load(path)
+    print("[bench:stats] chunk_prefill executions by program "
+          "[fingerprint, n, mean_ms, min_ms, max_ms] = "
+          + json.dumps(chunk_programs(trace)), flush=True)
+    return trace
+
+
 def _run_after_metrics(self, *args, **kw):
     _before["metrics"] = self.client.get("/metrics").text
     return _run(self, *args, **kw)
@@ -111,5 +147,6 @@ def _drain_after_stats(self) -> None:
 
 cluster.Served.drain = _drain_after_stats
 drive.Run.run = _run_after_metrics
+tracing.load = _load_and_print
 sys.argv = [os.path.join("benchmark", "run.py")] + sys.argv[1:]
 runpy.run_path(sys.argv[0], run_name="__main__")

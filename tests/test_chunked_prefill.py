@@ -750,3 +750,295 @@ def test_overlap_counter(ragged):
         assert alone.value - alone0 == solo_chunks
     finally:
         eng.stop()
+
+
+# -- the lane's window ladder doubles (ISSUE 42) -------------------------------
+#
+# The tiny presets stop at 256 positions, where both ladders are {256}:
+# these cases serve the same tiny models over longer tables.
+
+def _spanned_tier(monkeypatch, preset, span, **kw):
+    """A tier of ``preset``'s model over a table of ``span`` positions
+    (blocks of 16), one slot's worth of blocks and a few to spare."""
+    from distributed_llm_tpu.config import MODEL_PRESETS, TierConfig
+
+    model = {k: kw.pop(k) for k in ("attn_window", "tokenizer", "vocab_size")
+             if k in kw}
+    cfg = dataclasses.replace(MODEL_PRESETS[preset],
+                              name=f"{preset}_{span}", max_seq_len=span,
+                              **model)
+    monkeypatch.setitem(MODEL_PRESETS, cfg.name, cfg)
+    defaults = dict(
+        name="nano", model_preset=cfg.name, decode_batch=2,
+        kv_block_size=16, prefill_buckets=(32, 64, 128, 512, 2048),
+        prefill_chunk_tokens=256, max_new_tokens=4,
+        enable_prefix_cache=False, kv_pool_blocks=span // 16 + 8)
+    defaults.update(kw)
+    return TierConfig(**defaults)
+
+
+def _prefill_by_hand(eng, ids, trace=None):
+    """Chunk ``ids`` into slot 0 one settled chunk at a time (no
+    scheduler thread in the way).  Gives the in-flight prefill and the
+    token its last chunk sampled; the caller cancels it."""
+    req = _Request(history="x", max_new_tokens=None, temperature=None,
+                   trace=trace)
+    eng._start_prefill(req, 0, list(ids), len(ids), 2048, 4)
+    pf = eng._prefill
+    first = None
+    while pf.consumed < pf.total:
+        assert eng._dispatch_chunk(pf, overlapped=False)
+        first = eng._settle_chunk(pf)
+    return pf, int(first)
+
+
+def _fake_chunk_programs(eng):
+    """Stand-ins for the chunk programs: each is noted as compiled under
+    its (bucket, window) key, hands the pool back and computes nothing.
+    Gives the list the calls' keys are appended to."""
+    import jax.numpy as jnp
+    called = []
+
+    def fake(bucket, window):
+        eng._note_compile("chunk_prefill", (bucket, window))
+
+        def call(params, pool, *args):
+            called.append((bucket, window))
+            return jnp.int32(7), pool
+        return call
+    eng._chunk_prefill_fn = fake
+    return called
+
+
+@pytest.mark.parametrize("span,lane,reuse", [
+    (512, [256, 512], [256, 512]),
+    (1024, [256, 1024], [256, 1024]),
+    (2048, [256, 1024, 2048], [256, 1024, 2048]),
+    (4096, [256, 1024, 2048, 4096], [256, 1024, 4096]),
+    (5120, [256, 1024, 2048, 4096, 5120], [256, 1024, 5120]),
+    (8192, [256, 1024, 2048, 4096, 8192], [256, 1024, 8192]),
+])
+def test_window_ladders_by_span(span, lane, reuse):
+    """The lane's rungs double from 1024 to the span; the prefix-reuse
+    path keeps {256, 1024, span}; both block-aligned whatever the block."""
+    from distributed_llm_tpu.engine.batching import _window_ladder
+
+    for bs in (16, 64):
+        assert _window_ladder(span, bs, doubling=True) == lane
+        assert _window_ladder(span, bs, doubling=False) == reuse
+    # A block that does not divide a rung rounds it up to whole blocks.
+    assert _window_ladder(3 * 1280, 80, doubling=True) == [
+        320, 1040, 2080, 3840]
+
+
+def test_engine_derives_both_ladders_from_its_span(monkeypatch):
+    eng = ContinuousBatchingEngine(
+        _spanned_tier(monkeypatch, "nano_test", 8192), seed=11)
+    try:
+        assert eng._chunk_windows == [256, 1024, 2048, 4096, 8192]
+        assert eng._reuse_windows == [256, 1024, 8192]
+    finally:
+        eng.stop()
+
+
+def test_a_1792_token_prompt_climbs_the_ladder(monkeypatch):
+    """Seven chunks of 256 over an 8192 table: no chunk attends the span,
+    and the engine's counters say 1.32 positions attended a position
+    written (3.89 on the three-rung ladder) in prefill_stats, /stats'
+    engine block and the registry /metrics renders."""
+    from distributed_llm_tpu.obs import get_observability
+    from distributed_llm_tpu.obs.spans import RequestTrace
+    from distributed_llm_tpu.utils.telemetry import engine_stats
+
+    m = get_observability().m
+    attended = m.prefill_window_positions.labels("nano")
+    written = m.prefill_written_positions.labels("nano")
+    attended0, written0 = attended.value, written.value
+    eng = ContinuousBatchingEngine(
+        _spanned_tier(monkeypatch, "nano_test", 8192), seed=11)
+    try:
+        st = eng.prefill_stats()
+        assert (st["window_positions_total"], st["written_positions_total"],
+                st["window_over_written"]) == (0, 0, None)
+        trace = RequestTrace("req-1792")
+        ids = [5 + i % 200 for i in range(1792)]
+        _prefill_by_hand(eng, ids, trace=trace)
+        eng._cancel_prefill("test")
+        spans = [c for c in trace.root.children
+                 if c.name == "prefill_chunk"]
+        assert [s.attrs["window"] for s in spans] == [
+            256, 1024, 1024, 1024, 2048, 2048, 2048]
+        assert [s.attrs["start"] for s in spans] == list(range(0, 1792, 256))
+        assert eng._compiled["chunk_prefill"] == {
+            (256, 256), (256, 1024), (256, 2048)}
+        st = eng.prefill_stats()
+        assert st["window_positions_total"] == 256 + 3 * 1024 + 3 * 2048
+        assert st["written_positions_total"] == 7168
+        assert st["window_over_written"] == round(9472 / 7168, 4) == 1.3214
+        assert engine_stats(eng)["prefill"]["window_over_written"] == 1.3214
+        assert attended.value - attended0 == 9472
+        assert written.value - written0 == 7168
+        text = get_observability().metrics.render()
+        assert 'dllm_prefill_window_positions_total{tier="nano"}' in text
+        assert 'dllm_prefill_written_positions_total{tier="nano"}' in text
+        # A prompt that ends inside its last chunk is written to its own
+        # length, not to the chunk's end.
+        _prefill_by_hand(eng, ids[:300])
+        eng._cancel_prefill("test")
+        st = eng.prefill_stats()
+        assert st["written_positions_total"] == 7168 + 256 + 300
+        assert st["window_positions_total"] == 9472 + 256 + 1024
+    finally:
+        eng.stop()
+
+
+def test_slid_back_sliver_takes_the_span(monkeypatch):
+    """A chunk that would overrun the table is slid back against its end:
+    it ends at the span, and the span's rung is the one that holds it."""
+    eng = ContinuousBatchingEngine(_spanned_tier(
+        monkeypatch, "nano_test", 4096, prefill_chunk_tokens=768), seed=11)
+    try:
+        called = _fake_chunk_programs(eng)
+        req = _Request(history="x", max_new_tokens=None, temperature=None)
+        eng._start_prefill(req, 0, [5] * 4000, 4000, 2048, 4)
+        pf = eng._prefill
+        pf.consumed = 5 * 768                  # 3840 + 768 > 4096
+        assert eng._dispatch_chunk(pf, overlapped=False)
+        assert called == [(768, 4096)]
+        assert pf.consumed == pf.total == 4000
+        st = eng.prefill_stats()
+        assert (st["window_positions_total"],
+                st["written_positions_total"]) == (4096, 4000)
+        eng._cancel_prefill("test")
+    finally:
+        eng.stop()
+
+
+@pytest.mark.parametrize("preset,model", [
+    ("nano_test", {}), ("latent_test", {}), ("hybrid_test", {}),
+    ("shared_kv_test", {"attn_window": 256}),
+], ids=["dense", "latent", "hybrid", "shared_kv"])
+def test_doubling_ladder_changes_nothing_but_the_window(monkeypatch, preset,
+                                                        model):
+    """A prompt past 1024 on the lane's ladder (its last chunk at the
+    2048 rung) against an engine forced to the old three rungs (the same
+    chunk at the span, 4096): the same first token from the same logits,
+    and the same K/V, rings and rows in the pool — a narrower window
+    drops only positions the mask already excluded."""
+    import jax
+    import numpy as np
+    from distributed_llm_tpu.engine import batching
+
+    logits = []
+    real = batching._sample_batched
+
+    def recording(lg, rng, temps):
+        jax.debug.callback(lambda x: logits.append(np.asarray(x)), lg)
+        return real(lg, rng, temps)
+    monkeypatch.setattr(batching, "_sample_batched", recording)
+
+    ids = [int(x) for x in np.random.default_rng(5).integers(3, 500, 1100)]
+    got = {}
+    for ladder in ("lane", "old"):
+        eng = ContinuousBatchingEngine(
+            _spanned_tier(monkeypatch, preset, 4096, **model), seed=3)
+        try:
+            if ladder == "old":
+                eng._chunk_windows = list(eng._reuse_windows)
+            pf, first = _prefill_by_hand(eng, ids)
+            jax.effects_barrier()
+            got[ladder] = (first, logits[-1], list(pf.blocks),
+                           {k: np.asarray(v, np.float32)
+                            for k, v in eng.pool.items()},
+                           set(eng._compiled["chunk_prefill"]))
+            eng._cancel_prefill("test")
+        finally:
+            eng.stop()
+    (first, lg, blocks, pool, keys), (first_o, lg_o, blocks_o, pool_o,
+                                      keys_o) = got["lane"], got["old"]
+    assert keys == {(256, 256), (256, 1024), (256, 2048)}
+    assert keys_o == {(256, 256), (256, 1024), (256, 4096)}
+    assert first == first_o and blocks == blocks_o
+    np.testing.assert_allclose(lg, lg_o, rtol=2e-2, atol=2e-2)
+    assert int(lg.argmax()) == int(lg_o.argmax()) == first
+    for name in pool:
+        # The slot's blocks of the paged arrays; rings and rows whole.
+        cut = (lambda a: a[:, blocks]) if name in ("k", "v", "c") \
+            else (lambda a: a)
+        assert np.abs(cut(pool[name])).sum() > 0 or name == "owner"
+        np.testing.assert_allclose(cut(pool[name]), cut(pool_o[name]),
+                                   rtol=2e-2, atol=1e-3, err_msg=name)
+
+
+@pytest.mark.parametrize("span,lane_programs", [(4096, 4), (8192, 5)])
+def test_warmup_compiles_nine_reuse_programs_and_every_lane_rung(
+        monkeypatch, span, lane_programs):
+    """The warm set: every (reuse bucket, coarse window) pair, 9 as
+    before the lane's ladder doubled, and one (chunk, window) program a
+    rung of the lane's — so neither path traces mid-serve."""
+    from distributed_llm_tpu.obs import get_observability
+
+    eng = ContinuousBatchingEngine(_spanned_tier(
+        monkeypatch, "nano_test", span, enable_prefix_cache=True), seed=11)
+    try:
+        _fake_chunk_programs(eng)
+        eng.warmup()
+        keys = eng._compiled["chunk_prefill"]
+        reuse = {k for k in keys if k[0] != 256}
+        assert reuse == {(sb, w) for sb in (32, 64, 128)
+                         for w in (256, 1024, span)}
+        assert keys - reuse == {(256, w) for w in eng._chunk_windows}
+        assert len(keys) == 9 + lane_programs
+        gauge = get_observability().m.compiled_programs.labels(
+            "nano", "chunk_prefill")
+        assert gauge.value == 9 + lane_programs
+    finally:
+        eng.stop()
+
+
+def test_reuse_admission_past_1024_takes_the_span(monkeypatch):
+    """The prefix-reuse suffix chunk chooses from its own three rungs: a
+    parked prefix of 1100 tokens and a 32-token suffix attend the span,
+    where the lane would have taken 2048."""
+    eng = ContinuousBatchingEngine(_spanned_tier(
+        monkeypatch, "nano_test", 4096, tokenizer="byte", vocab_size=512,
+        enable_prefix_cache=True, max_new_tokens=2), seed=11)
+    try:
+        called = _fake_chunk_programs(eng)
+        turn = "q" * 1099                       # BOS + bytes: 1100 ids
+        eng.generate(turn)
+        lane = list(called)
+        assert lane == [(256, 256)] + [(256, 1024)] * 3 + [(256, 2048)]
+        eng.generate(turn + "and a few more words")
+        assert called[len(lane):] == [(32, 4096)]
+    finally:
+        eng.stop()
+
+
+def test_stats_and_metrics_endpoints_carry_the_window_counters():
+    from distributed_llm_tpu.config import tiny_batched_cluster
+    from distributed_llm_tpu.serving.app import create_app
+    from distributed_llm_tpu.serving.router import Router
+
+    tiny = tiny_batched_cluster()
+    cluster = dataclasses.replace(tiny, nano=dataclasses.replace(
+        tiny.nano, prefill_chunk_tokens=16, enable_prefix_cache=False,
+        max_new_tokens=4))
+    router = Router(strategy="token", benchmark_mode=True, cluster=cluster,
+                    config={"token_threshold": 1000000})
+    try:
+        client = create_app(router=router).test_client()
+        resp = client.post("/chat", json={"message": LONG_Q,
+                                          "strategy": "token",
+                                          "session_id": "w"})
+        assert resp.status_code == 200
+        pf = client.get("/stats").get_json()["tiers"]["nano"]["prefill"]
+        assert pf["chunks_total"] >= 2
+        assert pf["window_positions_total"] == 256 * pf["chunks_total"]
+        assert pf["window_over_written"] == round(
+            pf["window_positions_total"] / pf["written_positions_total"], 4)
+        text = client.get("/metrics").text
+        assert "# TYPE dllm_prefill_window_positions_total counter" in text
+        assert 'dllm_prefill_written_positions_total{tier="nano"}' in text
+    finally:
+        router.drain()

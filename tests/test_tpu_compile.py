@@ -245,6 +245,10 @@ POOL_PROGRAMS = {
         (_bench_tier, ("chunk", 256, 256), 1.0, False),
     "smollm2-chunk-256-1024":
         (_bench_tier, ("chunk", 256, 1024), 1.0, False),
+    # A rung of the lane's doubling ladder (ISSUE 42): what a chunk that
+    # ends at 1280-2048 runs where it ran the span's program, 8192.
+    "smollm2-chunk-256-2048":
+        (_bench_tier, ("chunk", 256, 2048), 1.0, False),
     "smollm2-copy_block":
         (_bench_tier, ("cow",), 1.0, False),
     "nano_1b-gqa-decode-256":
@@ -373,6 +377,8 @@ def test_routed_tick_reads_the_experts_where_they_rest(one_chip, as_on_tpu,
 SHARED_KV_PROGRAMS = {
     ("decode", 5120): (0.7, 2, 0),
     ("chunk", 256, 5120): (0.7, 1, 2),
+    # The lane's 2048 rung (ISSUE 42): the same loops, a narrower gather.
+    ("chunk", 256, 2048): (0.7, 1, 2),
 }
 
 
