@@ -631,6 +631,7 @@ def kernel_cases(nq: int, nkv: int, d: int, dtype, *, batch: int = 8,
     from distributed_llm_tpu.ops import grouped_product as GP
     from distributed_llm_tpu.ops import pallas_attention as PA
     from distributed_llm_tpu.ops import ragged_attention as RA
+    from distributed_llm_tpu.ops import rows_attention as RW
     from distributed_llm_tpu.ops import ssm_chunk_scan as SC
     from distributed_llm_tpu.ops.quant import quantize_kv_rows
 
@@ -651,6 +652,17 @@ def kernel_cases(nq: int, nkv: int, d: int, dtype, *, batch: int = 8,
                 rand(keys[1], (nkv, nb, block, d)),
                 rand(keys[2], (nkv, nb, block, d)),
                 jnp.asarray(tables), jnp.asarray(pos))
+
+    def rows_pool():
+        """The same batch over a WHOLE token-major pool of two layers,
+        a K/V head to every query head (what the served MHA tick hands
+        ``ops/rows_attention.py``); the first slot idle over the trash
+        block."""
+        q, _, _, tables, pos = pool((batch, nq, d))
+        nb = batch * blocks_per_slot + 1
+        return (q, rand(keys[1], (2, nb, block, nq * d)),
+                rand(keys[2], (2, nb, block, nq * d)),
+                tables.at[0].set(0), pos.at[0].set(0))
 
     def pool_q8():
         q, kp, vp, tables, pos = pool((batch, nq, d))
@@ -753,6 +765,13 @@ def kernel_cases(nq: int, nkv: int, d: int, dtype, *, batch: int = 8,
             "ragged_verify", RA.ragged_paged_verify_attention,
             lambda *a: A.ragged_verify(*a, impl="xla"),
             lambda: pool((batch, verify_q, nq, d), last_q=verify_q)),
+        # The served tick's own kernel (no head-major view: the pool
+        # whole, a traced layer), against the XLA form it replaces.
+        "paged_rows_decode_attention": KernelCase(
+            "paged_decode",
+            lambda *a: RW.paged_rows_decode_attention(*a, jnp.int32(1)),
+            lambda *a: A.paged_decode(*a, impl="xla", layer=jnp.int32(1)),
+            rows_pool),
         **grouped_cases,
         "ssm_chunk_scan": KernelCase(
             "ssm_scan",

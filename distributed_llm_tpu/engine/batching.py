@@ -381,6 +381,9 @@ class ContinuousBatchingEngine:
         # compile-churn surface ISSUE 6 bounds: logged on growth and
         # mirrored to the dllm_compiled_programs gauge.
         self._compiled: Dict[str, set] = {}
+        # The attention form each compiled rung of the decode tick was
+        # traced with, by its table window in tokens (``tick_stats``).
+        self._attention_forms: Dict[str, str] = {}
         # Routed-expert load (the latent and hybrid families): what the
         # tick and the chunk program return beside their tokens, summed on
         # the host, over the family's expert layers and the experts held.
@@ -1009,6 +1012,10 @@ class ContinuousBatchingEngine:
         if key in seen:
             return
         seen.add(key)
+        if stage == "decode":
+            window = key[0] * self.paged.block_size
+            self._attention_forms[str(window)] = (
+                self.decode_attention_form(window))
         # Stitch the compile onto the profiler timeline: a mid-serve
         # trace stalls every active slot, and the tick record it lands
         # next to shows exactly which tick paid for it.
@@ -1069,14 +1076,17 @@ class ContinuousBatchingEngine:
         return tp_paged_decode_attn(self.mesh, self.cfg, window,
                                     quantized=quantized)
 
-    def decode_attention_form(self) -> str:
-        """What this tier's decode tick attends, at its full span — a
-        label, static per tier (chip_smoke.py's what-ran lines, GET
-        /stats): ``merged`` the XLA path over the whole token-major pool
-        (``ops.attention.merged_decode_attention``: the gathered rows as
-        they rest), ``split`` a hook or a Pallas kernel over a layer's
-        head-major view, ``latent`` the latent family's own absorbed
-        attention."""
+    def decode_attention_form(self, window: Optional[int] = None) -> str:
+        """What this tier's decode tick attends at a table window of
+        ``window`` tokens (its full span by default) — a label, static
+        per compiled rung (chip_smoke.py's what-ran lines, GET /stats):
+        ``streamed`` the whole token-major pool read through the block
+        table where it rests (``ops/rows_attention.py``), ``merged`` the
+        XLA gather of the same pool's rows and
+        ``ops.attention.merged_decode_attention``, ``split`` a hook or a
+        Pallas kernel over a layer's head-major view
+        (``ops.attention.decode_form`` is the rule), ``latent`` the
+        latent family's own absorbed attention."""
         if self.cfg.latent:
             return "latent"
         if self.cfg.shared_kv:
@@ -1084,12 +1094,15 @@ class ContinuousBatchingEngine:
         from ..ops import attention as attn_ops
         kind = (("ragged_decode" if self.ragged else "paged_decode")
                 + ("_q8" if self.tier.kv_quantize == "int8" else ""))
-        span = self.paged.blocks_per_slot * self.paged.block_size
-        if (self._tick_attn_hook(span) is not None
-                or attn_ops._choose(self.cfg.attention_impl, kind,
-                                    span) == "pallas"):
+        bs = self.paged.block_size
+        span = self.paged.blocks_per_slot * bs
+        window = span if window is None or self.ragged else window
+        if self._tick_attn_hook(window) is not None:
             return "split"
-        return "merged"
+        return attn_ops.decode_form(
+            self.cfg.attention_impl, kind, self.cfg.num_heads,
+            self.cfg.head_dim, window // bs, bs,
+            self.cfg.num_kv_heads * self.cfg.head_dim, self.cfg.dtype)
 
     def _decode_step(self):
         """One compiled tick for all slots: ``decode_steps_per_tick``
@@ -3891,7 +3904,8 @@ class ContinuousBatchingEngine:
                "launched_total": launched, "resident_total": resident,
                "resident_share": (round(resident / launched, 4)
                                   if launched else None),
-               "prepare_uploads": dict(self.prepare_uploads_total)}
+               "prepare_uploads": dict(self.prepare_uploads_total),
+               "attention_form": dict(self._attention_forms)}
         if not ticks:
             return out
         # ONE snapshot, ONE sort, reused for every quantile: this runs

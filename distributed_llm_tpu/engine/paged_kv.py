@@ -699,14 +699,17 @@ def decode_step_paged(
             pools = _write_rows(pools, i, blk, off, k, v)
 
         # Attend this slot's logical window: position p is
-        # (table[p//bs], p%bs).  The Pallas path streams table blocks
-        # through VMEM in-kernel; the XLA path gathers whole rows
-        # [B, S, N_kv * D] from the carried pool and contracts over the
-        # merged axis (ops.attention.merged_decode_attention): a query
-        # of one token pays N_kv times the multiplications to never
-        # split the head axis off the window, which on a TPU is a copy.
-        # The chunk and verify steps' queries are long: they keep the
-        # split ([B, S, N_kv, D]) and chunk_attention.
+        # (table[p//bs], p%bs), over the carried pool WHOLE
+        # (ops.attention.decode_form names the form).  An engine that
+        # opted into kernels walks the block table in the kernel of
+        # ops/rows_attention.py, which copies the slot's live blocks
+        # from the pool where it rests (ISSUE 45); elsewhere the XLA
+        # path gathers whole rows [B, S, N_kv * D] and contracts over
+        # the merged axis (ops.attention.merged_decode_attention).
+        # Both pay a query of one token N_kv times the multiplications
+        # to never split the head axis off the window, which on a TPU
+        # is a copy.  The chunk and verify steps' queries are long:
+        # they keep the split ([B, S, N_kv, D]) and chunk_attention.
         with jax.named_scope("attention"):
             if attn is not None:
                 attn_out = _hooked(attn, q, pools, i, tables, pos)

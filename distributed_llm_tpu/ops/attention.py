@@ -318,18 +318,53 @@ def _gather_pool_seq(q, k_pool, v_pool, tables, k_scale, v_scale,
     return k_seq, v_seq
 
 
-def _gather_decode_paged(q, k_pool, v_pool, tables, pos, k_scale, v_scale,
-                         layer=None):
-    """XLA fallback shared by ``paged_decode`` and ``ragged_decode``:
-    gather the block table into a contiguous view and attend it (portable
-    / GSPMD-shardable; one code path so the two kinds' fallbacks are
-    byte-identical).  The form follows the representation it is handed:
-    per-layer head-major views (``layer`` None: a hook's shard, a
-    kernel's parity test) gather to ``[B, S, Nkv, D]`` and reuse
+def decode_form(impl: str, kind: str, n_q: int, head_dim: int,
+                table_blocks: int, block_size: int, row: int, dtype) -> str:
+    """The form a decode op over the WHOLE token-major pool (``layer=i``:
+    the served tick) attends in, from what the code sees when it traces —
+    the one rule ``paged_decode``/``ragged_decode`` dispatch by and
+    ``engine.decode_attention_form`` labels by:
+
+    ``split``     the dispatch table (or ``DLLM_ATTENTION=pallas``) puts
+                  ``kind`` on its head-major Pallas kernel, which gets a
+                  layer's view (``_layer_views``: a layer-sized copy);
+    ``streamed``  an engine that opted into kernels (``impl`` resolves to
+                  'pallas': unsharded, on the TPU) and a window
+                  ``ops.rows_attention`` serves (static shapes: floating
+                  rows of whole lane-widths, a K/V head to every query
+                  head): the block table walked in the kernel, the pool
+                  read once where it rests;
+    ``merged``    everything else — a mesh's GSPMD path, the CPU, an int8
+                  pool, GQA's narrow rows, a row off the lanes: the XLA
+                  gather and ``merged_decode_attention``."""
+    from . import rows_attention
+    if _choose(impl, kind, table_blocks * block_size) == "pallas":
+        return "split"
+    if (resolve_impl(impl) == "pallas" and not kind.endswith("_q8")
+            and rows_attention.serves(n_q, head_dim, table_blocks,
+                                      block_size, row, dtype)):
+        return "streamed"
+    return "merged"
+
+
+def _decode_paged_fallback(q, k_pool, v_pool, tables, pos, k_scale, v_scale,
+                           layer=None, impl: str = "auto", kind=None):
+    """What ``paged_decode`` and ``ragged_decode`` run where the dispatch
+    table does not put them on a head-major kernel: one code path, so the
+    two kinds agree byte for byte.  The form follows the representation
+    it is handed: per-layer head-major views (``layer`` None: a hook's
+    shard, a kernel's parity test) gather to ``[B, S, Nkv, D]`` and reuse
     ``decode_attention``, the parity reference for the Pallas kernels;
-    the WHOLE token-major pool (``layer=i``: the served tick) gathers to
-    merged rows ``[B, S, Nkv * D]`` and ``merged_decode_attention``
-    attends them as they rest, at every ``head_dim``."""
+    the WHOLE token-major pool (``layer=i``: the served tick) is attended
+    merged, at every ``head_dim`` — ``streamed`` through the table where
+    it rests or, where ``decode_form`` says so, gathered to rows ``[B, S,
+    Nkv * D]`` for ``merged_decode_attention``."""
+    if layer is not None and kind is not None and decode_form(
+            impl, kind, *q.shape[1:], tables.shape[1], *k_pool.shape[2:],
+            k_pool.dtype) == "streamed":
+        from .rows_attention import paged_rows_decode_attention
+        return paged_rows_decode_attention(q, k_pool, v_pool, tables, pos,
+                                           layer)
     k_seq, v_seq = _gather_pool_seq(q, k_pool, v_pool, tables,
                                     k_scale, v_scale, layer,
                                     merged=layer is not None)
@@ -359,7 +394,7 @@ def paged_decode(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
     attend — the XLA path gathers straight from the whole pool, a kernel
     gets the layer's head-major view (``_layer_views``).  Which form the
     XLA path attends in follows from which of the two it was handed
-    (``_gather_decode_paged``): head-major views split by head through
+    (``_decode_paged_fallback``): head-major views split by head through
     ``decode_attention``, the whole pool's rows merged through
     ``merged_decode_attention``.  Only the two decode ops (query length
     1) have a merged form; ``ragged_verify`` and ``paged_chunk`` split
@@ -378,8 +413,9 @@ def paged_decode(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
             q, *_layer_views(layer, q.shape[-1], k_pool, v_pool, k_scale,
                              v_scale),
             tables, pos)
-    return _gather_decode_paged(q, k_pool, v_pool, tables, pos,
-                                k_scale, v_scale, layer)
+    return _decode_paged_fallback(
+        q, k_pool, v_pool, tables, pos, k_scale, v_scale, layer, impl,
+        "paged_decode" + ("" if k_scale is None else "_q8"))
 
 
 def ragged_decode(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
@@ -416,14 +452,15 @@ def ragged_decode(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
             q, *_layer_views(layer, q.shape[-1], k_pool, v_pool, k_scale,
                              v_scale),
             tables, pos)
-    return _gather_decode_paged(q, k_pool, v_pool, tables, pos,
-                                k_scale, v_scale, layer)
+    return _decode_paged_fallback(
+        q, k_pool, v_pool, tables, pos, k_scale, v_scale, layer, impl,
+        "ragged_decode" + ("" if k_scale is None else "_q8"))
 
 
 def _gather_verify_paged(q, k_pool, v_pool, tables, pos, k_scale, v_scale,
                          layer=None):
     """XLA fallback for ``ragged_verify``: the SAME ``_gather_pool_seq``
-    gather as ``_gather_decode_paged`` (so the q_len=1 and q_len=γ+1
+    gather as ``_decode_paged_fallback`` (so the q_len=1 and q_len=γ+1
     fallbacks agree block-for-block by construction), attended through
     ``chunk_attention`` with per-query absolute positions — the
     byte-level correctness reference the Pallas verify kernels are

@@ -337,24 +337,49 @@ def _token_major_case(n_q, n_kv, d, dtype, int8, *, batch=4, bs=16, mb=8,
     return q, pools, scales, tables, pos
 
 
+# What the served tick's two forms are asked for: ``merged`` the XLA path
+# every engine off the chip or on a mesh takes (impl 'xla'); ``streamed``
+# an engine that opted into kernels, where the measured table keeps the
+# head-major kernels off the tick (as the committed table does): the
+# kernel of ops/rows_attention.py, interpreted here.
+_SERVED_FORMS = {
+    "merged": ("xla", {}),
+    "streamed": ("pallas", {"paged_decode": "xla", "ragged_decode": "xla",
+                            "paged_decode_q8": "xla",
+                            "ragged_decode_q8": "xla"}),
+}
+
+
+@pytest.mark.parametrize("form", list(_SERVED_FORMS))
 @pytest.mark.parametrize("pool", ["model-dtype-pool", "int8-pool"])
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
 @pytest.mark.parametrize("n_q,n_kv,d", [(32, 32, 64), (32, 8, 64),
                                         (32, 8, 128)],
                          ids=["mha-d64", "gqa-d64", "gqa-d128"])
-def test_merged_form_agrees_with_decode_attention(table, n_q, n_kv, d, dtype,
-                                                  pool):
-    """The served tick's XLA path (``layer=i``: the whole token-major
-    pool, gathered rows attended merged) against ``decode_attention``
-    over the SAME gathered rows with the head axis split off — the
-    reference the Pallas kernels are held to as well."""
+def test_served_forms_agree_with_decode_attention(table, n_q, n_kv, d, dtype,
+                                                  pool, form):
+    """The served tick's attention (``layer=i``: the whole token-major
+    pool) in both its forms — the XLA gather attended merged, and the
+    block table walked by the kernel with nothing gathered (ISSUE 45) —
+    against ``decode_attention`` over the SAME rows with the head axis
+    split off, the reference the Pallas kernels are held to as well: MHA
+    and GQA at head 64, GQA at head 128, an int8 pool with its scales
+    (GQA and int8 the kernel does not serve: the rule sends them to the
+    XLA form whatever the engine opted into), an idle slot over the trash
+    block, ``pos`` at 0 and at the window's last position."""
     import jax.numpy as jnp
     import numpy as np
-    table({})
+    impl, measured = _SERVED_FORMS[form]
+    table(measured)
+    int8 = pool == "int8-pool"
     q, (kp, vp), (ks, vs), tables, pos = _token_major_case(
-        n_q, n_kv, d, jnp.dtype(dtype), pool == "int8-pool")
+        n_q, n_kv, d, jnp.dtype(dtype), int8)
+    assert A.decode_form(
+        impl, "paged_decode" + ("_q8" if int8 else ""), n_q, d,
+        tables.shape[1], kp.shape[2], kp.shape[3], kp.dtype
+    ) == (form if n_kv == n_q and not int8 else "merged")
     layer = jnp.int32(1)
-    got = A.paged_decode(q, kp, vp, tables, pos, impl="xla", k_scale=ks,
+    got = A.paged_decode(q, kp, vp, tables, pos, impl=impl, k_scale=ks,
                          v_scale=vs, layer=layer)
     k_seq, v_seq = A._gather_pool_seq(q, kp, vp, tables, ks, vs, layer)
     assert k_seq.shape == (*tables.shape[:1], tables.shape[1] * kp.shape[2],
@@ -372,9 +397,38 @@ def test_merged_form_agrees_with_decode_attention(table, n_q, n_kv, d, dtype,
         atol=tol, rtol=tol)
     # One code path for both tick shapes: the fused ragged tick's
     # fallback is byte-identical.
-    ragged = A.ragged_decode(q, kp, vp, tables, pos, impl="xla", k_scale=ks,
+    ragged = A.ragged_decode(q, kp, vp, tables, pos, impl=impl, k_scale=ks,
                              v_scale=vs, layer=layer)
     np.testing.assert_array_equal(np.asarray(ragged), np.asarray(got))
+
+
+def test_streamed_form_is_chosen_from_static_shapes(table, monkeypatch):
+    """``decode_form``: the kernel takes the tick where the engine opted
+    into kernels, every query head has a K/V head of its own and the rows
+    fill whole lanes; the measured table still
+    decides the head-major kernels, ``DLLM_ATTENTION=xla`` still switches
+    every kernel off, and nothing else does."""
+    import jax.numpy as jnp
+    bf16 = jnp.bfloat16
+    table({"paged_decode": "xla", "paged_decode_q8": "xla"})
+    args = ("paged_decode", 32, 64, 4, 64)
+    assert A.decode_form("pallas", *args, 2048, bf16) == "streamed"
+    assert A.decode_form("auto", *args, 2048, bf16) == "merged"
+    assert A.decode_form("pallas", *args, 2048, jnp.int8) == "merged"
+    assert A.decode_form("pallas", "paged_decode_q8", 32, 64, 4, 64, 2048,
+                         bf16) == "merged"
+    # Query heads that share a K/V head (GQA 32/8: rows of 512 columns).
+    assert A.decode_form("pallas", *args, 512, bf16) == "merged"
+    # A row off the lanes (a tiny preset's), a block off the sublanes.
+    assert A.decode_form("pallas", "paged_decode", 3, 32, 4, 64, 96,
+                         bf16) == "merged"
+    assert A.decode_form("pallas", "paged_decode", 32, 64, 4, 8, 2048,
+                         bf16) == "merged"
+    table({"paged_decode": "pallas"})
+    assert A.decode_form("pallas", *args, 2048, bf16) == "split"
+    table({"paged_decode": "xla"})
+    monkeypatch.setenv("DLLM_ATTENTION", "xla")
+    assert A.decode_form("pallas", *args, 2048, bf16) == "merged"
 
 
 def test_head_major_views_keep_the_split_form(table):

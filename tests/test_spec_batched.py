@@ -108,7 +108,7 @@ def test_ragged_verify_g1_degenerates_to_decode():
     from distributed_llm_tpu.ops import attention as A
     from distributed_llm_tpu.ops import ragged_attention as RA
     q, kp, vp, _, _, tables, pos = _verify_inputs(g=1)
-    dec = A._gather_decode_paged(q[:, 0], kp, vp, tables, pos, None, None)
+    dec = A._decode_paged_fallback(q[:, 0], kp, vp, tables, pos, None, None)
     ver = RA.ragged_paged_verify_attention(q, kp, vp, tables, pos)[:, 0]
     np.testing.assert_allclose(np.asarray(ver), np.asarray(dec),
                                rtol=2e-5, atol=2e-5)

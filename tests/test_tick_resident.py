@@ -500,3 +500,45 @@ def test_stats_and_metrics_carry_the_resident_share():
                 "resident_probe", what).value == 1
     finally:
         engine.stop()
+
+
+@pytest.mark.parametrize("impl,want", [("auto", "merged"),
+                                       ("pallas", "streamed")])
+def test_stats_name_the_attention_form_of_every_warmed_rung(monkeypatch,
+                                                            impl, want):
+    """GET /stats ``tiers.<tier>.tick.attention_form``: the form each
+    compiled rung of the tick was traced with, by its table window in
+    tokens — ``ops.attention.decode_form``'s answer for the shapes the
+    tick sees, the rule the dispatching op itself follows.  On the CPU
+    an engine takes the XLA form (``merged``); one that opted into
+    kernels, as every unsharded engine on the chip does, gets the kernel
+    that walks the block table (``streamed``) where every query head has
+    a K/V head of its own and the rows fill whole lanes."""
+    from distributed_llm_tpu.config import MODEL_PRESETS
+    from distributed_llm_tpu.ops import attention as attn_ops
+    from distributed_llm_tpu.utils.telemetry import engine_stats
+    base = _tier()
+    cfg = MODEL_PRESETS[base.model_preset]
+    # A K/V head to every query head, rows of 128 lanes: what the rule
+    # asks of a window; and the committed table's verdict on the
+    # head-major kernels, which is measured for the chip only.
+    monkeypatch.setitem(MODEL_PRESETS, "form_probe", dataclasses.replace(
+        cfg, num_heads=4, num_kv_heads=4, hidden_size=128,
+        attention_impl=impl))
+    monkeypatch.setattr(attn_ops, "_DISPATCH_TABLE",
+                        {"paged_decode": "xla", "ragged_decode": "xla"})
+    monkeypatch.delenv("DLLM_ATTENTION", raising=False)
+    tier = dataclasses.replace(base, name="form_probe",
+                               model_preset="form_probe")
+    engine = ContinuousBatchingEngine(tier, seed=3)
+    try:
+        out = engine.generate(PROBE_A, max_new_tokens=8)
+        assert len(out.token_ids) == 8
+        forms = engine_stats(engine)["tick"]["attention_form"]
+        bs = engine.paged.block_size
+        assert forms == {str(wb * bs): want
+                         for wb, _ in engine._compiled["decode"]}
+        assert forms and engine.decode_attention_form() == want
+        assert engine_stats(engine)["decode_attention"] == want
+    finally:
+        engine.stop()

@@ -41,6 +41,10 @@ KERNELS = ["flash_causal_attention", "flash_decode_attention",
            "ragged_paged_decode_attention",
            "ragged_paged_decode_attention_q8",
            "ragged_paged_verify_attention",
+           # The served MHA tick's attention over the whole token-major
+           # pool (a K/V head to every query head, whatever the preset's
+           # own grouping: the kernel serves no other).
+           "paged_rows_decode_attention",
            # The routed experts' grouped product (no heads in it: the
            # same case under every preset): an up and a down product at
            # the decode ticks' rows and the widths the cells store.
@@ -223,53 +227,64 @@ def _flagship_nano(preset):
 
 
 GB = 1e9
-# (tier, program, temporaries allowed in GB, forced onto the ragged Pallas
-# tick).  At the benchmark's sizes the commit before PR 27 compiled to
-# 11.30 GB of temporaries for a tick and 3.51 for a chunk program, with 6
-# pool-sized copies, 2 update-slices and 2 slice fusions (compile for a
-# described v5e, PR 27); ISSUE 27 asked for under 1 GB and no pool-sized
-# move at all.  The served XLA ticks are held to what PR 30 compiled to,
-# the merged form of ops/attention.py: 0.4034 GB at both SmolLM2 rungs
-# (0.538 at 2048 before; what is left is the entry's two copies of wq
-# and wk), 0.1350 GB for GQA at head_dim 64 and 0.1352 at head_dim 128.
-# The HOOKED path — the fused ragged tick on its Pallas kernel, which
-# gets a layer's head-major view from ``ops.attention._layer_views`` —
-# is held to what the commit before PR 27 compiled to (5.073 and 1.213
-# GB).  No cell runs a hooked tier: these two cases are all that holds it.
+# (tier, program, temporaries allowed in GB, the tick's attention form).
+# At the benchmark's sizes the commit before PR 27 compiled to 11.30 GB of
+# temporaries for a tick and 3.51 for a chunk program, with 6 pool-sized
+# copies, 2 update-slices and 2 slice fusions (compile for a described
+# v5e, PR 27); ISSUE 27 asked for under 1 GB and no pool-sized move at
+# all.  The served ticks are held to what they compile to: 0.4034 GB at
+# both SmolLM2 rungs (the entry's two copies of wq and wk; the window
+# itself is no temporary in either form: XLA kept PR 30's gathered rows
+# in VMEM, PR 45's kernel never has them), 0.1350 GB for GQA at head_dim
+# 64 and 0.1352 at head_dim 128.  ``streamed`` (ISSUE 45): a K/V head to
+# every query head, the block table walked by the kernel of
+# ops/rows_attention.py; ``merged``: GQA, whose narrow rows the kernel
+# loses on (``rows_attention.serves``), keeps the XLA gather.  The HOOKED
+# path — ``split``: the fused ragged tick on its Pallas kernel, which gets
+# a layer's head-major view from ``ops.attention._layer_views`` — is held
+# to what the commit before PR 27 compiled to (5.073 and 1.213 GB).  No
+# cell runs a hooked tier: these two cases are all that holds it.
 POOL_PROGRAMS = {
     "smollm2-decode-256":
-        (_bench_tier, ("decode", 256), 0.41, False),
+        (_bench_tier, ("decode", 256), 0.41, "streamed"),
     "smollm2-decode-2048":
-        (_bench_tier, ("decode", 2048), 0.41, False),
+        (_bench_tier, ("decode", 2048), 0.41, "streamed"),
     "smollm2-chunk-256-256":
-        (_bench_tier, ("chunk", 256, 256), 1.0, False),
+        (_bench_tier, ("chunk", 256, 256), 1.0, None),
     "smollm2-chunk-256-1024":
-        (_bench_tier, ("chunk", 256, 1024), 1.0, False),
+        (_bench_tier, ("chunk", 256, 1024), 1.0, None),
     # A rung of the lane's doubling ladder (ISSUE 42): what a chunk that
     # ends at 1280-2048 runs where it ran the span's program, 8192.
     "smollm2-chunk-256-2048":
-        (_bench_tier, ("chunk", 256, 2048), 1.0, False),
+        (_bench_tier, ("chunk", 256, 2048), 1.0, None),
     "smollm2-copy_block":
-        (_bench_tier, ("cow",), 1.0, False),
+        (_bench_tier, ("cow",), 1.0, None),
     "nano_1b-gqa-decode-256":
-        (lambda _: _flagship_nano("nano_1b"), ("decode", 256), 0.14, False),
+        (lambda _: _flagship_nano("nano_1b"), ("decode", 256), 0.14,
+         "merged"),
     "orin_bench-d128-decode-256":
         (lambda _: _flagship_nano("orin_bench"), ("decode", 256), 0.14,
-         False),
+         "merged"),
     "nano_1b-gqa-ragged-pallas":
-        (lambda _: _flagship_nano("nano_1b"), ("decode", 0), 5.073, True),
+        (lambda _: _flagship_nano("nano_1b"), ("decode", 0), 5.073, "split"),
     "orin_bench-d128-ragged-pallas":
-        (lambda _: _flagship_nano("orin_bench"), ("decode", 0), 1.213, True),
+        (lambda _: _flagship_nano("orin_bench"), ("decode", 0), 1.213,
+         "split"),
 }
 
 
-def window_passes(hlo: str, window_elements: int):
+def window_passes(hlo: str, window_elements: int, fusions: bool = False):
     """``(result, opcode)`` of every instruction of the attention scope
-    that stands outside any fusion and produces ``window_elements``
-    elements or more, other than by handing a buffer on: the head-split
-    relayout of a gathered window (``reshape`` to ``[B, S, N_kv, D]``),
-    its padded transposes (``copy``), the group ``broadcast`` of a GQA
-    window.  A fusion's insides are the compiler's business."""
+    that produces ``window_elements`` elements or more, other than by
+    handing a buffer on: the head-split relayout of a gathered window
+    (``reshape`` to ``[B, S, N_kv, D]``), its padded transposes
+    (``copy``), the group ``broadcast`` of a GQA window.  Outside any
+    fusion by default (a fusion's insides are the compiler's business:
+    what PR 30's merged form is held to); with ``fusions``, inside them
+    too and the fusions themselves (ISSUE 45: the ``gather`` of the
+    window, which stands in a fusion of its own, and whatever it hands
+    the products)."""
+    handed_on = ("parameter", "get-tuple-element", "bitcast")
     found, computation = [], ""
     for line in hlo.splitlines():
         if line.endswith("{") and " = " not in line:
@@ -277,10 +292,9 @@ def window_passes(hlo: str, window_elements: int):
             computation = words[1 if words[0] == "ENTRY" else 0]
             continue
         m = chip_smoke._HLO_RESULT.match(line)
-        if (not m or "fused_computation" in computation
-                or "/attention/" not in line
-                or m[3] in ("parameter", "get-tuple-element", "bitcast",
-                            "fusion")):
+        if (not m or "/attention/" not in line or m[3] in handed_on
+                or not fusions and ("fused_computation" in computation
+                                    or m[3] == "fusion")):
             continue
         dims = m[2][m[2].index("[") + 1:-1]
         if math.prod(int(x) for x in dims.split(",") if x) >= window_elements:
@@ -301,16 +315,23 @@ def test_pool_program_leaves_the_pool_in_place(one_chip, as_on_tpu,
     two products as a ``bitcast``, nothing window-sized (``B × S × N_kv
     × D`` elements) is produced outside a fusion between the pool and
     the softmax — at head_dim 64 (MHA at both of the benchmark's rungs,
-    GQA) and at head_dim 128."""
-    make_tier, program, temp_limit_gb, ragged = POOL_PROGRAMS[case]
+    GQA) and at head_dim 128.  Where every query head has a K/V head of
+    its own (ISSUE 45) the tick's attention is the kernel that walks the
+    block table, handed the pool WHOLE, and nothing window-sized is
+    produced inside a fusion either."""
+    make_tier, program, temp_limit_gb, form = POOL_PROGRAMS[case]
+    ragged = form == "split"
     if ragged:
         monkeypatch.setenv("DLLM_RAGGED", "1")
         monkeypatch.setenv("DLLM_ATTENTION", "pallas")
     engine, pool, compiled, pool_arg = _pool_program(
         one_chip, make_tier(monkeypatch), program)
     assert engine.ragged is ragged
-    assert compiled.as_text().count("tpu_custom_call") == int(ragged)
-    assert engine.decode_attention_form() == ("split" if ragged else "merged")
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == int(form in ("split", "streamed"))
+    assert ("paged_rows_decode" in text) is (form == "streamed")
+    if form is not None:
+        assert engine.decode_attention_form(program[1] or None) == form
     facts = chip_smoke.pool_program_facts(compiled, pool_arg, pool)
     pool_bytes = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(pool))
     assert facts["formats_match"], facts
@@ -322,13 +343,21 @@ def test_pool_program_leaves_the_pool_in_place(one_chip, as_on_tpu,
     if program[0] != "cow":
         # The loop structure the benchmark files programs by: steps and
         # layers for a tick, layers alone for a chunk program.
-        assert compiled.as_text().count(" while(") == (
-            2 if program[0] == "decode" else 1)
-    if program[0] == "decode" and not ragged:
+        assert text.count(" while(") == (2 if program[0] == "decode" else 1)
+    if form in ("streamed", "merged"):
         cfg = engine.cfg
         window = (engine.paged.max_slots * program[1]
                   * cfg.num_kv_heads * cfg.head_dim)
-        assert window_passes(compiled.as_text(), window) == []
+        assert window_passes(text, window) == []
+        # The streamed tick has nothing window-sized in its attention
+        # scope at all, inside a fusion or outside one (on PR 42's tree
+        # this finds the two gathers and what they hand the products);
+        # the merged one still gathers its window, twice.
+        inside = window_passes(text, window, fusions=True)
+        if form == "streamed":
+            assert inside == []
+        else:
+            assert sum(op == "gather" for _, op in inside) == 2, inside
 
 
 # -- the routed experts stay where they rest (ISSUE 34) ------------------------
@@ -355,6 +384,11 @@ def test_routed_tick_reads_the_experts_where_they_rest(one_chip, as_on_tpu,
     tier = _bench_tier(monkeypatch, config)
     engine, _, compiled, _ = _pool_program(one_chip, tier, ("decode", 256))
     assert engine.grouped_product_form()["decode"] == "pallas"
+    # The hybrid family's attention layers are GQA 32/2 at head 128: rows
+    # of 512 B, which ``rows_attention.serves`` leaves to the XLA form
+    # (ISSUE 45); the latent family attends in code of its own.
+    assert engine.decode_attention_form(256) == (
+        "latent" if engine.cfg.latent else "merged")
     text = compiled.as_text()
     assert text.count("tpu_custom_call") == products
     assert "grouped_product" in text and "ragged-dot" not in text
