@@ -196,15 +196,20 @@ class ModelConfig:
     hc_clamp: float = 30.0
     # -- The state-space / attention / routed-expert hybrid family
     # (models/hybrid_ssm.py; a non-empty ``layer_pattern`` selects it).
-    # One character a layer, ``num_layers`` of them: "M" a Mamba-2
-    # state-space mixer, "*" attention, "E" routed experts; EACH layer is
-    # ONE pre-norm mixer.  The layer loop scans the pattern's shortest
-    # repeating period (``layer_period``).
+    # One character a layer, ``num_layers`` of them: "M" a state-space
+    # mixer (Mamba-1 where ``ssm_dt_rank`` > 0, with an RMSNorm on each
+    # of delta, B and C; else Mamba-2), "*" attention, "E" routed
+    # experts, "-" a dense gated MLP of ``ffn_size``; EACH layer is ONE
+    # pre-norm mixer (a mixer-then-MLP layer is two characters, "M-").
+    # The layer loop scans the pattern's shortest repeating period
+    # (``layer_period``).  The head is tied where ``tie_embeddings``.
     layer_pattern: str = ""
     # Mamba-2: ``ssm_heads`` heads of ``ssm_head_dim`` channels, a state
     # of ``ssm_state`` numbers a channel, B and C shared by groups of
     # heads (``ssm_groups``), a causal depthwise conv of ``ssm_conv``
     # taps.  The time-step range is the published init's (A in [1, 16]).
+    # Mamba-1 (``ssm_dt_rank`` > 0): ``ssm_heads`` channels of
+    # ``ssm_head_dim`` 1, a decay a channel AND state, no groups.
     ssm_heads: int = 0
     ssm_head_dim: int = 0
     ssm_state: int = 0
@@ -232,7 +237,8 @@ class ModelConfig:
     # -- The state-space / window-attention / shared-K/V family
     # (models/shared_kv_hybrid.py; an "F" in ``layer_pattern`` selects
     # it).  Its kinds: "M" a MAMBA-1 mixer (``ssm_dt_rank`` > 0: decay a
-    # channel AND state, ``ssm_heads`` channels of ``ssm_head_dim`` 1),
+    # channel AND state, ``ssm_heads`` channels of ``ssm_head_dim`` 1;
+    # the ONE copy in models/hybrid_ssm.py, here without inner norms),
     # "W" attention over the last ``attn_window`` positions (a ring a
     # slot), "F" full attention whose K/V are the only ones the paged
     # pool holds, "X" attention with a query projection only, over "F"'s
@@ -242,6 +248,8 @@ class ModelConfig:
     # its attention heads pair differentially (no option: the pattern
     # selects both).
     attn_window: int = 0
+    # The time step's low rank: > 0 makes EVERY "M" a Mamba-1 mixer, in
+    # either row family (0: the hybrid family's Mamba-2).
     ssm_dt_rank: int = 0
 
     @property
@@ -253,8 +261,10 @@ class ModelConfig:
         """The model family, by what selects it: "latent"
         (models/latent_moe.py: a latent cache row), "shared_kv"
         (models/shared_kv_hybrid.py: an "F" in ``layer_pattern``),
-        "hybrid" (models/hybrid_ssm.py: any other ``layer_pattern``), else
-        "dense" (transformer.py, moe.py).  What differs by family
+        "hybrid" (models/hybrid_ssm.py: any other ``layer_pattern``, of
+        Mamba-2 or Mamba-1 rows beside paged attention layers that each
+        own their K/V, experts or dense MLPs), else "dense"
+        (transformer.py, moe.py).  What differs by family
         dispatches on this one name."""
         if self.kv_lora_rank > 0:
             return "latent"
@@ -426,6 +436,16 @@ MODEL_PRESETS: Dict[str, ModelConfig] = {
         num_experts=8, experts_first=0, experts_count=4, moe_ffn_size=32,
         shared_ffn_size=48, experts_per_token=3, router_scale=2.5,
         expert_act="relu2",
+    ),
+    # The hybrid family's other pattern at unit-test size: Mamba-1 rows
+    # with inner norms, ONE K/V head under 2 query heads, a dense gated
+    # MLP after every mixer, a tied head; two periods of "M-M-*-M-".
+    "hybrid_mamba1_test": ModelConfig(
+        name="hybrid_mamba1_test", tokenizer="byte", vocab_size=512,
+        hidden_size=64, num_layers=16, num_heads=2, num_kv_heads=1,
+        ffn_size=96, max_seq_len=256, rotary=False, norm_eps=1e-6,
+        layer_pattern="M-M-*-M-" * 2, ssm_heads=128, ssm_head_dim=1,
+        ssm_state=8, ssm_conv=4, ssm_dt_rank=4,
     ),
     # The state-space / window-attention / shared-K/V family at unit-test
     # size (models/shared_kv_hybrid.py): 3 x "MW", "M", "F", 2 x "GX";

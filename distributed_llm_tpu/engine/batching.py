@@ -575,6 +575,11 @@ class ContinuousBatchingEngine:
         # what is there (prefill_stats' ``window_over_written``).
         self.prefill_window_positions_total = 0
         self.prefill_written_positions_total = 0
+        # The lane's chunks by the window rung each ran at
+        # (prefill_stats' ``chunks_by_window``,
+        # dllm_prefill_chunks_by_window_total): a long prompt's chunks
+        # laid against the ladder.
+        self.prefill_chunks_by_window: Dict[int, int] = {}
         # perf_counter of the last plain tick's fetch return: the
         # latest moment the host saw the device reach whatever was
         # queued behind that tick (``_settle_chunk``'s clock).
@@ -1575,15 +1580,23 @@ class ContinuousBatchingEngine:
 
     def state_stats(self) -> Optional[Dict[str, int]]:
         """The recurrent rows (GET /stats ``state``), or None for a model
-        without them: how many there are, how many name a sequence, what
-        one holds, and how many sequences started one from zero."""
+        without them: the kind of mixer and its layers, how many rows
+        there are, how many name a sequence, what one holds, how many
+        sequences started one from zero, and the K/V beside them."""
         if self._state_owner is None:
             return None
-        from ..utils.roofline import ring_row_bytes, state_row_bytes
-        out = {"rows": int(self._state_owner.size),
+        from ..utils.roofline import (kv_bytes_per_pos, ring_row_bytes,
+                                      state_row_bytes)
+        out = {"mixer": "mamba1" if self.cfg.ssm_dt_rank else "mamba2",
+               "layers": self.cfg.layers_of("M"),
+               "rows": int(self._state_owner.size),
                "rows_in_use": int(np.count_nonzero(self._rows_owned())),
                "row_bytes": int(state_row_bytes(self.cfg)),
-               "resets_total": int(self.state_resets_total)}
+               "resets_total": int(self.state_resets_total),
+               # Beside the rows: the layers whose K/V the paged pool
+               # holds by position, and what a token keeps there.
+               "kv_layers": self.cfg.kv_layers,
+               "kv_bytes_per_token": int(kv_bytes_per_pos(self.cfg))}
         if self.cfg.shared_kv:
             # The window layers' rings: a row a slot like the state, of
             # the window's positions whatever the sequence's length.
@@ -2325,6 +2338,8 @@ class ContinuousBatchingEngine:
         written = min(end, pf.total)
         self.prefill_window_positions_total += window
         self.prefill_written_positions_total += written
+        self.prefill_chunks_by_window[window] = \
+            self.prefill_chunks_by_window.get(window, 0) + 1
         self_only = self.cfg.shared_kv and end < pf.total
         self.prefill_self_only_chunks_total += int(self_only)
         try:
@@ -2336,6 +2351,8 @@ class ContinuousBatchingEngine:
                 self.tier.name,
                 "behind_tick" if overlapped else "alone").inc()
             m.prefill_window_positions.labels(self.tier.name).inc(window)
+            m.prefill_chunks_by_window.labels(self.tier.name,
+                                              str(window)).inc()
             m.prefill_written_positions.labels(self.tier.name).inc(written)
             if self_only:
                 m.prefill_self_only_chunks.labels(self.tier.name).inc()
@@ -4036,7 +4053,9 @@ class ContinuousBatchingEngine:
                "window_positions_total": attended,
                "written_positions_total": written,
                "window_over_written": (round(attended / written, 4)
-                                       if written else None)}
+                                       if written else None),
+               "chunks_by_window": dict(sorted(
+                   self.prefill_chunks_by_window.items()))}
         if self.cfg.shared_kv:
             out["chunks_self_only_total"] = \
                 self.prefill_self_only_chunks_total

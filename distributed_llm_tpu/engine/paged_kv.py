@@ -129,34 +129,28 @@ def init_pool(cfg: ModelConfig, pcfg: PagedConfig,
                 f"family ({cfg.name}) has no int8 pool — the dense int8 "
                 f"rows are not wired to its attention layers; use 'none'")
         dtype = jnp.dtype(cfg.dtype)
-        if cfg.shared_kv:
-            # ONE cached layer ("F"), a ring of the window's positions a
-            # slot a window layer, and Mamba-1's row a slot a state-space
-            # layer: the state [state, inner] float32 (channels on the
-            # lanes), the conv tail in the model's dtype.
-            n_m, n_w, r = (cfg.layers_of("M"), cfg.layers_of("W"),
-                           pcfg.max_slots)
-            # The ring is exactly the window (models/shared_kv_hybrid.py).
-            ring = (n_w, r, cfg.attn_window, cfg.cache_row_width)
-            return {"k": jnp.zeros((1,) + shape[1:], dtype),
-                    "v": jnp.zeros((1,) + shape[1:], dtype),
-                    "rk": jnp.zeros(ring, dtype),
-                    "rv": jnp.zeros(ring, dtype),
-                    "s": jnp.zeros((n_m, r, cfg.ssm_state, cfg.ssm_inner),
-                                   jnp.float32),
-                    "t": jnp.zeros((n_m, r, cfg.ssm_conv - 1,
-                                    cfg.ssm_inner), dtype),
-                    "owner": jnp.zeros((r,), jnp.int32)}
-        kv = (cfg.layers_of("*"),) + shape[1:]
+        # K/V blocks for the layers that own K/V by position (the hybrid
+        # family's "*" layers, the shared-K/V family's ONE "F"), and a
+        # ROW a slot a state-space layer: the float32 state — Mamba-1's
+        # [state, inner] (channels on the lanes), Mamba-2's [heads, P,
+        # N] — and the conv tail in the model's dtype.  "k" first:
+        # ``_block_size`` reads the first array.
         n_m, r = cfg.layers_of("M"), pcfg.max_slots
-        # "k" first: ``_block_size`` reads the first array.  The state is
-        # float32 at rest; the conv tail in the model's dtype.
-        return {"k": jnp.zeros(kv, dtype), "v": jnp.zeros(kv, dtype),
-                "s": jnp.zeros((n_m, r, cfg.ssm_heads, cfg.ssm_head_dim,
-                                cfg.ssm_state), jnp.float32),
-                "t": jnp.zeros((n_m, r, cfg.ssm_conv - 1,
-                                cfg.ssm_conv_width), dtype),
-                "owner": jnp.zeros((r,), jnp.int32)}
+        state = ((cfg.ssm_state, cfg.ssm_inner) if cfg.ssm_dt_rank else
+                 (cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state))
+        kv = (cfg.kv_layers,) + shape[1:]
+        pool = {"k": jnp.zeros(kv, dtype), "v": jnp.zeros(kv, dtype)}
+        if cfg.shared_kv:
+            # A ring of the window's positions a slot a window layer:
+            # exactly the window (models/shared_kv_hybrid.py).
+            ring = (cfg.layers_of("W"), r, cfg.attn_window,
+                    cfg.cache_row_width)
+            pool.update(rk=jnp.zeros(ring, dtype), rv=jnp.zeros(ring, dtype))
+        pool.update(s=jnp.zeros((n_m, r) + state, jnp.float32),
+                    t=jnp.zeros((n_m, r, cfg.ssm_conv - 1,
+                                 cfg.ssm_conv_width), dtype),
+                    owner=jnp.zeros((r,), jnp.int32))
+        return pool
     if kv_quantize == "int8":
         scales = rows + (cfg.num_kv_heads,)
         return {"k": jnp.zeros(shape, jnp.int8),

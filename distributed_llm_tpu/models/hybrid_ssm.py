@@ -2,9 +2,17 @@
 
 A non-empty ``ModelConfig.layer_pattern`` selects it: one character a
 layer, and EACH layer is ONE pre-norm mixer, ``x <- x + mixer(RMSNorm(x))``,
-the residual in the model's dtype.
+the residual in the model's dtype.  This module also holds what BOTH row
+families run (models/shared_kv_hybrid.py imports it): the row machinery
+(``claim_row``, ``rows_of``, ``chunk_ctx``, ``decode_ctx``) and the ONE
+copy of the Mamba-1 mixer (``mamba1`` and what it calls).
 
-- ``M``, **Mamba-2**: ``[z | xBC | dt] = x W_in``; a causal depthwise conv
+- ``M``, a state-space mixer: **Mamba-1** where ``cfg.ssm_dt_rank > 0``
+  (``mamba1``, below: a decay a channel AND state, the chunk stepped in
+  order by ``ops/ssm_chunk_scan.py``; in THIS family with an RMSNorm and
+  a gain on each of ``delta``, ``B`` and ``C`` before the time-step
+  projection, Jamba's block), else
+  **Mamba-2**: ``[z | xBC | dt] = x W_in``; a causal depthwise conv
   of ``ssm_conv`` taps (with bias) and silu over ``xBC``; per head ``h`` a
   state ``S_h [head_dim, state]``, ``S_t = exp(dt_t A_h) S_{t-1} + dt_t
   x_t[h] (x) B_t[group of h]``, ``y_t[h] = S_t C_t[group of h] + D_h
@@ -26,6 +34,12 @@ the residual in the model's dtype.
   (its rank of the expert-parallel pair adds it), in the plain reference
   alike.  Experts are non-gated, ``W_2 relu(W_1 x)^2``; one shared expert
   of ``shared_ffn_size`` adds for every token.
+- ``-``, a **dense gated MLP** of ``ffn_size``, ``W_down(silu(W_gate x) *
+  (W_up x))`` (``transformer._swiglu``, the dense family's): a layer that
+  is a mixer and then an MLP is two characters, ``M-`` or ``*-``.
+
+The head is the tree's own ``"head"``, or the embedding where
+``cfg.tie_embeddings``.
 
 Whose row: ``pool["owner"][r]`` is the FIRST BLOCK of the sequence that
 holds recurrent row ``r`` (0, the trash block: free).  The step functions
@@ -55,13 +69,13 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..config import ModelConfig
-from ..ops import attention, quant
+from ..ops import attention, pallas_attention, quant, ssm_chunk_scan
 from . import latent_moe, transformer
 from .latent_moe import (EMBED_STD, HIGHEST, ROUTER_BIAS_STD, init_normal,
                          init_table)
 
 Params = Dict[str, Any]
-KINDS = "M*E"
+KINDS = "M*E-"
 EXPERT_KEYS = ("we_up", "we_down")
 LANES = 128
 
@@ -108,17 +122,25 @@ def check(cfg: ModelConfig) -> None:
         raise ValueError(
             f"{cfg.name}: layer_pattern {cfg.layer_pattern!r} has to be "
             f"num_layers = {cfg.num_layers} characters of {KINDS!r}")
-    if cfg.ssm_heads % cfg.ssm_groups:
+    if cfg.ssm_dt_rank:
+        if cfg.ssm_head_dim != 1:
+            raise ValueError(f"{cfg.name}: Mamba-1 (ssm_dt_rank > 0) is a "
+                             f"head a channel (ssm_head_dim 1)")
+    elif cfg.ssm_heads % cfg.ssm_groups:
         raise ValueError(f"{cfg.name}: ssm_heads {cfg.ssm_heads} is not a "
                          f"multiple of ssm_groups {cfg.ssm_groups}")
+    if cfg.rotary:
+        raise ValueError(f"{cfg.name}: the hybrid family is written for "
+                         f"no rotary embedding")
+    if "E" not in cfg.layer_pattern:
+        return
     if not 0 <= cfg.experts_first <= cfg.num_experts - cfg.experts_held:
         raise ValueError(
             f"{cfg.name}: experts {cfg.experts_first}..+{cfg.experts_held} "
             f"are not among the router's {cfg.num_experts}")
-    if cfg.expert_act != "relu2" or cfg.rotary or cfg.tie_embeddings:
-        raise ValueError(f"{cfg.name}: the hybrid family is written for "
-                         f"relu2 experts, no rotary embedding and a head "
-                         f"of its own")
+    if cfg.expert_act != "relu2":
+        raise ValueError(f"{cfg.name}: the hybrid family's experts are "
+                         f"written for relu2")
 
 
 def kind_index(cfg: ModelConfig, kind: str):
@@ -138,21 +160,34 @@ def init_uniform(key, shape, dtype, bound):
                               bound).astype(dtype)
 
 
+def init_dt_bias(cfg: ModelConfig, key, width: int):
+    """The time step's bias, the published init of both Mamba forms: dt
+    log-uniform in [dt_min, dt_max], floored, stored as the inverse of
+    softplus."""
+    dt = jnp.exp(jax.random.uniform(key, (width,), jnp.float32)
+                 * (np.log(cfg.ssm_dt_max) - np.log(cfg.ssm_dt_min))
+                 + np.log(cfg.ssm_dt_min))
+    dt = jnp.maximum(dt, cfg.ssm_dt_floor)
+    return dt + jnp.log(-jnp.expm1(-dt))
+
+
 def init_layer(cfg: ModelConfig, key, kind: str) -> Params:
     """One layer from its own key, split 8 ways."""
     dtype = jnp.dtype(cfg.dtype)
     h = cfg.hidden_size
     ks = jax.random.split(key, 8)
     lp = {"ln": jnp.ones((h,), dtype)}
-    if kind == "M":
+    if kind == "M" and cfg.ssm_dt_rank:
+        lp.update(init_mamba1(cfg, ks[:7], norm_key=ks[7]))
+    elif kind == "-":
+        f = cfg.ffn_size
+        lp.update(w_gate=init_normal(ks[0], (h, f), dtype),
+                  w_up=init_normal(ks[1], (h, f), dtype),
+                  w_down=init_normal(ks[2], (f, h), dtype))
+    elif kind == "M":
         nh, di, c, k = (cfg.ssm_heads, cfg.ssm_inner, cfg.ssm_conv_width,
                         cfg.ssm_conv)
-        # The published init: dt log-uniform in [dt_min, dt_max], floored,
-        # stored as the inverse of softplus; A uniform in [1, 16]; D = 1.
-        dt = jnp.exp(jax.random.uniform(ks[3], (nh,), jnp.float32)
-                     * (np.log(cfg.ssm_dt_max) - np.log(cfg.ssm_dt_min))
-                     + np.log(cfg.ssm_dt_min))
-        dt = jnp.maximum(dt, cfg.ssm_dt_floor)
+        # The published init: A uniform in [1, 16]; D = 1.
         lp.update(
             # [z | xBC | dt], then zero columns up to the chip's lanes:
             # at the published 10304 (80.5 x 128) the device rests the
@@ -162,7 +197,7 @@ def init_layer(cfg: ModelConfig, key, kind: str) -> Params:
             # A depthwise conv's default init: uniform in +-1/sqrt(taps).
             conv_w=init_uniform(ks[1], (k, c), dtype, k ** -0.5),
             conv_b=init_uniform(ks[2], (c,), dtype, k ** -0.5),
-            dt_bias=dt + jnp.log(-jnp.expm1(-dt)),
+            dt_bias=init_dt_bias(cfg, ks[3], nh),
             a_log=jnp.log(jax.random.uniform(ks[4], (nh,), jnp.float32,
                                              1.0, 16.0)),
             d=jnp.ones((nh,), jnp.float32),
@@ -211,15 +246,18 @@ def init_params(cfg: ModelConfig, seed=0) -> Params:
     k_embed, k_head, k_layers = jax.random.split(jax.random.PRNGKey(seed), 3)
     lkeys = jax.random.split(k_layers, cfg.num_layers)
     period = cfg.layer_period
-    return {
+    params = {
         "embed": init_table(k_embed, cfg.vocab_size, cfg.hidden_size, dtype,
                             EMBED_STD),
-        "head": init_table(k_head, cfg.vocab_size, cfg.hidden_size, dtype),
         "final_ln": jnp.ones((cfg.hidden_size,), dtype),
         "periods": [jax.lax.map(lambda k, c=kind: init_layer(cfg, k, c),
                                 lkeys[j::len(period)])
                     for j, kind in enumerate(period)],
     }
+    if not cfg.tie_embeddings:
+        params["head"] = init_table(k_head, cfg.vocab_size, cfg.hidden_size,
+                                    dtype)
+    return params
 
 
 # =============================================================================
@@ -246,7 +284,168 @@ def rows_of(owner: jax.Array, first_blocks: jax.Array):
 
 
 # =============================================================================
-# The three mixers
+# The Mamba-1 mixer: both row families' (models/shared_kv_hybrid.py's "M")
+# =============================================================================
+
+INNER_NORM_STD = 0.1
+
+
+def init_mamba1(cfg: ModelConfig, ks, norm_key=None) -> Params:
+    """One Mamba-1 mixer's weights from seven keys (in, conv, conv bias,
+    x, time step, the time step's bias, out).  The published init:
+    ``init_dt_bias``; A = 1..state a channel; D 1.  ``norm_key``: the mixer
+    normalises ``delta``, ``B`` and ``C`` (an RMSNorm each) and holds
+    their gains, drawn AWAY from 1 so that a dropped gain moves the
+    logits; without it the tree holds none and ``_time_step`` runs none."""
+    dtype = jnp.dtype(cfg.dtype)
+    h = cfg.hidden_size
+    di, n, k, r = cfg.ssm_inner, cfg.ssm_state, cfg.ssm_conv, cfg.ssm_dt_rank
+    lp = dict(
+        w_in=init_normal(ks[0], (h, 2 * di), dtype),
+        conv_w=init_uniform(ks[1], (k, di), dtype, k ** -0.5),
+        conv_b=init_uniform(ks[2], (di,), dtype, k ** -0.5),
+        w_x=init_normal(ks[3], (di, r + 2 * n), dtype),
+        w_dt=init_uniform(ks[4], (r, di), dtype, r ** -0.5),
+        dt_bias=init_dt_bias(cfg, ks[5], di),
+        # [state, inner], as the state rests.
+        a_log=jnp.broadcast_to(jnp.log(jnp.arange(
+            1, n + 1, dtype=jnp.float32))[:, None], (n, di)),
+        d=jnp.ones((di,), jnp.float32),
+        w_out=init_normal(ks[6], (di, h), dtype))
+    if norm_key is not None:
+        for name, key, width in zip(("dt_ln", "b_ln", "c_ln"),
+                                    jax.random.split(norm_key, 3),
+                                    (r, n, n)):
+            lp[name] = (1.0 + INNER_NORM_STD * jax.random.normal(
+                key, (width,), jnp.float32)).astype(dtype)
+    return lp
+
+
+def _time_step(cfg: ModelConfig, lp: Params, u):
+    """u [..., inner] float32, the conv's output -> (dt [..., inner], B
+    and C [..., state]): the two small projections in float32 at the
+    highest precision — the time step's error compounds through every
+    later position of the state.  Where the layer's weights hold the
+    inner norms' gains, ``delta``, ``B`` and ``C`` are RMS-normalised
+    between the two projections."""
+    r, n = cfg.ssm_dt_rank, cfg.ssm_state
+    dbc = jnp.einsum("...c,cr->...r", u, lp["w_x"].astype(jnp.float32),
+                     precision=HIGHEST)
+    delta, b, c = dbc[..., :r], dbc[..., r:r + n], dbc[..., r + n:]
+    if "dt_ln" in lp:
+        with jax.named_scope("ssm_inner_norms"):
+            delta, b, c = (_norm_f32(a, lp[key], cfg.norm_eps)
+                           for a, key in ((delta, "dt_ln"), (b, "b_ln"),
+                                          (c, "c_ln")))
+    dt = jnp.einsum("...r,rc->...c", delta, lp["w_dt"].astype(jnp.float32),
+                    precision=HIGHEST)
+    return jax.nn.softplus(dt + lp["dt_bias"]), b, c
+
+
+def mamba1_step(cfg: ModelConfig, lp: Params, a, state, tail, valid):
+    """The one-step recurrence over ROWS: a [R, inner] the row's token
+    (before the conv), state [R, state, inner] float32, tail [R, K-1,
+    inner], valid [R].  Returns (m [R, inner] float32, state, tail); a row
+    that is not ``valid`` keeps both bit-identical."""
+    with jax.named_scope("ssm_conv"):
+        window = jnp.concatenate([tail, a[:, None]], axis=1)     # [R, K, C]
+        u = jnp.sum(window.astype(jnp.float32)
+                    * lp["conv_w"].astype(jnp.float32), axis=1)
+        u = jax.nn.silu(u + lp["conv_b"].astype(jnp.float32))
+        tail = jnp.where(valid[:, None, None], window[:, 1:], tail)
+    with jax.named_scope("ssm_step"):
+        dt, b, c = _time_step(cfg, lp, u)
+        decay = jnp.exp(dt[:, None, :] * -jnp.exp(lp["a_log"]))
+        new = decay * state + (dt * u)[:, None, :] * b[:, :, None]
+        new = jnp.where(valid[:, None, None], new, state)
+        m = jnp.sum(new * c[:, :, None], axis=1) + lp["d"] * u
+    return m, new, tail
+
+
+def scan_unrolled(dt, u, b, c, a_mat, state):
+    """``ops.ssm_chunk_scan`` as XLA operations, the loop unrolled when
+    the program is traced: what the kernel is held to, and what the CPU's
+    tests run at sizes the kernel does not serve.  Never a compiled
+    program's (``mamba1_scan`` refuses): the chip's compiler takes
+    minutes a program over its bodies."""
+    fed = dt * u
+    ys = []
+    for t in range(dt.shape[0]):
+        state = (jnp.exp(dt[t][None, :] * a_mat) * state
+                 + fed[t][None, :] * b[t][:, None])
+        ys.append(jnp.sum(state * c[t][:, None], axis=0))
+    return jnp.stack(ys), state
+
+
+def mamba1_scan(cfg: ModelConfig, lp: Params, a, state, tail, n_valid):
+    """The same recurrence over a CHUNK of one sequence, a position at a
+    time in order (``ops.ssm_chunk_scan``: the loop is the kernel's, none
+    is lowered): a [S, inner], from ``state`` [state, inner] and ``tail``
+    [K-1, inner]; positions ``>= n_valid`` are right padding — their time
+    step is 0, so they neither decay nor feed the state, and the tail is
+    taken from the last valid rows.  Returns (m [S, inner] float32, state,
+    tail)."""
+    s_c, k = a.shape[0], cfg.ssm_conv
+    with jax.named_scope("ssm_conv"):
+        seq = jnp.concatenate([tail, a], axis=0)                 # [S+K-1, C]
+        w = lp["conv_w"].astype(jnp.float32)
+        u = sum(seq[j:j + s_c].astype(jnp.float32) * w[j] for j in range(k))
+        u = jax.nn.silu(u + lp["conv_b"].astype(jnp.float32))
+        tail = jax.lax.dynamic_slice_in_dim(seq, n_valid, k - 1, axis=0)
+    with jax.named_scope("ssm_scan"):
+        dt, b, c = _time_step(cfg, lp, u)
+        dt = jnp.where((jnp.arange(s_c) < n_valid)[:, None], dt, 0.0)
+        a_mat = -jnp.exp(lp["a_log"])                        # [N, inner]
+        if ssm_chunk_scan.serves(s_c, cfg.ssm_state, cfg.ssm_inner):
+            scan = ssm_chunk_scan.ssm_chunk_scan
+        elif pallas_attention.kernel_mode() == "interpret":   # CPU tests
+            scan = scan_unrolled
+        else:
+            raise ValueError(
+                f"{cfg.name}: ops.ssm_chunk_scan does not serve a chunk "
+                f"of {s_c} positions x ssm_state {cfg.ssm_state} x "
+                f"ssm_inner {cfg.ssm_inner} (whole lane-widths of "
+                f"channels, states and positions in eights, B and C of "
+                f"the chunk in VMEM), and nothing else scans a chunk in a "
+                f"compiled program")
+        y, state = scan(dt, u, b, c, a_mat, state)
+        m = y + lp["d"] * u
+    return m, state, tail
+
+
+def mamba1(cfg: ModelConfig, lp: Params, h_in, pool, li, ctx):
+    """h_in [B, S, H] -> (mixer output, pool, the scan's output m [B, S,
+    inner] float32, before the gate and with the ``D`` term); ``li`` the
+    layer's index among the state-space layers.  ``pool["s"]`` [layers,
+    R, state, inner] float32 (the channels fill the chip's lanes),
+    ``pool["t"]`` [layers, R, K-1, inner]."""
+    di = cfg.ssm_inner
+    with jax.named_scope("ssm_in_proj"):
+        az = quant.matmul(h_in, lp["w_in"])
+        a, z = az[..., :di], az[..., di:]
+    s_all, t_all = pool["s"], pool["t"]
+    if "row" in ctx:                               # a chunk of one sequence
+        row, fresh = ctx["row"], ctx["fresh"]
+        state = jnp.where(fresh, 0.0, s_all[li, row])
+        tail = jnp.where(fresh, jnp.zeros((), t_all.dtype), t_all[li, row])
+        m, state, tail = mamba1_scan(cfg, lp, a[0], state, tail,
+                                     ctx["n_valid"])
+        pool = {**pool, "s": s_all.at[li, row].set(state),
+                "t": t_all.at[li, row].set(tail)}
+        m = m[None]
+    else:                                          # a decode step, by rows
+        src, valid, dst = ctx["rows"]
+        m, state, tail = mamba1_step(cfg, lp, a[src, 0], s_all[li],
+                                     t_all[li], valid)
+        pool = {**pool, "s": s_all.at[li].set(state),
+                "t": t_all.at[li].set(tail)}
+        m = m[dst][:, None]
+    out = (m * jax.nn.silu(z.astype(jnp.float32))).astype(h_in.dtype)
+    return quant.matmul(out, lp["w_out"]), pool, m
+
+
+# =============================================================================
+# This family's own mixers: Mamba-2, attention, experts, the MLP
 # =============================================================================
 
 def _split_in(cfg: ModelConfig, zxbcdt: jax.Array):
@@ -494,8 +693,9 @@ def _norm_f32(x, w, eps):
 def forward_paged(cfg: ModelConfig, params: Params, tokens: jax.Array,
                   pool, ctx: Dict[str, Any]):
     """tokens [B, S]; ``pool`` {"k", "v": [attention layers, NB, bs,
-    N_kv * D], "s": [state-space layers, R, heads, P, N] float32, "t":
-    [state-space layers, R, K-1, C], "owner": [R]}.  ``ctx`` is what the
+    N_kv * D], "s": [state-space layers, R, heads, P, N] float32 (Mamba-1:
+    [.., R, state, inner]), "t": [state-space layers, R, K-1, C],
+    "owner": [R]}.  ``ctx`` is what the
     mixers need of where the tokens sit (``chunk_ctx`` / ``decode_ctx``).
     Returns (hidden [B, S, H] after the final norm, pool, counts [expert
     layers, experts_held + 1])."""
@@ -524,9 +724,17 @@ def forward_paged(cfg: ModelConfig, params: Params, tokens: jax.Array,
             before, per_period = index[kind]
             li = p * per_period + before[j]
             h_f32 = _norm_f32(x, lp["ln"], cfg.norm_eps)
-            if kind == "M":
+            if kind == "M" and cfg.ssm_dt_rank:
+                out, carried, _ = mamba1(cfg, lp, h_f32.astype(dtype),
+                                         carried, li, ctx)
+            elif kind == "M":
                 out, carried = _mamba(cfg, lp, h_f32.astype(dtype), carried,
                                       li, ctx)
+            elif kind == "-":
+                with jax.named_scope("ffn"):
+                    out = transformer._swiglu(
+                        h_f32.astype(dtype), lp["w_gate"], lp["w_up"],
+                        lp["w_down"])
             elif kind == "*":
                 out, carried = _attention(cfg, lp, h_f32.astype(dtype),
                                           carried, li, ctx)

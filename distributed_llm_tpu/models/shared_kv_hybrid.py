@@ -13,10 +13,11 @@ mixer by the layer's character:
   channel AND state; ``S_t = exp(dt_t A) * S_{t-1} + (dt_t a_t) (x) B_t``;
   ``m_t = S_t C_t + D a_t``; out ``(m * silu(z)) W_out``.  The decay is
   not a scalar a head, so the chunk recurrence has no matrix form
-  (models/hybrid_ssm.py's): a chunk's positions are stepped IN ORDER by a
-  Pallas kernel (``ops/ssm_chunk_scan.py``: the loop is inside the
-  kernel, nothing lowers to a ``while``), float32, the reference's own
-  order of operations.  A sequence keeps ``S`` and the
+  (models/hybrid_ssm.py's Mamba-2): a chunk's positions are stepped IN
+  ORDER by a Pallas kernel (``ops/ssm_chunk_scan.py``: the loop is inside
+  the kernel, nothing lowers to a ``while``), float32, the reference's
+  own order of operations.  The mixer is ``hybrid_ssm.mamba1``, the one
+  copy both row families run; this family's has no inner norms.  A sequence keeps ``S`` and the
   conv's last ``ssm_conv - 1`` input rows a layer, in a ROW of
   ``pool["s"]`` / ``pool["t"]``.  ``S`` rests as ``[state, inner]``: the
   channels fill the chip's 128 lanes (``[inner, 16]`` would rest padded
@@ -67,14 +68,16 @@ from typing import Any, Dict
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from ..config import ModelConfig
-from ..ops import pallas_attention, quant, ssm_chunk_scan
+from ..ops import quant
 from ..ops.attention import NEG_INF
 from . import hybrid_ssm
-from .hybrid_ssm import init_uniform
-from .latent_moe import EMBED_STD, HIGHEST, init_normal, init_table
+# The ONE Mamba-1 mixer, both row families' (here without inner norms).
+from .hybrid_ssm import (init_mamba1, mamba1 as _mamba,   # noqa: F401
+                         mamba1_scan as ssm_scan, mamba1_step as ssm_step,
+                         scan_unrolled)
+from .latent_moe import EMBED_STD, init_normal, init_table
 
 Params = Dict[str, Any]
 KINDS = "MWFGX"
@@ -129,24 +132,7 @@ def init_layer(cfg: ModelConfig, key, kind: str) -> Params:
           "w1": init_normal(ks[2], (h, 2 * f), dtype),
           "w2": init_normal(ks[3], (f, h), dtype)}
     if kind == "M":
-        # The published init: dt log-uniform in [dt_min, dt_max], floored,
-        # stored as the inverse of softplus; A = 1..state a channel; D 1.
-        dt = jnp.exp(jax.random.uniform(ks[9], (di,), jnp.float32)
-                     * (np.log(cfg.ssm_dt_max) - np.log(cfg.ssm_dt_min))
-                     + np.log(cfg.ssm_dt_min))
-        dt = jnp.maximum(dt, cfg.ssm_dt_floor)
-        lp.update(
-            w_in=init_normal(ks[4], (h, 2 * di), dtype),
-            conv_w=init_uniform(ks[5], (k, di), dtype, k ** -0.5),
-            conv_b=init_uniform(ks[6], (di,), dtype, k ** -0.5),
-            w_x=init_normal(ks[7], (di, r + 2 * n), dtype),
-            w_dt=init_uniform(ks[8], (r, di), dtype, r ** -0.5),
-            dt_bias=dt + jnp.log(-jnp.expm1(-dt)),
-            # [state, inner], as the state rests.
-            a_log=jnp.broadcast_to(jnp.log(jnp.arange(
-                1, n + 1, dtype=jnp.float32))[:, None], (n, di)),
-            d=jnp.ones((di,), jnp.float32),
-            w_out=init_normal(ks[10], (di, h), dtype))
+        lp.update(init_mamba1(cfg, ks[4:11]))
     elif kind in "WFX":
         if kind == "X":
             lp.update(wq=init_normal(ks[4], (h, nq), dtype),
@@ -201,118 +187,6 @@ def layer_norm(x, w, b, eps):
     var = jnp.mean(jnp.square(xf - mean), axis=-1, keepdims=True)
     y = (xf - mean) * jax.lax.rsqrt(var + eps)
     return (y * w.astype(jnp.float32) + b.astype(jnp.float32)).astype(x.dtype)
-
-
-def _time_step(cfg: ModelConfig, lp: Params, u):
-    """u [..., inner] float32, the conv's output -> (dt [..., inner], B
-    and C [..., state]): the two small projections in float32 at the
-    highest precision — the time step's error compounds through every
-    later position of the state."""
-    r, n = cfg.ssm_dt_rank, cfg.ssm_state
-    dbc = jnp.einsum("...c,cr->...r", u, lp["w_x"].astype(jnp.float32),
-                     precision=HIGHEST)
-    dt = jnp.einsum("...r,rc->...c", dbc[..., :r],
-                    lp["w_dt"].astype(jnp.float32), precision=HIGHEST)
-    return (jax.nn.softplus(dt + lp["dt_bias"]), dbc[..., r:r + n],
-            dbc[..., r + n:])
-
-
-def ssm_step(cfg: ModelConfig, lp: Params, a, state, tail, valid):
-    """The one-step recurrence over ROWS: a [R, inner] the row's token
-    (before the conv), state [R, state, inner] float32, tail [R, K-1,
-    inner], valid [R].  Returns (m [R, inner] float32, state, tail); a row
-    that is not ``valid`` keeps both bit-identical."""
-    with jax.named_scope("ssm_conv"):
-        window = jnp.concatenate([tail, a[:, None]], axis=1)     # [R, K, C]
-        u = jnp.sum(window.astype(jnp.float32)
-                    * lp["conv_w"].astype(jnp.float32), axis=1)
-        u = jax.nn.silu(u + lp["conv_b"].astype(jnp.float32))
-        tail = jnp.where(valid[:, None, None], window[:, 1:], tail)
-    with jax.named_scope("ssm_step"):
-        dt, b, c = _time_step(cfg, lp, u)
-        decay = jnp.exp(dt[:, None, :] * -jnp.exp(lp["a_log"]))
-        new = decay * state + (dt * u)[:, None, :] * b[:, :, None]
-        new = jnp.where(valid[:, None, None], new, state)
-        m = jnp.sum(new * c[:, :, None], axis=1) + lp["d"] * u
-    return m, new, tail
-
-
-def scan_unrolled(dt, u, b, c, a_mat, state):
-    """``ops.ssm_chunk_scan`` as XLA operations, the loop unrolled when
-    the program is traced: what the kernel is held to, and what the CPU's
-    tests run at sizes the kernel does not serve.  Never a compiled
-    program's (``ssm_scan`` refuses): the chip's compiler takes minutes a
-    program over its bodies."""
-    fed = dt * u
-    ys = []
-    for t in range(dt.shape[0]):
-        state = (jnp.exp(dt[t][None, :] * a_mat) * state
-                 + fed[t][None, :] * b[t][:, None])
-        ys.append(jnp.sum(state * c[t][:, None], axis=0))
-    return jnp.stack(ys), state
-
-
-def ssm_scan(cfg: ModelConfig, lp: Params, a, state, tail, n_valid):
-    """The same recurrence over a CHUNK of one sequence, a position at a
-    time in order (``ops.ssm_chunk_scan``: the loop is the kernel's, none
-    is lowered): a [S, inner], from ``state`` [state, inner] and ``tail``
-    [K-1, inner]; positions ``>= n_valid`` are right padding — their time
-    step is 0, so they neither decay nor feed the state, and the tail is
-    taken from the last valid rows.  Returns (m [S, inner] float32, state,
-    tail)."""
-    s_c, k = a.shape[0], cfg.ssm_conv
-    with jax.named_scope("ssm_conv"):
-        seq = jnp.concatenate([tail, a], axis=0)                 # [S+K-1, C]
-        w = lp["conv_w"].astype(jnp.float32)
-        u = sum(seq[j:j + s_c].astype(jnp.float32) * w[j] for j in range(k))
-        u = jax.nn.silu(u + lp["conv_b"].astype(jnp.float32))
-        tail = jax.lax.dynamic_slice_in_dim(seq, n_valid, k - 1, axis=0)
-    with jax.named_scope("ssm_scan"):
-        dt, b, c = _time_step(cfg, lp, u)
-        dt = jnp.where((jnp.arange(s_c) < n_valid)[:, None], dt, 0.0)
-        a_mat = -jnp.exp(lp["a_log"])                        # [N, inner]
-        if ssm_chunk_scan.serves(s_c, cfg.ssm_state, cfg.ssm_inner):
-            scan = ssm_chunk_scan.ssm_chunk_scan
-        elif pallas_attention.kernel_mode() == "interpret":   # CPU tests
-            scan = scan_unrolled
-        else:
-            raise ValueError(
-                f"{cfg.name}: ops.ssm_chunk_scan does not serve a chunk "
-                f"of {s_c} positions x ssm_state {cfg.ssm_state} x "
-                f"ssm_inner {cfg.ssm_inner} (whole lane-widths of "
-                f"channels, states and positions in eights, B and C of "
-                f"the chunk in VMEM), and nothing else scans a chunk in a "
-                f"compiled program")
-        y, state = scan(dt, u, b, c, a_mat, state)
-        m = y + lp["d"] * u
-    return m, state, tail
-
-
-def _mamba(cfg: ModelConfig, lp: Params, h_in, pool, li, ctx):
-    """h_in [B, S, H] -> (mixer output, pool, the memory m [B, S, inner]
-    float32); ``li`` the layer's index among the state-space layers."""
-    di = cfg.ssm_inner
-    with jax.named_scope("ssm_in_proj"):
-        az = quant.matmul(h_in, lp["w_in"])
-        a, z = az[..., :di], az[..., di:]
-    s_all, t_all = pool["s"], pool["t"]
-    if "row" in ctx:                               # a chunk of one sequence
-        row, fresh = ctx["row"], ctx["fresh"]
-        state = jnp.where(fresh, 0.0, s_all[li, row])
-        tail = jnp.where(fresh, jnp.zeros((), t_all.dtype), t_all[li, row])
-        m, state, tail = ssm_scan(cfg, lp, a[0], state, tail, ctx["n_valid"])
-        pool = {**pool, "s": s_all.at[li, row].set(state),
-                "t": t_all.at[li, row].set(tail)}
-        m = m[None]
-    else:                                          # a decode step, by rows
-        src, valid, dst = ctx["rows"]
-        m, state, tail = ssm_step(cfg, lp, a[src, 0], s_all[li], t_all[li],
-                                  valid)
-        pool = {**pool, "s": s_all.at[li].set(state),
-                "t": t_all.at[li].set(tail)}
-        m = m[dst][:, None]
-    out = (m * jax.nn.silu(z.astype(jnp.float32))).astype(h_in.dtype)
-    return quant.matmul(out, lp["w_out"]), pool, m
 
 
 def _pairing(cfg: ModelConfig):

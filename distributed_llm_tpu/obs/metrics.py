@@ -443,6 +443,13 @@ METRIC_REGISTRY: Tuple[Tuple[str, str, str, Tuple[str, ...], str], ...] = (
      "Positions the chunked-prefill lane's chunks attended: each "
      "dispatched chunk's window rung (the smallest of 256, 1024, 2048, "
      "4096, ... and the slot's span that holds the chunk's end)"),
+    ("prefill_chunks_by_window", "counter",
+     "dllm_prefill_chunks_by_window_total", ("tier", "window"),
+     "Of dllm_prefill_chunks_total, the chunks by the window rung each "
+     "ran at (window: positions, one compiled chunk program a rung): a "
+     "16 k-token prompt's 64 chunks laid against the ladder, 1 at 256, "
+     "3 at 1024, 4, 8, 16 and 32 at the doublings (/stats "
+     "prefill.chunks_by_window)"),
     ("prefill_written_positions", "counter",
      "dllm_prefill_written_positions_total", ("tier",),
      "Positions written when each of those chunks ran (its end, capped "
@@ -470,8 +477,9 @@ METRIC_REGISTRY: Tuple[Tuple[str, str, str, Tuple[str, ...], str], ...] = (
      "program does not hold (ModelConfig.experts_first/experts_count: "
      "the other rank of an expert-parallel pair computes them), by "
      "stage; held + absent = every assignment"),
-    # The state-space hybrid family (models/hybrid_ssm.py): a sequence's
-    # recurrent row is zeroed when its prompt's first chunk starts.
+    # The row families (models/hybrid_ssm.py, shared_kv_hybrid.py): a
+    # sequence's recurrent row is zeroed when its prompt's first chunk
+    # starts.
     ("state_resets", "counter", "dllm_state_resets_total", ("tier",),
      "Recurrent rows started from zero: prompts (and preemption "
      "replays) whose first chunk was dispatched"),
@@ -745,6 +753,10 @@ BOUNDED_LABELS: Dict[str, str] = {
     "what": "closed set: pos|cur|temps|tables|owner (the decode tick's "
             "small inputs, engine/batching.py _count_prepare_upload)",
     "clock": "closed set: wall|cpu",
+    "window": "closed set: the rungs of the chunked-prefill lane's window "
+              "ladder (engine/batching.py _window_ladder: 256, 1024 and "
+              "its doublings below the slot's span, the span; 7 at a "
+              "span of 32768)",
 }
 
 

@@ -40,12 +40,21 @@ def _attention_params(cfg) -> int:
     return h * h + 2 * h * kv + h * h
 
 
+def _mamba1_params(cfg) -> int:
+    """One Mamba-1 mixer's four matrices (``hybrid_ssm.mamba1``, both row
+    families'): in, to [delta | B | C], the time step's, out."""
+    h, di = cfg.hidden_size, cfg.ssm_inner
+    return (h * 2 * di + di * (cfg.ssm_dt_rank + 2 * cfg.ssm_state)
+            + cfg.ssm_dt_rank * di + di * h)
+
+
 def _hybrid_layer_params(cfg):
     """Matrix parameters of one layer of each kind of the hybrid family
     (models/hybrid_ssm.py): (state-space, attention, an expert layer
     without its routed experts, one routed expert)."""
     h, d = cfg.hidden_size, cfg.head_dim
-    ssm = (h * (cfg.ssm_inner + cfg.ssm_conv_width + cfg.ssm_heads)
+    ssm = (_mamba1_params(cfg) if cfg.ssm_dt_rank else
+           h * (cfg.ssm_inner + cfg.ssm_conv_width + cfg.ssm_heads)
            + cfg.ssm_inner * h)
     attn = 2 * h * cfg.num_heads * d + 2 * h * cfg.num_kv_heads * d
     fixed = h * cfg.num_experts + 2 * h * cfg.shared_ffn_size
@@ -56,7 +65,8 @@ def _hybrid_params(cfg, experts: float) -> float:
     """All layers' matrices with ``experts`` routed experts a layer."""
     ssm, attn, fixed, expert = _hybrid_layer_params(cfg)
     return (cfg.layers_of("M") * ssm + cfg.layers_of("*") * attn
-            + cfg.layers_of("E") * (fixed + experts * expert))
+            + cfg.layers_of("E") * (fixed + experts * expert)
+            + cfg.layers_of("-") * 3 * cfg.hidden_size * cfg.ffn_size)
 
 
 def _shared_kv_params(cfg) -> int:
@@ -66,9 +76,7 @@ def _shared_kv_params(cfg) -> int:
     and a gated MLP every layer."""
     h, di = cfg.hidden_size, cfg.ssm_inner
     nq, kv = cfg.num_heads * cfg.head_dim, cfg.cache_row_width
-    ssm = (h * 2 * di + di * (cfg.ssm_dt_rank + 2 * cfg.ssm_state)
-           + cfg.ssm_dt_rank * di + di * h)
-    return (cfg.layers_of("M") * ssm
+    return (cfg.layers_of("M") * _mamba1_params(cfg)
             + (cfg.layers_of("W") + 1) * (h * (nq + 2 * kv) + nq * h)
             + cfg.layers_of("X") * 2 * h * nq
             + cfg.layers_of("G") * 2 * h * di
@@ -133,8 +141,10 @@ def weight_bytes(cfg, quantize: str = "none") -> int:
                 + (cfg.vocab_size * h + (4 * cfg.num_layers + 2) * h) * 2)
     if cfg.family == "hybrid":
         # Every held expert (an upper bound, as for the latent family).
+        tables = 1 if cfg.tie_embeddings else 2
         return (int(_hybrid_params(cfg, cfg.experts_held)) * per_param
-                + (2 * cfg.vocab_size * h + (cfg.num_layers + 1) * h) * 2)
+                + (tables * cfg.vocab_size * h
+                   + (cfg.num_layers + 1) * h) * 2)
     dense, moe = _layer_counts(cfg)
     expert = 3 * h * (cfg.moe_ffn_size or cfg.ffn_size)
     held = cfg.num_experts + cfg.shared_experts
