@@ -38,6 +38,10 @@ KERNEL_TOL = {"float32": 2e-5, ("float32", "chunk"): 2e-3, "bfloat16": 2e-2,
               ("float32", "grouped_product"): 2e-4,
               ("bfloat16", "grouped_product"): 5e-2}
 
+# Mamba-1's chunk scan as both row cells of the benchmark run it:
+# positions, states, channels.
+SCAN_SHAPE = (256, 16, 5120)
+
 LONG_SENTENCE = ("Rivers carry sediment from the mountains to the delta, "
                  "where the channels split, slow down and drop their load. ")
 
@@ -611,7 +615,7 @@ def kernel_cases(nq: int, nkv: int, d: int, dtype, *, batch: int = 8,
                  chunk: int = 64, chunk_window: int = 2048,
                  verify_q: int = 5,
                  grouped: Optional[Dict[str, tuple]] = None,
-                 scan: tuple = (256, 16, 5120)
+                 scan: tuple = SCAN_SHAPE
                  ) -> Dict[str, KernelCase]:
     """The main path's Pallas kernels at one head geometry, each with
     its XLA reference and a seeded argument builder.  chip_smoke runs
@@ -824,6 +828,7 @@ def phase_kernels() -> None:
     import jax.numpy as jnp
 
     from distributed_llm_tpu.config import MODEL_PRESETS
+    from distributed_llm_tpu.ops import ssm_chunk_scan
     from distributed_llm_tpu.ops.pallas_attention import kernel_mode
     check(kernel_mode() == "compiled",
           f"the Pallas kernels are in {kernel_mode()} mode")
@@ -831,6 +836,10 @@ def phase_kernels() -> None:
     say("kernels", f"nano_1b widths: Nq={cfg.num_heads} "
                    f"Nkv={cfg.num_kv_heads} D={cfg.head_dim}; kernels "
                    f"compiled (not interpreted)")
+    say("kernels", f"ssm_chunk_scan at {SCAN_SHAPE} (positions, states, "
+                   f"channels): serves={ssm_chunk_scan.serves(*SCAN_SHAPE)}"
+                   f", {ssm_chunk_scan.lane_widths(SCAN_SHAPE[2])} "
+                   f"lane-width(s) of channels a grid step")
     for dtype in (jnp.float32, jnp.bfloat16):
         compare_kernels(kernel_cases(cfg.num_heads, cfg.num_kv_heads,
                                      cfg.head_dim, dtype),

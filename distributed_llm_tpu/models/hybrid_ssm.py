@@ -383,7 +383,11 @@ def mamba1_scan(cfg: ModelConfig, lp: Params, a, state, tail, n_valid):
     is lowered): a [S, inner], from ``state`` [state, inner] and ``tail``
     [K-1, inner]; positions ``>= n_valid`` are right padding — their time
     step is 0, so they neither decay nor feed the state, and the tail is
-    taken from the last valid rows.  Returns (m [S, inner] float32, state,
+    taken from the last valid rows.  The kernel is handed ``dt``, ``u``,
+    ``a_mat`` and the state as they rest here (it reads their tiles in
+    place, 1024 channels a register) and ``B`` and ``C`` as the
+    projection's two narrow slices (it reads them as scalars): nothing is
+    spread or re-laid for it.  Returns (m [S, inner] float32, state,
     tail)."""
     s_c, k = a.shape[0], cfg.ssm_conv
     with jax.named_scope("ssm_conv"):
@@ -405,9 +409,9 @@ def mamba1_scan(cfg: ModelConfig, lp: Params, a, state, tail, n_valid):
                 f"{cfg.name}: ops.ssm_chunk_scan does not serve a chunk "
                 f"of {s_c} positions x ssm_state {cfg.ssm_state} x "
                 f"ssm_inner {cfg.ssm_inner} (whole lane-widths of "
-                f"channels, states and positions in eights, B and C of "
-                f"the chunk in VMEM), and nothing else scans a chunk in a "
-                f"compiled program")
+                f"channels, states and positions in eights, the chunk's "
+                f"dt, u and y in VMEM), and nothing else scans a chunk in "
+                f"a compiled program")
         y, state = scan(dt, u, b, c, a_mat, state)
         m = y + lp["d"] * u
     return m, state, tail

@@ -238,33 +238,113 @@ def test_one_step_update_equals_the_chunk_scan_position_by_position():
     np.testing.assert_array_equal(np.asarray(t2), np.asarray(t))
 
 
-def test_the_chunk_scans_kernel_equals_the_unrolled_recurrence():
+def test_what_the_chunk_scans_kernel_serves_and_in_which_form():
     from distributed_llm_tpu.ops import ssm_chunk_scan
-    rng = np.random.default_rng(2)
-    t, n, c = 24, 8, 256
-    assert ssm_chunk_scan.serves(t, n, c)
-    assert not ssm_chunk_scan.serves(t, n, 192)       # whole lane-widths
-    assert not ssm_chunk_scan.serves(4096, 16, 5120)  # B and C in VMEM
+    assert ssm_chunk_scan.serves(24, 8, 256)
+    assert not ssm_chunk_scan.serves(24, 8, 192)      # whole lane-widths
+    assert not ssm_chunk_scan.serves(4096, 16, 5120)  # the chunk in VMEM
     assert ssm_chunk_scan.serves(256, 16, 5120)
+    # Whole tiles of 8 positions and of 8 states (the operands' view).
+    assert not ssm_chunk_scan.serves(20, 8, 256)
+    assert not ssm_chunk_scan.serves(24, 12, 256)
+    # Without the spreads of B and C a chunk may be longer than the 384
+    # positions of the form before: 24.6 KB a position of 14 MB
+    # (tests/test_tpu_compile.py compiles the longest for a v5e).
+    assert ssm_chunk_scan.serves(512, 16, 5120)
+    assert ssm_chunk_scan.serves(576, 16, 5120)
+    assert not ssm_chunk_scan.serves(584, 16, 5120)
+    # B and C whole in SMEM: 128 B a position of 256 KB.
+    assert ssm_chunk_scan.serves(2048, 16, 128)
+    assert not ssm_chunk_scan.serves(2056, 16, 128)
+    # The form is the shape's alone: the widest of 8, 4, 2, 1 lane-widths
+    # that divides the channels.
+    assert [ssm_chunk_scan.lane_widths(c) for c in
+            (128, 256, 384, 512, 768, 1024, 2048, 5120)
+            ] == [1, 2, 1, 4, 2, 8, 8, 8]
+
+
+@pytest.mark.parametrize("t,n,c,form", [
+    (16, 8, 128, 1),       # one lane-width
+    (24, 8, 256, 2),       # the tiny presets' widths
+    (16, 8, 384, 1),       # three grid steps of one lane-width
+    (16, 16, 512, 4),
+    (16, 16, 1024, 8),     # the widest form: whole [8, 128] registers
+    (24, 16, 2048, 8),     # and two grid steps of it, as 5120 has five
+])
+def test_the_chunk_scans_kernel_equals_the_unrolled_recurrence(t, n, c,
+                                                               form):
+    from distributed_llm_tpu.ops import ssm_chunk_scan
+    assert ssm_chunk_scan.serves(t, n, c)
+    assert ssm_chunk_scan.lane_widths(c) == form
+    print(f"ssm_chunk_scan [{t}, {n}, {c}]: {form} lane-width(s) a grid "
+          f"step, {c // (128 * form)} step(s)")
+    rng = np.random.default_rng(2)
+    valid = t - 5                     # padding starts INSIDE a tile of 8
     dt = jnp.asarray(np.abs(rng.normal(size=(t, c))) * 0.1, jnp.float32)
-    dt = dt.at[20:].set(0.0)                          # padding
+    dt = dt.at[valid:].set(0.0)
     u = jnp.asarray(rng.normal(size=(t, c)), jnp.float32)
     b = jnp.asarray(rng.normal(size=(t, n)), jnp.float32)
     cm = jnp.asarray(rng.normal(size=(t, n)), jnp.float32)
     a = -jnp.asarray(1 + np.abs(rng.normal(size=(n, c))), jnp.float32)
     s0 = jnp.asarray(rng.normal(size=(n, c)), jnp.float32)
-    y, s = ssm_chunk_scan.ssm_chunk_scan(dt, u, b, cm, a, s0)
-    y_x, s_x = skv.scan_unrolled(dt, u, b, cm, a, s0)
-    # One recurrence in one order, float32, two compilers: 1e-5 of
-    # outputs of size 3 (2e-6 seen).
-    np.testing.assert_allclose(np.asarray(y), np.asarray(y_x), atol=1e-5,
-                               rtol=0)
-    np.testing.assert_allclose(np.asarray(s), np.asarray(s_x), atol=1e-5,
-                               rtol=0)
-    # Padding (dt = 0) neither decays nor feeds the state.
-    _, s_cut = ssm_chunk_scan.ssm_chunk_scan(dt[:20], u[:20], b[:20],
-                                             cm[:20], a, s0)
-    np.testing.assert_array_equal(np.asarray(s), np.asarray(s_cut))
+    for first in (jnp.zeros_like(s0), s0):       # a fresh row starts at 0
+        y, s = ssm_chunk_scan.ssm_chunk_scan(dt, u, b, cm, a, first)
+        y_x, s_x = skv.scan_unrolled(dt, u, b, cm, a, first)
+        # One recurrence in one order, float32, two compilers and two
+        # orders of a position's 16 products: 1e-5 of outputs of size 3
+        # (2e-6 seen).
+        np.testing.assert_allclose(np.asarray(y), np.asarray(y_x),
+                                   atol=1e-5, rtol=0)
+        np.testing.assert_allclose(np.asarray(s), np.asarray(s_x),
+                                   atol=1e-5, rtol=0)
+    # Padding (dt = 0) neither decays nor feeds the state: the recurrence
+    # over the valid positions alone, and the kernel with a whole tile of
+    # padding more, bit for bit.
+    _, s_valid = skv.scan_unrolled(dt[:valid], u[:valid], b[:valid],
+                                   cm[:valid], a, s0)
+
+    def pad(x):
+        return jnp.concatenate([x, jnp.zeros((8,) + x.shape[1:])])
+    y_pad, s_pad = ssm_chunk_scan.ssm_chunk_scan(
+        pad(dt), pad(u), pad(b), pad(cm), a, s0)
+    np.testing.assert_allclose(np.asarray(s), np.asarray(s_valid),
+                               atol=1e-5, rtol=0)
+    np.testing.assert_array_equal(np.asarray(s_pad), np.asarray(s))
+    np.testing.assert_array_equal(np.asarray(y_pad[:t]), np.asarray(y))
+    # Two chained calls are the one call cut in two: bit for bit.
+    y1, s1 = ssm_chunk_scan.ssm_chunk_scan(dt[:8], u[:8], b[:8], cm[:8],
+                                           a, s0)
+    y2, s2 = ssm_chunk_scan.ssm_chunk_scan(dt[8:], u[8:], b[8:], cm[8:],
+                                           a, s1)
+    np.testing.assert_array_equal(np.asarray(jnp.concatenate([y1, y2])),
+                                  np.asarray(y))
+    np.testing.assert_array_equal(np.asarray(s2), np.asarray(s))
+
+
+def test_a_program_that_calls_the_chunk_scan_13_times_traces_it_once(
+        monkeypatch):
+    # The kernel's body is 8 x state updates written out in Python: traced
+    # a call, 13 calls a chunk program and a program a window rung, it was
+    # 5 s a program of every engine's set-up (PR 47).  A shape no other
+    # test of this process uses, so the count is this test's own.
+    from distributed_llm_tpu.ops import ssm_chunk_scan
+    traced = []
+    kernel = ssm_chunk_scan._kernel
+    monkeypatch.setattr(ssm_chunk_scan, "_kernel",
+                        lambda *refs: traced.append(1) or kernel(*refs))
+    t, n, c = 8, 8, 640
+    dt, u = jnp.full((t, c), 0.1), jnp.ones((t, c))
+    b = cm = jnp.ones((t, n))
+    a, s0 = -jnp.ones((n, c)), jnp.zeros((n, c))
+
+    @jax.jit
+    def layers(state):
+        for _ in range(13):
+            _, state = ssm_chunk_scan.ssm_chunk_scan(dt, u, b, cm, a, state)
+        return state
+    layers(s0)
+    jax.jit(lambda s: layers(s) * 2)(s0)          # and a second program
+    assert len(traced) == 1
 
 
 def test_a_state_rounded_to_bfloat16_at_rest_fails_that_tolerance():

@@ -197,6 +197,24 @@ def test_ragged_pallas_nano_tick_compiles_for_v5e(one_chip, as_on_tpu,
     assert compiled.as_text().count("tpu_custom_call") == 1
 
 
+def test_the_chunk_scan_compiles_at_the_longest_chunk_it_serves(
+        one_chip, compiled_kernels):
+    """``ssm_chunk_scan.serves`` counts the blocks' bytes against the
+    kernel's VMEM; here the chip's compiler takes the longest chunk that
+    count admits at the benchmark's widths (576 positions since PR 47),
+    and the count refuses the next tile of 8."""
+    from distributed_llm_tpu.ops import ssm_chunk_scan
+    _, n, inner = chip_smoke.SCAN_SHAPE
+    steps = max(t for t in range(8, 4096, 8)
+                if ssm_chunk_scan.serves(t, n, inner))
+    assert steps == 576
+    case = chip_smoke.kernel_cases(1, 1, 64, jnp.float32,
+                                   scan=(steps, n, inner))["ssm_chunk_scan"]
+    shapes = _on(one_chip, jax.eval_shape(case.make_args))
+    compiled = jax.jit(case.pallas).lower(*shapes).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 1
+
+
 # -- the KV pool stays in place (ISSUE 27) -------------------------------------
 
 def _bench_tier(monkeypatch, config: str = "smollm2-1.7b"):
