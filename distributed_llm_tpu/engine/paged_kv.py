@@ -492,9 +492,7 @@ def chunk_prefill_paged(
 
     def layer(x, pools, lp, i):
         h_in = transformer.rms_norm(x, lp["ln1"], cfg.norm_eps)
-        q = quant.matmul(h_in, lp["wq"]).reshape(b, s_c, cfg.num_heads, d)
-        k = quant.matmul(h_in, lp["wk"]).reshape(b, s_c, cfg.num_kv_heads, d)
-        v = quant.matmul(h_in, lp["wv"]).reshape(b, s_c, cfg.num_kv_heads, d)
+        q, k, v = transformer.project_qkv(cfg, lp, h_in)
         q = transformer.apply_rope(q, sin, cos)
         k = transformer.apply_rope(k, sin, cos)
 
@@ -579,9 +577,7 @@ def verify_step_paged(
     off = wpos % bs
     def layer(x, pools, lp, i):
         h_in = transformer.rms_norm(x, lp["ln1"], cfg.norm_eps)
-        q = quant.matmul(h_in, lp["wq"]).reshape(b, g, cfg.num_heads, d)
-        k = quant.matmul(h_in, lp["wk"]).reshape(b, g, cfg.num_kv_heads, d)
-        v = quant.matmul(h_in, lp["wv"]).reshape(b, g, cfg.num_kv_heads, d)
+        q, k, v = transformer.project_qkv(cfg, lp, h_in)
         q = transformer.apply_rope(q, sin, cos)
         k = transformer.apply_rope(k, sin, cos)
 
@@ -681,9 +677,7 @@ def decode_step_paged(
 
     def layer(x, pools, lp, i):
         h_in = transformer.rms_norm(x, lp["ln1"], cfg.norm_eps)
-        q = quant.matmul(h_in, lp["wq"]).reshape(b, cfg.num_heads, d)
-        k = quant.matmul(h_in, lp["wk"]).reshape(b, cfg.num_kv_heads, d)
-        v = quant.matmul(h_in, lp["wv"]).reshape(b, cfg.num_kv_heads, d)
+        q, k, v = transformer.project_qkv(cfg, lp, h_in)
         q = transformer.apply_rope(q, sin, cos)
         k = transformer.apply_rope(k, sin, cos)
 

@@ -54,9 +54,7 @@ def decode_chunk(cfg, params, tokens: jax.Array, start_pos: jax.Array,
     def layer(x, scanned):
         lp, k_cache, v_cache = scanned                        # [B, S, NKV, D]
         h_in = transformer.rms_norm(x, lp["ln1"], cfg.norm_eps)
-        q = quant.matmul(h_in, lp["wq"]).reshape(b, g, cfg.num_heads, d)
-        k = quant.matmul(h_in, lp["wk"]).reshape(b, g, cfg.num_kv_heads, d)
-        v = quant.matmul(h_in, lp["wv"]).reshape(b, g, cfg.num_kv_heads, d)
+        q, k, v = transformer.project_qkv(cfg, lp, h_in)
         q = transformer.apply_rope(q, sin, cos)
         k = transformer.apply_rope(k, sin, cos)
 

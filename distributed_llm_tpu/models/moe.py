@@ -149,9 +149,7 @@ def prefill(cfg: ModelConfig, params: Params, tokens: jax.Array,
     def layer(carry, lp):
         x, aux = carry
         h_in = transformer.rms_norm(x, lp["ln1"], cfg.norm_eps)
-        q = quant.matmul(h_in, lp["wq"]).reshape(b, s, cfg.num_heads, d)
-        k = quant.matmul(h_in, lp["wk"]).reshape(b, s, cfg.num_kv_heads, d)
-        v = quant.matmul(h_in, lp["wv"]).reshape(b, s, cfg.num_kv_heads, d)
+        q, k, v = transformer.project_qkv(cfg, lp, h_in)
         q = transformer.apply_rope(q, sin, cos)
         k = transformer.apply_rope(k, sin, cos)
         attn = attention.causal(q, k, v, impl=cfg.attention_impl
@@ -192,9 +190,7 @@ def chunk_prefill(cfg: ModelConfig, params: Params, tokens: jax.Array,
     def layer(x, scanned):
         lp, k_cache, v_cache = scanned
         h_in = transformer.rms_norm(x, lp["ln1"], cfg.norm_eps)
-        q = quant.matmul(h_in, lp["wq"]).reshape(b, s_c, cfg.num_heads, d)
-        k = quant.matmul(h_in, lp["wk"]).reshape(b, s_c, cfg.num_kv_heads, d)
-        v = quant.matmul(h_in, lp["wv"]).reshape(b, s_c, cfg.num_kv_heads, d)
+        q, k, v = transformer.project_qkv(cfg, lp, h_in)
         q = transformer.apply_rope(q, sin, cos)
         k = transformer.apply_rope(k, sin, cos)
 
@@ -232,9 +228,7 @@ def decode_step(cfg: ModelConfig, params: Params, token: jax.Array,
     def layer(x, scanned):
         lp, k_cache, v_cache = scanned
         h_in = transformer.rms_norm(x, lp["ln1"], cfg.norm_eps)
-        q = quant.matmul(h_in, lp["wq"]).reshape(b, cfg.num_heads, d)
-        k = quant.matmul(h_in, lp["wk"]).reshape(b, cfg.num_kv_heads, d)
-        v = quant.matmul(h_in, lp["wv"]).reshape(b, cfg.num_kv_heads, d)
+        q, k, v = transformer.project_qkv(cfg, lp, h_in)
         q = transformer.apply_rope(q, sin, cos)
         k = transformer.apply_rope(k, sin, cos)
 

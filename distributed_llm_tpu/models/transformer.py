@@ -99,6 +99,36 @@ def _swiglu(x: jax.Array, gate, up, down) -> jax.Array:
         jax.nn.silu(quant.matmul(x, gate)) * quant.matmul(x, up), down)
 
 
+def project_qkv(cfg: ModelConfig, lp: Params, h_in: jax.Array
+                ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """A layer's q, k and v projections with the heads split off:
+    h_in [..., H] -> q [..., N_q, D], k and v [..., N_kv, D], before the
+    rotary embedding.  The one spelling of it for every dense body
+    (prefill, decode_step, chunk_prefill here; the three paged steps of
+    engine/paged_kv.py).
+
+    q and k cross an ``optimization_barrier`` as the dense [..., N·D]
+    rows the product writes, and only then split heads (ISSUE 48).
+    Without it the TPU compiler's layout assignment carries the
+    ``[.., N, D]`` result's preferred layout back through the product
+    into the WEIGHT: every tick then copies the whole ``wq`` and ``wk``
+    stacks into that layout at its entry, and every layer of a tick or
+    a chunk program writes its ``[H, N·D]`` matrix out before the
+    product reads it, where ``wv``'s product reads the stack in place.
+    The pin holds rows of activations (tiny beside a matrix), it is the
+    identity on values (an int8 weight's scale has multiplied inside
+    ``quant.matmul``, before it) and on the CPU; no weight changes shape
+    or order at rest."""
+    d = cfg.head_dim
+    q, k = jax.lax.optimization_barrier(
+        (quant.matmul(h_in, lp["wq"]), quant.matmul(h_in, lp["wk"])))
+    v = quant.matmul(h_in, lp["wv"])
+    lead = h_in.shape[:-1]
+    return (q.reshape(*lead, cfg.num_heads, d),
+            k.reshape(*lead, cfg.num_kv_heads, d),
+            v.reshape(*lead, cfg.num_kv_heads, d))
+
+
 # =============================================================================
 # Prefill (full-sequence forward)
 # =============================================================================
@@ -124,9 +154,7 @@ def prefill(cfg: ModelConfig, params: Params, tokens: jax.Array,
 
     def layer(x, lp):
         h_in = rms_norm(x, lp["ln1"], cfg.norm_eps)
-        q = quant.matmul(h_in, lp["wq"]).reshape(b, s, cfg.num_heads, d)
-        k = quant.matmul(h_in, lp["wk"]).reshape(b, s, cfg.num_kv_heads, d)
-        v = quant.matmul(h_in, lp["wv"]).reshape(b, s, cfg.num_kv_heads, d)
+        q, k, v = project_qkv(cfg, lp, h_in)
         q = apply_rope(q, sin, cos)
         k = apply_rope(k, sin, cos)
         out = attn(q, k, v).reshape(b, s, cfg.num_heads * d)
@@ -192,9 +220,7 @@ def decode_step(cfg: ModelConfig, params: Params, token: jax.Array,
             lp, k_cache, v_cache = scanned
             ks_cache = vs_cache = None
         h_in = rms_norm(x, lp["ln1"], cfg.norm_eps)
-        q = quant.matmul(h_in, lp["wq"]).reshape(b, cfg.num_heads, d)
-        k = quant.matmul(h_in, lp["wk"]).reshape(b, cfg.num_kv_heads, d)
-        v = quant.matmul(h_in, lp["wv"]).reshape(b, cfg.num_kv_heads, d)
+        q, k, v = project_qkv(cfg, lp, h_in)
         q = apply_rope(q, sin, cos)
         k = apply_rope(k, sin, cos)
 
@@ -273,9 +299,7 @@ def chunk_prefill(cfg: ModelConfig, params: Params, tokens: jax.Array,
             lp, k_cache, v_cache = scanned
             ks_cache = vs_cache = None
         h_in = rms_norm(x, lp["ln1"], cfg.norm_eps)
-        q = quant.matmul(h_in, lp["wq"]).reshape(b, s_c, cfg.num_heads, d)
-        k = quant.matmul(h_in, lp["wk"]).reshape(b, s_c, cfg.num_kv_heads, d)
-        v = quant.matmul(h_in, lp["wv"]).reshape(b, s_c, cfg.num_kv_heads, d)
+        q, k, v = project_qkv(cfg, lp, h_in)
         q = apply_rope(q, sin, cos)
         k = apply_rope(k, sin, cos)
 

@@ -21,6 +21,7 @@ from __future__ import annotations
 import dataclasses
 import math
 import os
+import re
 import sys
 from functools import partial
 
@@ -250,11 +251,13 @@ GB = 1e9
 # temporaries for a tick and 3.51 for a chunk program, with 6 pool-sized
 # copies, 2 update-slices and 2 slice fusions (compile for a described
 # v5e, PR 27); ISSUE 27 asked for under 1 GB and no pool-sized move at
-# all.  The served ticks are held to what they compile to: 0.4034 GB at
-# both SmolLM2 rungs (the entry's two copies of wq and wk; the window
+# all.  The served ticks are held to what they compile to: 0.0005-0.0006
+# GB at both SmolLM2 rungs and for GQA at head_dim 64 and 128 (the window
 # itself is no temporary in either form: XLA kept PR 30's gathered rows
-# in VMEM, PR 45's kernel never has them), 0.1350 GB for GQA at head_dim
-# 64 and 0.1352 at head_dim 128.  ``streamed`` (ISSUE 45): a K/V head to
+# in VMEM, PR 45's kernel never has them; until PR 48 the entry's two
+# re-laid copies of the wq and wk stacks stood here, 0.4034 GB at
+# SmolLM2's widths and 0.135 for GQA: the test after the routed ticks'
+# holds them gone).  ``streamed`` (ISSUE 45): a K/V head to
 # every query head, the block table walked by the kernel of
 # ops/rows_attention.py; ``merged``: GQA, whose narrow rows the kernel
 # loses on (``rows_attention.serves``), keeps the XLA gather.  The HOOKED
@@ -264,9 +267,9 @@ GB = 1e9
 # cell runs a hooked tier: these two cases are all that holds it.
 POOL_PROGRAMS = {
     "smollm2-decode-256":
-        (_bench_tier, ("decode", 256), 0.41, "streamed"),
+        (_bench_tier, ("decode", 256), 0.01, "streamed"),
     "smollm2-decode-2048":
-        (_bench_tier, ("decode", 2048), 0.41, "streamed"),
+        (_bench_tier, ("decode", 2048), 0.01, "streamed"),
     "smollm2-chunk-256-256":
         (_bench_tier, ("chunk", 256, 256), 1.0, None),
     "smollm2-chunk-256-1024":
@@ -278,10 +281,10 @@ POOL_PROGRAMS = {
     "smollm2-copy_block":
         (_bench_tier, ("cow",), 1.0, None),
     "nano_1b-gqa-decode-256":
-        (lambda _: _flagship_nano("nano_1b"), ("decode", 256), 0.14,
+        (lambda _: _flagship_nano("nano_1b"), ("decode", 256), 0.01,
          "merged"),
     "orin_bench-d128-decode-256":
-        (lambda _: _flagship_nano("orin_bench"), ("decode", 256), 0.14,
+        (lambda _: _flagship_nano("orin_bench"), ("decode", 256), 0.01,
          "merged"),
     "nano_1b-gqa-ragged-pallas":
         (lambda _: _flagship_nano("nano_1b"), ("decode", 0), 5.073, "split"),
@@ -415,6 +418,78 @@ def test_routed_tick_reads_the_experts_where_they_rest(one_chip, as_on_tpu,
     # The kernel is one operation of the layer body: the tick keeps the
     # two nested loops the benchmark files it by (steps, layers).
     assert text.count(" while(") == 2
+
+
+# -- wq and wk are read where they rest (ISSUE 48) -----------------------------
+
+def weight_sized_moves(hlo: str, least_bytes: int):
+    """``(name, result)`` of every ``copy`` and every slice fusion (a
+    ``fusion`` whose root is a ``dynamic-slice``) that stands as an
+    operation of its own — outside any fused computation — and whose
+    result holds ``least_bytes`` or more: a matrix written out, where a
+    product that reads the stack in place has the slice INSIDE its
+    fusion."""
+    fused = set(re.findall(r" fusion\(.*? calls=%?([\w.-]+)", hlo))
+    rows, roots, computation = [], {}, None
+    for line in hlo.splitlines():
+        if line.endswith("{") and " = " not in line:
+            words = line.split()
+            computation = words[1 if words[0] == "ENTRY" else 0].lstrip("%")
+            continue
+        m = chip_smoke._HLO_RESULT.match(line)
+        if not m:
+            continue
+        if m[1]:
+            roots[computation] = m[3]
+        if computation not in fused and m[3] in ("copy", "fusion"):
+            rows.append((line.split(" = ")[0].split()[-1], m[2], m[3], m[4]))
+    found = []
+    for name, result, op, rest in rows:
+        called = re.search(r"calls=%?([\w.-]+)", rest)
+        if op == "fusion" and roots.get(called[1]) != "dynamic-slice":
+            continue
+        kind, dims = result[:-1].split("[")
+        bits = re.search(r"\d+$", kind)            # bf16, f32, s8; pred: 8
+        elements = math.prod(int(x) for x in dims.split(",") if x)
+        if elements * (int(bits[0]) if bits else 8) // 8 >= least_bytes:
+            found.append((name, result))
+    return found
+
+
+# The tick at both of the benchmark's rungs and one continuation chunk
+# program.  The chunk program's two head-split copies of the gathered
+# window (``bf16[1024, 32, 64]``, 4.2 MB: ROADMAP S6) stand on both sides
+# of ISSUE 48, under the matrix's size at this rung and inside fusions.
+DENSE_PROGRAMS = {"decode-256": ("decode", 256),
+                  "decode-2048": ("decode", 2048),
+                  "chunk-256-1024": ("chunk", 256, 1024)}
+
+
+@pytest.mark.parametrize("program", list(DENSE_PROGRAMS))
+def test_dense_programs_read_wq_and_wk_where_they_rest(one_chip, as_on_tpu,
+                                                       monkeypatch, program):
+    """SmolLM2-1.7B's own tick and chunk program at their real sizes (24
+    x ``[2048, 2048]``, 32/32 heads of 64) hold no ``copy`` and no slice
+    fusion as large as one layer's ``wq`` (8.4 MB): the q and k products
+    read the stacks in place, as ``wv``'s does.  On PR 47's tree each
+    program holds four — the tick the entry's two re-laid copies of the
+    whole stacks (``bf16[24, 2048, 2048]{1,2,0}``, 0.40 GB of
+    temporaries) and a layer's two ``constant_dynamic-slice_fusion`` out
+    of them, the chunk program the two slice fusions and a transposing
+    ``copy`` of each — because the head split's layout travelled through
+    the product into the weight; ``transformer.project_qkv`` pins the
+    product's dense rows before the split (compile for a described v5e,
+    PR 48)."""
+    tier = _bench_tier(monkeypatch)
+    engine, _, compiled, _ = _pool_program(one_chip, tier,
+                                           DENSE_PROGRAMS[program])
+    cfg = engine.cfg
+    assert (cfg.hidden_size, cfg.num_heads, cfg.num_kv_heads,
+            cfg.head_dim, cfg.num_layers) == (2048, 32, 32, 64, 24)
+    matrix = cfg.hidden_size * cfg.num_heads * cfg.head_dim * 2
+    assert weight_sized_moves(compiled.as_text(), matrix) == []
+    # The entry's copies were the tick's temporaries: 0.4034 GB before.
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.01 * GB
 
 
 # -- the shared-K/V family's programs (ISSUE 35) --------------------------------
