@@ -26,13 +26,13 @@ from typing import (Any, Callable, Dict, List, NamedTuple, Optional, Sequence,
                     Tuple)
 
 # Kernel-comparison tolerances, fixed here before any run.  float32 is
-# the pin tests/test_ragged_parity.py and tests/test_pallas_attention.py
-# use (kernel and reference both at full matmul precision); bfloat16 —
+# the pin tests/test_pallas_attention.py uses (kernel and reference both
+# at full matmul precision); bfloat16 —
 # the dtype the tiers serve — is compared against the float32 reference
 # of the same bf16-valued inputs, bounded by the dtype: the kernel rounds
 # the softmax weights and the output to 8 mantissa bits (2^-8 = 3.9e-3
 # relative each).
-KERNEL_TOL = {"float32": 2e-5, ("float32", "chunk"): 2e-3, "bfloat16": 2e-2,
+KERNEL_TOL = {"float32": 2e-5, "bfloat16": 2e-2,
               # Two chained products over thousands of terms, the one
               # between them rounded to the dtype; another summation order.
               ("float32", "grouped_product"): 2e-4,
@@ -410,21 +410,12 @@ def _serve(served: Served) -> None:
 
 def phase_what_ran(served: Served) -> Dict[str, Any]:
     """The record of which path each tier actually took."""
-    from distributed_llm_tpu.ops import attention as attn_ops
     from distributed_llm_tpu.ops.pallas_attention import kernel_mode
     out: Dict[str, Any] = {}
-    prov = attn_ops.dispatch_provenance()
-    say("what-ran", f"pallas kernels: {kernel_mode()}; dispatch table: "
-                    f"backend={prov['backend']} "
-                    f"kernel_gen={prov['kernel_gen']} "
-                    f"(kernels are gen {prov['current_kernel_gen']}) "
-                    f"active={prov['active']} "
-                    f"stale={prov['stale_kernel_gen']}")
+    say("what-ran", f"pallas kernels: {kernel_mode()}")
     for name in served.router.tiers:
         engine = _engine(served.router, name)
         span = engine.paged.blocks_per_slot * engine.paged.block_size
-        impls = {kind: attn_ops._choose(engine.cfg.attention_impl, kind, span)
-                 for kind in attn_ops.DISPATCH_KINDS}
         row = {
             "engine": type(engine).__name__,
             "attention_impl": engine.cfg.attention_impl,
@@ -435,7 +426,6 @@ def phase_what_ran(served: Served) -> Dict[str, Any]:
             "tick_resident_share": engine.tick_stats()["resident_share"],
             "speculation": bool(engine.spec),
             "span": span,
-            "impl_by_kind": impls,
             "compiled_after_warmup":
                 served.record["compiled_after_warmup"][name],
             "compiled_after_requests":
@@ -603,7 +593,7 @@ def phase_drain(served: Served) -> None:
 # =============================================================================
 
 class KernelCase(NamedTuple):
-    kind: str                      # the dispatch kind it serves
+    kind: str                      # what it serves (tolerance key)
     pallas: Callable               # the Pallas entry
     xla: Callable                  # its XLA reference (same arguments)
     make_args: Callable            # () -> tuple of arrays, from a seed
@@ -611,9 +601,7 @@ class KernelCase(NamedTuple):
 
 def kernel_cases(nq: int, nkv: int, d: int, dtype, *, batch: int = 8,
                  block: int = 64, blocks_per_slot: int = 32,
-                 prefill_len: int = 1024, decode_len: int = 2048,
-                 chunk: int = 64, chunk_window: int = 2048,
-                 verify_q: int = 5,
+                 prefill_len: int = 1024,
                  grouped: Optional[Dict[str, tuple]] = None,
                  scan: tuple = SCAN_SHAPE
                  ) -> Dict[str, KernelCase]:
@@ -634,10 +622,8 @@ def kernel_cases(nq: int, nkv: int, d: int, dtype, *, batch: int = 8,
     from distributed_llm_tpu.ops import attention as A
     from distributed_llm_tpu.ops import grouped_product as GP
     from distributed_llm_tpu.ops import pallas_attention as PA
-    from distributed_llm_tpu.ops import ragged_attention as RA
     from distributed_llm_tpu.ops import rows_attention as RW
     from distributed_llm_tpu.ops import ssm_chunk_scan as SC
-    from distributed_llm_tpu.ops.quant import quantize_kv_rows
 
     span = block * blocks_per_slot
     keys = jax.random.split(jax.random.PRNGKey(0), 4)
@@ -645,38 +631,21 @@ def kernel_cases(nq: int, nkv: int, d: int, dtype, *, batch: int = 8,
     def rand(key, shape):
         return jax.random.normal(key, shape, jnp.float32).astype(dtype)
 
-    def pool(q_shape, last_q: int = 1):
+    def rows_pool():
         """q + shuffled non-contiguous block tables + skewed per-slot
-        positions (one slot near each end of the span)."""
+        positions (one slot near each end of the span) over a WHOLE
+        token-major pool of two layers, a K/V head to every query head
+        (what the served MHA tick hands ``ops/rows_attention.py``); the
+        first slot idle over the trash block."""
         nb = batch * blocks_per_slot + 1                  # + trash block 0
         perm = np.random.default_rng(0).permutation(nb - 1) + 1
         tables = perm.reshape(batch, blocks_per_slot).astype(np.int32)
-        pos = np.linspace(5, span - last_q - 1, batch).astype(np.int32)
-        return (rand(keys[0], q_shape),
-                rand(keys[1], (nkv, nb, block, d)),
-                rand(keys[2], (nkv, nb, block, d)),
-                jnp.asarray(tables), jnp.asarray(pos))
-
-    def rows_pool():
-        """The same batch over a WHOLE token-major pool of two layers,
-        a K/V head to every query head (what the served MHA tick hands
-        ``ops/rows_attention.py``); the first slot idle over the trash
-        block."""
-        q, _, _, tables, pos = pool((batch, nq, d))
-        nb = batch * blocks_per_slot + 1
-        return (q, rand(keys[1], (2, nb, block, nq * d)),
+        pos = np.linspace(5, span - 2, batch).astype(np.int32)
+        return (rand(keys[0], (batch, nq, d)),
+                rand(keys[1], (2, nb, block, nq * d)),
                 rand(keys[2], (2, nb, block, nq * d)),
-                tables.at[0].set(0), pos.at[0].set(0))
-
-    def pool_q8():
-        q, kp, vp, tables, pos = pool((batch, nq, d))
-        (kq, ks), (vq, vs) = quantize_kv_rows(kp), quantize_kv_rows(vp)
-        return q, kq, vq, ks, vs, tables, pos
-
-    def contiguous(q_shape, length, q_pos):
-        return (rand(keys[0], q_shape),
-                rand(keys[1], (q_shape[0], length, nkv, d)),
-                rand(keys[2], (q_shape[0], length, nkv, d)), q_pos)
+                jnp.asarray(tables).at[0].set(0),
+                jnp.asarray(pos).at[0].set(0))
 
     def experts(rows, per_layer, k, n):
         """Rows over a third of the MIDDLE layer's groups of a stack of
@@ -735,40 +704,11 @@ def kernel_cases(nq: int, nkv: int, d: int, dtype, *, batch: int = 8,
             lambda shape=shape: experts(*shape))
         for cell, shape in grouped.items()}
 
-    chunk_start = chunk_window - chunk - 5
     return {
         "flash_causal_attention": KernelCase(
             "prefill", PA.flash_causal_attention, A.causal_attention,
-            lambda: contiguous((1, prefill_len, nq, d), prefill_len,
-                               None)[:3]),
-        "flash_decode_attention": KernelCase(
-            "decode", PA.flash_decode_attention, A.decode_attention,
-            lambda: contiguous(
-                (batch, nq, d), decode_len,
-                jnp.asarray(np.linspace(3, decode_len - 1, batch),
-                            jnp.int32))),
-        "flash_chunk_attention": KernelCase(
-            "chunk", PA.flash_chunk_attention, A.chunk_attention,
-            lambda: contiguous(
-                (1, chunk, nq, d), chunk_window,
-                chunk_start + jnp.arange(chunk, dtype=jnp.int32)[None])),
-        "paged_decode_attention": KernelCase(
-            "paged_decode", PA.paged_decode_attention,
-            lambda *a: A.paged_decode(*a, impl="xla"),
-            lambda: pool((batch, nq, d))),
-        "ragged_paged_decode_attention": KernelCase(
-            "ragged_decode", RA.ragged_paged_decode_attention,
-            lambda *a: A.ragged_decode(*a, impl="xla"),
-            lambda: pool((batch, nq, d))),
-        "ragged_paged_decode_attention_q8": KernelCase(
-            "ragged_decode_q8", RA.ragged_paged_decode_attention_q8,
-            lambda q, kq, vq, ks, vs, tables, pos: A.ragged_decode(
-                q, kq, vq, tables, pos, impl="xla", k_scale=ks, v_scale=vs),
-            pool_q8),
-        "ragged_paged_verify_attention": KernelCase(
-            "ragged_verify", RA.ragged_paged_verify_attention,
-            lambda *a: A.ragged_verify(*a, impl="xla"),
-            lambda: pool((batch, verify_q, nq, d), last_q=verify_q)),
+            lambda: tuple(rand(key, (1, prefill_len, n, d))
+                          for key, n in zip(keys, (nq, nkv, nkv)))),
         # The served tick's own kernel (no head-major view: the pool
         # whole, a traced layer), against the XLA form it replaces.
         "paged_rows_decode_attention": KernelCase(

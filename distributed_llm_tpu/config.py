@@ -562,30 +562,15 @@ class TierConfig:
     decode_batch: int = 1
     kv_block_size: int = 64
     decode_steps_per_tick: int = 4
-    # Ragged paged decode (ops/ragged_attention.py): the batched engine's
-    # decode tick issues ONE fused attention call over every slot's FULL
-    # block-table row with true per-slot lengths, instead of slicing the
-    # tables to a bucketed window rung shared across the batch.  One
-    # compiled decode program serves the engine's whole life (the rung
-    # ladder minted one per (bucket, window) pair), the host stops
-    # re-uploading sliced tables every tick, and on TPU the Pallas kernel
-    # streams each slot's own frontier so length skew costs per-slot
-    # work, not the batch max.  On a ('batch','tp') tier mesh the fused
-    # tick runs UNDER shard_map over the kv-head axis (PR 16,
-    # parallel/tp_attention.tp_ragged_decode_attn) when the mesh
-    # qualifies — dense model, sp=ep=1, tp divides both head counts
-    # (parallel/tp_attention._tp_ragged_ok); non-qualifying meshes keep
-    # the dense windowed path.  On TPU the request is
-    # additionally GATED by the measured dispatch verdict: while
-    # ab_dispatch.json still says 'xla' for ragged_decode (the
-    # conservative pre-measure rows), the engine keeps the dense
-    # windowed tick — the fused XLA fallback's full-span gather is not
-    # measured-better there; an on-chip A/B flipping the row to 'pallas'
-    # flips the engine with no code change
-    # (ContinuousBatchingEngine._resolve_ragged).  DLLM_RAGGED=0/1
-    # forces the TICK SHAPE (fused vs windowed) past everything but the
-    # mesh rule; the KERNEL inside the fused tick stays the table's
-    # measured choice (DLLM_ATTENTION overrides that separately).
+    # The FUSED decode tick: ONE attention call over every slot's FULL
+    # block-table row with true per-slot lengths, one compiled program
+    # for the engine's life, instead of tables sliced to a bucketed
+    # window rung shared across the batch (a program a rung).  A request:
+    # ContinuousBatchingEngine._resolve_ragged has the rule (the latent
+    # and hybrid families, a mesh parallel/tp_attention._tp_ragged_ok
+    # refuses, and the TPU backend keep the windowed tick; DLLM_RAGGED=
+    # 0/1 forces the shape past everything but the family and mesh
+    # rules).  Batched speculation needs the fused tick.
     attention_ragged: bool = True
     # Disaggregated chunked prefill (engine/batching.py): a cold
     # admission whose prompt bucket exceeds this many tokens no longer
@@ -666,8 +651,8 @@ class TierConfig:
     # a draft_preset and decode_batch>1, each scheduler tick drafts γ
     # tokens per active slot with the draft model (its own paged pool
     # behind the SAME block tables), verifies every slot's γ+1 chunk in
-    # ONE fused ragged_verify call (ops/ragged_attention.py — the
-    # ragged kernel's q_len=γ+1 face), applies per-slot greedy
+    # ONE fused ragged_verify call (ops/attention.py), applies per-slot
+    # greedy
     # acceptance, and rewinds rejected tails' block frontiers (never
     # mutating shared/parked blocks — COW first, like admit).  Greedy
     # outputs stay byte-identical to plain decode.  Tri-state: None

@@ -58,18 +58,19 @@ def _e(name: str, default: Optional[str], consumer: str, doc: str) -> EnvVar:
 
 ENV_VARS: Dict[str, EnvVar] = {v.name: v for v in (
     _e("DLLM_ATTENTION", None, "ops/attention.py",
-       "Explicit attention-kernel override ('pallas' / 'xla'); unset = "
-       "the measured dispatch table (bench/ab_dispatch.json) decides per "
-       "kind."),
+       "Explicit attention override: 'pallas' = the flash prefill and "
+       "the streamed rows decode kernel wherever they serve, 'xla' = "
+       "neither; unset = each engine's own attention_impl (kernels on "
+       "an unsharded TPU tier)."),
     _e("DLLM_RAGGED", None, "engine/batching.py",
-       "'1' forces the batched engine's ragged fused decode TICK on, "
-       "'0' forces the dense windowed path; unset = "
-       "TierConfig.attention_ragged decides.  On a qualifying TP mesh "
-       "the fused tick runs under shard_map over the kv-head axis "
+       "'1' forces the batched engine's fused decode TICK (full table "
+       "rows, one program), '0' the windowed one (a program a rung); "
+       "unset = TierConfig.attention_ragged asks for the fused tick and "
+       "gets it off the TPU only.  On a qualifying TP mesh the fused "
+       "tick runs under shard_map over the kv-head axis "
        "(parallel/tp_attention._tp_ragged_ok); non-qualifying meshes "
-       "keep the dense windowed path regardless of this flag.  The "
-       "kernel inside the tick is DLLM_ATTENTION / dispatch-table "
-       "territory."),
+       "and the latent and hybrid families keep the windowed tick "
+       "regardless of this flag."),
     _e("DLLM_NATIVE", None, "native/__init__.py",
        "'0' disables the g++-built native tokenizer/counter helpers; "
        "behavior is bit-identical to the pure-Python fallback."),
@@ -186,12 +187,13 @@ CONFIG_FIELDS: Dict[str, str] = {
                                 "(engine/paged_kv.py).",
     "TierConfig.decode_steps_per_tick": "Sequential decode steps fused "
                                         "into one device call per tick.",
-    "TierConfig.attention_ragged": "Batched decode tick runs ONE fused "
-                                   "ragged paged-attention call over "
-                                   "full block tables with per-slot "
-                                   "lengths (no bucketed window rungs); "
-                                   "qualifying TP meshes run it under "
-                                   "shard_map over the kv-head axis.",
+    "TierConfig.attention_ragged": "Asks for the fused decode tick: ONE "
+                                   "paged-attention call over full "
+                                   "block tables with per-slot lengths "
+                                   "(no bucketed window rungs); granted "
+                                   "off the TPU, qualifying TP meshes "
+                                   "run it under shard_map over the "
+                                   "kv-head axis.",
     "TierConfig.prefill_chunk_tokens": "Cold prompts past one chunk "
                                        "prefill in fixed chunks of this "
                                        "many tokens interleaved with "

@@ -342,13 +342,9 @@ class ContinuousBatchingEngine:
                                  max_seq_len=self.cfg.max_seq_len,
                                  pool_blocks=tier.kv_pool_blocks)
         self.steps_per_tick = max(1, tier.decode_steps_per_tick)
-        # Ragged fused decode (ops/ragged_attention.py): the tick passes
-        # every slot's FULL table row to ONE attention.ragged_decode call
-        # instead of slicing to a bucketed window rung.  Unsharded
-        # engines only — the TP tick keeps the rung-specialized
-        # shard-mapped dense path.  DLLM_RAGGED=0/1 is the kill
-        # switch / forced-on override (kept strict like DLLM_ATTENTION:
-        # garbage raises rather than failing open).
+        # The fused tick: every slot's FULL table row goes to ONE
+        # attention.paged_decode call instead of a table sliced to a
+        # bucketed window rung (``_resolve_ragged`` has the rule).
         self.ragged = self._resolve_ragged()
         # Full-table device upload cache: under ragged decode the tables
         # arg is shape-stable, so it is re-uploaded only when a table row
@@ -769,9 +765,10 @@ class ContinuousBatchingEngine:
         # [ticks, Σ mean KV span]}, scheduler-thread writes only.
         self._tick_work: Dict[tuple, List[float]] = {}
         self.phases.lazy_work = self._tick_work_estimate
-        # Attention dispatch kind of a plain and of a speculative tick
-        # (fixed for the engine's life) and, per (kind, window rung),
-        # the impl the measured table chose with its metric children.
+        # The tick's shape, plain and speculative (fixed for the
+        # engine's life: ``ragged_*`` the fused tick, ``paged_decode``
+        # the windowed one) and, per (kind, window rung), what serves
+        # its attention with its metric children.
         q8 = "_q8" if tier.kv_quantize == "int8" else ""
         self._tick_kind = ("ragged_decode" if self.ragged
                            else "paged_decode") + q8
@@ -864,35 +861,18 @@ class ContinuousBatchingEngine:
         return jax.jit(fn, **kw)
 
     def _resolve_ragged(self) -> bool:
-        """Whether the decode tick runs the ragged fused path.
-
-        Policy: (a) meshes ride along IF the shard-mapped ragged hook
-        can own whole kv-head groups per chip (tp-only mesh, dense
-        model, tp divides both head counts — parallel/tp_attention.py
-        ``_tp_ragged_ok``); a mesh the hook can't serve keeps the dense
-        windowed path, since inside a plain jit a pallas_call has no
-        GSPMD rule; (b) DLLM_RAGGED
-        forces the TICK SHAPE ('1' fused, '0' dense windowed) — which
-        KERNEL serves the fused tick's attention is a separate, measured
-        choice (the dispatch table, overridable by DLLM_ATTENTION=pallas
-        like every other kind); (c) otherwise
-        ``TierConfig.attention_ragged`` requests it, GATED by the
-        measured dispatch verdict on TPU: the
-        fused tick's XLA fallback gathers the FULL table span, so while
-        the committed table still says 'xla' for ragged_decode at this
-        pool's span (no on-chip measurement yet — the conservative rows
-        ab_dispatch.json ships with), a TPU engine keeps the dense
-        windowed path, whose bucketed gather is the measured-better XLA
-        strategy there.  Off-TPU backends stay fused: the skew leg
-        measured the fallback WINNING on CPU (the rung ladder's host +
-        compile churn dominates the tiny gather), and the whole point of
-        the table is that an on-chip A/B flipping ragged_decode to
-        'pallas' flips this engine to the kernel with no code change."""
+        """Whether the decode tick is the FUSED one (every slot's full
+        table row, one program for the engine's life) or the WINDOWED one
+        (tables cut to a bucketed rung, a program a rung).  In order: the
+        latent and hybrid families and a mesh the shard-mapped hook cannot
+        serve (``_tp_ragged_ok``) are windowed; ``DLLM_RAGGED`` forces
+        either; ``TierConfig.attention_ragged`` False is windowed; then
+        off the TPU fused, on it windowed.  The fused tick's gather spans
+        the whole table whatever the slots hold, and it was never
+        measured better on the chip; off it the rung ladder's compiles
+        cost more than the tiny gather.  Tests and the chip so run
+        different ticks: ROADMAP D3."""
         if self.cfg.latent or self.cfg.hybrid:
-            # The latent family attends whatever tables it is given; the
-            # windowed tick bounds its gather (no fused ragged kernel
-            # reads a head-less pool).  The hybrid family's two attention
-            # layers keep the windowed tick too.
             return False
         if self.mesh is not None:
             from ..parallel.tp_attention import _tp_ragged_ok
@@ -904,24 +884,16 @@ class ContinuousBatchingEngine:
             raise ValueError(f"DLLM_RAGGED={raw!r}: expected '0' or '1'")
         if raw is not None:
             return raw == "1"
-        if not self.tier.attention_ragged:
-            return False
-        if jax.default_backend() != "tpu":
-            return True
-        from ..ops import attention as attn_ops
-        kind = ("ragged_decode_q8" if self.tier.kv_quantize == "int8"
-                else "ragged_decode")
-        span = self.paged.blocks_per_slot * self.paged.block_size
-        return attn_ops._choose(self.cfg.attention_impl, kind,
-                                span) == "pallas"
+        return (self.tier.attention_ragged
+                and jax.default_backend() != "tpu")
 
     def _resolve_spec(self) -> bool:
         """Whether ``TierConfig.spec_decode`` can actually arm batched
         speculation on this engine.  Requirements, each logged when it
         blocks: a ``draft_preset`` (the drafting model — the target's
         own preset is the zero-extra-weights self-draft), the fused
-        ragged tick (the verify call IS the ragged kernel's q_len=γ+1
-        face; the dense windowed tick has no verify shape — a TP mesh
+        ragged tick (the verify call takes every slot's full table
+        row; the dense windowed tick has no verify shape — a TP mesh
         qualifies exactly when its tick went ragged, PR 16), a greedy
         tier default (per-REQUEST
         temperature>0 just degrades that slot to γ=0; a sampled tier
@@ -1063,23 +1035,17 @@ class ContinuousBatchingEngine:
         self._prefill_fns[bucket] = fn
         return fn
 
-    def _tick_attn_hook(self, window: int):
-        """The decode tick's attention hook at a table window of
-        ``window`` tokens, or None for the dispatching op over the whole
-        pool.  TP tiers: ragged ticks wrap the DISPATCHING ragged decode
-        in shard_map over the kv-head axis (PR 16 — the fused paged path
-        runs sharded, combine is a head concat); dense ticks keep the
-        per-head-shard paged flash decode."""
-        if self.cfg.num_experts != 1:
+    def _tick_attn_hook(self):
+        """The decode tick's attention hook, or None for
+        ``attention.paged_decode`` over the whole pool.  A TP tier's
+        fused tick wraps that op in shard_map over the kv-head axis
+        (PR 16: combine is a head concat); its windowed tick has no hook
+        (the GSPMD XLA path)."""
+        if self.cfg.num_experts != 1 or not self.ragged:
             return None
-        quantized = self.tier.kv_quantize == "int8"
-        if self.ragged:
-            from ..parallel.tp_attention import tp_ragged_decode_attn
-            return tp_ragged_decode_attn(self.mesh, self.cfg,
-                                         quantized=quantized)
-        from ..parallel.tp_attention import tp_paged_decode_attn
-        return tp_paged_decode_attn(self.mesh, self.cfg, window,
-                                    quantized=quantized)
+        from ..parallel.tp_attention import tp_ragged_decode_attn
+        return tp_ragged_decode_attn(
+            self.mesh, self.cfg, quantized=self.tier.kv_quantize == "int8")
 
     def decode_attention_form(self, window: Optional[int] = None) -> str:
         """What this tier's decode tick attends at a table window of
@@ -1088,26 +1054,26 @@ class ContinuousBatchingEngine:
         ``streamed`` the whole token-major pool read through the block
         table where it rests (``ops/rows_attention.py``), ``merged`` the
         XLA gather of the same pool's rows and
-        ``ops.attention.merged_decode_attention``, ``split`` a hook or a
-        Pallas kernel over a layer's head-major view
-        (``ops.attention.decode_form`` is the rule), ``latent`` the
-        latent family's own absorbed attention."""
+        ``ops.attention.merged_decode_attention``
+        (``ops.attention.decode_form`` is the rule between the two),
+        ``split`` a tp hook over a layer's head-major view, ``latent``
+        the latent family's own absorbed attention."""
         if self.cfg.latent:
             return "latent"
         if self.cfg.shared_kv:
             return "merged"        # its own (shared_kv_hybrid.diff_merged)
+        if self._tick_attn_hook() is not None:
+            return "split"
         from ..ops import attention as attn_ops
-        kind = (("ragged_decode" if self.ragged else "paged_decode")
-                + ("_q8" if self.tier.kv_quantize == "int8" else ""))
         bs = self.paged.block_size
         span = self.paged.blocks_per_slot * bs
         window = span if window is None or self.ragged else window
-        if self._tick_attn_hook(window) is not None:
-            return "split"
+        quantized = self.tier.kv_quantize == "int8"
         return attn_ops.decode_form(
-            self.cfg.attention_impl, kind, self.cfg.num_heads,
+            self.cfg.attention_impl, self.cfg.num_heads,
             self.cfg.head_dim, window // bs, bs,
-            self.cfg.num_kv_heads * self.cfg.head_dim, self.cfg.dtype)
+            self.cfg.num_kv_heads * self.cfg.head_dim,
+            jnp.int8 if quantized else self.cfg.dtype)
 
     def _decode_step(self):
         """One compiled tick for all slots: ``decode_steps_per_tick``
@@ -1129,20 +1095,15 @@ class ContinuousBatchingEngine:
         cfg = self.cfg
         max_pos = cfg.max_seq_len - 1
         steps = self.steps_per_tick
-        ragged = self.ragged
         moe_counts = self._moe is not None
+        attn = self._tick_attn_hook()
 
         def decode_tick(params, pool, tables, pos, cur, temps, rng):
-            # The window width is static per trace, so a TP tier's hook
-            # resolves here.
-            attn = self._tick_attn_hook(
-                tables.shape[1] * self.paged.block_size)
-
             def step(carry, _):
                 pool, pos, cur, rng = carry
                 logits, pool, *n_exp = decode_step_paged(
                     cfg, params, cur, pos, pool, tables, attn=attn,
-                    ragged=ragged, counts=moe_counts)
+                    counts=moe_counts)
                 rng, sub = jax.random.split(rng)
                 nxt = _sample_batched(logits, sub, temps)
                 # Clamp: finished/overshooting slots keep writing into
@@ -1309,19 +1270,16 @@ class ContinuousBatchingEngine:
             else:
                 # Replicated small draft: every chip drafts the full
                 # batch locally inside an all-replicated shard_map
-                # region (the dispatcher may pick Pallas per device,
-                # which a plain jit over the mesh cannot).
+                # region: no collective in a draft round.
                 from ..parallel.tp_attention import tp_local_ragged_decode
                 attn = tp_local_ragged_decode(self.mesh,
-                                              impl=cfg_d.attention_impl,
                                               quantized=quantized)
 
         def spec_draft(params_d, pool_d, tables, pos, cur):
             def step(carry, _):
                 pool_d, tok, p = carry
                 logits, pool_d = decode_step_paged(
-                    cfg_d, params_d, tok, p, pool_d, tables, attn=attn,
-                    ragged=True)
+                    cfg_d, params_d, tok, p, pool_d, tables, attn=attn)
                 nxt = jnp.argmax(logits, -1).astype(jnp.int32)
                 return (pool_d, nxt, jnp.minimum(p + 1, max_pos)), nxt
             (pool_d, _, _), drafted = jax.lax.scan(
@@ -1337,8 +1295,8 @@ class ContinuousBatchingEngine:
 
     def _spec_verify_fn(self, gb: int):
         """Per γ bucket: the verify half — ONE fused
-        ``verify_step_paged`` call over every slot's γ+1 chunk (q_len =
-        γ+1 on the ragged kernel face), greedy acceptance with the
+        ``verify_step_paged`` call over every slot's γ+1 chunk
+        (``attention.ragged_verify``), greedy acceptance with the
         per-slot runtime γ cap, and the emitted-token assembly, all on
         device.  Keyed ONLY by (γ_bucket, pool span, tp) through
         ``_note_compile("verify")``: per-slot γ and acceptance lengths
@@ -3095,17 +3053,20 @@ class ContinuousBatchingEngine:
 
     def _tick_sinks(self, kind: str, window: int):
         """(impl, tick histogram child, tick counter child) for one
-        (dispatch kind, window rung): the measured table's choice and
+        (tick kind, window rung): ``impl`` is 'pallas' where the rung's
+        attention is the streamed rows kernel, else 'xla'
+        (``decode_attention_form``; a verify step is always XLA), and
         the metric children are resolved once per rung, not per tick —
-        which kernel actually serves decode must be readable off
-        /metrics, not guessed.  No injection path on the engine (same
-        pattern as the preemption counter): the process-global
-        registry.  A registry failure leaves the children None and the
-        tick unobserved, never failed."""
+        what serves decode must be readable off /metrics, not guessed.
+        No injection path on the engine (same pattern as the preemption
+        counter): the process-global registry.  A registry failure
+        leaves the children None and the tick unobserved, never
+        failed."""
         sinks = self._tick_sink_cache.get((kind, window))
         if sinks is None:
-            from ..ops import attention as attn_ops
-            impl = attn_ops._choose(self.cfg.attention_impl, kind, window)
+            streamed = (kind == self._tick_kind and
+                        self.decode_attention_form(window) == "streamed")
+            impl = "pallas" if streamed else "xla"
             try:
                 from ..obs import get_observability
                 m = get_observability().m
@@ -3157,8 +3118,8 @@ class ContinuousBatchingEngine:
         # Roofline work, counted not computed: one entry per (kind,
         # window, slots served, γ bucket) with its ticks and the sum of
         # the per-tick mean KV span.  The XLA paths read the whole
-        # window; frontier-clamped Pallas kernels stream
-        # ceil((pos+1)/bs) blocks of each row, taken mid-tick.
+        # window; the streamed rows kernel reads ceil((pos+1)/bs)
+        # blocks of each row, taken mid-tick.
         if impl == "pallas":
             mid = (spec_gb if spec_gb is not None
                    else self.steps_per_tick) // 2

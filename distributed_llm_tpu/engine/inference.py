@@ -275,16 +275,6 @@ class InferenceEngine:
         return next(c for c in self._cache_lens if c >= min(needed,
                                                             self._max_seq))
 
-    def _decode_kv_span(self, cache_len: int, start: int, steps: int) -> float:
-        """Average KV span the active decode kernel streamed over ``steps``
-        steps starting at query position ``start`` (roofline kv_ctx —
-        full span on XLA, frontier-clamped tiles on Pallas)."""
-        from ..ops import attention as attn_ops
-        kind = "decode_q8" if self._kv_quantize == "int8" else "decode"
-        return attn_ops.decode_kv_span(kind, cache_len,
-                                       range(start, start + max(steps, 1)),
-                                       impl=self.cfg.attention_impl)
-
     def _sp_attn(self, bucket: int):
         """Prefill attention override for mesh tiers: ring attention when
         the mesh has an 'sp' axis dividing this bucket (dense only —
@@ -448,18 +438,13 @@ class InferenceEngine:
         pad = self.tokenizer.pad_id
         max_new = self.tier.max_new_tokens   # static cap: sizes the buffer
         # Sequence-parallel tiers: partial+merge decode over the
-        # 'sp'-sharded cache (parallel/sp_attention.py).  TP-only tiers:
-        # per-head-shard flash decode (frontier-clamped KV streaming)
-        # instead of the GSPMD XLA path.  Dense models only.
+        # 'sp'-sharded cache (parallel/sp_attention.py).  TP-only tiers
+        # keep the GSPMD XLA path.  Dense models only.
         decode_kw = {}
-        if cfg.num_experts == 1 and self._kv_quantize == "none":
-            hook = None
-            if self._sp_shard:
-                from ..parallel.sp_attention import sp_decode_attn
-                hook = sp_decode_attn(self.mesh, cfg, cache_len)
-            if hook is None:
-                from ..parallel.tp_attention import tp_decode_attn
-                hook = tp_decode_attn(self.mesh, cfg, cache_len)
+        if (cfg.num_experts == 1 and self._kv_quantize == "none"
+                and self._sp_shard):
+            from ..parallel.sp_attention import sp_decode_attn
+            hook = sp_decode_attn(self.mesh, cfg, cache_len)
             if hook is not None:
                 decode_kw["attn"] = hook
 
@@ -651,8 +636,7 @@ class InferenceEngine:
         nsteps = max(0, int(steps) - 1)
         self.phases.add_work("decode", **roofline.decode_work(
             self.cfg, nsteps, cache_len,
-            wbytes=self._wbytes, kv_quantize=self._kv_quantize,
-            kv_ctx=self._decode_kv_span(cache_len, n, nsteps)))
+            wbytes=self._wbytes, kv_quantize=self._kv_quantize))
         total_ms = (time.perf_counter() - t0) * 1000.0
 
         if self.prefix_cache is not None:
@@ -734,9 +718,7 @@ class InferenceEngine:
                     self.phases.add_work("decode", **roofline.decode_work(
                         self.cfg, nsteps, cache_len,
                         wbytes=self._wbytes,
-                        kv_quantize=self._kv_quantize,
-                        kv_ctx=self._decode_kv_span(
-                            cache_len, n + len(gen) - 1, nsteps)))
+                        kv_quantize=self._kv_quantize))
                     for tok in out[1:int(steps)].tolist():
                         gen.append(tok)
                         if tok in (eos, pad):

@@ -20,9 +20,8 @@ TPU-native design is vLLM-style paging adapted to XLA's static shapes:
   was transposed, whole, twice a program; a layout pinned with
   ``jax.experimental.layout`` does not survive the persistent compile
   cache — PERF.md §6, PR 27.)  A 'tp' mesh axis shards the merged axis
-  (heads are contiguous runs of it), and a Pallas kernel is handed a
-  layer's head-major ``[N_kv, NB, bs, D]`` view
-  (``ops.attention._layer_views``).
+  (heads are contiguous runs of it), and a tp hook is handed a layer's
+  head-major ``[N_kv, NB, bs, D]`` view (``_hooked``).
 - The pool is ONE buffer that every step program updates in place: the
   layer loop CARRIES it whole (``_scan_layers``) and each layer writes
   its rows at ``(layer, block, offset)`` and gathers its window from the
@@ -407,11 +406,19 @@ def _whole(pools):
 
 def _hooked(attn, q, pools, i, *where):
     """One layer's attention through an ``attn`` hook — the shard-mapped
-    and forced-kernel paths of parallel/tp_attention.py — which keeps its
-    ``(q, kp, vp, *where, ks, vs)`` contract over per-layer head-major
-    ``[N_kv, NB, bs(, D)]`` views."""
-    k, v, ks, vs = attention._layer_views(i, q.shape[-1], *_whole(pools))
-    return attn(q, k, v, *where, ks, vs)
+    paths of parallel/tp_attention.py — which keeps its ``(q, kp, vp,
+    *where, ks, vs)`` contract over per-layer head-major ``[N_kv, NB,
+    bs(, D)]`` views: the layer sliced out of the whole pool and turned
+    here, a layer-sized copy that only a hook pays."""
+    def view(pool, *heads):
+        x = jax.lax.dynamic_index_in_dim(pool, i, 0, keepdims=False)
+        return jnp.moveaxis(x.reshape(*x.shape[:2], -1, *heads), 2, 0)
+
+    k, v, ks, vs = _whole(pools)
+    d = q.shape[-1]
+    return attn(q, view(k, d), view(v, d), *where,
+                ks if ks is None else view(ks),
+                vs if vs is None else view(vs))
 
 
 def _scan_layers(layer, x, layers, pool: KVPool):
@@ -497,15 +504,14 @@ def chunk_prefill_paged(
         k = transformer.apply_rope(k, sin, cos)
 
         # Write the chunk's K/V rows to their (block, offset) cells, then
-        # attend the table window (Pallas: in-kernel block walk; XLA:
-        # gather-then-attend).
+        # gather and attend the table window.
         with jax.named_scope("kv_write"):
             pools = _write_rows(pools, i, blk, off, k[0], v[0])
         with jax.named_scope("attention"):
             k_p, v_p, ks_p, vs_p = _whole(pools)
             attn = attention.paged_chunk(
-                q, k_p, v_p, table, start, q_pos, window,
-                impl=cfg.attention_impl, k_scale=ks_p, v_scale=vs_p, layer=i)
+                q, k_p, v_p, table, q_pos, window,
+                k_scale=ks_p, v_scale=vs_p, layer=i)
         x = x + quant.matmul(attn.reshape(b, s_c, cfg.num_heads * d),
                              lp["wo"])
         with jax.named_scope("ffn"):
@@ -593,7 +599,7 @@ def verify_step_paged(
             else:
                 k_p, v_p, ks_p, vs_p = _whole(pools)
                 attn_out = attention.ragged_verify(
-                    q, k_p, v_p, tables, pos, impl=cfg.attention_impl,
+                    q, k_p, v_p, tables, pos,
                     k_scale=ks_p, v_scale=vs_p, layer=i)  # [B, G, Nq, d]
 
         x = x + quant.matmul(
@@ -622,7 +628,7 @@ def decode_step_paged(
     pool: KVPool,
     tables: jax.Array,         # [B, MB] block ids per slot
     attn=None,                 # (q, kp, vp, tables, pos, ks, vs) override
-    ragged: bool = False,      # fused ragged decode over FULL tables
+    ragged: bool = False,      # the caller's table contract (below)
     counts: bool = False,      # latent family: also return expert counts
 ) -> Tuple[jax.Array, KVPool]:
     """One batched autoregressive step over paged caches.
@@ -635,10 +641,11 @@ def decode_step_paged(
     gather by passing a TRUNCATED table ([B, wb] covering every active
     position — the scheduler slices to a bucketed high-water mark so
     short conversations don't stream max_seq_len of pool per step);
-    ``ragged=True`` instead expects each slot's FULL table row and issues
-    one fused ``attention.ragged_decode`` call with true per-slot
-    lengths — the Pallas kernel streams each slot's own frontier, so the
-    padding costs nothing and one compiled step serves every width.
+    ``ragged=True`` instead expects each slot's FULL table row with true
+    per-slot lengths, so one compiled step serves every width.  One op
+    (``attention.paged_decode``) attends either: the flag names the
+    contract and changes nothing here (``benchmark/correct.py`` passes
+    it; ROADMAP C0).
     The latent family attends the tables it is given under either
     contract (masked by ``pos``), in the absorbed form; ``counts`` adds
     a third result, the step's assignments an expert ``[expert layers,
@@ -673,7 +680,6 @@ def decode_step_paged(
 
     x = quant.embed_rows(params["embed"], token)       # [B, H]
     sin, cos = transformer.rope_sincos(pos, d, cfg.rope_theta)
-    attn_op = attention.ragged_decode if ragged else attention.paged_decode
 
     def layer(x, pools, lp, i):
         h_in = transformer.rms_norm(x, lp["ln1"], cfg.norm_eps)
@@ -703,7 +709,7 @@ def decode_step_paged(
                 attn_out = _hooked(attn, q, pools, i, tables, pos)
             else:
                 k_p, v_p, ks_p, vs_p = _whole(pools)
-                attn_out = attn_op(
+                attn_out = attention.paged_decode(
                     q, k_p, v_p, tables, pos, impl=cfg.attention_impl,
                     k_scale=ks_p, v_scale=vs_p, layer=i)
 

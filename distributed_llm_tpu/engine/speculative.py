@@ -66,12 +66,9 @@ def decode_chunk(cfg, params, tokens: jax.Array, start_pos: jax.Array,
         v_cache = write(v_cache, v)
 
         # Per-query ragged attention (query g attends cols <= pos[b, g])
-        # through the dispatching chunk op: the verify chunk rides the
-        # same Pallas flash-chunk kernel as prefix-reuse suffix prefill
-        # on TPU (per the measured dispatch table), XLA elsewhere.
+        # through the chunk op, as a prefix-reuse suffix prefill.
         from ..ops import attention as attention_ops
-        attn = attention_ops.chunk(q, k_cache, v_cache, pos,
-                                   impl=cfg.attention_impl)
+        attn = attention_ops.chunk(q, k_cache, v_cache, pos)
 
         x = x + quant.matmul(attn.reshape(b, g, cfg.num_heads * d), lp["wo"])
         x = x + transformer._swiglu(

@@ -7,7 +7,7 @@ to one of those as the function argument (``jax.jit(run)``,
 ``jax.jit(lambda: ...)``).  ``pl.pallas_call`` counts as a wrapper too:
 a Pallas KERNEL body is traced exactly like a jitted function (and a
 blocking host call inside one wedges the whole device program), so the
-kernels in ops/pallas_attention.py and ops/ragged_attention.py are
+kernels in ops/pallas_attention.py and ops/rows_attention.py are
 roots — including the repo idiom ``kernel = partial(_kernel, ...)``
 followed by ``pl.pallas_call(kernel, ...)``, resolved through the
 module-local assignment.  The checker walks roots plus every

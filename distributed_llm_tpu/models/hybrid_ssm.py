@@ -604,8 +604,8 @@ def _attention(cfg: ModelConfig, lp: Params, h_in, pool, li, ctx):
     with jax.named_scope("attention"):
         if "row" in ctx:
             out = attention.paged_chunk(
-                q, k_p, v_p, ctx["table"], ctx["start"], ctx["q_pos"],
-                ctx["window"], impl=cfg.attention_impl, layer=li)
+                q, k_p, v_p, ctx["table"], ctx["q_pos"], ctx["window"],
+                layer=li)
         else:
             out = attention.paged_decode(
                 q[:, 0], k_p, v_p, ctx["tables"], ctx["pos"],

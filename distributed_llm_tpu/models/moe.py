@@ -203,8 +203,7 @@ def chunk_prefill(cfg: ModelConfig, params: Params, tokens: jax.Array,
 
         k_att = k_cache[:, :window] if window else k_cache
         v_att = v_cache[:, :window] if window else v_cache
-        attn = attention.chunk(q, k_att, v_att, q_pos,
-                               impl=cfg.attention_impl)
+        attn = attention.chunk(q, k_att, v_att, q_pos)
         x = x + quant.matmul(attn.reshape(b, s_c, cfg.num_heads * d), lp["wo"])
         ffn_out, _ = moe_ffn_train(
             cfg, lp, transformer.rms_norm(x, lp["ln2"], cfg.norm_eps))
@@ -239,8 +238,7 @@ def decode_step(cfg: ModelConfig, params: Params, token: jax.Array,
         k_cache = write(k_cache, k)
         v_cache = write(v_cache, v)
 
-        attn = attention.decode(q, k_cache, v_cache, pos,
-                                impl=cfg.attention_impl)
+        attn = attention.decode(q, k_cache, v_cache, pos)
         x = x + quant.matmul(attn.reshape(b, cfg.num_heads * d), lp["wo"])
         x = x + moe_ffn_decode(
             cfg, lp, transformer.rms_norm(x, lp["ln2"], cfg.norm_eps))

@@ -186,9 +186,8 @@ def decode_step(cfg: ModelConfig, params: Params, token: jax.Array,
     token: [B] current input token; pos: [B] its position (0-based);
     kv: cache with [L,B,S_max,N_kv,D] arrays, written in-place at ``pos``.
     ``attn`` optionally replaces the decode-attention op
-    (q, k_cache, v_cache, pos) -> [B,Nq,D] — the hook tensor-parallel
-    tiers use to run the flash decode kernel per head-shard
-    (parallel/tp_attention.py).
+    (q, k_cache, v_cache, pos) -> [B,Nq,D] — the hook sequence-parallel
+    tiers use for their partial+merge decode (parallel/sp_attention.py).
     Returns (logits [B,V] float32, updated cache).
     """
     b = token.shape[0]
@@ -197,11 +196,11 @@ def decode_step(cfg: ModelConfig, params: Params, token: jax.Array,
     sin, cos = rope_sincos(pos, d, cfg.rope_theta)    # [B, D/2]
     quantized = "ks" in kv
     if attn is None or quantized:
-        # int8 caches always use the scale-aware dispatcher (the TP flash
-        # hook carries no scale operands; its policy skips quantized
-        # tiers, engine/inference.py).
+        # int8 caches always use the scale-aware op (the sp hook carries
+        # no scale operands; engine/inference.py gives quantized tiers
+        # none).
         attn = lambda q, kc, vc, p, ks=None, vs=None: attention.decode(
-            q, kc, vc, p, impl=cfg.attention_impl, k_scale=ks, v_scale=vs)
+            q, kc, vc, p, k_scale=ks, v_scale=vs)
     else:
         base = attn
         attn = lambda q, kc, vc, p, ks=None, vs=None: base(q, kc, vc, p)
@@ -317,7 +316,6 @@ def chunk_prefill(cfg: ModelConfig, params: Params, tokens: jax.Array,
                    vs_cache[:, :window] if window else vs_cache)
                   if quantized else (None, None))
         attn = attention.chunk(q, k_att, v_att, q_pos,
-                               impl=cfg.attention_impl,
                                k_scale=scales[0], v_scale=scales[1])
         x = x + quant.matmul(attn.reshape(b, s_c, cfg.num_heads * d), lp["wo"])
         x = x + _swiglu(rms_norm(x, lp["ln2"], cfg.norm_eps),

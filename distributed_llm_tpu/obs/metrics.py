@@ -391,9 +391,10 @@ METRIC_REGISTRY: Tuple[Tuple[str, str, str, Tuple[str, ...], str], ...] = (
      "waited for yet carries that chunk's tail"),
     ("decode_ticks", "counter", "dllm_decode_ticks_total",
      ("tier", "kind", "impl"),
-     "Batched decode ticks, by attention dispatch kind "
-     "(ragged_decode|paged_decode[+_q8]) and the impl the "
-     "measured table chose (xla|pallas)"),
+     "Batched decode ticks, by the tick's shape (ragged_decode the "
+     "fused one, paged_decode the windowed one, ragged_verify a "
+     "speculative round; +_q8 over an int8 pool) and what serves its "
+     "attention (pallas: the streamed rows kernel; else xla)"),
     ("tick_prepare_uploads", "counter", "dllm_tick_prepare_uploads_total",
      ("tier", "what"),
      "Uploads a decode tick's prepare phase made with the device idle, "

@@ -1,9 +1,17 @@
 """Attention ops for prefill and single-step decode.
 
 Pure-XLA implementations (einsum + softmax) that GSPMD can shard over a 'tp'
-mesh axis (heads dimension).  The Pallas flash-attention kernel in
-``pallas_attention.py`` replaces the prefill path on TPU when enabled; these
-remain the portable fallback and the reference semantics.
+mesh axis (heads dimension), and the dispatching wrappers the engines call.
+ONE implementation stands behind each wrapper, chosen from what the code
+sees when it traces (``resolve_impl`` and static shapes):
+
+``causal``         the Pallas flash prefill (``pallas_attention.py``) where
+                   the engine opted into kernels, else ``causal_attention``;
+``decode``/``chunk``  (contiguous cache) the XLA form, an int8 cache
+                   dequantized first;
+``paged_decode``   the served tick: ``decode_form`` says ``streamed`` (the
+                   kernel of ``rows_attention.py``) or ``merged`` (XLA);
+``ragged_verify``/``paged_chunk``  the table gather and ``chunk_attention``.
 
 Shapes follow the KV-cache layout [B, S, N_kv, D] (batch, sequence, kv-heads,
 head_dim); queries are [B, S, N_q, D] with N_q a multiple of N_kv (GQA).
@@ -11,150 +19,12 @@ head_dim); queries are [B, S, N_q, D] with N_q a multiple of N_kv (GQA).
 
 from __future__ import annotations
 
-import json
-import logging
 import os
-from typing import Optional
 
 import jax
 import jax.numpy as jnp
 
-logger = logging.getLogger(__name__)
-
 NEG_INF = -1e30
-
-# Measured per-kernel dispatch table, written by
-# ``python -m distributed_llm_tpu.bench.ab_kernels micro --write-dispatch``
-# on real hardware and by nothing else:
-# {"decode": {"default": "pallas", "2048": "xla"}, ...}.
-# Consulted only when an engine opted into the Pallas family ('pallas'
-# resolved, no DLLM_ATTENTION override): a kernel kind/length the A/B
-# showed losing is demoted back to XLA per shape, instead of the round-1
-# blanket env pin.
-_DISPATCH_PATH = os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                              "..", "bench", "ab_dispatch.json")
-_DISPATCH_TABLE: Optional[dict] = None
-_DISPATCH_META: Optional[dict] = None
-
-# The registry of dispatch kinds: every kind ``_choose`` is consulted
-# with by the wrappers below.  This is the contract surface between the
-# serving ops and the measured table — bench/ab_kernels.py derives its
-# measurable case classes (ALL_KINDS) from it, and
-# tests/test_kernel_dispatch.py asserts the committed ab_dispatch.json
-# covers every entry, so a new kernel kind cannot ship without a table
-# row (the table had once silently fallen behind the kernels).
-DISPATCH_KINDS = ("prefill", "decode", "decode_q8", "chunk", "chunk_q8",
-                  "paged_decode", "paged_decode_q8", "paged_chunk",
-                  "ragged_decode", "ragged_decode_q8",
-                  "ragged_verify", "ragged_verify_q8")
-
-
-def _load_dispatch() -> None:
-    """Load (once) the measured dispatch table + its provenance.  A table
-    whose ``kernel_gen`` is absent or behind the current Pallas kernels
-    still dispatches — re-measuring needs hardware — but the staleness is
-    logged and surfaced via ``dispatch_provenance`` (/stats), so old
-    hardware conclusions read as provisional, not authoritative."""
-    global _DISPATCH_TABLE, _DISPATCH_META
-    if _DISPATCH_TABLE is not None:
-        return
-    from .pallas_attention import KERNEL_GEN
-    meta = {"path": _DISPATCH_PATH, "current_kernel_gen": KERNEL_GEN,
-            "backend": None, "kernel_gen": None, "active": False,
-            "stale_kernel_gen": False}
-    try:
-        with open(_DISPATCH_PATH) as f:
-            data = json.load(f)
-        meta["backend"] = data.get("backend")
-        meta["kernel_gen"] = data.get("kernel_gen")
-        # A table measured on another backend is meaningless here
-        # (interpreter-mode CPU timings would wrongly demote every
-        # kernel on TPU): ignore it.
-        if data.get("backend") == jax.default_backend():
-            _DISPATCH_TABLE = data.get("dispatch", {})
-            meta["active"] = bool(_DISPATCH_TABLE)
-            if meta["active"] and meta["kernel_gen"] != KERNEL_GEN:
-                meta["stale_kernel_gen"] = True
-                logger.warning(
-                    "dispatch table %s was measured at kernel_gen=%s but "
-                    "the kernels are at gen %s — its verdicts are "
-                    "provisional until re-measured on hardware "
-                    "(bench.ab_kernels micro --write-dispatch)",
-                    _DISPATCH_PATH, meta["kernel_gen"], KERNEL_GEN)
-        else:
-            _DISPATCH_TABLE = {}
-    except (OSError, ValueError):
-        _DISPATCH_TABLE = {}
-    _DISPATCH_META = meta
-
-
-def dispatch_provenance() -> dict:
-    """Provenance of the measured kernel-dispatch table: backend +
-    kernel generation it was measured on, whether it is steering this
-    process, and whether it is stale w.r.t. the current kernels."""
-    _load_dispatch()
-    if _DISPATCH_META is None:
-        # Table injected directly (tests monkeypatch _DISPATCH_TABLE
-        # without meta): report activity, claim nothing about origin.
-        from .pallas_attention import KERNEL_GEN
-        return {"path": _DISPATCH_PATH, "current_kernel_gen": KERNEL_GEN,
-                "backend": None, "kernel_gen": None,
-                "active": bool(_DISPATCH_TABLE),
-                "stale_kernel_gen": False}
-    return dict(_DISPATCH_META)
-
-
-def _measured_impl(kind: str, length: Optional[int]) -> Optional[str]:
-    _load_dispatch()
-    entry = _DISPATCH_TABLE.get(kind)
-    if isinstance(entry, str):
-        return entry
-    if isinstance(entry, dict):
-        hit = entry.get(str(length))
-        if hit is None and length is not None:
-            # Off-ladder shape (e.g. the batched engine's trimmed paged
-            # window, which takes many values): snap to the nearest
-            # measured rung so demotions cover it.
-            rungs = [int(k) for k in entry if str(k).isdigit()]
-            if rungs:
-                hit = entry[str(min(rungs,
-                                    key=lambda r: abs(r - int(length))))]
-        if hit is None:
-            hit = entry.get("default")
-        return hit
-    return None
-
-
-def _choose(impl: str, kind: str, length: Optional[int]) -> str:
-    resolved = resolve_impl(impl)
-    if resolved == "pallas" and os.environ.get("DLLM_ATTENTION") is None:
-        measured = _measured_impl(kind, length)
-        if measured in ("xla", "pallas"):
-            return measured
-    return resolved
-
-
-def decode_kv_span(kind: str, length: int, positions, impl: str = "auto",
-                   block: Optional[int] = None) -> float:
-    """Average per-sequence KV span the ACTIVE decode kernel streams per
-    step, for roofline accounting (utils/roofline.py decode_work kv_ctx).
-
-    The XLA paths read the full allocated span; the Pallas decode kernels
-    clamp their grid onto the causal frontier and stream only
-    ceil((pos+1)/block) tiles (pallas_attention.py ``_decode_kernel`` /
-    paged index maps), so charging the allocated span would overstate
-    hbm_util — the judged decode metric — past 1.0.
-
-    ``positions`` iterates the 0-based query positions of the accounted
-    steps (per step for a single sequence, per row for a batched tick);
-    ``block`` is the paged pool's block size, or None for the contiguous
-    kernels' own tile ladder."""
-    if _choose(impl, kind, length) != "pallas":
-        return float(length)
-    if block is None:      # flash_decode_* tile ladder (pallas_attention.py)
-        block = next((t for t in (256, 128) if length % t == 0), length)
-    spans = [min(length, (int(p) // block + 1) * block) for p in positions]
-    return float(sum(spans)) / max(len(spans), 1)
 
 
 def resolve_impl(impl: str = "auto") -> str:
@@ -164,10 +34,11 @@ def resolve_impl(impl: str = "auto") -> str:
     it is the only safe default inside pjit-sharded computations (the
     trainer's sp/tp meshes, tensor-sharded tiers).  'pallas' is an explicit
     opt-in used by unsharded serving engines (engine/inference.py picks it
-    for single-device tiers on TPU); a pallas_call has no GSPMD sharding
-    rule, so opting in under a >1-device mesh would replicate the operands.
-    DLLM_ATTENTION=xla|pallas overrides everything (kill switch / forced
-    testing); any other value raises rather than failing open.
+    for single-device tiers on TPU) and means the flash prefill and the
+    streamed rows kernel where they serve; a pallas_call has no GSPMD
+    sharding rule, so opting in under a >1-device mesh would replicate the
+    operands.  DLLM_ATTENTION=xla|pallas overrides everything (kill switch
+    / forced testing); any other value raises rather than failing open.
     """
     env = os.environ.get("DLLM_ATTENTION")
     if env is not None:
@@ -185,7 +56,7 @@ def resolve_impl(impl: str = "auto") -> str:
 def causal(q: jax.Array, k: jax.Array, v: jax.Array,
            impl: str = "auto") -> jax.Array:
     """Dispatching causal attention (prefill)."""
-    if _choose(impl, "prefill", q.shape[1]) == "pallas":
+    if resolve_impl(impl) == "pallas":
         from .pallas_attention import flash_causal_attention
         return flash_causal_attention(q, k, v)
     return causal_attention(q, k, v)
@@ -195,95 +66,46 @@ def _dequant_cache(k_cache, v_cache, k_scale, v_scale, dtype):
     """Contiguous int8 cache ([.., S, Nkv, D] + [.., S, Nkv] scales) ->
     model-dtype views for the XLA attention math (the cast fuses into the
     attention einsum read; the HBM-resident cache stays int8)."""
+    if k_scale is None:
+        return k_cache, v_cache
     from .quant import dequantize_kv_rows
     return (dequantize_kv_rows(k_cache, k_scale, dtype),
             dequantize_kv_rows(v_cache, v_scale, dtype))
 
 
 def decode(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
-           pos: jax.Array, impl: str = "auto", k_scale: jax.Array = None,
+           pos: jax.Array, k_scale: jax.Array = None,
            v_scale: jax.Array = None) -> jax.Array:
-    """Dispatching single-step decode attention.  ``k_scale``/``v_scale``
-    mark an int8 contiguous cache (TierConfig.kv_quantize): the Pallas
-    path streams int8 tiles + scales with in-VMEM dequant (its own
-    'decode_q8' dispatch kind); the XLA path dequantizes a view."""
-    if k_scale is not None:
-        if _choose(impl, "decode_q8", k_cache.shape[1]) == "pallas":
-            from .pallas_attention import flash_decode_attention_q8
-            return flash_decode_attention_q8(q, k_cache, v_cache, k_scale,
-                                             v_scale, pos)
-        k_cache, v_cache = _dequant_cache(k_cache, v_cache, k_scale,
-                                          v_scale, q.dtype)
-        return decode_attention(q, k_cache, v_cache, pos)
-    if _choose(impl, "decode", k_cache.shape[1]) == "pallas":
-        from .pallas_attention import flash_decode_attention
-        return flash_decode_attention(q, k_cache, v_cache, pos)
-    return decode_attention(q, k_cache, v_cache, pos)
+    """Single-step decode attention over a contiguous cache.
+    ``k_scale``/``v_scale`` mark an int8 cache (TierConfig.kv_quantize),
+    dequantized as a view."""
+    return decode_attention(
+        q, *_dequant_cache(k_cache, v_cache, k_scale, v_scale, q.dtype), pos)
 
 
 def chunk(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
-          q_positions: jax.Array, impl: str = "auto",
-          k_scale: jax.Array = None,
+          q_positions: jax.Array, k_scale: jax.Array = None,
           v_scale: jax.Array = None) -> jax.Array:
-    """Dispatching chunked-prefill attention (suffix queries vs the cache
-    window).  The Pallas path keeps cold prefill and prefix-reuse hits on
-    the same kernel family on TPU (flash recurrence, per-query frontier);
-    the XLA path is the portable/shardable fallback — and the only path
-    for int8 caches (scales given)."""
-    # Sublane-unaligned chunk rows (e.g. the speculative verify's γ+1=5)
-    # would hand Mosaic a block shape no hardware run has validated — the
-    # micro A/B measures the chunk kinds at bucket-sized rows only.  Keep
-    # those on XLA until a measured table covers them.
-    aligned = q.shape[1] % 8 == 0
-    if k_scale is not None:
-        if (aligned
-                and _choose(impl, "chunk_q8", k_cache.shape[1]) == "pallas"):
-            from .pallas_attention import flash_chunk_attention_q8
-            return flash_chunk_attention_q8(q, k_cache, v_cache, k_scale,
-                                            v_scale, q_positions)
-        k_cache, v_cache = _dequant_cache(k_cache, v_cache, k_scale,
-                                          v_scale, q.dtype)
-        return chunk_attention(q, k_cache, v_cache, q_positions)
-    if aligned and _choose(impl, "chunk", k_cache.shape[1]) == "pallas":
-        from .pallas_attention import flash_chunk_attention
-        return flash_chunk_attention(q, k_cache, v_cache, q_positions)
-    return chunk_attention(q, k_cache, v_cache, q_positions)
-
-
-def _layer_views(layer, head_dim, k_pool, v_pool, k_scale=None,
-                 v_scale=None):
-    """Per-layer head-major ``(k, v, ks, vs)`` views ``[Nkv, NB, bs(,
-    D)]`` for the kernels and hooks: with a ``layer`` index the pools are
-    the WHOLE token-major arrays of engine/paged_kv.py (``[L, NB, bs,
-    Nkv * D]``, scales ``[L, NB, bs, Nkv]``) and the layer is sliced out
-    and turned here — a layer-sized copy, which the XLA paths below avoid
-    by gathering from the whole pool; without one they already are the
-    views."""
-    if layer is None:
-        return k_pool, v_pool, k_scale, v_scale
-
-    def view(pool, *heads):
-        x = jax.lax.dynamic_index_in_dim(pool, layer, 0, keepdims=False)
-        return jnp.moveaxis(x.reshape(*x.shape[:2], -1, *heads), 2, 0)
-
-    return (view(k_pool, head_dim), view(v_pool, head_dim),
-            k_scale if k_scale is None else view(k_scale),
-            v_scale if v_scale is None else view(v_scale))
+    """Chunked-prefill attention (suffix queries vs the contiguous cache
+    window); scales as in ``decode``."""
+    return chunk_attention(
+        q, *_dequant_cache(k_cache, v_cache, k_scale, v_scale, q.dtype),
+        q_positions)
 
 
 def _gather_pool_seq(q, k_pool, v_pool, tables, k_scale, v_scale,
                      layer=None, merged=False):
-    """The paged fallbacks' ONE table gather: pools + tables [B, MB] ->
+    """The paged ops' ONE table gather: pools + tables [B, MB] ->
     contiguous [B, S, Nkv, D] views in ``q``'s dtype (int8 pools
     dequantized through the gathered scales).  Shared by the decode
-    (q_len=1), verify (q_len=γ+1) and chunk fallbacks so their
-    byte-parity is mechanical, not maintained by hand.
+    (q_len=1), verify (q_len=γ+1) and chunk ops so their byte-parity is
+    mechanical, not maintained by hand.
 
-    The pools are per-layer head-major views ``[Nkv, NB, bs(, D)]`` or,
-    with a ``layer`` index, the WHOLE token-major pool ``[L, NB, bs,
-    Nkv * D]`` (scales ``[L, NB, bs, Nkv]``), gathered at (layer, block)
-    directly: whole blocks of whole tokens, already in the order the
-    attention wants, and no layer-sized slice in between.
+    The pools are per-layer head-major views ``[Nkv, NB, bs(, D)]`` (a tp
+    hook's shard) or, with a ``layer`` index, the WHOLE token-major pool
+    ``[L, NB, bs, Nkv * D]`` (scales ``[L, NB, bs, Nkv]``), gathered at
+    (layer, block) directly: whole blocks of whole tokens, already in the
+    order the attention wants, and no layer-sized slice in between.
 
     ``merged`` (token-major pool only) leaves the gathered rows as they
     rest, ``[B, S, Nkv * D]`` with the heads side by side on the lanes,
@@ -291,8 +113,8 @@ def _gather_pool_seq(q, k_pool, v_pool, tables, k_scale, v_scale,
     ``merged_decode_attention`` contracts over.  Splitting the head axis
     off a window-sized array is a copy on a TPU wherever ``D`` is not a
     whole number of 128-lane rows (at 64 it is padded to twice its
-    size); the chunk and verify fallbacks still pay it, because their
-    queries are long and the merged form's zeros would be real work."""
+    size); the chunk and verify ops still pay it, because their queries
+    are long and the merged form's zeros would be real work."""
     b, mb = tables.shape
     d = q.shape[-1]
     if layer is None:
@@ -318,220 +140,93 @@ def _gather_pool_seq(q, k_pool, v_pool, tables, k_scale, v_scale,
     return k_seq, v_seq
 
 
-def decode_form(impl: str, kind: str, n_q: int, head_dim: int,
-                table_blocks: int, block_size: int, row: int, dtype) -> str:
-    """The form a decode op over the WHOLE token-major pool (``layer=i``:
-    the served tick) attends in, from what the code sees when it traces —
-    the one rule ``paged_decode``/``ragged_decode`` dispatch by and
-    ``engine.decode_attention_form`` labels by:
+def decode_form(impl: str, n_q: int, head_dim: int, table_blocks: int,
+                block_size: int, row: int, dtype) -> str:
+    """The form ``paged_decode`` over the WHOLE token-major pool
+    (``layer=i``: the served tick) attends in, from what the code sees
+    when it traces: the one rule the op dispatches by and
+    ``engine.decode_attention_form`` labels by.
 
-    ``split``     the dispatch table (or ``DLLM_ATTENTION=pallas``) puts
-                  ``kind`` on its head-major Pallas kernel, which gets a
-                  layer's view (``_layer_views``: a layer-sized copy);
     ``streamed``  an engine that opted into kernels (``impl`` resolves to
                   'pallas': unsharded, on the TPU) and a window
                   ``ops.rows_attention`` serves (static shapes: floating
                   rows of whole lane-widths, a K/V head to every query
                   head): the block table walked in the kernel, the pool
                   read once where it rests;
-    ``merged``    everything else — a mesh's GSPMD path, the CPU, an int8
-                  pool, GQA's narrow rows, a row off the lanes: the XLA
+    ``merged``    everything else (a mesh's GSPMD path, the CPU, an int8
+                  pool, GQA's narrow rows, a row off the lanes): the XLA
                   gather and ``merged_decode_attention``."""
     from . import rows_attention
-    if _choose(impl, kind, table_blocks * block_size) == "pallas":
-        return "split"
-    if (resolve_impl(impl) == "pallas" and not kind.endswith("_q8")
-            and rows_attention.serves(n_q, head_dim, table_blocks,
-                                      block_size, row, dtype)):
+    if resolve_impl(impl) == "pallas" and rows_attention.serves(
+            n_q, head_dim, table_blocks, block_size, row, dtype):
         return "streamed"
     return "merged"
-
-
-def _decode_paged_fallback(q, k_pool, v_pool, tables, pos, k_scale, v_scale,
-                           layer=None, impl: str = "auto", kind=None):
-    """What ``paged_decode`` and ``ragged_decode`` run where the dispatch
-    table does not put them on a head-major kernel: one code path, so the
-    two kinds agree byte for byte.  The form follows the representation
-    it is handed: per-layer head-major views (``layer`` None: a hook's
-    shard, a kernel's parity test) gather to ``[B, S, Nkv, D]`` and reuse
-    ``decode_attention``, the parity reference for the Pallas kernels;
-    the WHOLE token-major pool (``layer=i``: the served tick) is attended
-    merged, at every ``head_dim`` — ``streamed`` through the table where
-    it rests or, where ``decode_form`` says so, gathered to rows ``[B, S,
-    Nkv * D]`` for ``merged_decode_attention``."""
-    if layer is not None and kind is not None and decode_form(
-            impl, kind, *q.shape[1:], tables.shape[1], *k_pool.shape[2:],
-            k_pool.dtype) == "streamed":
-        from .rows_attention import paged_rows_decode_attention
-        return paged_rows_decode_attention(q, k_pool, v_pool, tables, pos,
-                                           layer)
-    k_seq, v_seq = _gather_pool_seq(q, k_pool, v_pool, tables,
-                                    k_scale, v_scale, layer,
-                                    merged=layer is not None)
-    if layer is not None:
-        return merged_decode_attention(q, k_seq, v_seq, pos)
-    return decode_attention(q, k_seq, v_seq, pos)
 
 
 def paged_decode(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
                  tables: jax.Array, pos: jax.Array,
                  impl: str = "auto", k_scale: jax.Array = None,
                  v_scale: jax.Array = None, layer=None) -> jax.Array:
-    """Dispatching batched decode attention over a paged KV pool
-    (engine/paged_kv.py): q [B, Nq, D], pools [Nkv, NB, bs, D], tables
-    [B, MB], pos [B] -> [B, Nq, D].  The Pallas path walks the block table
-    in-kernel; the XLA path gathers the table into a contiguous view and
-    attends it (portable / GSPMD-shardable fallback).
+    """Batched one-token decode attention over a paged KV pool
+    (engine/paged_kv.py): q [B, Nq, D], tables [B, MB], pos [B] -> [B, Nq,
+    D], each slot over positions ``<= pos[b]`` of its table's window.  The
+    windowed tick passes a truncated table, the fused tick every slot's
+    FULL row: one op, and what is gathered is what the tables span.
 
-    ``k_scale``/``v_scale`` ([Nkv, NB, bs]) mark an int8 pool: the Pallas
-    path streams int8 blocks + scales and dequantizes in VMEM
-    (paged_decode_attention_q8, its own dispatch kind); the XLA path
-    gathers HALF the bytes and dequantizes after.
-
-    ``layer`` (here and in the three ops below): the pools and scales
-    are the WHOLE token-major arrays of engine/paged_kv.py ([L, NB, bs,
-    Nkv * D], scales [L, NB, bs, Nkv]) and this is the traced layer to
-    attend — the XLA path gathers straight from the whole pool, a kernel
-    gets the layer's head-major view (``_layer_views``).  Which form the
-    XLA path attends in follows from which of the two it was handed
-    (``_decode_paged_fallback``): head-major views split by head through
-    ``decode_attention``, the whole pool's rows merged through
-    ``merged_decode_attention``.  Only the two decode ops (query length
-    1) have a merged form; ``ragged_verify`` and ``paged_chunk`` split
-    the head axis off their window whichever they are handed."""
-    b, mb = tables.shape
-    bs = k_pool.shape[-2]
-    if k_scale is None:
-        if _choose(impl, "paged_decode", mb * bs) == "pallas":
-            from .pallas_attention import paged_decode_attention
-            return paged_decode_attention(
-                q, *_layer_views(layer, q.shape[-1], k_pool, v_pool)[:2],
-                tables, pos)
-    elif _choose(impl, "paged_decode_q8", mb * bs) == "pallas":
-        from .pallas_attention import paged_decode_attention_q8
-        return paged_decode_attention_q8(
-            q, *_layer_views(layer, q.shape[-1], k_pool, v_pool, k_scale,
-                             v_scale),
-            tables, pos)
-    return _decode_paged_fallback(
-        q, k_pool, v_pool, tables, pos, k_scale, v_scale, layer, impl,
-        "paged_decode" + ("" if k_scale is None else "_q8"))
-
-
-def ragged_decode(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
-                  tables: jax.Array, pos: jax.Array,
-                  impl: str = "auto", k_scale: jax.Array = None,
-                  v_scale: jax.Array = None, layer=None) -> jax.Array:
-    """Dispatching RAGGED batched decode attention over a paged KV pool:
-    same shapes as ``paged_decode`` (q [B, Nq, D], pools [Nkv, NB, bs, D],
-    tables [B, MB], pos [B] -> [B, Nq, D]) but a different contract — the
-    caller passes each slot's FULL table row and TRUE position, never a
-    padded bucket window shared across the batch.
-
-    The Pallas path (ops/ragged_attention.py) grids over slots ×
-    KV blocks with all heads per program and clamps each slot onto its
-    own frontier, so one invocation serves the whole mixed-length batch
-    at per-slot cost and the batched engine compiles ONE decode program
-    for its life (no window-rung ladder, no per-rung compile churn).
-    The XLA path gathers the full table and masks by ``pos`` — the
-    portable fallback (default on CPU) and the byte-level correctness
-    reference the parity suite pins the kernel against.  ``k_scale``/
-    ``v_scale`` ([Nkv, NB, bs]) mark an int8 pool (ragged_decode_q8,
-    in-VMEM dequant on the Pallas path)."""
-    b, mb = tables.shape
-    bs = k_pool.shape[-2]
-    if k_scale is None:
-        if _choose(impl, "ragged_decode", mb * bs) == "pallas":
-            from .ragged_attention import ragged_paged_decode_attention
-            return ragged_paged_decode_attention(
-                q, *_layer_views(layer, q.shape[-1], k_pool, v_pool)[:2],
-                tables, pos)
-    elif _choose(impl, "ragged_decode_q8", mb * bs) == "pallas":
-        from .ragged_attention import ragged_paged_decode_attention_q8
-        return ragged_paged_decode_attention_q8(
-            q, *_layer_views(layer, q.shape[-1], k_pool, v_pool, k_scale,
-                             v_scale),
-            tables, pos)
-    return _decode_paged_fallback(
-        q, k_pool, v_pool, tables, pos, k_scale, v_scale, layer, impl,
-        "ragged_decode" + ("" if k_scale is None else "_q8"))
-
-
-def _gather_verify_paged(q, k_pool, v_pool, tables, pos, k_scale, v_scale,
-                         layer=None):
-    """XLA fallback for ``ragged_verify``: the SAME ``_gather_pool_seq``
-    gather as ``_decode_paged_fallback`` (so the q_len=1 and q_len=γ+1
-    fallbacks agree block-for-block by construction), attended through
-    ``chunk_attention`` with per-query absolute positions — the
-    byte-level correctness reference the Pallas verify kernels are
-    pinned against."""
-    g = q.shape[1]
-    k_seq, v_seq = _gather_pool_seq(q, k_pool, v_pool, tables,
-                                    k_scale, v_scale, layer)
-    q_pos = pos[:, None] + jnp.arange(g)[None]               # [B, G]
-    return chunk_attention(q, k_seq, v_seq, q_pos)
+    ``layer`` (here and in the two ops below): the pools and scales are
+    the WHOLE token-major arrays of engine/paged_kv.py ([L, NB, bs,
+    Nkv * D], scales [L, NB, bs, Nkv], int8 rows where scales are given)
+    and this is the traced layer to attend, in the form ``decode_form``
+    names: ``streamed`` through the table where the pool rests, or its
+    rows gathered ``[B, S, Nkv * D]`` for ``merged_decode_attention``.
+    Without it they are a layer's head-major views ``[Nkv, NB, bs(, D)]``
+    (a tp hook's shard), gathered to ``[B, S, Nkv, D]`` for
+    ``decode_attention``.  Only this op (query length 1) has a merged
+    form; ``ragged_verify`` and ``paged_chunk`` split the head axis off
+    their window whichever they are handed."""
+    if layer is None:
+        return decode_attention(
+            q, *_gather_pool_seq(q, k_pool, v_pool, tables, k_scale,
+                                 v_scale), pos)
+    if decode_form(impl, *q.shape[1:], tables.shape[1], *k_pool.shape[2:],
+                   k_pool.dtype) == "streamed":
+        from .rows_attention import paged_rows_decode_attention
+        return paged_rows_decode_attention(q, k_pool, v_pool, tables, pos,
+                                           layer)
+    return merged_decode_attention(
+        q, *_gather_pool_seq(q, k_pool, v_pool, tables, k_scale, v_scale,
+                             layer, merged=True), pos)
 
 
 def ragged_verify(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
                   tables: jax.Array, pos: jax.Array,
-                  impl: str = "auto", k_scale: jax.Array = None,
-                  v_scale: jax.Array = None, layer=None) -> jax.Array:
-    """Dispatching RAGGED speculative-verify attention over a paged KV
-    pool: q [B, G, Nq, D] — G = γ+1 chunk queries per slot at absolute
-    positions ``pos[b] + g`` (``pos`` [B] is the FIRST query's position;
-    the chunk's K/V are already written, write-before-attend), pools
-    [Nkv, NB, bs, D], tables [B, MB] -> [B, G, Nq, D].
-
-    The q_len=γ+1 extension of ``ragged_decode`` (the Ragged Paged
-    Attention paper's q-length flexibility): the Pallas path
-    (ops/ragged_attention.py verify kernels) streams each slot's own
-    ceil((pos+G)/bs) blocks with a per-query causal mask, so one
-    invocation verifies every slot's drafts at per-slot cost regardless
-    of length skew.  The XLA path gathers the full table and reuses
-    ``chunk_attention`` — the portable fallback (default everywhere
-    until an on-chip A/B writes a 'pallas' row; the shipped
-    ab_dispatch.json rows are conservative 'xla') and the byte-level
-    parity reference.  ``k_scale``/``v_scale`` ([Nkv, NB, bs]) mark an
-    int8 pool (ragged_verify_q8, in-VMEM dequant on the Pallas path)."""
-    b, mb = tables.shape
-    bs = k_pool.shape[-2]
-    if k_scale is None:
-        if _choose(impl, "ragged_verify", mb * bs) == "pallas":
-            from .ragged_attention import ragged_paged_verify_attention
-            return ragged_paged_verify_attention(
-                q, *_layer_views(layer, q.shape[-1], k_pool, v_pool)[:2],
-                tables, pos)
-    elif _choose(impl, "ragged_verify_q8", mb * bs) == "pallas":
-        from .ragged_attention import ragged_paged_verify_attention_q8
-        return ragged_paged_verify_attention_q8(
-            q, *_layer_views(layer, q.shape[-1], k_pool, v_pool, k_scale,
-                             v_scale),
-            tables, pos)
-    return _gather_verify_paged(q, k_pool, v_pool, tables, pos,
-                                k_scale, v_scale, layer)
+                  k_scale: jax.Array = None, v_scale: jax.Array = None,
+                  layer=None) -> jax.Array:
+    """Speculative-verify attention over a paged KV pool: q [B, G, Nq, D],
+    G = γ+1 chunk queries per slot at absolute positions ``pos[b] + g``
+    (``pos`` [B] is the FIRST query's position; the chunk's K/V are
+    already written, write-before-attend), tables [B, MB] each slot's FULL
+    row -> [B, G, Nq, D].  The SAME ``_gather_pool_seq`` gather as
+    ``paged_decode`` (so the q_len=1 and q_len=γ+1 ops agree
+    block-for-block by construction), attended through ``chunk_attention``
+    with per-query positions."""
+    k_seq, v_seq = _gather_pool_seq(q, k_pool, v_pool, tables,
+                                    k_scale, v_scale, layer)
+    q_pos = pos[:, None] + jnp.arange(q.shape[1])[None]      # [B, G]
+    return chunk_attention(q, k_seq, v_seq, q_pos)
 
 
 def paged_chunk(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
-                table: jax.Array, start: jax.Array, q_pos: jax.Array,
-                window: int, impl: str = "auto", k_scale: jax.Array = None,
-                v_scale: jax.Array = None, layer=None) -> jax.Array:
-    """Dispatching suffix-chunk attention over a paged KV pool
-    (engine/paged_kv.chunk_prefill_paged): q [1, S_c, Nq, D], pools
-    [Nkv, NB, bs, D], table [MB], start [1], q_pos [1, S_c] clamped
-    absolute positions, static ``window``.  The Pallas path reconstructs
-    positions from ``start`` (contiguous-chunk contract, like
-    flash_chunk_attention); the XLA path gathers the window and masks by
-    ``q_pos`` (portable / GSPMD-shardable fallback).  ``k_scale``/
-    ``v_scale`` mark an int8 pool (XLA dequant path, see paged_decode)."""
-    bs = k_pool.shape[-2]
-    if k_scale is None and _choose(impl, "paged_chunk", window) == "pallas":
-        from .pallas_attention import paged_chunk_attention
-        return paged_chunk_attention(
-            q, *_layer_views(layer, q.shape[-1], k_pool, v_pool)[:2], table,
-            start, window)
-    k_seq, v_seq = _gather_pool_seq(q, k_pool, v_pool,
-                                    table[None, :window // bs],
-                                    k_scale, v_scale, layer)
+                table: jax.Array, q_pos: jax.Array, window: int,
+                k_scale: jax.Array = None, v_scale: jax.Array = None,
+                layer=None) -> jax.Array:
+    """Suffix-chunk attention over a paged KV pool
+    (engine/paged_kv.chunk_prefill_paged): q [1, S_c, Nq, D], table [MB],
+    q_pos [1, S_c] clamped absolute positions, static ``window``: the
+    table's first ``window`` positions gathered and masked by ``q_pos``."""
+    k_seq, v_seq = _gather_pool_seq(
+        q, k_pool, v_pool, table[None, :window // k_pool.shape[-2]],
+        k_scale, v_scale, layer)
     return chunk_attention(q, k_seq, v_seq, q_pos)
 
 

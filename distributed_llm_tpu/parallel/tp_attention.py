@@ -1,19 +1,19 @@
-"""Tensor-parallel Pallas prefill: shard_map the flash kernel over heads.
+"""Tensor-parallel attention hooks: shard_map over the head axis.
 
-Round 1 left sharded tiers entirely on the XLA attention path — a
-``pallas_call`` has no GSPMD partitioning rule, so opting in under a
+A ``pallas_call`` has no GSPMD partitioning rule, so opting in under a
 mesh would replicate the operands (ops/attention.py resolve_impl).  But
 attention is embarrassingly parallel over kv-head groups: under Megatron
 sharding q/k/v are already head-sharded on the 'tp' axis, so wrapping the
-flash kernel in ``shard_map`` runs one per-shard kernel per chip with
-ZERO added collectives — each chip's [B, S, Nq/tp, D] slice is a complete
-smaller attention problem (GQA group structure is preserved because Nq
-and Nkv shard by the same factor).
+flash prefill kernel in ``shard_map`` runs one per-shard kernel per chip
+with ZERO added collectives — each chip's [B, S, Nq/tp, D] slice is a
+complete smaller attention problem (GQA group structure is preserved
+because Nq and Nkv shard by the same factor).
 
-That covers the FLOPs-heavy prefill.  Decode
-stays on the GSPMD path under meshes: it is weight-bandwidth-bound, the
-kernel win there is the frontier-clamped KV streaming, and the paged
-pool's gather already shards on the kv-head axis.
+That covers the FLOPs-heavy prefill.  Decode over the paged pool has two
+shapes under a mesh: the fused tick's hooks below (``tp_ragged_*``) wrap
+the ops of ops/attention.py over per-shard head-major views, and the
+windowed tick stays on the GSPMD XLA path (the pool's gather already
+shards on the kv-head axis).
 """
 
 from __future__ import annotations
@@ -41,163 +41,73 @@ def tp_flash_causal(mesh: jax.sharding.Mesh,
                      check_vma=False)
 
 
-def tp_flash_decode(mesh: jax.sharding.Mesh,
-                    head_axis: str = "tp") -> Callable:
-    """(q [B,Nq,D], k/v [B,S,Nkv,D], pos [B]) -> [B,Nq,D], head-sharded:
-    the KV-length-tiled flash decode kernel runs per head-shard — each
-    chip streams only its own heads' frontier-clamped cache slice."""
+def _paged_hook(op: Callable, mesh: jax.sharding.Mesh, qspec: P,
+                head_axis: Optional[str], quantized: bool) -> Callable:
+    """A paged op of ops/attention.py under shard_map, as the attention
+    hook of decode_step_paged / verify_step_paged: (q, k_pool, v_pool,
+    tables, pos, k_scale, v_scale) with per-layer head-major pools [Nkv,
+    NB, bs, D] (scales [Nkv, NB, bs]) on ``head_axis``, tables and
+    positions replicated."""
     from jax import shard_map
 
-    from ..ops.pallas_attention import flash_decode_attention
-
-    qspec = P(None, head_axis, None)
-    cspec = P(None, None, head_axis, None)
-    return shard_map(flash_decode_attention, mesh=mesh,
-                     in_specs=(qspec, cspec, cspec, P(None)),
-                     out_specs=qspec, check_vma=False)
-
-
-def tp_paged_decode(mesh: jax.sharding.Mesh, quantized: bool = False,
-                    head_axis: str = "tp") -> Callable:
-    """Paged-pool twin: pools [Nkv, NB, bs, D] (+ scale planes when
-    ``quantized``) shard on the kv-head axis — exactly the batched
-    engine's pool sharding (parallel/sharding.py kv_pool_specs) — so the
-    in-kernel block walk is shard-local.  Signature matches the
-    decode_step_paged attention hook: (q, k_pool, v_pool, tables, pos,
-    k_scale, v_scale)."""
-    from jax import shard_map
-
-    from ..ops.pallas_attention import (paged_decode_attention,
-                                        paged_decode_attention_q8)
-
-    qspec = P(None, head_axis, None)
     pspec = P(head_axis, None, None, None)
+    sspec = P(head_axis, None, None)
+    where = (P(None, None), P(None))
     if quantized:
-        sspec = P(head_axis, None, None)
         fn = shard_map(
-            lambda q, kp, vp, ks, vs, tbl, pos: paged_decode_attention_q8(
-                q, kp, vp, ks, vs, tbl, pos),
-            mesh=mesh,
-            in_specs=(qspec, pspec, pspec, sspec, sspec, P(None), P(None)),
-            out_specs=qspec, check_vma=False)
-        return lambda q, kp, vp, tbl, pos, ks, vs: fn(q, kp, vp, ks, vs,
-                                                      tbl, pos)
-    fn = shard_map(paged_decode_attention, mesh=mesh,
-                   in_specs=(qspec, pspec, pspec, P(None), P(None)),
-                   out_specs=qspec, check_vma=False)
-    return lambda q, kp, vp, tbl, pos, ks, vs: fn(q, kp, vp, tbl, pos)
-
-
-def tp_ragged_decode(mesh: jax.sharding.Mesh, impl: str = "auto",
-                     quantized: bool = False,
-                     head_axis: str = "tp") -> Callable:
-    """Shard-mapped RAGGED paged decode (PR 16): wraps the DISPATCHING
-    ``ops.attention.ragged_decode`` — not a fixed kernel — over the
-    kv-head axis, so each shard re-runs the pallas-vs-xla dispatch on its
-    own whole-head slice (fused ragged kernel on TPU, gather fallback on
-    CPU) and the combine is a head concat via ``out_specs``, never a
-    softmax merge.  Signature matches the decode_step_paged /
-    verify_step_paged attention hook: (q, k_pool, v_pool, tables, pos,
-    k_scale, v_scale) with per-layer pools [Nkv, NB, bs, D]."""
-    from jax import shard_map
-
-    from ..ops import attention
-
-    qspec = P(None, head_axis, None)
-    pspec = P(head_axis, None, None, None)
-    if quantized:
-        sspec = P(head_axis, None, None)
-        fn = shard_map(
-            lambda q, kp, vp, ks, vs, tbl, pos: attention.ragged_decode(
-                q, kp, vp, tbl, pos, impl=impl, k_scale=ks, v_scale=vs),
-            mesh=mesh,
-            in_specs=(qspec, pspec, pspec, sspec, sspec,
-                      P(None, None), P(None)),
+            lambda q, kp, vp, ks, vs, tbl, pos: op(
+                q, kp, vp, tbl, pos, k_scale=ks, v_scale=vs),
+            mesh=mesh, in_specs=(qspec, pspec, pspec, sspec, sspec, *where),
             out_specs=qspec, check_vma=False)
         return lambda q, kp, vp, tbl, pos, ks, vs: fn(q, kp, vp, ks, vs,
                                                       tbl, pos)
     fn = shard_map(
-        lambda q, kp, vp, tbl, pos: attention.ragged_decode(
-            q, kp, vp, tbl, pos, impl=impl),
-        mesh=mesh,
-        in_specs=(qspec, pspec, pspec, P(None, None), P(None)),
+        lambda q, kp, vp, tbl, pos: op(q, kp, vp, tbl, pos),
+        mesh=mesh, in_specs=(qspec, pspec, pspec, *where),
         out_specs=qspec, check_vma=False)
     return lambda q, kp, vp, tbl, pos, ks, vs: fn(q, kp, vp, tbl, pos)
 
 
-def tp_ragged_verify(mesh: jax.sharding.Mesh, impl: str = "auto",
+def tp_ragged_decode(mesh: jax.sharding.Mesh,
+                     quantized: bool = False,
+                     head_axis: str = "tp") -> Callable:
+    """Shard-mapped fused-tick paged decode (PR 16): wraps
+    ``ops.attention.paged_decode`` over the kv-head axis, so each shard
+    gathers and attends its own whole-head slice and the combine is a
+    head concat via ``out_specs``, never a softmax merge."""
+    from ..ops import attention
+    return _paged_hook(attention.paged_decode, mesh,
+                       P(None, head_axis, None), head_axis, quantized)
+
+
+def tp_ragged_verify(mesh: jax.sharding.Mesh,
                      quantized: bool = False,
                      head_axis: str = "tp") -> Callable:
     """Shard-mapped RAGGED speculative verify: q [B, G, Nq, D] sharded on
     its head axis, pools on the kv-head axis — the γ+1-query twin of
     ``tp_ragged_decode`` so a spec round verifies every slot's drafts in
-    ONE fused sharded call.  Same hook signature."""
-    from jax import shard_map
-
+    ONE fused sharded call."""
     from ..ops import attention
-
-    qspec = P(None, None, head_axis, None)
-    pspec = P(head_axis, None, None, None)
-    if quantized:
-        sspec = P(head_axis, None, None)
-        fn = shard_map(
-            lambda q, kp, vp, ks, vs, tbl, pos: attention.ragged_verify(
-                q, kp, vp, tbl, pos, impl=impl, k_scale=ks, v_scale=vs),
-            mesh=mesh,
-            in_specs=(qspec, pspec, pspec, sspec, sspec,
-                      P(None, None), P(None)),
-            out_specs=qspec, check_vma=False)
-        return lambda q, kp, vp, tbl, pos, ks, vs: fn(q, kp, vp, ks, vs,
-                                                      tbl, pos)
-    fn = shard_map(
-        lambda q, kp, vp, tbl, pos: attention.ragged_verify(
-            q, kp, vp, tbl, pos, impl=impl),
-        mesh=mesh,
-        in_specs=(qspec, pspec, pspec, P(None, None), P(None)),
-        out_specs=qspec, check_vma=False)
-    return lambda q, kp, vp, tbl, pos, ks, vs: fn(q, kp, vp, tbl, pos)
+    return _paged_hook(attention.ragged_verify, mesh,
+                       P(None, None, head_axis, None), head_axis, quantized)
 
 
-def tp_local_ragged_decode(mesh: jax.sharding.Mesh, impl: str = "auto",
+def tp_local_ragged_decode(mesh: jax.sharding.Mesh,
                            quantized: bool = False) -> Callable:
-    """ALL-REPLICATED shard_map wrap of the dispatching ragged decode:
+    """ALL-REPLICATED shard_map wrap of the fused-tick paged decode:
     every chip runs the FULL problem on its own replica (in/out specs
     all ``P(None, ...)``), so a REPLICATED draft model drafts locally
-    with zero collectives — and the per-device dispatcher may still
-    pick the fused Pallas kernel, which is illegal in a plain jit over
-    a mesh but fine inside shard_map's per-device region.  Hook
-    signature matches ``tp_ragged_decode``."""
-    from jax import shard_map
-
+    with zero collectives."""
     from ..ops import attention
-
-    qspec = P(None, None, None)
-    pspec = P(None, None, None, None)
-    if quantized:
-        sspec = P(None, None, None)
-        fn = shard_map(
-            lambda q, kp, vp, ks, vs, tbl, pos: attention.ragged_decode(
-                q, kp, vp, tbl, pos, impl=impl, k_scale=ks, v_scale=vs),
-            mesh=mesh,
-            in_specs=(qspec, pspec, pspec, sspec, sspec,
-                      P(None, None), P(None)),
-            out_specs=qspec, check_vma=False)
-        return lambda q, kp, vp, tbl, pos, ks, vs: fn(q, kp, vp, ks, vs,
-                                                      tbl, pos)
-    fn = shard_map(
-        lambda q, kp, vp, tbl, pos: attention.ragged_decode(
-            q, kp, vp, tbl, pos, impl=impl),
-        mesh=mesh,
-        in_specs=(qspec, pspec, pspec, P(None, None), P(None)),
-        out_specs=qspec, check_vma=False)
-    return lambda q, kp, vp, tbl, pos, ks, vs: fn(q, kp, vp, tbl, pos)
+    return _paged_hook(attention.paged_decode, mesh, P(None, None, None),
+                       None, quantized)
 
 
 def _tp_ragged_ok(mesh: Optional[jax.sharding.Mesh], cfg) -> bool:
     """Gate for the shard-mapped ragged hooks: tp-only mesh, dense model,
-    divisible q AND kv heads.  Deliberately NOT pallas-gated — the
-    dispatcher inside the shard re-decides pallas-vs-xla per shard, so
-    the wrap is correct (and byte-identical to tp=1) on any backend."""
+    divisible q AND kv heads.  Deliberately NOT pallas-gated: the ops
+    inside the shard are XLA, so the wrap is correct (and byte-identical
+    to tp=1) on any backend."""
     if mesh is None or cfg.num_experts > 1:
         return False
     shape = dict(mesh.shape)
@@ -212,8 +122,7 @@ def tp_ragged_decode_attn(mesh: Optional[jax.sharding.Mesh], cfg,
     """Ragged decode hook for TP tiers, or None (unsharded / non-tp)."""
     if not _tp_ragged_ok(mesh, cfg):
         return None
-    return tp_ragged_decode(mesh, impl=cfg.attention_impl,
-                            quantized=quantized)
+    return tp_ragged_decode(mesh, quantized=quantized)
 
 
 def tp_ragged_verify_attn(mesh: Optional[jax.sharding.Mesh], cfg,
@@ -221,48 +130,18 @@ def tp_ragged_verify_attn(mesh: Optional[jax.sharding.Mesh], cfg,
     """Ragged verify hook for TP tiers, or None."""
     if not _tp_ragged_ok(mesh, cfg):
         return None
-    return tp_ragged_verify(mesh, impl=cfg.attention_impl,
-                            quantized=quantized)
-
-
-def _tp_policy(mesh: Optional[jax.sharding.Mesh], cfg, kind: str,
-               length: int) -> bool:
-    """Shared gate for every shard-mapped Pallas hook: tp-only mesh,
-    dense model, divisible heads, Pallas preferred for (kind, length)."""
-    if mesh is None or cfg.num_experts > 1:
-        return False
-    shape = dict(mesh.shape)
-    tp = shape.get("tp", 1)
-    if tp <= 1 or shape.get("sp", 1) > 1:
-        return False
-    if cfg.num_kv_heads % tp or cfg.num_heads % tp:
-        return False
-    env = os.environ.get("DLLM_ATTENTION")
-    if env == "xla":
-        return False
-    if env != "pallas" and jax.default_backend() != "tpu":
-        return False
-    from ..ops.attention import _choose
-    return _choose("pallas", kind, length) == "pallas"
-
-
-def tp_decode_attn(mesh: Optional[jax.sharding.Mesh], cfg,
-                   cache_len: int) -> Optional[Callable]:
-    """Decode hook for TP tiers with a contiguous cache, or None for the
-    GSPMD XLA path."""
-    if not _tp_policy(mesh, cfg, "decode", cache_len):
-        return None
-    return tp_flash_decode(mesh)
+    return tp_ragged_verify(mesh, quantized=quantized)
 
 
 def tp_paged_decode_attn(mesh: Optional[jax.sharding.Mesh], cfg,
                          window: int,
                          quantized: bool = False) -> Optional[Callable]:
-    """Decode hook for TP tiers over the paged pool, or None."""
-    kind = "paged_decode_q8" if quantized else "paged_decode"
-    if not _tp_policy(mesh, cfg, kind, window):
-        return None
-    return tp_paged_decode(mesh, quantized)
+    """The windowed tick's hook under a mesh: always None, the GSPMD XLA
+    path (the per-head-shard paged kernel it once returned never served
+    on the chip and went with ISSUE 49).  The name stays because
+    ``benchmark/correct.py`` imports it and a ``simplicity`` PR may not
+    edit ``benchmark/``; it goes with ROADMAP C0."""
+    return None
 
 
 def tp_prefill_attn(mesh: Optional[jax.sharding.Mesh], cfg,
@@ -270,12 +149,20 @@ def tp_prefill_attn(mesh: Optional[jax.sharding.Mesh], cfg,
     """Policy twin of engine upgrade_attention_impl for TP meshes: the
     shard-mapped flash prefill when (a) the mesh is tensor-parallel only
     (ring attention owns sp prefill), (b) the model is dense with
-    tp-divisible kv heads and a block-aligned bucket, and (c) Pallas is
-    the preferred prefill impl — TPU backend or an explicit
-    DLLM_ATTENTION=pallas, minus dispatch-table demotions
-    (ops/attention.py).  None = stay on the GSPMD XLA path."""
+    tp-divisible heads and a block-aligned bucket, and (c) the backend is
+    the TPU or DLLM_ATTENTION=pallas forces it (=xla forbids it).  None =
+    stay on the GSPMD XLA path."""
     if bucket % min(bucket, 128):
         return None                       # flash kernel block contract
-    if not _tp_policy(mesh, cfg, "prefill", bucket):
+    if mesh is None or cfg.num_experts > 1:
+        return None
+    shape = dict(mesh.shape)
+    tp = shape.get("tp", 1)
+    if tp <= 1 or shape.get("sp", 1) > 1:
+        return None
+    if cfg.num_kv_heads % tp or cfg.num_heads % tp:
+        return None
+    env = os.environ.get("DLLM_ATTENTION")
+    if env == "xla" or (env != "pallas" and jax.default_backend() != "tpu"):
         return None
     return tp_flash_causal(mesh)

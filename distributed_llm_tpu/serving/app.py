@@ -399,36 +399,14 @@ def create_app(router: Optional[Router] = None,
             cache_stats = router_.query_router.get_cache_stats()
         except Exception:
             cache_stats = None
-        # Measurement provenance: whether the measured attention-dispatch
-        # table steers serving on THIS backend — "none" means the
-        # defaults are in effect.
         import jax as _jax
-        backend = _jax.default_backend()
-        provenance = {"backend": backend}
-        try:
-            from ..ops.attention import dispatch_provenance
-            disp = dispatch_provenance()
-            if disp["active"]:
-                provenance["dispatch"] = disp["backend"]
-                # A table measured against older kernels still dispatches
-                # (re-measuring needs hardware) but must read as
-                # provisional.
-                provenance["dispatch_kernel_gen"] = disp["kernel_gen"]
-                provenance["dispatch_stale_kernel_gen"] = (
-                    disp["stale_kernel_gen"])
-            elif disp["backend"] is not None:
-                provenance["dispatch"] = f"ignored ({disp['backend']})"
-            else:
-                provenance["dispatch"] = "none"
-        except Exception:
-            provenance["dispatch"] = "none"
         payload = {
             "strategy": strategy,
             "sessions": sessions,
             "cache": cache_stats,
             "tiers": tiers,
             "devices": device_memory_snapshot(),
-            "measured_tables": provenance,
+            "measured_tables": {"backend": _jax.default_backend()},
             "prefix_affinity_overrides": getattr(
                 router_, "prefix_affinity_overrides", 0),
             # Fault-tolerance observability (serving/breaker.py): per-tier

@@ -16,7 +16,7 @@ surroundings — a directory that moves never hits).
 
 The test suite wires the same rule in tests/conftest.py; this helper is
 for the runtime entry points (serving/app.py, chip_smoke.py,
-bench.tester, ab_kernels, training.pretrain).  Call before the first
+bench.tester, training.pretrain).  Call before the first
 device computation.
 """
 

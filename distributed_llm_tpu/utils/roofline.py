@@ -216,10 +216,10 @@ def decode_work(cfg, steps: int, ctx: int, batch: int = 1,
     ALLOCATED span the full-span XLA kernels compute over, masked or not).
 
     ``kv_ctx`` overrides the span per sequence when the ACTIVE kernel
-    prunes past the causal frontier: the Pallas decode kernels stream (and
-    compute) only ceil((pos+1)/bk) KV tiles, not the allocated span — the
-    engines pass ``ops.attention.decode_kv_span`` so hbm_util reflects the
-    tiles the kernel actually moved.  ``kv_batch`` overrides how many
+    prunes past the causal frontier: the streamed rows kernel
+    (ops/rows_attention.py) reads only a slot's ceil((pos+1)/bs) live
+    blocks, not the allocated span, and the batched engine passes that
+    mean so hbm_util reflects the blocks the kernel actually moved.  ``kv_batch`` overrides how many
     DISTINCT cache streams one step reads: a chunked verify of γ+1 queries
     reads its shared cache once, not γ+1 times (engine/speculative.py)."""
     pm = active_matmul_params(cfg)

@@ -75,8 +75,7 @@ def test_serve_and_what_ran_on_the_tiny_cluster(capsys):
         assert all(f["formats_match"] for f in programs.values()), programs
     # Warm-up is reported apart from the requests, per tier.
     assert set(served.record["warmup_s"]) == {"nano", "orin"}
-    # Off-TPU the batched engine takes the fused ragged tick on the XLA
-    # fallback (the dispatch table is a TPU table and is ignored here).
+    # Off-TPU the batched engine takes the fused ragged tick.
     for tier in ("nano", "orin"):
         row = what[tier]
         assert row["engine"] == "ContinuousBatchingEngine"
@@ -86,7 +85,6 @@ def test_serve_and_what_ran_on_the_tiny_cluster(capsys):
         # The tiny tiers' replies are a few ticks each; the share of them
         # that took everything from the tick before is a real number.
         assert 0.0 < row["tick_resident_share"] <= 1.0
-        assert set(row["impl_by_kind"].values()) == {"xla"}
         assert row["compiled_after_requests"]["decode"] == 1
     out = capsys.readouterr().out
     assert "[smoke:what-ran] pallas kernels: interpret" in out
@@ -100,12 +98,10 @@ def test_kernel_comparison_at_tiny_widths(dtype):
     interpret mode at a geometry the interpreter finishes quickly."""
     cases = chip_smoke.kernel_cases(
         8, 4, 16, dtype, batch=3, block=16, blocks_per_slot=4,
-        prefill_len=64, decode_len=128, chunk=16, chunk_window=128,
-        verify_q=5, grouped={"tiny": (24, 6, 256, 128)},
+        prefill_len=64, grouped={"tiny": (24, 6, 256, 128)},
         scan=(16, 8, 256))
     assert {c.kind for c in cases.values()} == {
-        "prefill", "decode", "chunk", "paged_decode", "ragged_decode",
-        "ragged_decode_q8", "ragged_verify", "grouped_product", "ssm_scan"}
+        "prefill", "paged_decode", "grouped_product", "ssm_scan"}
     errs = chip_smoke.compare_kernels(cases, jnp.dtype(dtype).name)
     assert set(errs) == set(cases)
 
@@ -113,15 +109,15 @@ def test_kernel_comparison_at_tiny_widths(dtype):
 def test_kernel_comparison_catches_a_wrong_kernel():
     cases = chip_smoke.kernel_cases(
         8, 4, 16, jnp.float32, batch=3, block=16, blocks_per_slot=4,
-        prefill_len=64, decode_len=128, chunk=16, chunk_window=128)
-    good = cases["ragged_paged_decode_attention"]
+        prefill_len=64)
+    good = cases["paged_rows_decode_attention"]
 
     def off_by_one_block(q, kp, vp, tables, pos):
         return good.pallas(q, kp, vp, tables, jnp.maximum(pos - 16, 0))
 
     with pytest.raises(chip_smoke.SmokeFailure, match="differs"):
         chip_smoke.compare_kernels(
-            {"ragged_paged_decode_attention":
+            {"paged_rows_decode_attention":
              good._replace(pallas=off_by_one_block)}, "float32")
 
 

@@ -73,67 +73,6 @@ def test_paged_decode_int8_matches_bf16_attention():
                                atol=5e-2, rtol=5e-2)
 
 
-def test_paged_decode_q8_pallas_matches_xla_dequant(monkeypatch):
-    """The int8 Pallas kernel (in-VMEM dequant, interpret mode on CPU)
-    agrees with the XLA gather+dequant path on the same quantized pool."""
-    from distributed_llm_tpu.ops.attention import paged_decode
-    from distributed_llm_tpu.ops.pallas_attention import \
-        paged_decode_attention_q8
-    key = jax.random.PRNGKey(4)
-    nkv, nb, bs, d, nq, b = 2, 5, 16, 32, 4, 2
-    kq, ks = quantize_kv_rows(
-        jax.random.normal(key, (nkv, nb, bs, d), jnp.bfloat16))
-    vq, vs = quantize_kv_rows(
-        jax.random.normal(jax.random.PRNGKey(5), (nkv, nb, bs, d),
-                          jnp.bfloat16))
-    q = jax.random.normal(jax.random.PRNGKey(6), (b, nq, d), jnp.bfloat16)
-    tables = jnp.asarray([[1, 2], [3, 4]], jnp.int32)
-    pos = jnp.asarray([20, 30], jnp.int32)
-    want = paged_decode(q, kq, vq, tables, pos, impl="xla",
-                        k_scale=ks, v_scale=vs)
-    got = paged_decode_attention_q8(q, kq, vq, ks, vs, tables, pos)
-    np.testing.assert_allclose(np.asarray(got, np.float32),
-                               np.asarray(want, np.float32),
-                               atol=2e-2, rtol=2e-2)
-    # And the dispatcher routes to it when the table prefers pallas.
-    from distributed_llm_tpu.ops import attention as A
-    monkeypatch.setattr(A, "_DISPATCH_TABLE",
-                        {"paged_decode_q8": {"default": "pallas"}})
-    via = A.paged_decode(q, kq, vq, tables, pos, impl="pallas",
-                         k_scale=ks, v_scale=vs)
-    np.testing.assert_allclose(np.asarray(via, np.float32),
-                               np.asarray(got, np.float32), atol=1e-6)
-
-
-def test_flash_decode_q8_matches_xla_dequant(monkeypatch):
-    """Contiguous int8 flash decode (in-VMEM dequant, interpret mode)
-    agrees with the XLA dequant path, and the dispatcher routes to it
-    when the measured table prefers pallas for 'decode_q8'."""
-    from distributed_llm_tpu.ops import attention as A
-    from distributed_llm_tpu.ops.pallas_attention import \
-        flash_decode_attention_q8
-    key = jax.random.PRNGKey(9)
-    b, s, nkv, d, nq = 2, 64, 2, 32, 4
-    kq, ks = quantize_kv_rows(
-        jax.random.normal(key, (b, s, nkv, d), jnp.bfloat16))
-    vq, vs = quantize_kv_rows(
-        jax.random.normal(jax.random.PRNGKey(10), (b, s, nkv, d),
-                          jnp.bfloat16))
-    q = jax.random.normal(jax.random.PRNGKey(11), (b, nq, d), jnp.bfloat16)
-    pos = jnp.asarray([10, 63], jnp.int32)
-    want = A.decode(q, kq, vq, pos, impl="xla", k_scale=ks, v_scale=vs)
-    got = flash_decode_attention_q8(q, kq, vq, ks, vs, pos)
-    np.testing.assert_allclose(np.asarray(got, np.float32),
-                               np.asarray(want, np.float32),
-                               atol=2e-2, rtol=2e-2)
-    monkeypatch.setattr(A, "_DISPATCH_TABLE",
-                        {"decode_q8": {"default": "pallas"}})
-    monkeypatch.delenv("DLLM_ATTENTION", raising=False)
-    via = A.decode(q, kq, vq, pos, impl="pallas", k_scale=ks, v_scale=vs)
-    np.testing.assert_allclose(np.asarray(via, np.float32),
-                               np.asarray(got, np.float32), atol=1e-6)
-
-
 def _tier(**kw):
     return dataclasses.replace(tiny_cluster().nano, decode_batch=2,
                                max_new_tokens=8, **kw)
@@ -255,29 +194,6 @@ def test_decode_work_accounts_int8_kv():
     assert q8["flops"] == full["flops"]
 
 
-def test_flash_decode_q8_serving_geometry_multiblock():
-    """The q8 decode kernel at the bench tiers' head geometry (16q/8kv)
-    with a multi-block cache and ragged positions — the exact shape
-    class whose compile wedged the r3 chip mid-A/B."""
-    from distributed_llm_tpu.ops import attention as A
-    from distributed_llm_tpu.ops.pallas_attention import \
-        flash_decode_attention_q8
-    b, s, nkv, d, nq = 2, 512, 8, 64, 16
-    kq, ks = quantize_kv_rows(
-        jax.random.normal(jax.random.PRNGKey(20), (b, s, nkv, d),
-                          jnp.bfloat16))
-    vq, vs = quantize_kv_rows(
-        jax.random.normal(jax.random.PRNGKey(21), (b, s, nkv, d),
-                          jnp.bfloat16))
-    q = jax.random.normal(jax.random.PRNGKey(22), (b, nq, d), jnp.bfloat16)
-    pos = jnp.asarray([300, 511], jnp.int32)
-    want = A.decode(q, kq, vq, pos, impl="xla", k_scale=ks, v_scale=vs)
-    got = flash_decode_attention_q8(q, kq, vq, ks, vs, pos)
-    np.testing.assert_allclose(np.asarray(got, np.float32),
-                               np.asarray(want, np.float32),
-                               atol=2e-2, rtol=2e-2)
-
-
 # -- the pool rides the layer loop's carry (ISSUE 27) --------------------------
 
 def _scans(jaxpr):
@@ -386,12 +302,10 @@ def _reference_step(step, cfg, params, pool, tables):
                                           **scales)
         elif step == "chunk":
             attn = attention.paged_chunk(q, view["k"], view["v"], tables[0],
-                                         start, q_pos, 48,
-                                         impl=cfg.attention_impl, **scales)
+                                         q_pos, 48, **scales)
         else:
             attn = attention.ragged_verify(q, view["k"], view["v"], tables,
-                                           pos, impl=cfg.attention_impl,
-                                           **scales)
+                                           pos, **scales)
         x = x + quant.matmul(attn.reshape(*lead, cfg.num_heads * d),
                              lp["wo"])
         h_ffn = transformer.rms_norm(x, lp["ln2"], cfg.norm_eps)

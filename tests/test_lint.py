@@ -693,20 +693,20 @@ def test_jit_purity_pallas_variable_resolution_is_scoped():
     assert _lint(JitPurityChecker(), {ENGINE: src}).findings == []
 
 
-def test_jit_purity_covers_shipped_ragged_kernel_module():
-    """The real ops/ragged_attention.py kernels are in the checker's
+def test_jit_purity_covers_shipped_rows_kernel_module():
+    """The real ops/rows_attention.py kernel is in the checker's
     jit-root coverage: injecting a host impurity into a kernel body of
     the SHIPPED source must produce a finding (a module the checker
     cannot see would pass this by linting nothing)."""
     path = os.path.join(repo_root(),
-                        "distributed_llm_tpu/ops/ragged_attention.py")
+                        "distributed_llm_tpu/ops/rows_attention.py")
     with open(path, encoding="utf-8") as f:
         src = f.read()
-    marker = "m_ref[...] = jnp.full_like(m_ref, NEG_INF)"
-    assert marker in src, "kernel init marker moved — update this test"
+    marker = "live = live_blocks(b)"
+    assert marker in src, "kernel body marker moved — update this test"
     bad = "import time\n" + src.replace(
-        marker, "time.sleep(0.0)\n        " + marker, 1)
-    rel = "distributed_llm_tpu/ops/ragged_attention.py"
+        marker, "time.sleep(0.0)\n    " + marker, 1)
+    rel = "distributed_llm_tpu/ops/rows_attention.py"
     result = _lint(JitPurityChecker(), {rel: bad}, dedent=False)
     assert "jit-host-impurity" in _rules(result), result.findings
     # And the pristine module lints clean (no false findings from the

@@ -1,9 +1,9 @@
-"""Pallas attention kernels vs the portable XLA reference implementations.
+"""The Pallas flash prefill vs the portable XLA reference implementation.
 
 Runs the real kernel code in Pallas interpreter mode on CPU (the TPU
-compiles the same kernels), checking numerics, GQA head grouping, causal
-masking, the ragged decode length mask, gradients through the custom VJP,
-and an end-to-end engine generation on the pallas path.
+compiles the same kernel), checking numerics, GQA head grouping, causal
+masking, gradients through the custom VJP, and an end-to-end engine
+generation on the pallas path.
 """
 
 import jax
@@ -12,8 +12,7 @@ import numpy as np
 import pytest
 
 from distributed_llm_tpu.ops import attention
-from distributed_llm_tpu.ops.pallas_attention import (
-    flash_causal_attention, flash_chunk_attention, flash_decode_attention)
+from distributed_llm_tpu.ops.pallas_attention import flash_causal_attention
 
 
 def _rand(key, shape):
@@ -68,87 +67,6 @@ def test_flash_causal_grad_matches_xla():
                                    atol=2e-4, rtol=2e-4)
 
 
-@pytest.mark.parametrize("b,nq,nkv,d,s_max", [
-    (1, 4, 4, 16, 64),
-    (3, 8, 2, 32, 128),
-    (2, 16, 8, 64, 512),      # bench-tier serving geometry, 2 KV blocks
-])
-def test_flash_decode_matches_xla(b, nq, nkv, d, s_max):
-    ks = jax.random.split(jax.random.PRNGKey(3), 4)
-    q = _rand(ks[0], (b, nq, d))
-    k_cache = _rand(ks[1], (b, s_max, nkv, d))
-    v_cache = _rand(ks[2], (b, s_max, nkv, d))
-    # Ragged: each sequence at a different position.
-    pos = jax.random.randint(ks[3], (b,), 0, s_max)
-    got = flash_decode_attention(q, k_cache, v_cache, pos)
-    want = attention.decode_attention(q, k_cache, v_cache, pos)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               atol=2e-5, rtol=2e-5)
-
-
-def test_flash_decode_masks_future_cache_slots():
-    b, n, d, s_max = 1, 2, 16, 32
-    ks = jax.random.split(jax.random.PRNGKey(4), 3)
-    q = _rand(ks[0], (b, n, d))
-    k_cache = _rand(ks[1], (b, s_max, n, d))
-    v_cache = _rand(ks[2], (b, s_max, n, d))
-    pos = jnp.array([5])
-    base = flash_decode_attention(q, k_cache, v_cache, pos)
-    # Garbage beyond pos must be invisible.
-    k2 = k_cache.at[:, 6:].set(1e4)
-    v2 = v_cache.at[:, 6:].set(-1e4)
-    pert = flash_decode_attention(q, k2, v2, pos)
-    np.testing.assert_allclose(np.asarray(base), np.asarray(pert), atol=1e-6)
-
-
-@pytest.mark.parametrize("b,nq,nkv,d,bs,mb", [
-    (1, 4, 4, 16, 16, 4),
-    (3, 8, 2, 32, 32, 4),
-])
-def test_paged_decode_matches_xla_gather(b, nq, nkv, d, bs, mb):
-    """The in-kernel block-table walk must equal gather-then-attend, with
-    shuffled non-contiguous tables, trash rows past the allocation, and
-    ragged per-slot positions."""
-    nb = b * mb + 1                          # + trash block 0
-    ks = jax.random.split(jax.random.PRNGKey(5), 4)
-    q = _rand(ks[0], (b, nq, d))
-    k_pool = _rand(ks[1], (nkv, nb, bs, d))
-    v_pool = _rand(ks[2], (nkv, nb, bs, d))
-    # Slot tables: disjoint shuffled block ids; last row trash for slot 0.
-    perm = np.asarray(jax.random.permutation(ks[3], nb - 1) + 1)
-    tables = np.asarray(perm[:b * mb]).reshape(b, mb).astype(np.int32)
-    tables[0, -1] = 0                        # unallocated tail → trash block
-    pos = jnp.asarray([min((mb - 1) * bs - 2, 5 + 11 * i) for i in range(b)],
-                      jnp.int32)
-    got = attention.paged_decode(q, k_pool, v_pool, jnp.asarray(tables), pos,
-                                 impl="pallas")
-    want = attention.paged_decode(q, k_pool, v_pool, jnp.asarray(tables), pos,
-                                  impl="xla")
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               atol=2e-5, rtol=2e-5)
-
-
-def test_paged_decode_masks_past_pos():
-    """Garbage in cells beyond pos (and in trash-pointed blocks) must be
-    invisible."""
-    b, nq, nkv, d, bs, mb = 1, 2, 2, 16, 16, 3
-    nb = mb + 1
-    ks = jax.random.split(jax.random.PRNGKey(6), 3)
-    q = _rand(ks[0], (b, nq, d))
-    k_pool = _rand(ks[1], (nkv, nb, bs, d))
-    v_pool = _rand(ks[2], (nkv, nb, bs, d))
-    tables = jnp.asarray([[2, 1, 0]], jnp.int32)
-    pos = jnp.asarray([bs + 3], jnp.int32)   # mid second block
-    from distributed_llm_tpu.ops.pallas_attention import paged_decode_attention
-    base = paged_decode_attention(q, k_pool, v_pool, tables, pos)
-    # Garbage in the trash block and in cells past pos within the current
-    # block must be invisible (pos = bs+3 → block 1 cells > 3 are unwritten).
-    k2 = k_pool.at[:, 0].set(1e4).at[:, 1, 4:].set(1e4)
-    v2 = v_pool.at[:, 0].set(-1e4).at[:, 1, 4:].set(-1e4)
-    pert = paged_decode_attention(q, k2, v2, tables, pos)
-    np.testing.assert_allclose(np.asarray(base), np.asarray(pert), atol=1e-6)
-
-
 def test_resolve_impl(monkeypatch):
     assert attention.resolve_impl("xla") == "xla"
     assert attention.resolve_impl("pallas") == "pallas"
@@ -162,35 +80,6 @@ def test_resolve_impl(monkeypatch):
     monkeypatch.delenv("DLLM_ATTENTION")
     with pytest.raises(ValueError):
         attention.resolve_impl("flash")
-
-
-@pytest.mark.parametrize("b,s_c,w,nq,nkv,d", [
-    (1, 64, 128, 4, 4, 16),     # MHA, one kv block
-    (2, 64, 256, 4, 2, 32),     # GQA, multiple kv blocks
-    (1, 128, 256, 8, 2, 16),    # multiple q blocks too
-    (1, 5, 256, 4, 2, 16),      # γ+1-row verify chunk (speculative.py)
-    (1, 512, 512, 4, 2, 16),    # LARGE chunk: the wide transpose kernel
-    (1, 128, 512, 16, 8, 64),   # bench-tier serving geometry (native)
-])
-def test_flash_chunk_matches_xla(b, s_c, w, nq, nkv, d):
-    ks = jax.random.split(jax.random.PRNGKey(3), 3)
-    q = _rand(ks[0], (b, s_c, nq, d))
-    k = _rand(ks[1], (b, w, nkv, d))
-    v = _rand(ks[2], (b, w, nkv, d))
-    # suffix starting mid-window: query r sits at absolute position start+r
-    start = w - s_c - 5
-    pos = jnp.broadcast_to(start + jnp.arange(s_c)[None], (b, s_c))
-    got = flash_chunk_attention(q, k, v, pos)
-    want = attention.chunk_attention(q, k, v, pos)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               atol=2e-3, rtol=2e-3)
-
-
-def test_flash_chunk_rejects_non_divisible_window():
-    q = jnp.zeros((1, 64, 4, 16))
-    k = v = jnp.zeros((1, 192, 4, 16))
-    with pytest.raises(ValueError, match="not multiples"):
-        flash_chunk_attention(q, k, v, jnp.zeros((1, 64), jnp.int32))
 
 
 def test_flash_rejects_non_divisible_seq():
@@ -218,35 +107,10 @@ def test_engine_generates_identically_on_pallas_path(monkeypatch):
     assert r_xla.token_ids == r_pal.token_ids
 
 
-@pytest.mark.parametrize("s_c,w,nq,nkv,d,bs", [
-    (16, 32, 4, 2, 16, 16),      # tiny suffix, 2 window blocks
-    (128, 256, 8, 2, 32, 32),    # multiple q blocks, 8 window blocks
-])
-def test_paged_chunk_matches_xla_gather(s_c, w, nq, nkv, d, bs):
-    """In-kernel block-walk suffix prefill must equal gather-then-attend
-    over a shuffled block table."""
-    from distributed_llm_tpu.ops.pallas_attention import paged_chunk_attention
-
-    mb = w // bs + 2                         # table longer than the window
-    nb = mb + 1
-    ks = jax.random.split(jax.random.PRNGKey(7), 4)
-    q = _rand(ks[0], (1, s_c, nq, d))
-    k_pool = _rand(ks[1], (nkv, nb, bs, d))
-    v_pool = _rand(ks[2], (nkv, nb, bs, d))
-    table = jnp.asarray(np.random.default_rng(0).permutation(nb - 1)[:mb] + 1,
-                        jnp.int32)
-    start = jnp.asarray([w - s_c - 3], jnp.int32)   # suffix mid-window
-    got = paged_chunk_attention(q, k_pool, v_pool, table, start, w)
-    q_pos = start[:, None] + jnp.arange(s_c)[None]
-    want = attention.paged_chunk(q, k_pool, v_pool, table, start, q_pos, w,
-                                 impl="xla")
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               atol=2e-3, rtol=2e-3)
-
-
-def test_batched_engine_generates_identically_on_pallas_paged_path(monkeypatch):
-    """Greedy generation through the batching engine (paged decode +
-    chunked suffix prefill) must be token-identical across impls."""
+def test_batched_engine_generates_identically_on_pallas_path(monkeypatch):
+    """Greedy generation through the batching engine (the flash prefill,
+    paged decode + chunked suffix prefill) must be token-identical across
+    impls."""
     from distributed_llm_tpu.config import TierConfig
     from distributed_llm_tpu.engine.batching import ContinuousBatchingEngine
 
@@ -269,32 +133,3 @@ def test_batched_engine_generates_identically_on_pallas_paged_path(monkeypatch):
     assert outs["xla"] == outs["pallas"]
 
 
-@pytest.mark.parametrize("b,s_c,w,nq,nkv,d", [
-    (1, 64, 128, 4, 4, 16),
-    (2, 64, 256, 4, 2, 32),
-    (1, 512, 512, 4, 2, 16),    # LARGE chunk: the wide transpose kernel
-    (1, 128, 512, 16, 8, 64),   # bench-tier serving geometry (native)
-])
-def test_flash_chunk_q8_matches_xla_dequant(b, s_c, w, nq, nkv, d):
-    """int8-cache chunk kernel == XLA chunk over the dequantized view
-    (the suffix-prefill member of the q8 family)."""
-    from distributed_llm_tpu.ops.pallas_attention import \
-        flash_chunk_attention_q8
-    from distributed_llm_tpu.ops.quant import quantize_kv_rows
-
-    ks = jax.random.split(jax.random.PRNGKey(11), 3)
-    q = _rand(ks[0], (b, s_c, nq, d))
-    k = _rand(ks[1], (b, w, nkv, d))
-    v = _rand(ks[2], (b, w, nkv, d))
-    kq, ksc = quantize_kv_rows(k)
-    vq, vsc = quantize_kv_rows(v)
-    start = w - s_c - 3
-    pos = jnp.broadcast_to(start + jnp.arange(s_c)[None], (b, s_c))
-    got = flash_chunk_attention_q8(q, kq, vq, ksc.astype(jnp.float32),
-                                   vsc.astype(jnp.float32), pos)
-    want = attention.chunk(q, kq, vq, pos, impl="xla",
-                           k_scale=ksc.astype(jnp.float32),
-                           v_scale=vsc.astype(jnp.float32))
-    np.testing.assert_allclose(np.asarray(got, np.float32),
-                               np.asarray(want, np.float32),
-                               atol=3e-3, rtol=3e-3)

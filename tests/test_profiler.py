@@ -485,10 +485,39 @@ def _spin(seconds):
         sum(range(200_000))
 
 
-def test_a_phase_beside_a_spinning_thread_is_off_the_cpu():
+def test_a_phase_beside_a_spinning_thread_is_off_the_cpu(monkeypatch):
     """Wall minus CPU is the time the stamping thread stood in a phase
     without running: most of it when another thread holds the
-    interpreter for a long switch interval, about none when alone."""
+    interpreter for a long switch interval, none when alone.  The
+    arithmetic, with clocks the test moves (ROADMAP D14: timed live, six
+    test workers on the box decided the result); the live threads are
+    ``..._live`` below, under the ``slow`` mark."""
+    clock = _FakeClock()
+    monkeypatch.setattr(P, "time", clock)
+    beside = P.TickProfiler("t", capacity=16, cpu_every=1)
+    with beside.phase("emit"):
+        for _ in range(2):
+            clock.work(0.0001)      # asks for the interpreter back
+            clock.sleep(0.005)      # its own sleep
+            clock.sleep(0.1)        # the spinner's switch interval
+    beside.commit(1)
+    wall, cpu = beside.self_totals()["emit"], beside.cpu_totals()["emit"]
+    assert wall == pytest.approx(210.2) and cpu == pytest.approx(0.2)
+    assert wall - cpu >= 0.8 * wall
+    alone = P.TickProfiler("t", capacity=16, cpu_every=1)
+    with alone.phase("emit"):
+        clock.work(0.005)
+    alone.commit(1)
+    assert alone.self_totals()["emit"] == pytest.approx(5.0)
+    assert alone.cpu_totals()["emit"] == pytest.approx(5.0)
+    (rec,) = alone.records()
+    assert rec["cpu_ms"] == pytest.approx(5.0)
+
+
+@pytest.mark.slow
+def test_a_phase_beside_a_spinning_thread_is_off_the_cpu_live():
+    """The same with a real spinner and the real clocks: a timing, so
+    not tier 1's (a loaded box can lose every try)."""
     import sys
     import threading
     old = sys.getswitchinterval()

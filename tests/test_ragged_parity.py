@@ -1,15 +1,14 @@
 """Ragged paged decode (ISSUE 6): parity pins and engine rewire checks.
 
 The contract under test: the ragged fused decode tick — one
-``attention.ragged_decode`` call over every slot's FULL block-table row
+``attention.paged_decode`` call over every slot's FULL block-table row
 with true per-slot lengths — produces BYTE-IDENTICAL greedy output to
 the dense windowed path it replaces, across skewed lengths, at the
 ``decode_batch`` boundaries (1 slot / full occupancy), on the int8-KV
 pool, and through a mid-decode preemption + replay (the PR 5
-interaction).  Op-level tests pin the Pallas kernel (interpreter mode —
-the exact code Mosaic compiles) against the XLA gather reference, and
-the compile-churn tests pin the one-decode-program property that is the
-tentpole's point.
+interaction).  The op itself is held to a float64 reference in
+tests/test_attention_forms.py; the compile-churn tests pin the
+one-decode-program property that is the tentpole's point.
 """
 
 from __future__ import annotations
@@ -19,14 +18,11 @@ import threading
 import time
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from distributed_llm_tpu.config import tiny_batched_cluster
 from distributed_llm_tpu.engine.batching import ContinuousBatchingEngine
-from distributed_llm_tpu.ops import attention as A
-from distributed_llm_tpu.ops import ragged_attention as RA
 
 SHORT = "short question about rivers please"
 LONG = ("long question: " + "rivers lakes mountains oceans deltas " * 16)
@@ -53,77 +49,7 @@ def _generate_all(tier, prompts, seed=0):
         engine.stop()
 
 
-# -- op-level: kernel vs XLA gather reference --------------------------------
-
-def _pool_case(b=4, nq=8, nkv=4, d=16, bs=16, mb=8, dtype=jnp.float32):
-    key = jax.random.PRNGKey(0)
-    nb = b * mb + 1
-    q = jax.random.normal(key, (b, nq, d), dtype)
-    kp = jax.random.normal(key, (nkv, nb, bs, d), dtype)
-    vp = jax.random.normal(jax.random.PRNGKey(1), (nkv, nb, bs, d), dtype)
-    tables = jnp.asarray(
-        np.arange(1, b * mb + 1, dtype=np.int32).reshape(b, mb))
-    # Skewed per-slot lengths: 6, 38, 121, 127 of a 128-position span.
-    pos = jnp.asarray([5, 37, 120, 127][:b], jnp.int32)
-    return q, kp, vp, tables, pos
-
-
-def test_ragged_kernel_matches_xla_gather():
-    q, kp, vp, tables, pos = _pool_case()
-    want = A.ragged_decode(q, kp, vp, tables, pos, impl="xla")
-    got = RA.ragged_paged_decode_attention(q, kp, vp, tables, pos)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               rtol=2e-5, atol=2e-5)
-
-
-def test_ragged_kernel_q8_matches_xla_dequant():
-    from distributed_llm_tpu.ops.quant import quantize_kv_rows
-    q, kp, vp, tables, pos = _pool_case()
-    kq, ks = quantize_kv_rows(kp)
-    vq, vs = quantize_kv_rows(vp)
-    want = A.ragged_decode(q, kq, vq, tables, pos, impl="xla",
-                           k_scale=ks, v_scale=vs)
-    got = RA.ragged_paged_decode_attention_q8(q, kq, vq, ks, vs, tables,
-                                              pos)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               rtol=2e-5, atol=2e-5)
-
-
-def test_ragged_kernel_honors_per_slot_frontier():
-    """Blocks past a slot's own length contribute nothing — perturbing
-    them must not change that slot's output (the per-slot TRUE-length
-    contract that distinguishes ragged from a padded shared window)."""
-    q, kp, vp, tables, pos = _pool_case()
-    base = RA.ragged_paged_decode_attention(q, kp, vp, tables, pos)
-    bs = kp.shape[2]
-    # Slot 0 sits at position 5 (block 0): poison its table's later block.
-    beyond = tables[0, (int(pos[0]) // bs) + 1]
-    kp2 = kp.at[:, beyond].set(99.0)
-    vp2 = vp.at[:, beyond].set(-99.0)
-    pert = RA.ragged_paged_decode_attention(q, kp2, vp2, tables, pos)
-    np.testing.assert_array_equal(np.asarray(base[0]), np.asarray(pert[0]))
-
-
-def test_ragged_xla_fallback_matches_dense_paged():
-    """The XLA fallbacks of ragged_decode and paged_decode are ONE code
-    path (the byte-level parity reference): same inputs, same bytes."""
-    q, kp, vp, tables, pos = _pool_case()
-    np.testing.assert_array_equal(
-        np.asarray(A.ragged_decode(q, kp, vp, tables, pos, impl="xla")),
-        np.asarray(A.paged_decode(q, kp, vp, tables, pos, impl="xla")))
-
-
-# -- dispatch registry --------------------------------------------------------
-
-def test_ragged_kinds_registered_and_covered():
-    assert "ragged_decode" in A.DISPATCH_KINDS
-    assert "ragged_decode_q8" in A.DISPATCH_KINDS
-    import json
-    with open(A._DISPATCH_PATH) as f:
-        table = json.load(f)["dispatch"]
-    assert "ragged_decode" in table and "default" in table["ragged_decode"]
-    assert "ragged_decode_q8" in table
-
+# -- which tick ------------------------------------------------------------------
 
 def test_dllm_ragged_env_override(monkeypatch):
     monkeypatch.setenv("DLLM_RAGGED", "0")
@@ -174,7 +100,7 @@ def test_ragged_matches_dense_single_slot():
 
 
 def test_ragged_matches_dense_int8_kv():
-    """int8 pool boundary: ragged_decode_q8's XLA fallback dequantizes
+    """int8 pool boundary: the paged decode op dequantizes
     byte-identically to the dense paged path."""
     tier = _tier(kv_quantize="int8")
     prompts = [SHORT, LONG, SHORT + " more"]
@@ -253,7 +179,7 @@ def test_ragged_tick_reuses_cached_table_upload():
 
 def test_decode_tick_metrics_and_ring():
     """The tick ring fills, and the obs counter attributes ticks to the
-    ragged dispatch kind + the impl the measured table chose."""
+    fused tick's kind + what served its attention (here XLA)."""
     from distributed_llm_tpu.obs import get_observability
     m = get_observability().m
     eng = ContinuousBatchingEngine(_tier(), seed=0)
@@ -272,29 +198,21 @@ def test_decode_tick_metrics_and_ring():
         eng.stop()
 
 
-def test_ragged_request_gated_by_measured_verdict_on_tpu(monkeypatch):
-    """On TPU, attention_ragged=True only runs fused when the measured
-    table says 'pallas' for ragged_decode at the pool span — shipping
-    the full-span XLA gather against a measured 'xla' verdict would be
-    a silent hot-path regression.  DLLM_RAGGED=1 forces past the gate
-    (the A/B's own measurement runs need that)."""
+def test_ragged_request_is_the_windowed_tick_on_tpu_unless_forced(
+        monkeypatch):
+    """On a TPU backend attention_ragged=True keeps the windowed tick (the
+    fused tick's gather spans the whole table; never measured better on
+    the chip), whatever the engine's attention_impl or DLLM_ATTENTION;
+    DLLM_RAGGED=1 forces past the rule."""
     eng = ContinuousBatchingEngine(_tier(), seed=0)
     try:
+        assert eng._resolve_ragged() is True          # off the TPU: fused
         monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
         monkeypatch.delenv("DLLM_RAGGED", raising=False)
-        # TPU unsharded tiers resolve 'pallas'; the committed table's
-        # conservative 'xla' row must demote the fused tick...
         eng.cfg = dataclasses.replace(eng.cfg, attention_impl="pallas")
-        monkeypatch.setattr(A, "_DISPATCH_TABLE",
-                            {"ragged_decode": {"default": "xla"}})
         assert eng._resolve_ragged() is False
-        # ...a measured 'pallas' row flips it with no code change...
-        monkeypatch.setattr(A, "_DISPATCH_TABLE",
-                            {"ragged_decode": {"default": "pallas"}})
-        assert eng._resolve_ragged() is True
-        # ...and the forced override wins for measurement runs.
-        monkeypatch.setattr(A, "_DISPATCH_TABLE",
-                            {"ragged_decode": {"default": "xla"}})
+        monkeypatch.setenv("DLLM_ATTENTION", "pallas")
+        assert eng._resolve_ragged() is False
         monkeypatch.setenv("DLLM_RAGGED", "1")
         assert eng._resolve_ragged() is True
     finally:

@@ -788,13 +788,10 @@ def test_bench_cluster_reads_no_table_and_no_environment(steer, monkeypatch):
 
 
 def test_stats_measured_tables_names_only_what_is_read(client):
-    """GET /stats ``measured_tables``: the backend and the attention
-    dispatch table's provenance — the one measured table serving reads."""
-    tables = client.get("/stats").get_json()["measured_tables"]
-    assert tables["backend"] == "cpu"
-    assert "dispatch" in tables
-    assert all(k == "backend" or k.startswith("dispatch") for k in tables)
-    assert "tuning" not in tables
+    """GET /stats ``measured_tables``: the backend, and no table (serving
+    reads none since ISSUE 49)."""
+    assert client.get("/stats").get_json()["measured_tables"] == {
+        "backend": "cpu"}
 
 
 def test_server_main_says_what_it_runs_on_before_serving(monkeypatch,

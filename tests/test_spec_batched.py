@@ -83,33 +83,14 @@ def _verify_inputs(q8=False, g=5):
     return q, kq, vq, ksc, vsc, tables, pos
 
 
-def test_ragged_verify_kernel_matches_gather_fallback():
+@pytest.mark.parametrize("q8", [False, True], ids=["bf16", "int8"])
+def test_ragged_verify_g1_degenerates_to_decode(q8):
     from distributed_llm_tpu.ops import attention as A
-    from distributed_llm_tpu.ops import ragged_attention as RA
-    q, kp, vp, _, _, tables, pos = _verify_inputs()
-    ref = A._gather_verify_paged(q, kp, vp, tables, pos, None, None)
-    out = RA.ragged_paged_verify_attention(q, kp, vp, tables, pos)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                               rtol=2e-5, atol=2e-5)
-
-
-def test_ragged_verify_q8_kernel_matches_gather_fallback():
-    from distributed_llm_tpu.ops import attention as A
-    from distributed_llm_tpu.ops import ragged_attention as RA
-    q, kq, vq, ksc, vsc, tables, pos = _verify_inputs(q8=True)
-    ref = A._gather_verify_paged(q, kq, vq, tables, pos, ksc, vsc)
-    out = RA.ragged_paged_verify_attention_q8(q, kq, vq, ksc, vsc,
-                                              tables, pos)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                               rtol=2e-5, atol=2e-5)
-
-
-def test_ragged_verify_g1_degenerates_to_decode():
-    from distributed_llm_tpu.ops import attention as A
-    from distributed_llm_tpu.ops import ragged_attention as RA
-    q, kp, vp, _, _, tables, pos = _verify_inputs(g=1)
-    dec = A._decode_paged_fallback(q[:, 0], kp, vp, tables, pos, None, None)
-    ver = RA.ragged_paged_verify_attention(q, kp, vp, tables, pos)[:, 0]
+    q, kp, vp, ks, vs, tables, pos = _verify_inputs(g=1, q8=q8)
+    dec = A.paged_decode(q[:, 0], kp, vp, tables, pos, k_scale=ks,
+                         v_scale=vs)
+    ver = A.ragged_verify(q, kp, vp, tables, pos, k_scale=ks,
+                          v_scale=vs)[:, 0]
     np.testing.assert_allclose(np.asarray(ver), np.asarray(dec),
                                rtol=2e-5, atol=2e-5)
 
@@ -138,7 +119,7 @@ def test_verify_step_reproduces_sequential_greedy_decode():
     seq = []
     for _ in range(3):
         logits, pool_a = decode_step_paged(cfg, params, c, p, pool_a,
-                                           tables, ragged=True)
+                                           tables)
         c = jnp.argmax(logits, -1).astype(jnp.int32)
         p = p + 1
         seq.append(np.asarray(c))

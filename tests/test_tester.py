@@ -141,18 +141,6 @@ def test_stats_endpoint_exposes_phases_and_cache():
     assert len(d["devices"]) == 8
 
 
-def test_ab_kernels_smoke(capsys):
-    """The kernel A/B harness produces both impl rows and a verdict."""
-    from distributed_llm_tpu.bench import ab_kernels
-    ab_kernels.main(["--tier", "nano", "--prompt-tokens", "32",
-                     "--max-new", "4", "--repeat", "1"])
-    out = capsys.readouterr().out.strip().splitlines()
-    import json
-    rows = [json.loads(l) for l in out]
-    assert {r.get("impl") for r in rows[:2]} == {"xla", "pallas"}
-    assert "verdict" in rows[-1]
-
-
 def test_long_context_set_straddles_threshold_sweep():
     """The long_context query set exists to de-degenerate the reference's
     signature token-threshold sweep: its query+context

@@ -452,7 +452,7 @@ def test_the_key_stream_is_a_chain_of_plain_splits():
         p, c, kv = pos, cur, pool
         for _ in range(STEPS):
             logits, kv = decode_step_paged(engine.cfg, params, c, p, kv,
-                                           tables, ragged=engine.ragged)
+                                           tables)
             r, sub = jax.random.split(r)
             c = _sample_batched(logits, sub, temps)
             p = p + 1
@@ -515,18 +515,14 @@ def test_stats_name_the_attention_form_of_every_warmed_rung(monkeypatch,
     that walks the block table (``streamed``) where every query head has
     a K/V head of its own and the rows fill whole lanes."""
     from distributed_llm_tpu.config import MODEL_PRESETS
-    from distributed_llm_tpu.ops import attention as attn_ops
     from distributed_llm_tpu.utils.telemetry import engine_stats
     base = _tier()
     cfg = MODEL_PRESETS[base.model_preset]
     # A K/V head to every query head, rows of 128 lanes: what the rule
-    # asks of a window; and the committed table's verdict on the
-    # head-major kernels, which is measured for the chip only.
+    # asks of a window.
     monkeypatch.setitem(MODEL_PRESETS, "form_probe", dataclasses.replace(
         cfg, num_heads=4, num_kv_heads=4, hidden_size=128,
         attention_impl=impl))
-    monkeypatch.setattr(attn_ops, "_DISPATCH_TABLE",
-                        {"paged_decode": "xla", "ragged_decode": "xla"})
     monkeypatch.delenv("DLLM_ATTENTION", raising=False)
     tier = dataclasses.replace(base, name="form_probe",
                                model_preset="form_probe")

@@ -68,9 +68,9 @@ def test_policy_gates(monkeypatch):
 
 
 def test_tp_engine_with_pallas_prefill_matches_unsharded(monkeypatch):
-    """Full TP engine under forced Pallas: BOTH the shard-mapped flash
-    prefill and the shard-mapped flash decode hooks are live (decode is
-    in the compiled while_loop), and tokens must match unsharded."""
+    """Full TP engine under forced Pallas: the shard-mapped flash
+    prefill hook is live (decode stays on the GSPMD XLA path), and
+    tokens must match unsharded."""
     from distributed_llm_tpu.engine.inference import InferenceEngine
     monkeypatch.setenv("DLLM_ATTENTION", "pallas")
     plain = InferenceEngine(_tier(), seed=9)
@@ -81,31 +81,10 @@ def test_tp_engine_with_pallas_prefill_matches_unsharded(monkeypatch):
     assert a.token_ids == b.token_ids
 
 
-def test_tp_flash_decode_matches_xla():
-    from distributed_llm_tpu.ops.attention import decode_attention
-    from distributed_llm_tpu.parallel.tp_attention import tp_flash_decode
-    mesh = tp_mesh(jax.devices(), 4)
-    cfg = MODEL_PRESETS["orin_test"]
-    key = jax.random.PRNGKey(7)
-    q = jax.random.normal(key, (2, cfg.num_heads, cfg.head_dim),
-                          jnp.bfloat16)
-    kc = jax.random.normal(key, (2, 64, cfg.num_kv_heads, cfg.head_dim),
-                           jnp.bfloat16)
-    vc = jax.random.normal(jax.random.PRNGKey(8),
-                           (2, 64, cfg.num_kv_heads, cfg.head_dim),
-                           jnp.bfloat16)
-    pos = jnp.asarray([10, 63], jnp.int32)
-    got = jax.jit(tp_flash_decode(mesh))(q, kc, vc, pos)
-    want = decode_attention(q, kc, vc, pos)
-    np.testing.assert_allclose(np.asarray(got, np.float32),
-                               np.asarray(want, np.float32),
-                               atol=2e-2, rtol=2e-2)
-
-
-def test_tp_batched_int8_pool_pallas_decode_matches(monkeypatch):
-    """TP batching engine with int8 KV under forced Pallas takes the
-    shard-mapped q8 paged kernel and still matches the unsharded engine
-    (which takes the unsharded q8 kernel)."""
+def test_tp_batched_int8_pool_pallas_prefill_matches(monkeypatch):
+    """TP batching engine with int8 KV under forced Pallas (the
+    shard-mapped flash prefill; the fused tick's hook dequantizes its
+    shard's gathered rows) still matches the unsharded engine."""
     from distributed_llm_tpu.engine.batching import ContinuousBatchingEngine
     monkeypatch.setenv("DLLM_ATTENTION", "pallas")
     tier = _tier(decode_batch=2, max_new_tokens=6, kv_quantize="int8")

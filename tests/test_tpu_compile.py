@@ -37,11 +37,7 @@ import chip_smoke  # noqa: E402  (repo-root script: the kernel case table)
 from distributed_llm_tpu.config import (MODEL_PRESETS,  # noqa: E402
                                         flagship_cluster)
 
-KERNELS = ["flash_causal_attention", "flash_decode_attention",
-           "flash_chunk_attention", "paged_decode_attention",
-           "ragged_paged_decode_attention",
-           "ragged_paged_decode_attention_q8",
-           "ragged_paged_verify_attention",
+KERNELS = ["flash_causal_attention",
            # The served MHA tick's attention over the whole token-major
            # pool (a K/V head to every query head, whatever the preset's
            # own grouping: the kernel serves no other).
@@ -85,9 +81,8 @@ def compiled_kernels(monkeypatch):
     """Steer ``_interpret`` from the test: jax.default_backend() is still
     'cpu' here, and the kernels must lower for Mosaic, not the
     interpreter."""
-    from distributed_llm_tpu.ops import pallas_attention, ragged_attention
+    from distributed_llm_tpu.ops import pallas_attention
     monkeypatch.setattr(pallas_attention, "_interpret", lambda: False)
-    monkeypatch.setattr(ragged_attention, "_interpret", lambda: False)
 
 
 def _on(sharding, tree):
@@ -165,20 +160,16 @@ def _nano_tick(one_chip):
 @pytest.fixture
 def as_on_tpu(monkeypatch):
     """The engine asks jax.default_backend() which path to take; here
-    the answer is steered to the chip's (and the dispatch table, which
-    only steers its own backend, is re-read under it)."""
-    from distributed_llm_tpu.ops import attention
+    the answer is steered to the chip's."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    monkeypatch.setattr(attention, "_DISPATCH_TABLE", None)
-    monkeypatch.setattr(attention, "_DISPATCH_META", None)
     for var in ("DLLM_RAGGED", "DLLM_ATTENTION"):
         monkeypatch.delenv(var, raising=False)
 
 
 def test_default_nano_tick_on_tpu_is_dense_windowed_xla(one_chip, as_on_tpu):
-    """What the committed dispatch table makes of the chip's default
-    path: the dense windowed tick with the XLA gather — no kernel in the
-    program (chip_smoke.py's what-ran lines say the same of the attached
+    """The chip's default path at nano_1b's GQA widths: the dense
+    windowed tick with the XLA gather — no kernel in the program
+    (chip_smoke.py's what-ran lines say the same of the attached
     chip)."""
     engine, compiled = _nano_tick(one_chip)
     assert engine.ragged is False and engine.spec is False
@@ -186,16 +177,16 @@ def test_default_nano_tick_on_tpu_is_dense_windowed_xla(one_chip, as_on_tpu):
     assert "tpu_custom_call" not in compiled.as_text()
 
 
-def test_ragged_pallas_nano_tick_compiles_for_v5e(one_chip, as_on_tpu,
-                                                  monkeypatch):
-    """The tick PRs 6 and 14 built — fused ragged decode on the Pallas
-    kernel — which a re-measured dispatch row would switch on: forced
-    here, it lowers with the kernel inside."""
+def test_forced_fused_nano_tick_compiles_for_v5e(one_chip, as_on_tpu,
+                                                 monkeypatch):
+    """The fused tick, which the chip does not take by itself (ROADMAP
+    D3): forced, it lowers for the chip, and over GQA's narrow rows it
+    attends in the XLA form like the windowed one."""
     monkeypatch.setenv("DLLM_RAGGED", "1")
-    monkeypatch.setenv("DLLM_ATTENTION", "pallas")
     engine, compiled = _nano_tick(one_chip)
     assert engine.ragged is True
-    assert compiled.as_text().count("tpu_custom_call") == 1
+    assert engine.decode_attention_form() == "merged"
+    assert "tpu_custom_call" not in compiled.as_text()
 
 
 def test_the_chunk_scan_compiles_at_the_longest_chunk_it_serves(
@@ -260,11 +251,7 @@ GB = 1e9
 # holds them gone).  ``streamed`` (ISSUE 45): a K/V head to
 # every query head, the block table walked by the kernel of
 # ops/rows_attention.py; ``merged``: GQA, whose narrow rows the kernel
-# loses on (``rows_attention.serves``), keeps the XLA gather.  The HOOKED
-# path — ``split``: the fused ragged tick on its Pallas kernel, which gets
-# a layer's head-major view from ``ops.attention._layer_views`` — is held
-# to what the commit before PR 27 compiled to (5.073 and 1.213 GB).  No
-# cell runs a hooked tier: these two cases are all that holds it.
+# loses on (``rows_attention.serves``), keeps the XLA gather.
 POOL_PROGRAMS = {
     "smollm2-decode-256":
         (_bench_tier, ("decode", 256), 0.01, "streamed"),
@@ -286,11 +273,6 @@ POOL_PROGRAMS = {
     "orin_bench-d128-decode-256":
         (lambda _: _flagship_nano("orin_bench"), ("decode", 256), 0.01,
          "merged"),
-    "nano_1b-gqa-ragged-pallas":
-        (lambda _: _flagship_nano("nano_1b"), ("decode", 0), 5.073, "split"),
-    "orin_bench-d128-ragged-pallas":
-        (lambda _: _flagship_nano("orin_bench"), ("decode", 0), 1.213,
-         "split"),
 }
 
 
@@ -341,18 +323,14 @@ def test_pool_program_leaves_the_pool_in_place(one_chip, as_on_tpu,
     block table, handed the pool WHOLE, and nothing window-sized is
     produced inside a fusion either."""
     make_tier, program, temp_limit_gb, form = POOL_PROGRAMS[case]
-    ragged = form == "split"
-    if ragged:
-        monkeypatch.setenv("DLLM_RAGGED", "1")
-        monkeypatch.setenv("DLLM_ATTENTION", "pallas")
     engine, pool, compiled, pool_arg = _pool_program(
         one_chip, make_tier(monkeypatch), program)
-    assert engine.ragged is ragged
+    assert engine.ragged is False
     text = compiled.as_text()
-    assert text.count("tpu_custom_call") == int(form in ("split", "streamed"))
+    assert text.count("tpu_custom_call") == int(form == "streamed")
     assert ("paged_rows_decode" in text) is (form == "streamed")
     if form is not None:
-        assert engine.decode_attention_form(program[1] or None) == form
+        assert engine.decode_attention_form(program[1]) == form
     facts = chip_smoke.pool_program_facts(compiled, pool_arg, pool)
     pool_bytes = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(pool))
     assert facts["formats_match"], facts
