@@ -199,8 +199,11 @@ class ModelConfig:
     # One character a layer, ``num_layers`` of them: "M" a state-space
     # mixer (Mamba-1 where ``ssm_dt_rank`` > 0, with an RMSNorm on each
     # of delta, B and C; else Mamba-2), "*" attention, "E" routed
-    # experts, "-" a dense gated MLP of ``ffn_size``; EACH layer is ONE
-    # pre-norm mixer (a mixer-then-MLP layer is two characters, "M-").
+    # experts, "-" a dense gated MLP of ``ffn_size``, "C" compressed
+    # convolutional attention (paged K/V AND a tail row a slot: the last
+    # inputs of its two convolutions and of its shifted value); EACH layer
+    # is ONE pre-norm mixer (a mixer-then-MLP layer is two characters,
+    # "M-"; attention then experts "CE").
     # The layer loop scans the pattern's shortest repeating period
     # (``layer_period``).  The head is tied where ``tie_embeddings``.
     layer_pattern: str = ""
@@ -218,9 +221,11 @@ class ModelConfig:
     ssm_dt_min: float = 0.001
     ssm_dt_max: float = 0.1
     ssm_dt_floor: float = 1e-4
-    # Attention heads of a width of their own (0: hidden / heads) and,
-    # for the hybrid family, no rotary embedding (position comes from the
-    # state-space layers).
+    # Attention heads of a width of their own (0: hidden / heads).  The
+    # hybrid family's "*" applies no rotary embedding (position comes from
+    # the state-space layers: ``rotary`` False); its "C" rotates the first
+    # ``qk_rope_head_dim`` numbers of each head (0: the whole head) by
+    # ``rope_theta`` (``rotary`` True).
     attn_head_dim: int = 0
     rotary: bool = True
     # The hybrid family's experts: the router scores ``num_experts``
@@ -228,12 +233,19 @@ class ModelConfig:
     # ``experts_first`` on (0: all) and computes their part of a layer's
     # result — what the absent ones would add is left out (the other
     # rank of an expert-parallel pair adds it; nothing here stands in for
-    # that rank).  "relu2": ``W_2 relu(W_1 x)^2``, no gate.  The shared
-    # expert is ``shared_ffn_size`` wide (0: none).
+    # that rank).  "relu2": ``W_2 relu(W_1 x)^2``, no gate; "swiglu":
+    # ``W_down(silu(W_gate x) * W_up x)``, three matrices.  The shared
+    # expert is ``shared_ffn_size`` wide (0: none).  ``router_hidden`` > 0
+    # makes the router an MLP of that width over a state it carries
+    # through the depth (down-projection + gain x the layer before's,
+    # RMSNorm, two GELU layers, the outputs), softmax scores, the chosen
+    # weighed by their probabilities as they are; 0 the sigmoid router of
+    # one matrix, the chosen renormalised and scaled.
     experts_first: int = 0
     experts_count: int = 0
     expert_act: str = "swiglu"
     shared_ffn_size: int = 0
+    router_hidden: int = 0
     # -- The state-space / window-attention / shared-K/V family
     # (models/shared_kv_hybrid.py; an "F" in ``layer_pattern`` selects
     # it).  Its kinds: "M" a MAMBA-1 mixer (``ssm_dt_rank`` > 0: decay a
@@ -263,7 +275,8 @@ class ModelConfig:
         (models/shared_kv_hybrid.py: an "F" in ``layer_pattern``),
         "hybrid" (models/hybrid_ssm.py: any other ``layer_pattern``, of
         Mamba-2 or Mamba-1 rows beside paged attention layers that each
-        own their K/V, experts or dense MLPs), else "dense"
+        own their K/V, or attention layers that own a tail row besides,
+        experts or dense MLPs), else "dense"
         (transformer.py, moe.py).  What differs by family
         dispatches on this one name."""
         if self.kv_lora_rank > 0:
@@ -325,11 +338,22 @@ class ModelConfig:
     @property
     def kv_layers(self) -> int:
         """Layers whose K/V the paged pool holds by position: every layer,
-        or a hybrid family's attention layers ("*"; the shared-K/V
-        family's ONE "F")."""
+        or the kinds of a row family that own K/V: the hybrid family's
+        attention layers ("*", and "C", which owns a tail row a slot
+        besides), the shared-K/V family's ONE "F" (its "W" keeps a ring a
+        slot and its "X" reads "F"'s)."""
         if self.hybrid:
-            return self.layers_of("*") + self.layers_of("F")
+            return sum(self.layers_of(kind) for kind in "*CF")
         return self.num_layers
+
+    @property
+    def cca_tail_width(self) -> int:
+        """Numbers a sequence keeps a "C" layer whatever its length: the
+        last token's input to each of the two convolutions (query and
+        K/V heads side by side) and the value the shifted half of the
+        K/V heads takes from it."""
+        heads = self.num_heads + self.num_kv_heads
+        return (2 * heads + self.num_kv_heads // 2) * self.head_dim
 
     @property
     def experts_held(self) -> int:
@@ -446,6 +470,17 @@ MODEL_PRESETS: Dict[str, ModelConfig] = {
         ffn_size=96, max_seq_len=256, rotary=False, norm_eps=1e-6,
         layer_pattern="M-M-*-M-" * 2, ssm_heads=128, ssm_head_dim=1,
         ssm_state=8, ssm_conv=4, ssm_dt_rank=4,
+    ),
+    # The hybrid family's third pattern at unit-test size: compressed
+    # convolutional attention (4 query / 2 K/V heads, rotary on half a
+    # head, a tail row a slot) then top-1 of 4 gated experts under the
+    # MLP router with its carry; three periods of "CE", a tied head.
+    "hybrid_cca_test": ModelConfig(
+        name="hybrid_cca_test", tokenizer="byte", vocab_size=512,
+        hidden_size=64, num_layers=6, num_heads=4, num_kv_heads=2,
+        attn_head_dim=16, max_seq_len=256, rotary=True, qk_rope_head_dim=8,
+        layer_pattern="CE" * 3, num_experts=4, experts_per_token=1,
+        moe_ffn_size=32, router_hidden=16, expert_act="swiglu",
     ),
     # The state-space / window-attention / shared-K/V family at unit-test
     # size (models/shared_kv_hybrid.py): 3 x "MW", "M", "F", 2 x "GX";

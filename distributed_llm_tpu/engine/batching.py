@@ -1538,15 +1538,19 @@ class ContinuousBatchingEngine:
 
     def state_stats(self) -> Optional[Dict[str, int]]:
         """The recurrent rows (GET /stats ``state``), or None for a model
-        without them: the kind of mixer and its layers, how many rows
-        there are, how many name a sequence, what one holds, how many
-        sequences started one from zero, and the K/V beside them."""
+        without them: the kind of mixer that owns them (``cca_tail``: the
+        "C" attention layers' tail rows, with no state) and its layers,
+        how many rows there are, how many name a sequence, what one
+        holds, how many sequences started one from zero, and the K/V
+        beside them."""
         if self._state_owner is None:
             return None
         from ..utils.roofline import (kv_bytes_per_pos, ring_row_bytes,
                                       state_row_bytes)
-        out = {"mixer": "mamba1" if self.cfg.ssm_dt_rank else "mamba2",
-               "layers": self.cfg.layers_of("M"),
+        tails = self.cfg.layers_of("C")
+        out = {"mixer": ("cca_tail" if tails else
+                         "mamba1" if self.cfg.ssm_dt_rank else "mamba2"),
+               "layers": tails or self.cfg.layers_of("M"),
                "rows": int(self._state_owner.size),
                "rows_in_use": int(np.count_nonzero(self._rows_owned())),
                "row_bytes": int(state_row_bytes(self.cfg)),

@@ -47,6 +47,7 @@ functions hand it the cells to write and the tables to read, and the
 block programs (``copy_block``, ``gather_blocks``, ``scatter_blocks``)
 work on whatever arrays the pool has.  The hybrid family's
 (models/hybrid_ssm.py) pool holds K/V blocks for its ATTENTION layers
+("*", and "C", whose tail row a slot is ``"t"`` with ``"s"`` empty)
 only and, beside them, one recurrent row a slot a state-space layer
 (``"s"``, ``"t"``) with the vector that says whose each row is
 (``"owner"``): rows are not blocks, so the block programs refuse that
@@ -129,14 +130,19 @@ def init_pool(cfg: ModelConfig, pcfg: PagedConfig,
                 f"rows are not wired to its attention layers; use 'none'")
         dtype = jnp.dtype(cfg.dtype)
         # K/V blocks for the layers that own K/V by position (the hybrid
-        # family's "*" layers, the shared-K/V family's ONE "F"), and a
-        # ROW a slot a state-space layer: the float32 state — Mamba-1's
-        # [state, inner] (channels on the lanes), Mamba-2's [heads, P,
-        # N] — and the conv tail in the model's dtype.  "k" first:
-        # ``_block_size`` reads the first array.
-        n_m, r = cfg.layers_of("M"), pcfg.max_slots
+        # family's "*" and "C" layers, the shared-K/V family's ONE "F"),
+        # and a ROW a slot a layer that keeps one: a state-space layer's
+        # float32 state — Mamba-1's [state, inner] (channels on the
+        # lanes), Mamba-2's [heads, P, N] — and its conv tail in the
+        # model's dtype; a "C" layer's tail alone (one row of the last
+        # token's inputs to its convolutions and its shifted value), the
+        # state then empty.  "k" first: ``_block_size`` reads the first
+        # array.
+        n_m, n_c, r = cfg.layers_of("M"), cfg.layers_of("C"), pcfg.max_slots
         state = ((cfg.ssm_state, cfg.ssm_inner) if cfg.ssm_dt_rank else
                  (cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state))
+        tail = ((n_c, r, 1, cfg.cca_tail_width) if n_c else
+                (n_m, r, cfg.ssm_conv - 1, cfg.ssm_conv_width))
         kv = (cfg.kv_layers,) + shape[1:]
         pool = {"k": jnp.zeros(kv, dtype), "v": jnp.zeros(kv, dtype)}
         if cfg.shared_kv:
@@ -146,8 +152,7 @@ def init_pool(cfg: ModelConfig, pcfg: PagedConfig,
                     cfg.cache_row_width)
             pool.update(rk=jnp.zeros(ring, dtype), rv=jnp.zeros(ring, dtype))
         pool.update(s=jnp.zeros((n_m, r) + state, jnp.float32),
-                    t=jnp.zeros((n_m, r, cfg.ssm_conv - 1,
-                                 cfg.ssm_conv_width), dtype),
+                    t=jnp.zeros(tail, dtype),
                     owner=jnp.zeros((r,), jnp.int32))
         return pool
     if kv_quantize == "int8":

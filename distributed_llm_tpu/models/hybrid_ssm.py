@@ -34,9 +34,31 @@ copy of the Mamba-1 mixer (``mamba1`` and what it calls).
   (its rank of the expert-parallel pair adds it), in the plain reference
   alike.  Experts are non-gated, ``W_2 relu(W_1 x)^2``; one shared expert
   of ``shared_ffn_size`` adds for every token.
+  Where ``cfg.router_hidden`` the router is an MLP over a state it
+  CARRIES through the depth instead (``route_mlp``: a second carry of the
+  layer loop beside the residual), softmax scores, the chosen weighed by
+  their probabilities as they are; where ``cfg.expert_act`` is ``swiglu``
+  the experts are gated, three matrices.
 - ``-``, a **dense gated MLP** of ``ffn_size``, ``W_down(silu(W_gate x) *
   (W_up x))`` (``transformer._swiglu``, the dense family's): a layer that
   is a mixer and then an MLP is two characters, ``M-`` or ``*-``.
+- ``C``, **compressed convolutional attention** (``_cca``): queries and
+  keys are projected once into the heads' own widths, ``u = [q~ | k~]``,
+  and everything after lives there — two causal convolutions of two taps
+  in turn over ``u`` (depthwise, then grouped by head), the mean of each
+  query latent and its K/V group's key latent added back, both
+  normalised a head to ``sqrt(head_dim)`` (the keys times a learned
+  temperature a K/V head), rotary on the first ``qk_rope_head_dim``
+  numbers of a head; the first half of the K/V heads take their values
+  from this token and the second half from the token before.  ``k`` (as
+  it is attended) and ``v`` go to the paged pool like ``*``'s; what the
+  NEXT token needs of this one — the two convolutions' inputs and the
+  shifted value — is a ROW of ``pool["t"]`` a slot, as a state-space
+  layer's conv tail is, with no state ``pool["s"]`` at all.
+
+A pattern with ``C`` scales the residual's merge in every sublayer, ``x
+<- (a_r x + b_r) + (a_o mixer(..) + b_o)``, four vectors a sublayer
+(``lp["res"]``); the others add.
 
 The head is the tree's own ``"head"``, or the embedding where
 ``cfg.tie_embeddings``.
@@ -71,12 +93,14 @@ import numpy as np
 from ..config import ModelConfig
 from ..ops import attention, pallas_attention, quant, ssm_chunk_scan
 from . import latent_moe, transformer
-from .latent_moe import (EMBED_STD, HIGHEST, ROUTER_BIAS_STD, init_normal,
-                         init_table)
+from .latent_moe import (EMBED_STD, HIGHEST, ROUTER_BIAS_STD, WEIGHT_STD,
+                         init_normal, init_table)
 
 Params = Dict[str, Any]
-KINDS = "M*E-"
-EXPERT_KEYS = ("we_up", "we_down")
+KINDS = "M*E-C"
+# The routed experts' matrices as a tree may hold them: all three where
+# the experts are gated, the last two where not.
+EXPERT_KEYS = ("we_gate", "we_up", "we_down")
 LANES = 128
 
 
@@ -111,8 +135,8 @@ def expert_dims_stored(cfg: ModelConfig):
 def expert_stacks(params: Params):
     """The routed experts' arrays as the tree holds them: those of the
     period's first ``E`` position (every position's are alike)."""
-    first = next(lp for lp in params["periods"] if EXPERT_KEYS[0] in lp)
-    return [first[key] for key in EXPERT_KEYS]
+    first = next(lp for lp in params["periods"] if EXPERT_KEYS[-1] in lp)
+    return [first[key] for key in EXPERT_KEYS if key in first]
 
 
 def check(cfg: ModelConfig) -> None:
@@ -129,18 +153,39 @@ def check(cfg: ModelConfig) -> None:
     elif cfg.ssm_heads % cfg.ssm_groups:
         raise ValueError(f"{cfg.name}: ssm_heads {cfg.ssm_heads} is not a "
                          f"multiple of ssm_groups {cfg.ssm_groups}")
-    if cfg.rotary:
-        raise ValueError(f"{cfg.name}: the hybrid family is written for "
-                         f"no rotary embedding")
+    # What each attention kind needs of the positional term: "*" applies
+    # none (the state-space layers beside it carry position), "C" rotates
+    # part of every head.
+    if "C" in cfg.layer_pattern:
+        rot = cfg.qk_rope_head_dim or cfg.head_dim
+        if (not cfg.rotary or rot % 2 or rot > cfg.head_dim
+                or cfg.num_kv_heads % 2
+                or cfg.num_heads % cfg.num_kv_heads):
+            raise ValueError(
+                f"{cfg.name}: a pattern with 'C' states rotary True over "
+                f"an even qk_rope_head_dim <= head_dim {cfg.head_dim} "
+                f"(got {rot}), and an even number of K/V heads (half take "
+                f"the shifted value) that divides the query heads")
+        if set(cfg.layer_pattern) & set("M*"):
+            raise ValueError(
+                f"{cfg.name}: the pool's K/V layers and its tail rows are "
+                f"indexed by the ONE kind that owns them: a pattern with "
+                f"'C' has no 'M' and no '*'")
+    elif "*" in cfg.layer_pattern and cfg.rotary:
+        raise ValueError(f"{cfg.name}: a pattern with '*' states rotary "
+                         f"False: that kind applies no rotary embedding")
     if "E" not in cfg.layer_pattern:
         return
     if not 0 <= cfg.experts_first <= cfg.num_experts - cfg.experts_held:
         raise ValueError(
             f"{cfg.name}: experts {cfg.experts_first}..+{cfg.experts_held} "
             f"are not among the router's {cfg.num_experts}")
-    if cfg.expert_act != "relu2":
-        raise ValueError(f"{cfg.name}: the hybrid family's experts are "
-                         f"written for relu2")
+    # What each expert form needs: "relu2" two matrices an expert, no
+    # gate; "swiglu" three.
+    if cfg.expert_act not in ("relu2", "swiglu"):
+        raise ValueError(f"{cfg.name}: expert_act {cfg.expert_act!r}; the "
+                         f"hybrid family's experts are 'relu2' (up, down) "
+                         f"or 'swiglu' (gate, up, down)")
 
 
 def kind_index(cfg: ModelConfig, kind: str):
@@ -169,6 +214,15 @@ def init_dt_bias(cfg: ModelConfig, key, width: int):
                  + np.log(cfg.ssm_dt_min))
     dt = jnp.maximum(dt, cfg.ssm_dt_floor)
     return dt + jnp.log(-jnp.expm1(-dt))
+
+
+# What a pattern with "C" draws non-trivially from the seed, so that a
+# dropped term moves the logits: the keys' temperature, the router's
+# carry, the merge's gains and offsets.  Its embedding is drawn like
+# every matrix (the table is the head too).
+TAU_MEAN, TAU_STD = 3.0, 0.25
+ROUTER_CARRY_MEAN, ROUTER_CARRY_STD = 0.5, 0.1
+RES_GAIN_STD, RES_BIAS_STD = 0.1, 0.02
 
 
 def init_layer(cfg: ModelConfig, key, kind: str) -> Params:
@@ -209,10 +263,31 @@ def init_layer(cfg: ModelConfig, key, kind: str) -> Params:
                   wk=init_normal(ks[1], (h, nkv * d), dtype),
                   wv=init_normal(ks[2], (h, nkv * d), dtype),
                   wo=init_normal(ks[3], (nq * d, h), dtype))
+    elif kind == "C":
+        d, nq, nkv = cfg.head_dim, cfg.num_heads, cfg.num_kv_heads
+        c = (nq + nkv) * d
+        k_a, k_ab, k_b, k_bb = jax.random.split(ks[4], 4)
+        # Two taps each: tap 0 meets the token before, tap 1 this one.
+        # The framework's default conv init: uniform in +-1/sqrt(fan-in),
+        # 2 for the depthwise conv, 2 x head_dim for the one grouped by
+        # head.
+        lp.update(wq=init_normal(ks[0], (h, nq * d), dtype),
+                  wk=init_normal(ks[1], (h, nkv * d), dtype),
+                  # [this token's half of the K/V heads | the shifted half]
+                  wv=init_normal(ks[2], (h, nkv * d), dtype),
+                  wo=init_normal(ks[3], (nq * d, h), dtype),
+                  conv0_w=init_uniform(k_a, (2, c), dtype, 2 ** -0.5),
+                  conv0_b=init_uniform(k_ab, (c,), dtype, 2 ** -0.5),
+                  conv1_w=init_uniform(k_b, (2, nq + nkv, d, d), dtype,
+                                       (2 * d) ** -0.5),
+                  conv1_b=init_uniform(k_bb, (c,), dtype, (2 * d) ** -0.5),
+                  tau=TAU_MEAN + TAU_STD * jax.random.normal(
+                      ks[5], (nkv,), jnp.float32))
     else:
         f, e = cfg.moe_ffn_size, cfg.num_experts
         held = slice(cfg.experts_first, cfg.experts_first + cfg.experts_held)
         h_st, f_st = expert_dims_stored(cfg)
+        k_gate, k_router = jax.random.split(ks[6])
 
         def experts(key, shape, stored):
             # A key a ROUTER OUTPUT, the held ones taken: an expert's
@@ -224,15 +299,41 @@ def init_layer(cfg: ModelConfig, key, kind: str) -> Params:
                 lambda k: jnp.pad(init_normal(k, shape, dtype), pad),
                 jax.random.split(key, e)[held])
 
-        lp.update(router=init_normal(ks[0], (h, e), dtype),
-                  router_bias=ROUTER_BIAS_STD * jax.random.normal(
+        lp.update(router_bias=ROUTER_BIAS_STD * jax.random.normal(
                       ks[1], (e,), jnp.float32),
                   we_up=experts(ks[2], (h, f), (h_st, f_st)),
                   we_down=experts(ks[3], (f, h), (f_st, h_st)))
+        if cfg.expert_act == "swiglu":
+            lp.update(we_gate=experts(k_gate, (h, f), (h_st, f_st)))
+        if cfg.router_hidden:
+            # The MLP router: the down-projection like any matrix; the
+            # MLP's own at unit gain (1/sqrt(fan-in)), so that its scores
+            # spread over the experts with the token and the choice-only
+            # bias moves a choice by a hair; the carry's gain drawn AWAY
+            # from 0 and 1.
+            rh = cfg.router_hidden
+            k_c, k_1, k_2, k_3 = jax.random.split(k_router, 4)
+            lp.update(
+                router=init_normal(ks[0], (h, rh), dtype),
+                router_carry=ROUTER_CARRY_MEAN + ROUTER_CARRY_STD
+                * jax.random.normal(k_c, (rh,), jnp.float32),
+                router_ln=jnp.ones((rh,), dtype),
+                router_w1=init_normal(k_1, (rh, rh), dtype, rh ** -0.5),
+                router_w2=init_normal(k_2, (rh, rh), dtype, rh ** -0.5),
+                router_w3=init_normal(k_3, (rh, e), dtype, rh ** -0.5))
+        else:
+            lp.update(router=init_normal(ks[0], (h, e), dtype))
         if cfg.shared_ffn_size:
             fs = cfg.shared_ffn_size
             lp.update(ws_up=init_normal(ks[4], (h, fs), dtype),
                       ws_down=init_normal(ks[5], (fs, h), dtype))
+    if "C" in cfg.layer_pattern:
+        # The scaled merge of every sublayer of such a pattern: (a_r, b_r,
+        # a_o, b_o), the gains drawn about 1 and the offsets about 0.
+        draw = jax.random.normal(ks[7], (4, h), jnp.float32)
+        lp.update(res=jnp.array([1.0, 0.0, 1.0, 0.0])[:, None]
+                  + jnp.array([RES_GAIN_STD, RES_BIAS_STD] * 2)[:, None]
+                  * draw)
     return lp
 
 
@@ -248,7 +349,8 @@ def init_params(cfg: ModelConfig, seed=0) -> Params:
     period = cfg.layer_period
     params = {
         "embed": init_table(k_embed, cfg.vocab_size, cfg.hidden_size, dtype,
-                            EMBED_STD),
+                            WEIGHT_STD if "C" in cfg.layer_pattern
+                            else EMBED_STD),
         "final_ln": jnp.ones((cfg.hidden_size,), dtype),
         "periods": [jax.lax.map(lambda k, c=kind: init_layer(cfg, k, c),
                                 lkeys[j::len(period)])
@@ -589,14 +691,11 @@ def _mamba(cfg: ModelConfig, lp: Params, h_in, pool, li, ctx):
     return quant.matmul(_gate_norm(cfg, lp, y, z), lp["w_out"]), pool
 
 
-def _attention(cfg: ModelConfig, lp: Params, h_in, pool, li, ctx):
-    """h_in [B, S, H] -> (mixer output, pool); ``li`` the layer's index
-    among the attention layers, which are the K/V pool's layers."""
-    b, s, _ = h_in.shape
-    d = cfg.head_dim
-    q = quant.matmul(h_in, lp["wq"]).reshape(b, s, cfg.num_heads, d)
-    k = quant.matmul(h_in, lp["wk"])
-    v = quant.matmul(h_in, lp["wv"])
+def _attend(cfg: ModelConfig, lp: Params, q, k, v, pool, li, ctx):
+    """q [B, S, N_q, D], k and v [B, S, N_kv * D] as they are cached ->
+    (mixer output, pool): the rows written at ``blk, off`` of K/V layer
+    ``li``, the table's window attended, ``W_o``."""
+    b, s = q.shape[:2]
     blk, off = ctx["blk"], ctx["off"]
     with jax.named_scope("kv_write"):
         k_p = pool["k"].at[li, blk, off].set(k)
@@ -610,8 +709,115 @@ def _attention(cfg: ModelConfig, lp: Params, h_in, pool, li, ctx):
             out = attention.paged_decode(
                 q[:, 0], k_p, v_p, ctx["tables"], ctx["pos"],
                 impl=cfg.attention_impl, layer=li)
-    out = quant.matmul(out.reshape(b, s, cfg.num_heads * d), lp["wo"])
+    out = quant.matmul(out.reshape(b, s, -1), lp["wo"])
     return out, {**pool, "k": k_p, "v": v_p}
+
+
+def _attention(cfg: ModelConfig, lp: Params, h_in, pool, li, ctx):
+    """h_in [B, S, H] -> (mixer output, pool); ``li`` the layer's index
+    among the attention layers, which are the K/V pool's layers."""
+    b, s, _ = h_in.shape
+    q = quant.matmul(h_in, lp["wq"]).reshape(b, s, cfg.num_heads,
+                                             cfg.head_dim)
+    return _attend(cfg, lp, q, quant.matmul(h_in, lp["wk"]),
+                   quant.matmul(h_in, lp["wv"]), pool, li, ctx)
+
+
+def cca_conv(cfg: ModelConfig, lp: Params, u, v2, tail):
+    """The two convolutions and the value shift over ``S`` positions in
+    order that follow the position ``tail`` describes: u [N, S, C] the
+    latents ``[q~ | k~]``, v2 [N, S, Dv] the values the shifted K/V heads
+    will take, tail [N, cca_tail_width] = the position before's ``[u | c
+    | v2]`` (zeros before a sequence's first).  Returns (d [N, S, C]
+    float32, the shifted values [N, S, Dv], every position's own ``[u | c
+    | v2]`` with the tail's in front [N, S + 1, cca_tail_width]: what
+    the next call is handed is a row of it).  ``c`` is rounded to the
+    model's dtype BEFORE the second convolution reads it, so what the
+    tail keeps at rest is what an unbroken pass would have read: a
+    sequence cut into chunks and steps anywhere gives the same numbers."""
+    n, s, c_w = u.shape
+    d = cfg.head_dim
+    f32 = jnp.float32
+    seq_u = jnp.concatenate([tail[:, None, :c_w], u], axis=1)
+    w0 = lp["conv0_w"].astype(f32)
+    c = (seq_u[:, :-1].astype(f32) * w0[0] + seq_u[:, 1:].astype(f32) * w0[1]
+         + lp["conv0_b"].astype(f32)).astype(u.dtype)
+    seq_c = jnp.concatenate([tail[:, None, c_w:2 * c_w], c], axis=1)
+    by_head = seq_c.reshape(n, s + 1, c_w // d, d)
+    # Float32 operands at the default precision: on the chip one bfloat16
+    # pass accumulated in float32, exact for operands that ARE bfloat16.
+    by_head, w1 = by_head.astype(f32), lp["conv1_w"].astype(f32)
+    out = sum(jnp.einsum("nsgi,gio->nsgo", by_head[:, j:j + s], w1[j])
+              for j in range(2))
+    out = out.reshape(n, s, c_w) + lp["conv1_b"].astype(f32)
+    seq_v = jnp.concatenate([tail[:, None, 2 * c_w:], v2], axis=1)
+    return out, seq_v[:, :-1], jnp.concatenate([seq_u, seq_c, seq_v], -1)
+
+
+def cca_qk(cfg: ModelConfig, lp: Params, u, conv, positions):
+    """u [N, S, C] the latents before the convolutions, conv [N, S, C]
+    float32 after them, positions [N, S] -> (q [N, S, N_q, D], k [N, S,
+    N_kv * D]) as attended and cached: the q-k mean of the latents added
+    to the convolutions' output, each head normalised to sqrt(D) (a
+    key's times its K/V head's temperature), the first
+    ``qk_rope_head_dim`` numbers of every head rotated."""
+    n, s, _ = u.shape
+    d, nq, nkv = cfg.head_dim, cfg.num_heads, cfg.num_kv_heads
+    lat = u.astype(jnp.float32).reshape(n, s, nq + nkv, d)
+    conv = conv.reshape(n, s, nq + nkv, d)
+    mean_q = 0.5 * (lat[:, :, :nq]
+                    + jnp.repeat(lat[:, :, nq:], nq // nkv, axis=2))
+    mean_k = jnp.mean(mean_q.reshape(n, s, nkv, nq // nkv, d), axis=3)
+    q, k = conv[:, :, :nq] + mean_q, conv[:, :, nq:] + mean_k
+
+    def unit(x):
+        norm = jnp.sqrt(jnp.sum(x * x, axis=-1, keepdims=True))
+        return x * (d ** 0.5 / jnp.maximum(norm, 1e-12))
+    q, k = unit(q), unit(k) * lp["tau"][:, None]
+    rot = cfg.qk_rope_head_dim or d
+    sin, cos = transformer.rope_sincos(positions, rot, cfg.rope_theta)
+
+    def rope(x):
+        return jnp.concatenate(
+            [transformer.apply_rope(x[..., :rot], sin, cos), x[..., rot:]],
+            axis=-1)
+    dtype = u.dtype
+    return rope(q).astype(dtype), rope(k).astype(dtype).reshape(n, s, -1)
+
+
+def _cca(cfg: ModelConfig, lp: Params, h_in, pool, li, ctx):
+    """h_in [B, S, H] -> (mixer output, pool); ``li`` the layer's index
+    among the "C" layers, which are the K/V pool's layers AND the tail
+    rows' (``pool["t"]`` [layers, R, 1, cca_tail_width])."""
+    half = cfg.num_kv_heads // 2 * cfg.head_dim
+    with jax.named_scope("cca_proj"):
+        u = jnp.concatenate([quant.matmul(h_in, lp["wq"]),
+                             quant.matmul(h_in, lp["wk"])], axis=-1)
+        v = quant.matmul(h_in, lp["wv"])
+        v1, v2 = v[..., :half], v[..., half:]
+    t_all = pool["t"]
+    with jax.named_scope("cca_conv"):
+        if "row" in ctx:                           # a chunk of one sequence
+            row = ctx["row"]
+            tail = jnp.where(ctx["fresh"], jnp.zeros((), t_all.dtype),
+                             t_all[li, row])                       # [1, W]
+            conv, shifted, seq = cca_conv(cfg, lp, u, v2, tail)
+            # Row ``n_valid`` of [tail | chunk] is the last valid one's.
+            tail = jax.lax.dynamic_slice_in_dim(seq[0], ctx["n_valid"], 1)
+            pool = {**pool, "t": t_all.at[li, row].set(tail)}
+            positions = ctx["q_pos"]
+        else:                                      # a decode step, by rows
+            src, valid, dst = ctx["rows"]
+            conv, shifted, seq = cca_conv(cfg, lp, u, v2,
+                                          t_all[li][dst, 0])
+            tail = jnp.where(valid[:, None, None], seq[src, 1:],
+                             t_all[li])
+            pool = {**pool, "t": t_all.at[li].set(tail)}
+            positions = ctx["pos"][:, None]
+    with jax.named_scope("cca_qk_norm"):
+        q, k = cca_qk(cfg, lp, u, conv, positions)
+    return _attend(cfg, lp, q, k, jnp.concatenate([v1, shifted], axis=-1),
+                   pool, li, ctx)
 
 
 def _relu2_mlp(x, up, down):
@@ -624,20 +830,52 @@ def shared_expert(lp: Params, x: jax.Array) -> jax.Array:
         return _relu2_mlp(x, lp["ws_up"], lp["ws_down"])
 
 
+def route_mlp(cfg: ModelConfig, lp: Params, x: jax.Array, carry):
+    """The router of ``cfg.router_hidden``: x [T, H] float32, carry [T,
+    router_hidden] float32 the router's state of the SAME tokens in the
+    expert layer before (zeros before the first) -> (choice [T, k] int32,
+    weight [T, k] float32, this layer's state).  ``r = x W_r + gain *
+    carry``; the scores are an MLP's over ``RMSNorm(r)`` (two GELU layers
+    of the state's width, then the outputs), softmax in float32; the top
+    ``experts_per_token`` of probability + bias are chosen (the bias
+    moves the CHOICE only) and weighed by their probabilities as they
+    are: nothing renormalises, nothing scales."""
+    def matmul(a, w):
+        return jnp.einsum("tr,re->te", a, w.astype(jnp.float32),
+                          precision=HIGHEST)
+    r = matmul(x.astype(jnp.float32), lp["router"]) \
+        + lp["router_carry"] * carry
+    z = _norm_f32(r, lp["router_ln"], cfg.norm_eps)
+    for name in ("router_w1", "router_w2"):
+        z = jax.nn.gelu(matmul(z, lp[name]), approximate=False)
+    p = jax.nn.softmax(matmul(z, lp["router_w3"]), axis=-1)
+    _, choice = jax.lax.top_k(p + lp["router_bias"], cfg.experts_per_token)
+    return (choice.astype(jnp.int32),
+            jnp.take_along_axis(p, choice, axis=1), r)
+
+
 def routed_experts(cfg: ModelConfig, lp: Params, x: jax.Array,
-                   stacked: Optional[Params] = None, period=None):
+                   stacked: Optional[Params] = None, period=None,
+                   carry=None):
     """x [T, H] float32 -> (the HELD experts' part of the routed sum [T, H]
     in the model's dtype, counts [experts_held + 1] int32: assignments a
-    held expert, then those that went to absent ones).  The router scores
+    held expert, then those that went to absent ones, the router's state
+    to carry to the next expert layer).  The router scores
     all ``num_experts`` outputs and weighs the chosen over ALL of them,
-    whoever holds them.  Dropless and sorted by expert as
+    whoever holds them: the sigmoid router of one matrix
+    (``latent_moe.route``, which carries nothing: ``carry`` comes back as
+    it went), or the MLP router over its carried state where
+    ``cfg.router_hidden``.  Dropless and sorted by expert as
     ``latent_moe.routed_experts``; ``stacked`` [periods, held, in, out]
     with ``period`` the traced index, for the same reason as there."""
     t, h = x.shape
     k, held = cfg.experts_per_token, cfg.experts_held
     xd = x.astype(jnp.dtype(cfg.dtype))
     with jax.named_scope("moe_router"):
-        choice, w = latent_moe.route(cfg, lp, x)
+        if cfg.router_hidden:
+            choice, w, carry = route_mlp(cfg, lp, x, carry)
+        else:
+            choice, w = latent_moe.route(cfg, lp, x)
         local = choice - cfg.experts_first
         # Absent experts sort last, as one group nothing multiplies.
         flat = jnp.where((local >= 0) & (local < held), local,
@@ -647,10 +885,9 @@ def routed_experts(cfg: ModelConfig, lp: Params, x: jax.Array,
         order = jnp.argsort(flat, stable=True)
         mats, sizes = lp, counts[:held]
         if stacked is not None:
-            n = stacked[EXPERT_KEYS[0]].shape[0]
-            mats = {key: stacked[key].reshape(n * held,
-                                              *stacked[key].shape[2:])
-                    for key in EXPERT_KEYS}
+            n = stacked[EXPERT_KEYS[-1]].shape[0]
+            mats = {key: w_.reshape(n * held, *w_.shape[2:])
+                    for key, w_ in stacked.items()}
             sizes = jax.lax.dynamic_update_slice(
                 jnp.zeros(n * held, jnp.int32), sizes, (period * held,))
     with jax.named_scope("moe_experts"):
@@ -659,27 +896,44 @@ def routed_experts(cfg: ModelConfig, lp: Params, x: jax.Array,
         group = jnp.minimum(expert, held - 1)
         xs = xd[order // k]                                    # [T*k, H]
         xs = jnp.pad(xs, ((0, 0), (0, expert_dims_stored(cfg)[0] - h)))
-        a = jax.nn.relu(latent_moe._grouped(xs, mats["we_up"], sizes, group))
-        y = latent_moe._grouped((a * a).astype(xd.dtype), mats["we_down"],
+        a = latent_moe._grouped(xs, mats["we_up"], sizes, group)
+        if cfg.expert_act == "relu2":
+            a = jax.nn.relu(a)
+            a = a * a
+        else:
+            a = jax.nn.silu(latent_moe._grouped(xs, mats["we_gate"], sizes,
+                                                group)) * a
+        y = latent_moe._grouped(a.astype(xd.dtype), mats["we_down"],
                                 sizes, group)[:, :h]
         # Rows past the held groups belong to no group: whatever the
         # grouped product left there is not a number of this layer.
         y = jnp.where(here, y, 0)
         y = y[jnp.argsort(order)].reshape(t, k, h)
         out = jnp.einsum("tkh,tk->th", y.astype(jnp.float32), w)
-    return out.astype(xd.dtype), counts
+    return out.astype(xd.dtype), counts, carry
 
 
-def _experts(cfg: ModelConfig, lp: Params, h_f32, stacked, period):
-    """h_f32 [B, S, H], the float32 normed input -> (output in the
-    model's dtype, counts)."""
+def _experts(cfg: ModelConfig, lp: Params, h_f32, stacked, period, carry):
+    """h_f32 [B, S, H], the float32 normed input, carry [B, S,
+    router_hidden] -> (output in the model's dtype, counts, carry)."""
     b, s, h = h_f32.shape
-    out, counts = routed_experts(cfg, lp, h_f32.reshape(b * s, h), stacked,
-                                 period)
+    out, counts, carry = routed_experts(
+        cfg, lp, h_f32.reshape(b * s, h), stacked, period,
+        carry.reshape(b * s, -1))
     out = out.reshape(b, s, h)
     if "ws_up" in lp:
         out = out + shared_expert(lp, h_f32.astype(out.dtype))
-    return out, counts
+    return out, counts, carry.reshape(b, s, -1)
+
+
+def _merge(lp: Params, x, out):
+    """The residual after a sublayer: the sum, or — where the layer holds
+    the four vectors of the scaled merge — ``(a_r x + b_r) + (a_o out +
+    b_o)`` in float32, rounded to the residual's dtype."""
+    if "res" not in lp:
+        return x + out
+    a_r, b_r, a_o, b_o = lp["res"]
+    return ((a_r * x + b_r) + (a_o * out + b_o)).astype(x.dtype)
 
 
 # =============================================================================
@@ -698,7 +952,8 @@ def forward_paged(cfg: ModelConfig, params: Params, tokens: jax.Array,
                   pool, ctx: Dict[str, Any]):
     """tokens [B, S]; ``pool`` {"k", "v": [attention layers, NB, bs,
     N_kv * D], "s": [state-space layers, R, heads, P, N] float32 (Mamba-1:
-    [.., R, state, inner]), "t": [state-space layers, R, K-1, C],
+    [.., R, state, inner]), "t": [state-space layers, R, K-1, C] (a
+    pattern with "C": ["C" layers, R, 1, cca_tail_width], and "s" empty),
     "owner": [R]}.  ``ctx`` is what the
     mixers need of where the tokens sit (``chunk_ctx`` / ``decode_ctx``).
     Returns (hidden [B, S, H] after the final norm, pool, counts [expert
@@ -717,10 +972,11 @@ def forward_paged(cfg: ModelConfig, params: Params, tokens: jax.Array,
     stacked = [None] * len(period)
     for j, kind in enumerate(period):
         if kind == "E" and not quant.is_quantized(layers[j]["we_up"]):
-            stacked[j] = {key: layers[j].pop(key) for key in EXPERT_KEYS}
+            stacked[j] = {key: layers[j].pop(key) for key in EXPERT_KEYS
+                          if key in layers[j]}
 
     def body(carry, scanned):
-        x, carried = carry
+        x, carried, routed = carry
         lps, p = scanned
         counts = []
         for j, kind in enumerate(period):
@@ -742,15 +998,22 @@ def forward_paged(cfg: ModelConfig, params: Params, tokens: jax.Array,
             elif kind == "*":
                 out, carried = _attention(cfg, lp, h_f32.astype(dtype),
                                           carried, li, ctx)
+            elif kind == "C":
+                out, carried = _cca(cfg, lp, h_f32.astype(dtype), carried,
+                                    li, ctx)
             else:
-                out, n = _experts(cfg, lp, h_f32, stacked[j], p)
+                out, n, routed = _experts(cfg, lp, h_f32, stacked[j], p,
+                                          routed)
                 counts.append(n)
-            x = x + out
-        return (x, carried), jnp.stack(counts) if counts else None
+            x = _merge(lp, x, out)
+        return (x, carried, routed), jnp.stack(counts) if counts else None
 
     n_periods = cfg.num_layers // len(period)
-    (x, carried), counts = jax.lax.scan(
-        body, (x, carried), (layers, jnp.arange(n_periods)))
+    # The MLP router's state of every token, carried from expert layer to
+    # expert layer (zero-wide under the router that carries nothing).
+    routed = jnp.zeros(tokens.shape + (cfg.router_hidden,), jnp.float32)
+    (x, carried, _), counts = jax.lax.scan(
+        body, (x, carried, routed), (layers, jnp.arange(n_periods)))
     hidden = transformer.rms_norm(x, params["final_ln"], cfg.norm_eps)
     if counts is None:
         counts = jnp.zeros((0, cfg.experts_held + 1), jnp.int32)

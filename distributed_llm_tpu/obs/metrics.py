@@ -471,7 +471,12 @@ METRIC_REGISTRY: Tuple[Tuple[str, str, str, Tuple[str, ...], str], ...] = (
      ("tier", "stage"),
      "Routed experts with at least one token, summed over expert layers "
      "and over steps (decode) or chunks (prefill): the experts whose "
-     "weights a step had to read"),
+     "weights a step had to read (under a top-1 router, "
+     "ModelConfig.router_hidden, at most one a token a layer: the "
+     "benchmark's moe.top1_experts_touched_per_step, and through it the "
+     "experts' bytes of step.decode_hbm_share_cca_moe and "
+     "attn.cca_kv_share_of_step_bytes; the experts' device time is "
+     "moe.grouped_product_share_of_step_ms, from the trace)"),
     ("moe_absent_assignments", "counter",
      "dllm_moe_absent_assignments_total", ("tier", "stage"),
      "Token-to-expert assignments the router made to experts this "
@@ -483,7 +488,9 @@ METRIC_REGISTRY: Tuple[Tuple[str, str, str, Tuple[str, ...], str], ...] = (
     # starts.
     ("state_resets", "counter", "dllm_state_resets_total", ("tier",),
      "Recurrent rows started from zero: prompts (and preemption "
-     "replays) whose first chunk was dispatched"),
+     "replays) whose first chunk was dispatched; a row is a state-space "
+     "layer's state and conv tail, or the tail a compressed "
+     "convolutional attention layer keeps beside its paged K/V"),
     # Batched-speculation family (ISSUE 15): drafted vs accepted
     # draft tokens per tier (the counter pair whose ratio IS the
     # realized acceptance rate) and the engine's running acceptance

@@ -50,21 +50,30 @@ def _mamba1_params(cfg) -> int:
 
 def _hybrid_layer_params(cfg):
     """Matrix parameters of one layer of each kind of the hybrid family
-    (models/hybrid_ssm.py): (state-space, attention, an expert layer
-    without its routed experts, one routed expert)."""
+    (models/hybrid_ssm.py): (state-space, attention — a "C" layer's with
+    its two convolutions' taps —, an expert layer without its routed
+    experts, one routed expert)."""
     h, d = cfg.hidden_size, cfg.head_dim
     ssm = (_mamba1_params(cfg) if cfg.ssm_dt_rank else
            h * (cfg.ssm_inner + cfg.ssm_conv_width + cfg.ssm_heads)
            + cfg.ssm_inner * h)
     attn = 2 * h * cfg.num_heads * d + 2 * h * cfg.num_kv_heads * d
-    fixed = h * cfg.num_experts + 2 * h * cfg.shared_ffn_size
-    return ssm, attn, fixed, 2 * h * cfg.moe_ffn_size
+    if cfg.layers_of("C"):
+        heads = cfg.num_heads + cfg.num_kv_heads
+        attn += 2 * heads * d + 2 * heads * d * d
+    rh = cfg.router_hidden
+    router = (h * rh + 2 * rh * rh + rh * cfg.num_experts if rh
+              else h * cfg.num_experts)
+    gated = 2 if cfg.expert_act == "relu2" else 3
+    return (ssm, attn, router + 2 * h * cfg.shared_ffn_size,
+            gated * h * cfg.moe_ffn_size)
 
 
 def _hybrid_params(cfg, experts: float) -> float:
     """All layers' matrices with ``experts`` routed experts a layer."""
     ssm, attn, fixed, expert = _hybrid_layer_params(cfg)
-    return (cfg.layers_of("M") * ssm + cfg.layers_of("*") * attn
+    return (cfg.layers_of("M") * ssm
+            + (cfg.layers_of("*") + cfg.layers_of("C")) * attn
             + cfg.layers_of("E") * (fixed + experts * expert)
             + cfg.layers_of("-") * 3 * cfg.hidden_size * cfg.ffn_size)
 
@@ -175,17 +184,19 @@ def _attention_width_layers(cfg):
     if cfg.family == "shared_kv":
         return cfg.num_heads * cfg.head_dim, kv_readers(cfg)
     if cfg.family == "hybrid":
-        return cfg.num_heads * cfg.head_dim, cfg.layers_of("*")
+        return cfg.num_heads * cfg.head_dim, cfg.kv_layers
     return cfg.hidden_size, cfg.num_layers
 
 
 def state_row_bytes(cfg) -> int:
     """What one sequence of the hybrid family keeps beside its K/V: the
-    float32 state and the conv tail of every state-space layer."""
-    return cfg.layers_of("M") * (
+    float32 state and the conv tail of every state-space layer, or the
+    tail row of every "C" layer."""
+    itemsize = 4 if cfg.dtype == "float32" else 2
+    return (cfg.layers_of("M") * (
         cfg.ssm_inner * cfg.ssm_state * 4
-        + (cfg.ssm_conv - 1) * cfg.ssm_conv_width
-        * (4 if cfg.dtype == "float32" else 2))
+        + (cfg.ssm_conv - 1) * cfg.ssm_conv_width * itemsize)
+        + cfg.layers_of("C") * cfg.cca_tail_width * itemsize)
 
 
 def prefill_work(cfg, end: int, start: int = 0,

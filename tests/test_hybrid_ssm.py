@@ -229,7 +229,9 @@ def test_the_shares_add_up_to_the_uncut_references_whole_layer(ref):
         np.testing.assert_array_equal(
             np.asarray(share["we_up"]),
             np.asarray(lp["we_up"][first:first + 4]))
-        out, n = hybrid_ssm._experts(cfg, share, x[None], None, None)
+        # The sigmoid router carries nothing: a zero-wide state.
+        out, n, _ = hybrid_ssm._experts(cfg, share, x[None], None, None,
+                                        jnp.zeros((1, x.shape[0], 0)))
         parts.append(np.asarray(out[0]))
         counts.append(np.asarray(n[0] if n.ndim > 1 else n))
     shared = np.asarray(hybrid_ssm.shared_expert(lp, x))

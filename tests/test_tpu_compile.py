@@ -368,13 +368,15 @@ def test_pool_program_leaves_the_pool_in_place(one_chip, as_on_tpu,
 ROUTED_TICKS = {
     "xing4.0-29b-a4b": (3, 2.35, 0.5),
     "nemotron-3-nano-30b-a3b": (6, 1.48, 0.5),
+    # Top-1 of 16 gated experts of 2048 x 2048 over 20 layers (PR 51).
+    "zaya1-8b": (3, 2.68, 0.5),
 }
 
 
 @pytest.mark.parametrize("config", list(ROUTED_TICKS))
 def test_routed_tick_reads_the_experts_where_they_rest(one_chip, as_on_tpu,
                                                        monkeypatch, config):
-    """The decode ticks of the benchmark's two routed-expert
+    """The decode ticks of the benchmark's routed-expert
     configurations, at their real sizes: every grouped product of the
     layer body is the repo's kernel, handed the STACKED experts whole —
     no copy of them at the program's entry, no per-layer slice: the
