@@ -248,6 +248,24 @@ class _Prefill:
     pending_t: float = 0.0
 
 
+@dataclasses.dataclass
+class _Tick:
+    """A plain decode tick between its dispatch and its fetch: what the
+    fetch, ``account`` and the emit need of it.  At most two exist at a
+    time, the one being fetched and the one dispatched AHEAD of that
+    fetch (``_may_go_ahead``)."""
+
+    active: List[int]             # the slots it was dispatched for
+    toks: Any                     # [T, B] as dispatched (routed experts:
+                                  # with the steps' assignment counts)
+    wb: int                       # its table rung, in blocks
+    key_in: Any                   # the key it was given (a failed tick's
+                                  # successor starts from its split)
+    t0: float                     # perf_counter at its launch
+    ahead: bool                   # dispatched before the fetch before it
+    ride_s: float = 0.0           # a riding chunk's host section
+
+
 class _ExpertLoad:
     """Assignments of tokens to routed experts, summed over an engine's
     life by stage (``decode`` steps, ``prefill`` chunks): a count a
@@ -491,6 +509,14 @@ class ContinuousBatchingEngine:
         # made there by what (dllm_tick_prepare_uploads_total).
         self.ticks_launched_total = 0
         self.ticks_resident_total = 0
+        # Of the launched, those dispatched AHEAD of the fetch of the
+        # tick before them (tick_stats' ``ahead_share``,
+        # dllm_decode_ticks_ahead_total), and the steps such a tick
+        # computed for a slot that an EOS/PAD in the tick before it had
+        # ended: the whole waste of the ahead order.
+        self.ticks_ahead_total = 0
+        self.ahead_dead_slot_steps_total = 0
+        self._ahead_sink: Any = None
         self.prepare_uploads_total: Dict[str, int] = {}
         self._prepare_upload_sinks: Dict[str, Any] = {}
 
@@ -2984,33 +3010,37 @@ class ContinuousBatchingEngine:
                   slot.prompt_len + slot.budget, self.cfg.max_seq_len)
         return min(slot.max_blocks, -(-end // self.paged.block_size))
 
-    def _prepare_ahead(self, active: List[int]) -> None:
+    def _prepare_ahead(self, active: List[int], in_flight: int = 1) -> None:
         """Between a plain tick's dispatch and its fetch, while the
         host would only wait: the blocks the NEXT tick's positions need
-        (the mirrors still stand before the running tick, so that span
-        ends two ticks ahead) and the upload of the table rung it will
-        take.  Only what the allocator gives outright: a dry pool is
-        left to the next pass's ``_plan_and_grow`` with its evictions,
-        cancellations and preemptions.  The running tick holds the
-        table it was given; a slot that ends in it frees these blocks
-        with its others.  Stamped ``prepare``/``table_upload`` like the
-        work it takes off the next pass."""
+        (the mirrors still stand before the ``in_flight`` ticks
+        dispatched and not emitted, so that span ends ``in_flight + 1``
+        ticks ahead: two behind a tick alone, three behind one
+        dispatched ahead of the fetch before it) and the upload of the
+        table rung it will take.  Only what the allocator gives
+        outright: a dry pool is left to the next pass's
+        ``_plan_and_grow`` with its evictions, cancellations and
+        preemptions (and keeps that tick from going ahead).  The
+        running tick holds the table it was given; a slot that ends in
+        it frees these blocks with its others.  Stamped
+        ``prepare``/``table_upload`` like the work it takes off the next
+        pass."""
+        steps = in_flight * self.steps_per_tick
         with self.profiler.phase("prepare"):
             for ix in active:
                 slot = self._slots[ix]
                 if slot is None:
                     continue
-                short = (self._blocks_needed(ix, slot,
-                                             2 * self.steps_per_tick)
-                         - len(slot.blocks))
+                short = (self._blocks_needed(
+                    ix, slot, steps + self.steps_per_tick)
+                    - len(slot.blocks))
                 if short <= 0:
                     continue
                 extra = self.allocator.alloc(short)
                 if extra is not None:           # else: the pass's own growth
                     slot.blocks.extend(extra)
                     self._set_table_row(ix, self._table_row(slot.blocks))
-            self._tick_tables(int(self._pos[active].max())
-                              + self.steps_per_tick)
+            self._tick_tables(int(self._pos[active].max()) + steps)
 
     def _plan_and_grow(self, active: List[int]):
         """The host work a tick needs before its uploads: the
@@ -3105,6 +3135,8 @@ class ContinuousBatchingEngine:
             share = tick_ms / len(active)
             for ix in active:
                 slot = self._slots[ix]
+                if slot is None:
+                    continue     # ended under this tick, dispatched ahead
                 trace = slot.request.trace
                 if trace is None:
                     continue     # direct engine use: unbilled
@@ -3292,160 +3324,218 @@ class ContinuousBatchingEngine:
                     self.profiler.commit(0)
                 continue
 
-            chunk_spent, ride_s = 0, 0.0
-            key_in = None
-            try:
-                spec_tick = spec_gb is not None
-                with self.profiler.phase("prepare"):
-                    if spec_tick:
-                        # The speculative round keeps the host's split
-                        # (and its uploads: ``_emit_spec`` moves the
-                        # mirrors by what was accepted, and drops).
-                        self._rng, rng = jax.random.split(self._rng)
-                    tables_arg, wb, uploaded = self._tick_tables(
-                        int(self._pos[active].max()))
-                    if uploaded:
-                        self._count_prepare_upload("tables")
-                    # The mirrors are the authority: upload what a
-                    # writer dropped since the last tick, and only that.
-                    for what, mirror in (("pos", self._pos),
-                                         ("cur", self._cur),
-                                         ("temps", self._temps)):
-                        if what not in self._carry:
-                            self._carry[what] = self._upload(mirror)
-                            self._count_prepare_upload(what)
-                            uploaded = True
-                    pos_dev, cur_dev, temps_dev = (
-                        self._carry[k] for k in ("pos", "cur", "temps"))
-                    if self._sync_state_owner():
-                        self._count_prepare_upload("owner")
-                        uploaded = True
-                    self.ticks_launched_total += 1
-                    self.ticks_resident_total += int(not uploaded)
-                    if spec_tick:
-                        gammas = np.zeros(self.paged.max_slots, np.int32)
-                        for ix in active:
-                            slot = self._slots[ix]
-                            if slot is not None and slot.spec:
-                                gammas[ix] = min(slot.gamma, spec_gb)
-                        gammas_dev = jnp.asarray(gammas)
-                t_tick = time.perf_counter()
-                if spec_tick:
-                    # One speculative round: γ_bucket drafts per slot in
-                    # one scanned draft call, then ONE fused γ+1-wide
-                    # ragged verify with per-slot acceptance caps as
-                    # runtime operands.  Two device calls, one sync (the
-                    # verify pull) — the draft phase stamps dispatch
-                    # wall, the verify phase carries the device wait
-                    # (DESIGN.md "Batched speculation" documents the
-                    # attribution).
-                    with self.profiler.phase("draft"):
-                        drafted, self.pool_d = self._spec_draft_fn(
-                            spec_gb)(self.params_d, self.pool_d,
-                                     tables_arg, pos_dev, cur_dev)
-                    with self.profiler.phase("verify"):
-                        out, n_acc, self.pool = self._spec_verify_fn(
-                            spec_gb)(self.params, self.pool, tables_arg,
-                                     pos_dev, cur_dev, drafted,
-                                     gammas_dev, temps_dev, rng)
-                        out, n_acc = _fetch_tick((out, n_acc))
-                else:
-                    self._note_compile("decode", (wb, self._tp_degree()))
-                    # ``decode`` is the tick as the device sees it; its
-                    # children split it into the launch (host work, the
-                    # device idle unless the last program still runs)
-                    # and the one sanctioned sync — and between the
-                    # two, while a chunked prefill is in flight, its
-                    # chunk of this pass (``chunk_prefill``): enqueued
-                    # behind the tick, so the device goes from the tick
-                    # straight into the chunk while the host fetches,
-                    # accounts and emits.
-                    with self.profiler.phase("decode"):
-                        with self.profiler.phase("dispatch"):
-                            # The tick splits the engine's key itself
-                            # and hands back what the next one starts
-                            # from; only ``toks`` is ever fetched.
-                            key_in = self._rng
-                            (toks, self._carry["pos"], self._carry["cur"],
-                             self._rng), self.pool = self._decode_step()(
-                                self.params, self.pool, tables_arg,
-                                pos_dev, cur_dev, temps_dev, key_in)
-                        if self._prefill is not None:
-                            t_ride = time.perf_counter()
-                            chunk_spent = self._ride_chunk()
-                            ride_s = time.perf_counter() - t_ride
-                        # The device runs the tick: what the NEXT tick
-                        # needs and this one's tokens do not decide.
-                        self._prepare_ahead(active)
-                        with self.profiler.phase("fetch"):
-                            toks = _fetch_tick(toks)           # [T, B]
-                    if self._moe is not None:
-                        # The same fetch brought the steps' assignments
-                        # an expert: counted in ``account``.
-                        toks, n_exp = toks
-                # The tick's launch and the wait for its tokens; a
-                # riding chunk's section between the two is the
-                # chunk's (dllm_prefill_chunk_ms), not the tick's.
-                self._tick_fetched_t = time.perf_counter()
-                tick_ms = (self._tick_fetched_t - t_tick - ride_s) * 1000.0
-                # Everything between the fetch and the emit, with the
-                # device idle: ``account``.  What it holds is priced in
-                # PERF.md ("what tracing costs") — keep it to dict
-                # lookups and float adds.
-                with self.profiler.phase("account"):
-                    self._account_tick(active, tick_ms, wb, spec_gb)
-                    if self._moe is not None and not spec_tick:
-                        self._moe.note("decode", n_exp)
-            except BaseException as exc:
-                # A dead tick must not become a dead scheduler: fail the
-                # in-flight requests and keep serving new ones.  What it
-                # would have handed the next tick is not to be trusted:
-                # the mirrors are uploaded, and the key moves on as if
-                # the tick had split it.
+            if spec_gb is not None:
+                self._spec_round(active, spec_gb)
+            else:
+                self._plain_ticks(active)
+
+    def _tick_inputs(self, active: List[int], in_flight: int = 0):
+        """The second half of a tick's ``prepare``: its table rung and
+        its small inputs as the device holds them, uploading what a
+        writer dropped since the last tick, and only that (the mirrors
+        are the authority); counts the launch.  ``in_flight`` ticks are
+        dispatched and not emitted: the mirrors stand that far behind
+        what this tick starts from.  (tables, rung in blocks, pos, cur,
+        temps)."""
+        tables_arg, wb, uploaded = self._tick_tables(
+            int(self._pos[active].max()) + in_flight * self.steps_per_tick)
+        if uploaded:
+            self._count_prepare_upload("tables")
+        for what, mirror in (("pos", self._pos), ("cur", self._cur),
+                             ("temps", self._temps)):
+            if what not in self._carry:
+                self._carry[what] = self._upload(mirror)
+                self._count_prepare_upload(what)
+                uploaded = True
+        if self._sync_state_owner():
+            self._count_prepare_upload("owner")
+            uploaded = True
+        self.ticks_launched_total += 1
+        self.ticks_resident_total += int(not uploaded)
+        return (tables_arg, wb, self._carry["pos"], self._carry["cur"],
+                self._carry["temps"])
+
+    def _spec_round(self, active: List[int], spec_gb: int) -> None:
+        """One speculative round: γ_bucket drafts per slot in one
+        scanned draft call, then ONE fused γ+1-wide ragged verify with
+        per-slot acceptance caps as runtime operands.  Two device
+        calls, one sync (the verify pull) — the draft phase stamps
+        dispatch wall, the verify phase carries the device wait
+        (DESIGN.md "Batched speculation" documents the attribution).
+        The round keeps the host's split of the key (and its uploads:
+        ``_emit_spec`` moves the mirrors by what was accepted, and
+        drops)."""
+        try:
+            with self.profiler.phase("prepare"):
+                self._rng, rng = jax.random.split(self._rng)
+                tables_arg, wb, pos_dev, cur_dev, temps_dev = \
+                    self._tick_inputs(active)
+                gammas = np.zeros(self.paged.max_slots, np.int32)
                 for ix in active:
-                    self._fail_slot(ix, exc)
-                self._drop_carry()
-                if key_in is not None:
-                    self._rng = jax.random.split(key_in)[0]
-                self.profiler.commit(len(active))
-                continue
+                    slot = self._slots[ix]
+                    if slot is not None and slot.spec:
+                        gammas[ix] = min(slot.gamma, spec_gb)
+                gammas_dev = jnp.asarray(gammas)
+            t_tick = time.perf_counter()
+            with self.profiler.phase("draft"):
+                drafted, self.pool_d = self._spec_draft_fn(
+                    spec_gb)(self.params_d, self.pool_d,
+                             tables_arg, pos_dev, cur_dev)
+            with self.profiler.phase("verify"):
+                out, n_acc, self.pool = self._spec_verify_fn(
+                    spec_gb)(self.params, self.pool, tables_arg,
+                             pos_dev, cur_dev, drafted,
+                             gammas_dev, temps_dev, rng)
+                out, n_acc = _fetch_tick((out, n_acc))
+            self._tick_fetched_t = time.perf_counter()
+            tick_ms = (self._tick_fetched_t - t_tick) * 1000.0
+            with self.profiler.phase("account"):
+                self._account_tick(active, tick_ms, wb, spec_gb)
+        except BaseException as exc:
+            self._tick_failed(active, exc)
+            return
+        self._emit_spec(active, out, n_acc, gammas)
+        if self._prefill is not None:
+            self._advance_prefill()
+        self._progress_t = time.monotonic()
+        self.profiler.commit(len(active))
 
-            if spec_tick:
-                self._emit_spec(active, out, n_acc, gammas)
-                if self._prefill is not None:
-                    self._advance_prefill()
-                self._progress_t = time.monotonic()
-                self.profiler.commit(len(active))
-                continue
+    def _tick_failed(self, active: List[int], exc: BaseException,
+                     key_in: Any = None) -> None:
+        """A dead tick must not become a dead scheduler: fail the
+        in-flight requests and keep serving new ones.  What the tick
+        (and one dispatched ahead behind it, which is dropped unfetched)
+        would have handed the next is not to be trusted: the mirrors
+        are uploaded, and from ``key_in``, the key a dispatched tick
+        was given, the key moves on as if that tick had split it."""
+        for ix in active:
+            self._fail_slot(ix, exc)
+        self._drop_carry()
+        if key_in is not None:
+            self._rng = jax.random.split(key_in)[0]
+        self.profiler.commit(len(active))
 
-            with self.profiler.phase("emit"):
-                for t in range(toks.shape[0]):
-                    for ix in active:
-                        slot = self._slots[ix]
-                        if slot is None:
-                            continue         # finished at an earlier t
-                        tok = int(toks[t, ix])
-                        slot.tokens.append(tok)
-                        # Tick-granular decode timeline: a tick's T
-                        # tokens stamp together because that is when
-                        # they become observable (one device call per
-                        # tick).  One list append per token — no span
-                        # objects on this path.
-                        obs_spans.add_token(slot.request.trace)
-                        if slot.request.token_queue is not None:
-                            slot.request.token_queue.put(tok)
-                        self._pos[ix] += 1
-                        self._cur[ix] = tok
-                        hit_cap = len(slot.tokens) >= slot.budget
-                        # PAD ends generation like EOS: trim_at_eos
-                        # truncates the result there, so streaming past
-                        # it would diverge.
-                        hit_end = (tok in (self.tokenizer.eos_id,
-                                           self.tokenizer.pad_id)
-                                   or self._pos[ix]
-                                   >= self.cfg.max_seq_len - 1)
-                        if hit_cap or hit_end:
-                            self._finish(ix)
+    def _launch_tick(self, active: List[int], ahead: bool = False) -> _Tick:
+        """``prepare`` and ``dispatch`` of one plain tick.  It takes the
+        engine's key, splits it itself and hands back what the next one
+        starts from, with ``pos`` and ``cur``; only ``toks`` is ever
+        fetched.  ``ahead``: the tick before it is dispatched and not
+        fetched, so this one queues behind it on the device, takes its
+        outputs as they stand there, and the mirrors lag by its
+        steps."""
+        with self.profiler.phase("prepare"):
+            tables_arg, wb, pos_dev, cur_dev, temps_dev = self._tick_inputs(
+                active, in_flight=int(ahead))
+            if ahead:
+                self._count_ahead()
+        t0 = time.perf_counter()
+        self._note_compile("decode", (wb, self._tp_degree()))
+        with self.profiler.phase("dispatch"):
+            key_in = self._rng
+            try:
+                (toks, self._carry["pos"], self._carry["cur"],
+                 self._rng), self.pool = self._decode_step()(
+                    self.params, self.pool, tables_arg,
+                    pos_dev, cur_dev, temps_dev, key_in)
+            except BaseException:
+                self._rng = jax.random.split(key_in)[0]
+                raise
+        return _Tick(active, toks, wb, key_in, t0, ahead)
+
+    def _count_ahead(self) -> None:
+        self.ticks_ahead_total += 1
+        if self._ahead_sink is None:
+            try:
+                # No injection path on the engine (same pattern as the
+                # tick histogram): the process-global registry.
+                from ..obs import get_observability
+                self._ahead_sink = get_observability(
+                ).m.decode_ticks_ahead.labels(self.tier.name)
+            except Exception:
+                return
+        self._ahead_sink.inc()
+
+    def _may_go_ahead(self, tick: _Tick) -> bool:
+        """Whether the tick after ``tick`` (dispatched, not fetched) may
+        be dispatched BEFORE that fetch, so that the host's fetch,
+        account, emit and the next ``prepare`` run under a tick and not
+        beside an idle chip.  Four tests on what the host already holds
+        (DESIGN.md "The pass's two orders"); where any fails the pass
+        settles ``tick`` first, today's order:
+
+        1. every slot is taken: nothing queued could be admitted before
+           a slot ends, so no arrival waits for a tick already queued;
+        2. no slot reaches its budget or the span within ``tick`` (the
+           mirrors stand before it): a known end is settled first and
+           the freed slot re-admitted as early as ever;
+        3. no chunked prefill is in flight, the next tick is a plain
+           one, and the engine is not stopping;
+        4. the carry is whole (no writer dropped it since ``tick`` was
+           dispatched) and the next tick's blocks are there
+           (``_prepare_ahead`` got them outright: no eviction, no
+           preemption).
+
+        What it cannot see is an EOS/PAD in ``tick``: that slot's rows
+        of the next tick compute tokens that are thrown away
+        (``ahead_dead_slot_steps_total``)."""
+        if (self._stop.is_set() or self._prefill is not None
+                or not self._carry.keys() >= {"pos", "cur", "temps"}
+                or self._spec_plan(tick.active) is not None):
+            return False
+        steps = self.steps_per_tick
+        last = self.cfg.max_seq_len - 1
+        for ix, slot in enumerate(self._slots):
+            if (slot is None
+                    or len(slot.tokens) + steps >= slot.budget
+                    or int(self._pos[ix]) + steps >= last
+                    or self._blocks_needed(ix, slot, 2 * steps)
+                    > len(slot.blocks)):
+                return False
+        return True
+
+    def _plain_ticks(self, active: List[int]) -> None:
+        """One plain tick from its launch to its emit and, for as long
+        as ``_may_go_ahead`` allows, the ticks after it, each dispatched
+        AHEAD of the fetch of the one before: one profiler record and
+        one fetch a tick either way, and never more than one tick in
+        flight beyond the one being fetched.  Returns with nothing in
+        flight, so whatever runs at the loop's top (admissions, growth
+        with its evictions and preemptions, a stop) sees the mirrors
+        settled.
+
+        ``decode`` is the tick as the device sees it, from its launch
+        to the return of its fetch; between the two, while a chunked
+        prefill is in flight, its chunk of this pass (``chunk_prefill``):
+        enqueued behind the tick, so the device goes from the tick
+        straight into the chunk while the host fetches, accounts and
+        emits."""
+        chunk_spent = 0
+        tick = nxt = None
+        while True:
+            try:
+                if tick is None:
+                    tick = self._launch_tick(active)
+                    if self._prefill is not None:
+                        t_ride = time.perf_counter()
+                        chunk_spent = self._ride_chunk()
+                        tick.ride_s = time.perf_counter() - t_ride
+                    # The device runs the tick: what the NEXT tick
+                    # needs and this one's tokens do not decide.
+                    self._prepare_ahead(active)
+                # The decision stands where ``_plan_and_grow`` stands in
+                # the other order, and is stamped like it.
+                with self.profiler.phase("prepare"):
+                    ahead = self._may_go_ahead(tick)
+                nxt = None
+                if ahead:
+                    nxt = self._launch_tick(active, ahead=True)
+                    self._prepare_ahead(active, 2)
+                toks = self._fetch_and_account(tick)
+            except BaseException as exc:
+                self._tick_failed(active, exc,
+                                  None if tick is None else tick.key_in)
+                return
+            self._emit_plain(tick, toks)
             if self._prefill is not None:
                 # Decode slots served: what is left of the tick's
                 # prefill budget after the chunk that rode behind the
@@ -3457,6 +3547,76 @@ class ContinuousBatchingEngine:
                 self._advance_prefill(self.chunk_budget - chunk_spent)
             self._progress_t = time.monotonic()  # tick completed
             self.profiler.commit(len(active))
+            if nxt is None:
+                return
+            tick = nxt
+
+    def _fetch_and_account(self, tick: _Tick):
+        """The one sanctioned sync, then everything between the fetch
+        and the emit: ``account``.  What it holds is priced in PERF.md
+        ("what tracing costs") — keep it to dict lookups and float
+        adds.  Returns the tick's tokens [T, B]."""
+        with self.profiler.phase("fetch"):
+            toks = _fetch_tick(tick.toks)
+            # Fetched: the device's copy goes here, under a stamp and
+            # before the emit wakes anyone, not with the record after it
+            # (freeing a device array releases the interpreter's lock).
+            tick.toks = None
+        if self._moe is not None:
+            # The same fetch brought the steps' assignments an expert.
+            toks, n_exp = toks
+        # One tick's device wait: from its launch, or from the fetch
+        # before it where it was dispatched ahead of that (it ran behind
+        # that tick, not since its own launch), to its tokens; a riding
+        # chunk's host section between the two is the chunk's
+        # (dllm_prefill_chunk_ms), not the tick's.
+        start = max(tick.t0, self._tick_fetched_t)
+        self._tick_fetched_t = time.perf_counter()
+        tick_ms = (self._tick_fetched_t - start - tick.ride_s) * 1000.0
+        self.profiler.span_from("decode", tick.t0)
+        with self.profiler.phase("account"):
+            self._account_tick(tick.active, tick_ms, tick.wb, None)
+            if self._moe is not None:
+                self._moe.note("decode", n_exp)
+        return toks
+
+    def _emit_plain(self, tick: _Tick, toks) -> None:
+        """A plain tick's tokens to their slots, streams and mirrors.  A
+        slot that ended under a tick dispatched ahead (``slot is None``
+        from the first step) takes none of them: its index is free, and
+        is given to nobody before this emit."""
+        active = tick.active
+        with self.profiler.phase("emit"):
+            if tick.ahead:
+                self.ahead_dead_slot_steps_total += toks.shape[0] * sum(
+                    1 for ix in active if self._slots[ix] is None)
+            for t in range(toks.shape[0]):
+                for ix in active:
+                    slot = self._slots[ix]
+                    if slot is None:
+                        continue         # finished at an earlier t
+                    tok = int(toks[t, ix])
+                    slot.tokens.append(tok)
+                    # Tick-granular decode timeline: a tick's T
+                    # tokens stamp together because that is when
+                    # they become observable (one device call per
+                    # tick).  One list append per token — no span
+                    # objects on this path.
+                    obs_spans.add_token(slot.request.trace)
+                    if slot.request.token_queue is not None:
+                        slot.request.token_queue.put(tok)
+                    self._pos[ix] += 1
+                    self._cur[ix] = tok
+                    hit_cap = len(slot.tokens) >= slot.budget
+                    # PAD ends generation like EOS: trim_at_eos
+                    # truncates the result there, so streaming past
+                    # it would diverge.
+                    hit_end = (tok in (self.tokenizer.eos_id,
+                                       self.tokenizer.pad_id)
+                               or self._pos[ix]
+                               >= self.cfg.max_seq_len - 1)
+                    if hit_cap or hit_end:
+                        self._finish(ix)
 
     # -- public surface (InferenceEngine parity) ---------------------------
 
@@ -3879,13 +4039,21 @@ class ContinuousBatchingEngine:
                 continue
         launched = self.ticks_launched_total
         resident = self.ticks_resident_total
+        ahead = self.ticks_ahead_total
         # How often the next tick's inputs were already on the device:
         # decode ticks launched with no upload in ``prepare``, and the
-        # uploads that were made there, by what.
+        # uploads that were made there, by what.  And how often the
+        # next tick was on the device's queue before the host fetched
+        # the one before it, with what that order wasted.
         out = {"n": len(ticks), "p50_ms": None, "p95_ms": None,
                "launched_total": launched, "resident_total": resident,
                "resident_share": (round(resident / launched, 4)
                                   if launched else None),
+               "ahead_total": ahead,
+               "ahead_share": (round(ahead / launched, 4)
+                               if launched else None),
+               "ahead_dead_slot_steps_total":
+                   self.ahead_dead_slot_steps_total,
                "prepare_uploads": dict(self.prepare_uploads_total),
                "attention_form": dict(self._attention_forms)}
         if not ticks:

@@ -395,6 +395,14 @@ METRIC_REGISTRY: Tuple[Tuple[str, str, str, Tuple[str, ...], str], ...] = (
      "fused one, paged_decode the windowed one, ragged_verify a "
      "speculative round; +_q8 over an int8 pool) and what serves its "
      "attention (pallas: the streamed rows kernel; else xla)"),
+    ("decode_ticks_ahead", "counter", "dllm_decode_ticks_ahead_total",
+     ("tier",),
+     "Plain decode ticks dispatched AHEAD of the fetch of the tick "
+     "before them (a full batch, no end known within that tick, no "
+     "prefill in flight, the carry whole and the blocks there), so the "
+     "host's fetch, account, emit and prepare ran under a tick and not "
+     "beside an idle chip: GET /stats tiers.<tier>.tick.ahead_share is "
+     "that share of the ticks launched"),
     ("tick_prepare_uploads", "counter", "dllm_tick_prepare_uploads_total",
      ("tier", "what"),
      "Uploads a decode tick's prepare phase made with the device idle, "

@@ -9,13 +9,15 @@ calls inside an admission (``prefill``), COW boundary copies
 (``cow_copy``), the work before the launch (``prepare``: speculative
 plan, KV growth, the rng split, the ``pos``/``cur``/``temps`` uploads,
 and nested in it the block-table upload ``table_upload``), the fused
-decode tick (``decode``, whose children are ``dispatch`` — until the
-jitted call returns — and ``fetch`` — the tick's one sanctioned device
-sync), what follows the fetch (``account``: cost attribution and the
+decode tick (``decode``: from its launch to the return of its fetch,
+put in by ``span_from`` since a tick may be dispatched ahead of the
+fetch before it and then begins in the pass before its own; under it
+``dispatch`` — until the jitted call returns — and ``fetch`` — the
+tick's one sanctioned device sync), what follows the fetch (``account``: cost attribution and the
 tick's counters), token fanout/detokenize (``emit``), and interleaved
 chunk-prefill grants (``chunk_prefill``: a chunk's host work, the wait
 for the chunk before it and its launch — between the tick's
-``dispatch`` and ``fetch`` while slots decode, so nested in ``decode``
+``dispatch`` and ``fetch`` while slots decode, so under ``decode``
 without being part of what the tick cost) — as a bounded ring of typed
 tick records.  The scheduler's idle backoff (``idle_wait``) is an
 annotation and a lifetime total only: it leaves no ring record, so an
@@ -283,6 +285,9 @@ class NullProfiler:
         return _NULL_LANE
 
     def event(self, name: str, **attrs: Any) -> None:
+        pass
+
+    def span_from(self, name: str, t0: float) -> None:
         pass
 
     def commit(self, slots: int = 0) -> None:
@@ -569,6 +574,22 @@ class TickProfiler:
             parent[5] += cpu_s
         self._spans.append((name, t0, dur_s, max(0.0, dur_s - child_s),
                             max(0.0, cpu_s - child_cpu_s)))
+
+    def span_from(self, name: str, t0: float) -> None:
+        """A span of the open record that began at ``t0`` (a
+        ``perf_counter`` stamp, possibly in an earlier pass) and ends
+        now, beside the stack's spans and outside their nesting: the
+        engine's ``decode``, a tick from its launch to the return of
+        its fetch, which overlaps the next tick's where that one was
+        dispatched ahead of this fetch.  All of its time is that of the
+        phases stamped under it: self-time 0 on both clocks, and no
+        annotation (an annotation is entered and left where it
+        stands)."""
+        now = time.perf_counter()
+        if self._t0 is None:
+            self._t0 = now
+            self._c0 = self._cpu_at(now)
+        self._spans.append((name, t0, now - t0, 0.0, 0.0))
 
     def event(self, name: str, **attrs: Any) -> None:
         """Instant event on the timeline (compile, sanctioned host
@@ -910,8 +931,10 @@ def chrome_trace(by_tier: Dict[str, Dict[str, Any]],
     # every ts of a tier thread is >= 0 (an edge slice that was open
     # when the first kept tick began starts before it); the earliest
     # edge slice where there is neither.
-    stamps = [rec["t0"] for snap in by_tier.values()
-              for rec in snap["records"]]
+    # (a ``decode`` slice begins before its record where its tick was
+    # dispatched in the pass before: ``span_from``.)
+    stamps = [rec["t0"] + min([0.0] + [sp[1] for sp in rec["spans"]]) / 1e3
+              for snap in by_tier.values() for rec in snap["records"]]
     stamps += [ev[1] for snap in by_tier.values() for ev in snap["events"]]
     if not stamps:
         stamps = [s[2] for snap in by_tier.values() for s in snap["edge"]]

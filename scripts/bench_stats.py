@@ -1,7 +1,9 @@
 """Run one benchmark cell (``benchmark/run.py``, same arguments) and,
 before the cluster drains, print what the result line does not carry:
 each tier's GET /stats ``tick`` and ``prefill`` blocks (the resident
-share of PR 36, the riding chunks of PR 32) and, from ``/metrics``
+share of PR 36, the ``ahead_share`` and ``ahead_dead_slot_steps_total``
+of PR 52: ticks dispatched ahead of the fetch before them, of the ticks
+launched over the engine's life; the riding chunks of PR 32) and, from ``/metrics``
 before the traffic and after it, who held the interpreter (PR 41): the
 scheduler's CPU / off-CPU / run-queue milliseconds a tick, every
 phase's self wall beside its self CPU, and the edge lanes' block, by the

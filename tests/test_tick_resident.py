@@ -63,6 +63,7 @@ class TickSpy:
         self.faults = []
         self._last = (None, None)
         self._uploads = dict(engine.prepare_uploads_total)
+        self._ahead = engine.ticks_ahead_total
         engine._decode_fn = self
 
     def __call__(self, params, pool, tables, pos, cur, temps, key):
@@ -71,12 +72,21 @@ class TickSpy:
         uploads = {k: v - self._uploads.get(k, 0) for k, v in now.items()
                    if v != self._uploads.get(k, 0)}
         self._uploads = now
+        # A tick dispatched ahead of the fetch before it (PR 52) starts
+        # where the tick in flight ends: the mirrors stand its steps
+        # behind, and its ``cur`` is no mirror's yet, only that tick's.
+        ahead = e.ticks_ahead_total != self._ahead
+        self._ahead = e.ticks_ahead_total
         call = {"resident": pos is self._last[0] and cur is self._last[1],
-                "uploads": uploads,
+                "uploads": uploads, "ahead": ahead,
                 "live": [s is not None for s in e._slots]}
-        for name, given, mirror in (("pos", pos, e._pos),
-                                    ("cur", cur, e._cur),
-                                    ("temps", temps, e._temps)):
+        if ahead and not (call["resident"] and all(call["live"])):
+            self.faults.append((len(self.calls), "ahead", call))
+        checks = [("pos", pos, e._pos + STEPS * ahead),
+                  ("temps", temps, e._temps)]
+        if not ahead:
+            checks.append(("cur", cur, e._cur))
+        for name, given, mirror in checks:
             if not np.array_equal(np.asarray(given), mirror):
                 self.faults.append((len(self.calls), name,
                                     np.asarray(given).tolist(),
@@ -376,13 +386,16 @@ def test_a_dry_pool_in_the_shadow_falls_back_to_the_pass_own_growth():
         dry = []
         ahead = engine._prepare_ahead
 
-        def watched(active):
+        def watched(active, in_flight=1):
+            # One slot is a full batch: behind a tick dispatched ahead
+            # (PR 52) the shadow looks a tick further.
             slot = engine._slots[active[0]]
-            short = (engine._blocks_needed(active[0], slot, 2 * STEPS)
+            short = (engine._blocks_needed(active[0], slot,
+                                           (in_flight + 1) * STEPS)
                      > len(slot.blocks))
             free = engine.allocator.available
             n = len(slot.blocks)
-            ahead(active)
+            ahead(active, in_flight)
             if short and not free:
                 dry.append(len(slot.blocks) == n)
 
