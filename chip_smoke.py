@@ -670,6 +670,26 @@ def kernel_cases(nq: int, nkv: int, d: int, dtype, *, batch: int = 8,
             return jnp.where(in_group[:, None], y, 0)
         return run
 
+    def ragged_ffn(x, gate, up, down, sizes):
+        u = jax.lax.ragged_dot(x, up, sizes)
+        h = (GP.activation(u) if gate is None else
+             GP.activation(jax.lax.ragged_dot(x, gate, sizes), u))
+        return jax.lax.ragged_dot(h, down, sizes)
+
+    def ffn(impl):
+        """An expert layer's whole FFN ([in, out], activation, [out, in]);
+        only rows in a group compare."""
+        def run(x, up, down, sizes, gate=None):
+            y = impl(x, gate, up, down, sizes)
+            in_group = jnp.arange(x.shape[0]) < jnp.sum(sizes)
+            return jnp.where(in_group[:, None], y, 0)
+        return run
+
+    def gated_experts(*shape):
+        x, up, down, sizes = experts(*shape)
+        gate = rand(keys[3], up.shape) * up.shape[1] ** -0.5
+        return x, up, down, sizes, gate
+
     def scan_args():
         """Time steps in the published range, the last eighth padding
         (0), decays A = -1..-state a channel."""
@@ -703,6 +723,14 @@ def kernel_cases(nq: int, nkv: int, d: int, dtype, *, batch: int = 8,
             up_and_down(jax.lax.ragged_dot),
             lambda shape=shape: experts(*shape))
         for cell, shape in grouped.items()}
+    # An expert layer's whole FFN as ONE call (PR 53) against the chain of
+    # ``ragged_dot`` calls: no gate where the cell's experts have none.
+    for cell, shape in grouped.items():
+        gated = cell != "wide-reasoning"
+        grouped_cases[f"grouped_ffn.{cell}"] = KernelCase(
+            "grouped_product", ffn(GP.grouped_ffn), ffn(ragged_ffn),
+            lambda shape=shape, make=gated_experts if gated else experts:
+            make(*shape))
 
     return {
         "flash_causal_attention": KernelCase(

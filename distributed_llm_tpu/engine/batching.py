@@ -4083,10 +4083,11 @@ class ContinuousBatchingEngine:
 
     def grouped_product_form(self) -> Dict[str, str]:
         """What the tick (``decode``) and the chunk program (``prefill``)
-        were traced with for the routed experts' grouped products:
-        ``pallas`` (ops/grouped_product.py) or ``ragged_dot`` — the
-        static test ``latent_moe._grouped`` makes, on these programs'
-        shapes; a fact of each compiled program, like
+        were traced with for the routed experts' FFN: ``pallas_ffn`` (ONE
+        call a layer, ``ops/grouped_product.py`` ``grouped_ffn``),
+        ``pallas`` (a call of ``grouped_product`` a product) or
+        ``ragged_dot`` — the static test ``latent_moe.expert_ffn`` makes,
+        on these programs' shapes; a fact of each compiled program, like
         ``decode_attention_form``."""
         stacks = models.model_module(self.cfg).expert_stacks(self.params)
         return {stage: models.latent_moe.grouped_product_form(

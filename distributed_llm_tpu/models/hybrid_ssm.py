@@ -896,15 +896,8 @@ def routed_experts(cfg: ModelConfig, lp: Params, x: jax.Array,
         group = jnp.minimum(expert, held - 1)
         xs = xd[order // k]                                    # [T*k, H]
         xs = jnp.pad(xs, ((0, 0), (0, expert_dims_stored(cfg)[0] - h)))
-        a = latent_moe._grouped(xs, mats["we_up"], sizes, group)
-        if cfg.expert_act == "relu2":
-            a = jax.nn.relu(a)
-            a = a * a
-        else:
-            a = jax.nn.silu(latent_moe._grouped(xs, mats["we_gate"], sizes,
-                                                group)) * a
-        y = latent_moe._grouped(a.astype(xd.dtype), mats["we_down"],
-                                sizes, group)[:, :h]
+        y = latent_moe.expert_ffn(xs, mats.get("we_gate"), mats["we_up"],
+                                  mats["we_down"], sizes, group)[:, :h]
         # Rows past the held groups belong to no group: whatever the
         # grouped product left there is not a number of this layer.
         y = jnp.where(here, y, 0)

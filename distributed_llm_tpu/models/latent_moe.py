@@ -369,9 +369,11 @@ def grouped_impl(rows: int, w) -> str:
 
 def _grouped(x, w, sizes, group_of_row):
     """Rows of ``x`` sorted by group, times their group's matrix of
-    ``w`` [G, in, out] (plain or int8).  Rows past the groups' (the
-    hybrid family's absent-expert rows) are zeros from the kernel and
-    whatever ``ragged_dot`` leaves there: the caller masks them."""
+    ``w`` [G, in, out] (plain or int8): ONE product of the chain
+    ``expert_ffn`` runs where the fused call does not serve.  Rows past
+    the groups' (the hybrid family's absent-expert rows) are zeros from
+    the kernel and whatever ``ragged_dot`` leaves there: the caller masks
+    them."""
     if grouped_impl(x.shape[0], w) == "pallas":
         return grouped_product.grouped_product(x, w, sizes)
     if not quant.is_quantized(w):
@@ -380,17 +382,55 @@ def _grouped(x, w, sizes, group_of_row):
     return y * w["s"][:, 0][group_of_row]
 
 
+def ffn_impl(rows: int, gate, up, down) -> str:
+    """What ``expert_ffn`` traces for ``rows`` rows against a layer's
+    experts (``gate`` None where they have none): ``pallas_ffn``, the
+    whole FFN as ONE call of ``grouped_product.grouped_ffn``, or the chain
+    of ``_grouped`` calls (``grouped_impl`` of each).  A test on static
+    shapes and nothing else."""
+    if not any(quant.is_quantized(w) for w in (up, down)):
+        (groups, k, f), n = up.shape, down.shape[2]
+        if grouped_product.serves_ffn(rows, groups, k, f, n, up.dtype,
+                                      gate is not None):
+            return "pallas_ffn"
+    return "+".join(sorted({grouped_impl(rows, w) for w in (gate, up, down)
+                            if w is not None}))
+
+
+def expert_ffn(xs, gate, up, down, sizes, group_of_row):
+    """Rows of ``xs`` sorted by group through their group's expert:
+    ``silu(xs @ gate) * (xs @ up)`` (``relu(xs @ up)^2`` where ``gate`` is
+    None), then ``@ down``; each product and the activation
+    (``grouped_product.activation``: float32, rounded once) rounded to
+    ``xs``'s dtype.  ONE kernel call where ``ffn_impl`` says so, which
+    rounds at the same places: a call of its own a product leaves its
+    first read hidden behind nothing and its last product with nothing in
+    flight, and XLA's small fusion of the activation stands between two
+    (6-10 us a call where a matrix streams in 10-16: PERF.md sections 5
+    and 6, PR 53)."""
+    if ffn_impl(xs.shape[0], gate, up, down) == "pallas_ffn":
+        return grouped_product.grouped_ffn(xs, gate, up, down, sizes)
+    u = _grouped(xs, up, sizes, group_of_row)
+    h = (grouped_product.activation(u) if gate is None else
+         grouped_product.activation(_grouped(xs, gate, sizes, group_of_row),
+                                    u))
+    return _grouped(h, down, sizes, group_of_row)
+
+
 def grouped_product_form(cfg: ModelConfig, stacks, tokens: int) -> str:
     """What a program over ``tokens`` tokens traces its routed experts'
-    products with (GET /stats ``moe.grouped_product``): ``stacks`` the
-    experts' arrays as the tree holds them, each [layers, experts, in,
-    out] or int8 (then a layer at a time, XLA's)."""
+    FFN with (GET /stats ``moe.grouped_product``): ``pallas_ffn`` (one
+    fused call a layer), ``pallas`` (a call a product) or ``ragged_dot``.
+    ``stacks`` the experts' arrays as the tree holds them ((gate,) up,
+    down), each [layers, experts, in, out] or int8 (then a layer at a
+    time, XLA's)."""
     rows = tokens * cfg.experts_per_token
-    return "+".join(sorted({
-        grouped_impl(rows, w if quant.is_quantized(w) else
-                     jax.ShapeDtypeStruct((w.shape[0] * w.shape[1],
-                                           *w.shape[2:]), w.dtype))
-        for w in stacks}))
+    *gate, up, down = (
+        w if quant.is_quantized(w) else
+        jax.ShapeDtypeStruct((w.shape[0] * w.shape[1], *w.shape[2:]),
+                             w.dtype)
+        for w in stacks)
+    return ffn_impl(rows, gate[0] if gate else None, up, down)
 
 
 EXPERT_KEYS = ("we_gate", "we_up", "we_down")
@@ -441,10 +481,8 @@ def routed_experts(cfg: ModelConfig, lp: Params, x: jax.Array,
     with jax.named_scope("moe_experts"):
         expert = flat[order]
         xs = xd[order // k]                                    # [T*k, H]
-        a = _grouped(xs, mats["we_gate"], sizes, expert)
-        u = _grouped(xs, mats["we_up"], sizes, expert)
-        y = _grouped((jax.nn.silu(a) * u).astype(xd.dtype), mats["we_down"],
-                     sizes, expert)
+        y = expert_ffn(xs, mats["we_gate"], mats["we_up"], mats["we_down"],
+                       sizes, expert)
         y = y[jnp.argsort(order)].reshape(t, k, h)
         out = jnp.einsum("tkh,tk->th", y.astype(jnp.float32), w)
     return out.astype(xd.dtype), counts

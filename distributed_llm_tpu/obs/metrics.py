@@ -484,7 +484,9 @@ METRIC_REGISTRY: Tuple[Tuple[str, str, str, Tuple[str, ...], str], ...] = (
      "benchmark's moe.top1_experts_touched_per_step, and through it the "
      "experts' bytes of step.decode_hbm_share_cca_moe and "
      "attn.cca_kv_share_of_step_bytes; the experts' device time is "
-     "moe.grouped_product_share_of_step_ms, from the trace)"),
+     "moe.grouped_product_share_of_step_ms, from the trace: the kernels "
+     "whose names begin with grouped_product, the one fused call a layer "
+     "grouped_product_ffn among them)"),
     ("moe_absent_assignments", "counter",
      "dllm_moe_absent_assignments_total", ("tier", "stage"),
      "Token-to-expert assignments the router made to experts this "

@@ -3,7 +3,9 @@ before the cluster drains, print what the result line does not carry:
 each tier's GET /stats ``tick`` and ``prefill`` blocks (the resident
 share of PR 36, the ``ahead_share`` and ``ahead_dead_slot_steps_total``
 of PR 52: ticks dispatched ahead of the fetch before them, of the ticks
-launched over the engine's life; the riding chunks of PR 32) and, from ``/metrics``
+launched over the engine's life; the riding chunks of PR 32), what each
+stage's program traced its routed experts' FFN with
+(``moe.grouped_product``: PR 53) and, from ``/metrics``
 before the traffic and after it, who held the interpreter (PR 41): the
 scheduler's CPU / off-CPU / run-queue milliseconds a tick, every
 phase's self wall beside its self CPU, and the edge lanes' block, by the
@@ -231,6 +233,9 @@ def _drain_after_stats(self) -> None:
             for key in ("tick", "prefill"):
                 print(f"[bench:stats] tiers.{name}.{key} = "
                       f"{json.dumps(block.get(key))}", flush=True)
+            print(f"[bench:stats] tiers.{name}.moe.grouped_product = "
+                  + json.dumps((block.get("moe") or {}).get(
+                      "grouped_product")), flush=True)
             for key, rows in (("host", HOST), ("edge", EDGE)):
                 print(f"[bench:stats] tiers.{name}.{key} = " + json.dumps(
                     {k: fn(ctx, name) for k, fn in rows}), flush=True)

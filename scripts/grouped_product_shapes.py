@@ -1,7 +1,8 @@
 #!/usr/bin/env python3
 """The grouped product at the shapes the benchmark's routed cells run:
 the Pallas kernel (``ops/grouped_product.py``) against
-``jax.lax.ragged_dot``, on the chip, in one process.
+``jax.lax.ragged_dot``, and an expert layer's whole FFN as ONE call
+(``grouped_ffn``) against the chain of calls, on the chip, in one process.
 
     python3 scripts/grouped_product_shapes.py [--rehearse] [--sweep]
 
@@ -12,8 +13,11 @@ inside ONE program, so that what is timed is the device and not the
 host's dispatch; the median of ``--reps`` timings; GB/s of the touched
 groups' bytes as stored; the largest difference between the two
 implementations' results.  ``--sweep`` also tries other DMA sizes of the
-kernel.  Prints one JSON line a reading and writes
-them to ``chiprun_out/grouped_product_shapes.jsonl``.  ``--rehearse``
+kernel.  Then the FFN both ways (``impl`` ``ffn.chain`` and
+``ffn.fused``: the (gate,) up, activation and down of a layer, ``--pairs``
+layers in one program; us an FFN, GB/s of ALL its touched matrices, the
+largest difference between the two).  Prints one JSON line a reading and
+writes them to ``chiprun_out/grouped_product_shapes.jsonl``.  ``--rehearse``
 (tiny widths, any backend) shows only that the script runs: a rate read
 off the chip is not a rate.
 """
@@ -34,7 +38,11 @@ SHAPES = {
     "reasoned-reply.tick": (32, 5, 64, 23, 32, (3584, 1024)),
     "wide-reasoning.chunk": (1536, 2, 64, 56, 768, (2816, 2048)),
     "reasoned-reply.chunk": (1024, 5, 64, 52, 1024, (3584, 1024)),
+    "context-reasoning.tick": (16, 20, 16, 7, 16, (2048, 2048)),
+    "context-reasoning.chunk": (256, 20, 16, 16, 256, (2048, 2048)),
 }
+# The cells whose experts have no gate (``relu2``).
+UNGATED = "wide-reasoning"
 
 
 def sizes_for(rng, layers, per_layer, touched, rows_in, layer):
@@ -48,6 +56,19 @@ def sizes_for(rng, layers, per_layer, touched, rows_in, layer):
     for g in rng.choice(ids, rows_in - touched, p=weights):
         sizes[g] += 1
     return sizes
+
+
+def median_seconds(run, args, reps: int) -> float:
+    """The median of ``reps`` timings of ``run(*args)``, after one call
+    that compiles it."""
+    import jax
+    jax.block_until_ready(run(*args))
+    times = []
+    for _ in range(reps):
+        t0 = time.perf_counter()
+        jax.block_until_ready(run(*args))
+        times.append(time.perf_counter() - t0)
+    return statistics.median(times)
 
 
 def main() -> int:
@@ -84,17 +105,21 @@ def main() -> int:
         if args.only and args.only not in name:
             continue
         if args.rehearse:
-            k, n, layers = k // 16 // 16 * 16, n // 16 // 128 * 128 or 128, 2
+            k, n, layers = 128, n // 16 // 128 * 128 or 128, 2
         groups = layers * per
         sizes = jnp.asarray(sizes_for(rng, layers, per, touched, rows_in,
                                       layers // 2))
         keys = jax.random.split(jax.random.PRNGKey(34), 3)
         x = jax.random.normal(keys[0], (rows, k), jnp.float32
                               ).astype(jnp.bfloat16)
-        up = (jax.random.normal(keys[1], (groups, k, n), jnp.float32)
-              * k ** -0.5).astype(jnp.bfloat16)
-        down = (jax.random.normal(keys[2], (groups, n, k), jnp.float32)
-                * n ** -0.5).astype(jnp.bfloat16)
+
+        @functools.partial(jax.jit, static_argnums=(1, 2))
+        def matrices(key, k, n):        # no float32 copy of 2.7 GB
+            return (jax.random.normal(key, (groups, k, n), jnp.float32)
+                    * k ** -0.5).astype(jnp.bfloat16)
+
+        up = down = gate = None         # the shape before's, freed
+        up, down = matrices(keys[1], k, n), matrices(keys[2], n, k)
         touched_bytes = 2 * touched * k * n * 2      # up + down, bfloat16
 
         def program(product):
@@ -122,18 +147,61 @@ def main() -> int:
                 line["max_abs_diff_to_ragged_dot"] = float(
                     np.max(np.abs(got - want)))
                 line["max_abs"] = float(np.max(np.abs(want)))
-                run = program(product)
-                jax.block_until_ready(run(x, up, down, sizes))
-                times = []
-                for _ in range(args.reps):
-                    t0 = time.perf_counter()
-                    jax.block_until_ready(run(x, up, down, sizes))
-                    times.append(time.perf_counter() - t0)
-                per_pair = statistics.median(times) / args.pairs
+                per_pair = median_seconds(
+                    program(product), (x, up, down, sizes),
+                    args.reps) / args.pairs
                 line["ms_a_product"] = per_pair / 2 * 1e3
                 line["gb_per_s_touched"] = touched_bytes / per_pair / 1e9
             except Exception as e:                   # a variant the
                 line["error"] = str(e)[:400]         # compiler refuses
+            print(json.dumps(line), flush=True)
+            lines.append(line)
+
+        # The layer's whole FFN: the chain of calls (what the models ran
+        # before PR 53, and run where the one call does not serve), and
+        # the one call.
+        gated = UNGATED not in name
+        gate = (matrices(jax.random.fold_in(keys[1], 1), k, n) if gated
+                else None)
+
+        def chain(h, gate, up, down, sizes):
+            u = GP.grouped_product(h, up, sizes)
+            a = (GP.activation(u) if gate is None else
+                 GP.activation(GP.grouped_product(h, gate, sizes), u))
+            return GP.grouped_product(a, down, sizes)
+
+        def layers_of(ffn):
+            def run(x, gate, up, down, sizes):
+                def layer(_, h):        # a residual keeps the numbers sane
+                    return x + ffn(h, gate, up, down, sizes) * 0.125
+                return jax.lax.fori_loop(0, args.pairs, layer, x)
+            return jax.jit(run)
+
+        ffns = {"ffn.chain": chain, "ffn.fused": GP.grouped_ffn}
+        want = None
+        for impl, ffn in ffns.items():
+            line = {"shape": name, "rows": rows, "groups": groups,
+                    "touched": touched, "in": k, "F": n, "gated": gated,
+                    "impl": impl,
+                    "device": f"{dev.platform}:{dev.device_kind}",
+                    "serves_ffn": GP.serves_ffn(rows, groups, k, n, k,
+                                                jnp.bfloat16, gated)}
+            try:
+                got = np.asarray(jax.jit(ffn)(x, gate, up, down, sizes),
+                                 np.float32)
+                if want is None:
+                    want = got
+                line["max_abs_diff_to_chain"] = float(
+                    np.max(np.abs(got - want)))
+                line["max_abs"] = float(np.max(np.abs(want)))
+                per_ffn = median_seconds(
+                    layers_of(ffn), (x, gate, up, down, sizes),
+                    args.reps) / args.pairs
+                line["us_an_ffn"] = per_ffn * 1e6
+                line["gb_per_s_touched"] = ((2 + gated) * touched * k * n * 2
+                                            / per_ffn / 1e9)
+            except Exception as e:
+                line["error"] = str(e)[:400]
             print(json.dumps(line), flush=True)
             lines.append(line)
     with open(os.path.join(out_dir, "grouped_product_shapes.jsonl"),

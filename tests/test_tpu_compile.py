@@ -47,6 +47,10 @@ KERNELS = ["flash_causal_attention",
            # the decode ticks' rows and the widths the cells store.
            "grouped_product.wide-reasoning",
            "grouped_product.reasoned-reply",
+           # An expert layer's whole FFN as ONE call (ISSUE 53): no gate
+           # at wide-reasoning's widths, gated at reasoned-reply's.
+           "grouped_ffn.wide-reasoning",
+           "grouped_ffn.reasoned-reply",
            # Mamba-1's recurrence over a chunk (float32 and headless: the
            # same case under every preset), at the shared-K/V cell's chunk.
            "ssm_chunk_scan"]
@@ -361,15 +365,16 @@ def test_pool_program_leaves_the_pool_in_place(one_chip, as_on_tpu,
 
 # -- the routed experts stay where they rest (ISSUE 34) ------------------------
 
-# configuration: (grouped products in the tick's one layer body, the
-# stacked experts of one key in GB, temporaries allowed in GB).  PR 33's
+# configuration: (kernel calls in the tick's one layer body: ONE an expert
+# layer since ISSUE 53, the stacked experts of one key in GB, temporaries
+# allowed in GB).  PR 33's
 # trap: at a minor width off the lanes the entry of every program copied
 # every held expert ([2, 64, 2688, 1856] x 3: 4.36 GB of temporaries).
 ROUTED_TICKS = {
-    "xing4.0-29b-a4b": (3, 2.35, 0.5),
-    "nemotron-3-nano-30b-a3b": (6, 1.48, 0.5),
+    "xing4.0-29b-a4b": (1, 2.35, 0.5),
+    "nemotron-3-nano-30b-a3b": (3, 1.48, 0.5),
     # Top-1 of 16 gated experts of 2048 x 2048 over 20 layers (PR 51).
-    "zaya1-8b": (3, 2.68, 0.5),
+    "zaya1-8b": (1, 2.68, 0.5),
 }
 
 
@@ -377,14 +382,15 @@ ROUTED_TICKS = {
 def test_routed_tick_reads_the_experts_where_they_rest(one_chip, as_on_tpu,
                                                        monkeypatch, config):
     """The decode ticks of the benchmark's routed-expert
-    configurations, at their real sizes: every grouped product of the
-    layer body is the repo's kernel, handed the STACKED experts whole —
-    no copy of them at the program's entry, no per-layer slice: the
-    temporaries stay far under one key's stack."""
+    configurations, at their real sizes: every expert layer of the
+    layer body is ONE call of the repo's kernel, handed the STACKED
+    experts whole — no copy of them at the program's entry, no per-layer
+    slice: the temporaries stay far under one key's stack."""
     products, stack_gb, temp_limit_gb = ROUTED_TICKS[config]
     tier = _bench_tier(monkeypatch, config)
     engine, _, compiled, _ = _pool_program(one_chip, tier, ("decode", 256))
-    assert engine.grouped_product_form()["decode"] == "pallas"
+    assert engine.grouped_product_form() == {"decode": "pallas_ffn",
+                                            "prefill": "pallas_ffn"}
     # The hybrid family's attention layers are GQA 32/2 at head 128: rows
     # of 512 B, which ``rows_attention.serves`` leaves to the XLA form
     # (ISSUE 45); the latent family attends in code of its own.
@@ -392,7 +398,7 @@ def test_routed_tick_reads_the_experts_where_they_rest(one_chip, as_on_tpu,
         "latent" if engine.cfg.latent else "merged")
     text = compiled.as_text()
     assert text.count("tpu_custom_call") == products
-    assert "grouped_product" in text and "ragged-dot" not in text
+    assert "grouped_product_ffn" in text and "ragged-dot" not in text
     temp_gb = compiled.memory_analysis().temp_size_in_bytes / GB
     assert temp_gb < temp_limit_gb < stack_gb, temp_gb
     # The kernel is one operation of the layer body: the tick keeps the
