@@ -203,9 +203,16 @@ class ModelConfig:
     # convolutional attention (paged K/V AND a tail row a slot: the last
     # inputs of its two convolutions and of its shifted value); EACH layer
     # is ONE pre-norm mixer (a mixer-then-MLP layer is two characters,
-    # "M-"; attention then experts "CE").
+    # "M-"; attention then experts "CE"), "K" delta-rule linear attention
+    # with a decay a channel (KDA: ``ssm_heads`` heads whose keys and
+    # values are ``ssm_head_dim`` wide, three convs of ``ssm_conv`` taps;
+    # a float32 MATRIX state a head and the convs' tails a slot), "L"
+    # latent attention WITHOUT rotary over one paged row of
+    # ``kv_lora_rank + qk_rope_head_dim`` numbers a token (models/
+    # latent_moe.py's attention; ``q_lora_rank`` 0: queries by one matrix).
     # The layer loop scans the pattern's shortest repeating period
-    # (``layer_period``).  The head is tied where ``tie_embeddings``.
+    # (``layer_period``) after the single sublayers that lead it
+    # (``layer_lead``).  The head is tied where ``tie_embeddings``.
     layer_pattern: str = ""
     # Mamba-2: ``ssm_heads`` heads of ``ssm_head_dim`` channels, a state
     # of ``ssm_state`` numbers a channel, B and C shared by groups of
@@ -270,20 +277,24 @@ class ModelConfig:
 
     @property
     def family(self) -> str:
-        """The model family, by what selects it: "latent"
-        (models/latent_moe.py: a latent cache row), "shared_kv"
-        (models/shared_kv_hybrid.py: an "F" in ``layer_pattern``),
-        "hybrid" (models/hybrid_ssm.py: any other ``layer_pattern``, of
-        Mamba-2 or Mamba-1 rows beside paged attention layers that each
-        own their K/V, or attention layers that own a tail row besides,
-        experts or dense MLPs), else "dense"
-        (transformer.py, moe.py).  What differs by family
-        dispatches on this one name."""
-        if self.kv_lora_rank > 0:
-            return "latent"
+        """The model family, by what selects it, tested in this order:
+        a ``layer_pattern`` makes a ROW family — "shared_kv"
+        (models/shared_kv_hybrid.py: an "F" in it), else "hybrid"
+        (models/hybrid_ssm.py: Mamba-2 or Mamba-1 rows beside paged
+        attention layers that each own their K/V, attention layers that
+        own a tail row besides, linear-attention rows of a matrix state
+        beside latent attention over a paged latent row, experts or
+        dense MLPs) — whatever else is set: a pattern with "L" states
+        ``kv_lora_rank`` too, and is no less a row family for it.
+        Without a pattern, "latent" (models/latent_moe.py: every layer
+        caches a latent row, ``kv_lora_rank > 0``), else "dense"
+        (transformer.py, moe.py).  What differs by family dispatches on
+        this one name."""
         if "F" in self.layer_pattern:
             return "shared_kv"
-        return "hybrid" if self.layer_pattern else "dense"
+        if self.layer_pattern:
+            return "hybrid"
+        return "latent" if self.kv_lora_rank > 0 else "dense"
 
     @property
     def latent(self) -> bool:
@@ -322,14 +333,28 @@ class ModelConfig:
         return tuple(out)
 
     @property
+    def layer_lead(self) -> str:
+        """The sublayers models/hybrid_ssm.py runs inline AHEAD of its one
+        loop: where the pattern is single sublayers and then one segment
+        that repeats to the end ("K-" before "KEKELEKE" x 2: a model's
+        dense lead layer), those singles; else none."""
+        segments = self.layer_segments
+        if (len(segments) > 1 and segments[-1][1] > 1
+                and all(reps == 1 for _, reps in segments[:-1])):
+            return "".join(period for period, _ in segments[:-1])
+        return ""
+
+    @property
     def layer_period(self) -> str:
-        """What models/hybrid_ssm.py scans, its whole depth ONE loop: the
-        period of a pattern that is one segment, else (nothing repeats
-        all the way down) the pattern itself — the shortest string whose
+        """What models/hybrid_ssm.py scans, its depth after
+        ``layer_lead`` ONE loop: the period of a pattern that is one
+        segment (or a lead and one segment), else (nothing repeats all
+        the way down) the pattern itself — the shortest string whose
         repetition is ``layer_pattern``."""
         segments = self.layer_segments
-        return (segments[0][0] if len(segments) == 1
-                else self.layer_pattern)
+        if len(segments) == 1 or self.layer_lead:
+            return segments[-1][0]
+        return self.layer_pattern
 
     def layers_of(self, kind: str) -> int:
         """Layers of one kind ("M", "*", "E", ...) in ``layer_pattern``."""
@@ -339,11 +364,12 @@ class ModelConfig:
     def kv_layers(self) -> int:
         """Layers whose K/V the paged pool holds by position: every layer,
         or the kinds of a row family that own K/V: the hybrid family's
-        attention layers ("*", and "C", which owns a tail row a slot
-        besides), the shared-K/V family's ONE "F" (its "W" keeps a ring a
-        slot and its "X" reads "F"'s)."""
+        attention layers ("*", "C", which owns a tail row a slot besides,
+        and "L", whose one array holds latent rows), the shared-K/V
+        family's ONE "F" (its "W" keeps a ring a slot and its "X" reads
+        "F"'s)."""
         if self.hybrid:
-            return sum(self.layers_of(kind) for kind in "*CF")
+            return sum(self.layers_of(kind) for kind in "*CFL")
         return self.num_layers
 
     @property
@@ -366,7 +392,10 @@ class ModelConfig:
     @property
     def ssm_conv_width(self) -> int:
         """Channels the conv runs over: x, B and C side by side
-        (Mamba-1, ``ssm_dt_rank`` > 0: x alone)."""
+        (Mamba-1, ``ssm_dt_rank`` > 0: x alone; "K": the three convs of
+        q, k and v side by side)."""
+        if "K" in self.layer_pattern:
+            return 3 * self.ssm_inner
         if self.ssm_dt_rank:
             return self.ssm_inner
         return self.ssm_inner + 2 * self.ssm_groups * self.ssm_state
@@ -375,8 +404,8 @@ class ModelConfig:
     def cache_row_width(self) -> int:
         """Numbers one position keeps in one array of the paged pool, a
         layer: K (and V) rows of all kv heads, or the one head-less
-        latent row."""
-        if self.latent:
+        latent row (the latent family's, and the hybrid family's "L")."""
+        if self.kv_lora_rank:
             return self.kv_lora_rank + self.qk_rope_head_dim
         return self.num_kv_heads * self.head_dim
 
@@ -481,6 +510,22 @@ MODEL_PRESETS: Dict[str, ModelConfig] = {
         attn_head_dim=16, max_seq_len=256, rotary=True, qk_rope_head_dim=8,
         layer_pattern="CE" * 3, num_experts=4, experts_per_token=1,
         moe_ffn_size=32, router_hidden=16, expert_act="swiglu",
+    ),
+    # The hybrid family's fourth pattern at unit-test size: a lead "K-",
+    # then two periods of "KEKELEKE" — delta-rule linear attention with a
+    # decay a channel (4 heads of 16, a matrix state and three conv tails
+    # a slot) three layers in four, latent attention without rotary over
+    # a 24 + 8 wide paged row in the fourth; top-3 of 8 sigmoid-routed
+    # gated experts of which this share holds 4, one gated shared expert.
+    "hybrid_kda_test": ModelConfig(
+        name="hybrid_kda_test", tokenizer="byte", vocab_size=512,
+        hidden_size=64, num_layers=18, num_heads=4, num_kv_heads=4,
+        ffn_size=96, max_seq_len=256, tie_embeddings=False, rotary=False,
+        layer_pattern="K-" + "KEKELEKE" * 2, ssm_heads=4, ssm_head_dim=16,
+        ssm_conv=4, kv_lora_rank=24, qk_nope_head_dim=16,
+        qk_rope_head_dim=8, v_head_dim=16, num_experts=8, experts_first=0,
+        experts_count=4, moe_ffn_size=32, shared_ffn_size=32,
+        experts_per_token=3, router_scale=2.446, expert_act="swiglu",
     ),
     # The state-space / window-attention / shared-K/V family at unit-test
     # size (models/shared_kv_hybrid.py): 3 x "MW", "M", "F", 2 x "GX";

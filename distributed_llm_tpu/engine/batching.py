@@ -1083,8 +1083,9 @@ class ContinuousBatchingEngine:
         ``ops.attention.merged_decode_attention``
         (``ops.attention.decode_form`` is the rule between the two),
         ``split`` a tp hook over a layer's head-major view, ``latent``
-        the latent family's own absorbed attention."""
-        if self.cfg.latent:
+        the latent family's own absorbed attention (the hybrid family's
+        "L" layers' too: whoever caches a latent row)."""
+        if self.cfg.kv_lora_rank:
             return "latent"
         if self.cfg.shared_kv:
             return "merged"        # its own (shared_kv_hybrid.diff_merged)
@@ -1565,18 +1566,20 @@ class ContinuousBatchingEngine:
     def state_stats(self) -> Optional[Dict[str, int]]:
         """The recurrent rows (GET /stats ``state``), or None for a model
         without them: the kind of mixer that owns them (``cca_tail``: the
-        "C" attention layers' tail rows, with no state) and its layers,
-        how many rows there are, how many name a sequence, what one
-        holds, how many sequences started one from zero, and the K/V
-        beside them."""
+        "C" attention layers' tail rows, with no state; ``kda``: the
+        linear-attention layers' matrix a head and three conv tails) and
+        its layers, how many rows there are, how many name a sequence,
+        what one holds, how many sequences started one from zero, and the
+        K/V (under ``kda`` the latent layers' one row a token) beside
+        them."""
         if self._state_owner is None:
             return None
         from ..utils.roofline import (kv_bytes_per_pos, ring_row_bytes,
                                       state_row_bytes)
-        tails = self.cfg.layers_of("C")
-        out = {"mixer": ("cca_tail" if tails else
+        tails, kda = self.cfg.layers_of("C"), self.cfg.layers_of("K")
+        out = {"mixer": ("cca_tail" if tails else "kda" if kda else
                          "mamba1" if self.cfg.ssm_dt_rank else "mamba2"),
-               "layers": tails or self.cfg.layers_of("M"),
+               "layers": tails or kda or self.cfg.layers_of("M"),
                "rows": int(self._state_owner.size),
                "rows_in_use": int(np.count_nonzero(self._rows_owned())),
                "row_bytes": int(state_row_bytes(self.cfg)),

@@ -47,7 +47,8 @@ functions hand it the cells to write and the tables to read, and the
 block programs (``copy_block``, ``gather_blocks``, ``scatter_blocks``)
 work on whatever arrays the pool has.  The hybrid family's
 (models/hybrid_ssm.py) pool holds K/V blocks for its ATTENTION layers
-("*", and "C", whose tail row a slot is ``"t"`` with ``"s"`` empty)
+("*", and "C", whose tail row a slot is ``"t"`` with ``"s"`` empty; or
+"L"'s latent rows, ``"c"`` alone)
 only and, beside them, one recurrent row a slot a state-space layer
 (``"s"``, ``"t"``) with the vector that says whose each row is
 (``"owner"``): rows are not blocks, so the block programs refuse that
@@ -134,17 +135,26 @@ def init_pool(cfg: ModelConfig, pcfg: PagedConfig,
         # and a ROW a slot a layer that keeps one: a state-space layer's
         # float32 state — Mamba-1's [state, inner] (channels on the
         # lanes), Mamba-2's [heads, P, N] — and its conv tail in the
-        # model's dtype; a "C" layer's tail alone (one row of the last
-        # token's inputs to its convolutions and its shifted value), the
-        # state then empty.  "k" first: ``_block_size`` reads the first
-        # array.
-        n_m, n_c, r = cfg.layers_of("M"), cfg.layers_of("C"), pcfg.max_slots
-        state = ((cfg.ssm_state, cfg.ssm_inner) if cfg.ssm_dt_rank else
+        # model's dtype; a linear-attention layer's ("K") float32 MATRIX a
+        # head [heads, d_k, d_v] and the tails of its three convs side by
+        # side; a "C" layer's tail alone (one row of the last token's
+        # inputs to its convolutions and its shifted value), the state
+        # then empty.  A pattern with "L" pages ONE array of latent rows,
+        # "c", where "*" and "C" page "k" and "v".  The paged array
+        # first: ``_block_size`` reads the first array.
+        n_c, r = cfg.layers_of("C"), pcfg.max_slots
+        n_m = cfg.layers_of("M") + cfg.layers_of("K")
+        state = ((cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_head_dim)
+                 if cfg.layers_of("K") else
+                 (cfg.ssm_state, cfg.ssm_inner) if cfg.ssm_dt_rank else
                  (cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state))
         tail = ((n_c, r, 1, cfg.cca_tail_width) if n_c else
                 (n_m, r, cfg.ssm_conv - 1, cfg.ssm_conv_width))
         kv = (cfg.kv_layers,) + shape[1:]
-        pool = {"k": jnp.zeros(kv, dtype), "v": jnp.zeros(kv, dtype)}
+        if cfg.layers_of("L"):
+            pool = {"c": jnp.zeros(kv, dtype)}
+        else:
+            pool = {"k": jnp.zeros(kv, dtype), "v": jnp.zeros(kv, dtype)}
         if cfg.shared_kv:
             # A ring of the window's positions a slot a window layer:
             # exactly the window (models/shared_kv_hybrid.py).

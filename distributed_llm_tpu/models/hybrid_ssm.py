@@ -55,6 +55,27 @@ copy of the Mamba-1 mixer (``mamba1`` and what it calls).
   NEXT token needs of this one — the two convolutions' inputs and the
   shifted value — is a ROW of ``pool["t"]`` a slot, as a state-space
   layer's conv tail is, with no state ``pool["s"]`` at all.
+- ``K``, **delta-rule linear attention with a decay a channel** (Kimi
+  Delta Attention, ``_kda``): ``ssm_heads`` heads whose keys and values
+  are ``ssm_head_dim`` wide.  Queries, keys and values each go through a
+  causal depthwise conv of ``ssm_conv`` taps (no bias) and silu; queries
+  and keys are normalised to unit length a head (the queries then times
+  ``d^-1/2``).  A head keeps a MATRIX ``S [d_k, d_v]``, float32: ``S' =
+  Diag(exp g_t) S_{t-1}``, ``S_t = S' + beta_t k_t (v_t - S'^T k_t)^T``,
+  ``o_t = S_t^T q_t``, with ``g_t = -exp(A_log[h]) softplus(W_fb W_fa x +
+  dt_bias)`` a log-decay a CHANNEL of the keys and ``beta_t =
+  sigmoid(w_b[h] . x)``; then an RMSNorm a head with one gain, times
+  ``sigmoid(W_gb W_ga x)``, ``W_o``.  A sequence keeps ``S`` and the
+  three convs' last ``ssm_conv - 1`` inputs (q, k and v side by side) a
+  layer: a ROW of ``pool["s"]`` / ``pool["t"]`` like ``M``'s.  The
+  chunk's recurrence is in matrix form (``kda_scan``).
+- ``L``, **latent attention without rotary**: ``latent_moe._attend``, the
+  latent family's own, over ONE paged row a token of ``kv_lora_rank``
+  normalised latent numbers and ``qk_rope_head_dim`` numbers shared by
+  every head, written and read UNROTATED (position comes from the ``K``
+  layers); queries by one matrix.  A pattern with ``L`` pages
+  ``pool["c"]`` [``L`` layers, NB, bs, row] where ``*`` and ``C`` page
+  ``"k"`` and ``"v"``.
 
 A pattern with ``C`` scales the residual's merge in every sublayer, ``x
 <- (a_r x + b_r) + (a_o mixer(..) + b_o)``, four vectors a sublayer
@@ -76,7 +97,10 @@ lookup only ever finds.
 
 ONE body a layer kind serves the chunk program and the decode tick; the
 layer loop is a ``scan`` over PERIODS of the pattern with the period's
-kinds inline, and nothing in a body lowers to a loop (the benchmark tells
+kinds inline — after the single sublayers that lead the pattern
+(``cfg.layer_lead``: a model's dense lead layer, "K-"), which run inline
+ahead of it so that the scan stays the program's one layer loop — and
+nothing in a body lowers to a loop (the benchmark tells
 a decode tick from a prefill program by how deep its ``while``s nest): the
 chunk's recurrence is in matrix form — one block, quadratic in the chunk
 length, made for chunks of a few hundred tokens.
@@ -97,7 +121,7 @@ from .latent_moe import (EMBED_STD, HIGHEST, ROUTER_BIAS_STD, WEIGHT_STD,
                          init_normal, init_table)
 
 Params = Dict[str, Any]
-KINDS = "M*E-C"
+KINDS = "M*E-CKL"
 # The routed experts' matrices as a tree may hold them: all three where
 # the experts are gated, the last two where not.
 EXPERT_KEYS = ("we_gate", "we_up", "we_down")
@@ -171,9 +195,23 @@ def check(cfg: ModelConfig) -> None:
                 f"{cfg.name}: the pool's K/V layers and its tail rows are "
                 f"indexed by the ONE kind that owns them: a pattern with "
                 f"'C' has no 'M' and no '*'")
-    elif "*" in cfg.layer_pattern and cfg.rotary:
-        raise ValueError(f"{cfg.name}: a pattern with '*' states rotary "
-                         f"False: that kind applies no rotary embedding")
+    elif set(cfg.layer_pattern) & set("*L") and cfg.rotary:
+        raise ValueError(f"{cfg.name}: a pattern with '*' or 'L' states "
+                         f"rotary False: those kinds apply no rotary "
+                         f"embedding")
+    # The rows and the paged arrays are indexed by the ONE kind that owns
+    # them: "K" rows beside no "M" or "C", "L"'s latent array beside no
+    # "*" or "C"; a latent row's widths come with "L" and only with it.
+    if (set(cfg.layer_pattern) >= set("KM")
+            or set(cfg.layer_pattern) >= set("KC")
+            or ("L" in cfg.layer_pattern
+                and set(cfg.layer_pattern) & set("*C"))
+            or ("L" in cfg.layer_pattern) != (cfg.kv_lora_rank > 0)
+            or ("L" in cfg.layer_pattern and cfg.q_lora_rank)):
+        raise ValueError(
+            f"{cfg.name}: 'K' rows stand beside no 'M' or 'C', 'L' pages "
+            f"its latent row (kv_lora_rank > 0, which only a pattern "
+            f"with 'L' states; q_lora_rank 0) beside no '*' or 'C'")
     if "E" not in cfg.layer_pattern:
         return
     if not 0 <= cfg.experts_first <= cfg.num_experts - cfg.experts_held:
@@ -263,6 +301,39 @@ def init_layer(cfg: ModelConfig, key, kind: str) -> Params:
                   wk=init_normal(ks[1], (h, nkv * d), dtype),
                   wv=init_normal(ks[2], (h, nkv * d), dtype),
                   wo=init_normal(ks[3], (nq * d, h), dtype))
+    elif kind == "K":
+        nh, d, k = cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_conv
+        di = nh * d
+        k_fa, k_fb, k_ga, k_gb, k_beta, k_conv = jax.random.split(ks[4], 6)
+        # The published layer's init (fla's KimiDeltaAttention): A uniform
+        # in [1, 16] a head, the time step's bias as both Mamba forms
+        # draw it — a channel's decay then spans a token to a thousand;
+        # the three convs' taps at the framework's default, no bias.
+        lp.update(wq=init_normal(ks[0], (h, di), dtype),
+                  wk=init_normal(ks[1], (h, di), dtype),
+                  wv=init_normal(ks[2], (h, di), dtype),
+                  wo=init_normal(ks[3], (di, h), dtype),
+                  # [q | k | v] channels side by side, as the tail rests.
+                  conv_w=init_uniform(k_conv, (k, 3 * di), dtype, k ** -0.5),
+                  w_fa=init_normal(k_fa, (h, d), dtype),
+                  w_fb=init_normal(k_fb, (d, di), dtype),
+                  dt_bias=init_dt_bias(cfg, ks[5], di),
+                  a_log=jnp.log(jax.random.uniform(ks[6], (nh,), jnp.float32,
+                                                   1.0, 16.0)),
+                  w_beta=init_normal(k_beta, (h, nh), dtype),
+                  w_ga=init_normal(k_ga, (h, d), dtype),
+                  w_gb=init_normal(k_gb, (d, di), dtype),
+                  gn=jnp.ones((d,), dtype))
+    elif kind == "L":
+        nh, dc = cfg.num_heads, cfg.kv_lora_rank
+        dn, dr, dv = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
+                      cfg.v_head_dim)
+        # ``latent_moe._attend``'s names, with ONE query matrix.
+        lp.update(wq=init_normal(ks[0], (h, nh * (dn + dr)), dtype),
+                  w_kva=init_normal(ks[1], (h, dc + dr), dtype),
+                  kv_ln=jnp.ones((dc,), dtype),
+                  w_kvb=init_normal(ks[2], (dc, nh * (dn + dv)), dtype),
+                  wo=init_normal(ks[3], (nh * dv, h), dtype))
     elif kind == "C":
         d, nq, nkv = cfg.head_dim, cfg.num_heads, cfg.num_kv_heads
         c = (nq + nkv) * d
@@ -324,9 +395,12 @@ def init_layer(cfg: ModelConfig, key, kind: str) -> Params:
         else:
             lp.update(router=init_normal(ks[0], (h, e), dtype))
         if cfg.shared_ffn_size:
+            # Of the experts' own form: gated where they are.
             fs = cfg.shared_ffn_size
             lp.update(ws_up=init_normal(ks[4], (h, fs), dtype),
                       ws_down=init_normal(ks[5], (fs, h), dtype))
+            if cfg.expert_act == "swiglu":
+                lp.update(ws_gate=init_normal(ks[7], (h, fs), dtype))
     if "C" in cfg.layer_pattern:
         # The scaled merge of every sublayer of such a pattern: (a_r, b_r,
         # a_o, b_o), the gains drawn about 1 and the offsets about 0.
@@ -341,12 +415,14 @@ def init_params(cfg: ModelConfig, seed=0) -> Params:
     """``seed`` may be traced (jit this with the seed as an ARGUMENT: one
     compiled program makes every seed's weights).  ``periods[j]`` holds
     position ``j`` of the period for every period, stacked — what the
-    layer loop scans; layer ``l`` draws from key ``l`` of ``num_layers``."""
+    layer loop scans; ``lead[j]`` sublayer ``j`` of ``cfg.layer_lead``,
+    one layer each; layer ``l`` draws from key ``l`` of ``num_layers``."""
     check(cfg)
     dtype = jnp.dtype(cfg.dtype)
     k_embed, k_head, k_layers = jax.random.split(jax.random.PRNGKey(seed), 3)
     lkeys = jax.random.split(k_layers, cfg.num_layers)
-    period = cfg.layer_period
+    lead, period = cfg.layer_lead, cfg.layer_period
+    lead_keys, lkeys = lkeys[:len(lead)], lkeys[len(lead):]
     params = {
         "embed": init_table(k_embed, cfg.vocab_size, cfg.hidden_size, dtype,
                             WEIGHT_STD if "C" in cfg.layer_pattern
@@ -356,6 +432,9 @@ def init_params(cfg: ModelConfig, seed=0) -> Params:
                                 lkeys[j::len(period)])
                     for j, kind in enumerate(period)],
     }
+    if lead:
+        params["lead"] = [init_layer(cfg, lead_keys[j], kind)
+                          for j, kind in enumerate(lead)]
     if not cfg.tie_embeddings:
         params["head"] = init_table(k_head, cfg.vocab_size, cfg.hidden_size,
                                     dtype)
@@ -383,6 +462,60 @@ def rows_of(owner: jax.Array, first_blocks: jax.Array):
     named = (owner[:, None] == first_blocks[None, :]) & (owner[:, None] != 0)
     return (jnp.argmax(named, axis=1), jnp.any(named, axis=1),
             jnp.argmax(named, axis=0))
+
+
+# =============================================================================
+# The causal depthwise conv over a row's tail: every kind's that keeps one
+# =============================================================================
+
+def conv_step(lp: Params, tail, a, valid):
+    """One token a ROW through the conv and silu: a [R, C] the row's
+    token, tail [R, K-1, C] the inputs before it, valid [R].  Returns (u
+    [R, C] float32, tail); a row that is not ``valid`` keeps its tail
+    bit-identical.  The bias where the layer holds one."""
+    window = jnp.concatenate([tail, a[:, None]], axis=1)         # [R, K, C]
+    u = jnp.sum(window.astype(jnp.float32)
+                * lp["conv_w"].astype(jnp.float32), axis=1)
+    if "conv_b" in lp:
+        u = u + lp["conv_b"].astype(jnp.float32)
+    return jax.nn.silu(u), jnp.where(valid[:, None, None], window[:, 1:],
+                                     tail)
+
+
+def conv_chunk(lp: Params, tail, a, n_valid):
+    """A CHUNK of one sequence through the same: a [S, C] from ``tail``
+    [K-1, C]; the tail that comes back is the last ``K - 1`` VALID rows'
+    (positions ``>= n_valid`` are right padding).  Returns (u [S, C]
+    float32, tail)."""
+    s_c, k = a.shape[0], tail.shape[0] + 1
+    seq = jnp.concatenate([tail, a], axis=0)                     # [S+K-1, C]
+    w = lp["conv_w"].astype(jnp.float32)
+    u = sum(seq[j:j + s_c].astype(jnp.float32) * w[j] for j in range(k))
+    if "conv_b" in lp:
+        u = u + lp["conv_b"].astype(jnp.float32)
+    return (jax.nn.silu(u),
+            jax.lax.dynamic_slice_in_dim(seq, n_valid, k - 1, axis=0))
+
+
+def through_rows(pool, li, ctx, scan, step):
+    """Layer ``li``'s state and tail through a row kind's recurrence: a
+    chunk of one sequence takes its row (zeros where the sequence is
+    fresh) through ``scan(state, tail, n_valid)``, a decode step takes
+    every row through ``step(src, state, tail, valid)`` (``src`` the batch
+    index a row reads its token from); both give (y, state, tail).
+    Returns (y [B, S, width], the pool with the rows written back)."""
+    s_all, t_all = pool["s"], pool["t"]
+    if "row" in ctx:                               # a chunk of one sequence
+        row, fresh = ctx["row"], ctx["fresh"]
+        state = jnp.where(fresh, 0.0, s_all[li, row])
+        tail = jnp.where(fresh, jnp.zeros((), t_all.dtype), t_all[li, row])
+        y, state, tail = scan(state, tail, ctx["n_valid"])
+        return y[None], {**pool, "s": s_all.at[li, row].set(state),
+                         "t": t_all.at[li, row].set(tail)}
+    src, valid, dst = ctx["rows"]                  # a decode step, by rows
+    y, state, tail = step(src, s_all[li], t_all[li], valid)
+    return y[dst][:, None], {**pool, "s": s_all.at[li].set(state),
+                             "t": t_all.at[li].set(tail)}
 
 
 # =============================================================================
@@ -450,11 +583,7 @@ def mamba1_step(cfg: ModelConfig, lp: Params, a, state, tail, valid):
     inner], valid [R].  Returns (m [R, inner] float32, state, tail); a row
     that is not ``valid`` keeps both bit-identical."""
     with jax.named_scope("ssm_conv"):
-        window = jnp.concatenate([tail, a[:, None]], axis=1)     # [R, K, C]
-        u = jnp.sum(window.astype(jnp.float32)
-                    * lp["conv_w"].astype(jnp.float32), axis=1)
-        u = jax.nn.silu(u + lp["conv_b"].astype(jnp.float32))
-        tail = jnp.where(valid[:, None, None], window[:, 1:], tail)
+        u, tail = conv_step(lp, tail, a, valid)
     with jax.named_scope("ssm_step"):
         dt, b, c = _time_step(cfg, lp, u)
         decay = jnp.exp(dt[:, None, :] * -jnp.exp(lp["a_log"]))
@@ -491,13 +620,9 @@ def mamba1_scan(cfg: ModelConfig, lp: Params, a, state, tail, n_valid):
     projection's two narrow slices (it reads them as scalars): nothing is
     spread or re-laid for it.  Returns (m [S, inner] float32, state,
     tail)."""
-    s_c, k = a.shape[0], cfg.ssm_conv
+    s_c = a.shape[0]
     with jax.named_scope("ssm_conv"):
-        seq = jnp.concatenate([tail, a], axis=0)                 # [S+K-1, C]
-        w = lp["conv_w"].astype(jnp.float32)
-        u = sum(seq[j:j + s_c].astype(jnp.float32) * w[j] for j in range(k))
-        u = jax.nn.silu(u + lp["conv_b"].astype(jnp.float32))
-        tail = jax.lax.dynamic_slice_in_dim(seq, n_valid, k - 1, axis=0)
+        u, tail = conv_chunk(lp, tail, a, n_valid)
     with jax.named_scope("ssm_scan"):
         dt, b, c = _time_step(cfg, lp, u)
         dt = jnp.where((jnp.arange(s_c) < n_valid)[:, None], dt, 0.0)
@@ -529,23 +654,11 @@ def mamba1(cfg: ModelConfig, lp: Params, h_in, pool, li, ctx):
     with jax.named_scope("ssm_in_proj"):
         az = quant.matmul(h_in, lp["w_in"])
         a, z = az[..., :di], az[..., di:]
-    s_all, t_all = pool["s"], pool["t"]
-    if "row" in ctx:                               # a chunk of one sequence
-        row, fresh = ctx["row"], ctx["fresh"]
-        state = jnp.where(fresh, 0.0, s_all[li, row])
-        tail = jnp.where(fresh, jnp.zeros((), t_all.dtype), t_all[li, row])
-        m, state, tail = mamba1_scan(cfg, lp, a[0], state, tail,
-                                     ctx["n_valid"])
-        pool = {**pool, "s": s_all.at[li, row].set(state),
-                "t": t_all.at[li, row].set(tail)}
-        m = m[None]
-    else:                                          # a decode step, by rows
-        src, valid, dst = ctx["rows"]
-        m, state, tail = mamba1_step(cfg, lp, a[src, 0], s_all[li],
-                                     t_all[li], valid)
-        pool = {**pool, "s": s_all.at[li].set(state),
-                "t": t_all.at[li].set(tail)}
-        m = m[dst][:, None]
+    m, pool = through_rows(
+        pool, li, ctx,
+        lambda state, tail, n: mamba1_scan(cfg, lp, a[0], state, tail, n),
+        lambda src, state, tail, valid: mamba1_step(
+            cfg, lp, a[src, 0], state, tail, valid))
     out = (m * jax.nn.silu(z.astype(jnp.float32))).astype(h_in.dtype)
     return quant.matmul(out, lp["w_out"]), pool, m
 
@@ -585,11 +698,7 @@ def ssm_step(cfg: ModelConfig, lp: Params, xbc, dt, state, tail, valid):
     r = xbc.shape[0]
     g = cfg.ssm_groups
     with jax.named_scope("ssm_conv"):
-        window = jnp.concatenate([tail, xbc[:, None]], axis=1)   # [R, K, C]
-        u = jnp.sum(window.astype(jnp.float32)
-                    * lp["conv_w"].astype(jnp.float32), axis=1)
-        u = jax.nn.silu(u + lp["conv_b"].astype(jnp.float32))
-        tail = jnp.where(valid[:, None, None], window[:, 1:], tail)
+        u, tail = conv_step(lp, tail, xbc, valid)
     with jax.named_scope("ssm_step"):
         x, b, c = _heads(cfg, u)
         dt = _by_group(cfg, jax.nn.softplus(dt.astype(jnp.float32)
@@ -618,14 +727,10 @@ def ssm_scan(cfg: ModelConfig, lp: Params, xbc, dt, state, tail, n_valid):
 
     every product a float32 einsum at the highest precision (under 1
     GFLOP a layer a chunk of 256)."""
-    s_c, k = xbc.shape[0], cfg.ssm_conv
+    s_c = xbc.shape[0]
     g = cfg.ssm_groups
     with jax.named_scope("ssm_conv"):
-        seq = jnp.concatenate([tail, xbc], axis=0)               # [S+K-1, C]
-        w = lp["conv_w"].astype(jnp.float32)
-        u = sum(seq[j:j + s_c].astype(jnp.float32) * w[j] for j in range(k))
-        u = jax.nn.silu(u + lp["conv_b"].astype(jnp.float32))
-        tail = jax.lax.dynamic_slice_in_dim(seq, n_valid, k - 1, axis=0)
+        u, tail = conv_chunk(lp, tail, xbc, n_valid)
     with jax.named_scope("ssm_scan"):
         x, b, c = _heads(cfg, u)              # [S, G, k, P], [S, G, N] x 2
         live = (jnp.arange(s_c) < n_valid)[:, None]
@@ -671,23 +776,12 @@ def _mamba(cfg: ModelConfig, lp: Params, h_in, pool, li, ctx):
     among the state-space layers."""
     with jax.named_scope("ssm_in_proj"):
         z, xbc, dt = _split_in(cfg, quant.matmul(h_in, lp["w_in"]))
-    s_all, t_all = pool["s"], pool["t"]
-    if "row" in ctx:                               # a chunk of one sequence
-        row, fresh = ctx["row"], ctx["fresh"]
-        state = jnp.where(fresh, 0.0, s_all[li, row])
-        tail = jnp.where(fresh, jnp.zeros((), t_all.dtype), t_all[li, row])
-        y, state, tail = ssm_scan(cfg, lp, xbc[0], dt[0], state, tail,
-                                  ctx["n_valid"])
-        pool = {**pool, "s": s_all.at[li, row].set(state),
-                "t": t_all.at[li, row].set(tail)}
-        y = y[None]
-    else:                                          # a decode step, by rows
-        src, valid, dst = ctx["rows"]
-        y, state, tail = ssm_step(cfg, lp, xbc[src, 0], dt[src, 0],
-                                  s_all[li], t_all[li], valid)
-        pool = {**pool, "s": s_all.at[li].set(state),
-                "t": t_all.at[li].set(tail)}
-        y = y[dst][:, None]
+    y, pool = through_rows(
+        pool, li, ctx,
+        lambda state, tail, n: ssm_scan(cfg, lp, xbc[0], dt[0], state, tail,
+                                        n),
+        lambda src, state, tail, valid: ssm_step(
+            cfg, lp, xbc[src, 0], dt[src, 0], state, tail, valid))
     return quant.matmul(_gate_norm(cfg, lp, y, z), lp["w_out"]), pool
 
 
@@ -820,13 +914,269 @@ def _cca(cfg: ModelConfig, lp: Params, h_in, pool, li, ctx):
                    pool, li, ctx)
 
 
+# -- "K": delta-rule linear attention with a decay a channel ------------------
+
+# The published layer's ``l2norm`` epsilon (fla: x * rsqrt(sum x^2 + eps)).
+KDA_L2_EPS = 1e-6
+# Positions a sub-block of the chunk form: the decay BETWEEN two positions
+# is at most 1, but its two halves ``exp(G_i)`` and ``exp(-G_j)`` are not
+# bounded over a chunk of a strong decay, so no product is ever split
+# across more than one sub-block's own span (``kda_scan``).
+KDA_BLOCK = 16
+
+
+def _kda_qkv(cfg: ModelConfig, u: jax.Array):
+    """The convs' output [..., 3 * inner] float32 -> (q, k, v) [..., heads,
+    d] float32: queries and keys at unit length a head, the queries times
+    ``d^-1/2``."""
+    nh, d = cfg.ssm_heads, cfg.ssm_head_dim
+    q, k, v = (u[..., j * nh * d:(j + 1) * nh * d].reshape(
+        *u.shape[:-1], nh, d) for j in range(3))
+
+    def unit(x):
+        return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True)
+                                 + KDA_L2_EPS)
+    return unit(q) * d ** -0.5, unit(k), v
+
+
+def kda_step(cfg: ModelConfig, lp: Params, qkv, g, beta, state, tail,
+             valid):
+    """The one-step recurrence over ROWS: qkv [R, 3 * inner] the row's
+    token before the convs, g [R, heads, d] float32 its log-decay a
+    channel, beta [R, heads] float32, state [R, heads, d_k, d_v] float32,
+    tail [R, K-1, 3 * inner], valid [R].  Returns (o [R, inner] float32,
+    state, tail); a row that is not ``valid`` keeps both bit-identical."""
+    with jax.named_scope("kda_conv"):
+        u, tail = conv_step(lp, tail, qkv, valid)
+    with jax.named_scope("kda_step"):
+        q, k, v = _kda_qkv(cfg, u)                           # [R, heads, d]
+        decayed = state * jnp.exp(g)[..., None]
+        read = jnp.sum(decayed * k[..., None], axis=2)       # S'^T k
+        new = decayed + ((beta[..., None] * k)[..., None]
+                         * (v - read)[:, :, None, :])
+        new = jnp.where(valid[:, None, None, None], new, state)
+        o = jnp.sum(new * q[..., None], axis=2)              # S^T q
+    return o.reshape(o.shape[0], -1), new, tail
+
+
+def _unit_lower_inverse(a: jax.Array, c: int) -> jax.Array:
+    """``(I + a)^-1`` for ``a`` [..., n, n] strictly lower triangular, ``n``
+    = ``c`` times a power of two: the diagonal blocks of ``c`` rows by
+    forward substitution (the rows unrolled: no loop is lowered), then
+    pairs of blocks merged, ``[[P, 0], [X, Q]]^-1 = [[P^-1, 0], [-Q^-1 X
+    P^-1, Q^-1]]``, until one is left.  Every entry is one the sequential
+    recurrence forms itself; no power of ``a`` is."""
+    lead, n = a.shape[:-2], a.shape[-1]
+
+    def diagonal_blocks(x, m, row, col):
+        """Block (2p + row, 2p + col) of ``m`` rows, for every p (row =
+        col = 0 with pairs of one: the diagonal blocks)."""
+        k = x.shape[-1] // m
+        x = x.reshape(*lead, k, m, k, m)
+        if row or col:
+            x = x.reshape(*lead, k // 2, 2, m, k // 2, 2, m)[
+                ..., :, row, :, :, col, :]
+        return jnp.moveaxis(jnp.diagonal(x, axis1=-4, axis2=-2), -1, -3)
+
+    diag = diagonal_blocks(a, c, 0, 0)                    # [..., n/c, c, c]
+    eye = jnp.eye(c, dtype=a.dtype)
+    rows = [jnp.broadcast_to(eye[0], diag.shape[:-2] + (c,))]
+    for i in range(1, c):
+        rows.append(eye[i] - jnp.einsum(
+            "...j,...jk->...k", diag[..., i, :i], jnp.stack(rows, axis=-2),
+            precision=HIGHEST))
+    inv, m = jnp.stack(rows, axis=-2), c
+    while m < n:
+        low = diagonal_blocks(a, m, 1, 0)                 # [..., n/2m, m, m]
+        pair = inv.reshape(*lead, n // (2 * m), 2, m, m)
+        top, bot = pair[..., 0, :, :], pair[..., 1, :, :]
+        mixed = -jnp.einsum("...ij,...jk,...kl->...il", bot, low, top,
+                            precision=HIGHEST)
+        inv = jnp.concatenate(
+            [jnp.concatenate([top, jnp.zeros_like(top)], axis=-1),
+             jnp.concatenate([mixed, bot], axis=-1)], axis=-2)
+        m *= 2
+    return inv[..., 0, :, :]
+
+
+def kda_scan(cfg: ModelConfig, lp: Params, qkv, g, beta, state, tail,
+             n_valid):
+    """The same recurrence over a CHUNK of one sequence, in matrix form:
+    qkv [S, 3 * inner], g [S, heads, d], beta [S, heads], from ``state``
+    [heads, d_k, d_v] and ``tail`` [K-1, 3 * inner]; positions ``>=
+    n_valid`` are right padding — their log-decay and their beta are 0, so
+    they neither decay nor feed the state, and the tail is taken from the
+    last valid rows.  Returns (o [S, inner] float32, state, tail).  A head
+    at a time, with ``G_t`` the running sum of ``g`` within the chunk and
+    ``P(x)_ij = sum_d x_id k_jd exp(G_id - G_jd)``:
+
+        A = strict_tril(beta_i P(k)_ij)        T = (I + A)^-1 Diag(beta)
+        D = T V - T (K exp G) S_0              (the corrected values)
+        O = (Q exp G) S_0 + tril(P(q)) D
+        S_end = Diag(exp G_end) S_0 + (K exp(G_end - G))^T D
+
+    ``exp(G_i - G_j) <= 1`` for ``i >= j`` but neither half is bounded, so
+    ``P`` is formed by sub-blocks of ``KDA_BLOCK`` positions: a row
+    against an EARLIER sub-block's position as ``(x_i exp(G_i - G_b)) .
+    (k_j exp(G_b - G_j))`` with ``G_b`` the sum up to the row's own
+    sub-block, both factors at most 1; within a sub-block the exponent is
+    taken whole.  Every other factor above is at most 1 as written.  All
+    products float32 at the highest precision (some 4 GFLOP a layer a
+    chunk of 256 at 32 heads of 128)."""
+    s_c = qkv.shape[0]
+    nh, d, c = cfg.ssm_heads, cfg.ssm_head_dim, KDA_BLOCK
+    with jax.named_scope("kda_conv"):
+        u, tail = conv_chunk(lp, tail, qkv, n_valid)
+    with jax.named_scope("kda_scan"):
+        q, k, v = _kda_qkv(cfg, u)                           # [S, heads, d]
+        live = jnp.arange(s_c) < n_valid
+        g = jnp.where(live[:, None, None], g, 0.0)
+        beta = jnp.where(live[:, None], beta, 0.0)
+        # Whole sub-blocks, a power of two of them (the inverse's merge);
+        # the padding is more right padding.
+        nb = 1
+        while nb * c < s_c:
+            nb *= 2
+        n = nb * c
+
+        def by_head(x):                          # [S, heads, w] -> [h, n, w]
+            x = jnp.pad(x.reshape(s_c, nh, -1), ((0, n - s_c), (0, 0),
+                                                 (0, 0)))
+            return x.transpose(1, 0, 2)
+        q, k, v, g = by_head(q), by_head(k), by_head(v), by_head(g)
+        beta = by_head(beta)[..., 0]                             # [h, n]
+
+        def blocks(x):
+            return x.reshape(nh, nb, c, -1)
+        tril = jnp.tril(jnp.ones((c, c), jnp.float32))
+        lower = jnp.tril(jnp.ones((nb, nb), jnp.float32), -1)
+        # Every span's log-decay is a SUM of its positions' (never the
+        # difference of two running sums: at -400 those leave 1e-5 of a
+        # factor that matters).  Within a sub-block up to a position
+        # (inclusive) and after it; whole sub-blocks before and after
+        # one, and strictly between two.
+        local = jnp.einsum("ij,hbjd->hbid", tril, blocks(g),
+                           precision=HIGHEST)
+        rest = jnp.einsum("ji,hbjd->hbid", tril - jnp.eye(c), blocks(g),
+                          precision=HIGHEST)
+        whole = local[:, :, -1]                                # [h, nb, d]
+        before = jnp.einsum("bc,hcd->hbd", lower, whole, precision=HIGHEST)
+        after = jnp.einsum("cb,hcd->hbd", lower, whole, precision=HIGHEST)
+        between = jnp.einsum(
+            "bcx,hxd->hbcd", lower[:, None, :] * lower.T[None, :, :], whole,
+            precision=HIGHEST)                      # blocks x: c < x < b
+        cum = (before[:, :, None] + local).reshape(nh, n, d)          # G
+        to_end = (after[:, :, None] + rest).reshape(nh, n, d)  # G_end - G
+        # Rows of sub-block b against every EARLIER position j, of
+        # sub-block c < b: what is left of c after j, and the sub-blocks
+        # between.
+        gap = jnp.where(lower[None, :, :, None, None] > 0,
+                        between[:, :, :, None] + rest[:, None], -jnp.inf)
+        k_from = k[:, None] * jnp.exp(gap.reshape(nh, nb, n, d))
+        to_block = jnp.exp(local)
+        rows = jnp.concatenate([blocks(k) * to_block, blocks(q) * to_block],
+                               axis=2)                        # [h, nb, 2c, d]
+        across = jnp.einsum("hbid,hbjd->hbij", rows, k_from,
+                            precision=HIGHEST)
+        # Within a sub-block, the exponent whole: i >= j only.
+        inside = jnp.exp(jnp.where(
+            tril[:, :, None] > 0,
+            local[:, :, :, None] - local[:, :, None], -jnp.inf))
+        kb = blocks(k)[:, :, None]                         # [h, nb, 1, c, d]
+
+        def pairs(x, off):
+            """P(x) [h, n, n]: zero above the diagonal."""
+            near = jnp.sum(blocks(x)[:, :, :, None] * kb * inside, axis=-1)
+            near = (near[:, :, :, None, :]
+                    * jnp.eye(nb, dtype=jnp.float32)[None, :, None, :, None])
+            return (across[:, :, off:off + c]
+                    + near.reshape(nh, nb, c, n)).reshape(nh, n, n)
+        a = (beta[:, :, None] * pairs(k, 0)
+             * jnp.tril(jnp.ones((n, n), jnp.float32), -1))
+        t = _unit_lower_inverse(a, c) * beta[:, None, :]
+        decay = jnp.exp(cum)
+        wu = jnp.einsum("hij,hjd->hid", t,
+                        jnp.concatenate([k * decay, v], axis=-1),
+                        precision=HIGHEST)
+        fixed = wu[..., d:] - jnp.einsum("hid,hde->hie", wu[..., :d], state,
+                                         precision=HIGHEST)
+        o = (jnp.einsum("hid,hde->hie", q * decay, state, precision=HIGHEST)
+             + jnp.einsum("hij,hje->hie", pairs(q, c), fixed,
+                          precision=HIGHEST))
+        new = (decay[:, -1, :, None] * state
+               + jnp.einsum("hjd,hje->hde", k * jnp.exp(to_end), fixed,
+                            precision=HIGHEST))
+    return o.transpose(1, 0, 2)[:s_c].reshape(s_c, -1), new, tail
+
+
+def _kda(cfg: ModelConfig, lp: Params, h_in, pool, li, ctx):
+    """h_in [B, S, H] -> (mixer output, pool); ``li`` the layer's index
+    among the "K" layers.  ``pool["s"]`` [layers, R, heads, d_k, d_v]
+    float32, ``pool["t"]`` [layers, R, K-1, 3 * inner]."""
+    nh, d = cfg.ssm_heads, cfg.ssm_head_dim
+    with jax.named_scope("kda_proj"):
+        qkv = jnp.concatenate([quant.matmul(h_in, lp[key])
+                               for key in ("wq", "wk", "wv")], axis=-1)
+    with jax.named_scope("kda_gate"):
+        # The decay compounds through every later position of the state:
+        # its two small projections and beta's in float32 at the highest
+        # precision, as ``_time_step``'s.
+        def f32(spec, x, w):
+            return jnp.einsum(spec, x.astype(jnp.float32),
+                              w.astype(jnp.float32), precision=HIGHEST)
+        f = f32("...r,rc->...c", f32("...h,hr->...r", h_in, lp["w_fa"]),
+                lp["w_fb"])
+        g = (-jnp.exp(lp["a_log"])[:, None]
+             * jax.nn.softplus(f + lp["dt_bias"]).reshape(*f.shape[:-1],
+                                                          nh, d))
+        beta = jax.nn.sigmoid(f32("...h,hn->...n", h_in, lp["w_beta"]))
+        z = quant.matmul(quant.matmul(h_in, lp["w_ga"]), lp["w_gb"])
+    o, pool = through_rows(
+        pool, li, ctx,
+        lambda state, tail, n: kda_scan(cfg, lp, qkv[0], g[0], beta[0],
+                                        state, tail, n),
+        lambda src, state, tail, valid: kda_step(
+            cfg, lp, qkv[src, 0], g[src, 0], beta[src, 0], state, tail,
+            valid))
+    with jax.named_scope("kda_out_norm"):
+        # An RMSNorm a head under ONE gain, times the sigmoid gate.
+        o = o.reshape(*o.shape[:-1], nh, d)
+        o = o * jax.lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True)
+                              + cfg.norm_eps) * lp["gn"].astype(jnp.float32)
+        o = (o.reshape(z.shape)
+             * jax.nn.sigmoid(z.astype(jnp.float32))).astype(h_in.dtype)
+    return quant.matmul(o, lp["wo"]), pool
+
+
+def _latent(cfg: ModelConfig, lp: Params, h_in, pool, li, ctx):
+    """h_in [B, S, H] -> (mixer output, pool); ``li`` the layer's index
+    among the "L" layers, which are ``pool["c"]``'s.  The latent family's
+    own attention with the rotary off: absorbed for a decode step,
+    up-projected over the table's window for a chunk."""
+    chunk = "row" in ctx
+    if chunk:
+        bs = pool["c"].shape[2]
+        tables, q_pos = ctx["table"][None, :ctx["window"] // bs], ctx["q_pos"]
+    else:
+        tables, q_pos = ctx["tables"], ctx["pos"][:, None]
+    out, rows = latent_moe._attend(cfg, lp, h_in, None, None, q_pos,
+                                   pool["c"], li, ctx["blk"], ctx["off"],
+                                   tables, absorbed=not chunk)
+    return quant.matmul(out, lp["wo"]), {**pool, "c": rows}
+
+
 def _relu2_mlp(x, up, down):
     a = jax.nn.relu(quant.matmul(x, up))
     return quant.matmul(a * a, down)
 
 
 def shared_expert(lp: Params, x: jax.Array) -> jax.Array:
+    """The expert every token goes through, of the routed experts' form:
+    gated where the layer holds a gate for it."""
     with jax.named_scope("shared_expert"):
+        if "ws_gate" in lp:
+            return transformer._swiglu(x, lp["ws_gate"], lp["ws_up"],
+                                       lp["ws_down"])
         return _relu2_mlp(x, lp["ws_up"], lp["ws_down"])
 
 
@@ -944,19 +1294,56 @@ def _norm_f32(x, w, eps):
 def forward_paged(cfg: ModelConfig, params: Params, tokens: jax.Array,
                   pool, ctx: Dict[str, Any]):
     """tokens [B, S]; ``pool`` {"k", "v": [attention layers, NB, bs,
-    N_kv * D], "s": [state-space layers, R, heads, P, N] float32 (Mamba-1:
-    [.., R, state, inner]), "t": [state-space layers, R, K-1, C] (a
-    pattern with "C": ["C" layers, R, 1, cca_tail_width], and "s" empty),
-    "owner": [R]}.  ``ctx`` is what the
+    N_kv * D] (a pattern with "L": "c" [latent layers, NB, bs,
+    kv_lora_rank + qk_rope_head_dim] alone), "s": [state-space layers, R,
+    heads, P, N] float32 (Mamba-1: [.., R, state, inner]; "K": [.., R,
+    heads, d_k, d_v]), "t": [state-space layers, R, K-1, C] ("K": C the
+    three convs' channels; a pattern with "C": ["C" layers, R, 1,
+    cca_tail_width], and "s" empty), "owner": [R]}.  ``ctx`` is what the
     mixers need of where the tokens sit (``chunk_ctx`` / ``decode_ctx``).
     Returns (hidden [B, S, H] after the final norm, pool, counts [expert
     layers, experts_held + 1])."""
     dtype = jnp.dtype(cfg.dtype)
-    period = cfg.layer_period
+    lead, period = cfg.layer_lead, cfg.layer_period
     index = {kind: kind_index(cfg, kind) for kind in KINDS}
     x = quant.embed_rows(params["embed"], tokens).astype(dtype)
     owner = pool["owner"]
-    carried = {key: pool[key] for key in ("k", "v", "s", "t")}
+    carried = {key: a for key, a in pool.items() if key != "owner"}
+
+    def sublayer(kind, lp, li, x, carried, routed, stacked=None, p=None):
+        """One sublayer of ``kind``, the ``li``-th of its kind -> (x,
+        carried, routed, the counts of an "E" or None)."""
+        counts = None
+        h_f32 = _norm_f32(x, lp["ln"], cfg.norm_eps)
+        if kind == "M" and cfg.ssm_dt_rank:
+            out, carried, _ = mamba1(cfg, lp, h_f32.astype(dtype), carried,
+                                     li, ctx)
+        elif kind == "-":
+            with jax.named_scope("ffn"):
+                out = transformer._swiglu(h_f32.astype(dtype), lp["w_gate"],
+                                          lp["w_up"], lp["w_down"])
+        elif kind == "E":
+            out, counts, routed = _experts(cfg, lp, h_f32, stacked, p,
+                                           routed)
+        else:
+            mixer = {"M": _mamba, "*": _attention, "C": _cca, "K": _kda,
+                     "L": _latent}[kind]
+            out, carried = mixer(cfg, lp, h_f32.astype(dtype), carried, li,
+                                 ctx)
+        return _merge(lp, x, out), carried, routed, counts
+
+    # The MLP router's state of every token, carried from expert layer to
+    # expert layer (zero-wide under the router that carries nothing).
+    routed = jnp.zeros(tokens.shape + (cfg.router_hidden,), jnp.float32)
+    # The pattern's lead sublayers run inline, so the periods' scan stays
+    # the program's one layer loop.
+    lead_counts = []
+    for j, kind in enumerate(lead):
+        x, carried, routed, n = sublayer(kind, params["lead"][j],
+                                         lead[:j].count(kind), x, carried,
+                                         routed)
+        if n is not None:
+            lead_counts.append(n)
 
     # The experts' matrices stay OUT of what the loop slices a period
     # (``latent_moe.routed_experts``); int8 ones are widened a layer at a
@@ -973,45 +1360,23 @@ def forward_paged(cfg: ModelConfig, params: Params, tokens: jax.Array,
         lps, p = scanned
         counts = []
         for j, kind in enumerate(period):
-            lp = lps[j]
             before, per_period = index[kind]
-            li = p * per_period + before[j]
-            h_f32 = _norm_f32(x, lp["ln"], cfg.norm_eps)
-            if kind == "M" and cfg.ssm_dt_rank:
-                out, carried, _ = mamba1(cfg, lp, h_f32.astype(dtype),
-                                         carried, li, ctx)
-            elif kind == "M":
-                out, carried = _mamba(cfg, lp, h_f32.astype(dtype), carried,
-                                      li, ctx)
-            elif kind == "-":
-                with jax.named_scope("ffn"):
-                    out = transformer._swiglu(
-                        h_f32.astype(dtype), lp["w_gate"], lp["w_up"],
-                        lp["w_down"])
-            elif kind == "*":
-                out, carried = _attention(cfg, lp, h_f32.astype(dtype),
-                                          carried, li, ctx)
-            elif kind == "C":
-                out, carried = _cca(cfg, lp, h_f32.astype(dtype), carried,
-                                    li, ctx)
-            else:
-                out, n, routed = _experts(cfg, lp, h_f32, stacked[j], p,
-                                          routed)
+            x, carried, routed, n = sublayer(
+                kind, lps[j], lead.count(kind) + p * per_period + before[j],
+                x, carried, routed, stacked[j], p)
+            if n is not None:
                 counts.append(n)
-            x = _merge(lp, x, out)
         return (x, carried, routed), jnp.stack(counts) if counts else None
 
-    n_periods = cfg.num_layers // len(period)
-    # The MLP router's state of every token, carried from expert layer to
-    # expert layer (zero-wide under the router that carries nothing).
-    routed = jnp.zeros(tokens.shape + (cfg.router_hidden,), jnp.float32)
+    n_periods = (cfg.num_layers - len(lead)) // len(period)
     (x, carried, _), counts = jax.lax.scan(
         body, (x, carried, routed), (layers, jnp.arange(n_periods)))
     hidden = transformer.rms_norm(x, params["final_ln"], cfg.norm_eps)
-    if counts is None:
-        counts = jnp.zeros((0, cfg.experts_held + 1), jnp.int32)
-    else:
-        counts = counts.reshape(-1, counts.shape[-1])
+    parts = [n[None] for n in lead_counts]
+    if counts is not None:
+        parts.append(counts.reshape(-1, counts.shape[-1]))
+    counts = (jnp.concatenate(parts) if parts else
+              jnp.zeros((0, cfg.experts_held + 1), jnp.int32))
     return hidden, {**carried, "owner": owner}, counts
 
 

@@ -291,21 +291,32 @@ def _attend(cfg: ModelConfig, lp: Params, h_in, sin, cos, q_pos, pool_c, i,
     Writes the chunk's cache rows at ``(i, blk, off)`` first, then
     attends the rows the ``tables`` [B, wb] name, position p of a
     sequence at flat index p of its gathered window; query (b, s) sees
-    columns ``<= q_pos[b, s]``."""
+    columns ``<= q_pos[b, s]``.  Queries come through the low-rank pair
+    the layer holds, or — a layer that holds ONE query matrix ``wq`` (the
+    hybrid family's "L", models/hybrid_ssm.py) — straight from it;
+    ``sin`` None applies no rotary embedding: the ``qk_rope_head_dim``
+    shared numbers are written and read as projected."""
     b, s, _ = h_in.shape
     nh, dc = cfg.num_heads, cfg.kv_lora_rank
     dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
     dtype = h_in.dtype
 
-    c_q = transformer.rms_norm(quant.matmul(h_in, lp["w_qa"]), lp["q_ln"],
-                               cfg.norm_eps)
-    q = quant.matmul(c_q, lp["w_qb"]).reshape(b, s, nh, dn + dr)
-    q_nope = q[..., :dn]
-    q_rope = transformer.apply_rope(q[..., dn:], sin, cos)
+    if "w_qa" in lp:
+        c_q = transformer.rms_norm(quant.matmul(h_in, lp["w_qa"]),
+                                   lp["q_ln"], cfg.norm_eps)
+        q = quant.matmul(c_q, lp["w_qb"])
+    else:
+        q = quant.matmul(h_in, lp["wq"])
+    q = q.reshape(b, s, nh, dn + dr)
+    q_nope, q_rope = q[..., :dn], q[..., dn:]
     kv = quant.matmul(h_in, lp["w_kva"])
+    k_rope = kv[..., dc:]
+    if sin is not None:
+        q_rope = transformer.apply_rope(q_rope, sin, cos)
+        k_rope = _rope_1(k_rope, sin, cos)
     row = jnp.concatenate(
         [transformer.rms_norm(kv[..., :dc], lp["kv_ln"], cfg.norm_eps),
-         _rope_1(kv[..., dc:], sin, cos)], axis=-1)          # [B, S, dc+dr]
+         k_rope], axis=-1)                                   # [B, S, dc+dr]
 
     with jax.named_scope("kv_write"):
         pool_c = pool_c.at[i, blk, off].set(row)

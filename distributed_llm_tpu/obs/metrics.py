@@ -491,16 +491,21 @@ METRIC_REGISTRY: Tuple[Tuple[str, str, str, Tuple[str, ...], str], ...] = (
      "dllm_moe_absent_assignments_total", ("tier", "stage"),
      "Token-to-expert assignments the router made to experts this "
      "program does not hold (ModelConfig.experts_first/experts_count: "
-     "the other rank of an expert-parallel pair computes them), by "
-     "stage; held + absent = every assignment"),
+     "the other ranks of an expert-parallel group compute them: one "
+     "other where half are held, three where 64 of 256 are), by stage; "
+     "held + absent = every assignment (the benchmark's "
+     "moe.held_assignment_share)"),
     # The row families (models/hybrid_ssm.py, shared_kv_hybrid.py): a
     # sequence's recurrent row is zeroed when its prompt's first chunk
     # starts.
     ("state_resets", "counter", "dllm_state_resets_total", ("tier",),
      "Recurrent rows started from zero: prompts (and preemption "
      "replays) whose first chunk was dispatched; a row is a state-space "
-     "layer's state and conv tail, or the tail a compressed "
-     "convolutional attention layer keeps beside its paged K/V"),
+     "layer's state and conv tail, a linear-attention layer's float32 "
+     "matrix a head and its three conv tails (GET /stats state.mixer "
+     "kda; the benchmark's kda.state_share_of_step_bytes counts its "
+     "bytes), or the tail a compressed convolutional attention layer "
+     "keeps beside its paged K/V"),
     # Batched-speculation family (ISSUE 15): drafted vs accepted
     # draft tokens per tier (the counter pair whose ratio IS the
     # realized acceptance rate) and the engine's running acceptance

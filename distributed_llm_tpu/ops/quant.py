@@ -190,13 +190,14 @@ def quantize_params(params: Dict[str, Any]) -> Dict[str, Any]:
                 layers[k] = quantize_tensor(layers[k])
         return layers
 
-    # "lead": the latent family's dense lead layers, a second stack;
+    # "lead": the latent family's dense lead layers, a second stack (the
+    # hybrid family's: a list, a layer a sublayer ahead of its loop);
     # "periods": the hybrid family's, a stack a position of its period.
-    for group in ("layers", "lead"):
-        if group in params:
+    for group in ("layers", "lead", "periods"):
+        if isinstance(params.get(group), list):
+            out[group] = [stack(lp) for lp in params[group]]
+        elif group in params:
             out[group] = stack(params[group])
-    if "periods" in params:
-        out["periods"] = [stack(lp) for lp in params["periods"]]
     # "segments": the shared-K/V family's, a list of such periods.
     if "segments" in params:
         out["segments"] = [[stack(lp) for lp in seg]

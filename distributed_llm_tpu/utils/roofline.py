@@ -50,14 +50,28 @@ def _mamba1_params(cfg) -> int:
 
 def _hybrid_layer_params(cfg):
     """Matrix parameters of one layer of each kind of the hybrid family
-    (models/hybrid_ssm.py): (state-space, attention — a "C" layer's with
-    its two convolutions' taps —, an expert layer without its routed
-    experts, one routed expert)."""
+    (models/hybrid_ssm.py): (a row kind's — state-space, or "K" linear
+    attention's four projections, two low-rank pairs, beta's and the
+    three convs' taps —, attention — a "C" layer's with its two
+    convolutions' taps, an "L" layer's latent projections —, an expert
+    layer without its routed experts, one routed expert)."""
     h, d = cfg.hidden_size, cfg.head_dim
-    ssm = (_mamba1_params(cfg) if cfg.ssm_dt_rank else
-           h * (cfg.ssm_inner + cfg.ssm_conv_width + cfg.ssm_heads)
-           + cfg.ssm_inner * h)
-    attn = 2 * h * cfg.num_heads * d + 2 * h * cfg.num_kv_heads * d
+    di = cfg.ssm_inner
+    if cfg.layers_of("K"):
+        ssm = (4 * h * di + 2 * cfg.ssm_head_dim * (h + di)
+               + h * cfg.ssm_heads + cfg.ssm_conv * 3 * di)
+    elif cfg.ssm_dt_rank:
+        ssm = _mamba1_params(cfg)
+    else:
+        ssm = h * (di + cfg.ssm_conv_width + cfg.ssm_heads) + di * h
+    if cfg.layers_of("L"):
+        nh = cfg.num_heads
+        dn, dr, dv = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
+                      cfg.v_head_dim)
+        attn = (h * nh * (dn + dr) + h * cfg.cache_row_width
+                + cfg.kv_lora_rank * nh * (dn + dv) + nh * dv * h)
+    else:
+        attn = 2 * h * cfg.num_heads * d + 2 * h * cfg.num_kv_heads * d
     if cfg.layers_of("C"):
         heads = cfg.num_heads + cfg.num_kv_heads
         attn += 2 * heads * d + 2 * heads * d * d
@@ -65,15 +79,15 @@ def _hybrid_layer_params(cfg):
     router = (h * rh + 2 * rh * rh + rh * cfg.num_experts if rh
               else h * cfg.num_experts)
     gated = 2 if cfg.expert_act == "relu2" else 3
-    return (ssm, attn, router + 2 * h * cfg.shared_ffn_size,
+    return (ssm, attn, router + gated * h * cfg.shared_ffn_size,
             gated * h * cfg.moe_ffn_size)
 
 
 def _hybrid_params(cfg, experts: float) -> float:
     """All layers' matrices with ``experts`` routed experts a layer."""
     ssm, attn, fixed, expert = _hybrid_layer_params(cfg)
-    return (cfg.layers_of("M") * ssm
-            + (cfg.layers_of("*") + cfg.layers_of("C")) * attn
+    return ((cfg.layers_of("M") + cfg.layers_of("K")) * ssm
+            + sum(cfg.layers_of(kind) for kind in "*CL") * attn
             + cfg.layers_of("E") * (fixed + experts * expert)
             + cfg.layers_of("-") * 3 * cfg.hidden_size * cfg.ffn_size)
 
@@ -170,6 +184,8 @@ def kv_bytes_per_pos(cfg, kv_quantize: str = "none") -> int:
     scales (engine/paged_kv.py); the latent family's one row a layer."""
     if cfg.latent:
         return cfg.num_layers * cfg.cache_row_width * 2
+    if cfg.layers_of("L"):
+        return cfg.kv_layers * cfg.cache_row_width * 2
     rows = 2 * cfg.kv_layers * cfg.num_kv_heads
     if kv_quantize == "int8":
         return rows * (cfg.head_dim + 4)
@@ -190,11 +206,13 @@ def _attention_width_layers(cfg):
 
 def state_row_bytes(cfg) -> int:
     """What one sequence of the hybrid family keeps beside its K/V: the
-    float32 state and the conv tail of every state-space layer, or the
-    tail row of every "C" layer."""
+    float32 state and the conv tail of every state-space layer (a "K"
+    layer: a matrix of ``ssm_head_dim`` squared a head and three convs'
+    tails), or the tail row of every "C" layer."""
     itemsize = 4 if cfg.dtype == "float32" else 2
-    return (cfg.layers_of("M") * (
-        cfg.ssm_inner * cfg.ssm_state * 4
+    state = cfg.ssm_head_dim if cfg.layers_of("K") else cfg.ssm_state
+    return ((cfg.layers_of("M") + cfg.layers_of("K")) * (
+        cfg.ssm_inner * state * 4
         + (cfg.ssm_conv - 1) * cfg.ssm_conv_width * itemsize)
         + cfg.layers_of("C") * cfg.cca_tail_width * itemsize)
 

@@ -230,7 +230,10 @@ def _drain_after_stats(self) -> None:
             metrics_after=self.client.get("/metrics").text)
         for name in self.entries:
             block = tiers.get(name, {})
-            for key in ("tick", "prefill"):
+            # "state": the recurrent rows' mixer (mamba2, mamba1, cca_tail,
+            # kda), their count and bytes, and the K/V or latent layers
+            # beside them; null for a model without rows.
+            for key in ("tick", "prefill", "state"):
                 print(f"[bench:stats] tiers.{name}.{key} = "
                       f"{json.dumps(block.get(key))}", flush=True)
             print(f"[bench:stats] tiers.{name}.moe.grouped_product = "

@@ -403,15 +403,10 @@ def test_stats_name_the_tail_rows_their_bytes_and_the_kv_beside_them(engine):
     assert moe["absent_assignments"] == {"decode": 0, "prefill": 0}
 
 
-@pytest.mark.parametrize("what,kw", [
-    ("kv_quantize", dict(kv_quantize="int8")),
-    ("draft_preset", dict(draft_preset="draft_test")),
-    ("enable_prefix_cache", dict(enable_prefix_cache=True)),
-    ("prefill_chunk_tokens", dict(prefill_chunk_tokens=0)),
-])
-def test_unsupported_combinations_raise_by_the_familys_one_row(what, kw):
-    with pytest.raises(ValueError, match="state-space hybrid family"):
-        ContinuousBatchingEngine(TierConfig(**{**TIER, **kw}), seed=0)
+# What the family refuses for this pattern, by its one row of
+# ``_FAMILY_REFUSALS``: tests/test_hybrid_kda.py
+# ``test_unsupported_combinations_raise_by_the_familys_one_row``, one
+# parametrised test over this pattern's preset and that file's.
 
 
 # (5) configuration, pool, roofline, int8 -----------------------------------------

@@ -375,6 +375,9 @@ ROUTED_TICKS = {
     "nemotron-3-nano-30b-a3b": (3, 1.48, 0.5),
     # Top-1 of 16 gated experts of 2048 x 2048 over 20 layers (PR 51).
     "zaya1-8b": (1, 2.68, 0.5),
+    # 64 held of 256 gated experts of 2304 x 1024 over 8 expert sublayers,
+    # four a period of "KEKELEKE" (PR 54).
+    "kimi-linear-48b-a3b": (4, 0.60, 0.5),
 }
 
 
@@ -395,7 +398,7 @@ def test_routed_tick_reads_the_experts_where_they_rest(one_chip, as_on_tpu,
     # of 512 B, which ``rows_attention.serves`` leaves to the XLA form
     # (ISSUE 45); the latent family attends in code of its own.
     assert engine.decode_attention_form(256) == (
-        "latent" if engine.cfg.latent else "merged")
+        "latent" if engine.cfg.kv_lora_rank else "merged")
     text = compiled.as_text()
     assert text.count("tpu_custom_call") == products
     assert "grouped_product_ffn" in text and "ragged-dot" not in text
@@ -404,6 +407,28 @@ def test_routed_tick_reads_the_experts_where_they_rest(one_chip, as_on_tpu,
     # The kernel is one operation of the layer body: the tick keeps the
     # two nested loops the benchmark files it by (steps, layers).
     assert text.count(" while(") == 2
+
+
+def test_a_pattern_with_a_lead_keeps_one_layer_loop_at_the_real_sizes(
+        one_chip, as_on_tpu, monkeypatch):
+    """``kimi-linear-48b-a3b``'s chunk program at its real sizes: the lead
+    "K-" runs inline and the periods "KEKELEKE" x 2 are the program's ONE
+    ``while`` (the tick's two are held by the routed-tick test above) —
+    neither the lead nor the linear-attention chunk recurrence (its
+    triangular inverse, its sub-blocks) lowers to a loop, so the
+    benchmark files the program as a prefill and not as a tick; and the
+    matrix form's temporaries stay under a gigabyte."""
+    tier = _bench_tier(monkeypatch, "kimi-linear-48b-a3b")
+    engine, _, compiled, _ = _pool_program(one_chip, tier,
+                                           ("chunk", 256, 5120))
+    assert engine.cfg.layer_lead == "K-"
+    assert engine.cfg.layer_period == "KEKELEKE"
+    text = compiled.as_text()
+    assert text.count(" while(") == 1
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.0 * GB
+    for scope in ("kda_proj", "kda_conv", "kda_gate", "kda_scan",
+                  "kda_out_norm", "latent_attention", "kv_write"):
+        assert scope in text, scope
 
 
 # -- wq and wk are read where they rest (ISSUE 48) -----------------------------
