@@ -715,7 +715,7 @@ def kernel_cases(nq: int, nkv: int, d: int, dtype, *, batch: int = 8,
         return jnp.concatenate([y, state])
 
     if grouped is None:
-        grouped = {"wide-reasoning": (96, 16, 2816, 2048),
+        grouped = {"wide-reasoning": (96, 16, 2688, 1920),
                    "reasoned-reply": (32, 16, 3584, 1024)}
     grouped_cases = {
         f"grouped_product.{cell}": KernelCase(

@@ -128,31 +128,38 @@ EXPERT_KEYS = ("we_gate", "we_up", "we_down")
 LANES = 128
 
 
-EXPERT_ALIGN = 256
-
-
 def expert_dims_stored(cfg: ModelConfig):
     """(hidden, width) of the routed experts' matrices as STORED: each
-    rounded up to a multiple of 256 with zero rows and columns (a zero
-    input row adds nothing, ``relu(0)^2 = 0``, a zero output column is cut
-    off: the same numbers).  Of the two reasons PR 33 had for it at the
-    published 2688 x 1856, one still holds.  At a minor width that is not
-    a multiple of the chip's 128 lanes the device rests ``W_1`` [.., H,
-    F] with H minor while a kernel wants F minor, so every program copied
-    every held expert at its entry (3 x 1.28 GB a tick: compile for a
-    described v5e, PR 33); and the chip's compiler cannot cut a matrix of
-    such a stack out by index, so ``ops.grouped_product.serves`` asks for
-    whole lane-widths of ``out`` and ``ragged_dot`` would take the rest.
-    Whole lane-widths are enough for that: 2688 x 1920 (21 and 15).  The
-    other reason went with the product becoming the repo's own kernel (PR
-    34): XLA's ``ragged_dot`` tiles by divisors of its dimensions (41 GB/s
-    of the touched experts' bytes at 2688 x 1856, 112 at 2688 x 1920, 305
-    at 2688 x 2048, 460 at 2816 x 2048, 581 at 3072 x 2048: my chip runs,
-    PR 33: 96 rows over 26 of 128 groups), ``ops/grouped_product.py``
-    reads whole matrices whatever their factors.  Taking the padding back
-    to 2688 x 1920 (8.86 -> 7.93 GB of experts) is the follow-up's."""
+    rounded up to whole lane-widths (128) with zero rows and columns (a
+    zero input row adds nothing, ``relu(0)^2 = 0``, a zero output column
+    is cut off: the same numbers), and no further: 2688 x 1920 (21 and 15
+    lane-widths) at the published 2688 x 1856.  At a minor width that is
+    not a multiple of the chip's 128 lanes the device rests ``W_1`` [..,
+    H, F] with H minor while a kernel wants F minor, so every program
+    copied every held expert at its entry (3 x 1.28 GB a tick: compile
+    for a described v5e, PR 33); and the chip's compiler cannot cut a
+    matrix of such a stack out by index, so ``ops.grouped_product.serves``
+    asks for whole lane-widths of ``F`` and ``out`` and whole tiles of 16
+    sublanes of ``in``: 128 gives both, for up [held, H, F] and down
+    [held, F, H] alike.  From PR 33 to PR 54 the rule was multiples of
+    256 (2816 x 2048), for XLA's ``ragged_dot``, which tiles by divisors
+    of its dimensions (41 GB/s of the touched experts' bytes at 2688 x
+    1856, 112 at 2688 x 1920, 305 at 2688 x 2048, 460 at 2816 x 2048, 581
+    at 3072 x 2048: my chip runs, PR 33: 96 rows over 26 of 128 groups);
+    ``ops/grouped_product.py`` (PR 34) reads whole matrices whatever
+    their factors.  Read at 2688 x 1920 (PR 55): the decode tick and
+    every chunk program take both stacks as stored (row-major, F minor
+    for up, H minor for down), no operation of either makes an array of
+    expert matrices and their temporaries are 0.06 and 0.03-0.15 GB
+    (compile for a described v5e; tests/test_tpu_compile.py holds it); on
+    the chip ``moe.grouped_product`` reads ``pallas_ffn`` for both stages,
+    a tick's one call streams its touched matrices at 694 GB/s and a
+    chunk's at 717 (696 and 719 at 2816 x 2048: the same rate, a tenth
+    fewer bytes: 774 against 861 us and 1612 against 1796 us an FFN;
+    ``ragged_dot`` there 112 and 86 GB/s), and the device's peak memory
+    fell 13.48 -> 12.54 GB (my chip runs, PR 55)."""
     def up(n):
-        return -(-n // EXPERT_ALIGN) * EXPERT_ALIGN
+        return -(-n // LANES) * LANES
     return up(cfg.hidden_size), up(cfg.moe_ffn_size)
 
 

@@ -108,13 +108,14 @@ def test_the_tiles_follow_the_matrix():
     """DMAs of whole sublane tiles of rows, a few MB each at most, the
     last one ending with the matrix; nothing of 2816 x 2048 baked in."""
     for k, n in ((2816, 2048), (2048, 2816), (3584, 1024), (1024, 3584),
-                 (2688, 1920), (1856, 2688), (48, 128)):
+                 (2688, 1920), (1920, 2688), (1856, 2688), (48, 128)):
         tiles = GP.dma_tiles(k, n, 2)
         assert tiles[0][0] == 0 and sum(h for _, h in tiles) == k
         assert all(a + h == b for (a, h), (b, _) in zip(tiles, tiles[1:]))
         assert all(h % GP.ROW_ALIGN == 0 and h * n * 2 <= GP.TILE_BYTES
                    for _, h in tiles)
     assert GP._split(1920, 1024, 128) == [(0, 1024), (1024, 896)]
+    assert GP.dma_tiles(2688, 1920, 2) == [(0, 1344), (1344, 1344)]
     with pytest.raises(ValueError, match="whole tiles"):
         GP.grouped_product(jnp.zeros((8, 200), jnp.bfloat16),
                            jnp.zeros((4, 200, 128), jnp.bfloat16),
@@ -124,15 +125,18 @@ def test_the_tiles_follow_the_matrix():
 # -- an expert layer's whole FFN as one call (ISSUE 53) ------------------------
 
 # name: (rows, layers, groups a layer, layer with the rows, rows in groups,
-#        groups touched, in, F, out, gated, options).  The published
-# ratios at smaller widths: 2816 / 2048 at a half (11 and 8 lane-widths)
+#        groups touched, in, F, out, gated, options).  The cells'
+# ratios at smaller widths: 2688 / 1920 at a third (7 and 5 lane-widths;
+# 2816 / 2048, what that cell stored until ISSUE 55, at a half: 11 and 8)
 # and 3584 / 1024 at a quarter (7 and 2); ``out`` is ``in`` in the models,
 # and another width in three cases so that nothing leans on that.
 FFN_CASES = {
     "context-reasoning-tick-gated": (16, 20, 16, 10, 16, 7, 256, 256, 256,
                                      True, {}),
-    "wide-reasoning-tick-relu2": (96, 2, 32, 1, 48, 20, 1408, 1024, 1408,
+    "wide-reasoning-tick-relu2": (96, 2, 32, 1, 48, 20, 896, 640, 896,
                                   False, {}),
+    "stored-by-256-tick-relu2": (96, 2, 32, 1, 48, 20, 1408, 1024, 1408,
+                                 False, {}),
     "reasoned-reply-tick-gated": (32, 5, 64, 2, 32, 23, 896, 256, 896,
                                   True, {}),
     "stacked-first-layer-gated": (32, 5, 16, 0, 32, 9, 128, 128, 128, True,
@@ -227,16 +231,18 @@ W = jax.ShapeDtypeStruct
 
 # What ``_grouped`` traces, by shapes alone: (rows, w, implementation).
 BRANCHES = {
-    "wide-reasoning-tick": (96, W((128, 2816, 2048), jnp.bfloat16),
+    "wide-reasoning-tick": (96, W((128, 2688, 1920), jnp.bfloat16),
                             "pallas"),
-    "wide-reasoning-tick-down": (96, W((128, 2048, 2816), jnp.bfloat16),
+    "wide-reasoning-tick-down": (96, W((128, 1920, 2688), jnp.bfloat16),
                                  "pallas"),
     "reasoned-reply-tick": (32, W((320, 3584, 1024), jnp.bfloat16),
                             "pallas"),
     "reasoned-reply-tick-down": (32, W((320, 1024, 3584), jnp.bfloat16),
                                  "pallas"),
-    "unpadded-15-lane-widths": (96, W((128, 2688, 1920), jnp.bfloat16),
-                                "pallas"),
+    # What that cell stored until ISSUE 55 (multiples of 256).
+    "stored-by-256": (96, W((128, 2816, 2048), jnp.bfloat16), "pallas"),
+    "stored-by-256-down": (96, W((128, 2048, 2816), jnp.bfloat16),
+                           "pallas"),
     # A minor width off the lanes: the chip's compiler cannot cut such a
     # matrix out of the stack by index (and the device rests it
     # transposed: models/hybrid_ssm.py ``expert_dims_stored``).
@@ -298,7 +304,8 @@ def test_grouped_chooses_by_static_shapes(case, monkeypatch):
 
 
 # What ``expert_ffn`` traces, by shapes alone: (rows, gate, up, down,
-# form).  Every shape under "pallas_ffn" is one a benchmark cell runs.
+# form).  Every shape under "pallas_ffn" is one a benchmark cell runs,
+# or ran.
 FFN_BRANCHES = {
     "context-reasoning-tick": (16, True, W((320, 2048, 2048), jnp.bfloat16),
                                W((320, 2048, 2048), jnp.bfloat16),
@@ -307,13 +314,21 @@ FFN_BRANCHES = {
                                 W((320, 2048, 2048), jnp.bfloat16),
                                 W((320, 2048, 2048), jnp.bfloat16),
                                 "pallas_ffn"),
-    "wide-reasoning-tick": (96, False, W((128, 2816, 2048), jnp.bfloat16),
-                            W((128, 2048, 2816), jnp.bfloat16),
+    "wide-reasoning-tick": (96, False, W((128, 2688, 1920), jnp.bfloat16),
+                            W((128, 1920, 2688), jnp.bfloat16),
                             "pallas_ffn"),
     "wide-reasoning-chunk": (1536, False,
-                             W((128, 2816, 2048), jnp.bfloat16),
-                             W((128, 2048, 2816), jnp.bfloat16),
+                             W((128, 2688, 1920), jnp.bfloat16),
+                             W((128, 1920, 2688), jnp.bfloat16),
                              "pallas_ffn"),
+    # What that cell stored until ISSUE 55 (multiples of 256).
+    "stored-by-256-tick": (96, False, W((128, 2816, 2048), jnp.bfloat16),
+                           W((128, 2048, 2816), jnp.bfloat16),
+                           "pallas_ffn"),
+    "stored-by-256-chunk": (1536, False,
+                            W((128, 2816, 2048), jnp.bfloat16),
+                            W((128, 2048, 2816), jnp.bfloat16),
+                            "pallas_ffn"),
     "reasoned-reply-tick": (32, True, W((320, 3584, 1024), jnp.bfloat16),
                             W((320, 1024, 3584), jnp.bfloat16),
                             "pallas_ffn"),
@@ -480,9 +495,28 @@ def test_hybrid_experts_agree_through_either_product(preset, monkeypatch):
     np.testing.assert_allclose(out_k, out_r, atol=2e-2 * scale, rtol=2e-2)
 
 
+@pytest.mark.parametrize("tokens", [16, 256], ids=["tick", "chunk"])
+def test_wide_reasonings_own_stacks_take_the_one_call(tokens):
+    """``nemotron-3-nano-30b-a3b``'s experts as ``expert_dims_stored``
+    rests them (2688 x 1856 published, top-6, no gate; two periods of 64
+    held experts), as shapes alone: the tick's 16 tokens and the chunk
+    program's 256 both trace the one kernel call, so a rule that stored a
+    width the kernel refuses would fall to ``ragged_dot`` HERE and not
+    unseen on the chip (112 GB/s at these widths: PERF.md section 6,
+    PR 33)."""
+    from distributed_llm_tpu.models import hybrid_ssm
+    cfg = dataclasses.replace(MODEL_PRESETS["hybrid_test"], hidden_size=2688,
+                              moe_ffn_size=1856, experts_per_token=6)
+    h, f = hybrid_ssm.expert_dims_stored(cfg)
+    assert (h, f) == (2688, 1920)
+    stacks = [W((2, 64, h, f), jnp.bfloat16), W((2, 64, f, h), jnp.bfloat16)]
+    assert latent_moe.grouped_product_form(cfg, stacks, tokens) == \
+        "pallas_ffn"
+
+
 @pytest.mark.parametrize("preset,form", [
-    # Stored 256 x 256 (``expert_dims_stored``): 12 and 48 rows over 8
-    # stacked groups: one fused call a layer.
+    # Stored 128 x 128 (``expert_dims_stored``: whole lane-widths): 12
+    # and 48 rows over 8 stacked groups: one fused call a layer.
     ("hybrid_test", {"decode": "pallas_ffn", "prefill": "pallas_ffn"}),
     # The gated experts under the MLP router: 2 and 16 rows over 12.
     ("hybrid_cca_test", {"decode": "pallas_ffn", "prefill": "pallas_ffn"}),

@@ -22,6 +22,7 @@ from distributed_llm_tpu.config import MODEL_PRESETS, TierConfig
 from distributed_llm_tpu.engine import paged_kv
 from distributed_llm_tpu.engine.batching import ContinuousBatchingEngine
 from distributed_llm_tpu.models import hybrid_ssm, latent_moe, transformer
+from distributed_llm_tpu.ops import grouped_product
 from test_latent_moe import _while_depth
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -236,12 +237,9 @@ def test_the_shares_add_up_to_the_uncut_references_whole_layer(ref):
         counts.append(np.asarray(n[0] if n.ndim > 1 else n))
     shared = np.asarray(hybrid_ssm.shared_expert(lp, x))
     model = dict(TINY, n_routed_experts=8)
-    # The program stores the experts' matrices zero-padded to multiples
-    # of 256; the reference takes them at the published sizes.
-    assert lp["we_up"].shape == (8, 256, 256)
-    assert float(jnp.abs(lp["we_up"][:, 64:]).max()) == 0.0
-    assert float(jnp.abs(lp["we_up"][:, :, 32:]).max()) == 0.0
-    assert float(jnp.abs(lp["we_down"][:, 32:]).max()) == 0.0
+    # The program stores the experts' matrices zero-padded to whole
+    # lane-widths (the test below); the reference takes them at the
+    # published sizes.
     cut = dict(lp, we_up=lp["we_up"][:, :64, :32],
                we_down=lp["we_down"][:, :32, :64])
     with jax.default_matmul_precision("highest"):
@@ -256,6 +254,71 @@ def test_the_shares_add_up_to_the_uncut_references_whole_layer(ref):
     assert counts[0][:4].sum() + counts[1][:4].sum() == 36
     assert counts[0][4] == counts[1][:4].sum()
     assert counts[1][4] == counts[0][:4].sum()
+
+
+# (hidden, width) as published -> as stored: whole lane-widths (128) of
+# each and no further.  The tiny preset; nemotron-3-nano-30b-a3b (stored
+# 2816 x 2048 until ISSUE 55); kimi-linear-48b-a3b and zaya1-8b, which
+# rest as published under either rule.
+STORED = {(64, 32): (128, 128), (2688, 1856): (2688, 1920),
+          (2304, 1024): (2304, 1024), (2048, 2048): (2048, 2048)}
+
+
+@pytest.mark.parametrize("published", list(STORED), ids=lambda p: "%dx%d" % p)
+def test_experts_rest_zero_padded_to_whole_lane_widths(published):
+    (h, f), (h_st, f_st) = published, STORED[published]
+    cfg = _cfg(experts_first=0, experts_count=8, hidden_size=h,
+               moe_ffn_size=f)
+    assert hybrid_ssm.expert_dims_stored(cfg) == (h_st, f_st)
+
+    def layer():
+        return hybrid_ssm.init_layer(cfg, jax.random.PRNGKey(SEED), "E")
+    # Shapes only at the published sizes: no array of that size is made.
+    lp = jax.eval_shape(layer)
+    assert lp["we_up"].shape == (8, h_st, f_st)
+    assert lp["we_down"].shape == (8, f_st, h_st)
+    if (h, f) == (64, 32):
+        lp = layer()
+        assert float(jnp.abs(lp["we_up"][:, :h, :f]).min()) > 0.0
+        assert float(jnp.abs(lp["we_up"][:, h:]).max()) == 0.0
+        assert float(jnp.abs(lp["we_up"][:, :, f:]).max()) == 0.0
+        assert float(jnp.abs(lp["we_down"][:, f:]).max()) == 0.0
+        assert float(jnp.abs(lp["we_down"][:, :, h:]).max()) == 0.0
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+def test_an_expert_layer_reads_the_same_from_a_tree_padded_further(
+        dtype, monkeypatch):
+    """The zero rows and columns beyond the published widths carry no
+    number of the layer: the tree as stored (128 x 128) and the same tree
+    zero-padded on to 256 x 256, what ``expert_dims_stored`` gave until
+    ISSUE 55, give the same output bit for bit, through the one kernel
+    call and through ``ragged_dot`` alike."""
+    cfg = _cfg(dtype, experts_first=0, experts_count=8)
+    lp = hybrid_ssm.init_layer(cfg, jax.random.PRNGKey(SEED), "E")
+    assert lp["we_up"].shape == (8, 128, 128)
+    wider = dict(lp,
+                 we_up=jnp.pad(lp["we_up"], ((0, 0), (0, 128), (0, 128))),
+                 we_down=jnp.pad(lp["we_down"], ((0, 0), (0, 128), (0, 128))))
+    x = jnp.asarray(np.random.default_rng(6).normal(size=(12, 64)),
+                    jnp.float32)
+    carry = jnp.zeros((12, 0))
+
+    def through(tree):
+        # ``routed_experts`` pads its rows to the rule's hidden width.
+        monkeypatch.setattr(hybrid_ssm, "expert_dims_stored",
+                            lambda cfg: tree["we_up"].shape[1:])
+        return hybrid_ssm.routed_experts(cfg, tree, x, None, None, carry)
+    for form in ("pallas_ffn", "ragged_dot"):
+        assert latent_moe.grouped_product_form(
+            cfg, [lp["we_up"][None], lp["we_down"][None]], 12) == form
+        (out, counts, _), (out_w, counts_w, _) = through(lp), through(wider)
+        assert float(jnp.abs(out).max()) > 1e-3
+        np.testing.assert_array_equal(np.asarray(out, np.float32),
+                                      np.asarray(out_w, np.float32))
+        np.testing.assert_array_equal(np.asarray(counts),
+                                      np.asarray(counts_w))
+        monkeypatch.setattr(grouped_product, "serves", lambda *a: False)
 
 
 def test_router_weights_are_normalised_over_all_choices_whoever_holds_them(

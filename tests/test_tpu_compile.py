@@ -372,7 +372,7 @@ def test_pool_program_leaves_the_pool_in_place(one_chip, as_on_tpu,
 # every held expert ([2, 64, 2688, 1856] x 3: 4.36 GB of temporaries).
 ROUTED_TICKS = {
     "xing4.0-29b-a4b": (1, 2.35, 0.5),
-    "nemotron-3-nano-30b-a3b": (3, 1.48, 0.5),
+    "nemotron-3-nano-30b-a3b": (3, 1.32, 0.5),
     # Top-1 of 16 gated experts of 2048 x 2048 over 20 layers (PR 51).
     "zaya1-8b": (1, 2.68, 0.5),
     # 64 held of 256 gated experts of 2304 x 1024 over 8 expert sublayers,
@@ -407,6 +407,42 @@ def test_routed_tick_reads_the_experts_where_they_rest(one_chip, as_on_tpu,
     # The kernel is one operation of the layer body: the tick keeps the
     # two nested loops the benchmark files it by (steps, layers).
     assert text.count(" while(") == 2
+
+
+@pytest.mark.parametrize("program", [("decode", 256), ("chunk", 256, 1024)],
+                         ids=["tick", "chunk"])
+def test_whole_lane_widths_rest_as_stored(one_chip, as_on_tpu, monkeypatch,
+                                          program):
+    """``nemotron-3-nano-30b-a3b``'s experts at 2688 x 1920 (ISSUE 55:
+    whole lane-widths, no longer multiples of 256): the tick and a chunk
+    program take both stacks in the layout they are stored in (F minor
+    for up, H minor for down) and no operation of either program makes an
+    array of expert matrices — the trap of the published 1856, where the
+    device rested ``we_up`` transposed and every program copied every
+    held expert at its entry (PR 33)."""
+    from distributed_llm_tpu.models import hybrid_ssm
+    tier = _bench_tier(monkeypatch, "nemotron-3-nano-30b-a3b")
+    engine, _, compiled, _ = _pool_program(one_chip, tier, program)
+    assert hybrid_ssm.expert_dims_stored(engine.cfg) == (2688, 1920)
+    up, down = hybrid_ssm.expert_stacks(engine.params)
+    assert up.shape == (2, 64, 2688, 1920)
+    assert down.shape == (2, 64, 1920, 2688)
+    leaves = jax.tree_util.tree_flatten_with_path(engine.params)[0]
+    formats = jax.tree.leaves(compiled.input_formats[0][0])
+    stacks = [fmt for (path, _), fmt in zip(leaves, formats)
+              if jax.tree_util.keystr(path).endswith(("['we_up']",
+                                                      "['we_down']"))]
+    assert len(stacks) == 6                  # three ``E`` positions a period
+    assert all(fmt.layout.major_to_minor == (0, 1, 2, 3) for fmt in stacks)
+    results = filter(None, map(chip_smoke._HLO_RESULT.match,
+                               compiled.as_text().splitlines()))
+    made = [(m[2], m[3]) for m in results
+            if m[2].endswith(("2688,1920]", "1920,2688]"))
+            and m[3] not in ("parameter", "get-tuple-element", "bitcast")]
+    assert made == []
+    assert engine.grouped_product_form() == {"decode": "pallas_ffn",
+                                            "prefill": "pallas_ffn"}
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.5 * GB
 
 
 def test_a_pattern_with_a_lead_keeps_one_layer_loop_at_the_real_sizes(
