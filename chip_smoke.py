@@ -464,30 +464,19 @@ def pool_programs(engine, pool, windows: Sequence[int],
     takes the full span), the chunk-prefill program per (chunk, window)
     of ``chunks``, ``copy_block``.  Gives ``{label: (compiled, position
     of the pool argument)}``.  Nothing runs."""
-    import jax
-    import jax.numpy as jnp
-    params = engine.params
-    home = jax.tree.leaves(pool)[0].sharding
-
-    def arg(shape, dtype=jnp.int32):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=home)
-
-    b, bs = engine.paged.max_slots, engine.paged.block_size
+    bs = engine.paged.block_size
     mb = engine.paged.blocks_per_slot
     out = {}
     for w in windows:
         wb = mb if engine.ragged else w // bs
-        out[f"decode tick, window {wb * bs}"] = engine._decode_step().lower(
-            params, pool, arg((b, wb)), arg((b,)), arg((b,)),
-            arg((b,), jnp.float32), arg((2,), jnp.uint32)).compile(), 1
+        out[f"decode tick, window {wb * bs}"] = engine.compile_pool_program(
+            "decode", wb, pool), 1
     for c, w in chunks:
-        out[f"chunk prefill ({c}, {w})"] = engine._chunk_prefill_fn(
-            c, w).lower(params, pool, arg((1, c)), arg((1,)), arg((1,)),
-                        arg((mb,)), arg((2,), jnp.uint32),
-                        arg((), jnp.float32)).compile(), 1
+        out[f"chunk prefill ({c}, {w})"] = engine.compile_pool_program(
+            "chunk_prefill", (c, w), pool), 1
     if cow:
-        out["copy_block"] = engine._cow_copy_fn().lower(
-            pool, arg(()), arg(())).compile(), 0
+        out["copy_block"] = engine.compile_pool_program(
+            "copy_block", pool=pool), 0
     return out
 
 

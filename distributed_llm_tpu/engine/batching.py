@@ -29,6 +29,7 @@ step serves every occupancy, so the scheduler never recompiles.
 
 from __future__ import annotations
 
+import concurrent.futures
 import dataclasses
 import logging
 import queue
@@ -36,7 +37,7 @@ import threading
 import time
 from collections import deque
 from functools import partial
-from typing import Any, Dict, List, Optional, Sequence, Union
+from typing import Any, Dict, List, Optional, Sequence, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -398,6 +399,9 @@ class ContinuousBatchingEngine:
         # The attention form each compiled rung of the decode tick was
         # traced with, by its table window in tokens (``tick_stats``).
         self._attention_forms: Dict[str, str] = {}
+        # ``step_programs``' entries by (stage, key): empty until GET
+        # /debug/programs asks, and nothing but that route fills it.
+        self._program_maps: Dict[Tuple[str, Any], Dict[str, Any]] = {}
         # Routed-expert load (the latent and hybrid families): what the
         # tick and the chunk program return beside their tokens, summed on
         # the host, over the family's expert layers and the experts held.
@@ -1131,16 +1135,19 @@ class ContinuousBatchingEngine:
                 logits, pool, *n_exp = decode_step_paged(
                     cfg, params, cur, pos, pool, tables, attn=attn,
                     counts=moe_counts)
-                rng, sub = jax.random.split(rng)
+                with jax.named_scope("sample"):
+                    rng, sub = jax.random.split(rng)
                 nxt = _sample_batched(logits, sub, temps)
                 # Clamp: finished/overshooting slots keep writing into
                 # their own last cell instead of indexing past the table.
                 return ((pool, jnp.minimum(pos + 1, max_pos), nxt, rng),
                         (nxt, *n_exp))
 
-            key, rng = jax.random.split(rng)
-            (pool, pos, cur, _), toks = jax.lax.scan(
-                step, (pool, pos, cur, rng), None, length=steps)
+            with jax.named_scope("sample"):
+                key, rng = jax.random.split(rng)
+            with jax.named_scope("step_scan"):
+                (pool, pos, cur, _), toks = jax.lax.scan(
+                    step, (pool, pos, cur, rng), None, length=steps)
             # A slot that holds no sequence (its row starts at the trash
             # block) starts every tick where the host's mirrors keep
             # it: position 0, token 0.
@@ -1178,6 +1185,118 @@ class ContinuousBatchingEngine:
         fn = self._pool_program(chunk_prefill, 1, lead=1)
         self._prefill_fns[key] = fn
         return fn
+
+    def lower_pool_program(self, stage: str, key=None, pool=None):
+        """One of the engine's OWN pool programs, lowered on abstract
+        arguments against ``pool`` where it lives: the engine's pool (the
+        default: its shapes only), or the shapes of a pool on a described
+        chip (``jax.eval_shape(init_pool)``, as tests/test_tpu_compile.py
+        builds it beside a tiny engine).
+        ``stage`` ``"decode"`` takes the table window in blocks,
+        ``"chunk_prefill"`` (chunk, window) in tokens, ``"copy_block"``
+        nothing.  The ONE place that spells these programs' argument
+        lists outside the scheduler.  Nothing runs."""
+        pool = self.pool if pool is None else pool
+        home = jax.tree.leaves(pool)[0].sharding
+
+        def arg(shape, dtype=jnp.int32):
+            return jax.ShapeDtypeStruct(shape, dtype, sharding=home)
+
+        pool = jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=x.sharding), pool)
+        b, mb = self.paged.max_slots, self.paged.blocks_per_slot
+        if stage == "decode":
+            return self._decode_step().lower(
+                self.params, pool, arg((b, key)), arg((b,)), arg((b,)),
+                arg((b,), jnp.float32), arg((2,), jnp.uint32))
+        if stage == "chunk_prefill":
+            chunk, window = key
+            return self._chunk_prefill_fn(chunk, window).lower(
+                self.params, pool, arg((1, chunk)), arg((1,)), arg((1,)),
+                arg((mb,)), arg((2,), jnp.uint32), arg((), jnp.float32))
+        if stage == "copy_block":
+            return self._cow_copy_fn().lower(pool, arg(()), arg(()))
+        raise ValueError(f"no pool program of stage {stage!r}")
+
+    def compile_pool_program(self, stage: str, key=None, pool=None):
+        """``lower_pool_program``, compiled; for a program the engine has
+        served that is a hit of the persistent compile cache."""
+        return self.lower_pool_program(stage, key, pool).compile()
+
+    def step_programs(self, stage: Optional[str] = None,
+                      window_tokens: Optional[Sequence[int]] = None,
+                      ops: bool = True) -> List[Dict[str, Any]]:
+        """What GET /debug/programs says of this engine: for every decode
+        tick and chunk program ``_note_compile`` has recorded (of
+        ``stage`` and of the ``window_tokens`` given, where they are), its
+        stage, its name on a device trace's module line, its table window
+        (and chunk) in tokens, the tick's attention form and, with
+        ``ops``, the seconds the entry took to build (lowering, compiling,
+        reading the text) and ``ops``: the named scope of every operation
+        a trace of it can show (``obs/program_scopes.py``).
+
+        Each program is compiled again for that (``lower_pool_program``)
+        the FIRST time it is asked about with ``ops`` and kept by its key
+        (those a request lacks are built side by side); nothing on the
+        warm-up, admission or tick path comes here.  The scopes stand in
+        the executable's metadata, which JAX leaves out of the persistent
+        compile cache's key: the served programs' entries may have been
+        compiled from a tree that named its scopes otherwise.  So this
+        compile keys the cache WITH the metadata
+        (``jax_compilation_cache_include_metadata_in_key``): entries of
+        its own, found again by the next process that runs this very
+        code.  No runtime this was tried on gives what stands in the
+        parentheses of a trace's module name (``jit_decode_tick(<n>)``): a
+        reader joins a traced program to its entry by the operations both
+        name."""
+        bs = self.paged.block_size
+        wanted = []
+        for st in ("decode", "chunk_prefill"):
+            for key in sorted(self._compiled.get(st, ())):
+                window = key[0] * bs if st == "decode" else key[1]
+                if stage in (None, st) and (window_tokens is None
+                                            or window in window_tokens):
+                    wanted.append((st, key, window))
+        missing = [w for w in wanted if w[:2] not in self._program_maps]
+        if ops and missing:
+            with concurrent.futures.ThreadPoolExecutor(
+                    min(8, len(missing))) as workers:
+                for (st, key, _), built in zip(missing, workers.map(
+                        lambda w: self._program_map(*w[:2]), missing)):
+                    self._program_maps[st, key] = built
+        out = []
+        for st, key, window in wanted:
+            tick = st == "decode"
+            entry = {"stage": st,
+                     "program": "jit_decode_tick" if tick
+                     else "jit_chunk_prefill",
+                     "window_tokens": window,
+                     "chunk_tokens": None if tick else key[0],
+                     "attention_form": (self.decode_attention_form(window)
+                                        if tick else None)}
+            if ops:
+                entry.update(self._program_maps[st, key])
+            out.append(entry)
+        return out
+
+    def _program_map(self, stage: str, key) -> Dict[str, Any]:
+        """``{"built_s", "ops"}`` of one recorded program."""
+        from jax._src.config import (
+            compilation_cache_include_metadata_in_key as keyed_by_metadata)
+        from ..obs.program_scopes import op_scopes
+        stamps = [time.perf_counter()]
+        lowered = self.lower_pool_program(
+            stage, key[0] if stage == "decode" else key)
+        stamps.append(time.perf_counter())
+        with keyed_by_metadata(True):
+            compiled = lowered.compile()
+        stamps.append(time.perf_counter())
+        ops = op_scopes(compiled.as_text())
+        stamps.append(time.perf_counter())
+        return {"built_s": dict(zip(("lower", "compile", "read"),
+                                    (b - a for a, b in
+                                     zip(stamps, stamps[1:])))),
+                "ops": ops}
 
     def _writer_fn(self, nb: int):
         """Jitted pool scatter (donated pool → in-place page-in), one
@@ -2234,7 +2353,10 @@ class ContinuousBatchingEngine:
                 # cannot do without, for the token its slot starts from.
                 with self.profiler.phase("chunk_prefill"):
                     first = self._settle_chunk(pf)
-                self._finish_prefill(pf, int(first))
+                # The slot's going live is host work: a phase of its own,
+                # one stamp a request, not the device wait's.
+                with self.profiler.phase("first_token"):
+                    self._finish_prefill(pf, int(first))
                 return True
         except BaseException as exc:       # surface to the caller
             self._fail_prefill(pf, exc)

@@ -448,9 +448,12 @@ def _scan_layers(layer, x, layers, pool: KVPool):
         return layer(*carry, *scanned), None
 
     n_layers = pool[keys[0]].shape[0]
-    (x, pools), _ = jax.lax.scan(
-        body, (x, tuple(pool[key] for key in keys)),
-        (layers, jnp.arange(n_layers)))
+    # The loop's own slices of the stacked weights carry this scope; what
+    # a layer names keeps its own.
+    with jax.named_scope("layer_scan"):
+        (x, pools), _ = jax.lax.scan(
+            body, (x, tuple(pool[key] for key in keys)),
+            (layers, jnp.arange(n_layers)))
     return x, dict(zip(keys, pools))
 
 
@@ -487,11 +490,13 @@ def chunk_prefill_paged(
     d = cfg.head_dim
     bs = _block_size(pool)
 
-    positions = start[:, None] + jnp.arange(s_c)[None, :]    # [1, S_c]
-    q_pos = jnp.minimum(positions, jnp.maximum(true_len, 1)[:, None] - 1)
-    flat_pos = positions[0]                                  # [S_c]
-    blk = table[flat_pos // bs]                              # [S_c]
-    off = flat_pos % bs
+    with jax.named_scope("step_inputs"):
+        positions = start[:, None] + jnp.arange(s_c)[None, :]    # [1, S_c]
+        q_pos = jnp.minimum(positions,
+                            jnp.maximum(true_len, 1)[:, None] - 1)
+        flat_pos = positions[0]                                  # [S_c]
+        blk = table[flat_pos // bs]                              # [S_c]
+        off = flat_pos % bs
     family = cfg.family
     if family == "latent":
         hidden, new_pool, n_exp = latent_moe.forward_paged(
@@ -510,25 +515,27 @@ def chunk_prefill_paged(
         return (hidden, new_pool, n_exp) if counts else (hidden, new_pool)
 
     x = quant.embed_rows(params["embed"], tokens)            # [1, S_c, H]
-    sin, cos = transformer.rope_sincos(positions, d, cfg.rope_theta)
+    with jax.named_scope("step_inputs"):
+        sin, cos = transformer.rope_sincos(positions, d, cfg.rope_theta)
 
     def layer(x, pools, lp, i):
-        h_in = transformer.rms_norm(x, lp["ln1"], cfg.norm_eps)
-        q, k, v = transformer.project_qkv(cfg, lp, h_in)
-        q = transformer.apply_rope(q, sin, cos)
-        k = transformer.apply_rope(k, sin, cos)
+        with jax.named_scope("mixer_proj"):
+            h_in = transformer.rms_norm(x, lp["ln1"], cfg.norm_eps)
+            q, k, v = transformer.project_qkv(cfg, lp, h_in)
+            q = transformer.apply_rope(q, sin, cos)
+            k = transformer.apply_rope(k, sin, cos)
 
-        # Write the chunk's K/V rows to their (block, offset) cells, then
-        # gather and attend the table window.
-        with jax.named_scope("kv_write"):
-            pools = _write_rows(pools, i, blk, off, k[0], v[0])
-        with jax.named_scope("attention"):
-            k_p, v_p, ks_p, vs_p = _whole(pools)
-            attn = attention.paged_chunk(
-                q, k_p, v_p, table, q_pos, window,
-                k_scale=ks_p, v_scale=vs_p, layer=i)
-        x = x + quant.matmul(attn.reshape(b, s_c, cfg.num_heads * d),
-                             lp["wo"])
+            # Write the chunk's K/V rows to their (block, offset) cells,
+            # then gather and attend the table window.
+            with jax.named_scope("kv_write"):
+                pools = _write_rows(pools, i, blk, off, k[0], v[0])
+            with jax.named_scope("attention"):
+                k_p, v_p, ks_p, vs_p = _whole(pools)
+                attn = attention.paged_chunk(
+                    q, k_p, v_p, table, q_pos, window,
+                    k_scale=ks_p, v_scale=vs_p, layer=i)
+            x = x + quant.matmul(attn.reshape(b, s_c, cfg.num_heads * d),
+                                 lp["wo"])
         with jax.named_scope("ffn"):
             h_ffn = transformer.rms_norm(x, lp["ln2"], cfg.norm_eps)
             if cfg.num_experts > 1:
@@ -541,7 +548,8 @@ def chunk_prefill_paged(
         return x, pools
 
     x, new_pool = _scan_layers(layer, x, params["layers"], pool)
-    hidden = transformer.rms_norm(x, params["final_ln"], cfg.norm_eps)
+    with jax.named_scope("head"):
+        hidden = transformer.rms_norm(x, params["final_ln"], cfg.norm_eps)
     return hidden, new_pool
 
 
@@ -586,7 +594,8 @@ def verify_step_paged(
     x = quant.embed_rows(params["embed"], tokens)      # [B, G, H]
     positions = pos[:, None] + jnp.arange(g)[None]     # [B, G]
     wpos = jnp.minimum(positions, max_pos)
-    sin, cos = transformer.rope_sincos(wpos, d, cfg.rope_theta)
+    with jax.named_scope("step_inputs"):
+        sin, cos = transformer.rope_sincos(wpos, d, cfg.rope_theta)
 
     # Overflowing rows route to the reserved trash block: a clamped
     # write would land INSIDE the slot's live frontier and corrupt
@@ -597,28 +606,29 @@ def verify_step_paged(
         TRASH_BLOCK)                                   # [B, G]
     off = wpos % bs
     def layer(x, pools, lp, i):
-        h_in = transformer.rms_norm(x, lp["ln1"], cfg.norm_eps)
-        q, k, v = transformer.project_qkv(cfg, lp, h_in)
-        q = transformer.apply_rope(q, sin, cos)
-        k = transformer.apply_rope(k, sin, cos)
+        with jax.named_scope("mixer_proj"):
+            h_in = transformer.rms_norm(x, lp["ln1"], cfg.norm_eps)
+            q, k, v = transformer.project_qkv(cfg, lp, h_in)
+            q = transformer.apply_rope(q, sin, cos)
+            k = transformer.apply_rope(k, sin, cos)
 
-        # Write-before-attend for the whole chunk: the [B, G] rows go to
-        # (blk[b, g], off[b, g]) — trash rows collide harmlessly like
-        # idle decode slots.
-        with jax.named_scope("kv_write"):
-            pools = _write_rows(pools, i, blk, off, k, v)
+            # Write-before-attend for the whole chunk: the [B, G] rows go to
+            # (blk[b, g], off[b, g]) — trash rows collide harmlessly like
+            # idle decode slots.
+            with jax.named_scope("kv_write"):
+                pools = _write_rows(pools, i, blk, off, k, v)
 
-        with jax.named_scope("attention"):
-            if attn is not None:
-                attn_out = _hooked(attn, q, pools, i, tables, pos)
-            else:
-                k_p, v_p, ks_p, vs_p = _whole(pools)
-                attn_out = attention.ragged_verify(
-                    q, k_p, v_p, tables, pos,
-                    k_scale=ks_p, v_scale=vs_p, layer=i)  # [B, G, Nq, d]
+            with jax.named_scope("attention"):
+                if attn is not None:
+                    attn_out = _hooked(attn, q, pools, i, tables, pos)
+                else:
+                    k_p, v_p, ks_p, vs_p = _whole(pools)
+                    attn_out = attention.ragged_verify(
+                        q, k_p, v_p, tables, pos,
+                        k_scale=ks_p, v_scale=vs_p, layer=i)  # [B, G, Nq, d]
 
-        x = x + quant.matmul(
-            attn_out.reshape(b, g, cfg.num_heads * d), lp["wo"])
+            x = x + quant.matmul(
+                attn_out.reshape(b, g, cfg.num_heads * d), lp["wo"])
         with jax.named_scope("ffn"):
             h_ffn = transformer.rms_norm(x, lp["ln2"], cfg.norm_eps)
             if cfg.num_experts > 1:
@@ -631,7 +641,8 @@ def verify_step_paged(
         return x, pools
 
     x, new_pool = _scan_layers(layer, x, params["layers"], pool)
-    hidden = transformer.rms_norm(x, params["final_ln"], cfg.norm_eps)
+    with jax.named_scope("head"):
+        hidden = transformer.rms_norm(x, params["final_ln"], cfg.norm_eps)
     return transformer.logits_from_hidden(params, hidden), new_pool
 
 
@@ -672,8 +683,10 @@ def decode_step_paged(
     d = cfg.head_dim
     bs = _block_size(pool)
 
-    blk = jnp.take_along_axis(tables, (pos // bs)[:, None], axis=1)[:, 0]
-    off = pos % bs                                     # [B]
+    with jax.named_scope("step_inputs"):
+        blk = jnp.take_along_axis(tables, (pos // bs)[:, None],
+                                  axis=1)[:, 0]
+        off = pos % bs                                 # [B]
     family = cfg.family
     if family == "latent":
         hidden, new_pool, n_exp = latent_moe.forward_paged(
@@ -694,42 +707,44 @@ def decode_step_paged(
         return (logits, new_pool, n_exp) if counts else (logits, new_pool)
 
     x = quant.embed_rows(params["embed"], token)       # [B, H]
-    sin, cos = transformer.rope_sincos(pos, d, cfg.rope_theta)
+    with jax.named_scope("step_inputs"):
+        sin, cos = transformer.rope_sincos(pos, d, cfg.rope_theta)
 
     def layer(x, pools, lp, i):
-        h_in = transformer.rms_norm(x, lp["ln1"], cfg.norm_eps)
-        q, k, v = transformer.project_qkv(cfg, lp, h_in)
-        q = transformer.apply_rope(q, sin, cos)
-        k = transformer.apply_rope(k, sin, cos)
+        with jax.named_scope("mixer_proj"):
+            h_in = transformer.rms_norm(x, lp["ln1"], cfg.norm_eps)
+            q, k, v = transformer.project_qkv(cfg, lp, h_in)
+            q = transformer.apply_rope(q, sin, cos)
+            k = transformer.apply_rope(k, sin, cos)
 
-        # Write-before-attend at (block, offset), one row a slot — active
-        # slots hit distinct blocks, idle ones collide in trash.
-        with jax.named_scope("kv_write"):
-            pools = _write_rows(pools, i, blk, off, k, v)
+            # Write-before-attend at (block, offset), one row a slot — active
+            # slots hit distinct blocks, idle ones collide in trash.
+            with jax.named_scope("kv_write"):
+                pools = _write_rows(pools, i, blk, off, k, v)
 
-        # Attend this slot's logical window: position p is
-        # (table[p//bs], p%bs), over the carried pool WHOLE
-        # (ops.attention.decode_form names the form).  An engine that
-        # opted into kernels walks the block table in the kernel of
-        # ops/rows_attention.py, which copies the slot's live blocks
-        # from the pool where it rests (ISSUE 45); elsewhere the XLA
-        # path gathers whole rows [B, S, N_kv * D] and contracts over
-        # the merged axis (ops.attention.merged_decode_attention).
-        # Both pay a query of one token N_kv times the multiplications
-        # to never split the head axis off the window, which on a TPU
-        # is a copy.  The chunk and verify steps' queries are long:
-        # they keep the split ([B, S, N_kv, D]) and chunk_attention.
-        with jax.named_scope("attention"):
-            if attn is not None:
-                attn_out = _hooked(attn, q, pools, i, tables, pos)
-            else:
-                k_p, v_p, ks_p, vs_p = _whole(pools)
-                attn_out = attention.paged_decode(
-                    q, k_p, v_p, tables, pos, impl=cfg.attention_impl,
-                    k_scale=ks_p, v_scale=vs_p, layer=i)
+            # Attend this slot's logical window: position p is
+            # (table[p//bs], p%bs), over the carried pool WHOLE
+            # (ops.attention.decode_form names the form).  An engine that
+            # opted into kernels walks the block table in the kernel of
+            # ops/rows_attention.py, which copies the slot's live blocks
+            # from the pool where it rests (ISSUE 45); elsewhere the XLA
+            # path gathers whole rows [B, S, N_kv * D] and contracts over
+            # the merged axis (ops.attention.merged_decode_attention).
+            # Both pay a query of one token N_kv times the multiplications
+            # to never split the head axis off the window, which on a TPU
+            # is a copy.  The chunk and verify steps' queries are long:
+            # they keep the split ([B, S, N_kv, D]) and chunk_attention.
+            with jax.named_scope("attention"):
+                if attn is not None:
+                    attn_out = _hooked(attn, q, pools, i, tables, pos)
+                else:
+                    k_p, v_p, ks_p, vs_p = _whole(pools)
+                    attn_out = attention.paged_decode(
+                        q, k_p, v_p, tables, pos, impl=cfg.attention_impl,
+                        k_scale=ks_p, v_scale=vs_p, layer=i)
 
-        x = x + quant.matmul(attn_out.reshape(b, cfg.num_heads * d),
-                             lp["wo"])
+            x = x + quant.matmul(attn_out.reshape(b, cfg.num_heads * d),
+                                 lp["wo"])
         with jax.named_scope("ffn"):
             h_ffn = transformer.rms_norm(x, lp["ln2"], cfg.norm_eps)
             if cfg.num_experts > 1:
@@ -741,5 +756,6 @@ def decode_step_paged(
         return x, pools
 
     x, new_pool = _scan_layers(layer, x, params["layers"], pool)
-    hidden = transformer.rms_norm(x, params["final_ln"], cfg.norm_eps)
+    with jax.named_scope("head"):
+        hidden = transformer.rms_norm(x, params["final_ln"], cfg.norm_eps)
     return transformer.logits_from_hidden(params, hidden), new_pool

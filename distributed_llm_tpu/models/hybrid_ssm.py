@@ -504,25 +504,43 @@ def conv_chunk(lp: Params, tail, a, n_valid):
             jax.lax.dynamic_slice_in_dim(seq, n_valid, k - 1, axis=0))
 
 
-def through_rows(pool, li, ctx, scan, step):
+def through_rows(pool, li, ctx, scan, step, kind: str):
     """Layer ``li``'s state and tail through a row kind's recurrence: a
     chunk of one sequence takes its row (zeros where the sequence is
     fresh) through ``scan(state, tail, n_valid)``, a decode step takes
     every row through ``step(src, state, tail, valid)`` (``src`` the batch
     index a row reads its token from); both give (y, state, tail).
-    Returns (y [B, S, width], the pool with the rows written back)."""
+    Returns (y [B, S, width], the pool with the rows written back).  The
+    rows' read and write-back carry the scopes of the recurrence whose
+    rows they are (``kind`` "ssm" or "kda": the state under
+    ``<kind>_scan`` / ``<kind>_step``, the tail under ``<kind>_conv``), so
+    an update the compiler fuses with its write-back has one name."""
     s_all, t_all = pool["s"], pool["t"]
+    of_tail = jax.named_scope(f"{kind}_conv")
     if "row" in ctx:                               # a chunk of one sequence
         row, fresh = ctx["row"], ctx["fresh"]
-        state = jnp.where(fresh, 0.0, s_all[li, row])
-        tail = jnp.where(fresh, jnp.zeros((), t_all.dtype), t_all[li, row])
+        of_state = jax.named_scope(f"{kind}_scan")
+        with of_state:
+            state = jnp.where(fresh, 0.0, s_all[li, row])
+        with of_tail:
+            tail = jnp.where(fresh, jnp.zeros((), t_all.dtype),
+                             t_all[li, row])
         y, state, tail = scan(state, tail, ctx["n_valid"])
-        return y[None], {**pool, "s": s_all.at[li, row].set(state),
-                         "t": t_all.at[li, row].set(tail)}
+        with of_state:
+            y, s_all = y[None], s_all.at[li, row].set(state)
+        with of_tail:
+            return y, {**pool, "s": s_all, "t": t_all.at[li, row].set(tail)}
     src, valid, dst = ctx["rows"]                  # a decode step, by rows
-    y, state, tail = step(src, s_all[li], t_all[li], valid)
-    return y[dst][:, None], {**pool, "s": s_all.at[li].set(state),
-                             "t": t_all.at[li].set(tail)}
+    of_state = jax.named_scope(f"{kind}_step")
+    with of_state:
+        state = s_all[li]
+    with of_tail:
+        tail = t_all[li]
+    y, state, tail = step(src, state, tail, valid)
+    with of_state:
+        y, s_all = y[dst][:, None], s_all.at[li].set(state)
+    with of_tail:
+        return y, {**pool, "s": s_all, "t": t_all.at[li].set(tail)}
 
 
 # =============================================================================
@@ -665,7 +683,7 @@ def mamba1(cfg: ModelConfig, lp: Params, h_in, pool, li, ctx):
         pool, li, ctx,
         lambda state, tail, n: mamba1_scan(cfg, lp, a[0], state, tail, n),
         lambda src, state, tail, valid: mamba1_step(
-            cfg, lp, a[src, 0], state, tail, valid))
+            cfg, lp, a[src, 0], state, tail, valid), "ssm")
     out = (m * jax.nn.silu(z.astype(jnp.float32))).astype(h_in.dtype)
     return quant.matmul(out, lp["w_out"]), pool, m
 
@@ -788,7 +806,7 @@ def _mamba(cfg: ModelConfig, lp: Params, h_in, pool, li, ctx):
         lambda state, tail, n: ssm_scan(cfg, lp, xbc[0], dt[0], state, tail,
                                         n),
         lambda src, state, tail, valid: ssm_step(
-            cfg, lp, xbc[src, 0], dt[src, 0], state, tail, valid))
+            cfg, lp, xbc[src, 0], dt[src, 0], state, tail, valid), "ssm")
     return quant.matmul(_gate_norm(cfg, lp, y, z), lp["w_out"]), pool
 
 
@@ -1144,7 +1162,7 @@ def _kda(cfg: ModelConfig, lp: Params, h_in, pool, li, ctx):
                                         state, tail, n),
         lambda src, state, tail, valid: kda_step(
             cfg, lp, qkv[src, 0], g[src, 0], beta[src, 0], state, tail,
-            valid))
+            valid), "kda")
     with jax.named_scope("kda_out_norm"):
         # An RMSNorm a head under ONE gain, times the sigmoid gate.
         o = o.reshape(*o.shape[:-1], nh, d)
@@ -1321,23 +1339,26 @@ def forward_paged(cfg: ModelConfig, params: Params, tokens: jax.Array,
         """One sublayer of ``kind``, the ``li``-th of its kind -> (x,
         carried, routed, the counts of an "E" or None)."""
         counts = None
-        h_f32 = _norm_f32(x, lp["ln"], cfg.norm_eps)
-        if kind == "M" and cfg.ssm_dt_rank:
-            out, carried, _ = mamba1(cfg, lp, h_f32.astype(dtype), carried,
-                                     li, ctx)
-        elif kind == "-":
-            with jax.named_scope("ffn"):
+        # Whatever of a sublayer no scope of its own names (its norm, a
+        # projection outside ``*_proj``, the merge) is the feed-forward's
+        # or the mixer's.
+        with jax.named_scope("ffn" if kind in "-E" else "mixer_proj"):
+            h_f32 = _norm_f32(x, lp["ln"], cfg.norm_eps)
+            if kind == "M" and cfg.ssm_dt_rank:
+                out, carried, _ = mamba1(cfg, lp, h_f32.astype(dtype),
+                                         carried, li, ctx)
+            elif kind == "-":
                 out = transformer._swiglu(h_f32.astype(dtype), lp["w_gate"],
                                           lp["w_up"], lp["w_down"])
-        elif kind == "E":
-            out, counts, routed = _experts(cfg, lp, h_f32, stacked, p,
-                                           routed)
-        else:
-            mixer = {"M": _mamba, "*": _attention, "C": _cca, "K": _kda,
-                     "L": _latent}[kind]
-            out, carried = mixer(cfg, lp, h_f32.astype(dtype), carried, li,
-                                 ctx)
-        return _merge(lp, x, out), carried, routed, counts
+            elif kind == "E":
+                out, counts, routed = _experts(cfg, lp, h_f32, stacked, p,
+                                               routed)
+            else:
+                mixer = {"M": _mamba, "*": _attention, "C": _cca, "K": _kda,
+                         "L": _latent}[kind]
+                out, carried = mixer(cfg, lp, h_f32.astype(dtype), carried,
+                                     li, ctx)
+            return _merge(lp, x, out), carried, routed, counts
 
     # The MLP router's state of every token, carried from expert layer to
     # expert layer (zero-wide under the router that carries nothing).
@@ -1376,9 +1397,11 @@ def forward_paged(cfg: ModelConfig, params: Params, tokens: jax.Array,
         return (x, carried, routed), jnp.stack(counts) if counts else None
 
     n_periods = (cfg.num_layers - len(lead)) // len(period)
-    (x, carried, _), counts = jax.lax.scan(
-        body, (x, carried, routed), (layers, jnp.arange(n_periods)))
-    hidden = transformer.rms_norm(x, params["final_ln"], cfg.norm_eps)
+    with jax.named_scope("layer_scan"):
+        (x, carried, _), counts = jax.lax.scan(
+            body, (x, carried, routed), (layers, jnp.arange(n_periods)))
+    with jax.named_scope("head"):
+        hidden = transformer.rms_norm(x, params["final_ln"], cfg.norm_eps)
     parts = [n[None] for n in lead_counts]
     if counts is not None:
         parts.append(counts.reshape(-1, counts.shape[-1]))
@@ -1391,15 +1414,18 @@ def chunk_ctx(pool, table, start, true_len, s_c: int, window: int,
               blk, off, q_pos):
     """One sequence's chunk: claims (``start == 0``) or finds its row.
     Returns (ctx, the pool with the row named)."""
-    fresh = start[0] == 0
-    row, owner = claim_row(pool["owner"], table[0], fresh)
-    return {"row": row, "fresh": fresh,
-            "n_valid": jnp.clip(true_len[0] - start[0], 0, s_c),
-            "table": table, "start": start, "q_pos": q_pos,
-            "window": window, "blk": blk[None], "off": off[None]}, {
-                **pool, "owner": owner}
+    with jax.named_scope("step_inputs"):
+        fresh = start[0] == 0
+        row, owner = claim_row(pool["owner"], table[0], fresh)
+        return {"row": row, "fresh": fresh,
+                "n_valid": jnp.clip(true_len[0] - start[0], 0, s_c),
+                "table": table, "start": start, "q_pos": q_pos,
+                "window": window, "blk": blk[None], "off": off[None]}, {
+                    **pool, "owner": owner}
 
 
 def decode_ctx(pool, tables, pos, blk, off):
-    return {"rows": rows_of(pool["owner"], tables[:, 0]), "tables": tables,
-            "pos": pos, "blk": blk[:, None], "off": off[:, None]}
+    with jax.named_scope("step_inputs"):
+        return {"rows": rows_of(pool["owner"], tables[:, 0]),
+                "tables": tables, "pos": pos, "blk": blk[:, None],
+                "off": off[:, None]}

@@ -528,10 +528,12 @@ def _block(cfg: ModelConfig, lp: Params, x, pool_c, i, *, moe: bool,
     """One layer over x [B, S, n, H] float32, pool layer ``i``; returns
     (x, pool array, counts).  ``stacked``: see ``routed_experts``."""
     def attention(mixed):
-        h_in = transformer.rms_norm(mixed, lp["ln1"], cfg.norm_eps)
-        out, pool = _attend(cfg, lp, h_in.astype(jnp.dtype(cfg.dtype)), sin,
-                            cos, q_pos, pool_c, i, blk, off, tables, absorbed)
-        return quant.matmul(out, lp["wo"]), pool
+        with jax.named_scope("mixer_proj"):
+            h_in = transformer.rms_norm(mixed, lp["ln1"], cfg.norm_eps)
+            out, pool = _attend(cfg, lp, h_in.astype(jnp.dtype(cfg.dtype)),
+                                sin, cos, q_pos, pool_c, i, blk, off, tables,
+                                absorbed)
+            return quant.matmul(out, lp["wo"]), pool
 
     def ffn(mixed):
         with jax.named_scope("ffn"):
@@ -556,9 +558,11 @@ def forward_paged(cfg: ModelConfig, params: Params, tokens: jax.Array,
         absorbed = tokens.shape[1] == 1
     n = cfg.residual_streams
     x = quant.embed_rows(params["embed"], tokens)             # [B, S, H]
-    x = jnp.broadcast_to(x.astype(jnp.float32)[..., None, :],
-                         x.shape[:-1] + (n, x.shape[-1]))
-    sin, cos = rope_sincos(cfg, positions)
+    with jax.named_scope("embed"):
+        x = jnp.broadcast_to(x.astype(jnp.float32)[..., None, :],
+                             x.shape[:-1] + (n, x.shape[-1]))
+    with jax.named_scope("step_inputs"):
+        sin, cos = rope_sincos(cfg, positions)
     kw = dict(sin=sin, cos=cos, q_pos=q_pos, blk=blk, off=off,
               tables=tables, absorbed=absorbed)
     pool_c = pool["c"]
@@ -583,10 +587,12 @@ def forward_paged(cfg: ModelConfig, params: Params, tokens: jax.Array,
                                    stacked=stacked, **kw)
         return (x, pool_c), counts
 
-    (x, pool_c), counts = jax.lax.scan(
-        body, (x, pool_c), (layers, jnp.arange(n_lead, cfg.num_layers)))
-    x = jnp.sum(x, axis=-2).astype(jnp.dtype(cfg.dtype))
-    hidden = transformer.rms_norm(x, params["final_ln"], cfg.norm_eps)
+    with jax.named_scope("layer_scan"):
+        (x, pool_c), counts = jax.lax.scan(
+            body, (x, pool_c), (layers, jnp.arange(n_lead, cfg.num_layers)))
+    with jax.named_scope("head"):
+        x = jnp.sum(x, axis=-2).astype(jnp.dtype(cfg.dtype))
+        hidden = transformer.rms_norm(x, params["final_ln"], cfg.norm_eps)
     return hidden, {"c": pool_c}, counts
 
 

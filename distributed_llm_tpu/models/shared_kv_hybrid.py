@@ -377,16 +377,20 @@ def _mlp(cfg: ModelConfig, lp: Params, x):
 def _layer(cfg, kind, lp, x, pool, mem, layer, index, ctx):
     """One layer of ``kind`` but ``F``: (x, pool, mem).  ``layer`` its
     index in the model, ``index`` among the layers of its kind."""
-    h_in = layer_norm(x, lp["ln1_w"], lp["ln1_b"], cfg.norm_eps)
-    if kind == "M":
-        out, pool, mem = _mamba(cfg, lp, h_in, pool, index, ctx)
-    elif kind == "W":
-        out, pool = _window(cfg, lp, h_in, pool, index, layer, ctx)
-    elif kind == "G":
-        out = _gated_memory(lp, h_in, mem)
-    else:
-        out = _cross(cfg, lp, h_in, pool, layer, ctx)
-    return _mlp(cfg, lp, x + out), pool, mem
+    # Whatever of a mixer no scope of its own names: the norm, the
+    # projections, the residual.
+    with jax.named_scope("mixer_proj"):
+        h_in = layer_norm(x, lp["ln1_w"], lp["ln1_b"], cfg.norm_eps)
+        if kind == "M":
+            out, pool, mem = _mamba(cfg, lp, h_in, pool, index, ctx)
+        elif kind == "W":
+            out, pool = _window(cfg, lp, h_in, pool, index, layer, ctx)
+        elif kind == "G":
+            out = _gated_memory(lp, h_in, mem)
+        else:
+            out = _cross(cfg, lp, h_in, pool, layer, ctx)
+        x = x + out
+    return _mlp(cfg, lp, x), pool, mem
 
 
 def _run_segments(cfg, segments, x, pool, mem, ctx):
@@ -413,8 +417,9 @@ def _run_segments(cfg, segments, x, pool, mem, ctx):
                 ([jax.tree_util.tree_map(lambda a: a[0], lp)
                   for lp in weights], 0))
         else:
-            (x, pool, mem), _ = jax.lax.scan(
-                body, (x, pool, mem), (weights, jnp.arange(reps)))
+            with jax.named_scope("layer_scan"):
+                (x, pool, mem), _ = jax.lax.scan(
+                    body, (x, pool, mem), (weights, jnp.arange(reps)))
     return x, pool, mem
 
 
@@ -444,16 +449,20 @@ def forward_paged(cfg: ModelConfig, params: Params, tokens: jax.Array,
 
     x, carried, mem = _run_segments(cfg, segments[:f_at], x, carried,
                                     mem, ctx)
-    h_in = layer_norm(x, lp_f["ln1_w"], lp_f["ln1_b"], cfg.norm_eps)
-    q, carried = _shared_write(cfg, lp_f, h_in, carried, ctx)
+    with jax.named_scope("mixer_proj"):
+        h_in = layer_norm(x, lp_f["ln1_w"], lp_f["ln1_b"], cfg.norm_eps)
+        q, carried = _shared_write(cfg, lp_f, h_in, carried, ctx)
 
     def rest(x):
-        out = _shared_attention(cfg, lp_f, q, carried, f_layer, ctx, dtype)
-        x = _mlp(cfg, lp_f, x + out)
+        with jax.named_scope("mixer_proj"):
+            x = x + _shared_attention(cfg, lp_f, q, carried, f_layer, ctx,
+                                      dtype)
+        x = _mlp(cfg, lp_f, x)
         x, _, _ = _run_segments(cfg, segments[f_at + 1:], x, carried,
                                 mem, ctx)
-        return layer_norm(x, params["final_ln_w"], params["final_ln_b"],
-                          cfg.norm_eps)
+        with jax.named_scope("head"):
+            return layer_norm(x, params["final_ln_w"], params["final_ln_b"],
+                              cfg.norm_eps)
 
     if "last" in ctx:
         hidden = jax.lax.cond(ctx["last"], rest, jnp.zeros_like, x)

@@ -171,7 +171,8 @@ def logits_from_hidden(params: Params, hidden: jax.Array) -> jax.Array:
     """LM head: [..., H] -> [..., V] in float32.  The tree's own "head"
     [V, H] where it holds one (``ModelConfig.tie_embeddings`` False),
     else the embedding, tied."""
-    return quant.tied_head(params.get("head", params["embed"]), hidden)
+    with jax.named_scope("head"):
+        return quant.tied_head(params.get("head", params["embed"]), hidden)
 
 
 # =============================================================================

@@ -765,7 +765,8 @@ BOUNDED_LABELS: Dict[str, str] = {
     "stage": "closed set: prefill|chunk_prefill|writer|decode",
     "phase": "closed set: obs/profiler.py PHASES (admit|prefill|cow_copy|"
              "prepare|table_upload|decode|dispatch|fetch|draft|verify|"
-             "account|emit|chunk_prefill|demote|promote|idle_wait)",
+             "account|emit|chunk_prefill|first_token|demote|promote|"
+             "idle_wait)",
     "session": "open set: BoundedLabels(cap=256) — 64-char truncation, "
                "257th distinct value collapses to '~overflow'",
     "tenant": "open set: BoundedLabels(cap=256) — 64-char truncation, "

@@ -161,7 +161,8 @@ from .metrics import DEFAULT_BUCKETS_MS
 # stamps a tick phase.
 PHASES = ("admit", "prefill", "cow_copy", "prepare", "table_upload",
           "decode", "dispatch", "fetch", "draft", "verify", "account",
-          "emit", "chunk_prefill", "demote", "promote", "idle_wait")
+          "emit", "chunk_prefill", "first_token", "demote", "promote",
+          "idle_wait")
 
 # The names the sampler's timeline (``tick_phases``) and the
 # ``dllm_tick_phase_p50_ms`` gauge carried before ``prepare``,

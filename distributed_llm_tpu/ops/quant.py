@@ -110,9 +110,11 @@ def embed_rows(embed: Any, tokens: jax.Array) -> jax.Array:
     Quantized tables carry PER-ROW scales (s [V, 1]): each token's row has
     its own dynamic range, so rare small-norm tokens keep full int8
     resolution instead of being crushed by a column-wide max."""
-    if not is_quantized(embed):
-        return embed[tokens]
-    return embed["q"][tokens].astype(embed["s"].dtype) * embed["s"][tokens]
+    with jax.named_scope("embed"):
+        if not is_quantized(embed):
+            return embed[tokens]
+        return (embed["q"][tokens].astype(embed["s"].dtype)
+                * embed["s"][tokens])
 
 
 def tied_head(embed: Any, hidden: jax.Array) -> jax.Array:

@@ -354,6 +354,41 @@ def create_app(router: Optional[Router] = None,
         body = fn(**window) if callable(fn) else {"traceEvents": []}
         return jsonify(body)
 
+    @app.route("/debug/programs", methods=["GET"])
+    def debug_programs():
+        """Every live engine's step programs, operation by operation:
+        ``{"tiers": {<tier>: [{"stage": "decode" | "chunk_prefill",
+        "program": "jit_decode_tick" | "jit_chunk_prefill",
+        "window_tokens", "chunk_tokens", "attention_form", "built_s":
+        {"lower", "compile", "read"}, "ops": {<HLO instruction>:
+        {"scope": <innermost jax.named_scope or null>, "mixed":
+        <bool>}}}]}}`` — the join between a device trace's operation
+        names and the model code's scopes (obs/program_scopes.py);
+        ``ops`` stands in the compiled text's own order (a reader parts
+        two programs that hold the same names by it).
+        ``?stage=decode|chunk_prefill`` and ``?window_tokens=<n>[,<n>]``
+        select programs; ``?ops=0`` lists them and builds nothing.  The
+        first request about a program compiles it again, under a cache
+        key that holds the metadata, and the engine keeps the answer; a
+        process that never asks pays nothing.  Not the profiler's:
+        DLLM_PROFILE=0 leaves it on."""
+        select = {"ops": request.args.get("ops", "1") != "0"}
+        stage = request.args.get("stage")
+        if stage is not None:
+            if stage not in ("decode", "chunk_prefill"):
+                return jsonify({"error": "Request failed: 'stage' is "
+                                         "decode or chunk_prefill"}), 400
+            select["stage"] = stage
+        raw = request.args.get("window_tokens")
+        if raw is not None:
+            try:
+                select["window_tokens"] = [int(w) for w in raw.split(",")]
+            except ValueError:
+                return jsonify({"error": "Request failed: 'window_tokens' "
+                                         "must be whole numbers"}), 400
+        fn = getattr(state["router"], "step_programs", None)
+        return jsonify(fn(**select) if callable(fn) else {"tiers": {}})
+
     @app.route("/stats", methods=["GET"])
     def stats():
         """Observability snapshot (SURVEY.md §5.5): routing-cache health,

@@ -621,11 +621,9 @@ class Router:
         rows.sort(key=lambda r: r["device_time_ms"], reverse=True)
         return rows
 
-    def _live_profilers(self):
-        """(label, TickProfiler) of every live engine that has one on.
-        Advisory reads — never the lifecycle lock; tiers without a
-        profiler (remote, sequential, DLLM_PROFILE=0) contribute
-        nothing."""
+    def _live_engines(self):
+        """(label, engine) of every live engine.  Advisory reads — never
+        the lifecycle lock."""
         for name, tier in self.tiers.items():
             mgr = tier.server_manager
             subs = getattr(mgr, "live_engines", None)
@@ -636,10 +634,28 @@ class Router:
                 engines = [(f"{name}/{key}", eng) for key, eng in subs()]
             else:
                 engines = [(name, getattr(mgr, "_engine", None))]
-            for label, engine in engines:
-                prof = getattr(engine, "profiler", None)
-                if prof is not None and getattr(prof, "enabled", False):
-                    yield label, prof
+            yield from ((label, engine) for label, engine in engines
+                        if engine is not None)
+
+    def _live_profilers(self):
+        """(label, TickProfiler) of every live engine that has one on;
+        tiers without a profiler (remote, sequential, DLLM_PROFILE=0)
+        contribute nothing."""
+        for label, engine in self._live_engines():
+            prof = getattr(engine, "profiler", None)
+            if prof is not None and getattr(prof, "enabled", False):
+                yield label, prof
+
+    def step_programs(self, **select) -> Dict[str, Any]:
+        """The GET /debug/programs body: per live batching engine, its
+        decode tick and chunk programs (``select``: ``stage``,
+        ``window_tokens``, ``ops``) with the named scope of each of their
+        operations (``engine.step_programs``; the FIRST request about a
+        program compiles it again, later ones read the engine's copy)."""
+        return {"tiers": {label: engine.step_programs(**select)
+                          for label, engine in self._live_engines()
+                          if callable(getattr(engine, "step_programs",
+                                              None))}}
 
     def profiler_trace(self, since: Optional[float] = None,
                        until: Optional[float] = None) -> Dict[str, Any]:

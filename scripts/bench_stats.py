@@ -13,14 +13,11 @@ benchmark's own readers
 (``benchmark/layer_metrics/host_readers.py``), so an untraced run shows
 them too; and, from ``/debug/trace`` (the rings' last two minutes), the
 scheduler phase that stamped each awake slice's first token.  With
-``--trace 1``, the chunk programs' device time by COMPILED PROGRAM (one a
-window rung, PR 42): the whole ``jit_chunk_prefill`` executions of the
-device trace grouped by the fingerprint in their name; and the decode
-tick's ATTENTION scope by rung (PR 45): the device time a step of the
-operations whose HLO metadata names the ``attention`` scope — the window
-traffic: a gather and two products a side in the ``merged`` form, one
-kernel in the ``streamed`` one — read off each warmed rung's own
-compiled program, by operation.
+``--trace 1``, the line of ``benchmark/layer_metrics/scope_readers.py``
+for the tick and for the chunk program, whichever of the two the cell's
+own metrics did not ask about: device time by ``jax.named_scope`` (the
+program's own ``GET /debug/programs`` says which scope each traced
+operation belongs to) and by window rung, each rung with its scopes.
 
     python3 scripts/bench_stats.py --workload smollm2-1.7b.decode-closed \
         --seed 7 --seconds 50 --trace 0
@@ -32,7 +29,6 @@ this process only.
 
 import json
 import os
-import re
 import runpy
 import sys
 import types
@@ -42,14 +38,13 @@ sys.path[:0] = [ROOT, os.path.join(ROOT, "benchmark")]
 
 import cluster                                   # noqa: E402
 import drive                                     # noqa: E402
-import tracing                                   # noqa: E402
-from layer_metrics import (host_readers, named_readers,   # noqa: E402
+import layers                                    # noqa: E402
+from layer_metrics import (host_readers, scope_readers,   # noqa: E402
                            span_readers)
 
-_build = cluster.build
 _drain = cluster.Served.drain
 _run = drive.Run.run
-_load = tracing.load
+_breakdown = layers.breakdown
 _before = {}
 
 HOST = (("host_cpu_ms_per_tick", host_readers.host_cpu_ms_per_tick),
@@ -98,123 +93,19 @@ def phase_split(ctx, tier):
             for p in phases}
 
 
-def chunk_programs(trace):
-    """Whole ``jit_chunk_prefill`` executions of every device by compiled
-    program, shortest first: ``[fingerprint, executions, mean ms, least,
-    most]``.  The engine compiles one chunk program a window rung, so
-    with one chunk width the rows ARE the rungs, in order."""
-    by_name = {}
-    for dev in trace["devices"].values():
-        for m in dev["modules"]:
-            if m[0].startswith("jit_chunk_prefill("):
-                by_name.setdefault(m[0], []).append(m)
-    rows = []
-    for name, modules in by_name.items():
-        ms = [d / 1e6 for _, d in named_readers.executions(
-            {"modules": modules}, "chunk_prefill",
-            trace["t_lo"], trace["t_hi"])]
-        if ms:
-            rows.append([name[name.index("(") + 1:-1], len(ms),
-                         round(sum(ms) / len(ms), 3), round(min(ms), 3),
-                         round(max(ms), 3)])
-    return sorted(rows, key=lambda r: r[2])
-
-
-_HLO_OP = re.compile(r"^\s*(?:ROOT )?%?([\w.-]+) = .*op_name=\"([^\"]*)\"")
-
-
-def tick_scopes(engine):
-    """Per warmed rung of the engine's decode tick (its table window in
-    tokens): ``{operation: in the attention scope?}`` for every
-    instruction of the compiled program that a device trace can show,
-    those outside any fusion — the rung's own program, compiled again
-    against the pool's shapes (the persistent compile cache has it)."""
-    import chip_smoke
-    import jax
-    bs = engine.paged.block_size
-    pool = jax.tree.map(
-        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=x.sharding),
-        engine.pool)
-    out = {}
-    for wb, _ in sorted(engine._compiled.get("decode", ())):
-        (compiled, _), = chip_smoke.pool_programs(
-            engine, pool, [wb * bs]).values()
-        ops, fused = {}, False
-        for line in compiled.as_text().splitlines():
-            if line.endswith("{") and " = " not in line:
-                fused = "fused_computation" in line
-            m = None if fused else _HLO_OP.match(line)
-            if m:
-                ops[m[1]] = "/attention/" in m[2]
-        out[wb * bs] = ops
-    return out
-
-
-def attention_by_rung(trace, scopes, steps):
-    """``{rungs: {"ticks", "ms_a_step", "by_op_ms_a_step"}}``: the
-    attention scope's operations inside the whole ``jit_decode_tick``
-    executions of the trace, each compiled program matched to the rung
-    (or rungs, ``"128|256"``) whose HLO names the most of its
-    operations."""
-    rows = {}
-    for dev in trace["devices"].values():
-        by_program = {}
-        for m in dev["modules"]:
-            if m[0].startswith("jit_decode_tick("):
-                by_program.setdefault(m[0], []).append(m)
-        in_time = sorted(dev["ops"], key=lambda e: e[1])
-        for modules in by_program.values():
-            spans = named_readers.executions(
-                {"modules": modules}, "decode_tick",
-                trace["t_lo"], trace["t_hi"])
-            if not spans:
-                continue
-            inside, i = {}, 0
-            for name, start, dur in in_time:
-                while i < len(spans) and sum(spans[i]) < start:
-                    i += 1
-                if i < len(spans) and spans[i][0] <= start:
-                    name = name.split("@")[0]
-                    inside[name] = inside.get(name, 0) + dur
-            named = {r: len(inside.keys() & scopes[r]) for r in scopes}
-            rungs = [r for r in scopes if named[r] == max(named.values())]
-            rung = rungs[-1]
-            per_step = 1e6 * len(spans) * steps
-            ops = {n: round(d / per_step, 4) for n, d in inside.items()
-                   if scopes[rung].get(n)}
-            # Rungs whose programs name their operations alike cannot be
-            # told apart in a trace: the row is filed under all of them.
-            rows["|".join(map(str, rungs))] = {
-                          "ticks": len(spans),
-                          "ms_a_step": round(sum(ops.values()), 4),
-                          "by_op_ms_a_step": dict(sorted(
-                              ops.items(), key=lambda kv: -kv[1])[:8])}
-    return rows
-
-
-def _load_and_print(path):
-    trace = _load(path)
-    print("[bench:stats] chunk_prefill executions by program "
-          "[fingerprint, n, mean_ms, min_ms, max_ms] = "
-          + json.dumps(chunk_programs(trace)), flush=True)
-    served = _before.get("served")
-    for name in (served.entries if served else ()):
-        engine = served.engine(name)
-        if not getattr(engine, "_compiled", {}).get("decode"):
-            continue
-        rows = attention_by_rung(trace, tick_scopes(engine),
-                                 engine.steps_per_tick)
-        for rungs, row in rows.items():
-            row["form"] = engine.decode_attention_form(
-                int(rungs.split("|")[-1]))
-        print(f"[bench:stats] tiers.{name}.attention device ms a step by "
-              f"rung = {json.dumps(rows)}", flush=True)
-    return trace
-
-
-def _build_and_keep(*args, **kw):
-    _before["served"] = _build(*args, **kw)
-    return _before["served"]
+def _breakdown_after_scopes(ctx):
+    """The reader's line for every tier's tick and chunk program (one a
+    context: what a metric of the cell has read is not printed again),
+    and the whole reduction, every operation's time and scope with it, in
+    ``chiprun_out/scopes.<cell>.json``."""
+    found = {f"{tier}.{program}": scope_readers.reduce_program(
+        ctx, tier, program) for tier in ctx.served.entries
+        for program in ("decode_tick", "chunk_prefill")}
+    os.makedirs("chiprun_out", exist_ok=True)
+    with open(os.path.join("chiprun_out",
+                           f"scopes.{ctx.cell['name']}.json"), "w") as f:
+        json.dump(found, f)
+    return _breakdown(ctx)
 
 
 def _run_after_metrics(self, *args, **kw):
@@ -251,9 +142,8 @@ def _drain_after_stats(self) -> None:
         _drain(self)
 
 
-cluster.build = _build_and_keep
 cluster.Served.drain = _drain_after_stats
 drive.Run.run = _run_after_metrics
-tracing.load = _load_and_print
+layers.breakdown = _breakdown_after_scopes
 sys.argv = [os.path.join("benchmark", "run.py")] + sys.argv[1:]
 runpy.run_path(sys.argv[0], run_name="__main__")
