@@ -5,6 +5,7 @@ One process, one TPU chip by default:
 
     python chip_smoke.py            # device, serve, what ran, pool, kernels
     python chip_smoke.py --chips 4  # placement + tp=2 vs tp=1, nothing else
+    python chip_smoke.py --config kimi-linear-48b-a3b  # that cell's pool
 
 Phases print their own lines; any failure exits non-zero at once.  The
 last line of stdout is one JSON object naming the device as jax reports
@@ -19,7 +20,6 @@ import argparse
 import dataclasses
 import json
 import os
-import re
 import sys
 import time
 from typing import (Any, Callable, Dict, List, NamedTuple, Optional, Sequence,
@@ -480,77 +480,54 @@ def pool_programs(engine, pool, windows: Sequence[int],
     return out
 
 
-_HLO_RESULT = re.compile(r"^\s*(ROOT )?%?[\w.-]+ = (\w+\[[\d,]*\])\S* "
-                         r"([\w-]+)\((.*)$")
-_HLO_TYPES = {"bfloat16": "bf16", "float32": "f32", "int8": "s8"}
-# What may have a pool-shaped result in a program that updates the pool
-# in place: the buffer coming in and going round (a ``while`` and a
-# ``tuple`` are tuple-typed and never match) and the in-place writes.
-_POOL_IN_PLACE = {"parameter", "get-tuple-element", "bitcast", "scatter",
-                  "dynamic-update-slice"}
-
-
 def pool_program_facts(compiled, pool_arg: int, pool) -> Dict[str, Any]:
     """What says a compiled pool program leaves the pool in place: its
     temporaries, the aliased bytes beside everything it returns, whether
-    the pool's input and output formats agree, and a count by opcode of
-    the instructions with a pool-shaped result that are neither the
-    buffer going round nor an in-place write (a ``copy``, a stacked
-    ``ys``, a slice fusion; a ``fusion`` is judged by its root)."""
-    import jax
+    the pool's input and output formats agree, the layout each array
+    comes in with, and the program's ``pool_sized_moves``
+    (``obs/program_scopes.py``: the count GET /debug/programs gives)."""
+    from distributed_llm_tpu.obs.program_scopes import pool_sized_moves
     fmt_in = compiled.input_formats[0][pool_arg]
     fmt_out = compiled.output_formats
     if not isinstance(fmt_out, dict):
         fmt_out = fmt_out[-1]
-    shapes = {f"{_HLO_TYPES[str(x.dtype)]}[{','.join(map(str, x.shape))}]"
-              for x in jax.tree.leaves(pool)}
-    roots: Dict[str, str] = {}
-    suspects, computation = [], None
-    for line in compiled.as_text().splitlines():
-        if line.endswith("{") and " = " not in line:
-            words = line.split()
-            computation = words[1 if words[0] == "ENTRY" else 0].lstrip("%")
-            continue
-        m = _HLO_RESULT.match(line)
-        if not m or m[2] not in shapes:
-            continue
-        if m[1]:
-            roots[computation] = m[3]
-        if m[3] not in _POOL_IN_PLACE:
-            suspects.append((m[3], m[4]))
-    moves: Dict[str, int] = {}
-    for op, rest in suspects:
-        called = re.search(r"calls=%?([\w.-]+)", rest)
-        if (op == "fusion" and called
-                and roots.get(called[1]) in _POOL_IN_PLACE):
-            continue
-        moves[op] = moves.get(op, 0) + 1
     mem = compiled.memory_analysis()
     return {"temp_bytes": mem.temp_size_in_bytes,
             "alias_bytes": mem.alias_size_in_bytes,
             "output_bytes": mem.output_size_in_bytes,
             "formats_match": fmt_in == fmt_out,
-            "pool_sized_moves": moves}
+            "major_to_minor": {key: list(fmt.layout.major_to_minor)
+                               for key, fmt in fmt_in.items()},
+            "pool_sized_moves": pool_sized_moves(compiled.as_text(), pool)}
 
 
-def phase_pool_programs(served: Served) -> Dict[str, Any]:
-    """Per tier: the pool's format at rest, and for the decode tick and
-    one chunk program what the chip's compiler made of them — to be set
-    beside the compile for a described v5e of tests/test_tpu_compile.py.
-    On the chip a pool program that does not alias the pool, gives it
+def phase_pool_programs(served, tiers: Optional[Sequence[str]] = None
+                        ) -> Dict[str, Any]:
+    """Per tier (of ``tiers``; every tier of the router by default): the
+    pool's format at rest, and for the decode tick and one chunk program
+    what the chip's compiler made of them — to be set beside the compile
+    for a described v5e of tests/test_tpu_compile.py.  On the chip a
+    pool-sized array that does not rest row-major
+    (``program_scopes.POOL_SIZED_BYTES``; DESIGN.md "A pool array has one
+    format"), and a pool program that does not alias the pool, gives it
     back in another format than it took it, or moves it (a pool-sized
     ``copy``, a stacked ``ys``) fails the smoke."""
+    from distributed_llm_tpu.obs.program_scopes import pool_sized
     out: Dict[str, Any] = {}
-    for name in served.router.tiers:
+    for name in tiers or served.router.tiers:
         engine = _engine(served.router, name)
-        at_rest = {k: str(x.format.layout) for k, x in engine.pool.items()}
+        at_rest = engine.pool_stats()["formats"]
         say("pool", f"tier {name}: pool at rest " + json.dumps(at_rest))
+        for key, x in engine.pool.items():
+            check(at_rest[key]["row_major"] or not pool_sized(x),
+                  f"{name}: pool[{key!r}] {at_rest[key]['shape']} rests "
+                  f"{at_rest[key]['major_to_minor']}, not row-major")
         bs = engine.paged.block_size
         chunk = engine._reuse_buckets[0]
         programs = pool_programs(
             engine, engine.pool, [engine._buckets[0]],
             [(chunk, max(engine._chunk_windows[0], -(-chunk // bs) * bs))],
-            cow=True)
+            cow=not engine.cfg.hybrid)
         out[name] = {"at_rest": at_rest}
         for label, (compiled, pool_arg) in programs.items():
             facts = pool_program_facts(compiled, pool_arg, engine.pool)
@@ -564,6 +541,31 @@ def phase_pool_programs(served: Served) -> Dict[str, Any]:
                 check(not facts["pool_sized_moves"],
                       f"{name}: {label} moves the pool: {facts}")
     return out
+
+
+def benchmark_cluster(config: str):
+    """``benchmark/cluster.py``, loaded by its path, and the configuration
+    ``benchmark/configs/<config>.json`` as it reads one:
+    tests/test_tpu_compile.py takes a tier's settings from the pair,
+    ``bench_served`` the built tiers."""
+    import importlib.util
+    bench = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                         "benchmark")
+    spec = importlib.util.spec_from_file_location(
+        "_benchmark_cluster", os.path.join(bench, "cluster.py"))
+    cluster = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(cluster)
+    with open(os.path.join(bench, "configs", config + ".json")) as f:
+        return cluster, json.load(f)
+
+
+def bench_served(config: str, seed: int = 0):
+    """A benchmark configuration's tiers, built and warmed the way
+    ``benchmark/run.py`` builds them (``benchmark/cluster.py`` ``build``):
+    weights from the seed at the cell's real sizes, on the first device."""
+    import jax
+    cluster, entries = benchmark_cluster(config)
+    return cluster.build(entries, seed, False, jax.devices()[:1])
 
 
 def phase_drain(served: Served) -> None:
@@ -992,10 +994,20 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
     ap.add_argument("--chips", type=int, choices=(1, 4), default=1,
                     help="4 runs ONLY the four-chip placement check and "
                          "the tp=2 vs tp=1 comparison (builder-run)")
+    ap.add_argument("--config", metavar="NAME",
+                    help="runs ONLY the pool phase, on the tiers of the "
+                         "benchmark configuration benchmark/configs/"
+                         "NAME.json as the benchmark builds them")
     args = ap.parse_args(argv)
     t0 = time.perf_counter()
     device = phase_device(args.chips)
-    if args.chips == 4:
+    if args.config:
+        served = bench_served(args.config)
+        try:
+            phase_pool_programs(served, list(served.entries))
+        finally:
+            served.drain()
+    elif args.chips == 4:
         phase_four_chips()
     else:
         import jax

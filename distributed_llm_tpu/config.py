@@ -409,6 +409,19 @@ class ModelConfig:
             return self.kv_lora_rank + self.qk_rope_head_dim
         return self.num_kv_heads * self.head_dim
 
+    @property
+    def cache_row_rest_width(self) -> int:
+        """``cache_row_width`` as the paged pool HOLDS it: the latent row,
+        the one row that is not heads by head_dim wide, rests at whole
+        lane-widths (128) with zeros behind its numbers, 640 for the
+        published 512 + 64.  The device's tiles pad the row to that
+        anyway; said in the shape, the device's default format for the
+        array is row-major whatever the pool's other dimensions are
+        (``engine/paged_kv.py``, DESIGN.md "A pool array has one
+        format")."""
+        width = self.cache_row_width
+        return -(-width // 128) * 128 if self.kv_lora_rank else width
+
     def param_count(self) -> int:
         """Approximate parameter count (embeddings counted once, tied head)."""
         h, f, l, v = self.hidden_size, self.ffn_size, self.num_layers, self.vocab_size

@@ -54,7 +54,8 @@ from .inference import (GenerationResult, prepare_prompt, trim_at_eos,
                         upgrade_attention_impl)
 from .paged_kv import (BlockAllocator, PagedConfig, TRASH_BLOCK,
                        chunk_prefill_paged, decode_step_paged, init_pool,
-                       verify_step_paged, write_prefill_blocks)
+                       pool_formats, verify_step_paged,
+                       write_prefill_blocks)
 from .tokenizer import get_tokenizer
 
 History = Union[str, Sequence[Dict[str, Any]]]
@@ -486,6 +487,7 @@ class ContinuousBatchingEngine:
         self._pool_donated = platform != "cpu"
         self._pool_home = home
         self.pool = self._new_pool(self.cfg, home)
+        self._pool_formats = pool_formats(self.pool)
         self.allocator = BlockAllocator(self.paged.num_blocks)
 
         b, mb = self.paged.max_slots, self.paged.blocks_per_slot
@@ -1232,8 +1234,10 @@ class ContinuousBatchingEngine:
         stage, its name on a device trace's module line, its table window
         (and chunk) in tokens, the tick's attention form and, with
         ``ops``, the seconds the entry took to build (lowering, compiling,
-        reading the text) and ``ops``: the named scope of every operation
-        a trace of it can show (``obs/program_scopes.py``).
+        reading the text), ``pool_sized_moves`` (what of the pool the
+        compiled program copies: ``{}`` is nothing) and ``ops``: the named
+        scope of every operation a trace of it can show
+        (``obs/program_scopes.py``).
 
         Each program is compiled again for that (``lower_pool_program``)
         the FIRST time it is asked about with ``ops`` and kept by its key
@@ -1280,10 +1284,11 @@ class ContinuousBatchingEngine:
         return out
 
     def _program_map(self, stage: str, key) -> Dict[str, Any]:
-        """``{"built_s", "ops"}`` of one recorded program."""
+        """``{"built_s", "pool_sized_moves", "ops"}`` of one recorded
+        program."""
         from jax._src.config import (
             compilation_cache_include_metadata_in_key as keyed_by_metadata)
-        from ..obs.program_scopes import op_scopes
+        from ..obs.program_scopes import op_scopes, pool_sized_moves
         stamps = [time.perf_counter()]
         lowered = self.lower_pool_program(
             stage, key[0] if stage == "decode" else key)
@@ -1291,12 +1296,14 @@ class ContinuousBatchingEngine:
         with keyed_by_metadata(True):
             compiled = lowered.compile()
         stamps.append(time.perf_counter())
-        ops = op_scopes(compiled.as_text())
+        text = compiled.as_text()
+        ops = op_scopes(text)
+        moves = pool_sized_moves(text, self.pool)
         stamps.append(time.perf_counter())
         return {"built_s": dict(zip(("lower", "compile", "read"),
                                     (b - a for a, b in
                                      zip(stamps, stamps[1:])))),
-                "ops": ops}
+                "pool_sized_moves": moves, "ops": ops}
 
     def _writer_fn(self, nb: int):
         """Jitted pool scatter (donated pool → in-place page-in), one
@@ -1681,6 +1688,14 @@ class ContinuousBatchingEngine:
         if pf is not None and pf.blocks:
             owner[pf.slot_ix] = pf.blocks[0]
         return owner
+
+    def pool_stats(self) -> Dict[str, Any]:
+        """The pool where it rests (GET /stats ``pool``): each array's
+        format as the device held it when the pool was made
+        (``paged_kv.pool_formats``), which every pool program gives back
+        (``chip_smoke.pool_program_facts`` ``formats_match``)."""
+        return {"formats": {key: dict(fmt) for key, fmt
+                            in self._pool_formats.items()}}
 
     def state_stats(self) -> Optional[Dict[str, int]]:
         """The recurrent rows (GET /stats ``state``), or None for a model

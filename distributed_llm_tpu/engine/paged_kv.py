@@ -6,20 +6,27 @@ needs many sequences of very different lengths resident at once, so the
 TPU-native design is vLLM-style paging adapted to XLA's static shapes:
 
 - One HBM **pool** per tier, arrays of ROWS ``[L, num_blocks,
-  block_size, cfg.cache_row_width]``: token-major, a block a contiguous
-  run of tokens and a token's row whatever the model caches of it — its
-  kv heads side by side on ONE axis (``N_kv * D``, one array for K and
-  one for V), or the latent family's one head-less row (``"c"``, the
-  only array: models/latent_moe.py).  That is the order the step programs
-  below use inside their layer loop (a row write and the table gather
-  both move whole tokens), and with the heads merged the last axis fills
-  the chip's 128 lanes at any head_dim — so the device's DEFAULT layout
-  for the array is that order, unpadded, on both sides of every program,
-  and no program transposes the pool on its way in or out.  (Head-major
+  block_size, cfg.cache_row_rest_width]``: token-major, a block a
+  contiguous run of tokens and a token's row whatever the model caches
+  of it — its kv heads side by side on ONE axis (``N_kv * D``, one array
+  for K and one for V), or the latent family's one head-less row
+  (``"c"``, the only array: models/latent_moe.py), which rests at whole
+  lane-widths with zeros behind its numbers (640 for the published 512 +
+  64).  That is the order the step programs below use inside their layer
+  loop (a row write and the table gather both move whole tokens), and
+  with the last axis whole 128-lane widths — the heads merged, the
+  latent row rounded up — the device's DEFAULT layout for the array is
+  that order, row-major, on both sides of every program, and no program
+  transposes the pool on its way in or out: ONE format, at rest and
+  through every program (DESIGN.md "A pool array has one format";
+  ``pool_formats`` says what the device holds, ``obs/program_scopes.py``
+  ``pool_sized_moves`` what a compiled program copies).  (Head-major
   ``[L, N_kv, NB, bs, D]`` at head_dim 64 rested block-axis-minor and
-  was transposed, whole, twice a program; a layout pinned with
+  was transposed, whole, twice a program, PR 27; so did a latent row of
+  576 over 1281 blocks, where padding the BLOCK axis to 1408 was the
+  more compact tiling, PR 57; a layout pinned with
   ``jax.experimental.layout`` does not survive the persistent compile
-  cache — PERF.md §6, PR 27.)  A 'tp' mesh axis shards the merged axis
+  cache — PERF.md §6, PRs 27 and 57.)  A 'tp' mesh axis shards the merged axis
   (heads are contiguous runs of it), and a tp hook is handed a layer's
   head-major ``[N_kv, NB, bs, D]`` view (``_hooked``).
 - The pool is ONE buffer that every step program updates in place: the
@@ -63,7 +70,7 @@ from __future__ import annotations
 
 import dataclasses
 import threading
-from typing import Dict, List, Optional, Tuple
+from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -71,11 +78,13 @@ import jax.numpy as jnp
 from ..config import ModelConfig
 from ..models import (hybrid_ssm, latent_moe, shared_kv_hybrid,
                       transformer)
+from ..obs.program_scopes import row_major
 from ..ops import attention, quant
 
 KVPool = Dict[str, jax.Array]    # {"k","v": [L, NB, bs, N_kv * D]}
 # int8 pools add {"ks","vs": [L, NB, bs, N_kv]} per-row dequant scales;
-# the latent family's pool is {"c": [L, NB, bs, kv_lora + rope]} alone.
+# the latent family's pool is {"c": [L, NB, bs, kv_lora + rope, rounded up
+# to whole lane-widths]} alone.
 
 TRASH_BLOCK = 0
 
@@ -115,7 +124,7 @@ def init_pool(cfg: ModelConfig, pcfg: PagedConfig,
     context × batch).  Writes quantize, reads dequantize at the attention
     op (ops/attention.py paged paths)."""
     rows = (cfg.num_layers, pcfg.num_blocks, pcfg.block_size)
-    shape = rows + (cfg.cache_row_width,)
+    shape = rows + (cfg.cache_row_rest_width,)
     if cfg.latent:
         if kv_quantize != "none":
             raise ValueError(
@@ -176,6 +185,26 @@ def init_pool(cfg: ModelConfig, pcfg: PagedConfig,
                          "or 'int8'")
     dtype = jnp.dtype(cfg.dtype)
     return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
+
+
+def pool_formats(pool: KVPool) -> Dict[str, Dict[str, Any]]:
+    """Each pool array's format AT REST, as the device holds it (GET
+    /stats ``tiers.<tier>.pool.formats``): its shape, the order of its
+    axes in memory (``major_to_minor``), the tiling, and whether that
+    order is the one a pool array rests in, row-major.  Nothing pins it
+    (a pinned layout does not survive the persistent compile cache:
+    PERF.md section 6, PRs 27 and 57): the device's default for the
+    array's SHAPE decides, so the shapes ``init_pool`` gives are the ones
+    whose default is row-major (``cfg.cache_row_rest_width``), and every
+    pool program takes and returns the array as it rests."""
+    out = {}
+    for key, x in pool.items():
+        layout = x.format.layout
+        out[key] = {"shape": list(x.shape), "dtype": str(x.dtype),
+                    "major_to_minor": list(layout.major_to_minor),
+                    "tiling": str(layout.tiling),
+                    "row_major": row_major(layout.major_to_minor)}
+    return out
 
 
 class BlockAllocator:
@@ -376,7 +405,7 @@ def pool_block_bytes(cfg: ModelConfig, block_size: int,
     Shared by the engine's spill accounting and the bench's budget
     sizing so the two can never drift."""
     if cfg.latent:
-        return (cfg.num_layers * block_size * cfg.cache_row_width
+        return (cfg.num_layers * block_size * cfg.cache_row_rest_width
                 * jnp.dtype(cfg.dtype).itemsize)
     d = cfg.head_dim
     per_row = cfg.kv_layers * cfg.num_kv_heads * block_size

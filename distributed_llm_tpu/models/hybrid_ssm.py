@@ -113,6 +113,7 @@ from typing import Any, Dict, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental.layout import Layout, with_layout_constraint
 
 from ..config import ModelConfig
 from ..ops import attention, pallas_attention, quant, ssm_chunk_scan
@@ -504,6 +505,22 @@ def conv_chunk(lp: Params, tail, a, n_valid):
             jax.lax.dynamic_slice_in_dim(seq, n_valid, k - 1, axis=0))
 
 
+def _row_major(x):
+    """``x``, a pool array on its way back into a loop's carry, held to
+    the order it rests in (``engine/paged_kv.py``): without it the chip's
+    compiler lays the WHOLE array out in the operand order a recurrence's
+    products over ONE row prefer and copies it, whole, into the layer
+    loop and out (``kimi-linear-48b-a3b``'s chunk program: 2 x 235 MB a
+    chunk; compile for a described v5e, PR 57).  With it the row is what
+    gets re-laid, if anything is; where the compiler kept the order
+    anyway the program is the same, instruction for instruction.  A
+    constraint inside the program, not a pinned argument: the program's
+    edge keeps the device's default, which the persistent compile cache
+    keeps too."""
+    return with_layout_constraint(
+        x, Layout(major_to_minor=tuple(range(x.ndim))))
+
+
 def through_rows(pool, li, ctx, scan, step, kind: str):
     """Layer ``li``'s state and tail through a row kind's recurrence: a
     chunk of one sequence takes its row (zeros where the sequence is
@@ -514,7 +531,9 @@ def through_rows(pool, li, ctx, scan, step, kind: str):
     rows' read and write-back carry the scopes of the recurrence whose
     rows they are (``kind`` "ssm" or "kda": the state under
     ``<kind>_scan`` / ``<kind>_step``, the tail under ``<kind>_conv``), so
-    an update the compiler fuses with its write-back has one name."""
+    an update the compiler fuses with its write-back has one name.  The
+    state array goes back into the carry in the order it rests in
+    (``_row_major``), whichever kind the rows are."""
     s_all, t_all = pool["s"], pool["t"]
     of_tail = jax.named_scope(f"{kind}_conv")
     if "row" in ctx:                               # a chunk of one sequence
@@ -527,7 +546,7 @@ def through_rows(pool, li, ctx, scan, step, kind: str):
                              t_all[li, row])
         y, state, tail = scan(state, tail, ctx["n_valid"])
         with of_state:
-            y, s_all = y[None], s_all.at[li, row].set(state)
+            y, s_all = y[None], _row_major(s_all.at[li, row].set(state))
         with of_tail:
             return y, {**pool, "s": s_all, "t": t_all.at[li, row].set(tail)}
     src, valid, dst = ctx["rows"]                  # a decode step, by rows
@@ -538,7 +557,7 @@ def through_rows(pool, li, ctx, scan, step, kind: str):
         tail = t_all[li]
     y, state, tail = step(src, state, tail, valid)
     with of_state:
-        y, s_all = y[dst][:, None], s_all.at[li].set(state)
+        y, s_all = y[dst][:, None], _row_major(s_all.at[li].set(state))
     with of_tail:
         return y, {**pool, "s": s_all, "t": t_all.at[li].set(tail)}
 

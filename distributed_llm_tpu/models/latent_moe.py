@@ -314,9 +314,13 @@ def _attend(cfg: ModelConfig, lp: Params, h_in, sin, cos, q_pos, pool_c, i,
     if sin is not None:
         q_rope = transformer.apply_rope(q_rope, sin, cos)
         k_rope = _rope_1(k_rope, sin, cos)
+    # The row as it rests: zeros behind its numbers up to the pool's whole
+    # lane-widths (``cfg.cache_row_rest_width``), which nothing reads.
+    rest = pool_c.shape[-1] - dc - dr
     row = jnp.concatenate(
         [transformer.rms_norm(kv[..., :dc], lp["kv_ln"], cfg.norm_eps),
-         k_rope], axis=-1)                                   # [B, S, dc+dr]
+         k_rope, jnp.zeros(k_rope.shape[:-1] + (rest,), k_rope.dtype)],
+        axis=-1)                                             # [B, S, R]
 
     with jax.named_scope("kv_write"):
         pool_c = pool_c.at[i, blk, off].set(row)
@@ -324,7 +328,7 @@ def _attend(cfg: ModelConfig, lp: Params, h_in, sin, cos, q_pos, pool_c, i,
         # One gather at (layer, block): no layer-sized slice in between.
         rows = pool_c[i, tables]                          # [B, wb, bs, R]
         rows = rows.reshape(b, -1, rows.shape[-1])        # [B, W, R]
-        c, k_r = rows[..., :dc], rows[..., dc:]
+        c, k_r = rows[..., :dc], rows[..., dc:dc + dr]
         w_kvb = quant.dequantize(lp["w_kvb"]).reshape(dc, nh, dn + dv)
         if absorbed:
             # Scores against the cached row itself: the up-projection of
@@ -602,7 +606,8 @@ def prefill(cfg: ModelConfig, params: Params, tokens: jax.Array,
     over a scratch pool of one block a sequence.  Returns (hidden,
     (rows [L, B, S, R],)) — the rows to page into the real pool."""
     b, s = tokens.shape
-    scratch = {"c": jnp.zeros((cfg.num_layers, b, s, cfg.cache_row_width),
+    scratch = {"c": jnp.zeros((cfg.num_layers, b, s,
+                               cfg.cache_row_rest_width),
                               jnp.dtype(cfg.dtype))}
     seq = jnp.broadcast_to(jnp.arange(b)[:, None], (b, s))
     hidden, scratch, _ = forward_paged(

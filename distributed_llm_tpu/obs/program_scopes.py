@@ -21,6 +21,10 @@ an async pair) carries no metadata and is named by what reads it.
 A pure function of the text: ``engine/batching.py`` ``step_programs``
 calls it on the engine's own tick and chunk programs when ``GET
 /debug/programs`` asks, and never otherwise.
+
+``pool_sized_moves`` reads the same text for the other thing only the
+compiled program says: whether it leaves the pool where it rests, or
+copies an array of it on the way in, round a loop, or on the way out.
 """
 
 from __future__ import annotations
@@ -61,6 +65,70 @@ _CALLED = re.compile(r"\b(body|condition|to_apply|calls|true_computation|"
                      r"false_computation)=%?([\w.-]+)")
 _BRANCHES = re.compile(r"\bbranch_computations=\{([^}]*)\}")
 _OPERAND = re.compile(r"%([\w.-]+)")
+
+
+# A pool array of this many bytes or more is POOL-SIZED: a copy of it costs
+# 0.03 ms or more at the 555 GB/s a transposing copy reaches on a v5e.
+# Under it lie the vector of the rows' owners and the conv tails (8.3 MB
+# at most in the benchmark's cells), whose pair of copies at a program's
+# edge no trace of a cell shows (PERF.md section 7).
+POOL_SIZED_BYTES = 16 << 20
+# What may have a pool-shaped result in a program that updates the pool
+# in place: the buffer coming in and going round (a ``while`` and a
+# ``tuple`` are tuple-typed and never match) and the in-place writes.
+_POOL_IN_PLACE = {"parameter", "get-tuple-element", "bitcast", "scatter",
+                  "dynamic-update-slice"}
+_HLO_TYPES = {"bfloat16": "bf16", "float32": "f32", "float16": "f16",
+              "int8": "s8", "int32": "s32", "uint32": "u32"}
+# (ROOT, result type without its layout, opcode, the rest) of an instruction.
+HLO_RESULT = re.compile(r"^\s*(ROOT )?%?[\w.-]+ = (\w+\[[\d,]*\])\S* "
+                        r"([\w-]+)\((.*)$")
+
+
+def pool_sized(x) -> bool:
+    """Whether ``x`` (an array or its shape and dtype) is a pool-sized
+    array: ``POOL_SIZED_BYTES`` or more."""
+    return x.size * x.dtype.itemsize >= POOL_SIZED_BYTES
+
+
+def row_major(major_to_minor) -> bool:
+    """Whether a layout's ``major_to_minor`` is the one order a pool
+    array rests in: its axes as written."""
+    return tuple(major_to_minor) == tuple(range(len(major_to_minor)))
+
+
+def pool_sized_moves(hlo_text: str, pool) -> Dict[str, int]:
+    """A count by opcode of the instructions of a compiled pool program
+    whose result is shaped like a pool-sized array of ``pool`` (arrays or
+    shapes; ``POOL_SIZED_BYTES``) and that are neither the buffer going
+    round nor an in-place write: a ``copy`` into another layout at the
+    program's edge or a loop's, a stacked ``ys``, a slice fusion (a
+    ``fusion`` is judged by its root).  ``{}`` says the program leaves
+    the pool where it rests."""
+    shapes = {f"{_HLO_TYPES[str(x.dtype)]}[{','.join(map(str, x.shape))}]"
+              for x in pool.values() if pool_sized(x)}
+    roots: Dict[str, str] = {}
+    suspects, computation = [], None
+    for line in hlo_text.splitlines():
+        if line.endswith("{") and " = " not in line:
+            words = line.split()
+            computation = words[1 if words[0] == "ENTRY" else 0].lstrip("%")
+            continue
+        m = HLO_RESULT.match(line)
+        if not m or m[2] not in shapes:
+            continue
+        if m[1]:
+            roots[computation] = m[3]
+        if m[3] not in _POOL_IN_PLACE:
+            suspects.append((m[3], m[4]))
+    moves: Dict[str, int] = {}
+    for op, rest in suspects:
+        called = re.search(r"calls=%?([\w.-]+)", rest)
+        if (op == "fusion" and called
+                and roots.get(called[1]) in _POOL_IN_PLACE):
+            continue
+        moves[op] = moves.get(op, 0) + 1
+    return moves
 
 
 class _Instruction(NamedTuple):

@@ -360,10 +360,14 @@ def create_app(router: Optional[Router] = None,
         ``{"tiers": {<tier>: [{"stage": "decode" | "chunk_prefill",
         "program": "jit_decode_tick" | "jit_chunk_prefill",
         "window_tokens", "chunk_tokens", "attention_form", "built_s":
-        {"lower", "compile", "read"}, "ops": {<HLO instruction>:
+        {"lower", "compile", "read"}, "pool_sized_moves": {<opcode>:
+        <count>}, "ops": {<HLO instruction>:
         {"scope": <innermost jax.named_scope or null>, "mixed":
         <bool>}}}]}}`` — the join between a device trace's operation
-        names and the model code's scopes (obs/program_scopes.py);
+        names and the model code's scopes (obs/program_scopes.py), and
+        beside it the count of the pool-sized arrays the program copies
+        on its way in, round a loop or out (``{}``: it leaves the pool
+        where it rests);
         ``ops`` stands in the compiled text's own order (a reader parts
         two programs that hold the same names by it).
         ``?stage=decode|chunk_prefill`` and ``?window_tokens=<n>[,<n>]``

@@ -131,6 +131,10 @@ def engine_stats(engine) -> Dict[str, Any]:
             entry["kv"] = kv_fn()
         except Exception:
             pass
+    pool_fn = getattr(engine, "pool_stats", None)
+    if callable(pool_fn):
+        # The pool's arrays as the device holds them at rest.
+        entry["pool"] = pool_fn()
     pf_fn = getattr(engine, "prefill_stats", None)
     if callable(pf_fn):
         # Chunked prefill: what is in flight, and of the chunks

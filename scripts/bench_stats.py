@@ -17,7 +17,10 @@ scheduler phase that stamped each awake slice's first token.  With
 for the tick and for the chunk program, whichever of the two the cell's
 own metrics did not ask about: device time by ``jax.named_scope`` (the
 program's own ``GET /debug/programs`` says which scope each traced
-operation belongs to) and by window rung, each rung with its scopes.
+operation belongs to) and by window rung, each rung with its scopes;
+beside it each of those programs' ``pool_sized_moves`` (PR 57: the same
+route's count of the pool-sized arrays a program copies), and in every
+run ``tiers.<tier>.pool``, each pool array's format at rest.
 
     python3 scripts/bench_stats.py --workload smollm2-1.7b.decode-closed \
         --seed 7 --seconds 50 --trace 0
@@ -105,6 +108,15 @@ def _breakdown_after_scopes(ctx):
     with open(os.path.join("chiprun_out",
                            f"scopes.{ctx.cell['name']}.json"), "w") as f:
         json.dump(found, f)
+    for tier in ctx.served.entries:
+        # Of the programs the readers asked GET /debug/programs about
+        # (nothing is built for this line): the pool-sized arrays each
+        # copies on its way in, round a loop or out; {} is none.
+        moves = {f"{stage}:{'x'.join(map(str, key))}": built[
+            "pool_sized_moves"] for (stage, key), built in sorted(
+            ctx.served.engine(tier)._program_maps.items())}
+        print(f"[bench:stats] tiers.{tier}.pool_sized_moves = "
+              + json.dumps(moves), flush=True)
     return _breakdown(ctx)
 
 
@@ -124,7 +136,8 @@ def _drain_after_stats(self) -> None:
             # "state": the recurrent rows' mixer (mamba2, mamba1, cca_tail,
             # kda), their count and bytes, and the K/V or latent layers
             # beside them; null for a model without rows.
-            for key in ("tick", "prefill", "state"):
+            # "pool": every pool array's format at rest (PR 57).
+            for key in ("tick", "prefill", "state", "pool"):
                 print(f"[bench:stats] tiers.{name}.{key} = "
                       f"{json.dumps(block.get(key))}", flush=True)
             print(f"[bench:stats] tiers.{name}.moe.grouped_product = "

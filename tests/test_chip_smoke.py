@@ -48,14 +48,25 @@ def test_script_exits_nonzero_off_tpu_and_prints_no_result():
         assert not line.startswith("{"), line
 
 
-def test_serve_and_what_ran_on_the_tiny_cluster(capsys):
+def test_serve_and_what_ran_on_the_tiny_cluster(capsys, monkeypatch):
     """The one-chip layout: both tiers on ONE device, the full request
     mix, then the record of what ran, then a clean drain."""
+    from distributed_llm_tpu.obs import program_scopes
     served = chip_smoke.phase_serve(tiny_batched_cluster(),
                                     devices=jax.devices()[:1])
     try:
         what = chip_smoke.phase_what_ran(served)
         pool = chip_smoke.phase_pool_programs(served)
+        # A pool-sized array that rests in another order than row-major
+        # fails the phase before anything is compiled (ISSUE 57).
+        engine = chip_smoke._engine(served.router, "nano")
+        rest = engine.pool_stats()
+        rest["formats"]["k"] = dict(rest["formats"]["k"], row_major=False,
+                                    major_to_minor=[0, 2, 3, 1])
+        monkeypatch.setattr(engine, "pool_stats", lambda: rest)
+        monkeypatch.setattr(program_scopes, "POOL_SIZED_BYTES", 0)
+        with pytest.raises(chip_smoke.SmokeFailure, match="not row-major"):
+            chip_smoke.phase_pool_programs(served, ["nano"])
     finally:
         chip_smoke.phase_drain(served)
 
@@ -72,7 +83,9 @@ def test_serve_and_what_ran_on_the_tiny_cluster(capsys):
     for tier in ("nano", "orin"):
         programs = {k: v for k, v in pool[tier].items() if k != "at_rest"}
         assert len(programs) == 3 and set(pool[tier]["at_rest"]) == {"k", "v"}
-        assert all(f["formats_match"] for f in programs.values()), programs
+        assert all(f["row_major"] for f in pool[tier]["at_rest"].values())
+        assert all(f["formats_match"] and f["pool_sized_moves"] == {}
+                   for f in programs.values()), programs
     # Warm-up is reported apart from the requests, per tier.
     assert set(served.record["warmup_s"]) == {"nano", "orin"}
     # Off-TPU the batched engine takes the fused ragged tick.

@@ -649,7 +649,9 @@ def test_the_pool_holds_latent_rows_a_matrix_state_and_three_tails():
     cfg = _cfg()
     pool = _pool(cfg)
     assert cfg.cache_row_width == 32 and cfg.kv_layers == 2
-    assert pool["c"].shape == (2, 17, BLOCK, 32)
+    # The latent row rests at whole lane-widths (ISSUE 57).
+    assert cfg.cache_row_rest_width == 128
+    assert pool["c"].shape == (2, 17, BLOCK, 128)
     assert pool["s"].shape == (7, 2, 4, 16, 16)
     assert pool["t"].shape == (7, 2, 3, 192)
     assert list(pool) == ["c", "s", "t", "owner"]

@@ -75,7 +75,8 @@ def _serve(cfg, params, tok, n_prompt, chunk=32):
     the rest through a latent pool; logits at positions n_prompt-1 ..."""
     pcfg = paged_kv.PagedConfig(block_size=16, max_slots=2, max_seq_len=128)
     pool = paged_kv.init_pool(cfg, pcfg)
-    assert set(pool) == {"c"} and pool["c"].shape[-1] == 32
+    # The row's 32 numbers rest at one whole lane-width (ISSUE 57).
+    assert set(pool) == {"c"} and pool["c"].shape[-1] == 128
     table = jnp.arange(1, 9, dtype=jnp.int32)
     for start in range(0, n_prompt, chunk):
         piece = np.zeros((1, chunk), np.int32)
@@ -405,19 +406,22 @@ def test_pool_programs_work_on_whatever_arrays_the_pool_has():
     cfg = _cfg()
     pool = paged_kv.init_pool(cfg, paged_kv.PagedConfig(
         block_size=16, max_slots=1, max_seq_len=64))
-    rows = jax.random.normal(jax.random.PRNGKey(0), (3, 32, 32), jnp.float32)
+    rows = jax.random.normal(jax.random.PRNGKey(0), (3, 32, 128), jnp.float32)
     pool = paged_kv.write_prefill_blocks(pool, jnp.array([2, 4]), rows)
     np.testing.assert_array_equal(np.asarray(pool["c"][:, 4]),
                                   np.asarray(rows[:, 16:]))
     pool = paged_kv.copy_block(pool, jnp.int32(4), jnp.int32(1))
     tiles = paged_kv.gather_blocks(pool, jnp.array([1, 2]))
-    assert tiles["c"].shape == (3, 2, 16, 32)
+    assert tiles["c"].shape == (3, 2, 16, 128)
     pool = paged_kv.scatter_blocks(pool, jnp.array([3, 0]), tiles)
     np.testing.assert_array_equal(np.asarray(pool["c"][:, 3]),
                                   np.asarray(rows[:, 16:]))
-    assert paged_kv.pool_block_bytes(cfg, 16) == 3 * 16 * 32 * 4
-    assert cfg.cache_row_width == 32
-    assert MODEL_PRESETS["nano_test"].cache_row_width == 2 * 16
+    assert paged_kv.pool_block_bytes(cfg, 16) == 3 * 16 * 128 * 4
+    # The latent row, the one row that is not heads by head_dim wide,
+    # rests at whole lane-widths; a K/V row rests as wide as it is.
+    assert (cfg.cache_row_width, cfg.cache_row_rest_width) == (32, 128)
+    nano = MODEL_PRESETS["nano_test"]
+    assert nano.cache_row_width == nano.cache_row_rest_width == 2 * 16
 
 
 def test_int8_weights_reach_the_familys_matrices():

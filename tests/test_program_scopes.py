@@ -144,6 +144,69 @@ def test_an_instruction_nothing_names_stays_null():
     assert got["reduce.1"]["scope"] == "ffn"
 
 
+# -- a program's pool-sized moves (ISSUE 57) -----------------------------------
+
+# ``kimi-linear-48b-a3b``'s tick on PR 56's tree, the lines that matter
+# (compile for a described v5e, PR 57): the latent pool rested block-minor
+# and was copied into row-major before the step loop and back after it;
+# the conv tails' pair of copies is under the floor.
+POOL_HLO = '''HloModule jit_decode_tick
+
+%fused_computation.35 (p.0: bf16[2,1281,64,576], p.1: bf16[16,576]) -> bf16[2,1281,64,576] {
+  %p.0 = bf16[2,1281,64,576]{3,2,1,0:T(8,128)(2,1)} parameter(0)
+  %p.1 = bf16[16,576]{1,0:T(8,128)(2,1)} parameter(1)
+  ROOT %scatter.1 = bf16[2,1281,64,576]{3,2,1,0:T(8,128)(2,1)} scatter(%p.0, %p.1), to_apply=%add
+}
+
+ENTRY %main.1 (pool__c__.1: bf16[2,1281,64,576], pool__t__.1: bf16[7,16,3,12288], rows: bf16[16,576]) -> (bf16[2,1281,64,576], bf16[7,16,3,12288]) {
+  %pool__c__.1 = bf16[2,1281,64,576]{1,3,2,0:T(8,128)(2,1)} parameter(0)
+  %pool__t__.1 = bf16[7,16,3,12288]{3,1,2,0:T(8,128)(2,1)} parameter(1)
+  %rows = bf16[16,576]{1,0:T(8,128)(2,1)} parameter(2)
+  %copy.150 = bf16[2,1281,64,576]{3,2,1,0:T(8,128)(2,1)} copy(%pool__c__.1)
+  %copy.151 = bf16[7,16,3,12288]{3,2,1,0:T(4,128)(2,1)} copy(%pool__t__.1)
+  %fusion.1183 = bf16[2,1281,64,576]{3,2,1,0:T(8,128)(2,1)} fusion(%copy.150, %rows), kind=kCustom, calls=%fused_computation.35
+  %copy.161 = bf16[2,1281,64,576]{1,3,2,0:T(8,128)(2,1)} copy(%fusion.1183)
+  %copy.162 = bf16[7,16,3,12288]{3,1,2,0:T(8,128)(2,1)} copy(%copy.151)
+  ROOT %tuple.1 = (bf16[2,1281,64,576]{1,3,2,0:T(8,128)(2,1)}, bf16[7,16,3,12288]{3,1,2,0:T(8,128)(2,1)}) tuple(%copy.161, %copy.162)
+}
+'''
+
+
+def _pool_shapes(**arrays):
+    import jax
+    import jax.numpy as jnp
+    return {key: jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+            for key, shape in arrays.items()}
+
+
+def test_pool_sized_moves_counts_copies_and_leaves_in_place_writes():
+    """A ``copy`` of a pool-sized array counts, the fusion whose root
+    writes it in place does not, one whose root makes a new array does;
+    an array under ``POOL_SIZED_BYTES`` (the conv tails) is not looked
+    for, and a row-major pool's program reads ``{}``."""
+    from distributed_llm_tpu.obs.program_scopes import (
+        POOL_SIZED_BYTES, pool_sized_moves, row_major)
+    pool = _pool_shapes(c=(2, 1281, 64, 576), t=(7, 16, 3, 12288))
+    assert pool["t"].size * 2 < POOL_SIZED_BYTES < pool["c"].size * 2
+    assert pool_sized_moves(POOL_HLO, pool) == {"copy": 2}
+    moved = POOL_HLO.replace("%scatter.1 =", "%add.9 =").replace(
+        "scatter(%p.0, %p.1), to_apply=%add", "add(%p.0, %p.0)")
+    assert pool_sized_moves(moved, pool) == {"copy": 2, "fusion": 1,
+                                             "add": 1}
+    in_place = "\n".join(
+        line.replace("%copy.150", "%pool__c__.1").replace(
+            "%copy.161", "%fusion.1183")
+        for line in POOL_HLO.splitlines()
+        if " copy(%pool__c__" not in line and " copy(%fusion" not in line)
+    assert pool_sized_moves(in_place, pool) == {}
+    # An int32 vector of owners beside the arrays is no pool-sized array.
+    import jax
+    import jax.numpy as jnp
+    pool["owner"] = jax.ShapeDtypeStruct((16,), jnp.int32)
+    assert pool_sized_moves(POOL_HLO, pool) == {"copy": 2}
+    assert row_major((0, 1, 2, 3)) and not row_major((0, 2, 3, 1))
+
+
 # -- the route, over a tiny engine of every family -----------------------------
 
 # Per preset: the scopes PERF.md section 3 lists for its family, by the
@@ -214,6 +277,8 @@ def asked(request):
         second = client.get("/debug/programs")
         yield types.SimpleNamespace(
             preset=request.param, client=client, compiled=compiled,
+            pool={key: x.shape for key, x in engine.pool.items()},
+            stats=client.get("/stats").get_json(),
             before=before, listing=listing, listed=listed, ticks=ticks,
             after_ticks=after_ticks, built=built, status=first.status_code,
             doc=first.get_json(), text=first.get_data(as_text=True)
@@ -235,7 +300,8 @@ def test_the_map_is_not_built_until_asked_and_only_once(asked):
 def test_a_listing_builds_nothing_and_a_selection_only_what_it_names(asked):
     assert asked.listed == {}
     listed = asked.listing["tiers"]["nano"]
-    assert [{k: v for k, v in e.items() if k not in ("ops", "built_s")}
+    assert [{k: v for k, v in e.items()
+             if k not in ("ops", "built_s", "pool_sized_moves")}
             for e in asked.doc["tiers"]["nano"]] == listed
     assert asked.after_ticks == [("decode", key)
                                  for key in asked.compiled["decode"]]
@@ -273,6 +339,27 @@ def test_the_route_returns_an_entry_for_each_warmed_program(asked):
         assert not any(name.startswith(("while", "conditional", "call",
                                         "%")) or "@" in name
                        for name in e["ops"])
+
+
+def test_the_route_counts_pool_moves_and_stats_says_how_the_pool_rests(
+        asked):
+    """ISSUE 57: every built entry carries ``pool_sized_moves`` (a tiny
+    pool holds no pool-sized array, so the count is empty here; the real
+    sizes are tests/test_tpu_compile.py's) and GET /stats
+    ``tiers.<tier>.pool.formats`` every pool array's format at rest: on
+    the CPU row-major, the shape the engine's pool has."""
+    for e in asked.doc["tiers"]["nano"]:
+        assert e["pool_sized_moves"] == {}
+    for e in asked.listing["tiers"]["nano"]:
+        assert "pool_sized_moves" not in e           # nothing was built
+    formats = asked.stats["tiers"]["nano"]["pool"]["formats"]
+    assert {key: tuple(f["shape"]) for key, f in formats.items()} \
+        == asked.pool
+    for key, f in formats.items():
+        assert f["row_major"] is True, (key, f)
+        assert f["major_to_minor"] == list(range(len(f["shape"])))
+        assert set(f) == {"shape", "dtype", "major_to_minor", "tiling",
+                          "row_major"}
 
 
 def test_every_scope_of_the_family_is_in_the_program_that_holds_it(asked):

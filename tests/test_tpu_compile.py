@@ -36,6 +36,8 @@ import chip_smoke  # noqa: E402  (repo-root script: the kernel case table)
 
 from distributed_llm_tpu.config import (MODEL_PRESETS,  # noqa: E402
                                         flagship_cluster)
+from distributed_llm_tpu.obs.program_scopes import (  # noqa: E402
+    HLO_RESULT, pool_sized, row_major)
 
 KERNELS = ["flash_causal_attention",
            # The served MHA tick's attention over the whole token-major
@@ -119,7 +121,22 @@ def test_kernel_compiles_for_v5e(one_chip, compiled_kernels, preset, dtype,
     assert "tpu_custom_call" in compiled.as_text()
 
 
+_COMPILED = {}
+
+
 def _pool_program(one_chip, tier, program):
+    """``_compile_pool_program``, once a (tier, program) of this process:
+    the tests below read a compiled program, its engine and its pool
+    shapes and change none of them, and several hold the same program to
+    different things (ISSUE 57's pool cases take what the others
+    compiled)."""
+    key = (tier, tuple(program))
+    if key not in _COMPILED:
+        _COMPILED[key] = _compile_pool_program(one_chip, tier, program)
+    return _COMPILED[key]
+
+
+def _compile_pool_program(one_chip, tier, program):
     """One of the engine's own pool programs, compiled for the described
     chip, lowered on shapes: ``jax.eval_shape`` weights (nothing of the
     model materializes) and a pool of shapes at the tier's real size,
@@ -156,7 +173,9 @@ def _pool_program(one_chip, tier, program):
 def _nano_tick(one_chip):
     """The batched engine's own decode-tick program at nano_1b and full
     KV residency, built the way the chip builds it."""
-    engine, _, compiled, _ = _pool_program(
+    # Compiled anew each time: the environment of the test, and not the
+    # tier, says which tick this is.
+    engine, _, compiled, _ = _compile_pool_program(
         one_chip, flagship_cluster(n_devices=1).nano, ("decode", 256))
     return engine, compiled
 
@@ -218,16 +237,9 @@ def _bench_tier(monkeypatch, config: str = "smollm2-1.7b"):
     way benchmark/cluster.py builds it (SmolLM2-1.7B: 24 layers, 32/32
     heads, head_dim 64, 144 + 1 blocks of 64 tokens, 8 slots, 4 steps a
     tick)."""
-    import importlib.util
-    import json
     from distributed_llm_tpu.config import TierConfig
-    bench = os.path.join(REPO, "benchmark")
-    spec = importlib.util.spec_from_file_location(
-        "_benchmark_cluster", os.path.join(bench, "cluster.py"))
-    cluster = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(cluster)
-    with open(os.path.join(bench, "configs", config + ".json")) as f:
-        entry = cluster.tier_entries(json.load(f), False)["nano"]
+    cluster, entries = chip_smoke.benchmark_cluster(config)
+    entry = cluster.tier_entries(entries, False)["nano"]
     monkeypatch.setitem(MODEL_PRESETS, entry["preset"],
                         cluster.program_config(entry))
     kw = dict(entry["tier"], prefill_buckets=tuple(
@@ -298,7 +310,7 @@ def window_passes(hlo: str, window_elements: int, fusions: bool = False):
             words = line.split()
             computation = words[1 if words[0] == "ENTRY" else 0]
             continue
-        m = chip_smoke._HLO_RESULT.match(line)
+        m = HLO_RESULT.match(line)
         if (not m or "/attention/" not in line or m[3] in handed_on
                 or not fusions and ("fused_computation" in computation
                                     or m[3] == "fusion")):
@@ -361,6 +373,97 @@ def test_pool_program_leaves_the_pool_in_place(one_chip, as_on_tpu,
             assert inside == []
         else:
             assert sum(op == "gather" for _, op in inside) == 2, inside
+
+
+# -- a pool array has one format (ISSUE 57) ------------------------------------
+
+# Every benchmark configuration's tick and one chunk program, at the real
+# sizes.  The programs other tests of this file compile are taken from
+# them (``_pool_program`` keeps what it compiled); compiled for these
+# cases alone: the tick of ``kimi-linear-48b-a3b`` at its cell's rung,
+# 5120, ``jamba2-3b``'s two and the chunk programs of ``xing4.0-29b-a4b``
+# and ``zaya1-8b``.
+BENCH_POOL_PROGRAMS = [
+    ("smollm2-1.7b", ("decode", 256)),
+    ("smollm2-1.7b", ("chunk", 256, 1024)),
+    ("xing4.0-29b-a4b", ("decode", 256)),
+    ("xing4.0-29b-a4b", ("chunk", 256, 4096)),
+    ("nemotron-3-nano-30b-a3b", ("decode", 256)),
+    ("nemotron-3-nano-30b-a3b", ("chunk", 256, 1024)),
+    ("phi-4-mini-flash-reasoning", ("decode", 5120)),
+    ("phi-4-mini-flash-reasoning", ("chunk", 256, 5120)),
+    ("jamba2-3b", ("decode", 256)),
+    ("jamba2-3b", ("chunk", 256, 2048)),
+    ("zaya1-8b", ("decode", 256)),
+    ("zaya1-8b", ("chunk", 256, 5120)),
+    ("kimi-linear-48b-a3b", ("decode", 256)),
+    ("kimi-linear-48b-a3b", ("decode", 5120)),
+    ("kimi-linear-48b-a3b", ("chunk", 256, 5120)),
+]
+# The pool-sized arrays (16 MiB or more: ``POOL_SIZED_BYTES``) a
+# configuration's pool must hold, so that a case cannot pass on a pool
+# the floor hides.  The conv tails ``t`` (8.3 MB at most) and ``owner``
+# lie under it: every hybrid configuration's ``t`` rests with the slots
+# and not the taps second-minor and is copied in and out of every
+# program, 0.03 ms a tick (PERF.md section 7).
+POOL_SIZED = {"smollm2-1.7b": {"k", "v"},
+              "xing4.0-29b-a4b": {"c"},
+              "nemotron-3-nano-30b-a3b": {"k", "v", "s"},
+              "phi-4-mini-flash-reasoning": {"k", "v", "rk", "rv", "s"},
+              "jamba2-3b": {"k", "v", "s"},
+              "zaya1-8b": {"k", "v"},
+              "kimi-linear-48b-a3b": {"c", "s"}}
+
+
+# What a program may still move, and why it is not this rule's to take:
+# ``jamba2-3b``'s tick (4 slots) keeps its whole state array, 34 MB, in
+# the chip's other memory space for the tick's four steps: the compiler's
+# memory-space assignment copies it there at the entry and back at the
+# end (an async ``copy-start`` / ``copy-done`` pair each way, the format
+# the same on both sides), on PR 56's tree as on this one.
+KNOWN_MOVES = {("jamba2-3b", ("decode", 256)): {"copy-done": 2}}
+
+
+@pytest.mark.parametrize(
+    "config,program", BENCH_POOL_PROGRAMS,
+    ids=[f"{c}-{'-'.join(map(str, p))}" for c, p in BENCH_POOL_PROGRAMS])
+def test_a_pool_array_has_one_format(one_chip, as_on_tpu, monkeypatch,
+                                     config, program):
+    """Compiled for a described v5e, every configuration's tick and chunk
+    program takes and returns every pool-sized array ROW-MAJOR, the
+    format the device rests it in by default (nothing is pinned, so a
+    program loaded from the persistent compile cache agrees), aliased,
+    and holds no pool-sized ``copy``: neither at its edge nor round a
+    loop.  On PR 56's tree ``kimi-linear-48b-a3b`` fails all three of its
+    cases, and no other configuration any: its latent pool
+    ``bf16[2, 1281, 64, 576]`` rested block-minor (padding 1281 blocks to
+    1408 is more compact than padding 576 lanes to 640) and was copied
+    into row-major and back by every tick (``pool_sized_moves`` ``{copy:
+    2}``) and every chunk program, which also carried its state array
+    ``f32[7, 16, 32, 128, 128]`` round the layer loop in the operand order
+    of ``kda_scan``'s products (``{copy: 4}``).  Since ISSUE 57 the latent
+    row rests 640 wide (``cfg.cache_row_rest_width``) and the state goes
+    back into the carry row-major (``hybrid_ssm._row_major``)."""
+    tier = _bench_tier(monkeypatch, config)
+    engine, pool, compiled, pool_arg = _pool_program(one_chip, tier, program)
+    sized = {key for key, x in pool.items() if pool_sized(x)}
+    assert sized == POOL_SIZED[config]
+    if engine.cfg.kv_lora_rank:
+        assert pool["c"].shape[-1] == engine.cfg.cache_row_rest_width == 640
+        assert engine.cfg.cache_row_width == 576
+    facts = chip_smoke.pool_program_facts(compiled, pool_arg, pool)
+    assert facts["formats_match"], facts
+    assert facts["pool_sized_moves"] == KNOWN_MOVES.get(
+        (config, program), {}), facts
+    for key in sized:
+        assert row_major(facts["major_to_minor"][key]), (key, facts)
+    # Everything large that comes back is the pool, aliased; the device's
+    # tiles pad no pool-sized array (the latent row says its padding in
+    # its shape), so the aliased bytes are the arrays' own but for the
+    # small ones' tiles.
+    pool_bytes = sum(x.size * x.dtype.itemsize for x in pool.values())
+    assert facts["output_bytes"] - facts["alias_bytes"] < 1 << 20, facts
+    assert pool_bytes <= facts["alias_bytes"] < pool_bytes + (8 << 20), facts
 
 
 # -- the routed experts stay where they rest (ISSUE 34) ------------------------
@@ -434,7 +537,7 @@ def test_whole_lane_widths_rest_as_stored(one_chip, as_on_tpu, monkeypatch,
                                                       "['we_down']"))]
     assert len(stacks) == 6                  # three ``E`` positions a period
     assert all(fmt.layout.major_to_minor == (0, 1, 2, 3) for fmt in stacks)
-    results = filter(None, map(chip_smoke._HLO_RESULT.match,
+    results = filter(None, map(HLO_RESULT.match,
                                compiled.as_text().splitlines()))
     made = [(m[2], m[3]) for m in results
             if m[2].endswith(("2688,1920]", "1920,2688]"))
@@ -483,7 +586,7 @@ def weight_sized_moves(hlo: str, least_bytes: int):
             words = line.split()
             computation = words[1 if words[0] == "ENTRY" else 0].lstrip("%")
             continue
-        m = chip_smoke._HLO_RESULT.match(line)
+        m = HLO_RESULT.match(line)
         if not m:
             continue
         if m[1]:
@@ -613,7 +716,7 @@ def test_few_instructions_rest_under_no_scope(one_chip, as_on_tpu, case):
                       kv_block_size=16, prefill_buckets=(16, 32, 64, 128),
                       prefill_chunk_tokens=16, enable_prefix_cache=False)
     try:
-        _, _, compiled, _ = _pool_program(one_chip, tier, program)
+        _, _, compiled, _ = _compile_pool_program(one_chip, tier, program)
     finally:
         # The tiny presets' shapes are the CPU tests' own: what was traced
         # here for the chip (a kernel not interpreted) must not be found
