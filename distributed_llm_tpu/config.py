@@ -207,9 +207,10 @@ class ModelConfig:
     # with a decay a channel (KDA: ``ssm_heads`` heads whose keys and
     # values are ``ssm_head_dim`` wide, three convs of ``ssm_conv`` taps;
     # a float32 MATRIX state a head and the convs' tails a slot), "L"
-    # latent attention WITHOUT rotary over one paged row of
-    # ``kv_lora_rank + qk_rope_head_dim`` numbers a token (models/
-    # latent_moe.py's attention; ``q_lora_rank`` 0: queries by one matrix).
+    # latent attention over one paged row of ``kv_lora_rank +
+    # qk_rope_head_dim`` numbers a token (models/latent_moe.py's
+    # attention; ``q_lora_rank`` 0: queries by one matrix), with or
+    # without a rotary term by ``rotary`` (True: YaRN's, ``rope_*``).
     # The layer loop scans the pattern's shortest repeating period
     # (``layer_period``) after the single sublayers that lead it
     # (``layer_lead``).  The head is tied where ``tie_embeddings``.
@@ -232,7 +233,8 @@ class ModelConfig:
     # hybrid family's "*" applies no rotary embedding (position comes from
     # the state-space layers: ``rotary`` False); its "C" rotates the first
     # ``qk_rope_head_dim`` numbers of each head (0: the whole head) by
-    # ``rope_theta`` (``rotary`` True).
+    # ``rope_theta`` (``rotary`` True); its "L" rotates its row's shared
+    # numbers where ``rotary`` is True and leaves them where it is False.
     attn_head_dim: int = 0
     rotary: bool = True
     # The hybrid family's experts: the router scores ``num_experts``

@@ -1701,19 +1701,22 @@ class ContinuousBatchingEngine:
         """The recurrent rows (GET /stats ``state``), or None for a model
         without them: the kind of mixer that owns them (``cca_tail``: the
         "C" attention layers' tail rows, with no state; ``kda``: the
-        linear-attention layers' matrix a head and three conv tails) and
-        its layers, how many rows there are, how many name a sequence,
-        what one holds, how many sequences started one from zero, and the
-        K/V (under ``kda`` the latent layers' one row a token) beside
-        them."""
+        linear-attention layers' matrix a head and three conv tails;
+        ``none``: a pattern with no row kind, whose rows are zero layers
+        deep) and its layers, how many rows there are, how many name a
+        sequence, what one holds, how many sequences started one from
+        zero, and the K/V (under ``kda`` and ``none`` the latent layers'
+        one row a token) beside them."""
         if self._state_owner is None:
             return None
         from ..utils.roofline import (kv_bytes_per_pos, ring_row_bytes,
                                       state_row_bytes)
         tails, kda = self.cfg.layers_of("C"), self.cfg.layers_of("K")
+        ssm = self.cfg.layers_of("M")
         out = {"mixer": ("cca_tail" if tails else "kda" if kda else
+                         "none" if not ssm else
                          "mamba1" if self.cfg.ssm_dt_rank else "mamba2"),
-               "layers": tails or kda or self.cfg.layers_of("M"),
+               "layers": tails or kda or ssm,
                "rows": int(self._state_owner.size),
                "rows_in_use": int(np.count_nonzero(self._rows_owned())),
                "row_bytes": int(state_row_bytes(self.cfg)),

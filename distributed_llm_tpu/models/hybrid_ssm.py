@@ -69,13 +69,23 @@ copy of the Mamba-1 mixer (``mamba1`` and what it calls).
   three convs' last ``ssm_conv - 1`` inputs (q, k and v side by side) a
   layer: a ROW of ``pool["s"]`` / ``pool["t"]`` like ``M``'s.  The
   chunk's recurrence is in matrix form (``kda_scan``).
-- ``L``, **latent attention without rotary**: ``latent_moe._attend``, the
-  latent family's own, over ONE paged row a token of ``kv_lora_rank``
-  normalised latent numbers and ``qk_rope_head_dim`` numbers shared by
-  every head, written and read UNROTATED (position comes from the ``K``
-  layers); queries by one matrix.  A pattern with ``L`` pages
+- ``L``, **latent attention**, with or without a rotary term, by
+  ``cfg.rotary``: ``latent_moe._attend``, the latent family's own, over
+  ONE paged row a token of ``kv_lora_rank`` normalised latent numbers and
+  ``qk_rope_head_dim`` numbers shared by every head; queries by one
+  matrix.  Under ``rotary`` False the shared numbers are written and read
+  UNROTATED (position comes from the ``K`` layers beside it); under True
+  they and the queries' last ``qk_rope_head_dim`` numbers a head are
+  rotated by YaRN's frequencies and the scores scaled by its magnitude
+  (``latent_moe.rope_sincos``, ``softmax_scale``: the row at rest is
+  ``[RMSNorm(c) | rotated k_r]``).  A pattern with ``L`` pages
   ``pool["c"]`` [``L`` layers, NB, bs, row] where ``*`` and ``C`` page
   ``"k"`` and ``"v"``.
+
+A pattern with no row kind (no ``M``, ``K`` or ``C``: ``"L-" + "LE" *
+5``) keeps ``pool["s"]`` and ``pool["t"]`` as arrays of ZERO layers, like
+the empty ``"s"`` beside ``C``'s tails: every program carries them and no
+mixer reads them; ``pool["owner"]`` still names a row a slot.
 
 A pattern with ``C`` scales the residual's merge in every sublayer, ``x
 <- (a_r x + b_r) + (a_o mixer(..) + b_o)``, four vectors a sublayer
@@ -187,7 +197,8 @@ def check(cfg: ModelConfig) -> None:
                          f"multiple of ssm_groups {cfg.ssm_groups}")
     # What each attention kind needs of the positional term: "*" applies
     # none (the state-space layers beside it carry position), "C" rotates
-    # part of every head.
+    # part of every head, "L" the shared numbers of its row where the
+    # pattern states rotary.
     if "C" in cfg.layer_pattern:
         rot = cfg.qk_rope_head_dim or cfg.head_dim
         if (not cfg.rotary or rot % 2 or rot > cfg.head_dim
@@ -203,10 +214,9 @@ def check(cfg: ModelConfig) -> None:
                 f"{cfg.name}: the pool's K/V layers and its tail rows are "
                 f"indexed by the ONE kind that owns them: a pattern with "
                 f"'C' has no 'M' and no '*'")
-    elif set(cfg.layer_pattern) & set("*L") and cfg.rotary:
-        raise ValueError(f"{cfg.name}: a pattern with '*' or 'L' states "
-                         f"rotary False: those kinds apply no rotary "
-                         f"embedding")
+    elif "*" in cfg.layer_pattern and cfg.rotary:
+        raise ValueError(f"{cfg.name}: a pattern with '*' states rotary "
+                         f"False: that kind applies no rotary embedding")
     # The rows and the paged arrays are indexed by the ONE kind that owns
     # them: "K" rows beside no "M" or "C", "L"'s latent array beside no
     # "*" or "C"; a latent row's widths come with "L" and only with it.
@@ -1195,15 +1205,18 @@ def _kda(cfg: ModelConfig, lp: Params, h_in, pool, li, ctx):
 def _latent(cfg: ModelConfig, lp: Params, h_in, pool, li, ctx):
     """h_in [B, S, H] -> (mixer output, pool); ``li`` the layer's index
     among the "L" layers, which are ``pool["c"]``'s.  The latent family's
-    own attention with the rotary off: absorbed for a decode step,
-    up-projected over the table's window for a chunk."""
+    own attention, absorbed for a decode step, up-projected over the
+    table's window for a chunk; rotated by ``ctx["rope"]``, the sin and
+    cos of the tokens' positions, where the pattern states rotary
+    (``forward_paged`` makes them once a program), else not at all."""
     chunk = "row" in ctx
     if chunk:
         bs = pool["c"].shape[2]
         tables, q_pos = ctx["table"][None, :ctx["window"] // bs], ctx["q_pos"]
     else:
         tables, q_pos = ctx["tables"], ctx["pos"][:, None]
-    out, rows = latent_moe._attend(cfg, lp, h_in, None, None, q_pos,
+    sin, cos = ctx.get("rope", (None, None))
+    out, rows = latent_moe._attend(cfg, lp, h_in, sin, cos, q_pos,
                                    pool["c"], li, ctx["blk"], ctx["off"],
                                    tables, absorbed=not chunk)
     return quant.matmul(out, lp["wo"]), {**pool, "c": rows}
@@ -1351,6 +1364,12 @@ def forward_paged(cfg: ModelConfig, params: Params, tokens: jax.Array,
     lead, period = cfg.layer_lead, cfg.layer_period
     index = {kind: kind_index(cfg, kind) for kind in KINDS}
     x = quant.embed_rows(params["embed"], tokens).astype(dtype)
+    if cfg.rotary and "L" in cfg.layer_pattern:
+        # Every "L" layer rotates by the same positions: a chunk's, or a
+        # decode step's one a sequence.
+        with jax.named_scope("step_inputs"):
+            ctx = {**ctx, "rope": latent_moe.rope_sincos(
+                cfg, ctx["q_pos"] if "row" in ctx else ctx["pos"][:, None])}
     owner = pool["owner"]
     carried = {key: a for key, a in pool.items() if key != "owner"}
 

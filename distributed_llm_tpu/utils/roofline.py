@@ -196,9 +196,16 @@ def _attention_width_layers(cfg):
     """(width summed over query heads, layers) of the layers that attend
     over positions: every layer, or the hybrid family's attention ones
     (the shared-K/V family's: the readers of its one cached layer; its
-    window layers' fixed span is not counted)."""
+    window layers' fixed span is not counted).  A pattern with "L": the
+    mean of a head's score width (nope + rope) and its value width, as a
+    chunk up-projects them (64 x 160 where hidden / heads would say 64 x
+    64); a decode step's absorbed form does more a position, against the
+    latent row itself."""
     if cfg.family == "shared_kv":
         return cfg.num_heads * cfg.head_dim, kv_readers(cfg)
+    if cfg.layers_of("L"):
+        width = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim + cfg.v_head_dim
+        return cfg.num_heads * width // 2, cfg.kv_layers
     if cfg.family == "hybrid":
         return cfg.num_heads * cfg.head_dim, cfg.kv_layers
     return cfg.hidden_size, cfg.num_layers

@@ -134,8 +134,9 @@ def _drain_after_stats(self) -> None:
         for name in self.entries:
             block = tiers.get(name, {})
             # "state": the recurrent rows' mixer (mamba2, mamba1, cca_tail,
-            # kda), their count and bytes, and the K/V or latent layers
-            # beside them; null for a model without rows.
+            # kda; none for a pattern with no row kind), their count and
+            # bytes, and the K/V or latent layers beside them; null for a
+            # model without rows.
             # "pool": every pool array's format at rest (PR 57).
             for key in ("tick", "prefill", "state", "pool"):
                 print(f"[bench:stats] tiers.{name}.{key} = "

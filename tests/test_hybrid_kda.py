@@ -664,8 +664,12 @@ def test_the_pattern_is_one_family_and_its_checks_say_what_each_needs():
     assert cfg.layer_segments == (("K", 1), ("-", 1), ("KEKELEKE", 2))
     assert hybrid_ssm.kind_index(cfg, "K") == ([0, 1, 1, 2, 2, 2, 2, 3], 3)
     assert hybrid_ssm.kind_index(cfg, "L") == ([0, 0, 0, 0, 0, 1, 1, 1], 1)
+    # "L" rotates where the pattern states rotary (ISSUE 59:
+    # tests/test_hybrid_latent_rotary.py); "*" still applies none.
+    hybrid_ssm.check(dataclasses.replace(cfg, rotary=True))
     with pytest.raises(ValueError, match="rotary False"):
-        hybrid_ssm.check(dataclasses.replace(cfg, rotary=True))
+        hybrid_ssm.check(dataclasses.replace(
+            MODEL_PRESETS["hybrid_test"], rotary=True))
     for mixed, n in (("KEM", 3), ("K-LE*E", 6)):
         with pytest.raises(ValueError, match="beside no"):
             hybrid_ssm.check(dataclasses.replace(
@@ -698,7 +702,8 @@ CONFIG_FAMILIES = {
     "smollm2-1.7b": "dense", "xing4.0-29b-a4b": "latent",
     "nemotron-3-nano-30b-a3b": "hybrid",
     "phi-4-mini-flash-reasoning": "shared_kv", "jamba2-3b": "hybrid",
-    "zaya1-8b": "hybrid", "kimi-linear-48b-a3b": "hybrid"}
+    "zaya1-8b": "hybrid", "kimi-linear-48b-a3b": "hybrid",
+    "sarvam-105b": "hybrid"}
 
 
 def test_every_preset_resolves_to_the_family_it_did():

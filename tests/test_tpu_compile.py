@@ -381,8 +381,8 @@ def test_pool_program_leaves_the_pool_in_place(one_chip, as_on_tpu,
 # sizes.  The programs other tests of this file compile are taken from
 # them (``_pool_program`` keeps what it compiled); compiled for these
 # cases alone: the tick of ``kimi-linear-48b-a3b`` at its cell's rung,
-# 5120, ``jamba2-3b``'s two and the chunk programs of ``xing4.0-29b-a4b``
-# and ``zaya1-8b``.
+# 5120, ``jamba2-3b``'s two, the chunk programs of ``xing4.0-29b-a4b``
+# and ``zaya1-8b`` and ``sarvam-105b``'s tick at 16 640.
 BENCH_POOL_PROGRAMS = [
     ("smollm2-1.7b", ("decode", 256)),
     ("smollm2-1.7b", ("chunk", 256, 1024)),
@@ -399,6 +399,10 @@ BENCH_POOL_PROGRAMS = [
     ("kimi-linear-48b-a3b", ("decode", 256)),
     ("kimi-linear-48b-a3b", ("decode", 5120)),
     ("kimi-linear-48b-a3b", ("chunk", 256, 5120)),
+    # The tick at the cell's furthest rung and the chunk program at its
+    # widest (ISSUE 59): 64 latent heads over 16 384 positions.
+    ("sarvam-105b", ("decode", 16640)),
+    ("sarvam-105b", ("chunk", 256, 16384)),
 ]
 # The pool-sized arrays (16 MiB or more: ``POOL_SIZED_BYTES``) a
 # configuration's pool must hold, so that a case cannot pass on a pool
@@ -412,7 +416,9 @@ POOL_SIZED = {"smollm2-1.7b": {"k", "v"},
               "phi-4-mini-flash-reasoning": {"k", "v", "rk", "rv", "s"},
               "jamba2-3b": {"k", "v", "s"},
               "zaya1-8b": {"k", "v"},
-              "kimi-linear-48b-a3b": {"c", "s"}}
+              "kimi-linear-48b-a3b": {"c", "s"},
+              # No row kind: "s" and "t" are zero layers deep.
+              "sarvam-105b": {"c"}}
 
 
 # What a program may still move, and why it is not this rule's to take:
@@ -470,7 +476,8 @@ def test_a_pool_array_has_one_format(one_chip, as_on_tpu, monkeypatch,
 
 # configuration: (kernel calls in the tick's one layer body: ONE an expert
 # layer since ISSUE 53, the stacked experts of one key in GB, temporaries
-# allowed in GB).  PR 33's
+# allowed in GB[, what a CHUNK's experts trace: the one fused call but
+# where its matrices and rows pass the kernel's VMEM]).  PR 33's
 # trap: at a minor width off the lanes the entry of every program copied
 # every held expert ([2, 64, 2688, 1856] x 3: 4.36 GB of temporaries).
 ROUTED_TICKS = {
@@ -481,6 +488,13 @@ ROUTED_TICKS = {
     # 64 held of 256 gated experts of 2304 x 1024 over 8 expert sublayers,
     # four a period of "KEKELEKE" (PR 54).
     "kimi-linear-48b-a3b": (4, 0.60, 0.5),
+    # 32 held of 128 gated experts of 4096 x 2048 over 5 expert sublayers,
+    # one a period of "LE" (PR 59); the temporaries are a period's slices
+    # of the scanned matrices (wq 100 MB, wo 67, the shared expert 50) and
+    # the lead MLP's 403 MB beside them, read at 0.73 GB.  A chunk's 2048
+    # assignments against three matrices of 4096 x 2048 pass the fused
+    # call's VMEM (``grouped_product.serves_ffn``): a call a product.
+    "sarvam-105b": (1, 2.68, 0.9, "pallas"),
 }
 
 
@@ -492,11 +506,11 @@ def test_routed_tick_reads_the_experts_where_they_rest(one_chip, as_on_tpu,
     layer body is ONE call of the repo's kernel, handed the STACKED
     experts whole — no copy of them at the program's entry, no per-layer
     slice: the temporaries stay far under one key's stack."""
-    products, stack_gb, temp_limit_gb = ROUTED_TICKS[config]
+    products, stack_gb, temp_limit_gb, *chunk = ROUTED_TICKS[config]
     tier = _bench_tier(monkeypatch, config)
     engine, _, compiled, _ = _pool_program(one_chip, tier, ("decode", 256))
-    assert engine.grouped_product_form() == {"decode": "pallas_ffn",
-                                            "prefill": "pallas_ffn"}
+    assert engine.grouped_product_form() == {
+        "decode": "pallas_ffn", "prefill": (chunk or ["pallas_ffn"])[0]}
     # The hybrid family's attention layers are GQA 32/2 at head 128: rows
     # of 512 B, which ``rows_attention.serves`` leaves to the XLA form
     # (ISSUE 45); the latent family attends in code of its own.
@@ -568,6 +582,37 @@ def test_a_pattern_with_a_lead_keeps_one_layer_loop_at_the_real_sizes(
     for scope in ("kda_proj", "kda_conv", "kda_gate", "kda_scan",
                   "kda_out_norm", "latent_attention", "kv_write"):
         assert scope in text, scope
+
+
+def test_a_pattern_of_latent_layers_alone_compiles_at_its_widest_rung(
+        one_chip, as_on_tpu, monkeypatch):
+    """``sarvam-105b``'s chunk program at its real sizes and widest rung
+    (ISSUE 59): the lead "L-" runs inline and the periods "LE" x 5 are the
+    program's ONE ``while`` (the tick's two are held by the routed-tick
+    test above); 64 heads' float32 scores against 16 384 positions, their
+    probabilities and the up-projected keys and values of the whole rung
+    are the plain form's temporaries, 1.64 GB a layer in turn, so that
+    weights, pool and temporaries stay under 13.5 GB of the chip's 16 GiB;
+    the sines of a chunk's positions are made once, under
+    ``step_inputs``."""
+    tier = _bench_tier(monkeypatch, "sarvam-105b")
+    engine, pool, compiled, _ = _pool_program(one_chip, tier,
+                                              ("chunk", 256, 16384))
+    cfg = engine.cfg
+    assert (cfg.layer_lead, cfg.layer_period) == ("L-", "LE")
+    assert cfg.rotary and cfg.num_heads == 64 and cfg.rope_factor == 40.0
+    assert pool["s"].size == pool["t"].size == 0
+    text = compiled.as_text()
+    assert text.count(" while(") == 1
+    m = compiled.memory_analysis()
+    assert m.temp_size_in_bytes < 2.0 * GB
+    assert (m.argument_size_in_bytes + m.temp_size_in_bytes) < 13.5 * GB
+    for scope in ("latent_attention", "kv_write", "mixer_proj",
+                  "moe_router", "moe_experts", "shared_expert", "ffn",
+                  "step_inputs", "head"):
+        assert scope in text, scope
+    assert text.count("tpu_custom_call") == 3          # gate, up, down
+    assert "ragged-dot" not in text
 
 
 # -- wq and wk are read where they rest (ISSUE 48) -----------------------------
