@@ -36,11 +36,19 @@ KERNEL_TOL = {"float32": 2e-5, "bfloat16": 2e-2,
               # Two chained products over thousands of terms, the one
               # between them rounded to the dtype; another summation order.
               ("float32", "grouped_product"): 2e-4,
-              ("bfloat16", "grouped_product"): 5e-2}
+              ("bfloat16", "grouped_product"): 5e-2,
+              # The up-projected keys and values and the probabilities
+              # rounded to the dtype between three chained products.
+              ("bfloat16", "latent_chunk"): 5e-2}
 
 # Mamba-1's chunk scan as both row cells of the benchmark run it:
 # positions, states, channels.
 SCAN_SHAPE = (256, 16, 5120)
+# The latent chunk attention as ``sarvam-105b``'s lane runs it at a rung
+# three quarters written: heads, queries, window rows, the chunk's end,
+# latent numbers, the row's resting width (a head's keys and values 128
+# each, 64 rotary numbers).
+LATENT_SHAPE = (64, 256, 4096, 3072, 512, 640)
 
 LONG_SENTENCE = ("Rivers carry sediment from the mountains to the delta, "
                  "where the channels split, slow down and drop their load. ")
@@ -594,7 +602,8 @@ def kernel_cases(nq: int, nkv: int, d: int, dtype, *, batch: int = 8,
                  block: int = 64, blocks_per_slot: int = 32,
                  prefill_len: int = 1024,
                  grouped: Optional[Dict[str, tuple]] = None,
-                 scan: tuple = SCAN_SHAPE
+                 scan: tuple = SCAN_SHAPE,
+                 latent: tuple = LATENT_SHAPE
                  ) -> Dict[str, KernelCase]:
     """The main path's Pallas kernels at one head geometry, each with
     its XLA reference and a seeded argument builder.  chip_smoke runs
@@ -605,13 +614,15 @@ def kernel_cases(nq: int, nkv: int, d: int, dtype, *, batch: int = 8,
     of the benchmark's two routed cells at the widths they store.
     ``scan``: Mamba-1's recurrence over a chunk (no heads in it either,
     float32 whatever ``dtype``) as (positions, state, inner); by default
-    a chunk of the shared-K/V family's benchmark cell."""
+    a chunk of the shared-K/V family's benchmark cell.  ``latent``: the
+    latent chunk attention (its own heads) as ``LATENT_SHAPE`` reads."""
     import jax
     import jax.numpy as jnp
     import numpy as np
 
     from distributed_llm_tpu.ops import attention as A
     from distributed_llm_tpu.ops import grouped_product as GP
+    from distributed_llm_tpu.ops import latent_chunk_attention as LCA
     from distributed_llm_tpu.ops import pallas_attention as PA
     from distributed_llm_tpu.ops import rows_attention as RW
     from distributed_llm_tpu.ops import ssm_chunk_scan as SC
@@ -705,6 +716,16 @@ def kernel_cases(nq: int, nkv: int, d: int, dtype, *, batch: int = 8,
         state, y = jax.lax.scan(step, state, (dt, u, b, c))
         return jnp.concatenate([y, state])
 
+    l_heads, l_s, l_w, l_end, l_dc, l_row = latent
+    l_dn, l_dr, l_scale = 128, 64, 192 ** -0.5
+
+    def latent_args():
+        return (rand(keys[0], (1, l_s, l_heads, l_dn)),
+                rand(keys[1], (1, l_s, l_heads, l_dr)),
+                rand(keys[2], (1, l_w, l_row)),
+                rand(keys[3], (l_dc, l_heads, 2 * l_dn)) * l_dc ** -0.5,
+                (l_end - l_s + jnp.arange(l_s, dtype=jnp.int32))[None])
+
     if grouped is None:
         grouped = {"wide-reasoning": (96, 16, 2688, 1920),
                    "reasoned-reply": (32, 16, 3584, 1024)}
@@ -740,6 +761,13 @@ def kernel_cases(nq: int, nkv: int, d: int, dtype, *, batch: int = 8,
             "ssm_scan",
             lambda *a: jnp.concatenate(SC.ssm_chunk_scan(*a)),
             scan_reference, scan_args),
+        # The latent row's chunk attention by blocks of the window (PR
+        # 61), against the plain form (on float32 arguments: nothing
+        # rounded).
+        "latent_chunk_attention": KernelCase(
+            "latent_chunk",
+            lambda *a: LCA.latent_chunk_attention(*a, scale=l_scale),
+            lambda *a: LCA.plain(*a, scale=l_scale), latent_args),
     }
 
 

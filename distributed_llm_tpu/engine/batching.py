@@ -400,6 +400,10 @@ class ContinuousBatchingEngine:
         # The attention form each compiled rung of the decode tick was
         # traced with, by its table window in tokens (``tick_stats``).
         self._attention_forms: Dict[str, str] = {}
+        # The chunk's twin: what each compiled chunk program's latent
+        # attention was traced with, by (chunk tokens, window tokens)
+        # (``prefill_stats``); empty for a family without a latent row.
+        self._chunk_attention_forms: Dict[Tuple[int, int], str] = {}
         # ``step_programs``' entries by (stage, key): empty until GET
         # /debug/programs asks, and nothing but that route fills it.
         self._program_maps: Dict[Tuple[str, Any], Dict[str, Any]] = {}
@@ -608,6 +612,9 @@ class ContinuousBatchingEngine:
         # dllm_prefill_chunks_by_window_total): a long prompt's chunks
         # laid against the ladder.
         self.prefill_chunks_by_window: Dict[int, int] = {}
+        # ... and by what their program's latent attention was traced
+        # with (``chunk_attention_form``): how often the kernel engages.
+        self.prefill_chunks_by_form: Dict[str, int] = {}
         # perf_counter of the last plain tick's fetch return: the
         # latest moment the host saw the device reach whatever was
         # queued behind that tick (``_settle_chunk``'s clock).
@@ -1025,6 +1032,9 @@ class ContinuousBatchingEngine:
             window = key[0] * self.paged.block_size
             self._attention_forms[str(window)] = (
                 self.decode_attention_form(window))
+        if stage == "chunk_prefill" and self.cfg.kv_lora_rank:
+            self._chunk_attention_forms[key] = (
+                self.chunk_attention_form(*key))
         # Stitch the compile onto the profiler timeline: a mid-serve
         # trace stalls every active slot, and the tick record it lands
         # next to shows exactly which tick paid for it.
@@ -1107,6 +1117,20 @@ class ContinuousBatchingEngine:
             self.cfg.head_dim, window // bs, bs,
             self.cfg.num_kv_heads * self.cfg.head_dim,
             jnp.int8 if quantized else self.cfg.dtype)
+
+    def chunk_attention_form(self, chunk: int, window: int) -> Optional[str]:
+        """What the chunk program of ``chunk`` tokens over a table window
+        of ``window`` attends its latent rows with: ``blocks`` (the kernel
+        of ``ops/latent_chunk_attention.py``) or ``plain`` (``einsum`` +
+        ``softmax`` over the whole up-projected window), the static test
+        ``latent_moe._attend`` makes on the program's shapes; None for a
+        family that caches no latent row.  A fact of each compiled
+        program, like ``decode_attention_form``."""
+        if not self.cfg.kv_lora_rank:
+            return None
+        rows = self.pool["c"]
+        return models.latent_moe.chunk_attention_form(
+            self.cfg, chunk, window, rows.shape[-1], rows.dtype)
 
     def _decode_step(self):
         """One compiled tick for all slots: ``decode_steps_per_tick``
@@ -1277,7 +1301,8 @@ class ContinuousBatchingEngine:
                      "window_tokens": window,
                      "chunk_tokens": None if tick else key[0],
                      "attention_form": (self.decode_attention_form(window)
-                                        if tick else None)}
+                                        if tick else
+                                        self.chunk_attention_form(*key))}
             if ops:
                 entry.update(self._program_maps[st, key])
             out.append(entry)
@@ -2471,6 +2496,10 @@ class ContinuousBatchingEngine:
         self.prefill_written_positions_total += written
         self.prefill_chunks_by_window[window] = \
             self.prefill_chunks_by_window.get(window, 0) + 1
+        form = self._chunk_attention_forms.get((c, window))
+        if form is not None:
+            self.prefill_chunks_by_form[form] = \
+                self.prefill_chunks_by_form.get(form, 0) + 1
         self_only = self.cfg.shared_kv and end < pf.total
         self.prefill_self_only_chunks_total += int(self_only)
         try:
@@ -2485,6 +2514,8 @@ class ContinuousBatchingEngine:
             m.prefill_chunks_by_window.labels(self.tier.name,
                                               str(window)).inc()
             m.prefill_written_positions.labels(self.tier.name).inc(written)
+            if form is not None:
+                m.prefill_chunks_by_form.labels(self.tier.name, form).inc()
             if self_only:
                 m.prefill_self_only_chunks.labels(self.tier.name).inc()
         except Exception:
@@ -4332,7 +4363,12 @@ class ContinuousBatchingEngine:
                "window_over_written": (round(attended / written, 4)
                                        if written else None),
                "chunks_by_window": dict(sorted(
-                   self.prefill_chunks_by_window.items()))}
+                   self.prefill_chunks_by_window.items())),
+               "chunks_by_attention_form": dict(
+                   self.prefill_chunks_by_form),
+               "attention_form": {
+                   "%dx%d" % key: form for key, form in sorted(
+                       self._chunk_attention_forms.items())}}
         if self.cfg.shared_kv:
             out["chunks_self_only_total"] = \
                 self.prefill_self_only_chunks_total

@@ -44,7 +44,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..config import ModelConfig
-from ..ops import grouped_product, quant
+from ..ops import grouped_product, latent_chunk_attention, quant
 from . import transformer
 
 Params = Dict[str, Any]
@@ -270,18 +270,26 @@ def _hyper(cfg: ModelConfig, hc: Params, x: jax.Array, sublayer):
 # Latent attention over the paged pool
 # =============================================================================
 
-def _einsum_f32(spec: str, a, b):
-    """Einsum of the operands as stored, accumulated and returned in
-    float32.  The CPU backend has no bfloat16 x bfloat16 -> float32
-    product, so there the operands are widened first: the same numbers."""
-    if jax.default_backend() == "cpu":
-        return jnp.einsum(spec, a.astype(jnp.float32), b.astype(jnp.float32))
-    return jnp.einsum(spec, a, b, preferred_element_type=jnp.float32)
+_einsum_f32 = latent_chunk_attention.einsum_f32
 
 
 def _rope_1(x, sin, cos):
     """Rotate-half on a head-less [..., D] row."""
     return transformer.apply_rope(x[..., None, :], sin, cos)[..., 0, :]
+
+
+def chunk_attention_form(cfg: ModelConfig, queries: int, window: int,
+                         row: int, dtype) -> str:
+    """What ``_attend`` traces a chunk's attention with, ``queries``
+    tokens a sequence against ``window`` gathered rows ``row`` wide:
+    ``blocks``, the kernel of ``ops/latent_chunk_attention.py`` (a block
+    of the window up-projected beside its use, no scores in memory), or
+    ``plain`` (``einsum`` + ``softmax`` over the whole up-projected
+    window).  A test on static shapes and nothing else."""
+    return "blocks" if latent_chunk_attention.serves(
+        queries, window, cfg.num_heads, cfg.qk_nope_head_dim,
+        cfg.qk_rope_head_dim, cfg.v_head_dim, cfg.kv_lora_rank, row,
+        dtype) else "plain"
 
 
 def _attend(cfg: ModelConfig, lp: Params, h_in, sin, cos, q_pos, pool_c, i,
@@ -330,26 +338,30 @@ def _attend(cfg: ModelConfig, lp: Params, h_in, sin, cos, q_pos, pool_c, i,
         rows = rows.reshape(b, -1, rows.shape[-1])        # [B, W, R]
         c, k_r = rows[..., :dc], rows[..., dc:dc + dr]
         w_kvb = quant.dequantize(lp["w_kvb"]).reshape(dc, nh, dn + dv)
-        if absorbed:
-            # Scores against the cached row itself: the up-projection of
-            # the keys folded into the query ...
-            q_lat = _einsum_f32("bsnd,cnd->bsnc", q_nope,
-                                w_kvb[..., :dn]).astype(dtype)
-            scores = _einsum_f32("bsnc,bwc->bnsw", q_lat, c)
-        else:
-            kvb = quant.matmul(c, lp["w_kvb"]).reshape(b, -1, nh, dn + dv)
-            scores = _einsum_f32("bsnd,bwnd->bnsw", q_nope, kvb[..., :dn])
-        scores = scores + _einsum_f32("bsnr,bwr->bnsw", q_rope, k_r)
+        if not absorbed:
+            # A chunk: the window's rows up-projected to keys and values,
+            # a block at a time beside their use or the whole of it.
+            attend = (latent_chunk_attention.latent_chunk_attention
+                      if chunk_attention_form(cfg, s, rows.shape[1],
+                                              rows.shape[2],
+                                              rows.dtype) == "blocks"
+                      else latent_chunk_attention.plain)
+            out = attend(q_nope, q_rope, rows, w_kvb.astype(dtype), q_pos,
+                         scale=softmax_scale(cfg))
+            return out.reshape(b, s, nh * dv), pool_c
+        # A step, absorbed: scores against the cached row itself, the
+        # up-projection of the keys folded into the query ...
+        q_lat = _einsum_f32("bsnd,cnd->bsnc", q_nope,
+                            w_kvb[..., :dn]).astype(dtype)
+        scores = (_einsum_f32("bsnc,bwc->bnsw", q_lat, c)
+                  + _einsum_f32("bsnr,bwr->bnsw", q_rope, k_r))
         cols = jnp.arange(rows.shape[1])
         mask = cols[None, None, None, :] <= q_pos[:, None, :, None]
         scores = jnp.where(mask, scores * softmax_scale(cfg), -1e30)
         p = jax.nn.softmax(scores, axis=-1).astype(dtype)
-        if absorbed:
-            # ... and that of the values into the output.
-            o_lat = _einsum_f32("bnsw,bwc->bsnc", p, c).astype(dtype)
-            out = _einsum_f32("bsnc,cnd->bsnd", o_lat, w_kvb[..., dn:])
-        else:
-            out = _einsum_f32("bnsw,bwnd->bsnd", p, kvb[..., dn:])
+        # ... and that of the values into the output.
+        o_lat = _einsum_f32("bnsw,bwc->bsnc", p, c).astype(dtype)
+        out = _einsum_f32("bsnc,cnd->bsnd", o_lat, w_kvb[..., dn:])
     return out.astype(dtype).reshape(b, s, nh * dv), pool_c
 
 

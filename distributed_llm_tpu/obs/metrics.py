@@ -459,6 +459,14 @@ METRIC_REGISTRY: Tuple[Tuple[str, str, str, Tuple[str, ...], str], ...] = (
      "16 k-token prompt's 64 chunks laid against the ladder, 1 at 256, "
      "3 at 1024, 4, 8, 16 and 32 at the doublings (/stats "
      "prefill.chunks_by_window)"),
+    ("prefill_chunks_by_form", "counter",
+     "dllm_prefill_chunks_by_attention_form_total", ("tier", "form"),
+     "Of a latent-row tier's dllm_prefill_chunks_total, the chunks by "
+     "what their compiled program attends the latent rows with (form: "
+     "blocks, the kernel of ops/latent_chunk_attention.py; plain, einsum "
+     "+ softmax over the whole up-projected window; a static test on "
+     "the program's shapes: /stats prefill.attention_form names it a "
+     "program)"),
     ("prefill_written_positions", "counter",
      "dllm_prefill_written_positions_total", ("tier",),
      "Positions written when each of those chunks ran (its end, capped "
@@ -781,6 +789,8 @@ BOUNDED_LABELS: Dict[str, str] = {
               "ladder (engine/batching.py _window_ladder: 256, 1024 and "
               "its doublings below the slot's span, the span; 7 at a "
               "span of 32768)",
+    "form": "closed set: blocks|plain (what a chunk program attends its "
+            "latent rows with, engine/batching.py chunk_attention_form)",
 }
 
 

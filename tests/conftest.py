@@ -6,6 +6,7 @@ multi-chip sharding is validated on host CPU via
 TPU.  Must be set before jax is imported anywhere.
 """
 
+import gc
 import os
 import sys
 
@@ -42,6 +43,7 @@ os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "0.5")
 os.environ.setdefault("DLLM_KV_LEAK_CHECK", "1")
 
 import jax  # noqa: E402
+import pytest  # noqa: E402
 
 # Belt and braces for a jax some pytest plugin imported before this file
 # ran (the env vars above are only read at import).
@@ -64,3 +66,19 @@ def pytest_configure(config):
     config.addinivalue_line(
         "markers", "chaos: scripted-fault chaos-soak scenarios "
                    "(utils/faults.py FaultSchedule)")
+
+
+@pytest.fixture(autouse=True, scope="module")
+def _release_a_files_programs():
+    """After a file's tests, drop jax's in-memory program caches.  A
+    loaded CPU executable keeps some 700 memory maps, an eager ``scan``
+    read back from the persistent cache loads one a CALL, and the jit
+    caches keep them all: a worker that serves five files passed Linux's
+    65 530 maps a process (``vm.max_map_count``) in
+    ``test_latent_moe.py``'s int8 test and died inside
+    ``deserialize_executable`` in 2 of 4 whole runs (PR 61: a new file
+    had shifted which files share a worker).  The persistent cache
+    keeps the next file's reads cheap."""
+    yield
+    jax.clear_caches()
+    gc.collect()

@@ -112,9 +112,10 @@ def test_kernel_comparison_at_tiny_widths(dtype):
     cases = chip_smoke.kernel_cases(
         8, 4, 16, dtype, batch=3, block=16, blocks_per_slot=4,
         prefill_len=64, grouped={"tiny": (24, 6, 256, 128)},
-        scan=(16, 8, 256))
+        scan=(16, 8, 256), latent=(2, 16, 512, 400, 128, 256))
     assert {c.kind for c in cases.values()} == {
-        "prefill", "paged_decode", "grouped_product", "ssm_scan"}
+        "prefill", "paged_decode", "grouped_product", "ssm_scan",
+        "latent_chunk"}
     errs = chip_smoke.compare_kernels(cases, jnp.dtype(dtype).name)
     assert set(errs) == set(cases)
 

@@ -330,7 +330,10 @@ def test_the_route_returns_an_entry_for_each_warmed_program(asked):
         tick = e["stage"] == "decode"
         assert e["program"] == ("jit_decode_tick" if tick
                                 else "jit_chunk_prefill")
-        assert (e["attention_form"] is not None) == tick
+        # A tick's form a rung; a chunk program's where it attends latent
+        # rows (the tiny presets' widths keep the plain form: ISSUE 61).
+        assert e["attention_form"] is not None if tick else (
+            e["attention_form"] in (None, "plain"))
         assert (e["chunk_tokens"] is None) == tick
         assert set(e["built_s"]) == {"lower", "compile", "read"}
         assert len(e["ops"]) > 50

@@ -55,7 +55,11 @@ KERNELS = ["flash_causal_attention",
            "grouped_ffn.reasoned-reply",
            # Mamba-1's recurrence over a chunk (float32 and headless: the
            # same case under every preset), at the shared-K/V cell's chunk.
-           "ssm_chunk_scan"]
+           "ssm_chunk_scan",
+           # The latent row's chunk attention by blocks of the window
+           # (its own 64 heads: the same case under every preset), at a
+           # rung of sarvam-105b's lane.
+           "latent_chunk_attention"]
 
 
 @pytest.fixture(scope="module")
@@ -591,10 +595,11 @@ def test_a_pattern_of_latent_layers_alone_compiles_at_its_widest_rung(
     program's ONE ``while`` (the tick's two are held by the routed-tick
     test above); 64 heads' float32 scores against 16 384 positions, their
     probabilities and the up-projected keys and values of the whole rung
-    are the plain form's temporaries, 1.64 GB a layer in turn, so that
-    weights, pool and temporaries stay under 13.5 GB of the chip's 16 GiB;
-    the sines of a chunk's positions are made once, under
-    ``step_inputs``."""
+    were the plain form's temporaries, 1.64 GB a layer in turn (PR 59);
+    by blocks of the window (ISSUE 61) the gathered rows, 21 MB, are the
+    attention's largest, and weights, pool and temporaries stay under 12.5
+    GB of the chip's 16 GiB; the sines of a chunk's positions are made
+    once, under ``step_inputs``."""
     tier = _bench_tier(monkeypatch, "sarvam-105b")
     engine, pool, compiled, _ = _pool_program(one_chip, tier,
                                               ("chunk", 256, 16384))
@@ -605,14 +610,93 @@ def test_a_pattern_of_latent_layers_alone_compiles_at_its_widest_rung(
     text = compiled.as_text()
     assert text.count(" while(") == 1
     m = compiled.memory_analysis()
-    assert m.temp_size_in_bytes < 2.0 * GB
-    assert (m.argument_size_in_bytes + m.temp_size_in_bytes) < 13.5 * GB
+    assert m.temp_size_in_bytes < 1.0 * GB
+    assert (m.argument_size_in_bytes + m.temp_size_in_bytes) < 12.5 * GB
     for scope in ("latent_attention", "kv_write", "mixer_proj",
                   "moe_router", "moe_experts", "shared_expert", "ffn",
                   "step_inputs", "head"):
         assert scope in text, scope
-    assert text.count("tpu_custom_call") == 3          # gate, up, down
+    # gate, up, down; the latent attention of the lead layer and of the
+    # scan's body.
+    assert text.count("tpu_custom_call") == 3 + 2
     assert "ragged-dot" not in text
+
+
+# -- the latent chunk attention by blocks of the window (ISSUE 61) -------------
+
+# The chunk program at the widest rung each latent cell's requests reach
+# (``reasoned-reply``'s prompts end under 4096), compiled by the pool
+# cases above: (heads, what else the program calls a kernel for).
+LATENT_CHUNKS = {
+    ("sarvam-105b", ("chunk", 256, 16384)): (64, 3),
+    ("xing4.0-29b-a4b", ("chunk", 256, 4096)): (32, 1),
+}
+
+
+@pytest.mark.parametrize("config,program", list(LATENT_CHUNKS),
+                         ids=[c for c, _ in LATENT_CHUNKS])
+def test_the_latent_chunk_attention_leaves_no_scores_in_memory(
+        one_chip, as_on_tpu, monkeypatch, config, program):
+    """One custom call a latent site (the inline lead layer, the scan's
+    body) and nothing ``[N, S, W]``-sized, inside a fusion or outside one:
+    neither the float32 scores, nor their probabilities, nor (at a head's
+    256 numbers a row, as many elements) the up-projected window.  On the
+    parent the scores alone were 1.07 GB a layer at sarvam's top rung."""
+    heads, other_calls = LATENT_CHUNKS[config, program]
+    tier = _bench_tier(monkeypatch, config)
+    engine, pool, compiled, _ = _pool_program(one_chip, tier, program)
+    _, chunk, window = program
+    assert engine.cfg.num_heads == heads
+    assert engine.chunk_attention_form(chunk, window) == "blocks"
+    text = compiled.as_text()
+    calls = [line for line in text.splitlines() if "tpu_custom_call" in line]
+    assert len(calls) == other_calls + 2
+    assert sum("latent_chunk_attention" in line for line in calls) == 2
+    large, scoped = [], 0
+    for line in text.splitlines():
+        m = HLO_RESULT.match(line)
+        if not m or "/latent_attention/" not in line:
+            continue
+        scoped += 1
+        dims = m[2][m[2].index("[") + 1:-1]
+        if math.prod(int(x) for x in dims.split(",") if x) >= (
+                heads * chunk * window):
+            large.append((m[2], m[3]))
+    assert scoped and large == []
+
+
+def test_no_other_program_reaches_the_latent_chunk_kernel(
+        one_chip, as_on_tpu, monkeypatch):
+    """Every configuration's tick, and the chunk program of the five that
+    cache no latent row, lower without ``serves`` being asked: the
+    absorbed decode form and the dense, ring, shared-K/V, one-head and
+    CCA attention have call sites of their own, so their programs are the
+    parent's.  Lowered anew at a rung no case above compiles (a ``jit``
+    does not trace a shape twice)."""
+    from distributed_llm_tpu.ops import latent_chunk_attention
+
+    def asked(*shape):
+        raise AssertionError(f"latent_chunk_attention.serves{shape}")
+    monkeypatch.setattr(latent_chunk_attention, "serves", asked)
+    lowered = 0
+    for config in dict.fromkeys(c for c, _ in BENCH_POOL_PROGRAMS):
+        tier = _bench_tier(monkeypatch, config)
+        engine, pool, _, _ = _pool_program(
+            one_chip, tier, next(p for c, p in BENCH_POOL_PROGRAMS
+                                 if c == config))
+        engine._decode_fn = None
+        assert engine.lower_pool_program(
+            "decode", 512 // tier.kv_block_size, pool) is not None
+        lowered += 1
+        if not engine.cfg.kv_lora_rank:
+            assert engine.chunk_attention_form(256, 512) is None
+            assert engine.lower_pool_program(
+                "chunk_prefill", (256, 512), pool) is not None
+            lowered += 1
+    assert lowered == 8 + 5
+    # The control: a latent configuration's chunk program asks.
+    with pytest.raises(AssertionError, match="latent_chunk_attention.serves"):
+        engine.lower_pool_program("chunk_prefill", (256, 512), pool)
 
 
 # -- wq and wk are read where they rest (ISSUE 48) -----------------------------
